@@ -47,9 +47,10 @@ Measured results:
 
 Each audit dict carries ``plan`` — the ``hvd.overlap_plan()`` decision
 recorded while the step traced — and ``gate_is_finite_ops``, the count of
-``is_finite`` gate ops in the lowered stablehlo (the chain's arithmetic
-gate is the only source of ``is_finite`` in this model, so the count is a
-direct structural probe of chain presence).
+the chain's own ``is_finite`` gate ops in the lowered stablehlo.  The gate
+is traced under the ``CHAIN_GATE_SCOPE`` name scope, and only ops whose
+location carries that scope are counted: the loss's logsumexp emits an
+``is_finite`` too, which a plain text count mistook for a gate.
 """
 
 from __future__ import annotations
@@ -95,6 +96,22 @@ def build_step():
     return model, opt, step
 
 
+def lowered_stats(lowered) -> dict:
+    """Structural counts read off the lowered stablehlo: all-reduces, and
+    the ``is_finite`` ops traced inside the bucket chain's gate scope."""
+    from horovod_tpu.ops.collective_ops import CHAIN_GATE_SCOPE
+
+    txt = lowered.as_text(debug_info=True)
+    gate_locs = set(re.findall(
+        rf'^(#loc\d+) = loc\("(?:[^"]*/)?{CHAIN_GATE_SCOPE}/is_finite"',
+        txt, re.M))
+    gates = sum(1 for m in re.finditer(
+        r"stablehlo\.is_finite .* loc\((#loc\d+)\)", txt)
+        if m.group(1) in gate_locs)
+    return {"stablehlo_all_reduces": txt.count("stablehlo.all_reduce"),
+            "gate_is_finite_ops": gates}
+
+
 def audit_text(txt: str) -> dict:
     lines = txt.splitlines()
     ar = [i for i, l in enumerate(lines)
@@ -129,10 +146,8 @@ def audit_cpu_sim() -> dict:
                                   hvd.batch_spec(1)),
                         out_specs=(P(), P()))
     lowered = jax.jit(sharded).lower(params, opt_state, x, y)
-    stablehlo = lowered.as_text()
     out = audit_text(lowered.compile().as_text())
-    out["stablehlo_all_reduces"] = stablehlo.count("all_reduce")
-    out["gate_is_finite_ops"] = stablehlo.count("is_finite")
+    out.update(lowered_stats(lowered))
     out["plan"] = hvd.overlap_plan()
     return out
 
@@ -146,7 +161,6 @@ def audit_cpu_sim_width1() -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     import horovod_tpu as hvd
@@ -154,18 +168,16 @@ def audit_cpu_sim_width1() -> dict:
     hvd.init()
     model, opt, step = build_step()
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("hvd",))
-    sharded = shard_map(step, mesh=mesh,
-                        in_specs=(P(), P(), P("hvd"), P("hvd")),
-                        out_specs=(P(), P()), check_rep=False)
+    sharded = jax.shard_map(step, mesh=mesh,
+                            in_specs=(P(), P(), P("hvd"), P("hvd")),
+                            out_specs=(P(), P()), check_vma=False)
     x = jnp.zeros((16, 1024))
     y = jnp.zeros((16,), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), x)
     opt_state = opt.init(params)
     lowered = jax.jit(sharded).lower(params, opt_state, x, y)
-    stablehlo = lowered.as_text()
     out = audit_text(lowered.compile().as_text())
-    out["stablehlo_all_reduces"] = stablehlo.count("all_reduce")
-    out["gate_is_finite_ops"] = stablehlo.count("is_finite")
+    out.update(lowered_stats(lowered))
     out["plan"] = hvd.overlap_plan()
     return out
 
@@ -177,7 +189,6 @@ def audit_tpu_topology(topology: str = "v5e:2x4",
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     topo = topologies.get_topology_desc(platform="tpu",
@@ -185,9 +196,9 @@ def audit_tpu_topology(topology: str = "v5e:2x4",
     mesh = Mesh(topo.devices, ("hvd",))
     model, opt, step = build_step()
 
-    sharded = shard_map(step, mesh=mesh,
-                        in_specs=(P(), P(), P("hvd"), P("hvd")),
-                        out_specs=(P(), P()), check_rep=False)
+    sharded = jax.shard_map(step, mesh=mesh,
+                            in_specs=(P(), P(), P("hvd"), P("hvd")),
+                            out_specs=(P(), P()), check_vma=False)
 
     pv = jax.eval_shape(model.init, jax.random.PRNGKey(0),
                         jnp.zeros((1, 1024)))
@@ -203,13 +214,11 @@ def audit_tpu_topology(topology: str = "v5e:2x4",
     ys = jax.ShapeDtypeStruct((64,), jnp.int32,
                               sharding=NamedSharding(mesh, P("hvd")))
     lowered = jax.jit(sharded).lower(ps, os_, xs, ys)
-    stablehlo = lowered.as_text()
     out = audit_text(lowered.compile().as_text()
                      if compiler_options is None else
                      lowered.compile(compiler_options=compiler_options)
                      .as_text())
-    out["stablehlo_all_reduces"] = stablehlo.count("all_reduce")
-    out["gate_is_finite_ops"] = stablehlo.count("is_finite")
+    out.update(lowered_stats(lowered))
     import horovod_tpu as hvd
 
     out["plan"] = hvd.overlap_plan()

@@ -127,12 +127,6 @@ def init(*, distributed: bool | None = None, coordinator_address: str | None = N
     global _topology
     import jax
 
-    from horovod_tpu.utils import jaxcompat
-
-    # Tests and user code reach jax.shard_map directly after init();
-    # bridge the pinned-release surface first (utils/jaxcompat.py).
-    jaxcompat.install()
-
     if comm is not None:
         if ranks is not None:
             raise ValueError("pass either ranks= or comm=, not both")
@@ -166,11 +160,6 @@ def init(*, distributed: bool | None = None, coordinator_address: str | None = N
         if want_dist is None:
             want_dist = coordinator_address is not None
         if want_dist:
-            if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-                # Multi-process CPU jobs (the launcher's -np N simulation)
-                # need an explicit CPU-collectives backend on the pinned
-                # jaxlib (utils/jaxcompat.py).
-                jaxcompat.enable_cpu_multiprocess_collectives()
             try:
                 jax.distributed.initialize(
                     coordinator_address=coordinator_address,

@@ -167,9 +167,6 @@ def _dense_reducer(mesh, n_pad: int, dtype):
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from horovod_tpu.utils import jaxcompat
-
-    jaxcompat.install()  # jax.shard_map on older pinned jax releases
 
     key = (mesh.size, n_pad, dtype.name)
     fn = _dense_cache.get(key)
@@ -230,10 +227,6 @@ def process_allgather(arr: np.ndarray) -> np.ndarray:
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.utils import jaxcompat
-
-    jaxcompat.install()  # jax.shard_map on older pinned jax releases
-
     mesh = _process_mesh()
     n = arr.size
     key = (mesh.size, n, arr.dtype.name)
@@ -283,9 +276,6 @@ def _int8_reducer(mesh, n_pad: int, nt: int):
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from horovod_tpu.utils import jaxcompat
-
-    jaxcompat.install()  # jax.shard_map on older pinned jax releases
 
     key = (mesh.size, n_pad, nt)
     fn = _int8_cache.get(key)

@@ -44,9 +44,6 @@ from jax.sharding import PartitionSpec
 from horovod_tpu import basics, mesh
 from horovod_tpu.ops.compression import Compression
 from horovod_tpu.ops import fusion
-from horovod_tpu.utils import jaxcompat
-
-jaxcompat.install()  # jax.shard_map on older pinned jax releases
 
 Average = True  # default matches reference allreduce(average=True)
 
@@ -350,6 +347,9 @@ def quantized_grouped_allreduce(tensors: Sequence, errors: Sequence | None = Non
     return reduced, resid
 
 
+CHAIN_GATE_SCOPE = "hvd_chain_gate"
+
+
 def _chained_allreduce(vals: list, axes, n_buckets: int,
                        bounds: Sequence[int] | None = None) -> list:
     """Per-tensor psums in ``n_buckets`` dependency-chained groups, reverse
@@ -419,8 +419,12 @@ def _chained_allreduce(vals: list, axes, n_buckets: int,
         scalars = [r.reshape(-1)[0].astype(jnp.float32) for r in red
                    if jnp.issubdtype(r.dtype, jnp.inexact) and r.size > 0]
         if scalars:
-            s = sum(scalars)
-            gate = jnp.where(jnp.isfinite(s), s, 0.0) * 0.0
+            # Named so a structural probe can count the chain's gates in a
+            # lowered program apart from every other is_finite (logsumexp
+            # emits one of its own) — examples/overlap_audit.py.
+            with jax.named_scope(CHAIN_GATE_SCOPE):
+                s = sum(scalars)
+                gate = jnp.where(jnp.isfinite(s), s, 0.0) * 0.0
         for i, r in zip(idx, red):
             out[i] = r
     return [out[i] for i in range(n)]

@@ -30,16 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from horovod_tpu.utils import jaxcompat
-
-jaxcompat.install()  # pltpu.CompilerParams spelling on older releases
-try:  # TPU-specific memory spaces; absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-    _SMEM = pltpu.SMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _SMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634  # log2(e): folded into the q scale so the
@@ -212,8 +203,6 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
 def _dims_arbitrary_last():
     """Mosaic dimension semantics for the backward grids: outer axes are
     parallel, the innermost is the sequential accumulation sweep."""
-    if pltpu is None:  # pragma: no cover - CPU-only builds
-        return None
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
@@ -256,13 +245,17 @@ def _sub_fit(block: int, sub: int) -> tuple[int, int]:
     return block, sub
 
 
-# Per-core VMEM is 16 MiB; the fit budget sits below it because this
-# estimate cannot see Mosaic's scheduling windows — exactly how the
-# hand-set block_k=4096 passed review at S=8192 and then overflowed the
-# remat backward at S=32768 (docs/benchmarks.md round 5).  Requested
-# blocks whose estimated resident set exceeds the budget are halved with
-# a warning instead of failing inside Pallas.
-VMEM_LIMIT_MB = 16.0
+# Mosaic gives one kernel 16 MiB of scoped VMEM by default on the v5e
+# (device_kind "TPU v5 lite"), and nothing here raises that limit.
+# Confirmed on that device with libtpu 0.0.34 (CHANGES.md PR 21): at the
+# default tiles the forward, dq and dk/dv kernels compile and run at
+# S=1024/B=8, S=8192/B=4 and S=32768/B=1 with remat (d=128, bf16).  The
+# fit budget sits below the limit because this estimate cannot see
+# Mosaic's scheduling windows — exactly how the hand-set block_k=4096
+# passed review at S=8192 and then overflowed the remat backward at
+# S=32768 (docs/benchmarks.md round 5).  Requested blocks whose estimated
+# resident set exceeds the budget are halved with a warning instead of
+# failing inside Pallas.  Another device kind needs its own confirmation.
 VMEM_FIT_BUDGET_MB = 13.0
 _VMEM_MIN_BLOCK = 128
 _vmem_clamp_warned: set = set()
@@ -330,7 +323,6 @@ def _flash_forward(q, k, v, causal, q_offset, k_offset, block_q, block_k,
         return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
 
     qb = _pad_to(to_bh(q), 1, block_q)
-    smem = {"memory_space": _SMEM} if _SMEM is not None else {}
     meta = jnp.asarray(
         [jnp.asarray(q_offset, jnp.int32),
          jnp.asarray(k_offset, jnp.int32),
@@ -349,7 +341,8 @@ def _flash_forward(q, k, v, causal, q_offset, k_offset, block_q, block_k,
         kernel,
         grid=(b * h, num_q_blocks, num_k_blocks),
         in_specs=[
-            pl.BlockSpec((3,), lambda bh, qi, ki: (0,), **smem),
+            pl.BlockSpec((3,), lambda bh, qi, ki: (0,),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d),
                          lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d),
@@ -664,7 +657,6 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
         [jnp.asarray(q_offset, jnp.int32),
          jnp.asarray(k_offset, jnp.int32),
          jnp.asarray(k_offset, jnp.int32) + s_k], jnp.int32)
-    smem = {"memory_space": _SMEM} if _SMEM is not None else {}
 
     dq_kernel = functools.partial(
         _bwd_dq_kernel, block_q=block_q, block_k=block_k, sub_k=sub_k,
@@ -675,7 +667,8 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
         dq_kernel,
         grid=(b * h, num_q_blocks, num_k_blocks),
         in_specs=[
-            pl.BlockSpec((3,), lambda bh, qi, ki: (0,), **smem),
+            pl.BlockSpec((3,), lambda bh, qi, ki: (0,),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
@@ -698,7 +691,8 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
         dkv_kernel,
         grid=(b * h, num_k_dkv, num_q_blocks),
         in_specs=[
-            pl.BlockSpec((3,), lambda bh, ki, qi: (0,), **smem),
+            pl.BlockSpec((3,), lambda bh, ki, qi: (0,),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
             pl.BlockSpec((1, bk_dkv, d), lambda bh, ki, qi: (bh, ki, 0)),
             pl.BlockSpec((1, bk_dkv, d), lambda bh, ki, qi: (bh, ki, 0)),

@@ -274,22 +274,21 @@ def probe_headroom_mb() -> float | None:
         if _probe_cache:
             return _probe_cache[0]
         headroom = None
-        try:
-            frees = []
-            for dev in jax.local_devices():
-                stats = getattr(dev, "memory_stats", lambda: None)()
-                if not stats:
-                    continue
-                limit = stats.get("bytes_limit")
-                in_use = stats.get("bytes_in_use")
-                if limit is None or in_use is None:
-                    continue
-                frees.append(max(int(limit) - int(in_use), 0))
-            if frees:
-                mb = min(frees) / (1024.0 * 1024.0)
-                headroom = (mb // HEADROOM_QUANTUM_MB) * HEADROOM_QUANTUM_MB
-        except Exception:  # backend without devices yet (AOT) — unknown
-            headroom = None
+        frees = []
+        for dev in jax.local_devices():
+            # None on backends that keep no allocator statistics (CPU);
+            # a probe that raises is a fault to see, not "unknown".
+            stats = dev.memory_stats()
+            if not stats:
+                continue
+            limit = stats.get("bytes_limit")
+            in_use = stats.get("bytes_in_use")
+            if limit is None or in_use is None:
+                continue
+            frees.append(max(int(limit) - int(in_use), 0))
+        if frees:
+            mb = min(frees) / (1024.0 * 1024.0)
+            headroom = (mb // HEADROOM_QUANTUM_MB) * HEADROOM_QUANTUM_MB
         _probe_cache.append(headroom)
         return headroom
 

@@ -40,6 +40,9 @@ def _make_backend(cfg: ServingConfig):
 
     from horovod_tpu.models.transformer import (Transformer,
                                                 TransformerConfig)
+    from horovod_tpu.utils import chip
+
+    chip.enable_compile_cache()
 
     mcfg = TransformerConfig(vocab_size=256, num_layers=2, num_heads=2,
                              head_dim=16, embed_dim=32, mlp_dim=64,

@@ -7,7 +7,7 @@ profiling module (and its jax dependency) eagerly.
 
 import importlib
 
-_SUBMODULES = ("backoff", "env", "jaxcompat", "manifest", "profiling")
+_SUBMODULES = ("backoff", "chip", "env", "manifest", "profiling")
 
 
 def __getattr__(name: str):

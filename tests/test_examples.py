@@ -46,9 +46,6 @@ def _final_metrics(out: str, np_: int = 2) -> dict[int, str]:
 def _run(script, *args, timeout=420, env=None):
     env = {
         **os.environ,
-        # Only the device-count flag: this image's jaxlib rejects the
-        # --xla_cpu_collective_call_* timeout flags (unknown XLA flags are a
-        # process abort, parse_flags_from_env.cc).
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
         "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO,

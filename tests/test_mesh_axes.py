@@ -48,15 +48,15 @@ def test_custom_axis_name_gets_in_mesh_semantics(hvd):
     must reduce over that bound axis, not fall back to eager process-level
     semantics.  Pins the `_bound_axis_names` contract so private-JAX-API
     drift (jax._src.core.get_axis_env) is caught loudly (advisor round 1)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh
 
     devs = np.array(jax.devices()[:4])
     custom = Mesh(devs, ("workers",))
     x = jnp.ones((4, 3), jnp.float32)
 
-    fn = shard_map(lambda v: hvd.allreduce(v, average=False),
-                   mesh=custom, in_specs=P("workers"), out_specs=P("workers"))
+    fn = jax.shard_map(lambda v: hvd.allreduce(v, average=False),
+                       mesh=custom, in_specs=P("workers"),
+                       out_specs=P("workers"))
     out = np.asarray(jax.jit(fn)(x))
     # sum over the 4-wide custom axis of all-ones must be exactly 4.0
     np.testing.assert_allclose(out, np.full((4, 3), 4.0), rtol=1e-6)
@@ -65,7 +65,6 @@ def test_custom_axis_name_gets_in_mesh_semantics(hvd):
 def test_bound_axis_names_fallback_probes_custom_mesh(hvd, monkeypatch):
     """Force the private-API path to fail and verify the fallback still
     discovers a bound custom axis via the active physical mesh."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh
     from horovod_tpu.ops import collective_ops
 
@@ -78,8 +77,8 @@ def test_bound_axis_names_fallback_probes_custom_mesh(hvd, monkeypatch):
     custom = Mesh(devs, ("workers",))
     x = jnp.ones((4, 3), jnp.float32)
     with custom:
-        fn = shard_map(lambda v: collective_ops.allreduce(v, average=False),
-                       mesh=custom, in_specs=P("workers"),
-                       out_specs=P("workers"))
+        fn = jax.shard_map(
+            lambda v: collective_ops.allreduce(v, average=False),
+            mesh=custom, in_specs=P("workers"), out_specs=P("workers"))
         out = np.asarray(jax.jit(fn)(x))
     np.testing.assert_allclose(out, np.full((4, 3), 4.0), rtol=1e-6)

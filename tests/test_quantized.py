@@ -375,8 +375,6 @@ def test_tiered_int8_on_hierarchical_mesh(hvd):
 
 _WIDTH32_SCRIPT = r"""
 import os
-# Device-count flag only: the pinned jaxlib aborts on unknown XLA flags
-# (the --xla_cpu_collective_call_* timeouts postdate it).
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=32"
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
