@@ -21,6 +21,7 @@ from typing import Any, Callable, Iterable, Iterator, Sequence
 import numpy as np
 
 from horovod_tpu import basics
+from horovod_tpu.utils import profiling
 
 
 def shard_arrays(*arrays, drop_remainder: bool = True):
@@ -122,10 +123,14 @@ class BackgroundLoader:
 
         def produce() -> None:
             try:
-                for item in self._source:
-                    if not put_or_stop(item):
+                source = iter(self._source)
+                while True:
+                    # what the source costs, on this thread's line of a
+                    # profiler trace; the wait for a free slot is outside
+                    with profiling.annotate(profiling.LOADER_PRODUCE):
+                        item = next(source, self._DONE)
+                    if not put_or_stop(item) or item is self._DONE:
                         return
-                put_or_stop(self._DONE)
             except BaseException as e:  # noqa: BLE001 — relayed to consumer
                 put_or_stop(e)
 
@@ -133,7 +138,9 @@ class BackgroundLoader:
         t.start()
         try:
             while True:
-                item = q.get()
+                # no batch was ready: the consumer's share of an input wait
+                with profiling.annotate(profiling.LOADER_WAIT):
+                    item = q.get()
                 if item is self._DONE:
                     return
                 if isinstance(item, BaseException):
@@ -183,10 +190,12 @@ def prefetch_to_device(iterator: Iterable, size: int = 2,
                 batch = next(it)
             except StopIteration:
                 return
-            out = (put(batch, sharding) if sharding is not None
-                   else put(batch))
-            if sync:
-                jax.block_until_ready(out)
+            # the copy was issued (and, on the CPU simulation, finished)
+            with profiling.annotate(profiling.H2D_PUT):
+                out = (put(batch, sharding) if sharding is not None
+                       else put(batch))
+                if sync:
+                    jax.block_until_ready(out)
             buf.append(out)
 
     enqueue(size)

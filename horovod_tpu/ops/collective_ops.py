@@ -44,6 +44,7 @@ from jax.sharding import PartitionSpec
 from horovod_tpu import basics, mesh
 from horovod_tpu.ops.compression import Compression
 from horovod_tpu.ops import fusion
+from horovod_tpu.utils import profiling
 
 Average = True  # default matches reference allreduce(average=True)
 
@@ -401,6 +402,7 @@ def _chained_allreduce(vals: list, axes, n_buckets: int,
     out: dict[int, jax.Array] = {}
     gate = None
     rev = list(range(n))[::-1]
+    k = 0       # the bucket's place in the chain: what its scope says
     for lo, hi in zip(bounds[:-1], bounds[1:]):
         idx = rev[lo:hi]
         if not idx:
@@ -411,7 +413,9 @@ def _chained_allreduce(vals: list, axes, n_buckets: int,
             if gate is not None and jnp.issubdtype(v.dtype, jnp.inexact):
                 v = v + gate.astype(v.dtype)
             bucket.append(v)
-        red = [_mesh_allreduce(v, axes) for v in bucket]
+        with jax.named_scope(profiling.bucket_scope(k)):
+            red = [_mesh_allreduce(v, axes) for v in bucket]
+        k += 1
         # The gate sums a scalar from EVERY inexact reduction in the
         # bucket, so the next bucket depends on all of them — merging any
         # of this bucket's ARs forward would form a cycle structurally,
@@ -508,7 +512,9 @@ def grouped_allreduce(tensors: Sequence, average: bool = True,
                                              plan.chain_depth,
                                              bounds=plan.bounds)
             else:
-                reduced = [_mesh_allreduce(c, axes) for c, _ in comp]
+                with jax.named_scope(
+                        profiling.bucket_scope(profiling.BUCKET_ALL)):
+                    reduced = [_mesh_allreduce(c, axes) for c, _ in comp]
         else:
             # Hierarchical (e.g. (dcn, ici)) route: each tensor lowers to
             # a psum_scatter→psum→all_gather CHAIN (parallel/hierarchy.py)

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.utils import profiling
+
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634  # log2(e): folded into the q scale so the
 # online softmax runs on exp2 — the VPU's native exponential — instead
@@ -368,6 +370,7 @@ def _flash_forward(q, k, v, causal, q_offset, k_offset, block_q, block_k,
         ),
         compiler_params=_dims_arbitrary_last(),
         interpret=interpret,
+        name=profiling.FLASH_FWD,
     )(meta, qb, kb, vb)
     out = out.astype(q.dtype)
     out = out[:, :s_q].reshape(b, h, s_q, d)
@@ -681,6 +684,7 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
         out_shape=jax.ShapeDtypeStruct(qb.shape, jnp.float32),
         compiler_params=_dims_arbitrary_last(),
         interpret=interpret,
+        name=profiling.FLASH_DQ,
     )(meta, qb, kb, vb, dob, lse_b, delta_b).astype(q.dtype)
 
     num_k_dkv = kb.shape[1] // bk_dkv
@@ -710,6 +714,7 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
         ),
         compiler_params=_dims_arbitrary_last(),
         interpret=interpret,
+        name=profiling.FLASH_DKV,
     )(meta, qb, kb, vb, dob, lse_b, delta_b)
     dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
 

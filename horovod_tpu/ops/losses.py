@@ -26,6 +26,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from horovod_tpu.utils import profiling
+
 
 @jax.custom_vjp
 def softmax_cross_entropy(logits, labels):
@@ -36,6 +38,7 @@ def softmax_cross_entropy(logits, labels):
     return loss
 
 
+@jax.named_scope(profiling.LOSS)
 def _ce_fwd(logits, labels):
     lf = logits.astype(jnp.float32)
     lse = jax.nn.logsumexp(lf, axis=-1)
@@ -43,6 +46,7 @@ def _ce_fwd(logits, labels):
     return lse - true_logit, (logits, lse, labels)
 
 
+@jax.named_scope(profiling.LOSS)
 def _ce_bwd(res, g):
     logits, lse, labels = res
     p = jnp.exp(logits.astype(jnp.float32) - lse[..., None])

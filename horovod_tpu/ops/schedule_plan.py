@@ -128,6 +128,14 @@ class BucketPlan:
     chain_extra_bytes: int
     reason: str
     bounds: tuple[int, ...] | None = None
+    # Where ``headroom_mb`` came from (:func:`headroom_record`): a record
+    # of circumstance and no part of the decision, so plans compare without
+    # it; filled by :func:`plan_overlap`, not by a planner.
+    headroom_source: str | None = dataclasses.field(default=None,
+                                                    compare=False)
+    headroom_probe: dict | None = dataclasses.field(default=None,
+                                                    compare=False)
+    plans_before: int = dataclasses.field(default=0, compare=False)
 
     @property
     def chained(self) -> bool:
@@ -251,6 +259,8 @@ class AdaptivePlanner(Planner):
 
 _probe_lock = threading.Lock()
 _probe_cache: list = []  # [float | None] once probed — one answer per process
+_probe_read: list = []   # [dict | None] beside it: what that one probe read
+_plans_made = 0          # every plan_overlap / plan_context call so far
 
 
 def probe_headroom_mb() -> float | None:
@@ -274,7 +284,7 @@ def probe_headroom_mb() -> float | None:
         if _probe_cache:
             return _probe_cache[0]
         headroom = None
-        frees = []
+        frees, read = [], []
         for dev in jax.local_devices():
             # None on backends that keep no allocator statistics (CPU);
             # a probe that raises is a fault to see, not "unknown".
@@ -286,11 +296,42 @@ def probe_headroom_mb() -> float | None:
             if limit is None or in_use is None:
                 continue
             frees.append(max(int(limit) - int(in_use), 0))
+            read.append({"bytes_in_use": int(in_use),
+                         "bytes_limit": int(limit)})
         if frees:
             mb = min(frees) / (1024.0 * 1024.0)
             headroom = (mb // HEADROOM_QUANTUM_MB) * HEADROOM_QUANTUM_MB
         _probe_cache.append(headroom)
+        # the fullest device's counters and how many plans the process had
+        # made by then: a later plan whose own ``plans_before`` is larger was
+        # handed a headroom that an earlier program's trace fixed
+        _probe_read.append(
+            {**read[frees.index(min(frees))], "plans_before": _plans_made}
+            if frees else None)
         return headroom
+
+
+def headroom_record(given: bool = False) -> dict:
+    """What a plan records about the headroom it was made with:
+    ``headroom_source`` -- ``"given"`` (the caller passed a number), ``"env"``
+    (``HVD_TPU_DEVICE_HEADROOM_MB``) or ``"probe"`` -- for a probe
+    ``headroom_probe``: the ``bytes_in_use`` and ``bytes_limit`` of the
+    fullest device and the ``plans_before`` it when the process's one cached
+    probe was taken; and this plan's own ``plans_before``.  Call after the
+    headroom was read, once a plan; record only, nothing here decides."""
+    global _plans_made
+    with _probe_lock:
+        before, _plans_made = _plans_made, _plans_made + 1
+        probe = _probe_read[0] if _probe_read else None
+    if given:
+        source = "given"
+    elif env.device_headroom_mb() is not None:
+        source = "env"
+    else:
+        source = "probe"
+    return {"headroom_source": source,
+            "headroom_probe": probe if source == "probe" else None,
+            "plans_before": before}
 
 
 # ---------------------------------------------------------------------------
@@ -327,7 +368,9 @@ def plan_overlap(tensors, width: int, override: int | None = None,
             else:
                 planner = AdaptivePlanner()
     manifest = GradientManifest.from_tensors(tensors)
-    plan = planner.plan(manifest, width, probe_headroom_mb())
+    plan = dataclasses.replace(
+        planner.plan(manifest, width, probe_headroom_mb()),
+        **headroom_record())
     _record(plan)
     return plan
 
@@ -336,7 +379,9 @@ def overlap_plan() -> dict | None:
     """The most recent :class:`BucketPlan` as a dict (``hvd.overlap_plan()``),
     or None before any compiled allreduce group has been planned.  Keys:
     planner, chain_depth, chained, width, tensor_count, total_bytes,
-    headroom_mb, chain_extra_bytes, bounds, reason."""
+    headroom_mb, chain_extra_bytes, bounds, reason, and where the headroom
+    came from (:func:`headroom_record`): headroom_source, headroom_probe,
+    plans_before."""
     with _plan_lock:
         return _last_plan.as_dict() if _last_plan is not None else None
 
@@ -393,9 +438,11 @@ def _emit_timeline(plan: BucketPlan) -> None:
 
 def _reset_for_tests() -> None:
     """Drop the cached probe/log state (test isolation only)."""
-    global _last_plan, _last_context_plan
+    global _last_plan, _last_context_plan, _plans_made
     with _probe_lock:
         _probe_cache.clear()
+        _probe_read.clear()
+        _plans_made = 0
     with _plan_lock:
         _last_plan = None
         _logged_keys.clear()
@@ -478,6 +525,13 @@ class ContextPlan:
     est_vmem_kb: int
     est_activation_mb: float
     reason: str
+    # where ``headroom_mb`` came from (:func:`headroom_record`): a record
+    # of circumstance, so plans compare without it
+    headroom_source: str | None = dataclasses.field(default=None,
+                                                    compare=False)
+    headroom_probe: dict | None = dataclasses.field(default=None,
+                                                    compare=False)
+    plans_before: int = dataclasses.field(default=0, compare=False)
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -511,7 +565,8 @@ def plan_context(workload: ContextWorkload, width: int,
             f"seq_len {workload.seq_len} not divisible by context width "
             f"{width}")
     s_local = workload.seq_len // width
-    if headroom_mb is None:
+    given = headroom_mb is not None
+    if not given:
         headroom_mb = probe_headroom_mb()
 
     why = []
@@ -590,7 +645,8 @@ def plan_context(workload: ContextWorkload, width: int,
         planner="context", width=width, seq_local=s_local, layout=layout,
         block_q=bq, block_k=bk, remat=bool(remat), causal=workload.causal,
         headroom_mb=headroom_mb, est_vmem_kb=est_vmem_kb,
-        est_activation_mb=round(act_mb, 3), reason="; ".join(why))
+        est_activation_mb=round(act_mb, 3), reason="; ".join(why),
+        **headroom_record(given))
     _record_context(plan)
     return plan
 
@@ -604,7 +660,8 @@ def context_plan() -> dict | None:
     (``hvd.context_plan()``), or None before any long-context program has
     been planned.  Keys: planner, width, seq_local, layout, block_q,
     block_k, remat, causal, headroom_mb, est_vmem_kb, est_activation_mb,
-    reason."""
+    reason, and headroom_source, headroom_probe, plans_before
+    (:func:`headroom_record`)."""
     with _plan_lock:
         return (_last_context_plan.as_dict()
                 if _last_context_plan is not None else None)

@@ -45,6 +45,7 @@ import optax
 from horovod_tpu import basics
 from horovod_tpu.ops import collective_ops
 from horovod_tpu.ops.compression import Compression
+from horovod_tpu.utils import profiling
 
 
 class DistributedState(NamedTuple):
@@ -136,12 +137,14 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         def update(grads, state, params=None, **extra):
             leaves, treedef = jax.tree.flatten(grads)
             err_leaves = jax.tree.leaves(state.error)
-            reduced, resid = collective_ops.quantized_grouped_allreduce(
-                leaves, err_leaves, average=average,
-                threshold_bytes=threshold_bytes)
+            with jax.named_scope(profiling.ALLREDUCE):
+                reduced, resid = collective_ops.quantized_grouped_allreduce(
+                    leaves, err_leaves, average=average,
+                    threshold_bytes=threshold_bytes)
             grads = jax.tree.unflatten(treedef, reduced)
-            updates, inner = optimizer.update(grads, state.inner, params,
-                                              **extra)
+            with jax.named_scope(profiling.OPTIMIZER):
+                updates, inner = optimizer.update(grads, state.inner, params,
+                                                  **extra)
             return updates, DistributedEFState(
                 inner=inner, error=jax.tree.unflatten(treedef, resid))
 
@@ -152,12 +155,15 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
 
     def update(grads, state, params=None, **extra):
         leaves, treedef = jax.tree.flatten(grads)
-        reduced = collective_ops.grouped_allreduce(
-            leaves, average=average, compression=compression,
-            threshold_bytes=threshold_bytes,
-            overlap_buckets=overlap_buckets, planner=planner)
+        with jax.named_scope(profiling.ALLREDUCE):
+            reduced = collective_ops.grouped_allreduce(
+                leaves, average=average, compression=compression,
+                threshold_bytes=threshold_bytes,
+                overlap_buckets=overlap_buckets, planner=planner)
         grads = jax.tree.unflatten(treedef, reduced)
-        updates, inner = optimizer.update(grads, state.inner, params, **extra)
+        with jax.named_scope(profiling.OPTIMIZER):
+            updates, inner = optimizer.update(grads, state.inner, params,
+                                              **extra)
         return updates, DistributedState(inner=inner)
 
     return optax.GradientTransformation(init, update)

@@ -1,8 +1,9 @@
 """Utility subpackage: env-var knobs and profiling helpers.
 
 Submodules resolve lazily (PEP 562) to keep the package root light —
-``hvd.utils.profiling.trace(...)`` works without anything importing the
-profiling module (and its jax dependency) eagerly.
+``hvd.profiling.trace(...)`` (the one spelling the docs use; the module is
+``horovod_tpu/utils/profiling.py``) works without anything importing the
+profiling module eagerly.
 """
 
 import importlib

@@ -66,12 +66,20 @@ def enable_compile_cache() -> str:
     With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads it and this
     sets nothing.  Otherwise the cache lives at ``.jax_cache/`` in the
     checkout — a fixed path, because the path is part of how a cached
-    program is found again.  Call before the first compile."""
+    program is found again.  Call before the first compile.
+
+    The names a program gives its work (``op_name``: module paths, the
+    ``hvd_*`` scopes of utils/profiling.py) are made part of the key.  JAX
+    leaves them out by default, and a step whose arithmetic an edit did not
+    change would then come back from the cache under the names it had
+    before the edit: ``profiling.scope_table`` would read another version's
+    scopes out of this one's executable."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path
-    import jax
-
     path = os.path.join(_REPO_ROOT, ".jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -1,23 +1,61 @@
-"""Device-side profiling helpers — the XLA half of the timeline story.
+"""Device-side profiling — the XLA half of the timeline story.
 
 The native timeline (core/src/timeline.cc, HOROVOD_TIMELINE) covers the
 coordination plane; device compute/collective timing belongs to the XLA
-profiler (docs/timeline.md).  These wrappers make that one call:
+profiler (docs/timeline.md, "Compiled steps").  This module is the
+compiled path's whole tracing: it opens the profiler session, it holds the
+names the program writes into its compiled steps and onto the host's
+timeline, and it reads those names back out of a compiled program.
 
-    with hvd.utils.profiling.trace("/tmp/jax-trace"):
+    with hvd.profiling.trace("/tmp/jax-trace"):
         for _ in range(10):
             state = train_step(state, batch)
+    table = hvd.profiling.scope_table(train_step.lower(state, batch).compile())
 
 View in XProf / TensorBoard (`tensorboard --logdir /tmp/jax-trace`) or
 Perfetto.  Rank-gated like every reference observability feature (only
 rank 0 traces by default).
+
+There is nothing to switch on.  A scope (``jax.named_scope``) is metadata
+of the traced program: it reaches the ``op_name`` of every instruction made
+under it and costs nothing on the device.  A span (:func:`annotate`) is a
+``TraceAnnotation``: with no profiler session open it is a branch on an
+atomic.  "On" is a profiler session.
 """
 
 from __future__ import annotations
 
 import contextlib
+import dataclasses
+import math
+import re
 
 from horovod_tpu import basics
+
+# -- The vocabulary ---------------------------------------------------------
+# Every name the program writes, once.  Scopes reach the compiled step's
+# instructions (``op_name``), spans the host lines of a profiler trace.
+ALLREDUCE = "hvd_allreduce"     # DistributedOptimizer: the gradient reduction
+OPTIMIZER = "hvd_optimizer"     # DistributedOptimizer: the inner optax update
+BUCKET = "hvd_bucket_"          # + chain position 0.., or BUCKET_ALL
+BUCKET_ALL = "all"              # the free-combining path: one bucket
+FLASH_FWD = "hvd_flash_fwd"     # ops/flash_attention: forward kernel
+FLASH_DQ = "hvd_flash_dq"       # ... backward, dq pass
+FLASH_DKV = "hvd_flash_dkv"     # ... backward, dk/dv pass
+LOSS = "hvd_loss"               # ops/losses.softmax_cross_entropy, both ways
+LOADER_WAIT = "hvd_loader_wait"         # data.BackgroundLoader: q.get()
+LOADER_PRODUCE = "hvd_loader_produce"   # ... next(source), producer thread
+H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
+# (``hvd_chain_gate`` is collective_ops.CHAIN_GATE_SCOPE, older than this
+# table; examples/overlap_audit.py counts it.)
+
+FLASH_PASSES = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
+# jax's own markers on the name stack, which the phase rule reads
+_REMAT = "rematted_computation"     # nn.remat / jax.checkpoint's re-run
+PHASES = ("forward", "backward", "recompute", "optimizer", "collective",
+          "unscoped")
+_CONTRACTIONS = ("convolution", "dot")
+_FORWARD_OWN = "forward contraction"    # marks a fusion's inner set, no phase
 
 
 @contextlib.contextmanager
@@ -39,3 +77,219 @@ def annotate(name: str):
     import jax
 
     return jax.profiler.TraceAnnotation(name)
+
+
+def bucket_scope(k) -> str:
+    """The scope of chain bucket ``k`` (``hvd_bucket_0``...), or of the one
+    free-combining bucket (``BUCKET_ALL``)."""
+    return f"{BUCKET}{k}"
+
+
+# -- Reading the names back -------------------------------------------------
+
+_COLLECTIVE = re.compile(
+    r"^(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)"
+    r"(-start|-done)?$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([^\s=]+) = (.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([^\s(]+) \(.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_BUCKET = re.compile(re.escape(BUCKET) + r"(\w+)")
+_ARRAY = re.compile(r"\b(pred|[a-z]+?(\d+)\w*)\[([\d,]*)\]")
+# path components jax put there: a transform around a name, and no module
+_TRANSFORM = re.compile(r"^(?:jvp|transpose|vmap)\((.*)\)$")
+_NO_MODULE = re.compile(r"^(?:p?jit\(.*\)|shard_map|checkpoint|remat\d*|"
+                        r"while|body|cond|branch_\d+_fun|closed_call|"
+                        + _REMAT + r")$")
+
+
+def phase_of(op_name: str, opcode: str = "") -> str:
+    """The phase of ONE instruction, from its ``op_name`` and opcode.
+
+    In order: a collective opcode is ``collective``; under ``hvd_optimizer``
+    is ``optimizer``; under jax's ``rematted_computation`` (the forward that
+    ``nn.remat`` / ``jax.checkpoint`` re-runs inside backward) is
+    ``recompute``; under ``transpose(`` is ``backward``; under ``jvp(`` is
+    ``forward``; anything else is ``unscoped``."""
+    if _COLLECTIVE.match(opcode):
+        return "collective"
+    if OPTIMIZER in op_name:
+        return "optimizer"
+    if _REMAT in op_name:
+        return "recompute"
+    if "transpose(" in op_name:
+        return "backward"
+    if "jvp(" in op_name:
+        return "forward"
+    return "unscoped"
+
+
+def module_of(op_name: str) -> str:
+    """The module path inside ``op_name`` with indices folded:
+    ``jit(step)/shard_map/transpose(jvp(Transformer))/layer_1/mlp/up/dot_general``
+    is ``Transformer/layer_N/mlp/up``.  A transform leaves the name it
+    wraps; jax's own components (``jit(..)``, ``shard_map``, remat's, a
+    loop's ``while/body``) and the closing primitive are dropped; an
+    instruction outside every module keeps our own scope if it has one
+    (``hvd_optimizer``), else ``""``."""
+    parts = []
+    for part in op_name.split(";")[0].split("/")[:-1]:
+        while (m := _TRANSFORM.match(part)):
+            part = m.group(1)
+        # (remat's backward is transpose(jvp(M))/jvp(M)/..: M once)
+        if part and not _NO_MODULE.match(part) and parts[-1:] != [part]:
+            parts.append(re.sub(r"_\d+$", "_N", part))
+    return "/".join(parts)
+
+
+@dataclasses.dataclass(frozen=True)
+class Scope:
+    """What the compiled program says of one instruction."""
+    opcode: str
+    op_name: str                # the instruction's own; "" if it has none
+    phases: tuple               # one phase; two or more for a mixed fusion
+    module: str                 # module_of(op_name)
+    bucket: str | None = None   # "0".., "all": a collective under hvd_bucket_
+    kernel: str | None = None   # a FLASH_PASSES name: a kernel under it
+    bytes: int = 0              # of the result, from its shape
+
+    @property
+    def phase(self) -> str:
+        """``forward`` ... ``unscoped``, or ``mixed`` (see ``label``)."""
+        return self.phases[0] if len(self.phases) == 1 else "mixed"
+
+    @property
+    def label(self) -> str:
+        """The phase, a mixed fusion's as its pair: ``backward+optimizer``."""
+        return "+".join(self.phases)
+
+
+def scope_table(compiled) -> dict[str, Scope]:
+    """``{instruction name: Scope}`` for every instruction of a compiled
+    program (``jit(f).lower(...).compile()``, or its ``as_text()``), parsed
+    from the optimized HLO text -- the names a device trace's ``XLA Ops``
+    events carry, so a trace joins to the program's own vocabulary by name.
+
+    The rule.  An instruction's phase is :func:`phase_of` its own
+    ``op_name``.  A fusion (any instruction that ``calls=`` a computation)
+    is labelled by the instructions inside it, since XLA fuses across
+    phases: the set of phases of its scoped inner instructions, in
+    ``PHASES`` order.  One phase: the fusion has it, and ``unscoped`` riders
+    inside (compiler-made copies, converts that lost their metadata) take
+    the fusion's label.  Two or more: the fusion is ``mixed`` and keeps the
+    pair (``backward+optimizer``: a weight-gradient matmul with adamw in
+    its epilogue); nobody guesses how its time divides.  None: the
+    fusion's own ``op_name`` decides.  One exception, by data dependence:
+    ``forward`` instructions in a fusion that also holds ``backward`` ones
+    run in the backward pass -- the compiler re-does cheap forward
+    arithmetic (a weight's cast, silu, a norm's rsqrt) where backward
+    consumes it rather than keep the result -- so they add no phase, unless
+    one of them is a contraction (``convolution``, ``dot``), which the
+    compiler never duplicates: then the fusion holds both passes' own work
+    (the head's matmul with the loss and its gradient) and is
+    ``forward+backward``.  A collective is ``collective`` by
+    opcode whatever its scope, and carries its ``hvd_bucket_<k>``; a kernel
+    (custom call) under ``hvd_flash_*`` carries that pass.  ``while`` and
+    ``conditional`` bodies are computations like the entry: their
+    instructions are in the table under their own names.
+    """
+    computations = _computations(
+        compiled if isinstance(compiled, str) else compiled.as_text())
+    inner_memo: dict[str, tuple] = {}
+
+    def inner(computation: str) -> tuple[frozenset, str]:
+        """(phases of a computation's scoped instructions, the first
+        module among them)."""
+        if computation not in inner_memo:
+            inner_memo[computation] = (frozenset(), "")  # a cycle cannot be
+            found, module = set(), ""
+            for _, opcode, op_name, calls, _ in computations.get(
+                    computation, ()):
+                if calls:
+                    deeper, inside = inner(calls)
+                    found |= deeper
+                    module = module or inside
+                elif op_name:
+                    phase = phase_of(op_name, opcode)
+                    found.add(phase)
+                    if phase == "forward" and opcode in _CONTRACTIONS:
+                        found.add(_FORWARD_OWN)
+                    module = module or module_of(op_name)
+            inner_memo[computation] = (frozenset(found - {"unscoped"}),
+                                       module)
+        return inner_memo[computation]
+
+    table: dict[str, Scope] = {}
+    for instructions in computations.values():
+        for name, opcode, op_name, calls, shape in instructions:
+            own = phase_of(op_name, opcode)
+            phases = (own,)
+            module = module_of(op_name)
+            if calls and own != "collective":
+                inside, inner_module = inner(calls)
+                if "backward" in inside and _FORWARD_OWN not in inside:
+                    inside = inside - {"forward"}
+                if inside:
+                    phases = tuple(p for p in PHASES if p in inside)
+                # a fusion whose own name holds no module (its root is the
+                # user's apply_updates, or it has no name) goes under the
+                # first module named inside it
+                module = module or inner_module
+            bucket = _BUCKET.search(op_name) if own == "collective" else None
+            kernel = next((k for k in FLASH_PASSES if k in op_name), None) \
+                if opcode == "custom-call" else None
+            table[name] = Scope(
+                opcode=opcode, op_name=op_name, phases=phases, module=module,
+                bucket=bucket.group(1) if bucket else None, kernel=kernel,
+                bytes=_shape_bytes(shape))
+    return table
+
+
+def _computations(text: str) -> dict[str, list]:
+    """Optimized HLO text as ``{computation: [(instruction name, opcode,
+    op_name, the computation it calls or None, result shape), ...]}``."""
+    computations: dict[str, list] = {}
+    current = None
+    for line in text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                current = computations.setdefault(m.group(1), [])
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        rest = m.group(2)
+        op = _OP_NAME.search(rest)
+        calls = _CALLS.search(rest)
+        shape, opcode = _shape_and_opcode(rest)
+        current.append((m.group(1), opcode, op.group(1) if op else "",
+                        calls.group(1) if calls else None, shape))
+    return computations
+
+
+def _shape_and_opcode(rest: str) -> tuple[str, str]:
+    """An instruction's text after ``%name = `` split into its result
+    shape, which for a tuple is a parenthesised list, and its opcode."""
+    if rest.startswith("("):
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        shape, rest = rest[:i + 1], rest[i + 1:].lstrip()
+    else:
+        shape, _, rest = rest.partition(" ")
+    return shape, re.match(r"[\w\-]*", rest).group(0)
+
+
+def _shape_bytes(shape: str) -> int:
+    """Bytes of every array in a shape's text (``f32[2048,32256]{1,0}``;
+    a tuple's arrays summed; ``pred`` and sub-byte types count a byte)."""
+    return sum(
+        math.prod(int(d) for d in m.group(3).split(",") if d)
+        * max(int(m.group(2) or 8) // 8, 1)
+        for m in _ARRAY.finditer(shape))

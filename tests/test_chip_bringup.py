@@ -16,6 +16,9 @@ from horovod_tpu.utils import chip
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+NAMES_IN_KEY = ("jax_compilation_cache_include_metadata_in_key", True)
+
+
 @pytest.fixture()
 def cache_dir_updates(monkeypatch):
     """Record what the helper would set instead of setting it: the suite
@@ -31,7 +34,8 @@ def cache_dir_updates(monkeypatch):
 def test_compile_cache_honours_env(monkeypatch, cache_dir_updates, tmp_path):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert chip.enable_compile_cache() == str(tmp_path)
-    assert cache_dir_updates == []  # JAX reads the variable; nothing set
+    # JAX reads the variable; no directory is set, only what the key holds
+    assert cache_dir_updates == [NAMES_IN_KEY]
 
 
 def test_compile_cache_default_is_one_fixed_path(monkeypatch,
@@ -39,7 +43,39 @@ def test_compile_cache_default_is_one_fixed_path(monkeypatch,
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     first, second = chip.enable_compile_cache(), chip.enable_compile_cache()
     assert first == second == os.path.join(REPO, ".jax_cache")
-    assert cache_dir_updates == [("jax_compilation_cache_dir", first)] * 2
+    assert cache_dir_updates == [
+        NAMES_IN_KEY, ("jax_compilation_cache_dir", first)] * 2
+
+
+NAMED = """
+import re, sys, jax, jax.numpy as jnp
+from horovod_tpu.utils import chip
+chip.enable_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+def f(x):
+    with jax.named_scope(sys.argv[1]):
+        return jnp.sin(x) * 2
+text = jax.jit(f).lower(jnp.ones((8, 128))).compile().as_text()
+print(sorted(set(re.findall(r'op_name="jit.f./(\\w+)/', text))))
+"""
+
+
+def test_a_cached_program_comes_back_under_its_own_names(tmp_path):
+    """Two programs of one arithmetic and two scopes, one cache: each
+    compiled text carries its own scope (utils/profiling.scope_table reads
+    it), not the one that was cached first."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    seen = [subprocess.run([sys.executable, "-c", NAMED, scope], env=env,
+                           capture_output=True, text=True, timeout=300)
+            for scope in ("hvd_before", "hvd_after", "hvd_before")]
+    assert [p.stdout.strip() for p in seen] == [
+        "['hvd_before']", "['hvd_after']", "['hvd_before']"], seen[1].stderr
+    assert len(os.listdir(tmp_path)) >= 2       # and both were cached
 
 
 def test_peak_table_raises_for_unknown_device_kind():
