@@ -378,8 +378,11 @@ def _chained_allreduce(vals: list, axes, n_buckets: int,
     inf/NaN gradients (no cross-bucket poisoning), yet data-dependent and
     fold-proof (the compiler cannot prove the select's output finite —
     plain ``s * 0`` would also work but ``optimization_barrier`` does NOT:
-    the TPU pipeline strips it before the combiner runs).  Non-float
-    leaves pass through ungated (the combiner may merge those; harmless).
+    the TPU pipeline has removed it by the time the all-reduce combiner
+    runs.  It is still there when instruction fusion runs, which is all
+    the width-1 plan asks of it below: deviceless v5e compiles, PR 25).
+    Non-float leaves pass through ungated (the combiner may merge those;
+    harmless).
 
     Memory trade: pulling the reductions into backward extends gradient
     live ranges, raising peak HBM by up to a few hundred MB on large
@@ -475,7 +478,12 @@ def grouped_allreduce(tensors: Sequence, average: bool = True,
     ``HOROVOD_OVERLAP_BUCKETS`` pins the legacy static semantics (0 =
     free-combining, N = N chained buckets — see ``_chained_allreduce``),
     and ``planner=`` (a schedule_plan.Planner) replaces the policy
-    outright.  The decision is observable via ``hvd.overlap_plan()``.
+    outright.  At width 1 the plan also lists the gradients to materialise
+    before they are used (``BucketPlan.materialized``); each of those comes
+    back behind its own ``optimization_barrier``, the identity on values,
+    which keeps XLA from fusing its consumer (the optimizer's update) into
+    the matmul that produces it.  The decision is observable via
+    ``hvd.overlap_plan()``.
     ``threshold_bytes`` is ignored on this path (docs/tensor-fusion.md).
     Hierarchical (multi-axis) meshes, the eager path, and the int8 path
     in any context: flat ``threshold_bytes``-bounded buckets
@@ -489,6 +497,7 @@ def grouped_allreduce(tensors: Sequence, average: bool = True,
         return reduced
     axes = _in_mesh_axes()
     comp = [compression.compress(t) for t in tensors]
+    held: tuple = ()    # gradients the plan materialises before the update
     if axes is not None:
         denom = _data_width(axes)
         if len(axes) == 1:
@@ -515,6 +524,7 @@ def grouped_allreduce(tensors: Sequence, average: bool = True,
                 with jax.named_scope(
                         profiling.bucket_scope(profiling.BUCKET_ALL)):
                     reduced = [_mesh_allreduce(c, axes) for c, _ in comp]
+            held = plan.materialized
         else:
             # Hierarchical (e.g. (dcn, ici)) route: each tensor lowers to
             # a psum_scatter→psum→all_gather CHAIN (parallel/hierarchy.py)
@@ -536,7 +546,10 @@ def grouped_allreduce(tensors: Sequence, average: bool = True,
             [c for c, _ in comp], _eager_process_reduce, threshold_bytes)
     if average:
         reduced = [r / denom for r in reduced]
-    return [compression.decompress(r, ctx) for r, (_, ctx) in zip(reduced, comp)]
+    out = [compression.decompress(r, ctx) for r, (_, ctx) in zip(reduced, comp)]
+    for i in held:
+        out[i] = lax.optimization_barrier(out[i])
+    return out
 
 
 def _eager_quantized_reduce(tensors, errors, average: bool):

@@ -24,10 +24,24 @@ concrete Python int under trace), and a device-memory headroom estimate
 free-combining bypass — and ``grouped_allreduce`` executes whatever the
 plan says.
 
+Width 1 is not "nothing to decide".  With no collective between a gradient
+and its update, XLA:TPU fuses the optimizer's arithmetic into the matmul
+that produces the gradient, and such a fusion costs more than the matmul
+and the update one after the other (PERF.md, PR 25: 25 ms of a 336 ms
+decoder step; the four-chip program, where the all-reduce stands between
+them, never had it).  So at width 1 the plan also names the gradients to
+**materialise before the update** (``BucketPlan.materialized``):
+``grouped_allreduce`` holds each behind its own
+``jax.lax.optimization_barrier`` -- per leaf, never the tree as one tuple,
+so no gradient lives longer than it does across chips -- and fusion cannot
+cross it.  :func:`materialized_leaves` is the rule; it reads the manifest
+alone.
+
 Two planners ship:
 
 * :class:`AdaptivePlanner` (the default when no override is present):
-  bypasses the chain at width 1, estimates the chain's extra live-range
+  bypasses the chain at width 1 and materialises gradients there,
+  estimates the chain's extra live-range
   bytes and degrades the depth (halving, down to bypass) when the estimate
   exceeds headroom, and keeps the round-5 depth-4 chain on configs with
   real width and slack headroom.
@@ -128,6 +142,12 @@ class BucketPlan:
     chain_extra_bytes: int
     reason: str
     bounds: tuple[int, ...] | None = None
+    # Width 1 only: indices (tensor order) of the gradients held behind a
+    # per-leaf ``optimization_barrier`` before the update, and their bytes
+    # (:func:`materialized_leaves`); empty wherever a collective already
+    # stands between a gradient and its update.
+    materialized: tuple[int, ...] = ()
+    materialized_bytes: int = 0
     # Where ``headroom_mb`` came from (:func:`headroom_record`): a record
     # of circumstance and no part of the decision, so plans compare without
     # it; filled by :func:`plan_overlap`, not by a planner.
@@ -141,10 +161,52 @@ class BucketPlan:
     def chained(self) -> bool:
         return self.chain_depth > 1 and self.tensor_count > 1
 
+    def holding(self, manifest: GradientManifest, held) -> "BucketPlan":
+        """This plan with the gradients ``held`` (indices into
+        ``manifest``) materialised before the update."""
+        held = tuple(held)
+        return dataclasses.replace(
+            self, materialized=held,
+            materialized_bytes=sum(manifest.nbytes[i] for i in held))
+
     def as_dict(self) -> dict:
         d = dataclasses.asdict(self)
         d["chained"] = self.chained
+        # the count says what the indices would, in one number a line
+        d["materialized_leaves"] = len(d.pop("materialized"))
         return d
+
+
+# Width 1: the smallest gradient worth materialising before its update.
+# Fused into the matmul that produces the gradient, an update saves one
+# write and one read of it (8 B a parameter) and is the cheaper form for as
+# long as XLA:TPU keeps the update's state (parameter, moments) in on-chip
+# memory across the fusion; past a leaf size it streams them from HBM inside
+# the matmul's epilogue instead, which costs more than the matmul and a
+# standalone update at the HBM roofline.  So:
+#
+#     gain(leaf) = epilogue_penalty(leaf) - 2 * leaf_bytes / HBM_bandwidth
+#
+# and the constant is where the measured gain changes sign on a TPU v5e (my
+# chip runs, PR 25; PERF.md section 6).  A dense toy step (16 384 tokens,
+# leaves of 0.5-64 MiB, adamw and sgd+momentum) loses 7-49 us a leaf by
+# materialising at 8 and 12 MiB and gains 25-89 us a leaf at 16-32 MiB
+# under both (at 64 MiB +326 under adamw, -44 under sgd: one reading each);
+# its compiled text shows why (all three adamw outputs of a fused 8 MiB
+# leaf live in fast memory, two of three at 12-16 MiB, one at 24).  The two
+# real programs agree: every ResNet-50 leaf (at most 9.4 MB) costs 3-87 us
+# more materialised (-0.9% img/s with all 161 held, -0.3% with the 29 of
+# 1 MiB or more), and the 1.3B-width decoder's matrices (16 MiB-264 MB) gain
+# 0.14-3.0 ms each, 25 ms of a 336 ms step.
+MATERIALIZE_MIN_BYTES = 16 * 1024 * 1024
+
+
+def materialized_leaves(manifest: GradientManifest) -> tuple[int, ...]:
+    """The width-1 rule: indices of the gradients to hold behind a barrier
+    before the update -- every leaf of :data:`MATERIALIZE_MIN_BYTES` or
+    more.  Reads the manifest alone."""
+    return tuple(i for i, n in enumerate(manifest.nbytes)
+                 if n >= MATERIALIZE_MIN_BYTES)
 
 
 def chain_extra_bytes(total_bytes: int, depth: int) -> int:
@@ -226,8 +288,14 @@ class AdaptivePlanner(Planner):
                 reason=reason)
 
         if width <= 1:
-            return mk(0, "width-1 bypass: psum is identity, nothing to "
-                         "overlap — free-combining structure")
+            held = materialized_leaves(manifest)
+            return mk(0, f"width-1 bypass: psum is identity, nothing to "
+                         f"overlap — free-combining structure; {len(held)} "
+                         f"of {manifest.count} gradients are "
+                         f"{MATERIALIZE_MIN_BYTES} B or more, where an "
+                         f"update fused into the matmul costs more than "
+                         f"one apart: materialised before the update"
+                      ).holding(manifest, held)
         if manifest.count <= 1:
             return mk(0, "single gradient tensor: nothing to chain")
         depth = self.default_depth
@@ -379,8 +447,10 @@ def overlap_plan() -> dict | None:
     """The most recent :class:`BucketPlan` as a dict (``hvd.overlap_plan()``),
     or None before any compiled allreduce group has been planned.  Keys:
     planner, chain_depth, chained, width, tensor_count, total_bytes,
-    headroom_mb, chain_extra_bytes, bounds, reason, and where the headroom
-    came from (:func:`headroom_record`): headroom_source, headroom_probe,
+    headroom_mb, chain_extra_bytes, bounds, reason, materialized_leaves and
+    materialized_bytes (the gradients held behind a barrier before the
+    update at width 1), and where the headroom came from
+    (:func:`headroom_record`): headroom_source, headroom_probe,
     plans_before."""
     with _plan_lock:
         return _last_plan.as_dict() if _last_plan is not None else None
@@ -389,7 +459,8 @@ def overlap_plan() -> dict | None:
 def _record(plan: BucketPlan) -> None:
     global _last_plan
     key = (plan.planner, plan.chain_depth, plan.width, plan.tensor_count,
-           plan.total_bytes, plan.headroom_mb, plan.bounds)
+           plan.total_bytes, plan.headroom_mb, plan.bounds,
+           plan.materialized)
     with _plan_lock:
         _last_plan = plan
         fresh = key not in _logged_keys

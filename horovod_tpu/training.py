@@ -98,7 +98,11 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     headroom pick a chain depth — chaining the bucket psums so the
     backend schedules early buckets' all-reduces during backward, and
     bypassing the chain where it cannot help (width 1) or cannot fit
-    (headroom deficit).  ``overlap_buckets`` (or a set
+    (headroom deficit).  At width 1 the same plan names the large
+    gradients to materialise before the inner update (each behind its own
+    ``optimization_barrier``, values unchanged), so XLA does not fuse the
+    update into the epilogue of the matmul that produces the gradient
+    (docs/tensor-fusion.md).  ``overlap_buckets`` (or a set
     ``HOROVOD_OVERLAP_BUCKETS``; 0 disables, N pins N buckets) overrides
     the planner with the legacy static semantics; ``planner=`` (a
     ``schedule_plan.Planner``) replaces the policy — the extension point

@@ -96,6 +96,103 @@ def test_single_tensor_never_chains():
 
 
 # ---------------------------------------------------------------------------
+# Width 1: which gradients are materialised before the update
+# ---------------------------------------------------------------------------
+
+def decoder_manifest(layers=6, hidden=2048, mlp=5504, vocab=32256):
+    """The benchmark's decoder in tree order: f32 matrices of 16.8-264 MB
+    and norm scales of 8 KB (benchmarks/configs/deepseek-coder-1.3b.json)."""
+    mat, scale = lambda a, b: a * b * 4, hidden * 4
+    layer = [scale, mat(hidden, hidden), mat(hidden, hidden),
+             mat(hidden, hidden), mat(hidden, hidden), scale,
+             mat(hidden, mlp), mat(hidden, mlp), mat(mlp, hidden)]
+    nbytes = [mat(vocab, hidden), scale] + layer * layers \
+        + [mat(hidden, vocab)]
+    return sp.GradientManifest(nbytes=tuple(nbytes),
+                               dtypes=("float32",) * len(nbytes))
+
+
+# ResNet-50 v1.5's 161 f32 leaves as {bytes: how many}
+# (models/resnet.ResNet50, 1000 classes): 102.2 MB, none over 9.5 MB.
+RESNET50_LEAVES = {
+    256: 14, 512: 16, 1024: 32, 2048: 22, 4000: 1, 4096: 14, 8192: 8,
+    16384: 1, 37632: 1, 65536: 6, 131072: 1, 147456: 3, 262144: 7,
+    524288: 2, 589824: 4, 1048576: 11, 2097152: 2, 2359296: 6, 4194304: 5,
+    8192000: 1, 8388608: 1, 9437184: 3}
+
+
+def resnet_manifest():
+    nbytes = [n for n, k in RESNET50_LEAVES.items() for _ in range(k)]
+    assert len(nbytes) == 161 and sum(nbytes) == 102228128
+    return sp.GradientManifest(nbytes=tuple(nbytes),
+                               dtypes=("float32",) * len(nbytes))
+
+
+def test_width1_materialises_the_decoders_matrices_not_its_norm_scales():
+    m = decoder_manifest()
+    plan = sp.AdaptivePlanner().plan(m, width=1, headroom_mb=None)
+    matrices = tuple(i for i, n in enumerate(m.nbytes) if n > 8192)
+    assert len(matrices) == 44 and m.count == 57
+    assert plan.materialized == matrices
+    assert plan.materialized_bytes == sum(m.nbytes[i] for i in matrices)
+    assert plan.chain_depth == 0 and not plan.chained
+    assert "width-1" in plan.reason and "44 of 57" in plan.reason
+
+
+def test_width1_materialises_no_resnet_leaf():
+    # the measured rule (PERF.md, PR 25): below MATERIALIZE_MIN_BYTES the
+    # fused update is the cheaper form -- every ResNet-50 leaf read slower
+    # materialised on the chip -- so the plan is the bare bypass, as before
+    m = resnet_manifest()
+    assert max(m.nbytes) < sp.MATERIALIZE_MIN_BYTES
+    plan = sp.AdaptivePlanner().plan(m, width=1, headroom_mb=None)
+    assert plan.materialized == () and plan.materialized_bytes == 0
+    assert plan.chain_depth == 0 and "width-1" in plan.reason
+    assert "0 of 161" in plan.reason
+
+
+def test_width1_floor_is_inclusive_and_reads_bytes_alone():
+    floor = sp.MATERIALIZE_MIN_BYTES
+    m = sp.GradientManifest(nbytes=(floor - 1, floor, floor + 1, 0),
+                            dtypes=("float32", "bfloat16", "float32",
+                                    "int32"))
+    assert sp.materialized_leaves(m) == (1, 2)
+
+
+def test_width1_plan_carries_the_counts_in_its_record():
+    m = decoder_manifest()
+    d = sp.AdaptivePlanner().plan(m, width=1, headroom_mb=None).as_dict()
+    assert d["materialized_leaves"] == 44
+    assert d["materialized_bytes"] == m.total_bytes - 13 * 8192
+    assert "materialized" not in d      # the count, not 44 indices a line
+
+
+def test_real_width_materialises_nothing():
+    # the all-reduce already stands between a gradient and its update
+    for m in (decoder_manifest(), resnet_manifest()):
+        for headroom in (None, 8000.0, 10.0):
+            plan = sp.AdaptivePlanner().plan(m, width=4,
+                                             headroom_mb=headroom)
+            assert plan.materialized == () and plan.materialized_bytes == 0
+            assert plan.as_dict()["materialized_leaves"] == 0
+
+
+def test_static_planner_materialises_nothing():
+    # an explicit overlap_buckets= keeps its legacy semantics bit for bit
+    plan = sp.StaticPlanner(4).plan(decoder_manifest(), width=1,
+                                    headroom_mb=None)
+    assert plan.materialized == ()
+
+
+def test_materialised_set_is_deterministic():
+    for m in (decoder_manifest(), resnet_manifest()):
+        plans = [sp.AdaptivePlanner().plan(m, width=1, headroom_mb=h)
+                 for h in (None, 1e9, 0.0)]
+        assert len({p.materialized for p in plans}) == 1
+        assert sp.materialized_leaves(m) == plans[0].materialized
+
+
+# ---------------------------------------------------------------------------
 # Overrides beat the adaptive plan
 # ---------------------------------------------------------------------------
 

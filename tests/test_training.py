@@ -335,3 +335,77 @@ def test_accumulate_composes_with_master_weights_and_int8_ef(hvd):
     # And training continues to make progress from the accumulated state.
     _, _, l_next = make_step(2)(p_acc, s_acc, x)
     assert float(l_next) < float(l_acc)
+
+
+def _held_planner(which):
+    """An adaptive planner whose plans hold ``"all"`` gradients, ``"none"``,
+    or (None) what the planner's own rule says."""
+    from horovod_tpu.ops import schedule_plan as sp
+
+    class Planner(sp.AdaptivePlanner):
+        def plan(self, manifest, width, headroom_mb):
+            plan = super().plan(manifest, width, headroom_mb)
+            if which is None:
+                return plan
+            return plan.holding(
+                manifest, range(manifest.count) if which == "all" else ())
+
+    return Planner()
+
+
+@pytest.mark.parametrize("make_inner", [
+    lambda: optax.adamw(1e-2),
+    lambda: optax.sgd(0.1, momentum=0.9)], ids=["adamw", "sgd_momentum"])
+def test_width1_materialised_gradients_move_no_bit(hvd, make_inner):
+    """At width 1 the plan holds gradients behind a per-leaf
+    ``optimization_barrier`` between ``hvd_allreduce`` and
+    ``hvd_optimizer``.  The barrier is the identity on values: two steps
+    with every leaf held, with none held, and under the planner's own rule
+    give the same parameters and optimizer state to the bit, and the
+    lowered step holds exactly as many barriers as the plan records."""
+    from jax.sharding import Mesh
+
+    from horovod_tpu.ops import schedule_plan as sp
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("hvd",))
+    # one leaf at the planner's floor, one under it, and a vector
+    big = sp.MATERIALIZE_MIN_BYTES // 4 // 64
+    key = jax.random.PRNGKey(0)
+    params = {"big": jax.random.normal(key, (64, big)) * 0.1,
+              "small": jax.random.normal(key, (big, 8)) * 0.1,
+              "scale": jnp.ones((8,))}
+    x = jax.random.normal(jax.random.PRNGKey(1), (16, 64))
+
+    def run(which):
+        opt = hvd.DistributedOptimizer(make_inner(),
+                                       planner=_held_planner(which))
+
+        def step(params, state, x):
+            def loss(p):
+                return jnp.mean(((x @ p["big"]) @ p["small"] * p["scale"])
+                                ** 2)
+            updates, state = opt.update(jax.grad(loss)(params), state,
+                                        params)
+            return optax.apply_updates(params, updates), state
+
+        fn = jax.jit(jax.shard_map(
+            step, mesh=mesh, in_specs=(P(), P(), P("hvd")),
+            out_specs=(P(), P()), check_vma=False))
+        state = opt.init(params)
+        barriers = fn.lower(params, state, x).as_text().count(
+            "optimization_barrier")
+        plan = hvd.overlap_plan()
+        assert plan["width"] == 1 and not plan["chained"], plan
+        assert barriers == plan["materialized_leaves"], (barriers, plan)
+        p = params
+        for _ in range(2):
+            p, state = fn(p, state, x)
+        return barriers, jax.tree.leaves((p, state))
+
+    n_none, ref = run("none")
+    n_all, held = run("all")
+    n_rule, ruled = run(None)
+    assert (n_none, n_all, n_rule) == (0, 3, 1)
+    for got in (held, ruled):
+        for a, b in zip(ref, got):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
