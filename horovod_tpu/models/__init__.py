@@ -17,7 +17,12 @@ from horovod_tpu.models.resnet import (  # noqa: F401
 from horovod_tpu.models.vgg import VGG, VGG16, VGG19  # noqa: F401
 from horovod_tpu.models.inception import InceptionV3  # noqa: F401
 from horovod_tpu.models.mnist import MnistCNN, MnistMLP  # noqa: F401
-from horovod_tpu.models.moe import MoEMLP  # noqa: F401
+from horovod_tpu.models.moe import (  # noqa: F401
+    MOE_LOSSES,
+    MOE_STATS,
+    MoEMLP,
+    moe_aux_loss,
+)
 from horovod_tpu.models.transformer import (  # noqa: F401
     Transformer,
     TransformerConfig,

@@ -1,46 +1,136 @@
-"""MoE transformer blocks — the model-level surface of expert parallelism.
+"""MoE transformer blocks — the sparse feed-forward of ``models/transformer.py``.
 
 Beyond reference scope (the reference has no attention or MoE code; SURVEY
 §2.9 lists EP as absent).  ``MoEMLP`` is a drop-in for the Transformer's
-dense GLU MLP: a router picks one expert per token (switch routing), tokens
-travel to the device holding their expert over ``lax.all_to_all``
-(parallel/expert.py), and the residual connection carries dropped
-(over-capacity) tokens unchanged.
+dense GLU MLP, in one of two layouts:
 
-Must run inside shard_map with the ``ep`` axis bound; each device holds ONE
-expert's weights (distinct via per-shard RNG folding — the same contract as
-tensor_parallel / pipeline stages).  Total parameter count is
-``n_experts ×`` the dense MLP while per-token FLOPs stay constant — the MoE
-scaling trade.
+* **every expert here** (``num_experts`` > 0, ``axis_name=None``;
+  ``TransformerConfig.num_experts``): the experts are three stacked leaves
+  ``[E, D, F]``, ``[E, D, F]``, ``[E, F, D]`` beside a router ``[D, E]``.
+  A token goes to its ``experts_per_token`` most probable experts and
+  nothing is dropped: the (token, expert) pairs are sorted by expert, the
+  tokens gathered into that order, the three matmuls run as grouped matmuls
+  (``lax.ragged_dot``) with the per-expert counts as group sizes, and the
+  results return to token order and are summed with their gate weights.
+  Shapes are static (``tokens × experts_per_token`` rows) and a token's
+  result does not depend on what else is in the batch.  The layer sows the
+  two auxiliary losses a sparse model is trained with and its per-expert
+  load (``MOE_LOSSES``, ``MOE_STATS``; docs/parallelism.md).  Data-parallel
+  like any other layer: every chip holds all experts.
+* **one expert per device** (``num_experts`` = 0; ``axis_name`` a bound mesh
+  axis, ``TransformerConfig.moe_axis``): a router picks one expert per token
+  (switch routing), tokens travel to the device holding their expert over
+  ``lax.all_to_all`` (parallel/expert.py, a dense ``[T, E, C]`` one-hot
+  dispatch), and the residual connection carries dropped (over-capacity)
+  tokens unchanged.  Must run inside shard_map with the axis bound; each
+  device holds ONE expert's weights (distinct via per-shard RNG folding —
+  the same contract as tensor_parallel / pipeline stages).
+
+Either way the parameter count is the number of experts times one expert's
+while per-token FLOPs follow the experts a token visits — the MoE scaling
+trade.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+from flax.traverse_util import flatten_dict
 from jax import lax
 
 from horovod_tpu.parallel.common import shard_init_rng
 from horovod_tpu.parallel.expert import expert_parallel_moe
+from horovod_tpu.utils import profiling
+
+# Collections the every-expert-here layout sows into, per layer, when the
+# caller makes them mutable (``model.apply(..., mutable=[MOE_LOSSES])``):
+MOE_LOSSES = "moe_losses"   # "load_balance", "router_z": f32 scalars
+MOE_STATS = "moe_stats"     # "expert_pairs": [E] int32, pairs per expert
+                            # (profiling.expert_load reads it); "picks":
+                            # [B, S, k] int32, each token's experts
+
+
+def moe_aux_loss(cfg, collections) -> jax.Array:
+    """The auxiliary loss a sparse ``Transformer`` is trained with, from the
+    collections ``model.apply(..., mutable=[MOE_LOSSES])`` returned: the
+    layers' mean load-balancing loss times ``cfg.moe_load_balance_coef``
+    plus their mean router z-loss times ``cfg.moe_router_z_coef``.  A user's
+    loss adds it to the cross-entropy."""
+    sown = flatten_dict(collections.get(MOE_LOSSES, {}))  # leaves: tuples
+    if not sown:
+        return jnp.zeros((), jnp.float32)
+
+    def mean(name):
+        values = [v for path, vs in sown.items() if path[-1] == name
+                  for v in vs]
+        return sum(values) / len(values)
+
+    return (cfg.moe_load_balance_coef * mean("load_balance")
+            + cfg.moe_router_z_coef * mean("router_z"))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(tokens, order, inverse, k):
+    """Rows of ``tokens`` [T, D] in pair order: row ``i`` is the token of
+    pair ``order[i]`` (pair ``p`` is token ``p // k``).  A gather both ways:
+    backward brings the cotangent back to token order through ``inverse``
+    (``order``'s inverse permutation) and sums a token's ``k`` copies, where
+    the gather's own transpose would be a scatter-add of T·k rows."""
+    return tokens[order // k]
+
+
+def _dispatch_fwd(tokens, order, inverse, k):
+    return tokens[order // k], inverse
+
+
+def _dispatch_bwd(k, inverse, g):
+    back = g[inverse].reshape(-1, k, g.shape[-1])
+    return back.sum(axis=1, dtype=jnp.float32).astype(g.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _permute(x, perm, inverse):
+    """``x[perm]`` for a permutation whose inverse is at hand: backward is
+    the gather ``g[inverse]``, not a scatter."""
+    return x[perm]
+
+
+_permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
+                lambda inverse, g: (g[inverse], None, None))
 
 
 class MoEMLP(nn.Module):
-    """Switch-MoE feed-forward: [B, S, E] → [B, S, E].
+    """Sparse GLU feed-forward: [B, S, D] → [B, S, D].
 
-    One expert (GLU MLP) per device on ``axis_name``; ``capacity_factor``
-    bounds each expert's per-call token budget.
+    ``num_experts`` > 0 holds every expert here and routes each token to
+    ``experts_per_token`` of them, dropless (``axis_name`` must be None).
+    ``num_experts`` = 0 is the switch layout: one expert per device on
+    ``axis_name``, ``capacity_factor`` bounding each expert's per-call
+    token budget.
     """
 
     embed_dim: int
     mlp_dim: int
-    axis_name: str = "ep"
+    axis_name: str | None = "ep"
     capacity_factor: float = 2.0
     dtype: Any = jnp.bfloat16
+    num_experts: int = 0
+    experts_per_token: int = 1
+    # divide a token's gate weights by their sum (OLMoE does not)
+    norm_topk_prob: bool = False
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, x):
+        if self.num_experts > 0:
+            return _all_experts_here(self, x)
         n_experts = lax.axis_size(self.axis_name)
         b, s, d = x.shape
         if d != self.embed_dim:
@@ -71,4 +161,72 @@ class MoEMLP(nn.Module):
         out = expert_parallel_moe(
             expert_fn, (w_gate, w_up, w_down), router_w, tokens,
             capacity_factor=self.capacity_factor, axis_name=self.axis_name)
+        return out.reshape(b, s, d).astype(x.dtype)
+
+
+def _all_experts_here(m: MoEMLP, x):
+    """``MoEMLP.__call__`` for ``num_experts`` > 0 (a function, so that flax
+    adds no method's name to the module path of what it traces)."""
+    if m.axis_name is not None:
+        raise ValueError(
+            "MoEMLP(num_experts > 0) holds every expert on each device; "
+            "sharding them over a mesh axis is the switch layout's "
+            "(num_experts=0) and not combined with it yet")
+    e, k, f = m.num_experts, m.experts_per_token, m.mlp_dim
+    b, s, d = x.shape
+    if d != m.embed_dim:
+        raise ValueError(
+            f"MoEMLP(embed_dim={m.embed_dim}) got feature dim {d}")
+    if not 0 < k <= e:
+        raise ValueError(f"experts_per_token={k} of num_experts={e}")
+    lecun = nn.initializers.lecun_normal
+    router_w = m.param("router", lecun(), (d, e), m.param_dtype)
+    # fan-in is axis 1 of [E, in, out]; the experts are a batch
+    stacked = lecun(in_axis=1, out_axis=2, batch_axis=0)
+    w_gate = m.param("gate", stacked, (e, d, f), m.param_dtype)
+    w_up = m.param("up", stacked, (e, d, f), m.param_dtype)
+    w_down = m.param("down", stacked, (e, f, d), m.param_dtype)
+    t = b * s
+
+    # Everything the layer does is under one of four scopes
+    # (utils/profiling.py), so a trace's time in them is the layer's.
+    with jax.named_scope(profiling.MOE_ROUTE):
+        tokens = x.reshape(t, d).astype(m.dtype)
+        # in float32 whatever the compute dtype: a pick is a comparison
+        logits = jnp.dot(tokens.astype(jnp.float32),
+                         router_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)               # [T, E]
+        _, picks = lax.top_k(lax.stop_gradient(probs), k)     # [T, k]
+        gates = jnp.take_along_axis(probs, picks, axis=-1)
+        if m.norm_topk_prob:
+            gates = gates / gates.sum(axis=-1, keepdims=True)
+        pair_expert = picks.reshape(t * k)      # pair p: token p // k
+        order = jnp.argsort(pair_expert, stable=True)
+        inverse = jnp.argsort(order)
+        pairs = (picks[..., None] == jnp.arange(e)).sum(
+            axis=(0, 1), dtype=jnp.int32)                     # [E]
+        if not m.is_initializing():  # init returns parameters only
+            m.sow(MOE_STATS, "expert_pairs", pairs)
+            m.sow(MOE_STATS, "picks", picks.reshape(b, s, k))
+            # E * sum_e f_e P_e: f_e the share of the pairs on expert e
+            # (a constant to the gradient), P_e e's mean probability
+            m.sow(MOE_LOSSES, "load_balance", e * jnp.sum(
+                pairs.astype(jnp.float32) / (t * k)
+                * probs.mean(axis=0)))
+            m.sow(MOE_LOSSES, "router_z", jnp.mean(jnp.square(
+                jax.nn.logsumexp(logits, axis=-1))))
+
+    with jax.named_scope(profiling.MOE_DISPATCH):
+        rows = _dispatch(tokens, order, inverse, k)           # [T*k, D]
+
+    with jax.named_scope(profiling.MOE_EXPERTS):
+        grouped = functools.partial(lax.ragged_dot, group_sizes=pairs)
+        hidden = (nn.silu(grouped(rows, w_gate.astype(m.dtype)))
+                  * grouped(rows, w_up.astype(m.dtype)))
+        out_rows = grouped(hidden, w_down.astype(m.dtype))  # [T*k, D]
+
+    with jax.named_scope(profiling.MOE_COMBINE):
+        by_token = _permute(out_rows, inverse, order).reshape(t, k, d)
+        out = (by_token.astype(jnp.float32) * gates[..., None]).sum(1)
         return out.reshape(b, s, d).astype(x.dtype)

@@ -30,6 +30,7 @@ class TransformerConfig:
     num_heads: int = 8
     head_dim: int = 64
     embed_dim: int = 512
+    # width of the dense GLU MLP; of ONE expert when num_experts > 0
     mlp_dim: int = 2048
     max_seq_len: int = 2048
     dtype: Any = jnp.bfloat16
@@ -41,13 +42,29 @@ class TransformerConfig:
     # causal attention.  parallel/ring_attention.py provides a drop-in for
     # sequence-sharded q/k/v.
     attention_fn: Callable | None = None
-    # Offset added to query positions — under sequence parallelism each shard
-    # passes shard_index * shard_len so RoPE and the causal mask see global
-    # positions.
+    # Base of the rotary embedding's frequencies.
     rope_theta: float = 10000.0
-    # Switch-MoE feed-forward: set to a bound mesh axis name (e.g. "ep") to
-    # replace the dense MLP with one expert per device on that axis
-    # (models/moe.py).  Requires calling inside shard_map.
+    # RMSNorm's epsilon, every norm of the model.
+    norm_eps: float = 1e-6
+    # RMSNorm (with a scale) on the whole q and k projections, before the
+    # split into heads and the rotary embedding (OLMoE's q_norm / k_norm).
+    qk_norm: bool = False
+    # Sparse feed-forward with every expert on each device (models/moe.py):
+    # num_experts GLU experts of width mlp_dim replace the dense MLP (0 = the
+    # dense MLP), a token visits its experts_per_token most probable ones,
+    # nothing is dropped.  norm_topk_prob divides a token's gate weights by
+    # their sum.  The layer sows a load-balancing loss and a router z-loss;
+    # models.moe_aux_loss(cfg, collections) weights them with the two
+    # coefficients for the user's loss to add (docs/parallelism.md).
+    num_experts: int = 0
+    experts_per_token: int = 1
+    norm_topk_prob: bool = False
+    moe_load_balance_coef: float = 0.01
+    moe_router_z_coef: float = 0.001
+    # Switch-MoE feed-forward, the other layout: set to a bound mesh axis
+    # name (e.g. "ep") to replace the dense MLP with one expert per device
+    # on that axis (top-1, capacity-bounded).  Requires calling inside
+    # shard_map; not combined with num_experts.
     moe_axis: str | None = None
     moe_capacity_factor: float = 2.0
     # dtype of the returned logits.  The [B, S, vocab] buffer dominates HBM
@@ -162,9 +179,18 @@ class Attention(nn.Module):
         proj = lambda name: nn.DenseGeneral(  # noqa: E731
             (cfg.num_heads, cfg.head_dim), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name=name)
-        q = rope(proj("q")(x), positions, cfg.rope_theta)
-        k = rope(proj("k")(x), positions, cfg.rope_theta)
-        v = proj("v")(x)
+
+        def rotated(name):
+            y = proj(name)(x)
+            if cfg.qk_norm:     # over the whole projection, all heads as one
+                y = FusedRMSNorm(
+                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    epsilon=cfg.norm_eps, use_fused=cfg.fused_norm,
+                    name=f"{name}_norm")(
+                    y.reshape(*y.shape[:-2], -1)).reshape(y.shape)
+            return rope(y, positions, cfg.rope_theta)
+
+        q, k, v = rotated("q"), rotated("k"), proj("v")(x)
         o_proj = nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), use_bias=False,
                                  dtype=cfg.dtype,
                                  param_dtype=cfg.param_dtype, name="o")
@@ -216,8 +242,10 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache=None, return_kv=False):
         cfg = self.cfg
-        y = FusedRMSNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                         use_fused=cfg.fused_norm, name="attn_norm")(x)
+        norm = lambda name: FusedRMSNorm(  # noqa: E731
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            epsilon=cfg.norm_eps, use_fused=cfg.fused_norm, name=name)
+        y = norm("attn_norm")(x)
         kv = None
         if cache is not None or return_kv:
             attn_out, kv = Attention(cfg, name="attn")(
@@ -225,9 +253,21 @@ class Block(nn.Module):
         else:
             attn_out = Attention(cfg, name="attn")(y, positions)
         x = x + attn_out
-        y = FusedRMSNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                         use_fused=cfg.fused_norm, name="mlp_norm")(x)
-        if cfg.moe_axis is not None:
+        y = norm("mlp_norm")(x)
+        if cfg.num_experts > 0:
+            from horovod_tpu.models.moe import MoEMLP
+
+            if cfg.moe_axis is not None:
+                raise ValueError("num_experts (every expert on each device) "
+                                 "and moe_axis (one expert a device) are two "
+                                 "layouts; set one")
+            x = x + MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
+                           axis_name=None, dtype=cfg.dtype,
+                           num_experts=cfg.num_experts,
+                           experts_per_token=cfg.experts_per_token,
+                           norm_topk_prob=cfg.norm_topk_prob,
+                           param_dtype=cfg.param_dtype, name="moe_mlp")(y)
+        elif cfg.moe_axis is not None:
             from horovod_tpu.models.moe import MoEMLP
 
             # Residual carries over-capacity (dropped) tokens unchanged.
@@ -312,7 +352,8 @@ class Transformer(nn.Module):
             else:
                 x = block_cls(cfg, name=f"layer_{i}")(x, positions)
         x = FusedRMSNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                         use_fused=cfg.fused_norm, name="final_norm")(x)
+                         epsilon=cfg.norm_eps, use_fused=cfg.fused_norm,
+                         name="final_norm")(x)
         # Head matmul in the compute dtype (bf16 hits the MXU at full rate;
         # f32 params, XLA accumulates in f32); logits upcast for the loss —
         # the standard LLM-trainer convention.  The f32 head matmul this

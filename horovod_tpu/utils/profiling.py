@@ -43,6 +43,11 @@ FLASH_FWD = "hvd_flash_fwd"     # ops/flash_attention: forward kernel
 FLASH_DQ = "hvd_flash_dq"       # ... backward, dq pass
 FLASH_DKV = "hvd_flash_dkv"     # ... backward, dk/dv pass
 LOSS = "hvd_loss"               # ops/losses.softmax_cross_entropy, both ways
+MOE_ROUTE = "hvd_moe_route"     # models/moe: router matmul, softmax, top-k,
+                                # the sort by expert, the counts, the losses
+MOE_DISPATCH = "hvd_moe_dispatch"   # ... tokens gathered into expert order
+MOE_EXPERTS = "hvd_moe_experts"     # ... the grouped matmuls, the activation
+MOE_COMBINE = "hvd_moe_combine"     # ... back to token order, gate-weighted sum
 LOADER_WAIT = "hvd_loader_wait"         # data.BackgroundLoader: q.get()
 LOADER_PRODUCE = "hvd_loader_produce"   # ... next(source), producer thread
 H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
@@ -50,6 +55,13 @@ H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
 # table; examples/overlap_audit.py counts it.)
 
 FLASH_PASSES = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
+MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
+# XLA:TPU replaces ``lax.ragged_dot`` with Mosaic kernels of its own and
+# names them afresh (``op_name="ragged-dot-none"``, and
+# ``"ragged-dot-metadata"`` for the tile table they share): the scope the
+# program wrote is gone from them.  The program's one grouped matmul is the
+# expert layer's, so :func:`scope_table` gives such a kernel that name back.
+_RAGGED_DOT_KERNEL = "ragged-dot"
 # jax's own markers on the name stack, which the phase rule reads
 _REMAT = "rematted_computation"     # nn.remat / jax.checkpoint's re-run
 PHASES = ("forward", "backward", "recompute", "optimizer", "collective",
@@ -150,7 +162,8 @@ class Scope:
     phases: tuple               # one phase; two or more for a mixed fusion
     module: str                 # module_of(op_name)
     bucket: str | None = None   # "0".., "all": a collective under hvd_bucket_
-    kernel: str | None = None   # a FLASH_PASSES name: a kernel under it
+    kernel: str | None = None   # a kernel's name: a FLASH_PASSES pass, or
+                                # MOE_EXPERTS (XLA's own grouped matmul)
     bytes: int = 0              # of the result, from its shape
 
     @property
@@ -189,7 +202,8 @@ def scope_table(compiled) -> dict[str, Scope]:
     (the head's matmul with the loss and its gradient) and is
     ``forward+backward``.  A collective is ``collective`` by
     opcode whatever its scope, and carries its ``hvd_bucket_<k>``; a kernel
-    (custom call) under ``hvd_flash_*`` carries that pass.  ``while`` and
+    (custom call) under ``hvd_flash_*`` carries that pass, and one that
+    XLA:TPU made of a ``ragged_dot`` carries ``hvd_moe_experts``.  ``while`` and
     ``conditional`` bodies are computations like the entry: their
     instructions are in the table under their own names.
     """
@@ -236,8 +250,12 @@ def scope_table(compiled) -> dict[str, Scope]:
                 # first module named inside it
                 module = module or inner_module
             bucket = _BUCKET.search(op_name) if own == "collective" else None
-            kernel = next((k for k in FLASH_PASSES if k in op_name), None) \
-                if opcode == "custom-call" else None
+            kernel = None
+            if opcode == "custom-call":
+                kernel = next((k for k in FLASH_PASSES if k in op_name),
+                              MOE_EXPERTS
+                              if op_name.startswith(_RAGGED_DOT_KERNEL)
+                              else None)
             table[name] = Scope(
                 opcode=opcode, op_name=op_name, phases=phases, module=module,
                 bucket=bucket.group(1) if bucket else None, kernel=kernel,
@@ -293,3 +311,17 @@ def _shape_bytes(shape: str) -> int:
         math.prod(int(d) for d in m.group(3).split(",") if d)
         * max(int(m.group(2) or 8) // 8, 1)
         for m in _ARRAY.finditer(shape))
+
+
+def expert_load(pairs) -> dict:
+    """What one call of an expert layer did to its experts, from the
+    per-expert counts of (token, expert) pairs the layer sows (``[E]``
+    ints; ``models/moe.py``, collection ``moe_stats``): how many pairs
+    there were, the fullest expert's over the mean, and how many experts
+    got none."""
+    counts = [int(c) for c in pairs]
+    total = sum(counts)
+    return {"pairs": total,
+            "max_over_mean": (max(counts) * len(counts) / total
+                              if total else 0.0),
+            "empty_experts": sum(c == 0 for c in counts)}
