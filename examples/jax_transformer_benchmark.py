@@ -53,10 +53,10 @@ def main():
                     help="dense einsum attention (for comparison / to "
                          "demonstrate where it OOMs)")
     ap.add_argument("--block-q", type=int, default=1024,
-                    help="q-side super tile (streamed in the dk/dv pass; "
+                    help="q-side super tile (streamed in the backward; "
                          "2048 exceeds the 16 MiB VMEM scope at d128)")
     ap.add_argument("--block-k", type=int, default=None,
-                    help="k-side super tile (streamed in fwd/dq passes). "
+                    help="k-side super tile (streamed in the forward). "
                          "Default min(seq_len, 2048), matching the "
                          "library default (_default_block_k): the bigger "
                          "streaming tile measured 57.4->59.6%% MFU at "

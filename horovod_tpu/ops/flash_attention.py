@@ -12,12 +12,17 @@ Integration points:
 * ``parallel/ring_attention.py`` can use it per ring step (each step is
   exactly one q-block × local-K/V attention with carried (m, l, acc)).
 
-Backward is fused too: a dq kernel (grid over q-blocks) and a dk/dv kernel
-(grid over k-blocks) recompute probabilities per block from the forward's
-saved log-sum-exp — p = exp(s − lse) — and carry Δ = rowsum(dO·O), the
-standard flash-attention backward.  No O(S²) tensor is ever materialized in
-HBM in either pass.  The kernels take lse/Δ as explicit inputs so ring
-attention can drive them per ring step with globally-merged statistics.
+Backward is fused too, and is ONE kernel (grid over k-blocks, then q-blocks):
+per tile it recomputes the probabilities from the forward's saved
+log-sum-exp — p = exp(s − lse) — and, with Δ = rowsum(dO·O), forms
+ds = p·(dO·vᵀ − Δ) once and feeds dv, dk and dq from it: the five matrix
+products a tile the backward needs, no more (two passes, one for dq and
+one for dk/dv, each formed q·kᵀ and dO·vᵀ: seven).  dk/dv accumulate in
+their resident output blocks; dq, whose rows are revisited once per
+k-block, in a VMEM-resident f32 block of the head's whole q length.  No
+O(S²) tensor is ever materialized in HBM in either direction.  The kernel
+takes lse/Δ as explicit inputs so ring attention can drive it per ring step
+with globally-merged statistics.
 
 Non-TPU backends fall back to Pallas interpret mode (tests) so numerics are
 identical everywhere.
@@ -44,7 +49,7 @@ LN2 = 0.6931471805599453
 
 
 def _sub_bounds(k_len, q_min, q_max, ks_min, sub_k, nsub, causal):
-    """Sub-tile split bounds shared by the forward and dq kernels: ``hi``
+    """The forward kernel's sub-tile split bounds: ``hi``
     is the causal sweep end (tiles past the diagonal contribute p == 0),
     ``interior_end`` the mask-free prefix (entirely below the diagonal and
     inside the valid K range)."""
@@ -202,13 +207,6 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
         lse_ref[0] = jnp.broadcast_to(lse[:, 0][None, :], lse_ref.shape[1:])
 
 
-def _dims_arbitrary_last():
-    """Mosaic dimension semantics for the backward grids: outer axes are
-    parallel, the innermost is the sequential accumulation sweep."""
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-
 def _pad_to(x, axis, multiple):
     n = x.shape[axis]
     pad = (-n) % multiple
@@ -248,10 +246,12 @@ def _sub_fit(block: int, sub: int) -> tuple[int, int]:
 
 
 # Mosaic gives one kernel 16 MiB of scoped VMEM by default on the v5e
-# (device_kind "TPU v5 lite"), and nothing here raises that limit.
-# Confirmed on that device with libtpu 0.0.34 (CHANGES.md PR 21): at the
-# default tiles the forward, dq and dk/dv kernels compile and run at
-# S=1024/B=8, S=8192/B=4 and S=32768/B=1 with remat (d=128, bf16).  The
+# (device_kind "TPU v5 lite").  The forward lives under that default; the
+# backward asks for a limit of its own (_bwd_vmem_limit_bytes below).
+# Confirmed on that device with libtpu 0.0.34: at the default tiles the
+# forward compiles and runs at S=1024/B=8, S=8192/B=4 and S=32768/B=1 with
+# remat (d=128, bf16; CHANGES.md PR 21), forward and the one backward pass
+# at S=2048/B=8, S=4096/B=4 and S=16384/B=1 (PR 27).  The
 # fit budget sits below the limit because this estimate cannot see
 # Mosaic's scheduling windows — exactly how the hand-set block_k=4096
 # passed review at S=8192 and then overflowed the remat backward at
@@ -265,11 +265,15 @@ _vmem_clamp_warned: set = set()
 
 def _vmem_estimate_bytes(block_q: int, block_k: int, d: int,
                          sub: int = 1024, itemsize: int = 2) -> int:
-    """Resident-set model of the worst pass (backward dq): double-buffered
-    K/V streaming super tiles, q/dO tiles, the f32 dq accumulator, the
-    sublane-replicated lse/Δ rows, and two live [block_q, sub] f32 compute
-    tiles (Mosaic fuses the elementwise chain, so s/p/dp/ds share ~two
-    buffers in practice)."""
+    """Resident-set model that sizes the tiles (the entry points' clamp
+    and ``ContextPlan``'s), priced for a pass of the forward's layout
+    that also streams dO and Δ — more than the forward holds, on purpose:
+    double-buffered K/V streaming super tiles, q/dO tiles, the f32
+    accumulator, the sublane-replicated lse/Δ rows, and two live
+    [block_q, sub] f32 compute tiles (Mosaic fuses the elementwise chain,
+    so s/p share ~two buffers in practice).  The backward's resident set,
+    whose dq accumulator grows with S_q, is
+    :func:`_bwd_vmem_estimate_bytes`."""
     sub_k = min(sub, max(block_k, 1))
     kv = 2 * 2 * block_k * d * itemsize          # K+V, double-buffered
     qdo = 2 * 2 * block_q * d * itemsize         # q + dO tiles
@@ -277,6 +281,62 @@ def _vmem_estimate_bytes(block_q: int, block_k: int, d: int,
     stats = 2 * 8 * block_q * 4                  # lse + Δ, sublane-replicated
     tiles = 2 * block_q * sub_k * 4              # live f32 compute tiles
     return kv + qdo + acc + stats + tiles
+
+
+# The backward's dq accumulator is the one VMEM term that grows with the
+# sequence (S_q·d·4 bytes: 8 MiB at S=16384, d=128), so that call asks
+# Mosaic for its own limit (``vmem_limit_bytes``) instead of living under
+# the 16 MiB default.  A v5e core has 128 MiB of VMEM (jax's own
+# ``pallas.tpu.get_tpu_info`` table; that call needs an attached TPU, this
+# file must also trace for a described one).  One call asks for at most
+# three quarters of it; a longer q is cut into row ranges, one call each.
+VMEM_PHYSICAL_MB = 128.0
+_BWD_VMEM_ASK_MAX_BYTES = int(0.75 * VMEM_PHYSICAL_MB * 2 ** 20)
+
+
+def _bwd_vmem_estimate_bytes(block_q: int, block_k: int, d: int, s_q: int,
+                             sub: int = 1024, itemsize: int = 2) -> int:
+    """Resident-set model of the one backward pass (:func:`_bwd_kernel`):
+    double-buffered Q/dO super tiles and K/V tiles, the sublane-replicated
+    lse/Δ rows, the double-buffered f32 dk/dv blocks, three live
+    [sub_q, block_k] f32 compute tiles (Mosaic fuses the elementwise
+    chain: the v5e compiler's own scoped allocation at the default tiles
+    is 15.7 MiB at S=2048 where this says 17.1), and the one term that
+    grows with the sequence: the f32 dq accumulator over the ``s_q`` padded
+    q rows of one call, single-buffered.  ``block_k`` is the kernel's own
+    k tile (≤ 1024 at the defaults)."""
+    sub_q = min(sub, max(block_q, 1))
+    qdo = 2 * 2 * block_q * d * itemsize         # q + dO, double-buffered
+    kv = 2 * 2 * block_k * d * itemsize          # K + V, double-buffered
+    stats = 2 * 2 * 8 * block_q * 4              # lse + Δ, double-buffered
+    dkv = 2 * 2 * block_k * d * 4                # f32 dk + dv blocks
+    tiles = 3 * sub_q * block_k * 4              # live f32 compute tiles
+    dq = s_q * d * 4                             # the call's accumulator
+    return qdo + kv + stats + dkv + tiles + dq
+
+
+def _bwd_vmem_limit_bytes(block_q: int, block_k: int, d: int, s_q: int,
+                          sub: int = 1024, itemsize: int = 2) -> int:
+    """What the backward call asks Mosaic for: its estimate and a quarter
+    more (the estimate cannot see the scheduler's windows), never under
+    the 16 MiB default."""
+    est = _bwd_vmem_estimate_bytes(block_q, block_k, d, s_q, sub, itemsize)
+    return max(16 * 2 ** 20, est + est // 4)
+
+
+def _bwd_q_rows_per_call(block_q: int, block_k: int, d: int, s_q: int,
+                         sub: int = 1024, itemsize: int = 2) -> int:
+    """How many of the ``s_q`` padded q rows one backward call takes: all
+    of them while the limit it would ask for stays within
+    ``_BWD_VMEM_ASK_MAX_BYTES``, else the fewest equal ranges of whole q
+    blocks that do (never under one block)."""
+    blocks = s_q // block_q
+    calls = 1
+    while calls < blocks and _bwd_vmem_limit_bytes(
+            block_q, block_k, d, -(-blocks // calls) * block_q, sub,
+            itemsize) > _BWD_VMEM_ASK_MAX_BYTES:
+        calls += 1
+    return -(-blocks // calls) * block_q
 
 
 def clamp_blocks_to_vmem(block_q: int, block_k: int, d: int,
@@ -368,7 +428,9 @@ def _flash_forward(q, k, v, causal, q_offset, k_offset, block_q, block_k,
             carry_shape,   # m carry (discarded)
             carry_shape,   # l carry (discarded)
         ),
-        compiler_params=_dims_arbitrary_last(),
+        # outer axes parallel, the innermost the sequential K sweep
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=profiling.FLASH_FWD,
     )(meta, qb, kb, vb)
@@ -382,124 +444,36 @@ def _flash_forward(q, k, v, causal, q_offset, k_offset, block_q, block_k,
     return out
 
 
-def _bwd_dq_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *, block_q: int, block_k: int, sub_k: int,
-                   num_k_blocks: int, causal: bool, scale: float):
-    """One (batch·head, q-block, K-super-tile) program: dq += p·(dp − Δ)·K.
+def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, *, block_q: int, block_k: int,
+                sub_q: int, num_q_blocks: int, causal: bool, scale: float):
+    """One (batch·head, k-block, Q-super-tile) program of the one backward
+    pass: per tile s, p, dp and ds = p·(dp − Δ) are formed ONCE and feed
+    all three gradients — dv += pᵀ·dO, dk += dsᵀ·(q·scale), dq += ds·k.
 
-    Same two-level streaming as the forward: the grid moves (block_k, D)
-    K/V super tiles double-buffered while the in-kernel loop computes
-    (block_q, sub_k) sub tiles; the f32 dq output block (index map
-    constant in ki) stays VMEM-resident as the accumulator.  Scoped VMEM
-    is independent of S — what lets large tiles compile where the round-2
-    whole-sequence layout overflowed the 16 MiB bound at S=8192.
-
-    The sub-tile loop splits into a mask-free interior prefix and a masked
-    diagonal/boundary suffix (padded q rows are safe maskless: their lse
-    is +1e30, so p = exp(s - lse) == 0); super tiles entirely above the
-    diagonal run zero sub-tiles.
-    """
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nsub = block_k // sub_k
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
-
-    q_min = meta_ref[0] + qi * block_q
-    q_max = q_min + block_q - 1
-    ks_min = meta_ref[1] + ki * block_k
-    hi, interior_end = _sub_bounds(meta_ref[2], q_min, q_max, ks_min,
-                                   sub_k, nsub, causal)
-
-    # Input-dtype matmul operands with f32 accumulation — see
-    # _flash_kernel.  The scale-fold rounding (incl. the LOG2E factor)
-    # matches the forward's, so s — hence p = exp2(s − lse·log2e) —
-    # recomputes consistently; the saved lse arrives in natural units
-    # (the public ring-attention contract) and converts per block row.
-    q = (q_ref[0].astype(jnp.float32) * (scale * LOG2E)).astype(q_ref.dtype)
-    do = do_ref[0]                                        # [bq, D]
-    lse = lse_ref[0, 0, :][:, None]                       # [bq, 1] natural
-    lse2 = lse * LOG2E                                    # log2 domain
-    delta = delta_ref[0, 0, :][:, None]
-
-    def body(si, carry, masked):
-        k = k_ref[0, pl.ds(si * sub_k, sub_k), :]
-        v = v_ref[0, pl.ds(si * sub_k, sub_k), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        if masked:
-            row_ok = lse > NEG_INF / 2                    # rows that attended
-            q_pos = (q_min + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, sub_k), 0))
-            k_pos = (ks_min + si * sub_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, sub_k), 1))
-            mask = k_pos < meta_ref[2]
-            if causal:
-                mask = jnp.logical_and(mask, q_pos >= k_pos)
-            p = jnp.where(jnp.logical_and(mask, row_ok),
-                          jnp.exp2(s - lse2), 0.0)
-        else:
-            p = jnp.exp2(s - lse2)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_ref[0] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return carry
-
-    if nsub == 1:
-        # Static single-tile case: straight-line pl.when (see _flash_kernel).
-        run = hi >= 1
-        interior = interior_end >= 1
-
-        @pl.when(jnp.logical_and(run, interior))
-        def _one_interior():
-            body(0, 0, masked=False)
-
-        @pl.when(jnp.logical_and(run, jnp.logical_not(interior)))
-        def _one_boundary():
-            body(0, 0, masked=True)
-    else:
-        # Static unroll (see _flash_kernel): no carry here at all — the
-        # dq accumulator lives in its ref — so sub-tile bodies are fully
-        # independent for Mosaic's MXU/VPU scheduling.
-        for si in range(nsub):
-            @pl.when(si < interior_end)
-            def _interior(si=si):
-                body(si, 0, masked=False)
-
-            @pl.when(jnp.logical_and(si >= interior_end, si < hi))
-            def _boundary(si=si):
-                body(si, 0, masked=True)
-
-    @pl.when(ki == num_k_blocks - 1)
-    def _finish():
-        # q was pre-scaled for s; the K-contraction needs one more scale.
-        dq_ref[0] = dq_ref[0] * scale
-
-
-def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, block_q: int, block_k: int,
-                    sub_q: int, num_q_blocks: int, causal: bool,
-                    scale: float):
-    """One (batch·head, k-block, Q-super-tile) program:
-    dv += pᵀ·dO;  dk += (p·(dp − Δ))ᵀ·(q·scale).
-
-    The forward/dq layout with the roles swapped: the grid streams
+    The forward's layout with the roles swapped: the grid streams
     (block_q, D) Q/dO super tiles (lse/Δ alongside) double-buffered while
-    the in-kernel loop computes (sub_q, block_k) sub tiles; the f32 dk/dv
-    output blocks stay VMEM-resident across the qi sweep.
+    the in-kernel loop computes (sub_q, block_k) sub tiles.  dk/dv are f32
+    output blocks that stay VMEM-resident across the qi sweep.  dq's rows
+    are revisited once per k-block, so its f32 output block is the head's
+    WHOLE padded q length (index map constant in ki and qi): resident for
+    the head, zeroed at the head's first grid step (so a call in which no
+    tile runs — K wholly after Q — still returns zeros), written back
+    once a head.  That block is what grows with S_q;
+    :func:`_bwd_vmem_estimate_bytes` prices it.
 
-    Sub-tile split mirrors the others, from the K block's point of view:
-    q sub-tiles entirely ABOVE the diagonal (q_sub_max < k_min) are
-    skipped; the diagonal band runs masked; q sub-tiles entirely below
-    (q_sub_min >= k_max, with the K block fully valid) run mask-free —
-    padded q rows are safe maskless (lse = +1e30 ⇒ p = 0).
+    Sub-tile split, from the K block's point of view: q sub-tiles entirely
+    ABOVE the diagonal (q_sub_max < k_min) are skipped; the diagonal band
+    runs masked; q sub-tiles entirely below (q_sub_min >= k_max, with the
+    K block fully valid) run mask-free — padded q rows are safe maskless
+    (lse = +1e30 ⇒ p = 0).
     """
     ki, qi = pl.program_id(1), pl.program_id(2)
     nsub = block_q // sub_q
+
+    @pl.when(jnp.logical_and(ki == 0, qi == 0))
+    def _init_dq():
+        dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
     @pl.when(qi == 0)
     def _init():
@@ -526,12 +500,15 @@ def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0]                                          # [bk, D]
     v = v_ref[0]
 
-    def body(si, carry, masked):
-        # Same scale-fold rounding (incl. LOG2E) as the forward and dq
-        # kernels, so s — hence p = exp2(s − lse·log2e) — recomputes
-        # consistently; k/v/do stay in the input dtype like everywhere
-        # else.  The fold's log2e surplus on dk is repaid by the ·ln2 in
-        # _finish (dv uses p directly and needs none).
+    def body(si, masked):
+        # Input-dtype matmul operands with f32 accumulation — see
+        # _flash_kernel.  The scale-fold rounding (incl. LOG2E) matches
+        # the forward's, so s — hence p = exp2(s − lse·log2e) — recomputes
+        # consistently; the saved lse arrives in natural units (the public
+        # ring-attention contract) and converts per row.  The fold's log2e
+        # surplus on dk is repaid by the ·ln2 in _finish (dv uses p
+        # directly and needs none; dq takes plain ``scale`` after the
+        # call, with its cast).
         q = (q_ref[0, pl.ds(si * sub_q, sub_q), :].astype(jnp.float32)
              * (scale * LOG2E)).astype(q_ref.dtype)       # [sq, D]
         do = do_ref[0, pl.ds(si * sub_q, sub_q), :]
@@ -541,7 +518,7 @@ def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if masked:
-            row_ok = lse > NEG_INF / 2
+            row_ok = lse > NEG_INF / 2                    # rows that attended
             q_pos = (qs_min + si * sub_q + jax.lax.broadcasted_iota(
                 jnp.int32, (sub_q, block_k), 0))
             k_pos = (k_min + jax.lax.broadcasted_iota(
@@ -560,13 +537,16 @@ def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
+        ds = (p * (dp - delta)).astype(q_ref.dtype)       # one cast, two uses
         # q is pre-scaled (incl. LOG2E), so this is d s/d k contracted
         # with ds up to the log2e surplus repaid in _finish.
         dk_ref[0] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return carry
+        rows = pl.ds(pl.multiple_of(qi * block_q + si * sub_q, sub_q), sub_q)
+        dq_ref[0, rows, :] += jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     if nsub == 1:
         # Static single-tile case: straight-line pl.when (see _flash_kernel).
@@ -575,23 +555,23 @@ def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
         @pl.when(jnp.logical_and(run, jnp.logical_not(interior)))
         def _one_boundary():
-            body(0, 0, masked=True)
+            body(0, masked=True)
 
         @pl.when(interior)
         def _one_interior():
-            body(0, 0, masked=False)
+            body(0, masked=False)
     else:
-        # Static unroll (see _flash_kernel); dk/dv accumulate in refs so
-        # sub-tile bodies are independent.  Masked band first (lo <= si <
-        # int_start), mask-free tail (si >= int_start).
+        # Static unroll (see _flash_kernel); the three gradients accumulate
+        # in refs so sub-tile bodies are independent.  Masked band first
+        # (lo <= si < int_start), mask-free tail (si >= int_start).
         for si in range(nsub):
             @pl.when(jnp.logical_and(si >= lo, si < int_start))
             def _boundary(si=si):
-                body(si, 0, masked=True)
+                body(si, masked=True)
 
             @pl.when(si >= int_start)
             def _interior(si=si):
-                body(si, 0, masked=False)
+                body(si, masked=False)
 
     @pl.when(qi == num_q_blocks - 1)
     def _finish():
@@ -603,7 +583,8 @@ def _bwd_dkv_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def flash_attention_backward(q, k, v, dout, lse, delta, causal,
                              q_offset, k_offset, block_q, block_k,
                              interpret, sub: int = 1024):
-    """Fused backward: (dq, dk, dv) from saved lse and Δ = rowsum(dO·O).
+    """Fused backward: (dq, dk, dv) from saved lse and Δ = rowsum(dO·O),
+    one kernel, one sweep over the tiles (:func:`_bwd_kernel`).
 
     ``lse``/``delta``: [B, S_q, H] float32 — from ``_flash_forward(...,
     with_lse=True)`` (or the ring's globally-merged statistics), so the
@@ -623,15 +604,15 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
         where="flash_attention_backward")
     block_q, sub_q = _sub_fit(block_q, sub)
     block_k, sub_k = _sub_fit(block_k, sub)
-    # The dk/dv pass's k tile is BOTH its resident accumulator width and
-    # its compute-tile width (intermediates are [sub_q, k_tile]) — cap it
-    # near 1024 (keeping the s/p/dp/ds buffers ~2 MB) instead of letting
-    # it scale with the streaming super-tile chosen for the fwd/dq passes,
-    # while keeping it a divisor of the padded K length.
-    bk_dkv = sub_k
-    while (bk_dkv * 2 <= min(block_k, max(1024, sub_k))
-           and block_k % (bk_dkv * 2) == 0):
-        bk_dkv *= 2
+    # The k tile is BOTH the resident dk/dv accumulator width and the
+    # compute-tile width (intermediates are [sub_q, k_tile]) — cap it near
+    # 1024 (keeping the s/p/dp/ds buffers ~2 MB) instead of letting it
+    # scale with the streaming super-tile chosen for the forward, while
+    # keeping it a divisor of the padded K length.
+    bk = sub_k
+    while (bk * 2 <= min(block_k, max(1024, sub_k))
+           and block_k % (bk * 2) == 0):
+        bk *= 2
 
     def to_bh(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
@@ -654,68 +635,61 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
     delta_b = _pad_to(to_bh2(delta.astype(jnp.float32)), 1, block_q)
     delta_b = jnp.broadcast_to(delta_b[:, None, :],
                                (delta_b.shape[0], 8, delta_b.shape[1]))
-    num_q_blocks = qb.shape[1] // block_q
-    num_k_blocks = kb.shape[1] // block_k
-    meta = jnp.asarray(
-        [jnp.asarray(q_offset, jnp.int32),
-         jnp.asarray(k_offset, jnp.int32),
-         jnp.asarray(k_offset, jnp.int32) + s_k], jnp.int32)
+    rows = _bwd_q_rows_per_call(block_q, bk, d, qb.shape[1], sub,
+                                q.dtype.itemsize)
 
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel, block_q=block_q, block_k=block_k, sub_k=sub_k,
-        num_k_blocks=num_k_blocks, causal=causal, scale=scale)
-    # Outputs accumulate in f32 in the VMEM-resident block (index maps
-    # constant over the innermost grid axis); cast back after the call.
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(b * h, num_q_blocks, num_k_blocks),
-        in_specs=[
-            pl.BlockSpec((3,), lambda bh, qi, ki: (0,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda bh, qi, ki: (bh, 0, qi)),
-            pl.BlockSpec((1, 8, block_q), lambda bh, qi, ki: (bh, 0, qi)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d),
-                               lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct(qb.shape, jnp.float32),
-        compiler_params=_dims_arbitrary_last(),
-        interpret=interpret,
-        name=profiling.FLASH_DQ,
-    )(meta, qb, kb, vb, dob, lse_b, delta_b).astype(q.dtype)
+    def call(r0, n):
+        meta = jnp.asarray(
+            [jnp.asarray(q_offset, jnp.int32) + r0,
+             jnp.asarray(k_offset, jnp.int32),
+             jnp.asarray(k_offset, jnp.int32) + s_k], jnp.int32)
+        # One kernel over the n q rows from r0 against all of K.  Outputs
+        # accumulate in f32 in their VMEM-resident blocks.  dq's block is
+        # revisited across BOTH inner axes, so both are sequential; one
+        # buffer for it (its index changes once a head — a second would
+        # only double the one term that grows with S_q).
+        return pl.pallas_call(
+            functools.partial(
+                _bwd_kernel, block_q=block_q, block_k=bk, sub_q=sub_q,
+                num_q_blocks=n // block_q, causal=causal, scale=scale),
+            grid=(b * h, kb.shape[1] // bk, n // block_q),
+            in_specs=[
+                pl.BlockSpec((3,), lambda bh, ki, qi: (0,),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
+                pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
+                pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
+                pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
+                pl.BlockSpec((1, 8, block_q), lambda bh, ki, qi: (bh, 0, qi)),
+                pl.BlockSpec((1, 8, block_q), lambda bh, ki, qi: (bh, 0, qi)),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, n, d), lambda bh, ki, qi: (bh, 0, 0),
+                             pipeline_mode=pl.Buffered(1)),
+                pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
+                pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
+            ),
+            out_shape=(
+                jax.ShapeDtypeStruct((b * h, n, d), jnp.float32),
+                jax.ShapeDtypeStruct(kb.shape, jnp.float32),
+                jax.ShapeDtypeStruct(vb.shape, jnp.float32),
+            ),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=_bwd_vmem_limit_bytes(
+                    block_q, bk, d, n, sub, q.dtype.itemsize)),
+            interpret=interpret,
+            name=profiling.FLASH_BWD,
+        )(meta, qb[:, r0:r0 + n], kb, vb, dob[:, r0:r0 + n],
+          lse_b[:, :, r0:r0 + n], delta_b[:, :, r0:r0 + n])
 
-    num_k_dkv = kb.shape[1] // bk_dkv
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel, block_q=block_q, block_k=bk_dkv, sub_q=sub_q,
-        num_q_blocks=num_q_blocks, causal=causal, scale=scale)
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(b * h, num_k_dkv, num_q_blocks),
-        in_specs=[
-            pl.BlockSpec((3,), lambda bh, ki, qi: (0,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, bk_dkv, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bk_dkv, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, ki, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, 8, block_q), lambda bh, ki, qi: (bh, 0, qi)),
-            pl.BlockSpec((1, 8, block_q), lambda bh, ki, qi: (bh, 0, qi)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, bk_dkv, d), lambda bh, ki, qi: (bh, ki, 0)),
-            pl.BlockSpec((1, bk_dkv, d), lambda bh, ki, qi: (bh, ki, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct(kb.shape, jnp.float32),
-            jax.ShapeDtypeStruct(vb.shape, jnp.float32),
-        ),
-        compiler_params=_dims_arbitrary_last(),
-        interpret=interpret,
-        name=profiling.FLASH_DKV,
-    )(meta, qb, kb, vb, dob, lse_b, delta_b)
+    parts = [call(r0, min(rows, qb.shape[1] - r0))
+             for r0 in range(0, qb.shape[1], rows)]
+    dqs, dks, dvs = zip(*parts)
+    dq = dqs[0] if len(parts) == 1 else jnp.concatenate(dqs, axis=1)
+    dk, dv = functools.reduce(jnp.add, dks), functools.reduce(jnp.add, dvs)
+    # q was pre-scaled for s; the K-contraction needs one more scale.
+    dq = (dq * scale).astype(q.dtype)
     dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
 
     def from_bh(x, s):
@@ -774,7 +748,8 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     row/col (sequence-parallel shards pass shard_index × shard_len).
 
     Tiling: the grid streams (block_k, D) K/V super tiles (Q/dO super
-    tiles of block_q rows in the dk/dv pass) double-buffered — few, large
+    tiles of block_q rows in the backward, against k tiles of at most
+    1024 rows) double-buffered — few, large
     DMAs and few grid steps — while the in-kernel loop computes over
     ``sub``-sized slices so the [block_q, sub] intermediates bound scoped
     VMEM independent of S (the round-2 whole-sequence layout hit the
@@ -786,7 +761,12 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     measures 60.3 % there but VMEM-overflows the S=32768 remat backward
     — while the statically-unrolled sub loop keeps scoped VMEM bounded.
     ``block_q`` stays ≤1024: the [block_q, sub] s-tile is VMEM-resident
-    and 2048 exceeds the 16 MiB scope at d=128.
+    and 2048 exceeds the 16 MiB scope at d=128.  The backward is one
+    kernel (:func:`_bwd_kernel`) whose f32 dq accumulator is the head's
+    whole q length in VMEM, so it asks Mosaic for a limit of its own
+    (:func:`_bwd_vmem_limit_bytes`; 30 MiB at S=16384) and a q too long
+    for the chip's VMEM (past 65536 rows at d=128) is cut into row
+    ranges, one call each.
 
     Keep ``block_k / sub`` (and ``block_q / sub`` in the backward) at or
     below :data:`MAX_SUB_TILES` (8): the sub-tile sweep is statically

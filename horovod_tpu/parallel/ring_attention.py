@@ -249,7 +249,7 @@ def _ring_flash_bwd(axis_name, causal, block_q, block_k, res, g):
         if not causal:
             return run((k, v, dq, dk, dv))
         # Fully-masked block (owner > my): p ≡ 0, so dq/dk/dv partials are
-        # exactly zero — skip the two backward kernels entirely.
+        # exactly zero — skip the backward kernel entirely.
         needed = owner <= my
         return lax.cond(needed, run, lambda ops: (ops[2], ops[3], ops[4]),
                         (k, v, dq, dk, dv))

@@ -245,6 +245,33 @@ def test_readers_read_the_join_made_once(recorded, monkeypatch, capsys):
     assert reader("flash_dq_ms").read(run) is None
 
 
+@pytest.mark.parametrize("kernel,bwd,dq", [
+    ("FLASH_BWD", 1.0, 0.0),    # the one backward pass: the old names read 0
+    ("FLASH_DQ", 0.0, 1.0),     # an older program's trace: the new one does
+])
+def test_flash_bwd_ms_reads_the_fused_pass(recorded, monkeypatch, kernel,
+                                           bwd, dq):
+    dev = next(p for p in recorded if p["name"] == "/device:TPU:0")
+    ops = next(l for l in dev["lines"] if l["name"] == "XLA Ops")["events"]
+    table = {e[0]: scope(("backward",), "custom-call",
+                         kernel=getattr(profiling, kernel))
+             if trace.kind_of(e[3]) == "flash" else scope(("backward",))
+             for e in ops}
+    monkeypatch.setattr(trace, "load", lambda d: recorded)
+    monkeypatch.setattr(scopes, "table_of", lambda compiled: table)
+    summary = trace.reduce(recorded)
+    run = fake_run(summary, chips=1)
+    run.traced_steps = summary.calls
+    run.compiled, run.trace_dir = object(), "somewhere"
+    flash = 1e3 * summary.kind_s["flash"] / summary.calls
+    assert reader("flash_bwd_ms").read(run) == pytest.approx(bwd * flash)
+    assert reader("flash_dq_ms").read(run) == pytest.approx(dq * flash)
+    assert reader("flash_dkv_ms").read(run) == 0.0
+    # a program from before the fused pass has no such name: left out
+    monkeypatch.delattr(profiling, "FLASH_BWD")
+    assert reader("flash_bwd_ms").read(run) is None
+
+
 def test_a_program_without_a_scope_table_joins_to_nothing(monkeypatch):
     """What the parent commit is to these readers."""
     monkeypatch.delattr(profiling, "scope_table")

@@ -76,6 +76,50 @@ def test_plan_clamps_pinned_block_k_to_vmem():
     assert "VMEM fit" in plan.reason  # the plain case hits the model
 
 
+@pytest.mark.parametrize("s_q", [2048, 16384, 32768])
+def test_backward_vmem_limit_covers_its_estimate(s_q):
+    """The one backward pass holds the head's whole f32 dq accumulator
+    (S_q·d·4 bytes), so it has an estimate of its own and asks Mosaic for a
+    limit from it.  At the default tiles (d=128, bf16) the limit covers the
+    estimate, the accumulator is in the estimate, and the ask stays under
+    the chip's VMEM, in one call; what the forward and the plan read
+    (``_vmem_estimate_bytes``, no ``s_q``) is untouched by it."""
+    from horovod_tpu.ops.flash_attention import (
+        _BWD_VMEM_ASK_MAX_BYTES,
+        VMEM_PHYSICAL_MB,
+        _bwd_q_rows_per_call,
+        _bwd_vmem_estimate_bytes,
+        _bwd_vmem_limit_bytes,
+    )
+
+    geometry = (1024, 1024, 128, s_q)
+    est = _bwd_vmem_estimate_bytes(*geometry)
+    limit = _bwd_vmem_limit_bytes(*geometry)
+    assert est - _bwd_vmem_estimate_bytes(1024, 1024, 128, 0) \
+        == s_q * 128 * 4
+    assert est <= limit <= _BWD_VMEM_ASK_MAX_BYTES \
+        < VMEM_PHYSICAL_MB * 2 ** 20
+    assert limit >= 16 * 2 ** 20        # never under Mosaic's default
+    assert _bwd_q_rows_per_call(*geometry) == s_q
+
+
+def test_backward_rows_per_call_on_each_side_of_the_bound():
+    from horovod_tpu.ops.flash_attention import (
+        _BWD_VMEM_ASK_MAX_BYTES,
+        _bwd_q_rows_per_call,
+        _bwd_vmem_limit_bytes,
+    )
+
+    # 65536 rows are the most one call takes at d=128: twice that is two
+    # equal calls, and a length that does not divide is cut evenly
+    assert _bwd_q_rows_per_call(1024, 1024, 128, 65536) == 65536
+    assert _bwd_q_rows_per_call(1024, 1024, 128, 131072) == 65536
+    rows = _bwd_q_rows_per_call(1024, 1024, 128, 262144)
+    assert rows == 86 * 1024
+    assert _bwd_vmem_limit_bytes(1024, 1024, 128, rows) \
+        <= _BWD_VMEM_ASK_MAX_BYTES
+
+
 def test_plan_remat_follows_headroom_and_width():
     wl = _wl(131072, h=16, d=128, embed_dim=2048, mlp_dim=8192,
              num_layers=16)

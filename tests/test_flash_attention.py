@@ -135,9 +135,9 @@ def test_deep_sub_tile_unroll_warns(hvd):
 
 
 def test_bf16_gradients(hvd):
-    """bf16 end to end through the backward kernels: the input-dtype
+    """bf16 end to end through the backward kernel: the input-dtype
     matmul path (round 5 — bf16 operands, f32 accumulation, scale-fold
-    rounding shared by fwd/dq/dkv) must stay near the f32 dense
+    rounding shared by forward and backward) must stay near the f32 dense
     reference within bf16 tolerance."""
     q, k, v = _qkv(s=32, dtype=jnp.bfloat16)
 
@@ -177,7 +177,7 @@ def test_transformer_with_flash_attention(hvd):
 
 def test_gradients_unaligned_lengths(hvd):
     # S not a multiple of the block size exercises the padded-row masking
-    # (lse = +inf padding) in the fused backward kernels.
+    # (lse = +inf padding) in the fused backward kernel.
     q, k, v = _qkv(s=23)
 
     def f_flash(q, k, v):
@@ -237,8 +237,8 @@ def test_gradients_with_offsets(hvd):
 @pytest.mark.parametrize("causal", [True, False])
 def test_subtiled_matches_dense(hvd, causal):
     """The nsub>1 path (sub < block_k: in-kernel fori over sub-tiles with
-    split interior/masked bounds) — fwd AND both backward kernels,
-    including a bk_dkv smaller than the streaming super tile."""
+    split interior/masked bounds) — fwd AND the backward kernel, whose
+    k tile is smaller than the forward's streaming super tile."""
     q, k, v = _qkv(s=96)
     out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=64,
                           sub=16)
@@ -274,3 +274,127 @@ def test_subtiled_unaligned_gradients(hvd):
     g2 = jax.grad(f_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+
+def _dense_backward(q, k, v, do, causal, q_offset, k_offset):
+    """The backward written out densely in f32 from its definition: the
+    positions' mask, p from the rows' own log-sum-exp, dv = pᵀ·dO,
+    ds = p·(dO·vᵀ − Δ), dq = ds·k·scale, dk = dsᵀ·q·scale.  Returns
+    (lse, Δ, dq, dk, dv); a row that may attend to nothing has
+    lse = −1e30 and p = 0."""
+    q, k, v, do = (t.astype(jnp.float32) for t in (q, k, v, do))
+    scale = q.shape[-1] ** -0.5
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    mask = jnp.ones(s.shape[-2:], bool)
+    if causal:
+        mask = (q_offset + jnp.arange(q.shape[1]))[:, None] \
+            >= (k_offset + jnp.arange(k.shape[1]))[None, :]
+    s = jnp.where(mask, s, -jnp.inf)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    attended = jnp.isfinite(m)
+    m = jnp.where(attended, m, 0.0)
+    l = jnp.sum(jnp.exp(s - m), axis=-1, keepdims=True)
+    lse = jnp.where(attended, m + jnp.log(jnp.maximum(l, 1e-30)), -1e30)
+    p = jnp.where(attended, jnp.exp(s - lse), 0.0)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    delta = jnp.sum(do * out, axis=-1)                        # [B, S_q, H]
+    dp = jnp.einsum("bqhd,bkhd->bhqk", do, v)
+    ds = p * (dp - delta.transpose(0, 2, 1)[..., None])
+    return (lse[..., 0].transpose(0, 2, 1), delta,
+            jnp.einsum("bhqk,bkhd->bqhd", ds, k) * scale,
+            jnp.einsum("bhqk,bqhd->bkhd", ds, q) * scale,
+            jnp.einsum("bhqk,bqhd->bkhd", p, do))
+
+
+def _backward_case(s_q, s_k, q_offset, k_offset, causal, block_q, block_k,
+                   sub, dtype=jnp.float32):
+    """(kernel's dq, dk, dv), (dense dq, dk, dv) for one direct call of the
+    backward entry, the way ring attention drives it: its own lengths and
+    offsets, lse and Δ handed in."""
+    from horovod_tpu.ops.flash_attention import flash_attention_backward
+
+    ks = jax.random.split(jax.random.PRNGKey(s_q + 7 * s_k), 4)
+    q, do = (jax.random.normal(kk, (2, s_q, 2, 16), dtype) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (2, s_k, 2, 16), dtype) for kk in ks[2:])
+    lse, delta, *dense = _dense_backward(q, k, v, do, causal, q_offset,
+                                         k_offset)
+    got = flash_attention_backward(q, k, v, do, lse, delta, causal,
+                                   q_offset, k_offset, block_q, block_k,
+                                   True, sub=sub)
+    return got, dense
+
+
+@pytest.mark.parametrize("s_q,s_k,q_offset,k_offset,causal,bq,bk,sub", [
+    # a late shard of q against a longer K: three k-blocks a head, so dq's
+    # rows are revisited across grid steps; both lengths unaligned
+    (40, 72, 32, 0, True, 16, 16, 1024),
+    # K begins inside Q's range: the first rows attend to nothing
+    (64, 64, 0, 32, True, 16, 32, 1024),
+    (24, 56, 0, 0, False, 16, 16, 1024),
+    # block / sub > 1 on the q side, where the fused kernel's sub-tiles are
+    (64, 96, 32, 0, True, 32, 32, 8),
+    (48, 80, 0, 0, False, 32, 16, 16),
+    # one k-block, several q-blocks, and the reverse
+    (64, 16, 48, 0, True, 16, 16, 1024),
+    (16, 64, 48, 0, True, 16, 16, 1024),
+])
+def test_backward_entry_matches_dense(hvd, s_q, s_k, q_offset, k_offset,
+                                      causal, bq, bk, sub):
+    got, dense = _backward_case(s_q, s_k, q_offset, k_offset, causal, bq,
+                                bk, sub)
+    for a, b in zip(got, dense):
+        np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+
+def test_backward_entry_bf16_unequal_lengths(hvd):
+    got, dense = _backward_case(48, 80, 32, 0, True, 16, 32, 1024,
+                                dtype=jnp.bfloat16)
+    for a, b in zip(got, dense):
+        assert a.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(a, np.float32), b,
+                                   atol=6e-2, rtol=6e-2)
+
+
+@pytest.mark.parametrize("bq,sub", [(16, 1024), (32, 8)])
+def test_backward_k_wholly_after_q_is_zero(hvd, bq, sub):
+    """A ring step whose K lies wholly after its Q runs no tile: all three
+    gradients are zeros (dq's accumulator is zeroed at the head's first
+    grid step whether or not a tile follows), whatever lse says."""
+    from horovod_tpu.ops.flash_attention import flash_attention_backward
+
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, do = (jax.random.normal(kk, (2, 32, 2, 16)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (2, 48, 2, 16)) for kk in ks[2:])
+    stats = jax.random.normal(ks[0], (2, 32, 2))      # the ring's merged lse
+    for g in flash_attention_backward(q, k, v, do, stats, stats, True, 0,
+                                      64, bq, 16, True, sub=sub):
+        assert not np.asarray(g).any()
+
+
+def test_backward_long_q_is_cut_into_row_ranges(hvd, monkeypatch):
+    """Past the VMEM the backward may ask for, q is cut into ranges of
+    whole blocks, one call each, dk/dv summed in f32: the same gradients
+    as the one call on the other side of the bound (dq to the bit)."""
+    import importlib
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+
+    def calls(*a):
+        return str(jax.make_jaxpr(
+            lambda: fa.flash_attention_backward(*a))()).count("pallas_call")
+
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, do = (jax.random.normal(kk, (2, 72, 2, 16)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (2, 50, 2, 16)) for kk in ks[2:])
+    lse, delta, *dense = _dense_backward(q, k, v, do, True, 16, 0)
+    args = (q, k, v, do, lse, delta, True, 16, 0, 16, 16, True)
+    assert calls(*args) == 1
+    whole = fa.flash_attention_backward(*args)
+    # the bound made small: no sequence fits, one q block (the least) a call
+    monkeypatch.setattr(fa, "_BWD_VMEM_ASK_MAX_BYTES", 0)
+    assert fa._bwd_q_rows_per_call(16, 16, 16, 80, 1024, 4) == 16
+    assert calls(*args) == 5
+    cut = fa.flash_attention_backward(*args)
+    np.testing.assert_array_equal(cut[0], whole[0])
+    for a, b, c in zip(cut, whole, dense):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(a, c, atol=5e-4, rtol=5e-4)

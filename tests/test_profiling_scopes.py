@@ -131,6 +131,7 @@ ENTRY %main.1 (x: bf16[8,16], w: f32[16,4], m: f32[16,4]) -> f32[16,4] {{
   %fusion.3 = (f32[8,4]{{1,0}}, f32[8,4]{{1,0}}) fusion(%x, %w), kind=kOutput, calls=%fused_computation.3, {md(STEP + "jvp(Net)/head/dot_general")}
   %while.1 = (s32[], f32[8,4]{{1,0}}) while(%fusion.3), condition=%cond, body=%body, {md(STEP + "jvp(Net)/while")}
   %hvd_flash_dq.4 = f32[8,4]{{1,0:T(8,128)}} custom-call(%x), custom_call_target="tpu_custom_call", {md(STEP + "transpose(jvp(Net))/layer_0/attn/hvd_flash_dq/pallas_call")}
+  %hvd_flash_bwd.8 = (f32[8,4]{{1,0:T(8,128)}}, f32[8,4]{{1,0:T(8,128)}}, f32[8,4]{{1,0:T(8,128)}}) custom-call(%x), custom_call_target="tpu_custom_call", {md(STEP + "transpose(jvp(Net))/layer_0/attn/hvd_flash_bwd/pallas_call")}
   %fusion.2 = f32[8,4]{{1,0}} fusion(%x, %w), kind=kOutput, calls=%fused_computation.2, {md(BWD + "transpose")}
   %psum.5 = f32[16,4]{{1,0}} all-reduce(%w), channel_id=1, replica_groups={{{{0,1,2,3}}}}, to_apply=%sum, {md(STEP + "hvd_allreduce/hvd_bucket_2/psum")}
   %all-reduce.6 = f32[] all-reduce(%w), channel_id=2, to_apply=%sum, {md(STEP + "psum")}
@@ -159,6 +160,7 @@ def table():
     ("psum.5", "collective"),           # by opcode, not by name
     ("all-reduce.6", "collective"),
     ("hvd_flash_dq.4", "backward"),
+    ("hvd_flash_bwd.8", "backward"),
     ("multiply.1", "optimizer"),        # inside a fusion: still in the table
 ])
 def test_scope_table_labels(table, name, label):
@@ -169,7 +171,12 @@ def test_scope_table_labels(table, name, label):
 def test_scope_table_reads_buckets_kernels_modules_and_bytes(table):
     assert table["psum.5"].bucket == "2" and table["psum.5"].bytes == 256
     assert table["all-reduce.6"].bucket is None
+    # an older program's two backward passes keep their names; the one
+    # fused pass has its own
     assert table["hvd_flash_dq.4"].kernel == profiling.FLASH_DQ
+    assert profiling.FLASH_DKV == "hvd_flash_dkv"
+    assert table["hvd_flash_bwd.8"].kernel == profiling.FLASH_BWD
+    assert table["hvd_flash_bwd.8"].bytes == 3 * 8 * 4 * 4
     assert table["fusion.1"].kernel is None
     assert table["fusion.1"].module == "Net/layer_N/up"
     assert table["negate.7"].module == "Net/layer_N"     # the loop is jax's
@@ -316,10 +323,13 @@ def test_flash_passes_and_loss_are_tellable_in_interpret_mode():
 
     forward = lowered_names(jax.jit(loss).lower(q))
     assert profiling.FLASH_FWD in forward and profiling.LOSS in forward
-    assert profiling.FLASH_DQ not in forward
+    assert profiling.FLASH_BWD not in forward
     both = lowered_names(jax.jit(jax.grad(loss)).lower(q))
-    for name in profiling.FLASH_PASSES + (profiling.LOSS,):
+    for name in (profiling.FLASH_FWD, profiling.FLASH_BWD, profiling.LOSS):
         assert name in both, name
+    # the backward is one kernel: the two passes' names are an older
+    # program's
+    assert profiling.FLASH_DQ not in both and profiling.FLASH_DKV not in both
     table = profiling.scope_table(jax.jit(jax.grad(loss)).lower(q).compile())
     assert {s.phase for s in table.values()
             if profiling.LOSS in s.op_name} >= {"forward", "backward"}
@@ -401,7 +411,7 @@ def test_loader_spans_nest_inside_the_callers_span(hvd_module, tmp_path):
 def test_every_name_of_the_vocabulary_is_written_once():
     names = [v for k, v in vars(profiling).items()
              if k.isupper() and isinstance(v, str) and v.startswith("hvd_")]
-    assert len(names) == len(set(names)) == 14      # four of models/moe.py
+    assert len(names) == len(set(names)) == 15      # four of models/moe.py
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for directory, _, files in itertools.chain(
             os.walk(os.path.join(root, "horovod_tpu")),

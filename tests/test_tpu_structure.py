@@ -73,3 +73,32 @@ def test_width1_update_is_no_epilogue_of_a_weight_gradient_matmul(
     with_sqrt = [ops for ops in opcodes if ops & {"sqrt", "rsqrt"}]
     assert len(with_sqrt) >= 4, "adamw's sqrt is nowhere: wrong probe"
     assert not [ops for ops in with_sqrt if "convolution" in ops]
+
+
+@pytest.mark.parametrize("b,s", [(8, 2048), (4, 4096), (1, 16384),
+                                  (1, 32768)])
+def test_fused_flash_backward_compiles_within_the_vmem_it_asks_for(
+        one_chip_mesh, b, s):
+    """The backward's one kernel holds a head's whole f32 dq accumulator in
+    VMEM (8 MiB at S=16384, 16 at S=32768): past Mosaic's 16 MiB default,
+    so the call asks for its own limit.  The chip's compiler takes the
+    kernel at the benchmark's three geometries and at S=32768 (H=16,
+    d=128, bf16, default tiles) and makes one custom call of it."""
+    import importlib
+
+    from horovod_tpu.utils import profiling
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+
+    one_chip = NamedSharding(one_chip_mesh, P())
+    x = jax.ShapeDtypeStruct((b, s, 16, 128), jnp.bfloat16, sharding=one_chip)
+    stat = jax.ShapeDtypeStruct((b, s, 16), jnp.float32, sharding=one_chip)
+
+    def backward(q, k, v, do, lse, delta):
+        return fa.flash_attention_backward(
+            q, k, v, do, lse, delta, True, 0, 0, 1024,
+            fa._default_block_k(s, 128), False)
+
+    text = jax.jit(backward).lower(x, x, x, x, stat, stat).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and profiling.FLASH_BWD in kernels[0]
