@@ -683,21 +683,12 @@ def overlap_plan_microbench() -> None:
     depth−1 of them at width 1 and cost −4.3% on the single-chip ResNet
     headline; this line keeps that structurally impossible to ship."""
     import horovod_tpu as hvd
-    from horovod_tpu.utils import env as hvd_env
+    from horovod_tpu.ops import schedule_plan
 
     hvd.init()
-    # Measure the ADAPTIVE default: ambient bucket overrides route to the
-    # StaticPlanner, which chains regardless of width by contract.
-    saved = {v: os.environ.pop(v, None)
-             for v in ("HOROVOD_OVERLAP_BUCKETS", "HVD_TPU_OVERLAP_BUCKETS")}
-    try:
-        from examples.overlap_audit import audit_cpu_sim_width1
+    from examples.overlap_audit import audit_cpu_sim_width1
 
-        audit = audit_cpu_sim_width1()
-    finally:
-        for v, val in saved.items():
-            if val is not None:
-                os.environ[v] = val
+    audit = audit_cpu_sim_width1()
     gates, plan = audit["gate_is_finite_ops"], audit["plan"]
     assert gates == 0 and plan is not None and not plan["chained"], (
         "width-1 lowering still carries the bucket chain", audit)
@@ -705,7 +696,7 @@ def overlap_plan_microbench() -> None:
         "metric": "overlap_width1_chain_gates",
         "value": gates,
         "unit": "ops",
-        "vs_baseline": hvd_env.DEFAULT_OVERLAP_BUCKETS - 1,
+        "vs_baseline": schedule_plan.DEFAULT_CHAIN_DEPTH - 1,
         "plan": plan,
     }))
 

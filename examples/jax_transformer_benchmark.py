@@ -77,11 +77,6 @@ def main():
                     help="capture an XLA profiler trace of one timed "
                          "dispatch into DIR (view in XProf/TensorBoard; "
                          "rank 0 only — horovod_tpu.profiling.trace)")
-    ap.add_argument("--fused-norm", action="store_true",
-                    help="opt into the fused Pallas RMSNorm kernels "
-                         "(measured ~3.4 MFU pts SLOWER than XLA's native "
-                         "fusion at this geometry — docs/benchmarks.md; "
-                         "default is the plain jnp path)")
     ap.add_argument("--accumulate", type=int, default=1,
                     help="gradient-accumulation microbatches per step "
                          "(hvd.accumulate_gradients — the reference's "
@@ -111,7 +106,6 @@ def main():
                remat=args.remat,
                param_dtype=(jnp.bfloat16 if args.bf16_params
                             else jnp.float32),
-               fused_norm=True if args.fused_norm else None,
                # bf16 logits buffer (f32 softmax via the fused upcast below)
                logits_dtype=jnp.bfloat16)
     attn = None if args.no_flash else make_flash_attention(

@@ -60,7 +60,10 @@ import re
 import sys
 
 
-def build_step():
+def build_step(planner=None):
+    """``planner`` forces a chain depth for a comparison
+    (``AdaptivePlanner(default_depth=0)`` is the unchained program); None
+    audits what ships."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -81,7 +84,7 @@ def build_step():
     # combiner owns batching), each issued as soon as its gradient exists
     # (backward order) — the structure that WOULD overlap if the backend
     # kept the collectives separate.
-    opt = hvd.DistributedOptimizer(optax.sgd(0.01))
+    opt = hvd.DistributedOptimizer(optax.sgd(0.01), planner=planner)
 
     def step(params, opt_state, x, y):
         def loss_fn(p):
@@ -128,7 +131,7 @@ def audit_text(txt: str) -> dict:
     }
 
 
-def audit_cpu_sim() -> dict:
+def audit_cpu_sim(planner=None) -> dict:
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -136,7 +139,7 @@ def audit_cpu_sim() -> dict:
     import horovod_tpu as hvd
 
     hvd.init()
-    model, opt, step = build_step()
+    model, opt, step = build_step(planner)
     x = jnp.zeros((16, 1024))
     y = jnp.zeros((16,), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), x)
@@ -231,31 +234,26 @@ def assert_planner() -> int:
     branches on the CPU sim and fail loudly on any regression —
 
     * at the sim mesh's real width the adaptive default must keep the
-      depth-4 chain (gates present, >= DEFAULT_OVERLAP_BUCKETS surviving
+      depth-4 chain (gates present, >= DEFAULT_CHAIN_DEPTH surviving
       all-reduces);
     * at width 1 it must bypass the chain entirely (zero gates — the
       free-combining structure, so single-chip runs never pay for it).
 
-    Runs deviceless: ambient bucket overrides are stripped first (the
-    gate audits the SHIPPED default, not the local shell).
+    Runs deviceless.
     """
-    import os
-
-    for v in ("HOROVOD_OVERLAP_BUCKETS", "HVD_TPU_OVERLAP_BUCKETS"):
-        os.environ.pop(v, None)
-    from horovod_tpu.utils import env as _env
+    from horovod_tpu.ops.schedule_plan import DEFAULT_CHAIN_DEPTH
 
     wide = audit_cpu_sim()
     w1 = audit_cpu_sim_width1()
     failures = []
     plan_wide, plan_w1 = wide["plan"], w1["plan"]
     if not (plan_wide and plan_wide["chained"]
-            and plan_wide["chain_depth"] == _env.DEFAULT_OVERLAP_BUCKETS
+            and plan_wide["chain_depth"] == DEFAULT_CHAIN_DEPTH
             and plan_wide["planner"] == "adaptive"):
         failures.append(f"width>1 plan lost the default chain: {plan_wide}")
     if wide["gate_is_finite_ops"] == 0:
         failures.append("width>1 lowering carries no chain gates")
-    if wide["all_reduce_ops"] < _env.DEFAULT_OVERLAP_BUCKETS:
+    if wide["all_reduce_ops"] < DEFAULT_CHAIN_DEPTH:
         failures.append(
             f"chained all-reduces merged: {wide['all_reduce_ops']} survive")
     if not (plan_w1 and not plan_w1["chained"]

@@ -55,9 +55,8 @@ for _mod, _names in {
     ),
     "horovod_tpu.ops": (
         "AdaptivePlanner", "BucketPlan", "Compression", "ContextPlan",
-        "ContextWorkload", "GradientManifest",
-        "Planner", "StaticPlanner", "allgather", "allgather_async",
-        "allreduce",
+        "ContextWorkload", "GradientManifest", "allgather",
+        "allgather_async", "allreduce",
         "allreduce_async", "allreduce_sparse", "alltoall", "alltoall_async",
         "barrier", "batch_spec", "broadcast", "broadcast_async",
         "context_plan",

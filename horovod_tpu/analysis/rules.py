@@ -557,63 +557,6 @@ class StaleTopologyConstant(Rule):
         return out
 
 
-class HandTunedOverlapKnob(Rule):
-    """Code that WRITES the overlap-bucket env knob.
-
-    Since the trace-time schedule planner (ops/schedule_plan.py) the
-    chained-bucket depth is chosen per program from width, gradient
-    manifest, and device headroom; a hand-set ``HOROVOD_OVERLAP_BUCKETS``
-    / ``HVD_TPU_OVERLAP_BUCKETS`` pins the legacy StaticPlanner and
-    silently opts the job out of the width-1 bypass and the
-    headroom-deficit degradation (the r5 regression and the 468M OOM both
-    trace to exactly this kind of hand tuning rotting into convention).
-    Reading the knob is fine; writing it from code is almost never what a
-    new job wants.  Test fixtures that pin the legacy branch on purpose
-    carry ``# hvd-lint: disable=HVD107`` — that is the sanctioned idiom,
-    not a recommendation.
-    """
-
-    code = "HVD107"
-    name = "hand-tuned-overlap-knob"
-    hint = ("let the AdaptivePlanner choose the chain depth (it bypasses "
-            "at width 1 and degrades under headroom pressure); if you "
-            "really need the legacy static behavior, pass overlap_buckets= "
-            "or planner=StaticPlanner(...) in code, and mark deliberate "
-            "test fixtures with `# hvd-lint: disable=HVD107`")
-
-    _KNOBS = frozenset({"HOROVOD_OVERLAP_BUCKETS",
-                        "HVD_TPU_OVERLAP_BUCKETS"})
-    _SET_CALLS = frozenset({"setenv", "putenv", "setdefault"})
-
-    def _knob_const(self, node: ast.expr | None) -> bool:
-        return (isinstance(node, ast.Constant)
-                and isinstance(node.value, str)
-                and node.value in self._KNOBS)
-
-    def run(self, ctx: Context) -> list[Finding]:
-        out: list[Finding] = []
-        for node in ast.walk(ctx.module):
-            if isinstance(node, ast.Assign):
-                # os.environ["HOROVOD_OVERLAP_BUCKETS"] = ...
-                for t in node.targets:
-                    if isinstance(t, ast.Subscript) and \
-                            self._knob_const(t.slice):
-                        out.append(self.finding(node, (
-                            "environment write hand-sets the overlap-"
-                            "bucket knob: the schedule planner already "
-                            "adapts chain depth to width and headroom")))
-            elif isinstance(node, ast.Call) and \
-                    call_name(node) in self._SET_CALLS:
-                # monkeypatch.setenv(...) / os.putenv(...) /
-                # os.environ.setdefault(...)
-                if node.args and self._knob_const(node.args[0]):
-                    out.append(self.finding(node, (
-                        f"'{call_name(node)}' hand-sets the overlap-bucket "
-                        f"knob: the schedule planner already adapts chain "
-                        f"depth to width and headroom")))
-        return out
-
-
 class HandTunedContextLayout(Rule):
     """Hand-set long-context layout or flash kernel tiles.
 
@@ -863,7 +806,6 @@ RULES: list[Rule] = [
     ImpureJitStep(),
     UnknownAxisName(),
     StaleTopologyConstant(),
-    HandTunedOverlapKnob(),
     HandTunedContextLayout(),
     UnbucketedServeShape(),
     CollectiveBeforeReconfigure(),

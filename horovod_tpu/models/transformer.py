@@ -18,9 +18,8 @@ import dataclasses
 from typing import Any, Callable
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
-
-from horovod_tpu.ops.rmsnorm import FusedRMSNorm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,12 +70,6 @@ class TransformerConfig:
     # traffic at large vocab; bfloat16 halves it — upcast inside your loss
     # (the cast fuses into the softmax chain, nothing f32 is materialized).
     logits_dtype: Any = jnp.float32
-    # RMSNorm implementation: False/None (default) = pure jnp — measured
-    # FASTER than the fused Pallas kernels inside the block (XLA fuses
-    # the norm with neighboring work; ops/rmsnorm.py docstring has the
-    # numbers).  True opts into the kernels.  Same parameter structure
-    # either way.
-    fused_norm: bool | None = None
     # Rematerialize each block in the backward pass (jax.checkpoint):
     # activation memory drops from O(L) layer working sets to one layer +
     # L boundary tensors — the FLOPs-for-HBM trade long-context training
@@ -94,6 +87,27 @@ class TransformerConfig:
     # The ContextPlan (ops/schedule_plan.plan_context) that decided the
     # layout, kernel tiles, and remat policy for this model.
     context_plan: Any = None
+
+
+class RMSNorm(nn.Module):
+    """RMSNorm over the last axis with one ``scale`` vector (``nn.RMSNorm``'s
+    parameter structure): statistics in f32, output in ``dtype``.  Plain
+    ``jnp`` on purpose -- XLA fuses the norm with its neighbours (residual
+    add, casts, matmul epilogues), which a kernel's boundary would undo."""
+
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    epsilon: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           self.param_dtype)
+        x = x.astype(self.dtype)
+        xf = x.astype(jnp.float32)
+        inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                            + self.epsilon)
+        return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)
 
 
 def rope(x, positions, theta: float):
@@ -183,10 +197,9 @@ class Attention(nn.Module):
         def rotated(name):
             y = proj(name)(x)
             if cfg.qk_norm:     # over the whole projection, all heads as one
-                y = FusedRMSNorm(
+                y = RMSNorm(
                     dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    epsilon=cfg.norm_eps, use_fused=cfg.fused_norm,
-                    name=f"{name}_norm")(
+                    epsilon=cfg.norm_eps, name=f"{name}_norm")(
                     y.reshape(*y.shape[:-2], -1)).reshape(y.shape)
             return rope(y, positions, cfg.rope_theta)
 
@@ -200,8 +213,6 @@ class Attention(nn.Module):
             # cache.  K/V at a position depend only on that position's token
             # and rotary phase, so cached entries match what a full forward
             # pass would compute there.
-            import jax
-
             k_cache, v_cache, lengths = cache
             upd = lambda c, u, i: jax.lax.dynamic_update_slice(  # noqa: E731
                 c, u, (i, 0, 0))
@@ -242,9 +253,9 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache=None, return_kv=False):
         cfg = self.cfg
-        norm = lambda name: FusedRMSNorm(  # noqa: E731
+        norm = lambda name: RMSNorm(  # noqa: E731
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            epsilon=cfg.norm_eps, use_fused=cfg.fused_norm, name=name)
+            epsilon=cfg.norm_eps, name=name)
         y = norm("attn_norm")(x)
         kv = None
         if cache is not None or return_kv:
@@ -351,9 +362,8 @@ class Transformer(nn.Module):
                 kvs.append(kv)
             else:
                 x = block_cls(cfg, name=f"layer_{i}")(x, positions)
-        x = FusedRMSNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                         epsilon=cfg.norm_eps, use_fused=cfg.fused_norm,
-                         name="final_norm")(x)
+        x = RMSNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    epsilon=cfg.norm_eps, name="final_norm")(x)
         # Head matmul in the compute dtype (bf16 hits the MXU at full rate;
         # f32 params, XLA accumulates in f32); logits upcast for the loss —
         # the standard LLM-trainer convention.  The f32 head matmul this

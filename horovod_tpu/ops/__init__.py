@@ -18,8 +18,6 @@ from horovod_tpu.ops.schedule_plan import (  # noqa: F401
     ContextPlan,
     ContextWorkload,
     GradientManifest,
-    Planner,
-    StaticPlanner,
     context_plan,
     overlap_plan,
     plan_context,

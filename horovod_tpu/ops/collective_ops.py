@@ -351,8 +351,7 @@ def quantized_grouped_allreduce(tensors: Sequence, errors: Sequence | None = Non
 CHAIN_GATE_SCOPE = "hvd_chain_gate"
 
 
-def _chained_allreduce(vals: list, axes, n_buckets: int,
-                       bounds: Sequence[int] | None = None) -> list:
+def _chained_allreduce(vals: list, axes, n_buckets: int) -> list:
     """Per-tensor psums in ``n_buckets`` dependency-chained groups, reverse
     tree order (≈ backward availability: output-side layers' gradients
     exist first).
@@ -392,16 +391,9 @@ def _chained_allreduce(vals: list, axes, n_buckets: int,
     device headroom and degrades the depth — or bypasses the chain — when
     it would not fit, so chain memory pressure is a planner input, not a
     hand-tuning chore (docs/troubleshooting.md OOM entry).
-
-    ``bounds`` (from ``BucketPlan.bounds``) overrides the default
-    equal-count bucket split with explicit boundaries over the
-    reverse-order index — how a custom planner shapes buckets by bytes.
     """
     n = len(vals)
-    if bounds is None:
-        bounds = np.linspace(0, n, n_buckets + 1).astype(int)
-    else:
-        bounds = np.asarray(bounds, dtype=int)
+    bounds = np.linspace(0, n, n_buckets + 1).astype(int)
     out: dict[int, jax.Array] = {}
     gate = None
     rev = list(range(n))[::-1]
@@ -466,24 +458,22 @@ def overlap_compiler_options() -> dict:
 def grouped_allreduce(tensors: Sequence, average: bool = True,
                       compression=Compression.none,
                       threshold_bytes: int | None = None,
-                      overlap_buckets: int | None = None,
                       planner=None) -> list:
     """Fused allreduce of many tensors (reference fusion-buffer semantics,
     operations.cc:1807-1842).  In-mesh on a single axis: one psum per
     tensor, dependency-chained into buckets per the trace-time schedule
-    planner (ops/schedule_plan.py) — the default ``AdaptivePlanner``
-    chains at real data width with slack headroom, bypasses the chain at
-    width 1 (psum is identity there), and degrades the depth under
-    device-memory pressure; ``overlap_buckets=`` or a set
-    ``HOROVOD_OVERLAP_BUCKETS`` pins the legacy static semantics (0 =
-    free-combining, N = N chained buckets — see ``_chained_allreduce``),
-    and ``planner=`` (a schedule_plan.Planner) replaces the policy
-    outright.  At width 1 the plan also lists the gradients to materialise
-    before they are used (``BucketPlan.materialized``); each of those comes
-    back behind its own ``optimization_barrier``, the identity on values,
-    which keeps XLA from fusing its consumer (the optimizer's update) into
-    the matmul that produces it.  The decision is observable via
-    ``hvd.overlap_plan()``.
+    planner (ops/schedule_plan.py), which chains at real data width with
+    slack headroom, bypasses the chain at width 1 (psum is identity there),
+    and degrades the depth under device-memory pressure (see
+    ``_chained_allreduce`` for what a chain is).  At width 1 the plan also
+    lists the gradients to materialise before they are used
+    (``BucketPlan.materialized``); each of those comes back behind its own
+    ``optimization_barrier``, the identity on values, which keeps XLA from
+    fusing its consumer (the optimizer's update) into the matmul that
+    produces it.  The decision is observable via ``hvd.overlap_plan()``.
+    ``planner=`` is the test seam ops/schedule_plan.py describes: an object
+    with ``plan(manifest, width, headroom_mb) -> BucketPlan`` that stands
+    in for the planner.
     ``threshold_bytes`` is ignored on this path (docs/tensor-fusion.md).
     Hierarchical (multi-axis) meshes, the eager path, and the int8 path
     in any context: flat ``threshold_bytes``-bounded buckets
@@ -514,12 +504,10 @@ def grouped_allreduce(tensors: Sequence, average: bool = True,
             from horovod_tpu.ops import schedule_plan
 
             plan = schedule_plan.plan_overlap(
-                [c for c, _ in comp], width=denom,
-                override=overlap_buckets, planner=planner)
+                [c for c, _ in comp], width=denom, planner=planner)
             if plan.chained:
                 reduced = _chained_allreduce([c for c, _ in comp], axes,
-                                             plan.chain_depth,
-                                             bounds=plan.bounds)
+                                             plan.chain_depth)
             else:
                 with jax.named_scope(
                         profiling.bucket_scope(profiling.BUCKET_ALL)):

@@ -1,28 +1,28 @@
-"""Trace-time overlap schedule planning for the compiled allreduce path.
+"""Trace-time schedule planning for the compiled allreduce path.
 
-Round 5 recreated the reference's defining runtime property — comm/compute
-overlap (reference horovod/common/operations.cc fusion + hook architecture)
-— by dependency-chaining the gradient bucket psums
-(ops/collective_ops.py:_chained_allreduce).  But it shipped the chain as a
-static default (``HOROVOD_OVERLAP_BUCKETS=4``), engaged unconditionally,
-and the round-5 measurements show exactly where a static default is wrong:
+``grouped_allreduce`` issues one psum per gradient.  Left alone, XLA's
+all-reduce combiner merges them into one tuple all-reduce that can only run
+after all of backward; dependency-chaining them into buckets
+(ops/collective_ops.py:_chained_allreduce) lets the early buckets' reductions
+run during the rest of backward, at the price of longer gradient live ranges.
+Whether that trade pays differs by program, so it is decided **per traced
+program**, here, from what is static at trace time: tensor shapes and dtypes
+(the :class:`GradientManifest`), the data-parallel width (``lax.axis_size``
+is a concrete Python int under trace), and a device-memory headroom estimate
+(:func:`probe_headroom_mb`).  :class:`AdaptivePlanner` maps those to a
+:class:`BucketPlan` -- the chain's depth, or the free-combining bypass -- and
+``grouped_allreduce`` executes what the plan says.  No argument and no
+environment name selects another policy: the same program at the same width
+with the same headroom gets the same plan.
 
-* at data-parallel **width 1** ``psum`` is the identity — there is nothing
-  to overlap, yet the chain still constrains the scheduler (−4.3% on the
-  single-chip ResNet headline, 2662 → 2547 img/s/chip, BENCH r04→r05);
-* the chain pulls reductions into backward, extending gradient live ranges
-  and raising peak HBM — the 468M transformer rows OOM by 79 MB under the
-  default and had to hand-set ``HOROVOD_OVERLAP_BUCKETS=0``
-  (docs/benchmarks.md round 5).
-
-This module decides the chain **per traced program** instead.  Everything a
-good decision needs is static at trace time: tensor shapes/dtypes (the
-:class:`GradientManifest`), the data-parallel width (``lax.axis_size`` is a
-concrete Python int under trace), and a device-memory headroom estimate
-(:func:`probe_headroom_mb`).  A :class:`Planner` maps those to a
-:class:`BucketPlan` — chain depth, optional bucket boundaries, or the
-free-combining bypass — and ``grouped_allreduce`` executes whatever the
-plan says.
+* At data-parallel **width 1** ``psum`` is the identity: there is nothing to
+  overlap and a chain only constrains the scheduler (-4.3% img/s on the
+  single-chip ResNet, docs/benchmarks.md round 5).  The planner bypasses it.
+* The chain raises peak HBM (a 468M transformer ran out of memory by 79 MB
+  under a depth-4 chain and fit without it, same source).  The planner
+  estimates the chain's extra live bytes and halves the depth, down to the
+  bypass, until the estimate fits the headroom.
+* With real width and slack headroom it keeps :data:`DEFAULT_CHAIN_DEPTH`.
 
 Width 1 is not "nothing to decide".  With no collective between a gradient
 and its update, XLA:TPU fuses the optimizer's arithmetic into the matmul
@@ -37,29 +37,15 @@ so no gradient lives longer than it does across chips -- and fusion cannot
 cross it.  :func:`materialized_leaves` is the rule; it reads the manifest
 alone.
 
-Two planners ship:
-
-* :class:`AdaptivePlanner` (the default when no override is present):
-  bypasses the chain at width 1 and materialises gradients there,
-  estimates the chain's extra live-range
-  bytes and degrades the depth (halving, down to bypass) when the estimate
-  exceeds headroom, and keeps the round-5 depth-4 chain on configs with
-  real width and slack headroom.
-* :class:`StaticPlanner`: the legacy env-knob semantics, bit-for-bit — an
-  explicit ``overlap_buckets=`` argument or a set ``HOROVOD_OVERLAP_BUCKETS``
-  / ``HVD_TPU_OVERLAP_BUCKETS`` env var routes here and wins exactly as
-  documented since round 5.
-
-The interface is the extension point for ROADMAP items 2 and 4: a
-control-plane-scale planner can shard the manifest across coordinator
-groups, and a ring-attention planner can interleave attention collectives
-into the same chain — both by returning a richer ``BucketPlan`` (explicit
-``bounds``) from a custom ``Planner`` passed to ``DistributedOptimizer``
-or ``grouped_allreduce``.
+``DistributedOptimizer`` and ``grouped_allreduce`` take ``planner=``, any
+object with ``plan(manifest, width, headroom_mb) -> BucketPlan``.  It is the
+seam through which tests and ``examples/overlap_audit.py`` force a depth
+(``AdaptivePlanner(default_depth=0)``) to compare programs; no shipped code
+path passes one.
 
 Every decision is observable: :func:`overlap_plan` returns the last plan,
-rank 0 logs one line per distinct decision, and — when the native engine
-is up with ``HOROVOD_TIMELINE`` set — an ``OVERLAP_PLAN`` instant lands on
+rank 0 logs one line per distinct decision, and -- when the native engine
+is up with ``HOROVOD_TIMELINE`` set -- an ``OVERLAP_PLAN`` instant lands on
 the timeline next to the CACHE_HIT/NEGOTIATED markers
 (core/src/timeline.cc).
 """
@@ -77,8 +63,12 @@ from horovod_tpu.utils import env
 
 _log = logging.getLogger("horovod_tpu")
 
+# Buckets in the chain where nothing argues for fewer (real width, headroom
+# unknown or ample).
+DEFAULT_CHAIN_DEPTH = 4
+
 # Fraction of the total gradient bytes the dependency chain keeps extra-live
-# at peak, per unit of (depth-1)/depth.  Calibrated against the round-5
+# at peak, per unit of (depth-1)/depth.  Calibrated against one
 # measurement: the 468M transformer carries ~936 MB of bf16 gradients and
 # OOMed by 79 MB under the depth-4 chain — 936 MB * (3/4) * (1/8) ≈ 88 MB,
 # a deliberately conservative (over-)estimate of the measured deficit.  The
@@ -127,11 +117,7 @@ class BucketPlan:
     """One planner decision for one traced allreduce group.
 
     ``chain_depth`` <= 1 (or a single tensor) means the free-combining
-    bypass: plain per-tensor psums whose batching XLA's combiner owns —
-    the round-4 structure.  ``bounds``, when set, are explicit bucket
-    boundaries (len ``chain_depth + 1``, ascending, over the reverse-order
-    tensor index) for planners that shape buckets by bytes instead of the
-    default equal-count split."""
+    bypass: plain per-tensor psums whose batching XLA's combiner owns."""
 
     planner: str
     chain_depth: int
@@ -141,7 +127,6 @@ class BucketPlan:
     headroom_mb: float | None
     chain_extra_bytes: int
     reason: str
-    bounds: tuple[int, ...] | None = None
     # Width 1 only: indices (tensor order) of the gradients held behind a
     # per-leaf ``optimization_barrier`` before the update, and their bytes
     # (:func:`materialized_leaves`); empty wherever a collective already
@@ -217,65 +202,26 @@ def chain_extra_bytes(total_bytes: int, depth: int) -> int:
     return int(total_bytes * CHAIN_LIVE_FRACTION * (depth - 1) / depth)
 
 
-class Planner:
-    """Interface: manifest + width + headroom -> :class:`BucketPlan`.
-
-    Implementations must be deterministic functions of their arguments
-    (the plan is made under trace on every rank of an SPMD job and must
-    agree everywhere).  This is the pluggable extension point ROADMAP
-    items 2 and 4 build on — pass an instance via
-    ``DistributedOptimizer(planner=...)`` or
-    ``grouped_allreduce(planner=...)``.
-    """
-
-    name = "abstract"
-
-    def plan(self, manifest: GradientManifest, width: int,
-             headroom_mb: float | None) -> BucketPlan:
-        raise NotImplementedError
-
-
-class StaticPlanner(Planner):
-    """Legacy round-5 semantics: a fixed bucket count, engaged whenever
-    depth > 1 and there is more than one tensor — regardless of width or
-    headroom.  ``HOROVOD_OVERLAP_BUCKETS`` / explicit ``overlap_buckets=``
-    route here, bit-for-bit what they did before the planner existed."""
-
-    name = "static"
-
-    def __init__(self, n_buckets: int, source: str = "overlap_buckets"):
-        self.n_buckets = int(n_buckets)
-        self.source = source
-
-    def plan(self, manifest, width, headroom_mb):
-        depth = self.n_buckets if self.n_buckets > 1 else 0
-        if manifest.count <= 1:
-            depth = 0
-        return BucketPlan(
-            planner=self.name, chain_depth=depth, width=width,
-            tensor_count=manifest.count, total_bytes=manifest.total_bytes,
-            headroom_mb=headroom_mb,
-            chain_extra_bytes=chain_extra_bytes(manifest.total_bytes, depth),
-            reason=f"explicit override via {self.source}="
-                   f"{self.n_buckets}")
-
-
-class AdaptivePlanner(Planner):
-    """The shipping default: chain only where it can pay for itself.
+class AdaptivePlanner:
+    """Manifest + width + headroom -> :class:`BucketPlan`: chain only where
+    it can pay for itself.
 
     * width 1 -> bypass (psum is identity; chaining only constrains the
-      scheduler — the r5 −4.3% ResNet regression);
+      scheduler), and the large gradients materialised before the update;
     * headroom deficit -> halve the depth until the estimated extra
-      live-range bytes fit, down to bypass (the 468M 79 MB OOM runs with
-      no hand-set env);
-    * real width, slack headroom -> today's depth-4 chain, unchanged.
+      live-range bytes fit, down to bypass;
+    * real width, slack headroom -> a chain of ``default_depth`` buckets.
+
+    A deterministic function of its arguments: the plan is made under trace
+    on every rank of an SPMD job and must agree everywhere.
+    ``default_depth`` is for tests and the overlap audit, which compare the
+    programs of different depths; everything else constructs it bare.
     """
 
     name = "adaptive"
 
-    def __init__(self, default_depth: int | None = None):
-        self.default_depth = (env.DEFAULT_OVERLAP_BUCKETS
-                              if default_depth is None else int(default_depth))
+    def __init__(self, default_depth: int = DEFAULT_CHAIN_DEPTH):
+        self.default_depth = int(default_depth)
 
     def plan(self, manifest, width, headroom_mb):
         def mk(depth, reason):
@@ -411,30 +357,13 @@ _last_plan: BucketPlan | None = None
 _logged_keys: set = set()
 
 
-def plan_overlap(tensors, width: int, override: int | None = None,
-                 planner: Planner | None = None) -> BucketPlan:
-    """Make (and record) the bucket plan for one traced allreduce group.
-
-    Resolution order — most explicit wins:
-
-    1. a ``planner`` instance passed in code;
-    2. an explicit ``overlap_buckets=`` argument (``override``) ->
-       :class:`StaticPlanner`, legacy semantics;
-    3. a set ``HOROVOD_OVERLAP_BUCKETS`` / ``HVD_TPU_OVERLAP_BUCKETS``
-       env var -> :class:`StaticPlanner` (malformed values degrade to the
-       documented default-with-warning, unchanged from round 5);
-    4. :class:`AdaptivePlanner`.
-    """
+def plan_overlap(tensors, width: int, planner=None) -> BucketPlan:
+    """Make (and record) the bucket plan for one traced allreduce group:
+    :class:`AdaptivePlanner`'s, from the tensors, the width and
+    :func:`probe_headroom_mb`.  ``planner`` (the seam the module docstring
+    describes) stands in for it when given."""
     if planner is None:
-        if override is not None:
-            planner = StaticPlanner(override, source="overlap_buckets")
-        else:
-            env_depth = env.overlap_buckets_override()
-            if env_depth is not None:
-                planner = StaticPlanner(env_depth,
-                                        source="HOROVOD_OVERLAP_BUCKETS")
-            else:
-                planner = AdaptivePlanner()
+        planner = AdaptivePlanner()
     manifest = GradientManifest.from_tensors(tensors)
     plan = dataclasses.replace(
         planner.plan(manifest, width, probe_headroom_mb()),
@@ -447,7 +376,7 @@ def overlap_plan() -> dict | None:
     """The most recent :class:`BucketPlan` as a dict (``hvd.overlap_plan()``),
     or None before any compiled allreduce group has been planned.  Keys:
     planner, chain_depth, chained, width, tensor_count, total_bytes,
-    headroom_mb, chain_extra_bytes, bounds, reason, materialized_leaves and
+    headroom_mb, chain_extra_bytes, reason, materialized_leaves and
     materialized_bytes (the gradients held behind a barrier before the
     update at width 1), and where the headroom came from
     (:func:`headroom_record`): headroom_source, headroom_probe,
@@ -459,8 +388,7 @@ def overlap_plan() -> dict | None:
 def _record(plan: BucketPlan) -> None:
     global _last_plan
     key = (plan.planner, plan.chain_depth, plan.width, plan.tensor_count,
-           plan.total_bytes, plan.headroom_mb, plan.bounds,
-           plan.materialized)
+           plan.total_bytes, plan.headroom_mb, plan.materialized)
     with _plan_lock:
         _last_plan = plan
         fresh = key not in _logged_keys
@@ -545,7 +473,7 @@ class ContextWorkload:
     """Static description of one long-context training workload — every
     field is a Python int/bool at trace time, so the plan is a
     deterministic function of (workload, width, headroom) on every rank
-    (the SPMD discipline :class:`Planner` documents)."""
+    (the SPMD discipline :class:`AdaptivePlanner` documents)."""
 
     seq_len: int
     num_heads: int

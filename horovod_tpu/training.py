@@ -67,7 +67,6 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
                          compression=Compression.none,
                          threshold_bytes: int | None = None,
                          sharded_state: bool = False,
-                         overlap_buckets: int | None = None,
                          planner=None,
                          ) -> optax.GradientTransformation:
     """Wrap ``optimizer`` so updates see globally-averaged gradients.
@@ -102,28 +101,25 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
     gradients to materialise before the inner update (each behind its own
     ``optimization_barrier``, values unchanged), so XLA does not fuse the
     update into the epilogue of the matmul that produces the gradient
-    (docs/tensor-fusion.md).  ``overlap_buckets`` (or a set
-    ``HOROVOD_OVERLAP_BUCKETS``; 0 disables, N pins N buckets) overrides
-    the planner with the legacy static semantics; ``planner=`` (a
-    ``schedule_plan.Planner``) replaces the policy — the extension point
-    for custom schedules.  Pass
+    (docs/tensor-fusion.md).  Nothing else selects the schedule: no
+    argument and no environment name overrides the planner.  ``planner=``
+    is the seam tests and ``examples/overlap_audit.py`` use to force a
+    depth (``AdaptivePlanner(default_depth=0)``) and compare programs: any
+    object with ``plan(manifest, width, headroom_mb) -> BucketPlan``.  Pass
     ``compiler_options=hvd.overlap_compiler_options()`` to ``jax.jit`` to
     make the chained all-reduces asynchronous
     (collective_ops._chained_allreduce); inspect the decision with
     ``hvd.overlap_plan()``.
     """
     if sharded_state:
-        # overlap_buckets=0 means "disabled" and is compatible (a user
-        # mirroring HOROVOD_OVERLAP_BUCKETS=0 into code must not error).
         if (compression is not Compression.none
                 or threshold_bytes is not None
-                or planner is not None
-                or overlap_buckets not in (None, 0)):
+                or planner is not None):
             raise ValueError(
                 "sharded_state=True uses a reduce-scatter of the flat "
-                "gradient vector; compression/threshold_bytes/"
-                "overlap_buckets/planner do not apply to that path — drop "
-                "them or use the replicated optimizer.")
+                "gradient vector; compression/threshold_bytes/planner do "
+                "not apply to that path — drop them or use the replicated "
+                "optimizer.")
         from horovod_tpu.parallel.zero import zero_optimizer
 
         return zero_optimizer(optimizer, average=average)
@@ -162,8 +158,7 @@ def DistributedOptimizer(optimizer: optax.GradientTransformation,
         with jax.named_scope(profiling.ALLREDUCE):
             reduced = collective_ops.grouped_allreduce(
                 leaves, average=average, compression=compression,
-                threshold_bytes=threshold_bytes,
-                overlap_buckets=overlap_buckets, planner=planner)
+                threshold_bytes=threshold_bytes, planner=planner)
         grads = jax.tree.unflatten(treedef, reduced)
         with jax.named_scope(profiling.OPTIMIZER):
             updates, inner = optimizer.update(grads, state.inner, params,

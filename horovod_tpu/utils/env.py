@@ -105,14 +105,6 @@ aliases; the TPU-specific defaults differ where the hardware does:
   30000), and the member-knock silence after which a standby concludes
   its primary is wedged — not merely slow — and promotes (default 1000;
   this, not EOF, bounds recovery from a SIGSTOP'd aggregator).
-* ``HOROVOD_OVERLAP_BUCKETS`` — chained-bucket OVERRIDE for the compiled
-  single-axis allreduce path.  Unset (the default): the AdaptivePlanner
-  (ops/schedule_plan.py) picks the chain depth at trace time from the
-  data-parallel width, the gradient manifest, and the device-memory
-  headroom — bypassing the chain at width 1 and degrading depth under
-  headroom pressure.  Any set value pins the legacy StaticPlanner
-  semantics exactly (0 = free-combining, N = N chained buckets),
-  bit-for-bit what rounds 5–8 shipped (docs/tensor-fusion.md).
 * ``HVD_TPU_DEVICE_HEADROOM_MB`` — device-memory headroom estimate (MB)
   the schedule planner budgets against, overriding the
   ``device.memory_stats()`` probe.  Needed on AOT/CPU/sim paths (no
@@ -387,7 +379,7 @@ def standby_rank() -> int:
     -1 for the default policy (lowest non-coordinator rank that advertised
     a standby listen port).  Read natively in core/src/controller.cc; this
     accessor exists for tests and tooling.  Malformed values degrade to the
-    default policy — same contract as :func:`overlap_buckets`."""
+    default policy."""
     raw = _get("STANDBY")
     if raw in (None, ""):
         return -1
@@ -406,62 +398,6 @@ def reconfig_timeout_ms() -> float:
     raw = _get("RECONFIG_TIMEOUT_MS")
     return float(raw) if raw not in (None, "") \
         else DEFAULT_RECONFIG_TIMEOUT_MS
-
-
-DEFAULT_OVERLAP_BUCKETS = 4
-
-
-def overlap_buckets() -> int:
-    """Number of chained gradient buckets on the compiled single-axis
-    allreduce path (``HOROVOD_OVERLAP_BUCKETS`` / ``HVD_TPU_OVERLAP_BUCKETS``;
-    0 disables).  Chaining keeps the bucket all-reduces uncombinable so the
-    TPU backend can schedule the early ones DURING backward — the
-    comm/compute overlap the reference's hook architecture exists for
-    (reference horovod/common/operations.cc:203-216,
-    horovod/torch/__init__.py:83-112); pair with
-    ``hvd.overlap_compiler_options()`` at jit time for async execution
-    (ops/collective_ops.py:_chained_allreduce, examples/overlap_audit.py).
-
-    A malformed value (non-integer, or negative) falls back to the default
-    with a warning instead of crashing the job at its first compiled step —
-    launch-script typos in a knob this deep in the stack should degrade,
-    not abort."""
-    raw = _get("OVERLAP_BUCKETS")
-    if not raw:
-        return DEFAULT_OVERLAP_BUCKETS
-    try:
-        value = int(raw)
-        if value < 0:
-            raise ValueError("negative bucket count")
-    except ValueError:
-        import warnings
-
-        name = ("HOROVOD_OVERLAP_BUCKETS"
-                if "HOROVOD_OVERLAP_BUCKETS" in os.environ
-                else "HVD_TPU_OVERLAP_BUCKETS")
-        warnings.warn(
-            f"{name}={raw!r} is not a non-negative integer; falling back "
-            f"to the default ({DEFAULT_OVERLAP_BUCKETS})",
-            RuntimeWarning, stacklevel=2)
-        return DEFAULT_OVERLAP_BUCKETS
-    return value
-
-
-def overlap_buckets_override() -> int | None:
-    """The explicitly-requested chained-bucket count, or None when the env
-    carries no override.
-
-    Since the schedule planner (ops/schedule_plan.py) the bucket env vars
-    are an OVERRIDE, not the default: unset means "let the AdaptivePlanner
-    choose from width/manifest/headroom", while any set value — including
-    0 — pins the legacy StaticPlanner semantics bit-for-bit.  A set-but-
-    malformed value still degrades to :data:`DEFAULT_OVERLAP_BUCKETS` with
-    the :func:`overlap_buckets` warning (the typo'd launch script gets
-    round-5 behavior, not a crash and not a silently different plan)."""
-    raw = _get("OVERLAP_BUCKETS")
-    if not raw:
-        return None
-    return overlap_buckets()
 
 
 def ckpt_async() -> bool:

@@ -108,13 +108,17 @@ def test_chained_allreduce_matches_uncained_and_isolates_nonfinite(hvd):
     xs = [_per_chip_values(hvd, (8,), jnp.float32, seed=40 + i)
           for i in range(6)]
 
+    # depths 3 and 0, forced through the planner seam
+    chain3 = hvd.AdaptivePlanner(default_depth=3)
+
     def step_chain(*vs):
         return tuple(hvd.grouped_allreduce(list(vs), average=False,
-                                           overlap_buckets=3))
+                                           planner=chain3))
 
     def step_plain(*vs):
-        return tuple(hvd.grouped_allreduce(list(vs), average=False,
-                                           overlap_buckets=0))
+        return tuple(hvd.grouped_allreduce(
+            list(vs), average=False,
+            planner=hvd.AdaptivePlanner(default_depth=0)))
 
     specs = tuple(P("hvd") for _ in xs)
     a = hvd.shard(step_chain, in_specs=specs, out_specs=specs)(*xs)
@@ -130,7 +134,7 @@ def test_chained_allreduce_matches_uncained_and_isolates_nonfinite(hvd):
 
     def step_empty(*vs):
         return tuple(hvd.grouped_allreduce(list(vs), average=False,
-                                           overlap_buckets=3))
+                                           planner=chain3))
 
     out7 = hvd.shard(step_empty, in_specs=specs7, out_specs=specs7)(
         *with_empty)

@@ -305,49 +305,22 @@ def test_hvd106_exempt_when_refreshed_in_on_reconfigure_callback():
     """) == []
 
 
-# ---------------------------------------------------------------------------
-# HVD107 — hand-tuned overlap knob (the schedule planner owns the chain)
-# ---------------------------------------------------------------------------
+def test_documented_rule_table_is_the_registry():
+    # The catalog in docs/static_analysis.md lists exactly the registered
+    # codes, in order; the code between 106 and 108 was retired with the
+    # knob it guarded (PR 29) and is not reused.
+    import re
 
-def test_hvd107_env_assignment_and_setdefault():
-    assert codes("""
-        import os
+    from horovod_tpu.analysis.rules import RULES
 
-        os.environ["HOROVOD_OVERLAP_BUCKETS"] = "4"
-        os.environ.setdefault("HVD_TPU_OVERLAP_BUCKETS", "0")
-    """) == ["HVD107", "HVD107"]
-
-
-def test_hvd107_monkeypatch_setenv():
-    assert codes("""
-        def test_thing(monkeypatch):
-            monkeypatch.setenv("HOROVOD_OVERLAP_BUCKETS", "0")
-    """) == ["HVD107"]
-
-
-def test_hvd107_clean_other_knobs_and_reads():
-    # Reading the knob, deleting it, and setting unrelated vars is fine —
-    # only SETTING the overlap knob rots into hand-tuned cargo culting.
-    assert codes("""
-        import os
-
-        n = os.environ.get("HOROVOD_OVERLAP_BUCKETS")
-        os.environ.pop("HOROVOD_OVERLAP_BUCKETS", None)
-        os.environ["HOROVOD_CYCLE_TIME"] = "3.5"
-
-        def test_thing(monkeypatch):
-            monkeypatch.delenv("HOROVOD_OVERLAP_BUCKETS", raising=False)
-            monkeypatch.setenv("HVD_TPU_DEVICE_HEADROOM_MB", "3")
-    """) == []
-
-
-def test_hvd107_suppressible_for_legacy_fixtures():
-    # In-repo legacy-branch fixtures (tests pinning StaticPlanner
-    # semantics) stay, exempted line by line — visible, not normalized.
-    assert codes("""
-        def test_legacy(monkeypatch):
-            monkeypatch.setenv("HOROVOD_OVERLAP_BUCKETS", "0")  # hvd-lint: disable=HVD107
-    """) == []
+    registered = [r.code for r in RULES]
+    assert registered == [f"HVD{n}" for n in
+                          (101, 102, 103, 104, 105, 106, 108, 109, 110)]
+    doc = os.path.join(os.path.dirname(__file__), os.pardir, "docs",
+                       "static_analysis.md")
+    with open(doc) as f:
+        documented = re.findall(r"^\| (HVD\d+) \|", f.read(), re.M)
+    assert documented == registered
 
 
 # ---------------------------------------------------------------------------

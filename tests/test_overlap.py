@@ -13,9 +13,10 @@ start/done pairs and continuation fusions on the real v5e backend —
 examples/overlap_audit.py, docs/benchmarks.md round 5.
 
 These tests pin both sides on the CPU sim: the shipped default keeps the
-bucket all-reduces split and interleaved; disabling the chain
-(HOROVOD_OVERLAP_BUCKETS=0) reproduces the round-4 single-merged-AR
-structure, so a future XLA that changes either behavior flips loudly.
+bucket all-reduces split and interleaved; the same step without the chain
+(``planner=AdaptivePlanner(default_depth=0)``) reproduces the round-4
+single-merged-AR structure, so a future XLA that changes either behavior
+flips loudly.
 
 Round 9: the chain decision moved into the trace-time schedule planner
 (ops/schedule_plan.py), so BOTH planner branches are pinned here — the
@@ -32,24 +33,12 @@ import pytest
 
 @pytest.fixture(scope="module")
 def audit():
-    import os
-
     import horovod_tpu as hvd
 
     hvd.init()
-    # Pin the SHIPPED default: an ambient HOROVOD_OVERLAP_BUCKETS /
-    # HVD_TPU_OVERLAP_BUCKETS override would change what the audit
-    # lowers and fail these tests spuriously.
-    saved = {v: os.environ.pop(v, None)
-             for v in ("HOROVOD_OVERLAP_BUCKETS", "HVD_TPU_OVERLAP_BUCKETS")}
-    try:
-        from examples.overlap_audit import audit_cpu_sim
+    from examples.overlap_audit import audit_cpu_sim
 
-        return audit_cpu_sim()
-    finally:
-        for v, val in saved.items():
-            if val is not None:
-                os.environ[v] = val
+    return audit_cpu_sim()
 
 
 def test_buckets_issued_before_combining(audit):
@@ -62,11 +51,10 @@ def test_buckets_issued_before_combining(audit):
 def test_chained_buckets_survive_and_interleave(audit):
     # The shipped default (AdaptivePlanner at the sim's width 8 keeps the
     # depth-4 chain): the dependency chain keeps the bucket all-reduces
-    # uncombined...  (The DEFAULT constant, not the live env: the fixture
-    # lowered under the default.)
-    from horovod_tpu.utils import env
+    # uncombined...
+    from horovod_tpu.ops.schedule_plan import DEFAULT_CHAIN_DEPTH
 
-    assert audit["all_reduce_ops"] >= env.DEFAULT_OVERLAP_BUCKETS, audit
+    assert audit["all_reduce_ops"] >= DEFAULT_CHAIN_DEPTH, audit
     # ...and the scheduler places early buckets' reductions BEFORE the
     # last backward op — the interleaving that becomes true async overlap
     # under hvd.overlap_compiler_options() on the TPU backend.
@@ -74,36 +62,31 @@ def test_chained_buckets_survive_and_interleave(audit):
 
 
 def test_chained_buckets_assertion_uses_default(audit):
-    # The >= bound below reads the DEFAULT bucket count, not the ambient
-    # env (the fixture strips overrides before lowering).
-    from horovod_tpu.utils import env
+    from horovod_tpu.ops.schedule_plan import DEFAULT_CHAIN_DEPTH
 
-    assert env.DEFAULT_OVERLAP_BUCKETS == 4
-    assert audit["all_reduce_ops"] >= env.DEFAULT_OVERLAP_BUCKETS
+    assert DEFAULT_CHAIN_DEPTH == 4
+    assert audit["all_reduce_ops"] >= DEFAULT_CHAIN_DEPTH
 
 
 def test_adaptive_planner_chains_at_real_width(audit):
     # Branch 1 of the planner: at the sim mesh's real width (8) the
     # adaptive default keeps the depth-4 chain — plan recorded, gates in
     # the lowered stablehlo (one gate between consecutive buckets).
-    from horovod_tpu.utils import env
+    from horovod_tpu.ops.schedule_plan import DEFAULT_CHAIN_DEPTH
 
     plan = audit["plan"]
     assert plan is not None and plan["planner"] == "adaptive", plan
     assert plan["chained"] and plan["chain_depth"] == \
-        env.DEFAULT_OVERLAP_BUCKETS, plan
+        DEFAULT_CHAIN_DEPTH, plan
     assert plan["width"] == 8, plan
-    assert audit["gate_is_finite_ops"] == env.DEFAULT_OVERLAP_BUCKETS - 1, \
-        audit
+    assert audit["gate_is_finite_ops"] == DEFAULT_CHAIN_DEPTH - 1, audit
 
 
-def test_adaptive_planner_width1_bypasses_chain(monkeypatch):
+def test_adaptive_planner_width1_bypasses_chain():
     # Branch 2: the same step over a ONE-device mesh must lower with NO
     # dependency chain — zero is_finite gates, the round-4 free-combining
     # structure — and the recorded plan must say why (width-1 bypass).
     # This is the r5 ResNet headline regression, pinned dead.
-    monkeypatch.delenv("HOROVOD_OVERLAP_BUCKETS", raising=False)
-    monkeypatch.delenv("HVD_TPU_OVERLAP_BUCKETS", raising=False)
     import horovod_tpu as hvd
 
     hvd.init()
@@ -116,70 +99,31 @@ def test_adaptive_planner_width1_bypasses_chain(monkeypatch):
     assert not plan["chained"] and plan["width"] == 1, plan
 
 
-def test_disabling_chain_restores_single_merged_all_reduce(monkeypatch):
-    monkeypatch.delenv("HVD_TPU_OVERLAP_BUCKETS", raising=False)
-    # HOROVOD_OVERLAP_BUCKETS=0 restores the round-4 free-combining
-    # structure: one merged all-reduce after all backward compute.  Pins
-    # that the gate really is what prevents combining (and that the
-    # escape hatch works).
+def test_disabling_chain_restores_single_merged_all_reduce():
+    # Without the chain (default_depth=0 through the planner seam) the
+    # round-4 free-combining structure is back: one merged all-reduce
+    # after all backward compute.  Pins that the gate really is what
+    # prevents combining.
     import horovod_tpu as hvd
+    from horovod_tpu.ops.schedule_plan import AdaptivePlanner
 
     hvd.init()
-    # Deliberate legacy-branch fixture, not a recommendation (HVD107).
-    monkeypatch.setenv("HOROVOD_OVERLAP_BUCKETS", "0")  # hvd-lint: disable=HVD107
     from examples.overlap_audit import audit_cpu_sim
 
-    audit = audit_cpu_sim()
+    audit = audit_cpu_sim(planner=AdaptivePlanner(default_depth=0))
+    assert not audit["plan"]["chained"], audit
     if audit["all_reduce_ops"] >= 10:
         # Per-tensor psums survived untouched: this XLA build runs no
         # all-reduce combiner pass on the CPU pipeline at all, so "free
         # combining" has nothing to combine with — the gate-vs-combiner
         # distinction this test pins is unobservable here.  (A chaining
-        # regression would show ~OVERLAP_BUCKETS ops, not dozens.)
+        # regression would show about DEFAULT_CHAIN_DEPTH ops, not dozens.)
         import pytest
 
         pytest.skip("no all-reduce combiner in this XLA CPU pipeline "
                     f"({audit['all_reduce_ops']} per-tensor all-reduces)")
     assert audit["all_reduce_ops"] == 1, audit
     assert audit["all_reduces_before_last_backward"] == 0, audit
-
-
-def test_overlap_buckets_malformed_env_falls_back_with_warning(monkeypatch):
-    # A launch-script typo in the bucket knob must degrade to the default
-    # with a warning naming the offending env var — not crash the job at
-    # its first compiled step.
-    import warnings
-
-    from horovod_tpu.utils import env
-
-    monkeypatch.delenv("HOROVOD_OVERLAP_BUCKETS", raising=False)
-    monkeypatch.setenv("HVD_TPU_OVERLAP_BUCKETS", "fourish")  # hvd-lint: disable=HVD107
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        assert env.overlap_buckets() == env.DEFAULT_OVERLAP_BUCKETS
-    assert any("HVD_TPU_OVERLAP_BUCKETS" in str(w.message) for w in caught)
-
-    # The HOROVOD_* spelling wins the lookup and is named in the warning.
-    monkeypatch.setenv("HOROVOD_OVERLAP_BUCKETS", "-3")  # hvd-lint: disable=HVD107
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        assert env.overlap_buckets() == env.DEFAULT_OVERLAP_BUCKETS
-    assert any("HOROVOD_OVERLAP_BUCKETS" in str(w.message) for w in caught)
-
-
-def test_overlap_buckets_well_formed_env_still_parses(monkeypatch):
-    monkeypatch.delenv("HOROVOD_OVERLAP_BUCKETS", raising=False)
-    monkeypatch.setenv("HVD_TPU_OVERLAP_BUCKETS", "7")  # hvd-lint: disable=HVD107
-    from horovod_tpu.utils import env
-
-    assert env.overlap_buckets() == 7
-    assert env.overlap_buckets_override() == 7
-    monkeypatch.setenv("HVD_TPU_OVERLAP_BUCKETS", "0")  # hvd-lint: disable=HVD107
-    assert env.overlap_buckets() == 0
-    assert env.overlap_buckets_override() == 0
-    monkeypatch.delenv("HVD_TPU_OVERLAP_BUCKETS", raising=False)
-    # Unset: no override — the adaptive planner owns the decision.
-    assert env.overlap_buckets_override() is None
 
 
 def test_overlap_compiler_options_shape():

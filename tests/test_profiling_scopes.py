@@ -264,7 +264,8 @@ def test_distributed_optimizer_step_carries_the_scopes(hvd_module, buckets,
     from jax.sharding import Mesh, PartitionSpec as P
 
     hvd = hvd_module
-    opt = hvd.DistributedOptimizer(optax.sgd(0.1), overlap_buckets=buckets)
+    opt = hvd.DistributedOptimizer(
+        optax.sgd(0.1), planner=hvd.AdaptivePlanner(default_depth=buckets))
     params = {f"w{i}": jnp.ones((4, 4)) for i in range(8)}
 
     def step(params, state, x):
@@ -433,8 +434,7 @@ def test_every_name_of_the_vocabulary_is_written_once():
 def fresh_planner(monkeypatch):
     from horovod_tpu.ops import schedule_plan as sp
 
-    for v in ("HOROVOD_OVERLAP_BUCKETS", "HVD_TPU_OVERLAP_BUCKETS",
-              "HOROVOD_DEVICE_HEADROOM_MB", "HVD_TPU_DEVICE_HEADROOM_MB"):
+    for v in ("HOROVOD_DEVICE_HEADROOM_MB", "HVD_TPU_DEVICE_HEADROOM_MB"):
         monkeypatch.delenv(v, raising=False)
     sp._reset_for_tests()
     yield sp

@@ -1,15 +1,14 @@
 """Unit + lowering tests for the trace-time overlap schedule planner
-(ops/schedule_plan.py) — ISSUE 9's tentpole.
+(ops/schedule_plan.py).
 
 The planner's contract, pinned here:
 
-* width-1 bypass (the r5 −4.3% ResNet headline regression: chaining where
-  psum is identity only constrains the scheduler);
+* width-1 bypass (chaining where psum is identity only constrains the
+  scheduler);
 * headroom-deficit degradation — the 468M config's 79 MB OOM must turn
-  into a shallower chain (or free-combining fallback) with NO hand-set
-  ``HOROVOD_OVERLAP_BUCKETS``;
-* explicit overrides (argument, env, custom planner instance) win
-  bit-for-bit over the adaptive plan;
+  into a shallower chain (or free-combining fallback);
+* one owner: no environment name changes the plan, and ``planner=`` is the
+  seam through which a test forces a depth;
 * plan stability: the same manifest/width/headroom always produces the
   same plan, across repeated traces.
 """
@@ -23,10 +22,8 @@ from horovod_tpu.utils import env
 
 @pytest.fixture(autouse=True)
 def _fresh_planner_state(monkeypatch):
-    # Planner decisions must come from THIS test's env, not the shell's;
+    # The headroom must come from THIS test's env, not the shell's;
     # the probe cache and dedup log reset so tests stay order-independent.
-    monkeypatch.delenv("HOROVOD_OVERLAP_BUCKETS", raising=False)
-    monkeypatch.delenv("HVD_TPU_OVERLAP_BUCKETS", raising=False)
     monkeypatch.delenv("HOROVOD_DEVICE_HEADROOM_MB", raising=False)
     monkeypatch.delenv("HVD_TPU_DEVICE_HEADROOM_MB", raising=False)
     sp._reset_for_tests()
@@ -56,12 +53,12 @@ def test_width1_bypasses_chain():
 def test_real_width_slack_headroom_keeps_default_depth():
     plan = sp.AdaptivePlanner().plan(manifest(), width=8,
                                      headroom_mb=8000.0)
-    assert plan.chain_depth == env.DEFAULT_OVERLAP_BUCKETS and plan.chained
+    assert plan.chain_depth == sp.DEFAULT_CHAIN_DEPTH and plan.chained
 
 
 def test_unknown_headroom_keeps_default_depth():
     plan = sp.AdaptivePlanner().plan(manifest(), width=8, headroom_mb=None)
-    assert plan.chain_depth == env.DEFAULT_OVERLAP_BUCKETS and plan.chained
+    assert plan.chain_depth == sp.DEFAULT_CHAIN_DEPTH and plan.chained
 
 
 def test_headroom_deficit_degrades_depth_then_bypasses():
@@ -73,7 +70,7 @@ def test_headroom_deficit_degrades_depth_then_bypasses():
         nbytes=(936 * 1024 * 1024 // 20,) * 20, dtypes=("bfloat16",) * 20)
     assert sp.chain_extra_bytes(m.total_bytes, 4) > 80 * 1024 * 1024
     degraded = sp.AdaptivePlanner().plan(m, width=16, headroom_mb=80.0)
-    assert 1 < degraded.chain_depth < env.DEFAULT_OVERLAP_BUCKETS
+    assert 1 < degraded.chain_depth < sp.DEFAULT_CHAIN_DEPTH
     assert sp.chain_extra_bytes(m.total_bytes, degraded.chain_depth) \
         <= 80 * 1024 * 1024
     assert "degraded" in degraded.reason
@@ -177,13 +174,6 @@ def test_real_width_materialises_nothing():
             assert plan.as_dict()["materialized_leaves"] == 0
 
 
-def test_static_planner_materialises_nothing():
-    # an explicit overlap_buckets= keeps its legacy semantics bit for bit
-    plan = sp.StaticPlanner(4).plan(decoder_manifest(), width=1,
-                                    headroom_mb=None)
-    assert plan.materialized == ()
-
-
 def test_materialised_set_is_deterministic():
     for m in (decoder_manifest(), resnet_manifest()):
         plans = [sp.AdaptivePlanner().plan(m, width=1, headroom_mb=h)
@@ -193,44 +183,107 @@ def test_materialised_set_is_deterministic():
 
 
 # ---------------------------------------------------------------------------
-# Overrides beat the adaptive plan
+# One owner: the environment cannot change the plan; planner= is the seam
 # ---------------------------------------------------------------------------
 
-def test_argument_override_beats_adaptive():
-    # overlap_buckets=6 at width 1: legacy semantics chain anyway —
-    # bit-for-bit what the knob did before the planner existed.
-    t = [np.zeros((8, 8), np.float32)] * 4
-    plan = sp.plan_overlap(t, width=1, override=6)
-    assert plan.planner == "static" and plan.chain_depth == 6
-    assert plan.chained  # width is irrelevant to the static branch
-    off = sp.plan_overlap(t, width=8, override=0)
-    assert off.planner == "static" and not off.chained
+# The two names that, until PR 29, swapped the planner for a static one.
+RETIRED_NAMES = ["HOROVOD_OVERLAP_BUCKETS", "HVD_TPU_OVERLAP_BUCKETS"]
 
 
-def test_env_override_beats_adaptive(monkeypatch):
-    # Legacy-pin fixture on purpose (the planner normally decides).
-    monkeypatch.setenv("HOROVOD_OVERLAP_BUCKETS", "5")  # hvd-lint: disable=HVD107
-    t = [np.zeros((8, 8), np.float32)] * 4
-    plan = sp.plan_overlap(t, width=1, override=None)
-    assert plan.planner == "static" and plan.chain_depth == 5
+@pytest.mark.parametrize("value", ["0", "4", "junk"])
+@pytest.mark.parametrize("name", RETIRED_NAMES)
+def test_bucket_names_in_the_environment_are_read_by_nothing(
+        monkeypatch, name, value):
+    # Set to anything, either name made the plan static: chained at width
+    # 1, materialised nothing.  Now they are two strings nobody reads: the
+    # same plan as with nothing set, and no warning.
+    import warnings
+
+    t = [np.zeros((8, 8), np.float32)] * 12
+    unset = {w: sp.plan_overlap(t, width=w) for w in (1, 8)}
+    monkeypatch.setenv(name, value)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        for w in (1, 8):
+            plan = sp.plan_overlap(t, width=w)
+            assert plan == unset[w] and plan.planner == "adaptive"
+    assert not unset[1].chained
+    assert unset[8].chain_depth == sp.DEFAULT_CHAIN_DEPTH
 
 
-def test_argument_beats_env(monkeypatch):
-    monkeypatch.setenv("HOROVOD_OVERLAP_BUCKETS", "5")  # hvd-lint: disable=HVD107
-    t = [np.zeros((8, 8), np.float32)] * 4
-    plan = sp.plan_overlap(t, width=8, override=2)
-    assert plan.chain_depth == 2
+@pytest.mark.parametrize("name", RETIRED_NAMES)
+def test_width1_step_lowers_the_same_with_a_bucket_name_set(
+        monkeypatch, name):
+    # A width-1 DistributedOptimizer step with one gradient large enough to
+    # be materialised: the lowered program and the plan's record are the
+    # same with the name set to 0 (at the parent: static planner, no
+    # barrier, another program).
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    import horovod_tpu as hvd
+
+    hvd.init()
+    opt = hvd.DistributedOptimizer(optax.adamw(1e-3))
+
+    def step(params, opt_state, x):
+        def loss(p):
+            return jnp.mean((jnp.tanh(x @ p["big"]) @ p["small"]) ** 2)
+
+        u, opt_state = opt.update(jax.grad(loss)(params), opt_state, params)
+        return optax.apply_updates(params, u), opt_state
+
+    params = {"big": jax.ShapeDtypeStruct((2048, 2048), jnp.float32),
+              "small": jax.ShapeDtypeStruct((2048, 8), jnp.float32)}
+    assert 2048 * 2048 * 4 >= sp.MATERIALIZE_MIN_BYTES
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("hvd",))
+    sharded = jax.jit(jax.shard_map(
+        step, mesh=mesh, in_specs=(P(), P(), P("hvd")),
+        out_specs=(P(), P()), check_vma=False))
+    args = (params, jax.eval_shape(opt.init, params),
+            jax.ShapeDtypeStruct((4, 2048), jnp.float32))
+
+    def lowered():
+        text = sharded.lower(*args).as_text()
+        return text, hvd.overlap_plan()
+
+    text, plan = lowered()
+    monkeypatch.setenv(name, "0")
+    text_set, plan_set = lowered()
+    assert text_set == text
+    assert plan["planner"] == plan_set["planner"] == "adaptive"
+    assert plan["materialized_leaves"] == plan_set["materialized_leaves"] == 1
+    assert "optimization_barrier" in text
 
 
-def test_custom_planner_instance_wins(monkeypatch):
-    monkeypatch.setenv("HOROVOD_OVERLAP_BUCKETS", "5")  # hvd-lint: disable=HVD107
+@pytest.mark.parametrize("depth,chain,gates", [
+    (0, 0, 0), (1, 0, 0), (2, 2, 1), (3, 3, 2), (8, 8, 7)])
+def test_default_depth_through_the_one_planner(depth, chain, gates):
+    # A depth of N forced through ``planner=``: at real
+    # width with the headroom unknown, ``AdaptivePlanner(default_depth=N)``
+    # chains N buckets (none for N <= 1), and the lowered program carries
+    # one gate between consecutive buckets.
+    import horovod_tpu as hvd
 
-    class Fixed3(sp.Planner):
-        name = "fixed3"
+    hvd.init()
+    from examples.overlap_audit import audit_cpu_sim
 
+    audit = audit_cpu_sim(planner=sp.AdaptivePlanner(default_depth=depth))
+    plan = audit["plan"]
+    assert plan["planner"] == "adaptive" and plan["width"] == 8, plan
+    assert plan["headroom_mb"] is None and plan["tensor_count"] >= 9, plan
+    assert plan["chain_depth"] == chain, plan
+    assert audit["gate_is_finite_ops"] == gates, audit
+
+
+def test_custom_planner_instance_wins():
+    # the seam is duck-typed: anything with plan(manifest, width, headroom)
+    class Fixed3:
         def plan(self, m, width, headroom_mb):
             return sp.BucketPlan(
-                planner=self.name, chain_depth=3, width=width,
+                planner="fixed3", chain_depth=3, width=width,
                 tensor_count=m.count, total_bytes=m.total_bytes,
                 headroom_mb=headroom_mb, chain_extra_bytes=0,
                 reason="test planner")
@@ -238,21 +291,6 @@ def test_custom_planner_instance_wins(monkeypatch):
     t = [np.zeros((8, 8), np.float32)] * 4
     plan = sp.plan_overlap(t, width=8, planner=Fixed3())
     assert plan.planner == "fixed3" and plan.chain_depth == 3
-
-
-def test_malformed_env_override_degrades_to_static_default(monkeypatch):
-    # A typo'd knob stays on the round-5 path (static depth 4 + warning),
-    # NOT silently adaptive — set-but-broken must not change semantics.
-    import warnings
-
-    monkeypatch.setenv("HOROVOD_OVERLAP_BUCKETS", "four")  # hvd-lint: disable=HVD107
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        t = [np.zeros((8, 8), np.float32)] * 4
-        plan = sp.plan_overlap(t, width=1, override=None)
-    assert plan.planner == "static"
-    assert plan.chain_depth == env.DEFAULT_OVERLAP_BUCKETS
-    assert any("HOROVOD_OVERLAP_BUCKETS" in str(w.message) for w in caught)
 
 
 # ---------------------------------------------------------------------------
@@ -308,9 +346,8 @@ def test_probe_result_is_cached_per_process(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_simulated_headroom_deficit_degrades_lowered_chain(monkeypatch):
-    # Acceptance: a simulated deficit (HVD_TPU_DEVICE_HEADROOM_MB) makes
-    # the planner degrade chain depth in the ACTUAL lowered program, with
-    # no hand-set HOROVOD_OVERLAP_BUCKETS anywhere.  The audit model
+    # A simulated deficit (HVD_TPU_DEVICE_HEADROOM_MB) makes the planner
+    # degrade chain depth in the ACTUAL lowered program.  The audit model
     # carries ~33.6 MB of gradients -> depth-4 chain bill ≈ 3.01 MB,
     # depth-2 ≈ 2.0 MB: a 3 MB headroom forces exactly one halving.
     import horovod_tpu as hvd
@@ -332,8 +369,7 @@ def test_distributed_optimizer_planner_kwarg_rejected_with_zero1():
     import optax
 
     import horovod_tpu as hvd
-    from horovod_tpu.ops import StaticPlanner
 
     with pytest.raises(ValueError, match="planner"):
         hvd.DistributedOptimizer(optax.sgd(0.01), sharded_state=True,
-                                 planner=StaticPlanner(4))
+                                 planner=sp.AdaptivePlanner())
