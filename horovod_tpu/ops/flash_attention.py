@@ -17,12 +17,31 @@ per tile it recomputes the probabilities from the forward's saved
 log-sum-exp — p = exp(s − lse) — and, with Δ = rowsum(dO·O), forms
 ds = p·(dO·vᵀ − Δ) once and feeds dv, dk and dq from it: the five matrix
 products a tile the backward needs, no more (two passes, one for dq and
-one for dk/dv, each formed q·kᵀ and dO·vᵀ: seven).  dk/dv accumulate in
-their resident output blocks; dq, whose rows are revisited once per
-k-block, in a VMEM-resident f32 block of the head's whole q length.  No
-O(S²) tensor is ever materialized in HBM in either direction.  The kernel
-takes lse/Δ as explicit inputs so ring attention can drive it per ring step
-with globally-merged statistics.
+one for dk/dv, each formed q·kᵀ and dO·vᵀ: seven).  No O(S²) tensor is
+ever materialized in HBM in either direction.  The kernel takes lse/Δ as
+explicit inputs so ring attention can drive it per ring step with
+globally-merged statistics.
+
+What is scratch and what is output.  Every accumulator is float32 VMEM
+scratch that never reaches HBM: the forward's o accumulator and its
+running max and sum; the backward's dk/dv blocks and dq, whose rows are
+revisited once per k-block, over the head's whole q length.  An output
+block is written once, cast to the output's own dtype on the grid step
+that finishes it.  That dtype is decided by the caller's structure and by
+nothing a user sets: ``flash_attention`` (one device, one call over the
+whole sequence: nothing sums its results again) takes o, dq, dk and dv in
+the compute dtype, so no float32 copy of an activation lies in HBM between
+a kernel and the cast that followed it; ``flash_attention_with_lse`` and
+``flash_attention_backward``, which ring and zigzag attention call once
+per ring step and whose results they merge and sum, return float32, as
+does a backward cut into several calls (dk and dv are summed over them
+first).  Same arithmetic, same single rounding: the compute-dtype
+outputs are the float32 ones cast, to the bit.
+
+The kernels' own layout is [B·H, S, D], padded to whole blocks.
+``flash_attention`` lays q, k, v out once, for the forward call, and
+keeps them, o and lse that way as the residuals its backward reads: only
+the incoming cotangent is laid out again.
 
 Non-TPU backends fall back to Pallas interpret mode (tests) so numerics are
 identical everywhere.
@@ -30,6 +49,7 @@ identical everywhere.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -65,8 +85,8 @@ def _sub_bounds(k_len, q_min, q_max, ks_min, sub_k, nsub, causal):
     return hi, jnp.clip(interior_end, 0, hi)
 
 
-def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
-                  l_ref, *, block_q: int, block_k: int, sub_k: int,
+def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
+                  m_ref, l_ref, *, block_q: int, block_k: int, sub_k: int,
                   num_k_blocks: int, causal: bool, scale: float):
     """One (batch·head, q-block, K-super-tile) program: online softmax.
 
@@ -85,9 +105,15 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
     no per-element iota/compare/select (VPU work bracketing the MXU
     matmuls) — and only the diagonal/boundary suffix pays for masking.
 
-    ``m_ref``/``l_ref`` are carry storage in the lse layout (sublane-
-    replicated (8, block_q)); callers discard them.  ``o_ref`` is f32
-    (accumulation precision); the caller casts.
+    Scratch and output.  ``acc_ref`` (f32 [block_q, D]) and ``m_ref`` /
+    ``l_ref`` (the running max and sum in the lse layout, sublane-
+    replicated (8, block_q)) are VMEM scratch: they carry the online
+    softmax across the K sweep and never reach HBM.  ``o_ref`` and
+    ``lse_ref`` are the outputs, written once, on the sweep's last step:
+    ``o_ref`` takes ``acc / l`` cast to its own dtype there -- the caller's
+    compute dtype, or float32 for a caller that merges partial results
+    (ring attention) -- which is the one rounding a cast after the call
+    would make, without the f32 array in HBM between the two.
     """
     qi, ki = pl.program_id(1), pl.program_id(2)
     nsub = block_k // sub_k
@@ -96,7 +122,7 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
     def _init():
         m_ref[0] = jnp.full_like(m_ref[0], NEG_INF)
         l_ref[0] = jnp.zeros_like(l_ref[0])
-        o_ref[0] = jnp.zeros_like(o_ref[0])
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q_min = meta_ref[0] + qi * block_q
     q_max = q_min + block_q - 1
@@ -145,7 +171,7 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
         pv = jax.lax.dot_general(
             p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        o_ref[0] = o_ref[0] * corr + pv
+        acc_ref[...] = acc_ref[...] * corr + pv
         return m_new, l_new
 
     def _writeback(m, l):
@@ -193,7 +219,7 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref,
     def _finish():
         m = m_ref[0, 0, :][:, None]
         l = l_ref[0, 0, :][:, None]
-        o_ref[0] = o_ref[0] / jnp.maximum(l, 1e-30)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         # log-sum-exp per query row (NEG_INF where a row attended to
         # nothing) — lets callers combine partial attentions exactly
         # (ring attention).  m carries log2-domain scores (LOG2E fold),
@@ -283,49 +309,57 @@ def _vmem_estimate_bytes(block_q: int, block_k: int, d: int,
     return kv + qdo + acc + stats + tiles
 
 
-# The backward's dq accumulator is the one VMEM term that grows with the
-# sequence (S_q·d·4 bytes: 8 MiB at S=16384, d=128), so that call asks
-# Mosaic for its own limit (``vmem_limit_bytes``) instead of living under
-# the 16 MiB default.  A v5e core has 128 MiB of VMEM (jax's own
-# ``pallas.tpu.get_tpu_info`` table; that call needs an attached TPU, this
-# file must also trace for a described one).  One call asks for at most
-# three quarters of it; a longer q is cut into row ranges, one call each.
+# The backward's dq accumulator and the dq output block beside it are the
+# VMEM terms that grow with the sequence (S_q·d·(4 + 2) bytes in bf16:
+# 8 + 4 MiB at S=16384, d=128), so that call asks Mosaic for its own limit
+# (``vmem_limit_bytes``) instead of living under the 16 MiB default.  A v5e
+# core has 128 MiB of VMEM (jax's own ``pallas.tpu.get_tpu_info`` table;
+# that call needs an attached TPU, this file must also trace for a
+# described one).  One call asks for at most three quarters of it; a
+# longer q is cut into row ranges, one call each.
 VMEM_PHYSICAL_MB = 128.0
 _BWD_VMEM_ASK_MAX_BYTES = int(0.75 * VMEM_PHYSICAL_MB * 2 ** 20)
 
 
 def _bwd_vmem_estimate_bytes(block_q: int, block_k: int, d: int, s_q: int,
-                             sub: int = 1024, itemsize: int = 2) -> int:
+                             sub: int = 1024, itemsize: int = 2,
+                             out_itemsize: int | None = None) -> int:
     """Resident-set model of the one backward pass (:func:`_bwd_kernel`):
     double-buffered Q/dO super tiles and K/V tiles, the sublane-replicated
-    lse/Δ rows, the double-buffered f32 dk/dv blocks, three live
-    [sub_q, block_k] f32 compute tiles (Mosaic fuses the elementwise
-    chain: the v5e compiler's own scoped allocation at the default tiles
-    is 15.7 MiB at S=2048 where this says 17.1), and the one term that
-    grows with the sequence: the f32 dq accumulator over the ``s_q`` padded
-    q rows of one call, single-buffered.  ``block_k`` is the kernel's own
-    k tile (≤ 1024 at the defaults)."""
+    lse/Δ rows, the f32 dk/dv scratch and their double-buffered output
+    blocks, three live [sub_q, block_k] f32 compute tiles (Mosaic fuses the
+    elementwise chain: the v5e compiler's own scoped allocation at the
+    default tiles ran 1.4 MiB under this model at S=2048, PR 27), and the
+    two terms that grow with the sequence: the f32 dq accumulator over the
+    ``s_q`` padded q rows of one call, and the single-buffered dq output
+    block of the same rows in the output's dtype.  ``block_k`` is the
+    kernel's own k tile (≤ 1024 at the defaults); ``out_itemsize`` is the
+    gradients' (None: the inputs')."""
+    out_itemsize = itemsize if out_itemsize is None else out_itemsize
     sub_q = min(sub, max(block_q, 1))
     qdo = 2 * 2 * block_q * d * itemsize         # q + dO, double-buffered
     kv = 2 * 2 * block_k * d * itemsize          # K + V, double-buffered
     stats = 2 * 2 * 8 * block_q * 4              # lse + Δ, double-buffered
-    dkv = 2 * 2 * block_k * d * 4                # f32 dk + dv blocks
+    dkv = 2 * block_k * d * (4 + 2 * out_itemsize)   # dk + dv: scratch, out
     tiles = 3 * sub_q * block_k * 4              # live f32 compute tiles
-    dq = s_q * d * 4                             # the call's accumulator
+    dq = s_q * d * (4 + out_itemsize)            # accumulator + out block
     return qdo + kv + stats + dkv + tiles + dq
 
 
 def _bwd_vmem_limit_bytes(block_q: int, block_k: int, d: int, s_q: int,
-                          sub: int = 1024, itemsize: int = 2) -> int:
+                          sub: int = 1024, itemsize: int = 2,
+                          out_itemsize: int | None = None) -> int:
     """What the backward call asks Mosaic for: its estimate and a quarter
     more (the estimate cannot see the scheduler's windows), never under
     the 16 MiB default."""
-    est = _bwd_vmem_estimate_bytes(block_q, block_k, d, s_q, sub, itemsize)
+    est = _bwd_vmem_estimate_bytes(block_q, block_k, d, s_q, sub, itemsize,
+                                   out_itemsize)
     return max(16 * 2 ** 20, est + est // 4)
 
 
 def _bwd_q_rows_per_call(block_q: int, block_k: int, d: int, s_q: int,
-                         sub: int = 1024, itemsize: int = 2) -> int:
+                         sub: int = 1024, itemsize: int = 2,
+                         out_itemsize: int | None = None) -> int:
     """How many of the ``s_q`` padded q rows one backward call takes: all
     of them while the limit it would ask for stays within
     ``_BWD_VMEM_ASK_MAX_BYTES``, else the fewest equal ranges of whole q
@@ -334,7 +368,7 @@ def _bwd_q_rows_per_call(block_q: int, block_k: int, d: int, s_q: int,
     calls = 1
     while calls < blocks and _bwd_vmem_limit_bytes(
             block_q, block_k, d, -(-blocks // calls) * block_q, sub,
-            itemsize) > _BWD_VMEM_ASK_MAX_BYTES:
+            itemsize, out_itemsize) > _BWD_VMEM_ASK_MAX_BYTES:
         calls += 1
     return -(-blocks // calls) * block_q
 
@@ -374,34 +408,46 @@ def clamp_blocks_to_vmem(block_q: int, block_k: int, d: int,
     return bq, bk
 
 
-def _flash_forward(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                   interpret, *, sub: int = 1024, with_lse: bool = False):
-    b, s_q, h, d = q.shape
+def _to_bh(x):
+    """[B, S, H, D] → [B·H, S, D], the kernels' layout."""
+    b, s, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+
+def _from_bh(x, b: int, s: int):
+    """[B·H, S_pad, D] → [B, S, H, D], the padding rows dropped."""
+    return x[:, :s].reshape(b, -1, s, x.shape[-1]).transpose(0, 2, 1, 3)
+
+
+def _meta(q_offset, k_offset, s_k: int):
+    """The kernels' SMEM int32[3]: [q_offset, k_offset, k_len]."""
+    k_offset = jnp.asarray(k_offset, jnp.int32)
+    return jnp.stack([jnp.asarray(q_offset, jnp.int32), k_offset,
+                      k_offset + s_k])
+
+
+def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
+                interpret, sub, out_dtype):
+    """The forward kernel on [B, S, H, D] inputs, everything it read and
+    wrote left in the kernels' layout, padded to whole blocks:
+    ``(qb, kb, vb, ob, lse_b)`` with ``ob`` [B·H, S_q_pad, D] in
+    ``out_dtype`` and ``lse_b`` [B·H, 8, S_q_pad] float32 (sublane-
+    replicated).  The backward reads all five as they are."""
+    d = q.shape[-1]
     s_k = k.shape[1]
-    scale = d ** -0.5
     block_k, sub_k = _sub_fit(block_k, sub)
-    # [B, S, H, D] → [B·H, S, D]
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
-
-    qb = _pad_to(to_bh(q), 1, block_q)
-    meta = jnp.asarray(
-        [jnp.asarray(q_offset, jnp.int32),
-         jnp.asarray(k_offset, jnp.int32),
-         jnp.asarray(k_offset, jnp.int32) + s_k], jnp.int32)
+    qb = _pad_to(_to_bh(q), 1, block_q)
+    kb = _pad_to(_to_bh(k), 1, block_k)
+    vb = _pad_to(_to_bh(v), 1, block_k)
     num_q_blocks = qb.shape[1] // block_q
-    carry_shape = jax.ShapeDtypeStruct((qb.shape[0], 8, qb.shape[1]),
-                                       jnp.float32)
-
-    kb = _pad_to(to_bh(k), 1, block_k)
-    vb = _pad_to(to_bh(v), 1, block_k)
     num_k_blocks = kb.shape[1] // block_k
+    stat_block = pl.BlockSpec((1, 8, block_q), lambda bh, qi, ki: (bh, 0, qi))
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k, sub_k=sub_k,
-        num_k_blocks=num_k_blocks, causal=causal, scale=scale)
-    out, lse, _m, _l = pl.pallas_call(
+        num_k_blocks=num_k_blocks, causal=causal, scale=d ** -0.5)
+    ob, lse_b = pl.pallas_call(
         kernel,
-        grid=(b * h, num_q_blocks, num_k_blocks),
+        grid=(qb.shape[0], num_q_blocks, num_k_blocks),
         in_specs=[
             pl.BlockSpec((3,), lambda bh, qi, ki: (0,),
                          memory_space=pltpu.SMEM),
@@ -415,52 +461,51 @@ def _flash_forward(q, k, v, causal, q_offset, k_offset, block_q, block_k,
         out_specs=(
             pl.BlockSpec((1, block_q, d),
                          lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 8, block_q),
-                         lambda bh, qi, ki: (bh, 0, qi)),
-            pl.BlockSpec((1, 8, block_q),
-                         lambda bh, qi, ki: (bh, 0, qi)),
-            pl.BlockSpec((1, 8, block_q),
-                         lambda bh, qi, ki: (bh, 0, qi)),
+            stat_block,
         ),
         out_shape=(
-            jax.ShapeDtypeStruct(qb.shape, jnp.float32),  # f32 acc
-            carry_shape,   # lse
-            carry_shape,   # m carry (discarded)
-            carry_shape,   # l carry (discarded)
+            jax.ShapeDtypeStruct(qb.shape, out_dtype),
+            jax.ShapeDtypeStruct((qb.shape[0], 8, qb.shape[1]), jnp.float32),
         ),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),      # acc
+            pltpu.VMEM((1, 8, block_q), jnp.float32),   # m carry
+            pltpu.VMEM((1, 8, block_q), jnp.float32),   # l carry
+        ],
         # outer axes parallel, the innermost the sequential K sweep
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=profiling.FLASH_FWD,
-    )(meta, qb, kb, vb)
-    out = out.astype(q.dtype)
-    out = out[:, :s_q].reshape(b, h, s_q, d)
-    out = out.transpose(0, 2, 1, 3)
-    if with_lse:
-        # [B·H, 8, S] (sublane-replicated) → [B, S, H]
-        lse = lse[:, 0, :s_q].reshape(b, h, s_q).transpose(0, 2, 1)
-        return out, lse
-    return out
+    )(_meta(q_offset, k_offset, s_k), qb, kb, vb)
+    return qb, kb, vb, ob, lse_b
 
 
 def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, *, block_q: int, block_k: int,
-                sub_q: int, num_q_blocks: int, causal: bool, scale: float):
+                dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                block_q: int, block_k: int, sub_q: int, num_q_blocks: int,
+                num_k_blocks: int, causal: bool, scale: float):
     """One (batch·head, k-block, Q-super-tile) program of the one backward
     pass: per tile s, p, dp and ds = p·(dp − Δ) are formed ONCE and feed
     all three gradients — dv += pᵀ·dO, dk += dsᵀ·(q·scale), dq += ds·k.
 
     The forward's layout with the roles swapped: the grid streams
     (block_q, D) Q/dO super tiles (lse/Δ alongside) double-buffered while
-    the in-kernel loop computes (sub_q, block_k) sub tiles.  dk/dv are f32
-    output blocks that stay VMEM-resident across the qi sweep.  dq's rows
-    are revisited once per k-block, so its f32 output block is the head's
-    WHOLE padded q length (index map constant in ki and qi): resident for
-    the head, zeroed at the head's first grid step (so a call in which no
-    tile runs — K wholly after Q — still returns zeros), written back
-    once a head.  That block is what grows with S_q;
-    :func:`_bwd_vmem_estimate_bytes` prices it.
+    the in-kernel loop computes (sub_q, block_k) sub tiles.
+
+    Scratch and output.  The three gradients accumulate in f32 VMEM
+    scratch that never reaches HBM: ``dk_acc`` / ``dv_acc`` ([block_k, D])
+    across the qi sweep, and ``dq_acc``, whose rows are revisited once per
+    k-block, over the head's WHOLE padded q length: zeroed at the head's
+    first grid step (so a call in which no tile runs — K wholly after Q —
+    still returns zeros).  That accumulator is what grows with S_q;
+    :func:`_bwd_vmem_estimate_bytes` prices it.  The outputs are written
+    once each, cast to their own dtype (the caller's compute dtype, or
+    float32 where the caller sums partial results again), on the step
+    that finishes them: ``dk_ref`` / ``dv_ref`` on the k-block's last q
+    tile, and each q tile's rows of ``dq_ref`` (the head's whole length,
+    index map constant in ki and qi, written back once a head) during
+    the head's last k-block.
 
     Sub-tile split, from the K block's point of view: q sub-tiles entirely
     ABOVE the diagonal (q_sub_max < k_min) are skipped; the diagonal band
@@ -473,12 +518,12 @@ def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(jnp.logical_and(ki == 0, qi == 0))
     def _init_dq():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     @pl.when(qi == 0)
     def _init():
-        dk_ref[0] = jnp.zeros_like(dk_ref[0])
-        dv_ref[0] = jnp.zeros_like(dv_ref[0])
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
     qs_min = meta_ref[0] + qi * block_q   # super-tile base position
     k_min = meta_ref[1] + ki * block_k
@@ -506,9 +551,9 @@ def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         # the forward's, so s — hence p = exp2(s − lse·log2e) — recomputes
         # consistently; the saved lse arrives in natural units (the public
         # ring-attention contract) and converts per row.  The fold's log2e
-        # surplus on dk is repaid by the ·ln2 in _finish (dv uses p
-        # directly and needs none; dq takes plain ``scale`` after the
-        # call, with its cast).
+        # surplus on dk is repaid by the ·ln2 in _finish_dkv (dv uses p
+        # directly and needs none; dq takes plain ``scale`` in
+        # _finish_dq, with its cast).
         q = (q_ref[0, pl.ds(si * sub_q, sub_q), :].astype(jnp.float32)
              * (scale * LOG2E)).astype(q_ref.dtype)       # [sq, D]
         do = do_ref[0, pl.ds(si * sub_q, sub_q), :]
@@ -532,19 +577,19 @@ def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             p = jnp.exp2(s - lse2)
         # p stays f32 (mirroring the forward's PV choice); do up-casts for
         # this one dot since lax.dot_general needs matching dtypes.
-        dv_ref[0] += jax.lax.dot_general(
+        dv_acc[...] += jax.lax.dot_general(
             p, do.astype(jnp.float32), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = (p * (dp - delta)).astype(q_ref.dtype)       # one cast, two uses
         # q is pre-scaled (incl. LOG2E), so this is d s/d k contracted
-        # with ds up to the log2e surplus repaid in _finish.
-        dk_ref[0] += jax.lax.dot_general(
+        # with ds up to the log2e surplus repaid in _finish_dkv.
+        dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         rows = pl.ds(pl.multiple_of(qi * block_q + si * sub_q, sub_q), sub_q)
-        dq_ref[0, rows, :] += jax.lax.dot_general(
+        dq_acc[rows, :] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
@@ -574,25 +619,32 @@ def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 body(si, masked=False)
 
     @pl.when(qi == num_q_blocks - 1)
-    def _finish():
+    def _finish_dkv():
         # The q fold carried scale·log2e; dk needs plain scale — repay
-        # the log2e once per resident block (log2e·ln2 == 1).
-        dk_ref[0] = dk_ref[0] * LN2
+        # the log2e once per finished block (log2e·ln2 == 1).
+        dk_ref[0] = (dk_acc[...] * LN2).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(ki == num_k_blocks - 1)
+    def _finish_dq():
+        # This q tile's rows have met every k-block.  q was pre-scaled
+        # for s; the K-contraction needs one more scale.
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_ref[0, rows, :] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
 
 
-def flash_attention_backward(q, k, v, dout, lse, delta, causal,
-                             q_offset, k_offset, block_q, block_k,
-                             interpret, sub: int = 1024):
-    """Fused backward: (dq, dk, dv) from saved lse and Δ = rowsum(dO·O),
-    one kernel, one sweep over the tiles (:func:`_bwd_kernel`).
-
-    ``lse``/``delta``: [B, S_q, H] float32 — from ``_flash_forward(...,
-    with_lse=True)`` (or the ring's globally-merged statistics), so the
-    per-block probabilities recompute exactly without an O(S²) tensor.
-    """
-    b, s_q, h, d = q.shape
-    s_k = k.shape[1]
-    scale = d ** -0.5
+def _backward_bh(qb, kb, vb, dob, lse_b, delta_b, s_q, s_k, causal, q_offset,
+                 k_offset, block_q, block_k, interpret, sub, out_dtype):
+    """The backward kernel on arrays in the kernels' layout: ``qb`` / ``dob``
+    [B·H, ≥ s_q, D], ``kb`` / ``vb`` [B·H, ≥ s_k, D], ``lse_b`` [B·H, 8,
+    ≥ s_q] (sublane-replicated) and ``delta_b`` [B·H, ≥ s_q] float32; rows
+    past ``s_q`` / ``s_k`` are padding (zeros in q, k, v, dO and Δ).
+    Returns (dq, dk, dv) in that layout, padded to whole blocks, in
+    ``out_dtype`` — cast inside the kernel, so float32 gradients reach HBM
+    only for a caller that asks for them, or where q is cut into several
+    calls (dk and dv are then summed over the calls in f32 first)."""
+    d = qb.shape[-1]
+    itemsize = qb.dtype.itemsize
     # Clamp to the actual sequence lengths (like the public forward
     # wrappers): ring/zigzag drive this entry per ring step with SHARD
     # lengths — without the clamp the 512/1024 defaults would pad small
@@ -600,7 +652,7 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
     block_q = min(block_q, max(s_q, 1))
     block_k = min(block_k, max(s_k, 1))
     block_q, block_k = clamp_blocks_to_vmem(
-        block_q, block_k, d, sub, q.dtype.itemsize,
+        block_q, block_k, d, sub, itemsize,
         where="flash_attention_backward")
     block_q, sub_q = _sub_fit(block_q, sub)
     block_k, sub_k = _sub_fit(block_k, sub)
@@ -614,45 +666,41 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
            and block_k % (bk * 2) == 0):
         bk *= 2
 
-    def to_bh(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d)
-
-    def to_bh2(x):  # [B, S, H] → [B·H, S]
-        return x.transpose(0, 2, 1).reshape(b * h, x.shape[1])
-
-    qb = _pad_to(to_bh(q), 1, block_q)
-    dob = _pad_to(to_bh(dout.astype(q.dtype)), 1, block_q)
-    kb = _pad_to(to_bh(k), 1, block_k)
-    vb = _pad_to(to_bh(v), 1, block_k)
+    # What the forward saved is padded to ITS blocks, which are these
+    # unless block/sub does not divide: pad on only then.
+    qb, dob = _pad_to(qb, 1, block_q), _pad_to(dob, 1, block_q)
+    kb, vb = _pad_to(kb, 1, block_k), _pad_to(vb, 1, block_k)
+    n_q = qb.shape[1]
     # Padded q rows get lse = +inf-ish so p = exp(s − lse) = 0 there.
     # Both vectors are stored sublane-replicated [B·H, 8, S] (Mosaic tiling
     # constraint — see the forward's lse output).
-    lse_b = jnp.pad(to_bh2(lse.astype(jnp.float32)),
-                    ((0, 0), (0, qb.shape[1] - s_q)),
-                    constant_values=-NEG_INF)
-    lse_b = jnp.broadcast_to(lse_b[:, None, :],
-                             (lse_b.shape[0], 8, lse_b.shape[1]))
-    delta_b = _pad_to(to_bh2(delta.astype(jnp.float32)), 1, block_q)
-    delta_b = jnp.broadcast_to(delta_b[:, None, :],
-                               (delta_b.shape[0], 8, delta_b.shape[1]))
-    rows = _bwd_q_rows_per_call(block_q, bk, d, qb.shape[1], sub,
-                                q.dtype.itemsize)
+    lse_b = _pad_to(lse_b.astype(jnp.float32), 2, block_q)
+    if n_q > s_q:
+        lse_b = jnp.where(jnp.arange(n_q) < s_q, lse_b, -NEG_INF)
+    delta_b = _pad_to(delta_b.astype(jnp.float32), 1, block_q)
+    delta_b = jnp.broadcast_to(delta_b[:, None, :], lse_b.shape)
+
+    rows = _bwd_q_rows_per_call(block_q, bk, d, n_q, sub, itemsize,
+                                jnp.dtype(out_dtype).itemsize)
+    part_dtype = out_dtype
+    if rows < n_q:      # several calls: dk and dv are summed over them
+        part_dtype = jnp.float32
+        rows = _bwd_q_rows_per_call(block_q, bk, d, n_q, sub, itemsize, 4)
+    part_itemsize = jnp.dtype(part_dtype).itemsize
+    scale = d ** -0.5
 
     def call(r0, n):
-        meta = jnp.asarray(
-            [jnp.asarray(q_offset, jnp.int32) + r0,
-             jnp.asarray(k_offset, jnp.int32),
-             jnp.asarray(k_offset, jnp.int32) + s_k], jnp.int32)
-        # One kernel over the n q rows from r0 against all of K.  Outputs
-        # accumulate in f32 in their VMEM-resident blocks.  dq's block is
-        # revisited across BOTH inner axes, so both are sequential; one
-        # buffer for it (its index changes once a head — a second would
-        # only double the one term that grows with S_q).
+        # One kernel over the n q rows from r0 against all of K.  dq's
+        # rows are revisited across BOTH inner axes, so both are
+        # sequential; one buffer for its output block (its index changes
+        # once a head — a second would only double a term that grows
+        # with S_q).
         return pl.pallas_call(
             functools.partial(
                 _bwd_kernel, block_q=block_q, block_k=bk, sub_q=sub_q,
-                num_q_blocks=n // block_q, causal=causal, scale=scale),
-            grid=(b * h, kb.shape[1] // bk, n // block_q),
+                num_q_blocks=n // block_q, num_k_blocks=kb.shape[1] // bk,
+                causal=causal, scale=scale),
+            grid=(qb.shape[0], kb.shape[1] // bk, n // block_q),
             in_specs=[
                 pl.BlockSpec((3,), lambda bh, ki, qi: (0,),
                              memory_space=pltpu.SMEM),
@@ -670,57 +718,114 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
                 pl.BlockSpec((1, bk, d), lambda bh, ki, qi: (bh, ki, 0)),
             ),
             out_shape=(
-                jax.ShapeDtypeStruct((b * h, n, d), jnp.float32),
-                jax.ShapeDtypeStruct(kb.shape, jnp.float32),
-                jax.ShapeDtypeStruct(vb.shape, jnp.float32),
+                jax.ShapeDtypeStruct((qb.shape[0], n, d), part_dtype),
+                jax.ShapeDtypeStruct(kb.shape, part_dtype),
+                jax.ShapeDtypeStruct(vb.shape, part_dtype),
             ),
+            scratch_shapes=[
+                pltpu.VMEM((n, d), jnp.float32),     # dq accumulator
+                pltpu.VMEM((bk, d), jnp.float32),    # dk
+                pltpu.VMEM((bk, d), jnp.float32),    # dv
+            ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary", "arbitrary"),
                 vmem_limit_bytes=_bwd_vmem_limit_bytes(
-                    block_q, bk, d, n, sub, q.dtype.itemsize)),
+                    block_q, bk, d, n, sub, itemsize, part_itemsize)),
             interpret=interpret,
             name=profiling.FLASH_BWD,
-        )(meta, qb[:, r0:r0 + n], kb, vb, dob[:, r0:r0 + n],
+        )(_meta(jnp.asarray(q_offset, jnp.int32) + r0, k_offset, s_k),
+          qb[:, r0:r0 + n], kb, vb, dob[:, r0:r0 + n],
           lse_b[:, :, r0:r0 + n], delta_b[:, :, r0:r0 + n])
 
-    parts = [call(r0, min(rows, qb.shape[1] - r0))
-             for r0 in range(0, qb.shape[1], rows)]
+    parts = [call(r0, min(rows, n_q - r0)) for r0 in range(0, n_q, rows)]
+    if len(parts) == 1:
+        return parts[0]
     dqs, dks, dvs = zip(*parts)
-    dq = dqs[0] if len(parts) == 1 else jnp.concatenate(dqs, axis=1)
-    dk, dv = functools.reduce(jnp.add, dks), functools.reduce(jnp.add, dvs)
-    # q was pre-scaled for s; the K-contraction needs one more scale.
-    dq = (dq * scale).astype(q.dtype)
-    dk, dv = dk.astype(k.dtype), dv.astype(v.dtype)
+    return (jnp.concatenate(dqs, axis=1).astype(out_dtype),
+            functools.reduce(jnp.add, dks).astype(out_dtype),
+            functools.reduce(jnp.add, dvs).astype(out_dtype))
 
-    def from_bh(x, s):
-        return x[:, :s].reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
-    return from_bh(dq, s_q), from_bh(dk, s_k), from_bh(dv, s_k)
+def flash_attention_backward(q, k, v, dout, lse, delta, causal,
+                             q_offset, k_offset, block_q, block_k,
+                             interpret, sub: int = 1024):
+    """Fused backward: (dq, dk, dv) from saved lse and Δ = rowsum(dO·O),
+    one kernel, one sweep over the tiles (:func:`_bwd_kernel`).
+
+    ``lse``/``delta``: [B, S_q, H] float32 — from
+    ``flash_attention_with_lse`` (or the ring's globally-merged
+    statistics), so the per-block probabilities recompute exactly without
+    an O(S²) tensor.
+
+    The entry of callers that sum what it returns again (ring and zigzag
+    attention, once per ring step): the gradients come back in float32,
+    [B, S, H, D], whatever the inputs' dtype, and the caller rounds once,
+    after its last sum.  ``flash_attention`` itself differentiates through
+    :func:`_backward_bh` and takes the compute dtype from the kernel.
+    """
+    b, s_q = q.shape[:2]
+    s_k = k.shape[1]
+
+    def stat_bh(x):     # [B, S, H] → [B·H, S]
+        return x.transpose(0, 2, 1).reshape(-1, x.shape[1])
+
+    lse_b = stat_bh(lse)
+    lse_b = jnp.broadcast_to(lse_b[:, None, :], (lse_b.shape[0], 8, s_q))
+    dq, dk, dv = _backward_bh(
+        _to_bh(q), _to_bh(k), _to_bh(v), _to_bh(dout.astype(q.dtype)),
+        lse_b, stat_bh(delta), s_q, s_k, causal, q_offset, k_offset,
+        block_q, block_k, interpret, sub, jnp.float32)
+    return _from_bh(dq, b, s_q), _from_bh(dk, b, s_k), _from_bh(dv, b, s_k)
+
+
+@jax.tree_util.register_static
+@dataclasses.dataclass(frozen=True)
+class _Lengths:
+    """The unpadded lengths, carried beside the padded residuals."""
+    s_q: int
+    s_k: int
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 6, 7, 8, 9))
 def _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
            interpret):
-    return _flash_forward(q, k, v, causal, q_offset, k_offset, block_q,
-                          block_k, interpret, sub=sub)
+    """One device, one call over the whole sequence: nothing sums its
+    results again, so the kernels write the compute dtype themselves."""
+    return _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k,
+                      sub, interpret)[0]
 
 
 def _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
                interpret):
-    out, lse = _flash_forward(q, k, v, causal, q_offset, k_offset, block_q,
-                              block_k, interpret, sub=sub, with_lse=True)
-    return out, (q, k, v, out, lse, q_offset, k_offset)
+    # The residuals stay as the forward call read and wrote them, in the
+    # layout the backward kernel reads: nothing is laid out twice.
+    qb, kb, vb, ob, lse_b = _forward_bh(
+        q, k, v, causal, q_offset, k_offset, block_q, block_k, interpret,
+        sub, q.dtype)
+    return _from_bh(ob, q.shape[0], q.shape[1]), (
+        qb, kb, vb, ob, lse_b, q_offset, k_offset,
+        _Lengths(q.shape[1], k.shape[1]))
 
 
 def _flash_bwd(causal, block_q, block_k, sub, interpret, res, g):
-    q, k, v, out, lse, q_offset, k_offset = res
-    # Δ = rowsum(dO·O) — the softmax-normalization term of the backward.
-    # [B, S, H, D] → [B, S, H], matching the lse layout.
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    dq, dk, dv = flash_attention_backward(
-        q, k, v, g, lse, delta, causal, q_offset, k_offset, block_q,
-        block_k, interpret, sub=sub)
-    return dq, dk, dv, None, None
+    qb, kb, vb, ob, lse_b, q_offset, k_offset, lengths = res
+    b = g.shape[0]
+    # Only the incoming cotangent is laid out here.
+    dob = _pad_to(_to_bh(g.astype(qb.dtype)), 1, ob.shape[1])
+    # Δ = rowsum(dO·O) — the softmax-normalization term of the backward,
+    # [B·H, S_q_pad] beside lse.
+    delta_b = jnp.sum(dob.astype(jnp.float32) * ob.astype(jnp.float32),
+                      axis=-1)
+    dq, dk, dv = _backward_bh(
+        qb, kb, vb, dob, lse_b, delta_b, lengths.s_q, lengths.s_k, causal,
+        q_offset, k_offset, block_q, block_k, interpret, sub, qb.dtype)
+    grads = (_from_bh(dq, b, lengths.s_q), _from_bh(dk, b, lengths.s_k),
+             _from_bh(dv, b, lengths.s_k))
+    # The gradients leave as they are: in the compute dtype.  Without the
+    # barrier XLA:TPU moves a consumer's float32 cast (rope's backward)
+    # ahead of the change of layout and copies the f32 array, twice the
+    # bytes (read off the compiled text of an Attention layer, PR 31).
+    return jax.lax.optimization_barrier(grads) + (None, None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -762,11 +867,12 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     — while the statically-unrolled sub loop keeps scoped VMEM bounded.
     ``block_q`` stays ≤1024: the [block_q, sub] s-tile is VMEM-resident
     and 2048 exceeds the 16 MiB scope at d=128.  The backward is one
-    kernel (:func:`_bwd_kernel`) whose f32 dq accumulator is the head's
-    whole q length in VMEM, so it asks Mosaic for a limit of its own
-    (:func:`_bwd_vmem_limit_bytes`; 30 MiB at S=16384) and a q too long
-    for the chip's VMEM (past 65536 rows at d=128) is cut into row
-    ranges, one call each.
+    kernel (:func:`_bwd_kernel`) whose f32 dq accumulator, and the dq
+    output block beside it, are the head's whole q length in VMEM, so it
+    asks Mosaic for a limit of its own (:func:`_bwd_vmem_limit_bytes`;
+    35 MiB at S=16384) and a q too long for the chip's VMEM (past 65536
+    rows at d=128) is cut into row ranges, one call each.  Output and
+    gradients come back in the inputs' dtype, written so by the kernels.
 
     Keep ``block_k / sub`` (and ``block_q / sub`` in the backward) at or
     below :data:`MAX_SUB_TILES` (8): the sub-tile sweep is statically
@@ -790,13 +896,17 @@ def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
                              k_offset=0, block_q: int = 1024,
                              block_k: int | None = None, sub: int = 1024,
                              interpret: bool | None = None):
-    """Forward-only fused attention returning (out, lse).
+    """Forward-only fused attention returning (out, lse): a PARTIAL
+    attention, for a caller that merges several of them.
 
     ``lse[b, s, h] = logsumexp_k(q·kᵀ·scale)`` (NEG_INF for rows that
     attended to nothing) — the combiner state ring attention needs to merge
-    partial attentions over K/V blocks exactly.  Differentiation is handled
-    by the caller (ring attention drives ``flash_attention_backward`` per
-    ring step with the globally-merged lse under its own vjp).
+    partial attentions over K/V blocks exactly; ``out`` is float32 whatever
+    the inputs' dtype (the kernel's accumulator, not yet rounded: the
+    merge weights and sums it again, and the caller rounds once at the
+    end).  Differentiation is handled by the caller (ring attention drives
+    ``flash_attention_backward`` per ring step with the globally-merged
+    lse under its own vjp).
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -807,8 +917,12 @@ def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
     block_q, block_k = clamp_blocks_to_vmem(
         block_q, block_k, q.shape[-1], sub, q.dtype.itemsize,
         where="flash_attention_with_lse")
-    return _flash_forward(q, k, v, causal, q_offset, k_offset, block_q,
-                          block_k, interpret, sub=sub, with_lse=True)
+    b, s_q = q.shape[:2]
+    *_, ob, lse_b = _forward_bh(q, k, v, causal, q_offset, k_offset, block_q,
+                                block_k, interpret, sub, jnp.float32)
+    # [B·H, 8, S_pad] (sublane-replicated) → [B, S, H]
+    lse = lse_b[:, 0, :s_q].reshape(b, -1, s_q).transpose(0, 2, 1)
+    return _from_bh(ob, b, s_q), lse
 
 
 def make_flash_attention(block_q: int = 1024, block_k: int | None = None,

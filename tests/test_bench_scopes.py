@@ -24,7 +24,8 @@ from horovod_tpu.utils.profiling import Scope  # noqa: E402
 NEW_READERS = ("fwd_ms", "bwd_ms", "recompute_ms", "optimizer_ms",
                "mixed_phase_ms", "unscoped_ms", "flash_fwd_ms", "flash_dq_ms",
                "flash_dkv_ms", "allreduce_buckets", "allreduce_lead_ms",
-               "allreduce_tail_ms", "loader_wait_ms", "h2d_ms")
+               "allreduce_tail_ms", "loader_wait_ms", "h2d_ms",
+               "attn_glue_ms")
 
 
 def reader(stem):
@@ -270,6 +271,33 @@ def test_flash_bwd_ms_reads_the_fused_pass(recorded, monkeypatch, kernel,
     # a program from before the fused pass has no such name: left out
     monkeypatch.delattr(profiling, "FLASH_BWD")
     assert reader("flash_bwd_ms").read(run) is None
+
+
+@pytest.mark.parametrize("module,glue", [
+    ("Transformer/layer_N/attn", True),            # rope, casts, copies
+    ("Transformer/layer_N/attn/q_norm", True),     # OLMoE's QK-norm
+    ("Transformer/layer_N/attn/q", False),         # the four projections
+    ("Transformer/layer_N/attn/o", False),
+    ("Transformer/layer_N/mlp/up", False),
+    ("hvd_optimizer", False),
+    ("", False),
+])
+def test_attn_glue_ms_is_the_attention_module_less_its_projections(
+        recorded, monkeypatch, module, glue):
+    """Every XLA op of the recorded window under one module: the reader
+    takes all of the window's XLA time, or none of it."""
+    dev = next(p for p in recorded if p["name"] == "/device:TPU:0")
+    ops = next(l for l in dev["lines"] if l["name"] == "XLA Ops")["events"]
+    table = {e[0]: scope(("backward",), module=module) for e in ops}
+    monkeypatch.setattr(trace, "load", lambda d: recorded)
+    monkeypatch.setattr(scopes, "table_of", lambda compiled: table)
+    summary = trace.reduce(recorded)
+    run = fake_run(summary, chips=1)
+    run.traced_steps = summary.calls
+    run.compiled, run.trace_dir = object(), "somewhere"
+    xla = 1e3 * summary.kind_s["xla"] / summary.calls
+    assert reader("attn_glue_ms").is_glue(module) is glue
+    assert reader("attn_glue_ms").read(run) == pytest.approx(glue * xla)
 
 
 def test_a_program_without_a_scope_table_joins_to_nothing(monkeypatch):

@@ -79,11 +79,13 @@ def test_plan_clamps_pinned_block_k_to_vmem():
 @pytest.mark.parametrize("s_q", [2048, 16384, 32768])
 def test_backward_vmem_limit_covers_its_estimate(s_q):
     """The one backward pass holds the head's whole f32 dq accumulator
-    (S_q·d·4 bytes), so it has an estimate of its own and asks Mosaic for a
-    limit from it.  At the default tiles (d=128, bf16) the limit covers the
-    estimate, the accumulator is in the estimate, and the ask stays under
-    the chip's VMEM, in one call; what the forward and the plan read
-    (``_vmem_estimate_bytes``, no ``s_q``) is untouched by it."""
+    (S_q·d·4 bytes) and, beside it, the dq output block in the gradients'
+    own dtype (S_q·d·2 in bf16; ·4 for a caller that asks for float32), so
+    it has an estimate of its own and asks Mosaic for a limit from it.  At
+    the default tiles (d=128, bf16) the limit covers the estimate, both
+    terms are in the estimate, and the ask stays under the chip's VMEM, in
+    one call; what the forward and the plan read (``_vmem_estimate_bytes``,
+    no ``s_q``) is untouched by it."""
     from horovod_tpu.ops.flash_attention import (
         _BWD_VMEM_ASK_MAX_BYTES,
         VMEM_PHYSICAL_MB,
@@ -96,7 +98,9 @@ def test_backward_vmem_limit_covers_its_estimate(s_q):
     est = _bwd_vmem_estimate_bytes(*geometry)
     limit = _bwd_vmem_limit_bytes(*geometry)
     assert est - _bwd_vmem_estimate_bytes(1024, 1024, 128, 0) \
-        == s_q * 128 * 4
+        == s_q * 128 * (4 + 2)
+    assert _bwd_vmem_estimate_bytes(*geometry, out_itemsize=4) - est \
+        == (s_q + 2 * 2 * 1024) * 128 * 2       # dq's block, dk's and dv's
     assert est <= limit <= _BWD_VMEM_ASK_MAX_BYTES \
         < VMEM_PHYSICAL_MB * 2 ** 20
     assert limit >= 16 * 2 ** 20        # never under Mosaic's default
@@ -115,8 +119,14 @@ def test_backward_rows_per_call_on_each_side_of_the_bound():
     assert _bwd_q_rows_per_call(1024, 1024, 128, 65536) == 65536
     assert _bwd_q_rows_per_call(1024, 1024, 128, 131072) == 65536
     rows = _bwd_q_rows_per_call(1024, 1024, 128, 262144)
-    assert rows == 86 * 1024
+    assert rows == 64 * 1024
     assert _bwd_vmem_limit_bytes(1024, 1024, 128, rows) \
+        <= _BWD_VMEM_ASK_MAX_BYTES
+    # several calls hand back float32 parts (dk and dv are summed over
+    # them before the cast), and are priced with float32 output blocks
+    rows = _bwd_q_rows_per_call(1024, 1024, 128, 262144, out_itemsize=4)
+    assert rows == 52 * 1024
+    assert _bwd_vmem_limit_bytes(1024, 1024, 128, rows, out_itemsize=4) \
         <= _BWD_VMEM_ASK_MAX_BYTES
 
 

@@ -346,13 +346,84 @@ def test_backward_entry_matches_dense(hvd, s_q, s_k, q_offset, k_offset,
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
 
 
-def test_backward_entry_bf16_unequal_lengths(hvd):
-    got, dense = _backward_case(48, 80, 32, 0, True, 16, 32, 1024,
-                                dtype=jnp.bfloat16)
+@pytest.mark.parametrize("s_q,s_k,q_offset,k_offset", [
+    (48, 80, 32, 0),      # a ring step: a late shard of q, a longer K
+    (32, 32, 96, 32),     # a zigzag half pair: equal chunks, both offset
+])
+def test_backward_entry_returns_float32_partials(hvd, s_q, s_k, q_offset,
+                                                 k_offset):
+    """Ring and zigzag attention call this entry once per ring step and sum
+    what it returns: bf16 inputs, float32 gradients (the kernel's f32
+    accumulators, written as they are), rounded by the caller after its
+    last sum."""
+    got, dense = _backward_case(s_q, s_k, q_offset, k_offset, True, 16, 32,
+                                1024, dtype=jnp.bfloat16)
     for a, b in zip(got, dense):
-        assert a.dtype == jnp.bfloat16
-        np.testing.assert_allclose(np.asarray(a, np.float32), b,
-                                   atol=6e-2, rtol=6e-2)
+        assert a.dtype == jnp.float32
+        np.testing.assert_allclose(a, b, atol=6e-2, rtol=6e-2)
+    # and what it returns holds more than bf16 does: not yet rounded
+    assert any(np.any(np.asarray(a) != np.asarray(a.astype(jnp.bfloat16),
+                                                   np.float32)) for a in got)
+
+
+@pytest.mark.parametrize("causal,s_q,s_k,q_offset,k_offset,bq,bk,sub", [
+    (True, 64, 64, 0, 0, 16, 16, 1024),      # whole tiles
+    (False, 64, 64, 0, 0, 16, 16, 1024),
+    (True, 50, 50, 0, 0, 16, 16, 1024),      # S no multiple of the tile
+    (False, 40, 72, 0, 0, 16, 16, 1024),
+    (True, 40, 72, 32, 0, 16, 16, 1024),     # offsets, unequal lengths
+    (True, 64, 64, 0, 32, 16, 32, 1024),     # rows that attend to nothing
+    (True, 64, 96, 32, 0, 32, 32, 8),        # block / sub > 1
+])
+def test_compute_dtype_outputs_are_the_float32_outputs_cast(
+        hvd, causal, s_q, s_k, q_offset, k_offset, bq, bk, sub):
+    """The kernels write bf16 themselves, on the grid step that finishes a
+    block, from the f32 accumulator a float32 output would hold: the same
+    arithmetic and the same one rounding as a cast after the call, so the
+    two agree to the bit -- forward and backward, and through
+    ``flash_attention``'s own vjp (residuals kept in the kernels' layout)
+    against the [B, S, H, D] entry ring attention calls."""
+    import importlib
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+
+    bf16 = jnp.bfloat16
+    ks = jax.random.split(jax.random.PRNGKey(s_q + 7 * s_k + q_offset), 4)
+    q, do = (jax.random.normal(kk, (2, s_q, 2, 16), bf16) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (2, s_k, 2, 16), bf16) for kk in ks[2:])
+    forward = lambda dtype: fa._forward_bh(  # noqa: E731
+        q, k, v, causal, q_offset, k_offset, bq, bk, True, sub, dtype)
+    *_, o16, lse16 = forward(bf16)
+    qb, kb, vb, o32, lse32 = forward(jnp.float32)
+    assert (o16.dtype, o32.dtype) == (bf16, jnp.float32)
+    np.testing.assert_array_equal(o16, o32.astype(bf16))
+    np.testing.assert_array_equal(lse16, lse32)
+
+    dob = fa._pad_to(fa._to_bh(do), 1, o16.shape[1])
+    delta = jnp.sum(dob.astype(jnp.float32) * o16.astype(jnp.float32), -1)
+    backward = lambda dtype: fa._backward_bh(  # noqa: E731
+        qb, kb, vb, dob, lse16, delta, s_q, s_k, causal, q_offset, k_offset,
+        bq, bk, True, sub, dtype)
+    g16, g32 = backward(bf16), backward(jnp.float32)
+    for a, b in zip(g16, g32):
+        assert (a.dtype, b.dtype) == (bf16, jnp.float32)
+        np.testing.assert_array_equal(a, b.astype(bf16))
+
+    # the public path: [B, S, H, D] in and out, in the compute dtype
+    out, vjp = jax.vjp(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, q_offset=q_offset, k_offset=k_offset,
+        block_q=bq, block_k=bk, sub=sub), q, k, v)
+    o_ring, lse = fa.flash_attention_with_lse(
+        q, k, v, causal=causal, q_offset=q_offset, k_offset=k_offset,
+        block_q=bq, block_k=bk, sub=sub)
+    assert (out.dtype, o_ring.dtype) == (bf16, jnp.float32)
+    np.testing.assert_array_equal(out, o_ring.astype(bf16))
+    ring = fa.flash_attention_backward(
+        q, k, v, do, lse, jnp.sum(do.astype(jnp.float32)
+                                  * out.astype(jnp.float32), -1),
+        causal, q_offset, k_offset, bq, bk, True, sub=sub)
+    for a, b in zip(vjp(do), ring):
+        assert (a.dtype, b.dtype) == (bf16, jnp.float32)
+        np.testing.assert_array_equal(a, b.astype(bf16))
 
 
 @pytest.mark.parametrize("bq,sub", [(16, 1024), (32, 8)])

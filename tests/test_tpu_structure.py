@@ -5,6 +5,9 @@ file of the suite that loads the TPU compiler: keep such tests here, and
 the topology inside the fixture (a module that describes it while being
 imported gives pytest-xdist's workers different tests to collect)."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -80,10 +83,13 @@ def test_width1_update_is_no_epilogue_of_a_weight_gradient_matmul(
 def test_fused_flash_backward_compiles_within_the_vmem_it_asks_for(
         one_chip_mesh, b, s):
     """The backward's one kernel holds a head's whole f32 dq accumulator in
-    VMEM (8 MiB at S=16384, 16 at S=32768): past Mosaic's 16 MiB default,
-    so the call asks for its own limit.  The chip's compiler takes the
-    kernel at the benchmark's three geometries and at S=32768 (H=16,
-    d=128, bf16, default tiles) and makes one custom call of it."""
+    VMEM (8 MiB at S=16384, 16 at S=32768) and the dq output block beside
+    it (half that in bf16, as much again in float32): past Mosaic's 16 MiB
+    default, so the call asks for its own limit.  The chip's compiler
+    takes the kernel at the benchmark's three geometries and at S=32768
+    (H=16, d=128, bf16, default tiles), with the gradients in the compute
+    dtype (``flash_attention``'s own backward) and in float32 (the entry
+    ring attention calls), and makes one custom call of it."""
     import importlib
 
     from horovod_tpu.utils import profiling
@@ -92,13 +98,128 @@ def test_fused_flash_backward_compiles_within_the_vmem_it_asks_for(
     one_chip = NamedSharding(one_chip_mesh, P())
     x = jax.ShapeDtypeStruct((b, s, 16, 128), jnp.bfloat16, sharding=one_chip)
     stat = jax.ShapeDtypeStruct((b, s, 16), jnp.float32, sharding=one_chip)
+    xb = jax.ShapeDtypeStruct((b * 16, s, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    lse_b = jax.ShapeDtypeStruct((b * 16, 8, s), jnp.float32,
+                                 sharding=one_chip)
+    delta_b = jax.ShapeDtypeStruct((b * 16, s), jnp.float32,
+                                   sharding=one_chip)
+    tiles = (1024, fa._default_block_k(s, 128))
 
-    def backward(q, k, v, do, lse, delta):
+    def partials(q, k, v, do, lse, delta):
         return fa.flash_attention_backward(
-            q, k, v, do, lse, delta, True, 0, 0, 1024,
-            fa._default_block_k(s, 128), False)
+            q, k, v, do, lse, delta, True, 0, 0, *tiles, False)
 
-    text = jax.jit(backward).lower(x, x, x, x, stat, stat).compile().as_text()
-    kernels = [line for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(kernels) == 1 and profiling.FLASH_BWD in kernels[0]
+    def compute_dtype(qb, kb, vb, dob, lse_b, delta_b):
+        return fa._backward_bh(qb, kb, vb, dob, lse_b, delta_b, s, s, True,
+                               0, 0, *tiles, False, 1024, jnp.bfloat16)
+
+    for fn, args, dtype in (
+            (partials, (x, x, x, x, stat, stat), "f32"),
+            (compute_dtype, (xb, xb, xb, xb, lse_b, delta_b), "bf16")):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        kernels = [line for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(kernels) == 1 and profiling.FLASH_BWD in kernels[0]
+        assert kernels[0].split(" = ")[1].startswith(
+            f"({dtype}[{b * 16},{s},128]")
+
+
+def _arrays(shape: str) -> list[tuple[str, int]]:
+    """[(dtype, elements)] of every array in a shape's text."""
+    from horovod_tpu.utils.profiling import _ARRAY
+
+    return [(t, math.prod(int(n) for n in dims.split(",") if n))
+            for t, _, dims in _ARRAY.findall(shape)]
+
+
+def _entry_instructions(text: str) -> list[dict]:
+    """The ENTRY computation's instructions: name, opcode, result shape,
+    the operands' shapes, and whether a matmul is inside (its own opcode,
+    or the computation a fusion calls)."""
+    from horovod_tpu.utils.profiling import _computations
+
+    comps = _computations(text)
+    shape_of = {name: shape for body in comps.values()
+                for name, _, _, _, shape in body}
+    with_matmul = {c for c, body in comps.items()
+                   if any(op in ("convolution", "dot")
+                          for _, op, _, _, _ in body)}
+    entry = text[text.index("\nENTRY "):]
+    out = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"^\s+(?:ROOT )?%?([^\s=]+) = .*?\s([\w\-]+)\((.*)$", line)
+        if not m or m.group(1) not in shape_of:
+            continue
+        name, opcode, rest = m.groups()
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        calls = re.search(r"calls=%?([\w.\-]+)", rest)
+        out.append({
+            "name": name, "opcode": opcode, "shape": shape_of[name],
+            "operands": [shape_of[o] for o in operands if o in shape_of],
+            "kernel": 'custom_call_target="tpu_custom_call"' in line,
+            "matmul": opcode in ("convolution", "dot")
+            or bool(calls and calls.group(1) in with_matmul)})
+    return out
+
+
+# (B, S): at most this many full-size ``copy`` ops, none of them float32,
+# and at most this many GB moved by XLA ops that are neither matmul nor
+# kernel.  The parent of PR 31 (f32 kernel outputs, residuals in
+# [B, S, H, D]): 6 copies, two of them f32, 3.35 GB; 0 copies, 1.84 GB.
+@pytest.mark.parametrize("b,s,copies,glue_gb", [(8, 2048, 6, 2.8),
+                                                (1, 16384, 0, 1.75)])
+def test_attention_layer_keeps_no_f32_activation_between_its_kernels(
+        hvd, one_chip_mesh, monkeypatch, b, s, copies, glue_gb):
+    """One ``Attention`` layer at the benchmark's widths (16 heads of 128,
+    bf16), forward and backward, as the chip's compiler leaves it: the
+    flash kernels read and write the compute dtype (no float32 array of
+    B·H·S·D elements goes into or comes out of a kernel, and no standalone
+    ``convert`` reads one), what changes a layout between a projection
+    and a kernel does so on bf16, and XLA's own traffic around the
+    kernels stays under what PR 31 left (2.66 / 1.69 GB a layer)."""
+    from horovod_tpu.models.transformer import Attention, TransformerConfig
+    from horovod_tpu.utils.profiling import _shape_bytes
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # Mosaic
+    one_chip = NamedSharding(one_chip_mesh, P())
+    layer = Attention(TransformerConfig(
+        num_heads=16, head_dim=128, embed_dim=2048,
+        attention_fn=hvd.make_flash_attention()))
+    x = jax.ShapeDtypeStruct((b, s, 2048), jnp.bfloat16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x, pos))
+
+    def forward_and_backward(params, x, pos, g):
+        out, vjp = jax.vjp(lambda p, x: layer.apply(p, x, pos), params, x)
+        return (out,) + vjp(g)
+
+    text = jax.jit(forward_and_backward).lower(
+        params, x, pos, x).compile().as_text()
+    full = b * s * 16 * 128
+    f32_full = lambda shapes: [  # noqa: E731
+        sh for sh in shapes
+        if any(t == "f32" and n >= full for t, n in _arrays(sh))]
+    instructions = _entry_instructions(text)
+    kernels = [i for i in instructions if i["kernel"]]
+    assert len(kernels) == 2
+    for k in kernels:
+        assert not f32_full([k["shape"]] + k["operands"]), k["name"]
+    for i in instructions:
+        if i["opcode"] == "convert":
+            assert not f32_full(i["operands"]), i["name"]
+    full_copies = [i for i in instructions if i["opcode"] == "copy"
+                   and any(n >= full for _, n in _arrays(i["shape"]))]
+    assert not f32_full([i["shape"] for i in full_copies])
+    assert len(full_copies) <= copies, [i["name"] for i in full_copies]
+    moved = sum(
+        _shape_bytes(sh)
+        for i in instructions
+        if not (i["kernel"] or i["matmul"] or i["opcode"] in (
+            "parameter", "constant", "tuple", "get-tuple-element",
+            "bitcast", "iota", "custom-call")
+            or i["opcode"].endswith(("-start", "-done")))
+        for sh in [i["shape"]] + i["operands"])
+    assert moved / 1e9 <= glue_gb
