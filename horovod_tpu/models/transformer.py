@@ -15,11 +15,14 @@ MXU-aligned widths, all shapes static.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 from typing import Any, Callable
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from horovod_tpu.ops.flash_attention import repeat_kv_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -87,6 +90,49 @@ class TransformerConfig:
     # The ContextPlan (ops/schedule_plan.plan_context) that decided the
     # layout, kernel tiles, and remat policy for this model.
     context_plan: Any = None
+    # The sequence mixer of each layer, by name: "attention" (Attention) or
+    # "mamba" (models/mamba.py: Mamba-2).  None is "attention" num_layers
+    # times; otherwise one entry a layer.  A layer type owns its parameters
+    # and its sizes below; every layer is followed by the same feed-forward.
+    layer_types: tuple | None = None
+    # Grouped-query attention: K and V are projected at num_kv_heads heads
+    # (None = num_heads) and query head j reads KV head j // group.
+    num_kv_heads: int | None = None
+    # False: attention sees no positions at all ("nope").
+    rotary: bool = True
+    # The softmax scale; None is head_dim ** -0.5.
+    attention_scale: float | None = None
+    # The output head is the embedding transposed: no lm_head parameter.
+    tie_embeddings: bool = False
+    # h0 = embedding_multiplier * embed(tokens); a layer adds
+    # residual_multiplier times what its mixer and its feed-forward give;
+    # the logits are divided by logits_scaling.  At 1 none of them is an op.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # Mamba-2 sizes ("mamba" layers): heads x head size inner channels,
+    # a state of state_dim a channel, B and C shared by heads / groups heads,
+    # the causal convolution's taps, the scan's chunk.
+    mamba_heads: int = 0
+    mamba_head_dim: int = 64
+    mamba_state_dim: int = 128
+    mamba_groups: int = 1
+    mamba_conv_width: int = 4
+    mamba_chunk: int = 256
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """One mixer name a layer."""
+        if self.layer_types is None:
+            return ("attention",) * self.num_layers
+        if len(self.layer_types) != self.num_layers:
+            raise ValueError(f"layer_types names {len(self.layer_types)} "
+                             f"layers, num_layers is {self.num_layers}")
+        return tuple(self.layer_types)
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads or self.num_heads
 
 
 class RMSNorm(nn.Module):
@@ -122,9 +168,12 @@ def rope(x, positions, theta: float):
     return out.astype(x.dtype)
 
 
-def dense_causal_attention(q, k, v, causal: bool = True):
-    """Reference attention: one softmax(QKᵀ)V, causal-masked. [B, S, H, D]."""
-    scale = q.shape[-1] ** -0.5
+def dense_causal_attention(q, k, v, causal: bool = True,
+                           scale: float | None = None):
+    """Reference attention: one softmax(QKᵀ)V, causal-masked. [B, S, H, D];
+    k and v may have fewer heads (grouped-query)."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    k, v = repeat_kv_heads(k, q.shape[2]), repeat_kv_heads(v, q.shape[2])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
@@ -144,7 +193,7 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     appends one position per step, so the buffer is allocated once and
     the jitted programs never see a shape change."""
     s = max_len or cfg.max_seq_len
-    shape = (cfg.num_layers, num_slots, s, cfg.num_heads, cfg.head_dim)
+    shape = (cfg.num_layers, num_slots, s, cfg.kv_heads, cfg.head_dim)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
 
 
@@ -155,12 +204,13 @@ def init_kv_pages(cfg: TransformerConfig, num_pages: int, page_size: int):
     slot is a row of page ids (its page table) and a page holding a
     shared prompt-prefix chunk can appear in many slots' rows at once.
     Page 0 is the scratch page inactive slots point at."""
-    shape = (cfg.num_layers, num_pages, page_size, cfg.num_heads,
+    shape = (cfg.num_layers, num_pages, page_size, cfg.kv_heads,
              cfg.head_dim)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
 
 
-def cached_decode_attention(q, k_cache, v_cache, lengths):
+def cached_decode_attention(q, k_cache, v_cache, lengths,
+                            scale: float | None = None):
     """Block attention over a per-slot KV cache.
 
     ``q``: [B, S_q, H, D] — the block of positions being decoded per
@@ -171,8 +221,11 @@ def cached_decode_attention(q, k_cache, v_cache, lengths):
     position of the block, just written), everything past each row's own
     position masked causally.  Same f32-softmax/-1e30-mask arithmetic as
     :func:`dense_causal_attention`, so an incrementally decoded position
-    matches the full forward pass."""
-    scale = q.shape[-1] ** -0.5
+    matches the full forward pass.  The caches may hold fewer heads than
+    ``q`` (grouped-query)."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    k_cache = repeat_kv_heads(k_cache, q.shape[2])
+    v_cache = repeat_kv_heads(v_cache, q.shape[2])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache).astype(
         jnp.float32) * scale
     s, s_q = k_cache.shape[1], q.shape[1]
@@ -190,20 +243,25 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, positions, cache=None, return_kv=False):
         cfg = self.cfg
-        proj = lambda name: nn.DenseGeneral(  # noqa: E731
-            (cfg.num_heads, cfg.head_dim), use_bias=False, dtype=cfg.dtype,
+        proj = lambda name, heads: nn.DenseGeneral(  # noqa: E731
+            (heads, cfg.head_dim), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name=name)
 
-        def rotated(name):
-            y = proj(name)(x)
+        def rotated(name, heads):
+            y = proj(name, heads)(x)
             if cfg.qk_norm:     # over the whole projection, all heads as one
                 y = RMSNorm(
                     dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                     epsilon=cfg.norm_eps, name=f"{name}_norm")(
                     y.reshape(*y.shape[:-2], -1)).reshape(y.shape)
-            return rope(y, positions, cfg.rope_theta)
+            return rope(y, positions, cfg.rope_theta) if cfg.rotary else y
 
-        q, k, v = rotated("q"), rotated("k"), proj("v")(x)
+        q, k = rotated("q", cfg.num_heads), rotated("k", cfg.kv_heads)
+        v = proj("v", cfg.kv_heads)(x)
+        # a caller's softmax scale goes to the attention function by name;
+        # without one the call is what it always was
+        scaled = ({} if cfg.attention_scale is None
+                  else {"scale": cfg.attention_scale})
         o_proj = nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), use_bias=False,
                                  dtype=cfg.dtype,
                                  param_dtype=cfg.param_dtype, name="o")
@@ -218,15 +276,20 @@ class Attention(nn.Module):
                 c, u, (i, 0, 0))
             k_cache = jax.vmap(upd)(k_cache, k, lengths)
             v_cache = jax.vmap(upd)(v_cache, v, lengths)
-            out = cached_decode_attention(q, k_cache, v_cache, lengths)
+            out = cached_decode_attention(q, k_cache, v_cache, lengths,
+                                          **scaled)
             return o_proj(out), (k_cache, v_cache)
         attn = cfg.attention_fn
         if attn is None and cfg.context_axis and cfg.context_plan is not None:
+            if cfg.kv_heads != cfg.num_heads or scaled:
+                raise NotImplementedError(
+                    "ring / zigzag attention over a context axis takes as "
+                    "many KV heads as query heads and the d^-1/2 scale")
             from horovod_tpu.parallel.context import context_attention_fn
 
             attn = context_attention_fn(cfg.context_axis, cfg.context_plan)
         attn = attn or dense_causal_attention
-        out = attn(q, k, v, causal=True)
+        out = attn(q, k, v, causal=True, **scaled)
         if return_kv:
             return o_proj(out), (k, v)
         return o_proj(out)
@@ -247,8 +310,27 @@ class MLP(nn.Module):
                         name="down")(nn.silu(gate) * up)
 
 
+# layer type -> (module of the mixer's class, the class, its name in a layer).
+# A mixer is ``Mixer(cfg, name=...)(x, positions)``; one that can serve from a
+# cache also takes ``cache`` / ``return_kv`` and then returns (out, kv).
+MIXERS = {
+    "attention": ("horovod_tpu.models.transformer", "Attention", "attn"),
+    "mamba": ("horovod_tpu.models.mamba", "Mamba2Mixer", "mamba"),
+}
+CACHED_MIXERS = ("attention",)
+
+
+def _scaled(x, multiplier: float):
+    """x * multiplier, the product in float32 (0.22 is no bf16 number);
+    at 1 no op at all."""
+    if multiplier == 1.0:
+        return x
+    return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
+    layer_type: str = "attention"
 
     @nn.compact
     def __call__(self, x, positions, cache=None, return_kv=False):
@@ -256,14 +338,20 @@ class Block(nn.Module):
         norm = lambda name: RMSNorm(  # noqa: E731
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             epsilon=cfg.norm_eps, name=name)
-        y = norm("attn_norm")(x)
+        try:
+            module, cls, name = MIXERS[self.layer_type]
+        except KeyError:
+            raise ValueError(f"layer type {self.layer_type!r}; "
+                             f"models/transformer.py has {sorted(MIXERS)}"
+                             ) from None
+        mixer = getattr(importlib.import_module(module), cls)(cfg, name=name)
+        y = norm(f"{name}_norm")(x)
         kv = None
         if cache is not None or return_kv:
-            attn_out, kv = Attention(cfg, name="attn")(
-                y, positions, cache=cache, return_kv=return_kv)
+            mixed, kv = mixer(y, positions, cache=cache, return_kv=return_kv)
         else:
-            attn_out = Attention(cfg, name="attn")(y, positions)
-        x = x + attn_out
+            mixed = mixer(y, positions)
+        x = x + _scaled(mixed, cfg.residual_multiplier)
         y = norm("mlp_norm")(x)
         if cfg.num_experts > 0:
             from horovod_tpu.models.moe import MoEMLP
@@ -272,22 +360,23 @@ class Block(nn.Module):
                 raise ValueError("num_experts (every expert on each device) "
                                  "and moe_axis (one expert a device) are two "
                                  "layouts; set one")
-            x = x + MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
-                           axis_name=None, dtype=cfg.dtype,
-                           num_experts=cfg.num_experts,
-                           experts_per_token=cfg.experts_per_token,
-                           norm_topk_prob=cfg.norm_topk_prob,
-                           param_dtype=cfg.param_dtype, name="moe_mlp")(y)
+            ff = MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
+                        axis_name=None, dtype=cfg.dtype,
+                        num_experts=cfg.num_experts,
+                        experts_per_token=cfg.experts_per_token,
+                        norm_topk_prob=cfg.norm_topk_prob,
+                        param_dtype=cfg.param_dtype, name="moe_mlp")(y)
         elif cfg.moe_axis is not None:
             from horovod_tpu.models.moe import MoEMLP
 
             # Residual carries over-capacity (dropped) tokens unchanged.
-            x = x + MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
-                           axis_name=cfg.moe_axis,
-                           capacity_factor=cfg.moe_capacity_factor,
-                           dtype=cfg.dtype, name="moe_mlp")(y)
+            ff = MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
+                        axis_name=cfg.moe_axis,
+                        capacity_factor=cfg.moe_capacity_factor,
+                        dtype=cfg.dtype, name="moe_mlp")(y)
         else:
-            x = x + MLP(cfg, name="mlp")(y)
+            ff = MLP(cfg, name="mlp")(y)
+        x = x + _scaled(ff, cfg.residual_multiplier)
         if cache is not None or return_kv:
             return x, kv
         return x
@@ -325,8 +414,16 @@ class Transformer(nn.Module):
                  kv_cache=None, lengths=None, return_kv=False):
         cfg = self.cfg
         decode = kv_cache is not None
-        x = nn.Embed(cfg.vocab_size, cfg.embed_dim, dtype=cfg.dtype,
-                     param_dtype=cfg.param_dtype, name="embed")(tokens)
+        kinds = cfg.layer_kinds
+        if (decode or return_kv) and set(kinds) - set(CACHED_MIXERS):
+            raise NotImplementedError(
+                f"decode and serving through a recurrent layer are not "
+                f"supported yet: layer_types holds "
+                f"{sorted(set(kinds) - set(CACHED_MIXERS))}, and kv_cache / "
+                f"return_kv serve {list(CACHED_MIXERS)} layers only")
+        embed = nn.Embed(cfg.vocab_size, cfg.embed_dim, dtype=cfg.dtype,
+                         param_dtype=cfg.param_dtype, name="embed")
+        x = _scaled(embed(tokens), cfg.embedding_multiplier)
         if decode:
             # Block row i of a cache call decodes position lengths + i:
             # S=1 is plain decode, S>1 is a speculative verify window or a
@@ -350,27 +447,31 @@ class Transformer(nn.Module):
             and not return_kv
         block_cls = nn.remat(Block) if remat_on else Block
         kvs = []
-        for i in range(cfg.num_layers):
+        for i, kind in enumerate(kinds):
+            block = block_cls(cfg, kind, name=f"layer_{i}")
             if decode:
-                x, kv = block_cls(cfg, name=f"layer_{i}")(
+                x, kv = block(
                     x, positions,
                     cache=(kv_cache[0][i], kv_cache[1][i], lengths))
                 kvs.append(kv)
             elif return_kv:
-                x, kv = block_cls(cfg, name=f"layer_{i}")(
-                    x, positions, return_kv=True)
+                x, kv = block(x, positions, return_kv=True)
                 kvs.append(kv)
             else:
-                x = block_cls(cfg, name=f"layer_{i}")(x, positions)
+                x = block(x, positions)
         x = RMSNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                     epsilon=cfg.norm_eps, name="final_norm")(x)
         # Head matmul in the compute dtype (bf16 hits the MXU at full rate;
         # f32 params, XLA accumulates in f32); logits upcast for the loss —
         # the standard LLM-trainer convention.  The f32 head matmul this
         # replaces was ~15% of step time (docs/benchmarks.md profile).
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          param_dtype=cfg.param_dtype, name="lm_head")(x)
-        logits = logits.astype(cfg.logits_dtype)
+        if cfg.tie_embeddings:
+            logits = embed.attend(x)
+        else:
+            logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                              param_dtype=cfg.param_dtype, name="lm_head")(x)
+        logits = _scaled(logits, 1.0 / cfg.logits_scaling).astype(
+            cfg.logits_dtype)
         if decode:
             kv_out = (jnp.stack([kv[0] for kv in kvs]),
                       jnp.stack([kv[1] for kv in kvs]))

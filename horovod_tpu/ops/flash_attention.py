@@ -408,6 +408,18 @@ def clamp_blocks_to_vmem(block_q: int, block_k: int, d: int,
     return bq, bk
 
 
+def repeat_kv_heads(x, num_heads: int):
+    """[B, S, H_kv, D] -> [B, S, H, D]: query head j reads KV head
+    j // (H / H_kv).  The same array where the two counts agree."""
+    kv = x.shape[2]
+    if kv == num_heads:
+        return x
+    if num_heads % kv:
+        raise ValueError(f"{kv} KV heads do not divide {num_heads} query "
+                         f"heads")
+    return jnp.repeat(x, num_heads // kv, axis=2)
+
+
 def _to_bh(x):
     """[B, S, H, D] → [B·H, S, D], the kernels' layout."""
     b, s, h, d = x.shape
@@ -427,7 +439,7 @@ def _meta(q_offset, k_offset, s_k: int):
 
 
 def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                interpret, sub, out_dtype):
+                interpret, sub, out_dtype, scale=None):
     """The forward kernel on [B, S, H, D] inputs, everything it read and
     wrote left in the kernels' layout, padded to whole blocks:
     ``(qb, kb, vb, ob, lse_b)`` with ``ob`` [B·H, S_q_pad, D] in
@@ -444,7 +456,8 @@ def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
     stat_block = pl.BlockSpec((1, 8, block_q), lambda bh, qi, ki: (bh, 0, qi))
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k, sub_k=sub_k,
-        num_k_blocks=num_k_blocks, causal=causal, scale=d ** -0.5)
+        num_k_blocks=num_k_blocks, causal=causal,
+        scale=d ** -0.5 if scale is None else scale)
     ob, lse_b = pl.pallas_call(
         kernel,
         grid=(qb.shape[0], num_q_blocks, num_k_blocks),
@@ -634,7 +647,8 @@ def _bwd_kernel(meta_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _backward_bh(qb, kb, vb, dob, lse_b, delta_b, s_q, s_k, causal, q_offset,
-                 k_offset, block_q, block_k, interpret, sub, out_dtype):
+                 k_offset, block_q, block_k, interpret, sub, out_dtype,
+                 scale=None):
     """The backward kernel on arrays in the kernels' layout: ``qb`` / ``dob``
     [B·H, ≥ s_q, D], ``kb`` / ``vb`` [B·H, ≥ s_k, D], ``lse_b`` [B·H, 8,
     ≥ s_q] (sublane-replicated) and ``delta_b`` [B·H, ≥ s_q] float32; rows
@@ -687,7 +701,7 @@ def _backward_bh(qb, kb, vb, dob, lse_b, delta_b, s_q, s_k, causal, q_offset,
         part_dtype = jnp.float32
         rows = _bwd_q_rows_per_call(block_q, bk, d, n_q, sub, itemsize, 4)
     part_itemsize = jnp.dtype(part_dtype).itemsize
-    scale = d ** -0.5
+    scale = d ** -0.5 if scale is None else scale
 
     def call(r0, n):
         # One kernel over the n q rows from r0 against all of K.  dq's
@@ -786,28 +800,28 @@ class _Lengths:
     s_k: int
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 6, 7, 8, 9, 10))
 def _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
-           interpret):
+           interpret, scale):
     """One device, one call over the whole sequence: nothing sums its
     results again, so the kernels write the compute dtype themselves."""
     return _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                      sub, interpret)[0]
+                      sub, interpret, scale)[0]
 
 
 def _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
-               interpret):
+               interpret, scale):
     # The residuals stay as the forward call read and wrote them, in the
     # layout the backward kernel reads: nothing is laid out twice.
     qb, kb, vb, ob, lse_b = _forward_bh(
         q, k, v, causal, q_offset, k_offset, block_q, block_k, interpret,
-        sub, q.dtype)
+        sub, q.dtype, scale)
     return _from_bh(ob, q.shape[0], q.shape[1]), (
         qb, kb, vb, ob, lse_b, q_offset, k_offset,
         _Lengths(q.shape[1], k.shape[1]))
 
 
-def _flash_bwd(causal, block_q, block_k, sub, interpret, res, g):
+def _flash_bwd(causal, block_q, block_k, sub, interpret, scale, res, g):
     qb, kb, vb, ob, lse_b, q_offset, k_offset, lengths = res
     b = g.shape[0]
     # Only the incoming cotangent is laid out here.
@@ -818,7 +832,8 @@ def _flash_bwd(causal, block_q, block_k, sub, interpret, res, g):
                       axis=-1)
     dq, dk, dv = _backward_bh(
         qb, kb, vb, dob, lse_b, delta_b, lengths.s_q, lengths.s_k, causal,
-        q_offset, k_offset, block_q, block_k, interpret, sub, qb.dtype)
+        q_offset, k_offset, block_q, block_k, interpret, sub, qb.dtype,
+        scale)
     grads = (_from_bh(dq, b, lengths.s_q), _from_bh(dk, b, lengths.s_k),
              _from_bh(dv, b, lengths.s_k))
     # The gradients leave as they are: in the compute dtype.  Without the
@@ -846,8 +861,18 @@ def _default_block_k(s_k: int, d: int) -> int:
 
 def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
                     block_q: int = 1024, block_k: int | None = None,
-                    sub: int = 1024, interpret: bool | None = None):
+                    sub: int = 1024, interpret: bool | None = None,
+                    scale: float | None = None):
     """Fused attention over [B, S, H, D] tensors.
+
+    ``scale`` is the softmax scale, ``d ** -0.5`` when None.  It reaches the
+    kernels as the constant they fold into q (an argument, not a pre-scale
+    of q outside: exact for any value, and no op of its own).  ``k`` and
+    ``v`` may have fewer heads than ``q`` (grouped-query attention, query
+    head j reading KV head j // group): they are repeated to q's heads
+    before the kernels, which see as many KV heads as query heads, and JAX
+    sums the group's dk and dv; the repeat and the sum are XLA's ops around
+    the kernels (``attn_glue_ms`` in the benchmark).
 
     ``q_offset``/``k_offset`` are global sequence positions of the first
     row/col (sequence-parallel shards pass shard_index × shard_len).
@@ -888,8 +913,9 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     block_k = min(block_k, max(k.shape[1], 1))
     block_q, block_k = clamp_blocks_to_vmem(
         block_q, block_k, q.shape[-1], sub, q.dtype.itemsize)
+    k, v = repeat_kv_heads(k, q.shape[2]), repeat_kv_heads(v, q.shape[2])
     return _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                  sub, interpret)
+                  sub, interpret, None if scale is None else float(scale))
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
@@ -929,7 +955,7 @@ def make_flash_attention(block_q: int = 1024, block_k: int | None = None,
                          sub: int = 1024):
     """Adapter producing a ``TransformerConfig.attention_fn``.  block_k
     defaults per-call to min(S, 2048) at d<=128 (_default_block_k)."""
-    def attn(q, k, v, causal=True):
+    def attn(q, k, v, causal=True, scale=None):
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k, sub=sub)
+                               block_k=block_k, sub=sub, scale=scale)
     return attn

@@ -51,6 +51,11 @@ MOE_ROUTE = "hvd_moe_route"     # models/moe: router matmul, softmax, top-k,
 MOE_DISPATCH = "hvd_moe_dispatch"   # ... tokens gathered into expert order
 MOE_EXPERTS = "hvd_moe_experts"     # ... the grouped matmuls, the activation
 MOE_COMBINE = "hvd_moe_combine"     # ... back to token order, gate-weighted sum
+SSM_PROJ = "hvd_ssm_proj"       # models/mamba: the in and out projections
+SSM_CONV = "hvd_ssm_conv"       # ... causal depthwise conv, bias, silu
+SSM_SCAN = "hvd_ssm_scan"       # ... ops/ssd_scan: softplus, decays, the intra-
+                                # and inter-chunk products, the D skip
+SSM_GATE = "hvd_ssm_gate"       # ... y * silu(z) and the gated RMSNorm
 LOADER_WAIT = "hvd_loader_wait"         # data.BackgroundLoader: q.get()
 LOADER_PRODUCE = "hvd_loader_produce"   # ... next(source), producer thread
 H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
@@ -59,6 +64,7 @@ H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
 
 FLASH_PASSES = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD)
 MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
+SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE)
 # XLA:TPU replaces ``lax.ragged_dot`` with Mosaic kernels of its own and
 # names them afresh (``op_name="ragged-dot-none"``, and
 # ``"ragged-dot-metadata"`` for the tile table they share): the scope the

@@ -469,3 +469,82 @@ def test_backward_long_q_is_cut_into_row_ranges(hvd, monkeypatch):
     for a, b, c in zip(cut, whole, dense):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
         np.testing.assert_allclose(a, c, atol=5e-4, rtol=5e-4)
+
+
+# -- grouped-query attention, head size 64, a caller's scale (PR 33) ---------
+
+def _grouped(b=1, s=48, h=8, kv=2, d=64, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (b, s, h, d), dtype),
+            jax.random.normal(ks[1], (b, s, kv, d), dtype),
+            jax.random.normal(ks[2], (b, s, kv, d), dtype))
+
+
+def _dense_by_hand(q, k, v, scale):
+    """Query head j reads KV head j // group, written out per head."""
+    group = q.shape[2] // k.shape[2]
+    outs = []
+    for j in range(q.shape[2]):
+        kj, vj = k[:, :, j // group], v[:, :, j // group]
+        logits = jnp.einsum("bqd,bkd->bqk", q[:, :, j], kj) * scale
+        mask = jnp.tril(jnp.ones(logits.shape[-2:], bool))
+        probs = jax.nn.softmax(jnp.where(mask, logits, -1e30), axis=-1)
+        outs.append(jnp.einsum("bqk,bkd->bqd", probs, vj))
+    return jnp.stack(outs, axis=2)
+
+
+@pytest.mark.parametrize("scale", [None, 0.015625, 0.3])
+def test_grouped_kv_heads_and_a_callers_scale_match_dense(hvd, scale):
+    """d = 64, 4 query heads a KV head: forward and every gradient against
+    dense attention written out per head, and against the program's own
+    dense path."""
+    q, k, v = _grouped()
+    used = 64 ** -0.5 if scale is None else scale
+    out = flash_attention(q, k, v, block_q=16, block_k=16, scale=scale)
+    np.testing.assert_allclose(out, _dense_by_hand(q, k, v, used),
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(
+        out, dense_causal_attention(q, k, v, scale=scale),
+        atol=2e-5, rtol=2e-5)
+    weights = jax.random.normal(jax.random.PRNGKey(5), out.shape)
+    g_flash = jax.grad(lambda *a: (flash_attention(
+        *a, block_q=16, block_k=16, scale=scale) * weights).sum(),
+        (0, 1, 2))(q, k, v)
+    g_dense = jax.grad(lambda *a: (_dense_by_hand(*a, used) * weights).sum(),
+                       (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", g_flash, g_dense):
+        assert a.shape == b.shape, name             # dk, dv at the KV heads
+        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4, err_msg=name)
+
+
+def test_grouped_kv_heads_in_bf16(hvd):
+    q, k, v = _grouped(dtype=jnp.bfloat16)
+    out = flash_attention(q, k, v, block_q=16, block_k=16, scale=0.015625)
+    assert out.dtype == jnp.bfloat16
+    ref = _dense_by_hand(*(a.astype(jnp.float32) for a in (q, k, v)),
+                         0.015625)
+    np.testing.assert_allclose(out.astype(jnp.float32), ref, atol=3e-2,
+                               rtol=3e-2)
+    grads = jax.grad(lambda *a: flash_attention(
+        *a, block_q=16, block_k=16, scale=0.015625).astype(
+        jnp.float32).sum(), (0, 1, 2))(q, k, v)
+    assert [g.dtype for g in grads] == [jnp.bfloat16] * 3
+    assert grads[1].shape == k.shape
+
+
+def test_kv_heads_that_do_not_divide_are_refused(hvd):
+    q, k, v = _grouped(h=8, kv=3)
+    with pytest.raises(ValueError, match="do not divide"):
+        flash_attention(q, k, v, block_q=16, block_k=16)
+
+
+def test_the_default_scale_is_the_kernels_old_constant(hvd):
+    """No scale and d ** -0.5 by name are one program: the argument adds no
+    op, so every configuration that names none compiles to what it did."""
+    q, k, v = _qkv(s=32)
+    f = lambda scale: jax.jit(lambda *a: flash_attention(  # noqa: E731
+        *a, block_q=16, block_k=16, scale=scale)).lower(q, k, v).as_text()
+    strip = lambda t: "\n".join(  # noqa: E731
+        line.split(" loc(")[0] for line in t.splitlines()
+        if not line.startswith("#loc"))
+    assert strip(f(None)) == strip(f(16 ** -0.5))

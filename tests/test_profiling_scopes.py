@@ -361,6 +361,42 @@ def test_remat_leaves_jaxs_marker_on_the_recomputed_forward():
                for s in table.values() if s.phase == "recompute")
 
 
+def test_the_mamba_mixers_four_scopes_reach_the_table_in_every_phase():
+    """``hvd_ssm_proj`` / ``_conv`` / ``_scan`` / ``_gate`` on a compiled
+    step of a rematted hybrid: forward, backward and recomputed instructions
+    keep the name, as a folded module path under the layer's ``mamba``."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import Transformer, TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=2, num_heads=2, head_dim=8, embed_dim=16,
+        mlp_dim=32, dtype=jnp.float32, remat=True,
+        layer_types=("mamba", "mamba"), mamba_heads=4, mamba_head_dim=8,
+        mamba_state_dim=8, mamba_chunk=8)
+    model = Transformer(cfg)
+    tokens = jnp.zeros((1, 24), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens)
+    loss = lambda p: model.apply(p, tokens).sum()  # noqa: E731
+    table = profiling.scope_table(
+        jax.jit(jax.value_and_grad(loss)).lower(params).compile())
+    assert len(profiling.SSM_SCOPES) == 4
+    for name in profiling.SSM_SCOPES:
+        phases = set()
+        for s in table.values():
+            parts = s.module.split("/")
+            if name in parts:
+                assert parts[:3] == ["Transformer", "layer_N", "mamba"], s
+                phases |= set(s.phases)
+        assert {"forward", "backward", "recompute"} <= phases, (name, phases)
+    # the projections are flax modules under the scope's name
+    modules = {s.module for s in table.values()}
+    assert f"Transformer/layer_N/mamba/{profiling.SSM_PROJ}/in_proj" in modules
+    assert f"Transformer/layer_N/mamba/{profiling.SSM_PROJ}/out_proj" \
+        in modules
+
+
 def host_events(logdir):
     """{span name: [(line, start_ns, end_ns)]} off the host planes; a line
     (one thread) is its place in the file, threads sharing their name."""
@@ -412,7 +448,8 @@ def test_loader_spans_nest_inside_the_callers_span(hvd_module, tmp_path):
 def test_every_name_of_the_vocabulary_is_written_once():
     names = [v for k, v in vars(profiling).items()
              if k.isupper() and isinstance(v, str) and v.startswith("hvd_")]
-    assert len(names) == len(set(names)) == 15      # four of models/moe.py
+    # four of models/moe.py, four of models/mamba.py
+    assert len(names) == len(set(names)) == 19
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for directory, _, files in itertools.chain(
             os.walk(os.path.join(root, "horovod_tpu")),

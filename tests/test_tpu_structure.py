@@ -223,3 +223,40 @@ def test_attention_layer_keeps_no_f32_activation_between_its_kernels(
             or i["opcode"].endswith(("-start", "-done")))
         for sh in [i["shape"]] + i["operands"])
     assert moved / 1e9 <= glue_gb
+
+
+def test_grouped_flash_attention_at_head_size_64_compiles_for_the_chip(
+        one_chip_mesh, monkeypatch):
+    """``granite4hm-s8192``'s one attention layer (PR 33): 32 query heads
+    reading 8 KV heads of 64, no rotary embedding, the softmax scale
+    2**-6, one 8192-token sequence.  The chip's compiler takes the forward
+    and the backward kernel at d = 64 through the public entry, K and V
+    repeated to the query heads outside them, and the caller's scale is a
+    constant of the kernels: no op of its own on q."""
+    import importlib
+
+    from horovod_tpu.utils import profiling
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, scale=0.015625).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2
+    assert sum(profiling.FLASH_FWD in k for k in kernels) == 1
+    assert sum(profiling.FLASH_BWD in k for k in kernels) == 1
+    # dk and dv leave at the KV heads' shape
+    root = [line for line in text.splitlines() if "ROOT" in line][-1]
+    assert "bf16[1,8192,32,64]" in root and root.count(
+        "bf16[1,8192,8,64]") == 2
