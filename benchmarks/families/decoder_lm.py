@@ -41,8 +41,9 @@ LOGITS_TOL = 0.03
 def model_config(cfg: dict, traffic: dict) -> TransformerConfig:
     heads = cfg["num_attention_heads"]
     if cfg["num_key_value_heads"] != heads:
-        raise ValueError("models/transformer.py has as many KV heads as "
-                         "query heads; this configuration does not")
+        raise ValueError("decoder_lm builds multi-head attention: as many KV "
+                         "heads as query heads (families/hybrid_lm.py "
+                         "passes num_kv_heads); this configuration has not")
     if cfg["rms_norm_eps"] != 1e-6:
         raise ValueError("models/transformer.py fixes RMSNorm's eps at 1e-6")
     if cfg.get("tie_word_embeddings"):

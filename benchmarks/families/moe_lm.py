@@ -86,8 +86,9 @@ def model_config(cfg: dict, traffic: dict) -> TransformerConfig:
             f"sparse decoder (no {sorted(set(MOE_FIELDS) - have)})")
     heads = cfg["num_attention_heads"]
     if cfg["num_key_value_heads"] != heads:
-        raise ValueError("models/transformer.py has as many KV heads as "
-                         "query heads; this configuration does not")
+        raise ValueError("moe_lm builds multi-head attention: as many KV "
+                         "heads as query heads (families/hybrid_lm.py "
+                         "passes num_kv_heads); this configuration has not")
     for key, want in (("tie_word_embeddings", False), ("rope_scaling", None),
                       ("clip_qkv", None), ("attention_bias", False),
                       ("hidden_act", "silu")):

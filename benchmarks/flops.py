@@ -31,8 +31,9 @@ def flash_train_flops(b: int, h: int, s: int, d: int, causal: bool) -> float:
     states them: 2 products forward (QK', PV), 5 backward (QK' again from
     the saved log-sum-exp, dO V', dS K, dS' Q, P' dO) -- FlashAttention's own
     count, backward = 2.5 x forward.  Each is 2 S^2 D a head, halved when
-    causal.  The program's two backward kernels each form QK' and dO V',
-    which makes 9; the extra 2 are not counted."""
+    causal.  Since PR 27 the program's one backward kernel forms each once,
+    so 7 is also what the kernels execute, but for a rematerialised layer,
+    whose forward runs twice and is counted once."""
     per_product = 2.0 * b * h * s * s * d * (0.5 if causal else 1.0)
     return 7.0 * per_product
 

@@ -1,9 +1,10 @@
 """Kernels (``ops/flash_attention``): device milliseconds a step inside the
-Pallas kernels (forward, dq, dk/dv), from the trace."""
+flash kernels, forward and backward: the kernels of the traced window that
+the program's scope table names as flash passes (``scopes.Joined.pass_s``
+over ``profiling.FLASH_PASSES``), whatever other kernels the step runs."""
+
+from benchmarks import scopes
 
 
 def read(run):
-    t = run.trace
-    if t is None or not run.built.flash_calls:
-        return None
-    return 1e3 * t.kind_s.get("flash", 0.0) / run.traced_steps
+    return scopes.flash_ms(run)

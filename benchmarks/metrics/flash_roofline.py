@@ -1,9 +1,12 @@
 """Kernels (``ops/flash_attention``): the least time the chip could take
 for a step's attention -- the larger of operations over peak FLOP/s and
 bytes over peak HBM bandwidth, from shapes (benchmarks/flops.py) and the
-peaks table -- over the time the trace shows in the kernels, in percent."""
+peaks table -- over the time the trace shows in the flash kernels
+(``flash_ms``), in percent.  The count is of needed work: where a layer is
+rematerialised its forward kernel runs twice and is counted once, so the
+reading lies under the kernels' share of peak for the work they executed."""
 
-from benchmarks import flops
+from benchmarks import flops, scopes
 
 
 def bound(run):
@@ -17,11 +20,10 @@ def bound(run):
 
 
 def read(run):
-    t = run.trace
-    if t is None or not run.built.flash_calls or not t.kind_s.get("flash"):
+    took_ms = scopes.flash_ms(run)
+    if not took_ms or run.peaks is None:
         return None
     least, which = bound(run)
-    took = t.kind_s["flash"] / run.traced_steps
     print(f"flash_roofline: bound_by={which} least_ms={1e3 * least:.3f} "
-          f"took_ms={1e3 * took:.3f}")
-    return 100.0 * least / took
+          f"took_ms={took_ms:.3f}")
+    return 100.0 * 1e3 * least / took_ms

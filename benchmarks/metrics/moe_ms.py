@@ -1,7 +1,11 @@
 """Models (``models/moe.py``): device milliseconds a step in the expert
-layers, every operation under a layer's module path: XLA's own operations by
-module (``scopes.Joined.module_s``) and the grouped-matmul kernels the
-program's scope table names ``hvd_moe_experts`` (``pass_s``).
+layers, every operation under a layer's module path: XLA's own operations
+and the kernels launched there, both by module path
+(``scopes.Joined.module_s`` and ``kernel_module_s``), and the grouped-matmul
+kernels the compiler makes of ``lax.ragged_dot``, which lose their
+``op_name`` and with it their path: the program's scope table names them
+``hvd_moe_experts``, and they are counted by that name (``pathless_s``; a
+kernel that has a path is counted by its path alone).
 
 :func:`parts` is what the other ``moe_*`` readers read: the same time by the
 four scopes the layer wraps its work in (``utils/profiling.py``:
@@ -18,27 +22,12 @@ ROLES = {"route": "MOE_ROUTE", "dispatch": "MOE_DISPATCH",
 def parts(run):
     """{"route", "dispatch", "experts", "combine", "elsewhere"}: device
     milliseconds a step, or None."""
-    j = scopes.of(run)
-    if j is None:
+    out = scopes.by_scope(run, ROLES)
+    if out is None:
         return None
     from horovod_tpu.utils import profiling
-    names = {role: getattr(profiling, const, None)
-             for role, const in ROLES.items()}
-    if None in names.values():
-        return None
-    ms = lambda seconds: 1e3 * seconds / run.traced_steps  # noqa: E731
-    under = lambda name: sum(  # noqa: E731
-        v for m, v in j.module_s.items() if name in m.split("/"))
-    out = {role: ms(under(name)) for role, name in names.items()}
-    # a grouped matmul that the compiler made a kernel of is no XLA op
-    out["experts"] += ms(j.pass_s.get(names["experts"], 0.0))
-    # the layers' own paths: what stands before a scope's name
-    layers = {m.split("/" + name)[0] for name in names.values()
-              for m in j.module_s if name in m.split("/")}
-    inside = sum(v for m, v in j.module_s.items()
-                 if any(m == p or m.startswith(p + "/") for p in layers))
-    out["elsewhere"] = ms(inside) - sum(
-        ms(under(name)) for name in names.values())
+    out["experts"] += 1e3 * scopes.of(run).pathless_s(profiling.MOE_EXPERTS) \
+        / run.traced_steps
     return out
 
 

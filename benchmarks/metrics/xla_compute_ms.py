@@ -1,6 +1,7 @@
 """Models (``models/transformer.py``, ``models/resnet.py``): device
-milliseconds a step in every operation that is neither a flash kernel nor
-a collective -- what XLA made of the model and the optimizer."""
+milliseconds a step in every operation that is neither a kernel (a Mosaic
+custom call, ours or the compiler's) nor a collective -- what XLA made of
+the model and the optimizer."""
 
 
 def read(run):

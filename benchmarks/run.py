@@ -61,6 +61,9 @@ class Run:
     compiles_in_window: int
     memory: dict | None         # the fullest chip's memory_stats() counters
     trace: object = None        # benchmarks.trace.Summary, traced runs only
+    compiled: object = None     # the timed step as compiled: its text holds
+                                # the program's names (benchmarks/scopes.py)
+    trace_dir: str | None = None    # where a traced run's profile lies
 
     @property
     def calls(self) -> int:
@@ -289,7 +292,7 @@ def main() -> int:
               setup_s=setup_s, stamps=list(stamps),
               losses=list(losses), input_wait_s=list(waits),
               dispatch_s=list(dispatches), fetch_s=list(fetches),
-              compiles_in_window=compiles, memory=memory)
+              compiles_in_window=compiles, memory=memory, compiled=step)
     print(f"memory: fullest_chip={json.dumps(memory)} "
           f"step_memory_analysis={json.dumps(program_bytes(analysis))}")
 
@@ -300,11 +303,11 @@ def main() -> int:
               "count": len(devices), "memory_peak_bytes": run.peak_bytes}
     breakdown = None
     if args.trace:
-        trace_dir = os.path.join(out_dir, f"{tag}.profile")
-        with trace.record(trace_dir):
+        run.trace_dir = os.path.join(out_dir, f"{tag}.profile")
+        with trace.record(run.trace_dir):
             target = len(stamps) + TRACED_CALLS
             calls_until(lambda: len(stamps) >= target)
-        run.trace = trace.reduce(trace.load(trace_dir))
+        run.trace = trace.reduce(trace.load(run.trace_dir))
         if run.trace is not None:
             device["busy_s"] = run.trace.busy_s
             device["window_s"] = run.trace.window_s
@@ -376,7 +379,19 @@ def main() -> int:
               "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # every number ``correct`` was decided from, beside its limit: the last
+    # lines of stderr and the last key of the line (what is kept of a run
+    # that was not correct)
+    compared = {c["name"]: [c["error"], c["tolerance"]] for c in checks}
+    compared["compiles_in_window"] = [compiles, 0]
+    compared["non_finite_losses"] = [finite.count(False), 0]
+    if traffic["expect_loss_to_fall"]:
+        compared["last_segment_loss_less_first"] = [
+            loss_means[-1] - loss_means[0], 0.0]
+    result["compared"] = compared
     hvd.shutdown()
+    for name, (value, limit) in compared.items():
+        print(f"compared: {name}={value!r} limit={limit!r}", file=sys.stderr)
     line = json.dumps(result)
     if dev.platform != "tpu":
         line = "REHEARSAL on " + dev.platform + ", no result: " + line

@@ -3,24 +3,34 @@
 ``benchmarks/trace.py`` tells three kinds of device operation apart by
 opcode.  The program says more: ``horovod_tpu.utils.profiling.scope_table``
 reads, from the compiled step's own text, each instruction's phase (forward,
-backward, recompute, optimizer), module, all-reduce bucket and flash pass.  A
-trace event is named by its instruction, so the two join by name: the
+backward, recompute, optimizer), module, all-reduce bucket and kernel name.
+A trace event is named by its instruction, so the two join by name: the
 program's vocabulary, the benchmark's clock.  Every name is the program's;
 none is written here (``profiling``'s constants are read, and a program
 without them, as every commit before PR 24 is, joins to nothing: the readers
 then return None and the line leaves their metrics out).
 
+One rule files a device operation: what the scope table says of it.  Being a
+Mosaic custom call decides its kind (``trace.KERNEL``) and nothing else: a
+kernel is kept under its kernel name (``pass_s``: a flash pass, the
+compiler's grouped matmul, ``(unnamed)``) and, where the table gives it a
+module path, under that path too (``kernel_module_s``), so a reader of a
+scope (``hvd_ssm_scan``, ``hvd_moe_dispatch``) counts XLA's operations and
+the kernels under it, and a kernel is nobody's by being a kernel.
+
 The window is ``trace.reduce``'s: on each chip from the start of the first
 whole execution of the step program to the end of the last, own times as
 ``trace.own_times`` gives them, means over the chips.  So by construction
-the phases of the ``xla`` kind sum to ``Summary.kind_s["xla"]`` and the
-passes of the ``flash`` kind to ``kind_s["flash"]``.
+the phases, and the modules, of the ``xla`` kind sum to
+``Summary.kind_s["xla"]``, and the kernels by name to
+``kind_s[trace.KERNEL]``, as the kernels by path do with the pathless ones.
 
-The compiled step and the trace's directory are not fields of ``Run``, and
-this PR may not edit ``run.py`` to add them: :func:`harness` takes them from
-``Run`` where a later benchmark PR has put them (``compiled``,
-``trace_dir``) and until then from the frame of ``run.main`` that called the
-reader, where they are the locals ``step`` and ``trace_dir``.
+The compiled step and the trace's directory are fields of ``Run``
+(``compiled``, ``trace_dir``), which ``run.main`` assigns.  :func:`harness`
+still looks for them in the frame of a ``main`` that called the reader, as
+it had to before ``Run`` had the fields: ``run.py`` never takes that path
+now, and it goes with the assertion of tests/test_bench_scopes.py (tier-1,
+no benchmark file) that holds it.
 """
 
 from __future__ import annotations
@@ -32,8 +42,10 @@ import sys
 
 from benchmarks import trace
 
+
 def harness(run) -> tuple:
-    """(the compiled step, the trace's directory), either of them None."""
+    """(the compiled step, the trace's directory), either of them None:
+    ``Run``'s two fields, and only where one is missing a caller's frame."""
     compiled = getattr(run, "compiled", None)
     trace_dir = getattr(run, "trace_dir", None)
     frame = sys._getframe(1)
@@ -68,12 +80,33 @@ class Joined:
     calls: int                # executions of the step program in the window
     phase_s: dict             # label -> seconds of xla own time, mean over chips
     module_s: dict            # folded module -> seconds of xla own time
-    pass_s: dict              # flash pass -> seconds of kernel own time
+    pass_s: dict              # kernel name -> seconds of kernel own time
     buckets: dict             # bucket -> {calls, bytes, seconds, start_s}
     lead_s: float             # a call: first bucket's start to backward's end
     tail_s: float             # a call: collective time after backward's end
     joined_share: float       # of all own time, found in the table
     span_s: dict              # host span role -> seconds inside the window
+    # (kernel name, folded module; "" where it has none) -> seconds of kernel
+    # own time: what pass_s sums by name, by where the program launched it
+    kernel_s: dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def kernel_module_s(self) -> dict:
+        """Folded module -> seconds of kernel own time.  A kernel with no
+        module path (the compiler's ``ragged-dot``, which loses its
+        ``op_name``) is in none: see :meth:`pathless_s`."""
+        out: dict[str, float] = {}
+        for (_, module), v in self.kernel_s.items():
+            if module:
+                out[module] = out.get(module, 0.0) + v
+        return out
+
+    def pathless_s(self, kernel: str) -> float:
+        """Seconds in the kernels named ``kernel`` that have no module path:
+        what a scope's reader may add by name without counting twice."""
+        return self.pass_s.get(kernel, 0.0) - sum(
+            v for (k, module), v in self.kernel_s.items()
+            if k == kernel and module)
 
     def phase(self, label: str) -> float:
         return self.phase_s.get(label, 0.0)
@@ -111,10 +144,11 @@ def _clip(intervals, lo, hi):
 
 def _one_chip(runs: list, ops: list, async_ops: list, table: dict) -> dict:
     """One chip's window by the table: nanoseconds of own time by phase,
-    module, pass and bucket, and where the collectives lie."""
+    module, kernel and bucket, and where the collectives lie."""
     phase: dict[str, float] = {}
     module: dict[str, float] = {}
     passes: dict[str, float] = {}
+    kernels: dict[tuple, float] = {}
     buckets: dict[str, dict] = {}
     found = everything = 0.0
     backward_ends, bucketed_starts = [], []
@@ -131,9 +165,11 @@ def _one_chip(runs: list, ops: list, async_ops: list, table: dict) -> dict:
             module[where] = module.get(where, 0.0) + own
             if scope and scope.phases == ("backward",) and own > 0:
                 backward_ends.append(start + dur)
-        elif kind == "flash":
+        elif kind == trace.KERNEL:
             which = (scope.kernel if scope else None) or "(unnamed)"
             passes[which] = passes.get(which, 0.0) + own
+            where = (which, scope.module if scope else "")
+            kernels[where] = kernels.get(where, 0.0) + own
         else:
             which = (scope.bucket if scope else None) or "(none)"
             b = buckets.setdefault(which, {"calls": 0, "bytes": 0,
@@ -163,7 +199,8 @@ def _one_chip(runs: list, ops: list, async_ops: list, table: dict) -> dict:
         b["start"] = sum(offsets) / len(offsets) if offsets else 0.0
         del b["starts"]
     return {"calls": len(runs), "phase": phase, "module": module,
-            "passes": passes, "buckets": buckets, "lead": lead / len(runs),
+            "passes": passes, "kernels": kernels, "buckets": buckets,
+            "lead": lead / len(runs),
             "tail": tail / len(runs), "found": found,
             "everything": everything}
 
@@ -208,7 +245,7 @@ def join(planes: list[dict], table: dict, spans: dict | None = None
         tail_s=sum(c["tail"] for c in chips) / n / 1e9,
         joined_share=(sum(c["found"] for c in chips) / everything
                       if everything else 0.0),
-        span_s=span_s)
+        span_s=span_s, kernel_s=mean("kernels"))
 
 
 def describe(j: Joined, steps: int) -> str:
@@ -221,6 +258,7 @@ def describe(j: Joined, steps: int) -> str:
             j.phase_s.items(), key=lambda kv: -kv[1])},
         "module_ms": {k: ms(v) for k, v in heaviest},
         "flash_pass_ms": {k: ms(v) for k, v in j.pass_s.items()},
+        "kernel_module_ms": {k: ms(v) for k, v in j.kernel_module_s.items()},
         "buckets": {k: {"calls": b["calls"], "bytes": b["bytes"],
                         "ms": round(1e3 * b["seconds"] * per_call, 3),
                         "start_ms": round(1e3 * b["start_s"], 3)}
@@ -266,6 +304,47 @@ def pass_ms(run, kernel_const: str) -> float | None:
     from horovod_tpu.utils import profiling
     return 1e3 * j.pass_s.get(getattr(profiling, kernel_const), 0.0) \
         / run.traced_steps
+
+
+def flash_ms(run) -> float | None:
+    """Milliseconds a step in the flash kernels: the passes the program
+    names (``profiling.FLASH_PASSES``) and no other kernel of the window."""
+    j = of(run)
+    if j is None or not run.built.flash_calls:
+        return None
+    from horovod_tpu.utils import profiling
+    return 1e3 * sum(j.pass_s.get(k, 0.0) for k in profiling.FLASH_PASSES) \
+        / run.traced_steps
+
+
+def by_scope(run, roles: dict) -> dict | None:
+    """A module's device milliseconds a step by the scopes it wraps its work
+    in.  ``roles`` maps a role to the constant of ``profiling`` that holds
+    its scope's name (``{"scan": "SSM_SCAN"}``).  A scope's time is XLA's
+    operations and the kernels whose module path holds its name;
+    ``"elsewhere"`` is what lies under the paths that own the scopes (what
+    stands before a scope's name: the mixer, the layer) and under none of
+    them.  None without a join, or for a program without the names."""
+    j = of(run)
+    if j is None:
+        return None
+    from horovod_tpu.utils import profiling
+    names = {role: getattr(profiling, const, None)
+             for role, const in roles.items()}
+    if None in names.values():
+        return None
+    ms = lambda seconds: 1e3 * seconds / run.traced_steps  # noqa: E731
+    timed = [(m, v) for d in (j.module_s, j.kernel_module_s)
+             for m, v in d.items()]
+    under = lambda name: sum(  # noqa: E731
+        v for m, v in timed if name in m.split("/"))
+    out = {role: ms(under(name)) for role, name in names.items()}
+    owners = {m.split("/" + name)[0] for name in names.values()
+              for m, _ in timed if name in m.split("/")}
+    inside = sum(v for m, v in timed
+                 if any(m == p or m.startswith(p + "/") for p in owners))
+    out["elsewhere"] = ms(inside) - sum(out.values())
+    return out
 
 
 def across_chips(run) -> Joined | None:

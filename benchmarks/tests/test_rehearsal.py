@@ -88,6 +88,11 @@ def test_a_cell_runs_and_prints_a_line_of_the_result_shape(
             / sum(record["periods_s"]), rel=1e-9)
     assert "plan: overlap_plan=" in stdout and "reference: " in stdout
     assert "memory: " in stdout and "host_gap_s=" in stdout
+    # what ``correct`` was decided from, each number beside its limit, last
+    assert list(result)[-1] == "compared"
+    assert {"loss", "compiles_in_window", "non_finite_losses"} \
+        <= set(result["compared"])
+    assert all(len(pair) == 2 for pair in result["compared"].values())
     if "lm" in workload:
         assert result["correct"], stdout[-3000:]
 

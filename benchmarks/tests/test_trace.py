@@ -41,7 +41,7 @@ def test_recorded_trace_reduces_to_whole_steps(recorded):
     kernels = [e for e in ops if e[3].get("custom_call_target")
                == "tpu_custom_call" and lo <= e[1] and e[1] + e[2] <= hi]
     assert len(kernels) == 3 * 18
-    assert s.kind_s["flash"] == pytest.approx(
+    assert s.kind_s[trace.KERNEL] == pytest.approx(
         sum(e[2] for e in kernels) / 1e9, rel=1e-9)
     assert s.collective_s == 0.0
     assert s.device_ops[0][0].startswith("fusion.")
