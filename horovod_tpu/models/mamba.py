@@ -2,8 +2,10 @@
 ``TransformerConfig.layer_types``: one input projection into a gate ``z``, the
 convolved stream ``xBC`` and a step size per head; a causal depthwise
 convolution with bias and silu over ``xBC``; the state-space recurrence per
-head (``ops/ssd_scan.py``: chunked, as matrix products); the gate and an
-RMSNorm over all inner channels; the output projection.
+head (``ops/ssd_scan.py``: chunked, as matrix products; two Pallas kernels
+where the widths meet their tiling rule, XLA's ops elsewhere, by shape alone:
+``ssm_plan(...)["scan"]`` says which); the gate and an RMSNorm over all inner
+channels; the output projection.
 
 Parameters, all the layer's own (transformers' names in brackets, for
 ``MambaMixer`` of Bamba / GraniteMoeHybrid):
@@ -19,7 +21,8 @@ Parameters, all the layer's own (transformers' names in brackets, for
 
 with I = heads x head size.  Everything the layer does is under one of four
 scopes (``utils/profiling.py``: ``hvd_ssm_proj`` / ``_conv`` / ``_scan`` /
-``_gate``), which backward and recomputed ops keep.
+``_gate``), which backward and recomputed ops keep, and the scan's kernels
+with them (``hvd_ssd_fwd`` / ``hvd_ssd_bwd`` under ``hvd_ssm_scan``).
 
 Not supported yet: decode through the layer (it would carry the conv's last
 K-1 inputs and the state [H, P, N] in a cache of their own), and a sequence
@@ -35,7 +38,8 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import RMSNorm, TransformerConfig
-from horovod_tpu.ops.ssd_scan import carried_state_bytes, ssd_scan
+from horovod_tpu.ops.ssd_scan import (carried_state_bytes, scan_form,
+                                      ssd_scan)
 from horovod_tpu.utils import profiling
 
 
@@ -132,4 +136,6 @@ def ssm_plan(cfg: TransformerConfig, seq_len: int) -> dict:
                                                      seq_len)),
             "carried_state_bytes_per_layer_and_sequence": carried_state_bytes(
                 cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state_dim),
-            "scan": "xla"}
+            "scan": scan_form(seq_len, cfg.mamba_chunk, cfg.mamba_heads,
+                              cfg.mamba_groups, cfg.mamba_head_dim,
+                              cfg.mamba_state_dim)}

@@ -53,9 +53,12 @@ MOE_EXPERTS = "hvd_moe_experts"     # ... the grouped matmuls, the activation
 MOE_COMBINE = "hvd_moe_combine"     # ... back to token order, gate-weighted sum
 SSM_PROJ = "hvd_ssm_proj"       # models/mamba: the in and out projections
 SSM_CONV = "hvd_ssm_conv"       # ... causal depthwise conv, bias, silu
-SSM_SCAN = "hvd_ssm_scan"       # ... ops/ssd_scan: softplus, decays, the intra-
-                                # and inter-chunk products, the D skip
+SSM_SCAN = "hvd_ssm_scan"       # ... ops/ssd_scan: softplus, the decays' sums,
+                                # and the scan's two kernels (or, at shapes
+                                # outside their tiling rule, XLA's products)
 SSM_GATE = "hvd_ssm_gate"       # ... y * silu(z) and the gated RMSNorm
+SSD_FWD = "hvd_ssd_fwd"         # ops/ssd_scan: forward kernel, launched under
+SSD_BWD = "hvd_ssd_bwd"         # SSM_SCAN; ... backward kernel (no flash pass)
 LOADER_WAIT = "hvd_loader_wait"         # data.BackgroundLoader: q.get()
 LOADER_PRODUCE = "hvd_loader_produce"   # ... next(source), producer thread
 H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
@@ -65,6 +68,7 @@ H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
 FLASH_PASSES = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD)
 MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
 SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE)
+SSD_PASSES = (SSD_FWD, SSD_BWD)
 # XLA:TPU replaces ``lax.ragged_dot`` with Mosaic kernels of its own and
 # names them afresh (``op_name="ragged-dot-none"``, and
 # ``"ragged-dot-metadata"`` for the tile table they share): the scope the
@@ -171,8 +175,9 @@ class Scope:
     phases: tuple               # one phase; two or more for a mixed fusion
     module: str                 # module_of(op_name)
     bucket: str | None = None   # "0".., "all": a collective under hvd_bucket_
-    kernel: str | None = None   # a kernel's name: a FLASH_PASSES pass, or
-                                # MOE_EXPERTS (XLA's own grouped matmul)
+    kernel: str | None = None   # a kernel's name: a FLASH_PASSES or
+                                # SSD_PASSES pass, or MOE_EXPERTS (XLA's own
+                                # grouped matmul)
     bytes: int = 0              # of the result, from its shape
 
     @property
@@ -211,7 +216,7 @@ def scope_table(compiled) -> dict[str, Scope]:
     (the head's matmul with the loss and its gradient) and is
     ``forward+backward``.  A collective is ``collective`` by
     opcode whatever its scope, and carries its ``hvd_bucket_<k>``; a kernel
-    (custom call) under ``hvd_flash_*`` carries that pass, and one that
+    (custom call) under ``hvd_flash_*`` or ``hvd_ssd_*`` carries that pass, and one that
     XLA:TPU made of a ``ragged_dot`` carries ``hvd_moe_experts``.  ``while`` and
     ``conditional`` bodies are computations like the entry: their
     instructions are in the table under their own names.
@@ -261,7 +266,8 @@ def scope_table(compiled) -> dict[str, Scope]:
             bucket = _BUCKET.search(op_name) if own == "collective" else None
             kernel = None
             if opcode == "custom-call":
-                kernel = next((k for k in FLASH_PASSES if k in op_name),
+                kernel = next((k for k in FLASH_PASSES + SSD_PASSES
+                               if k in op_name),
                               MOE_EXPERTS
                               if op_name.startswith(_RAGGED_DOT_KERNEL)
                               else None)

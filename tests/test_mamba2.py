@@ -1,8 +1,10 @@
 """The Mamba-2 mixer (``models/mamba.py``) and its chunked scan
-(``ops/ssd_scan.py``): the scan against the recurrence itself, one position
-after the other, and against the quadratic form over the whole sequence
-(both written here, sharing nothing with the program's chunks), values and
-the gradient of every input; the convolution's causality; and
+(``ops/ssd_scan.py``), in both its forms (XLA's ops at the tiny widths, the
+two Pallas kernels, interpreted here, at widths that meet their tiling rule):
+the scan against the recurrence itself, one position after the other, and
+against the quadratic form over the whole sequence (both written here,
+sharing nothing with the program's chunks), values and the gradient of every
+input; the two forms against each other; the convolution's causality; and
 ``layer_types`` as the one switch between mixers in ``models.Transformer``."""
 
 import dataclasses
@@ -13,21 +15,38 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import Transformer, TransformerConfig
+from horovod_tpu.models import mamba
 from horovod_tpu.models.mamba import Mamba2Mixer, causal_conv, ssm_plan
-from horovod_tpu.ops.ssd_scan import ssd_scan
+from horovod_tpu.ops import ssd_scan as ssd_scan_module
+from horovod_tpu.ops.ssd_scan import head_block, scan_form, ssd_scan
 
 H, P, G, N = 4, 8, 2, 16
+TINY = dict(h=H, p=P, g=G, n=N)                 # the XLA form
+KERNEL = dict(h=4, p=64, n=128, batch=1)        # the kernels: chunk 128
 
 
-def inputs(s, dtype, seed=0, batch=2):
+def inputs(s, dtype, seed=0, batch=2, h=H, p=P, g=G, n=N):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-    x = jax.random.normal(ks[0], (batch, s, H, P), jnp.float32)
-    dt = jax.nn.softplus(jax.random.normal(ks[1], (batch, s, H)) - 2.0)
-    a = -jnp.exp(jax.random.uniform(ks[2], (H,), minval=0.0, maxval=2.7))
-    b = jax.random.normal(ks[3], (batch, s, G, N), jnp.float32)
-    c = jax.random.normal(ks[4], (batch, s, G, N), jnp.float32)
-    d = jax.random.normal(ks[5], (H,), jnp.float32)
+    x = jax.random.normal(ks[0], (batch, s, h, p), jnp.float32)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (batch, s, h)) - 2.0)
+    a = -jnp.exp(jax.random.uniform(ks[2], (h,), minval=0.0, maxval=2.7))
+    b = jax.random.normal(ks[3], (batch, s, g, n), jnp.float32)
+    c = jax.random.normal(ks[4], (batch, s, g, n), jnp.float32)
+    d = jax.random.normal(ks[5], (h,), jnp.float32)
     return (x.astype(dtype), dt, a, b.astype(dtype), c.astype(dtype), d)
+
+
+def form_of(args, chunk):
+    x, b = args[0], args[3]
+    return scan_form(x.shape[1], chunk, x.shape[2], b.shape[2], x.shape[3],
+                     b.shape[3])
+
+
+def xla_form(*args):
+    """``ssd_scan`` held to XLA's ops whatever the shapes."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ssd_scan_module, "scan_form", lambda *shape: "xla")
+        return ssd_scan(*args)
 
 
 def sequential(x, dt, a, b, c, d):
@@ -51,7 +70,7 @@ def sequential(x, dt, a, b, c, d):
 def quadratic(x, dt, a, b, c, d):
     """y_t = sum_{s<=t} exp(sum_{s<r<=t} dt_r a) (C_t . B_s) dt_s x_s + D x_t."""
     x, b, c = (v.astype(jnp.float32) for v in (x, b, c))
-    b, c = (jnp.repeat(v, H // G, axis=2) for v in (b, c))
+    b, c = (jnp.repeat(v, x.shape[2] // v.shape[2], axis=2) for v in (b, c))
     s = x.shape[1]
     cum = jnp.cumsum(dt * a, axis=1)
     lower = jnp.tril(jnp.ones((s, s), bool))[None, :, :, None]
@@ -65,16 +84,22 @@ def loss_of(fn, weights):
     return lambda *args: jnp.sum(fn(*args).astype(jnp.float32) * weights)
 
 
-# length, chunk: one chunk, several whole chunks, a ragged last chunk, a
-# sequence shorter than the chunk
-SHAPES = [(16, 16), (64, 16), (50, 16), (10, 16), (33, 8)]
+# length, chunk, widths: one chunk, several whole chunks, a ragged last chunk,
+# a sequence shorter than the chunk; then the kernels: two chunks, the state
+# crossing three chunks with two groups, a ragged last chunk
+SHAPES = [(16, 16, TINY), (64, 16, TINY), (50, 16, TINY), (10, 16, TINY),
+          (33, 8, TINY), (256, 128, dict(KERNEL, g=1)),
+          (384, 128, dict(KERNEL, g=2)), (200, 128, dict(KERNEL, g=1))]
+IDS = [f"{s}-{chunk}-{'kernel' if dims is not TINY else 'xla'}"
+       for s, chunk, dims in SHAPES]
 
 
 @pytest.mark.parametrize("other", [sequential, quadratic])
-@pytest.mark.parametrize("s,chunk", SHAPES)
-def test_scan_matches_the_recurrence_in_float32(s, chunk, other):
-    args = inputs(s, jnp.float32)
-    weights = jax.random.normal(jax.random.PRNGKey(9), (2, s, H, P))
+@pytest.mark.parametrize("s,chunk,dims", SHAPES, ids=IDS)
+def test_scan_matches_the_recurrence_in_float32(s, chunk, dims, other):
+    args = inputs(s, jnp.float32, **dims)
+    assert form_of(args, chunk) == ("xla" if dims is TINY else "kernel")
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
     with jax.default_matmul_precision("highest"):
         got = ssd_scan(*args, chunk)
         want = other(*args)
@@ -88,14 +113,20 @@ def test_scan_matches_the_recurrence_in_float32(s, chunk, other):
         np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-3, err_msg=name)
 
 
+def rel(g, w):
+    g, w = (np.asarray(v, np.float32) for v in (g, w))
+    return np.linalg.norm(g - w) / np.linalg.norm(w)
+
+
 @pytest.mark.parametrize("other", [sequential, quadratic])
-@pytest.mark.parametrize("s,chunk", [(64, 16), (50, 16)])
-def test_scan_in_bfloat16_stays_within_its_roundings(s, chunk, other):
+@pytest.mark.parametrize("s,chunk,dims", [SHAPES[1], SHAPES[2], SHAPES[6]],
+                         ids=[IDS[1], IDS[2], IDS[6]])
+def test_scan_in_bfloat16_stays_within_its_roundings(s, chunk, dims, other):
     """The program's precision: bf16 operands, f32 accumulation, f32 decays.
     Against the f32 recurrence on the same (bf16-rounded) inputs the result
     and every gradient agree to a few bf16 roundings of their norm."""
-    args = inputs(s, jnp.bfloat16)
-    weights = jax.random.normal(jax.random.PRNGKey(9), (2, s, H, P))
+    args = inputs(s, jnp.bfloat16, **dims)
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
     every = tuple(range(6))
     got = ssd_scan(*args, chunk)
     assert got.dtype == jnp.bfloat16
@@ -105,24 +136,85 @@ def test_scan_in_bfloat16_stays_within_its_roundings(s, chunk, other):
         want = other(*args)
         g_want = jax.grad(loss_of(other, weights), every)(*args)
 
-    def rel(g, w):
-        g, w = (np.asarray(v, np.float32) for v in (g, w))
-        return np.linalg.norm(g - w) / np.linalg.norm(w)
-
     assert rel(got, want) < 0.02
     for name, g, w in zip("x dt a b c d".split(), g_got, g_want):
         assert rel(g, w) < 0.03, (name, rel(g, w))
 
 
-def test_padding_neither_decays_nor_feeds_the_state():
+@pytest.mark.parametrize("dtype,within", [(jnp.float32, 1e-4),
+                                          (jnp.bfloat16, 0.01)],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("s,g", [(384, 1), (200, 2)])
+def test_the_kernels_are_the_xla_form(s, g, dtype, within):
+    """One algorithm in two forms: at shapes that meet the kernels' rule the
+    result and every gradient are those of XLA's ops on the same inputs, to
+    float32's last digits, and in bfloat16 far inside the distance either
+    keeps from the recurrence (the two round the same operands)."""
+    args = inputs(s, dtype, **dict(KERNEL, g=g))
+    assert form_of(args, 128) == "kernel"
+    weights = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    every = tuple(range(6))
+    with jax.default_matmul_precision("highest"):
+        got, want = ssd_scan(*args, 128), xla_form(*args, 128)
+        g_got = jax.grad(loss_of(lambda *a: ssd_scan(*a, 128), weights),
+                         every)(*args)
+        g_want = jax.grad(loss_of(lambda *a: xla_form(*a, 128), weights),
+                          every)(*args)
+    assert got.dtype == want.dtype == dtype
+    assert rel(got, want) < within
+    for name, g_k, g_x in zip("x dt a b c d".split(), g_got, g_want):
+        assert g_k.dtype == g_x.dtype and g_k.shape == g_x.shape, name
+        assert rel(g_k, g_x) < within, (name, rel(g_k, g_x))
+
+
+@pytest.mark.parametrize("long,cut,chunk,dims", [
+    (48, 37, 16, TINY), (256, 150, 128, dict(KERNEL, g=1))],
+    ids=["xla", "kernel"])
+def test_padding_neither_decays_nor_feeds_the_state(long, cut, chunk, dims):
     """A ragged length is padded inside the scan; the real positions read
     what they read in a longer sequence cut at the same place."""
-    long = inputs(48, jnp.float32)
-    cut = tuple(v[:, :37] if v.ndim > 1 else v for v in long)
+    whole = inputs(long, jnp.float32, **dims)
+    short = tuple(v[:, :cut] if v.ndim > 1 else v for v in whole)
+    assert form_of(short, chunk) == ("xla" if dims is TINY else "kernel")
     with jax.default_matmul_precision("highest"):
-        np.testing.assert_allclose(ssd_scan(*cut, 16),
-                                   ssd_scan(*long, 16)[:, :37],
+        np.testing.assert_allclose(ssd_scan(*short, chunk),
+                                   ssd_scan(*whole, chunk)[:, :cut],
                                    rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("shape,heads", [
+    ((256, 64, 64, 128), 16),       # granite-4.0-h-micro: 16 heads a program
+    ((128, 4, 64, 128), 4), ((128, 2, 64, 128), 2), ((256, 6, 64, 256), 6),
+    ((256, 34, 64, 128), 2),        # no larger even block divides the group
+    ((256, 64, 64, 16), None),      # N off the lane grid
+    ((64, 64, 64, 128), None),      # the chunk off it
+    ((512, 64, 64, 128), None),     # a chunk whose matrices outgrow VMEM
+    ((256, 3, 64, 128), None),      # heads do not pair into tiles
+    ((256, 8, 32, 128), None), ((256, 8, 128, 128), None),
+    ((16, 2, 8, 16), None)])
+def test_the_tiling_rule(shape, heads):
+    assert head_block(*shape) == heads
+    q, per_group, p, n = shape
+    assert scan_form(4 * q, q, 2 * per_group, 2, p, n) \
+        == ("kernel" if heads else "xla")
+    # a sequence shorter than the chunk is one chunk of its own length
+    assert scan_form(q, 4 * q, per_group, 1, p, n) \
+        == ("kernel" if heads else "xla")
+
+
+def test_the_scan_runs_the_form_the_plan_reports(monkeypatch):
+    """``ssd_scan`` dispatches on, and ``ssm_plan`` reports, one function."""
+    assert mamba.scan_form is ssd_scan_module.scan_form is scan_form
+    asked = []
+    monkeypatch.setattr(ssd_scan_module, "scan_form",
+                        lambda *shape: asked.append(shape) or "xla")
+    args = inputs(256, jnp.float32, **dict(KERNEL, g=1))
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda *a: ssd_scan(*a, 128))(*args))
+    assert asked == [(256, 128, 4, 1, 64, 128)]
+    monkeypatch.undo()
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda *a: ssd_scan(*a, 128))(*args))
 
 
 def test_groups_must_divide_heads():
@@ -196,14 +288,24 @@ def test_each_layer_type_owns_its_parameters():
     assert params["layer_1"]["attn"]["q"]["kernel"].shape == (32, 4, 8)
 
 
-@pytest.mark.parametrize("remat", [False, True])
-def test_hybrid_model_is_causal_and_trains(remat):
-    cfg = TransformerConfig(**HYBRID, remat=remat)
+# the widths meet the kernels' rule: two heads of 64, a state of 128
+KERNEL_HYBRID = dict(HYBRID, layer_types=("mamba", "attention"), num_layers=2,
+                     mamba_heads=2, mamba_head_dim=64, mamba_state_dim=128,
+                     mamba_chunk=128, max_seq_len=256)
+
+
+@pytest.mark.parametrize("widths,length,remat", [
+    (HYBRID, 24, False), (HYBRID, 24, True), (KERNEL_HYBRID, 200, True)],
+    ids=["xla", "xla-remat", "kernel-remat"])
+def test_hybrid_model_is_causal_and_trains(widths, length, remat):
+    cfg = TransformerConfig(**widths, remat=remat)
+    assert ssm_plan(cfg, length)["scan"] == \
+        ("kernel" if widths is KERNEL_HYBRID else "xla")
     model = Transformer(cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 24), 0, 64)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, length), 0, 64)
     params = model.init(jax.random.PRNGKey(0), tokens)
     logits = model.apply(params, tokens)
-    assert logits.shape == (2, 24, 64)
+    assert logits.shape == (2, length, 64)
     later = model.apply(params, tokens.at[:, 17].set(5))
     np.testing.assert_allclose(logits[:, :17], later[:, :17], atol=1e-6)
     assert not np.allclose(logits[:, 17:], later[:, 17:])
@@ -292,8 +394,10 @@ def test_ssm_plan_counts_layers_chunks_and_state():
     assert plan == {"layers": {"attention": 1, "mamba": 2}, "chunk": 256,
                     "chunks_per_sequence": 32,
                     "carried_state_bytes_per_layer_and_sequence": 2097152,
-                    "scan": "xla"}
+                    "scan": "kernel"}
     assert ssm_plan(cfg, 1000)["chunks_per_sequence"] == 4
+    # the tiny widths of the tests, and of the benchmark's rehearsal
+    assert ssm_plan(TransformerConfig(**HYBRID), 64)["scan"] == "xla"
 
 
 def test_a_scan_refuses_a_sequence_sharded_over_chips():

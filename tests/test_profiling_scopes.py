@@ -397,6 +397,66 @@ def test_the_mamba_mixers_four_scopes_reach_the_table_in_every_phase():
         in modules
 
 
+def test_the_scans_kernels_are_launched_inside_the_mixers_scan_scope():
+    """At widths that meet the kernels' tiling rule the gradient of a tiny
+    rematted hybrid holds three ``pallas_call``s a mamba layer (forward, the
+    forward again under remat, backward), each named by its constant of
+    ``profiling`` and each under a name stack that holds ``hvd_ssm_scan``:
+    the module path the benchmark's ``ssm_scan_ms`` finds them by.  They are
+    no flash pass."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import Transformer, TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=1, num_heads=2, head_dim=8, embed_dim=16,
+        mlp_dim=32, dtype=jnp.float32, remat=True, layer_types=("mamba",),
+        mamba_heads=2, mamba_head_dim=64, mamba_state_dim=128,
+        mamba_chunk=128)
+    model = Transformer(cfg)
+    tokens = jnp.zeros((1, 128), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    jaxpr = jax.make_jaxpr(
+        jax.grad(lambda p: model.apply(p, tokens).sum()))(params)
+
+    def kernels(jaxpr, outer=()):
+        """(kernel name, name stack) of every ``pallas_call``; an equation
+        inside a ``jit`` carries the stack from that ``jit`` inwards."""
+        for eqn in jaxpr.eqns:
+            stack = outer + tuple(
+                part for part in str(eqn.source_info.name_stack).split("/")
+                if part)
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"], "/".join(stack)
+            inner = stack if eqn.primitive.name in ("pjit", "jit") else outer
+            for v in eqn.params.values():
+                for sub in v if isinstance(v, (list, tuple)) else [v]:
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from kernels(sub, inner)
+
+    found = list(kernels(jaxpr.jaxpr))
+    assert sorted(name for name, _ in found) == [
+        profiling.SSD_BWD, profiling.SSD_FWD, profiling.SSD_FWD]
+    for name, stack in found:
+        parts = stack.split("/")
+        assert parts[-4:] == ["layer_0", "mamba", profiling.SSM_SCAN, name]
+    assert any("rematted_computation" in stack for _, stack in found)
+    assert not set(profiling.SSD_PASSES) & set(profiling.FLASH_PASSES)
+    # and the table of a compiled program names such a kernel by its pass
+    text = """HloModule m
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  ROOT %hvd_ssd_bwd.1 = f32[8]{0} custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(Transformer))/layer_3/mamba/hvd_ssm_scan/hvd_ssd_bwd/pallas_call"}
+}
+"""
+    scope = profiling.scope_table(text)["hvd_ssd_bwd.1"]
+    assert scope.kernel == profiling.SSD_BWD and scope.phase == "backward"
+    assert scope.module == "Transformer/layer_N/mamba/hvd_ssm_scan/" \
+        + profiling.SSD_BWD
+
+
 def host_events(logdir):
     """{span name: [(line, start_ns, end_ns)]} off the host planes; a line
     (one thread) is its place in the file, threads sharing their name."""
@@ -448,8 +508,8 @@ def test_loader_spans_nest_inside_the_callers_span(hvd_module, tmp_path):
 def test_every_name_of_the_vocabulary_is_written_once():
     names = [v for k, v in vars(profiling).items()
              if k.isupper() and isinstance(v, str) and v.startswith("hvd_")]
-    # four of models/moe.py, four of models/mamba.py
-    assert len(names) == len(set(names)) == 19
+    # four of models/moe.py, four of models/mamba.py, two of ops/ssd_scan.py
+    assert len(names) == len(set(names)) == 21
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for directory, _, files in itertools.chain(
             os.walk(os.path.join(root, "horovod_tpu")),

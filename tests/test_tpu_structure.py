@@ -125,6 +125,47 @@ def test_fused_flash_backward_compiles_within_the_vmem_it_asks_for(
             f"({dtype}[{b * 16},{s},128]")
 
 
+@pytest.mark.parametrize("q,n,heads,groups,dtype", [
+    (256, 128, 64, 1, jnp.bfloat16),    # granite-4.0-h-micro's mixer
+    (256, 256, 32, 1, jnp.bfloat16), (128, 128, 8, 2, jnp.bfloat16),
+    (256, 256, 64, 8, jnp.float32)])
+def test_the_scans_kernels_compile_wherever_their_rule_sends_them(
+        one_chip_mesh, monkeypatch, q, n, heads, groups, dtype):
+    """``ssd_scan`` decides by shape alone whether the kernels run, so every
+    shape its rule admits has to be one the chip's compiler takes, within
+    Mosaic's default VMEM (the kernels ask for no limit of their own): the
+    cell's widths, the rule's largest chunk, state and block, its smallest,
+    and float32 operands.  Forward and backward are one custom call each,
+    under their names; off the rule there is none."""
+    from horovod_tpu.ops import ssd_scan as ssd
+    from horovod_tpu.utils import profiling
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+
+    def kernels_of(q, p):
+        args = (shape(2, 4 * q, heads, p, dtype=dtype),
+                shape(2, 4 * q, heads), shape(heads),
+                shape(2, 4 * q, groups, n, dtype=dtype),
+                shape(2, 4 * q, groups, n, dtype=dtype), shape(heads))
+        grad = jax.grad(lambda *a: ssd.ssd_scan(*a, q).astype(
+            jnp.float32).sum(), tuple(range(6)))
+        text = jax.jit(grad).lower(*args).compile().as_text()
+        return sorted(
+            next(k for k in profiling.SSD_PASSES
+                 if k in line.split("op_name=")[1])
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line)
+
+    assert ssd.head_block(q, heads // groups, 64, n) == min(
+        heads // groups, 16)
+    assert kernels_of(q, 64) == [profiling.SSD_BWD, profiling.SSD_FWD]
+    assert ssd.head_block(2 * q, heads // groups, 32, n) is None
+    assert kernels_of(2 * q, 32) == []
+
+
 def _arrays(shape: str) -> list[tuple[str, int]]:
     """[(dtype, elements)] of every array in a shape's text."""
     from horovod_tpu.utils.profiling import _ARRAY
