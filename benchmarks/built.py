@@ -1,4 +1,6 @@
-"""What a family's ``build(config, traffic, chips, seed)`` hands the harness."""
+"""What a family's ``build(config, traffic, chips, seed)`` (a model that is
+trained) or ``serve(config, traffic, chips, seed)`` (one that is served)
+hands the harness."""
 
 from __future__ import annotations
 
@@ -28,4 +30,31 @@ class Built:
     compare: Callable[[Any], list[dict]]
     # flash-kernel calls of one optimizer step on one chip, as shapes
     flash_calls: list[dict]
+    notes: dict = dataclasses.field(default_factory=dict)
+
+
+@dataclasses.dataclass
+class Served:
+    # horovod_tpu.serving.ServingEngine, its clock time.perf_counter, over
+    # benchmarks.serving.Timed(the backend): what the window drives
+    engine: Any
+    # () -> None: compiles every prefill bucket and the decode program and
+    # runs each once, so that nothing compiles inside the window
+    warm: Callable[[], None]
+    # () -> None: the cache and the program's weights leave the chip
+    release: Callable[[], None]
+    # ([(prompt ids, served ids), ...] of requests the window finished,
+    # seed) -> [{"name", "error", "tolerance", "ok"}, ...]; after release()
+    compare: Callable[[list[tuple], int], list[dict]]
+    vocab_size: int
+    parameters: int
+    num_slots: int
+    # bytes of K and V one cached token holds, over all layers
+    kv_bytes_per_token: int
+    # {"decode": ..., "prefill": ...}: what a trace's ``XLA Modules`` line
+    # calls the backend's programs
+    program_names: dict
+    # () -> the decode program's scope table (instruction -> what the
+    # program called it), or None; asked of a traced run on the chip alone
+    decode_scopes: Callable[[], Any]
     notes: dict = dataclasses.field(default_factory=dict)

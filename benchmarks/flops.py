@@ -46,6 +46,15 @@ def flash_train_bytes(b: int, h: int, s: int, d: int, itemsize: int = 2
     return float(4 + 8) * b * h * s * d * itemsize
 
 
+def decode_attention_bytes(kv_bytes_per_token: int, live_tokens) -> float:
+    """HBM traffic the attention of decode steps cannot avoid: every cached
+    key and value of every live slot is read once a step (``live_tokens``:
+    for each step, the sum of its slots' live lengths).  The one position a
+    step writes and the queries are thousands of times smaller and left
+    out."""
+    return float(kv_bytes_per_token) * float(sum(live_tokens))
+
+
 def conv_macs(out_hw: int, k: int, cin: int, cout: int) -> int:
     return out_hw * out_hw * k * k * cin * cout
 

@@ -8,14 +8,21 @@ lists (PERF.md, "How the harness finds a cell's files"):
 
     configs/<config>.json     sizes as run, with "family"      (manifest "file")
     traffic/<traffic>.json    batch, optimizer, data stream ("extends": another)
-    families/<family>.py      build(config, traffic, chips, seed) -> Built
+    families/<family>.py      build(config, traffic, chips, seed) -> Built, or
+                              serve(config, traffic, chips, seed) -> Served
     reference/<family>.py     the plain reference the family compares with
     metrics/<stem>.py         read(run) -> value or None; <stem> is the
                               metric's name up to its first "."
 
+Which loop runs follows from what the family defines, not from a flag: a
+family with ``build`` is trained by :func:`train`, one with ``serve`` is sent
+requests by ``benchmarks/serving.py``; what is printed of either is
+:func:`report`'s.
+
 With ``--trace 0`` the metrics are the cell's end-to-end metrics; with
-``--trace 1`` its per-layer metrics, a few more steps being run under the
-profiler after the window.  The last line of stdout is the result.
+``--trace 1`` its per-layer metrics, a few more steps (or seconds of the same
+traffic) being run under the profiler after the window.  The last line of
+stdout is the result.
 """
 
 from __future__ import annotations
@@ -94,6 +101,35 @@ class Run:
         step's ``memory_analysis()`` beside the sum."""
         m = self.memory
         return m["peak_bytes_in_use"] + m["peak_bytes_reserved"] if m else 0
+
+
+@dataclasses.dataclass
+class Harness:
+    """What ``main`` hands the loop it chose."""
+    args: argparse.Namespace
+    manifest: dict
+    cell: dict
+    config: dict
+    traffic: dict
+    chips: int
+    family: object              # the module families/<family>.py
+    dev: object                 # jax.devices()[0]
+    devices: list
+    peaks: dict | None
+    compile_events: list        # host-clock stamps of backend compilations
+
+
+@dataclasses.dataclass
+class Outcome:
+    """What a loop hands back for :func:`report` to print."""
+    run: object                 # what the metric readers are given
+    correct: bool
+    attempted: int
+    failed: int
+    compared: dict              # name -> [number, limit]
+    device: dict
+    breakdown: dict | None
+    finish: object = None       # called once the readers have read
 
 
 class Heartbeat(threading.Thread):
@@ -177,11 +213,10 @@ def main() -> int:
     chips = int(cell["chips"])
 
     import jax
-    import numpy as np
 
     from horovod_tpu.utils import chip
 
-    from benchmarks import peaks as peak_table, rates, trace
+    from benchmarks import peaks as peak_table
 
     cache_dir = chip.enable_compile_cache()
     # keep the small programs (init, comparison) too: every run is a new
@@ -209,12 +244,32 @@ def main() -> int:
         lambda name, _secs, **_kw: compile_events.append(time.perf_counter())
         if name.endswith("backend_compile_duration") else None)
 
+    family = load_module("families", config["family"])
+    h = Harness(args=args, manifest=manifest, cell=cell, config=config,
+                traffic=traffic, chips=chips, family=family, dev=dev,
+                devices=devices, peaks=peaks, compile_events=compile_events)
+    if hasattr(family, "serve"):
+        from benchmarks import serving
+
+        return report(h, serving.measure(h))
+    return report(h, train(h))
+
+
+def train(h: Harness) -> Outcome:
+    """One run of a training cell: the family's step, one call in flight."""
+    import jax
+    import numpy as np
+
     import horovod_tpu as hvd
 
+    from benchmarks import rates, trace
+
+    args, cell, config, traffic = h.args, h.cell, h.config, h.traffic
+    chips, peaks, dev, devices = h.chips, h.peaks, h.dev, h.devices
+    compile_events = h.compile_events
     hvd.init()
     t = time.perf_counter()
-    built = load_module("families", config["family"]).build(
-        config, traffic, chips, args.seed)
+    built = h.family.build(config, traffic, chips, args.seed)
     model_state = built.init_model()
     jax.block_until_ready(model_state)
     n_params = sum(int(np.prod(x.shape))
@@ -368,29 +423,39 @@ def main() -> int:
     correct = (all(c["ok"] for c in checks) and compiles == 0
                and all(finite) and learnt)
 
-    group = "per_layer" if args.trace else "end_to_end"
-    metrics = {}
-    for entry in metrics_of(manifest, group, cell["name"]):
-        value = load_module("metrics", entry["name"].split(".")[0]).read(run)
-        if value is not None:
-            metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
-    result = {"correct": bool(correct), "attempted": run.steps,
-              "failed": finite.count(False) * built.steps_per_call,
-              "metrics": metrics, "device": device}
-    if breakdown is not None:
-        result["breakdown"] = breakdown
-    # every number ``correct`` was decided from, beside its limit: the last
-    # lines of stderr and the last key of the line (what is kept of a run
-    # that was not correct)
+    # every number ``correct`` was decided from, beside its limit
     compared = {c["name"]: [c["error"], c["tolerance"]] for c in checks}
     compared["compiles_in_window"] = [compiles, 0]
     compared["non_finite_losses"] = [finite.count(False), 0]
     if traffic["expect_loss_to_fall"]:
         compared["last_segment_loss_less_first"] = [
             loss_means[-1] - loss_means[0], 0.0]
-    result["compared"] = compared
-    hvd.shutdown()
-    for name, (value, limit) in compared.items():
+    return Outcome(run=run, correct=correct, attempted=run.steps,
+                   failed=finite.count(False) * built.steps_per_call,
+                   compared=compared, device=device, breakdown=breakdown,
+                   finish=hvd.shutdown)
+
+
+def report(h: Harness, o: Outcome) -> int:
+    """Read the cell's metrics off the run and print the result line."""
+    args, cell, dev = h.args, h.cell, h.dev
+    group = "per_layer" if args.trace else "end_to_end"
+    metrics = {}
+    for entry in metrics_of(h.manifest, group, cell["name"]):
+        value = load_module("metrics", entry["name"].split(".")[0]).read(o.run)
+        if value is not None:
+            metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
+    result = {"correct": bool(o.correct), "attempted": o.attempted,
+              "failed": o.failed, "metrics": metrics, "device": o.device}
+    if o.breakdown is not None:
+        result["breakdown"] = o.breakdown
+    # every number ``correct`` was decided from, beside its limit: the last
+    # lines of stderr and the last key of the line (what is kept of a run
+    # that was not correct)
+    result["compared"] = o.compared
+    if o.finish is not None:
+        o.finish()
+    for name, (value, limit) in o.compared.items():
         print(f"compared: {name}={value!r} limit={limit!r}", file=sys.stderr)
     line = json.dumps(result)
     if dev.platform != "tpu":
@@ -436,4 +501,7 @@ def free_name(directory: str, tag: str, suffix: str) -> str:
 
 
 if __name__ == "__main__":
+    # a loop that says ``from benchmarks import run`` gets this module, not
+    # a second copy with a later PROCESS_START
+    sys.modules["benchmarks.run"] = sys.modules["__main__"]
     sys.exit(main())
