@@ -106,6 +106,11 @@ _permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
                 lambda inverse, g: (g[inverse], None, None))
 
 
+# how the router's logits [T, E] become an expert's score for a token
+SELECTIONS = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
+              "sigmoid": jax.nn.sigmoid}
+
+
 class MoEMLP(nn.Module):
     """Sparse GLU feed-forward: [B, S, D] → [B, S, D].
 
@@ -126,11 +131,27 @@ class MoEMLP(nn.Module):
     # divide a token's gate weights by their sum (OLMoE does not)
     norm_topk_prob: bool = False
     param_dtype: Any = jnp.float32
+    # how an expert is scored for a token: "softmax" over all experts'
+    # logits (OLMoE) or "sigmoid" of each logit alone
+    selection: str = "softmax"
+    # experts every token visits, beside the routed ones: their mean is
+    # added to the routed sum (leaves shared_gate / shared_up / shared_down)
+    num_shared_experts: int = 0
+    # (lo, hi): this chip holds routed experts lo..hi-1 of ``num_experts``
+    # (the expert leaves are [hi - lo, ...]; the router keeps every output).
+    # None holds them all.
+    experts_held: tuple | None = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, valid=None):
+        """``valid`` ([B, S] bool, ``num_experts`` > 0 only): positions that
+        hold a token.  One that does not (a bucket's padding, a slot with no
+        request) is routed to no expert: its pairs are in no group of the
+        grouped matmuls and in no count."""
         if self.num_experts > 0:
-            return _all_experts_here(self, x)
+            return _all_experts_here(self, x, valid)
+        if valid is not None:
+            raise ValueError("valid is the every-expert-here layout's")
         n_experts = lax.axis_size(self.axis_name)
         b, s, d = x.shape
         if d != self.embed_dim:
@@ -164,7 +185,7 @@ class MoEMLP(nn.Module):
         return out.reshape(b, s, d).astype(x.dtype)
 
 
-def _all_experts_here(m: MoEMLP, x):
+def _all_experts_here(m: MoEMLP, x, valid=None):
     """``MoEMLP.__call__`` for ``num_experts`` > 0 (a function, so that flax
     adds no method's name to the module path of what it traces)."""
     if m.axis_name is not None:
@@ -179,16 +200,33 @@ def _all_experts_here(m: MoEMLP, x):
             f"MoEMLP(embed_dim={m.embed_dim}) got feature dim {d}")
     if not 0 < k <= e:
         raise ValueError(f"experts_per_token={k} of num_experts={e}")
+    if m.selection not in SELECTIONS:
+        raise ValueError(f"selection {m.selection!r}; models/moe.py has "
+                         f"{sorted(SELECTIONS)}")
+    lo, hi = (0, e) if m.experts_held is None else m.experts_held
+    if not 0 <= lo < hi <= e:
+        raise ValueError(f"experts_held={m.experts_held} of num_experts={e}")
+    held = hi - lo
     lecun = nn.initializers.lecun_normal
     router_w = m.param("router", lecun(), (d, e), m.param_dtype)
     # fan-in is axis 1 of [E, in, out]; the experts are a batch
     stacked = lecun(in_axis=1, out_axis=2, batch_axis=0)
-    w_gate = m.param("gate", stacked, (e, d, f), m.param_dtype)
-    w_up = m.param("up", stacked, (e, d, f), m.param_dtype)
-    w_down = m.param("down", stacked, (e, f, d), m.param_dtype)
+    w_gate = m.param("gate", stacked, (held, d, f), m.param_dtype)
+    w_up = m.param("up", stacked, (held, d, f), m.param_dtype)
+    w_down = m.param("down", stacked, (held, f, d), m.param_dtype)
+    n_shared = m.num_shared_experts
+    if n_shared:
+        # the shared experts side by side are one GLU of width n * F:
+        # expert j owns columns (rows of down) j * F .. (j + 1) * F
+        sw_gate = m.param("shared_gate", lecun(), (d, n_shared * f),
+                          m.param_dtype)
+        sw_up = m.param("shared_up", lecun(), (d, n_shared * f),
+                        m.param_dtype)
+        sw_down = m.param("shared_down", lecun(), (n_shared * f, d),
+                          m.param_dtype)
     t = b * s
 
-    # Everything the layer does is under one of four scopes
+    # Everything the layer does is under one of five scopes
     # (utils/profiling.py), so a trace's time in them is the layer's.
     with jax.named_scope(profiling.MOE_ROUTE):
         tokens = x.reshape(t, d).astype(m.dtype)
@@ -196,26 +234,46 @@ def _all_experts_here(m: MoEMLP, x):
         logits = jnp.dot(tokens.astype(jnp.float32),
                          router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)               # [T, E]
+        probs = SELECTIONS[m.selection](logits)               # [T, E]
         _, picks = lax.top_k(lax.stop_gradient(probs), k)     # [T, k]
         gates = jnp.take_along_axis(probs, picks, axis=-1)
         if m.norm_topk_prob:
             gates = gates / gates.sum(axis=-1, keepdims=True)
-        pair_expert = picks.reshape(t * k)      # pair p: token p // k
+        everything = held == e and valid is None
+        if everything:
+            local = picks
+        else:
+            # The router, the picks and the gates are over all e experts.
+            # A pair on an expert another chip holds, or of a position that
+            # holds no token, sorts behind every held one, belongs to no
+            # group of the grouped matmuls and adds nothing here; an absent
+            # expert's gate has taken its part of the sum above.
+            on_chip = (picks >= lo) & (picks < hi)
+            if valid is not None:
+                on_chip &= valid.reshape(t, 1)
+            local = jnp.where(on_chip, picks - lo, held)
+        pair_expert = local.reshape(t * k)      # pair p: token p // k
         order = jnp.argsort(pair_expert, stable=True)
         inverse = jnp.argsort(order)
-        pairs = (picks[..., None] == jnp.arange(e)).sum(
-            axis=(0, 1), dtype=jnp.int32)                     # [E]
+        pairs = (local[..., None] == jnp.arange(held)).sum(
+            axis=(0, 1), dtype=jnp.int32)                     # [held]
         if not m.is_initializing():  # init returns parameters only
             m.sow(MOE_STATS, "expert_pairs", pairs)
             m.sow(MOE_STATS, "picks", picks.reshape(b, s, k))
-            # E * sum_e f_e P_e: f_e the share of the pairs on expert e
-            # (a constant to the gradient), P_e e's mean probability
-            m.sow(MOE_LOSSES, "load_balance", e * jnp.sum(
-                pairs.astype(jnp.float32) / (t * k)
-                * probs.mean(axis=0)))
-            m.sow(MOE_LOSSES, "router_z", jnp.mean(jnp.square(
-                jax.nn.logsumexp(logits, axis=-1))))
+            if m.selection == "softmax":
+                # E * sum_e f_e P_e: f_e the share of the pairs on expert e
+                # (a constant to the gradient), P_e e's mean probability
+                m.sow(MOE_LOSSES, "load_balance", e * jnp.sum(
+                    pairs.astype(jnp.float32) / (t * k)
+                    * probs.mean(axis=0)))
+                m.sow(MOE_LOSSES, "router_z", jnp.mean(jnp.square(
+                    jax.nn.logsumexp(logits, axis=-1))))
+            elif m.is_mutable_collection(MOE_LOSSES):
+                raise NotImplementedError(
+                    f"the load-balancing and router z losses are defined "
+                    f"for softmax selection; with selection="
+                    f"{m.selection!r} the layer sows none: do not make "
+                    f"{MOE_LOSSES!r} mutable")
 
     with jax.named_scope(profiling.MOE_DISPATCH):
         rows = _dispatch(tokens, order, inverse, k)           # [T*k, D]
@@ -228,5 +286,17 @@ def _all_experts_here(m: MoEMLP, x):
 
     with jax.named_scope(profiling.MOE_COMBINE):
         by_token = _permute(out_rows, inverse, order).reshape(t, k, d)
+        if not everything:
+            # rows past the last group are in no product: whatever the
+            # grouped matmul left there is taken out, not weighted
+            by_token = jnp.where(on_chip[..., None], by_token, 0)
         out = (by_token.astype(jnp.float32) * gates[..., None]).sum(1)
+        if not n_shared:
+            return out.reshape(b, s, d).astype(x.dtype)
+
+    with jax.named_scope(profiling.MOE_SHARED):
+        act = (nn.silu(tokens @ sw_gate.astype(m.dtype))
+               * (tokens @ sw_up.astype(m.dtype)))
+        shared = act @ sw_down.astype(m.dtype)   # the n experts' sum
+        out = out + shared.astype(jnp.float32) * (1.0 / n_shared)
         return out.reshape(b, s, d).astype(x.dtype)

@@ -15,6 +15,7 @@ MXU-aligned widths, all shapes static.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 from typing import Any, Callable
 
@@ -110,6 +111,31 @@ class TransformerConfig:
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # "layer": every norm is a LayerNorm (the mean taken out, a scale and no
+    # bias) in place of RMSNorm; norm_eps is its epsilon.
+    norm: str = "rms"
+    # One norm a layer: the mixer and the feed-forward both read norm(x) and
+    # both are added to the residual (x + mixer(h) + ff(h)).  False is the
+    # sequential block with a norm before each.
+    parallel_block: bool = False
+    # "sliding_attention" layers see the last sliding_window positions
+    # (query i sees keys i - sliding_window < j <= i) and are rotated;
+    # "full_attention" layers see every earlier position and none at all
+    # ("nope").  "attention" layers follow ``rotary`` and have no window.
+    sliding_window: int | None = None
+    # The rotary pairs: False rotates (i, i + d/2) (rotate_half), True the
+    # adjacent pair (2i, 2i + 1) ("rope_gptj").
+    rope_interleaved: bool = False
+    # Sparse feed-forward, further (models/moe.py): how a token's experts
+    # are scored ("softmax" over all experts, or "sigmoid" of each logit);
+    # num_shared_experts experts of width mlp_dim every token visits, their
+    # mean added to the routed sum; experts_held = (lo, hi) says this chip
+    # holds routed experts lo..hi-1 of num_experts (expert parallelism's
+    # share: the router keeps all num_experts outputs, the layer computes
+    # what its own experts give).  None holds them all.
+    moe_selection: str = "softmax"
+    num_shared_experts: int = 0
+    experts_held: tuple | None = None
     # Mamba-2 sizes ("mamba" layers): heads x head size inner channels,
     # a state of state_dim a channel, B and C shared by heads / groups heads,
     # the causal convolution's taps, the scan's chunk.
@@ -119,6 +145,22 @@ class TransformerConfig:
     mamba_groups: int = 1
     mamba_conv_width: int = 4
     mamba_chunk: int = 256
+
+    @classmethod
+    def from_dict(cls, fields: dict) -> "TransformerConfig":
+        """A configuration from plain data (a JSON file's object): lists
+        become tuples and the three dtypes may be names ("bfloat16").  A key
+        that is no field is an error that names it."""
+        known = {f.name for f in dataclasses.fields(cls)}
+        unknown = sorted(set(fields) - known)
+        if unknown:
+            raise ValueError(f"TransformerConfig has no field {unknown}")
+        out = {k: tuple(v) if isinstance(v, list) else v
+               for k, v in fields.items()}
+        for k in ("dtype", "param_dtype", "logits_dtype"):
+            if isinstance(out.get(k), str):
+                out[k] = jnp.dtype(out[k]).type
+        return cls(**out)
 
     @property
     def layer_kinds(self) -> tuple:
@@ -156,28 +198,77 @@ class RMSNorm(nn.Module):
         return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope(x, positions, theta: float):
-    """Rotary embeddings; x: [B, S, H, D], positions: [B, S] (f32 math)."""
+class LayerNorm(nn.Module):
+    """LayerNorm over the last axis with one ``scale`` vector and no bias:
+    the mean taken out, then :class:`RMSNorm`'s arithmetic on what is left
+    (statistics in f32, output in ``dtype``)."""
+
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+    epsilon: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           self.param_dtype)
+        x = x.astype(self.dtype)
+        xf = x.astype(jnp.float32)
+        xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+        inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                            + self.epsilon)
+        return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+NORMS = {"rms": RMSNorm, "layer": LayerNorm}
+
+
+def make_norm(cfg: "TransformerConfig", name: str):
+    """The model's norm (``cfg.norm``) under ``name``."""
+    try:
+        cls = NORMS[cfg.norm]
+    except KeyError:
+        raise ValueError(f"norm {cfg.norm!r}; models/transformer.py has "
+                         f"{sorted(NORMS)}") from None
+    return cls(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+               epsilon=cfg.norm_eps, name=name)
+
+
+def rope(x, positions, theta: float, interleaved: bool = False):
+    """Rotary embeddings; x: [B, S, H, D], positions: [B, S] (f32 math).
+    Pair i is (x[i], x[i + D/2]), or with ``interleaved`` the adjacent
+    (x[2i], x[2i + 1]); the same angle either way."""
     d = x.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angles = positions[..., None].astype(jnp.float32) * freq  # [B, S, d/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if interleaved:
+        xf = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
+        x1, x2 = xf[..., 0], xf[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 
 
 def dense_causal_attention(q, k, v, causal: bool = True,
-                           scale: float | None = None):
+                           scale: float | None = None,
+                           window: int | None = None):
     """Reference attention: one softmax(QKᵀ)V, causal-masked. [B, S, H, D];
-    k and v may have fewer heads (grouped-query)."""
+    k and v may have fewer heads (grouped-query).  With ``window`` (causal
+    only) query i sees keys i - window < j <= i."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     k, v = repeat_kv_heads(k, q.shape[2]), repeat_kv_heads(v, q.shape[2])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    if window is not None and not causal:
+        raise ValueError("a sliding window is a causal band: causal=True")
     if causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((s_q, s_k), dtype=bool),
+                              k=s_k - s_q - window)
         logits = jnp.where(mask, logits, -1e30)
     probs = nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -210,7 +301,8 @@ def init_kv_pages(cfg: TransformerConfig, num_pages: int, page_size: int):
 
 
 def cached_decode_attention(q, k_cache, v_cache, lengths,
-                            scale: float | None = None):
+                            scale: float | None = None,
+                            window: int | None = None):
     """Block attention over a per-slot KV cache.
 
     ``q``: [B, S_q, H, D] — the block of positions being decoded per
@@ -222,27 +314,53 @@ def cached_decode_attention(q, k_cache, v_cache, lengths,
     position masked causally.  Same f32-softmax/-1e30-mask arithmetic as
     :func:`dense_causal_attention`, so an incrementally decoded position
     matches the full forward pass.  The caches may hold fewer heads than
-    ``q`` (grouped-query)."""
+    ``q`` (grouped-query).  With ``window`` a row at position p sees cache
+    positions p - window < j <= p alone."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
-    k_cache = repeat_kv_heads(k_cache, q.shape[2])
-    v_cache = repeat_kv_heads(v_cache, q.shape[2])
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_cache).astype(
-        jnp.float32) * scale
     s, s_q = k_cache.shape[1], q.shape[1]
     qpos = lengths[:, None] + jnp.arange(s_q)[None, :]         # [B, S_q]
     mask = (jnp.arange(s)[None, None, :]
             <= qpos[:, :, None])[:, None, :, :]                # [B,1,S_q,S]
-    logits = jnp.where(mask, logits, -1e30)
+    if window is not None:
+        mask &= (jnp.arange(s)[None, None, :]
+                 > qpos[:, :, None] - window)[:, None, :, :]
+    # The query heads of a group ride one axis beside their KV head, so a
+    # grouped-query cache is read once as it lies.  Repeated to the query
+    # heads it would be written and read again every step, group times the
+    # cache's bytes (16 x at 128 query to 8 KV heads).
+    heads, kv_heads = q.shape[2], k_cache.shape[2]
+    if heads % kv_heads:
+        raise ValueError(f"{kv_heads} KV heads do not divide {heads} "
+                         f"query heads")
+    grouped = q.reshape(*q.shape[:2], kv_heads, heads // kv_heads,
+                        q.shape[-1])
+    logits = jnp.einsum("bqhgd,bkhd->bhgqk", grouped, k_cache).astype(
+        jnp.float32) * scale
+    logits = jnp.where(mask[:, :, None], logits, -1e30)
     probs = nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v_cache)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v_cache).reshape(q.shape)
 
 
 class Attention(nn.Module):
+    """Causal self-attention.  ``layer_type`` decides positions and mask:
+    "attention" follows ``cfg.rotary`` and sees every earlier position;
+    "sliding_attention" is rotated and sees ``cfg.sliding_window``
+    positions; "full_attention" is not rotated and sees them all."""
+
     cfg: TransformerConfig
+    layer_type: str = "attention"
 
     @nn.compact
     def __call__(self, x, positions, cache=None, return_kv=False):
         cfg = self.cfg
+        rotary = {"attention": cfg.rotary, "sliding_attention": True,
+                  "full_attention": False}[self.layer_type]
+        window = None
+        if self.layer_type == "sliding_attention":
+            if not cfg.sliding_window:
+                raise ValueError("a sliding_attention layer needs "
+                                 "TransformerConfig.sliding_window")
+            window = int(cfg.sliding_window)
         proj = lambda name, heads: nn.DenseGeneral(  # noqa: E731
             (heads, cfg.head_dim), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name=name)
@@ -254,14 +372,19 @@ class Attention(nn.Module):
                     dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                     epsilon=cfg.norm_eps, name=f"{name}_norm")(
                     y.reshape(*y.shape[:-2], -1)).reshape(y.shape)
-            return rope(y, positions, cfg.rope_theta) if cfg.rotary else y
+            if not rotary:
+                return y
+            return rope(y, positions, cfg.rope_theta,
+                        interleaved=cfg.rope_interleaved)
 
         q, k = rotated("q", cfg.num_heads), rotated("k", cfg.kv_heads)
         v = proj("v", cfg.kv_heads)(x)
-        # a caller's softmax scale goes to the attention function by name;
-        # without one the call is what it always was
-        scaled = ({} if cfg.attention_scale is None
-                  else {"scale": cfg.attention_scale})
+        # a caller's softmax scale and a layer's window go to the attention
+        # function by name; without them the call is what it always was
+        told = ({} if cfg.attention_scale is None
+                else {"scale": cfg.attention_scale})
+        if window is not None:
+            told["window"] = window
         o_proj = nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), use_bias=False,
                                  dtype=cfg.dtype,
                                  param_dtype=cfg.param_dtype, name="o")
@@ -277,19 +400,20 @@ class Attention(nn.Module):
             k_cache = jax.vmap(upd)(k_cache, k, lengths)
             v_cache = jax.vmap(upd)(v_cache, v, lengths)
             out = cached_decode_attention(q, k_cache, v_cache, lengths,
-                                          **scaled)
+                                          **told)
             return o_proj(out), (k_cache, v_cache)
         attn = cfg.attention_fn
         if attn is None and cfg.context_axis and cfg.context_plan is not None:
-            if cfg.kv_heads != cfg.num_heads or scaled:
+            if cfg.kv_heads != cfg.num_heads or told:
                 raise NotImplementedError(
                     "ring / zigzag attention over a context axis takes as "
-                    "many KV heads as query heads and the d^-1/2 scale")
+                    "many KV heads as query heads, the d^-1/2 scale and no "
+                    "sliding window")
             from horovod_tpu.parallel.context import context_attention_fn
 
             attn = context_attention_fn(cfg.context_axis, cfg.context_plan)
         attn = attn or dense_causal_attention
-        out = attn(q, k, v, causal=True, **scaled)
+        out = attn(q, k, v, causal=True, **told)
         if return_kv:
             return o_proj(out), (k, v)
         return o_proj(out)
@@ -310,14 +434,18 @@ class MLP(nn.Module):
                         name="down")(nn.silu(gate) * up)
 
 
-# layer type -> (module of the mixer's class, the class, its name in a layer).
-# A mixer is ``Mixer(cfg, name=...)(x, positions)``; one that can serve from a
-# cache also takes ``cache`` / ``return_kv`` and then returns (out, kv).
+# layer type -> (module of the mixer's class, the class, its name in a layer,
+# what else its constructor is told).  A mixer is
+# ``Mixer(cfg, name=...)(x, positions)``; one that can serve from a cache
+# also takes ``cache`` / ``return_kv`` and then returns (out, kv).
+_ATTENTION = ("horovod_tpu.models.transformer", "Attention", "attn")
 MIXERS = {
-    "attention": ("horovod_tpu.models.transformer", "Attention", "attn"),
-    "mamba": ("horovod_tpu.models.mamba", "Mamba2Mixer", "mamba"),
+    "attention": _ATTENTION + ({},),
+    "sliding_attention": _ATTENTION + ({"layer_type": "sliding_attention"},),
+    "full_attention": _ATTENTION + ({"layer_type": "full_attention"},),
+    "mamba": ("horovod_tpu.models.mamba", "Mamba2Mixer", "mamba", {}),
 }
-CACHED_MIXERS = ("attention",)
+CACHED_MIXERS = ("attention", "sliding_attention", "full_attention")
 
 
 def _scaled(x, multiplier: float):
@@ -328,58 +456,74 @@ def _scaled(x, multiplier: float):
     return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
 
 
+def _feed_forward(cfg: TransformerConfig, y, valid=None):
+    """The layer's feed-forward on its normed input: the dense GLU MLP or
+    one of the two sparse layouts.  A function inside :class:`Block`'s
+    compact call, so that flax adds no method's name to the module path."""
+    if cfg.num_experts > 0:
+        from horovod_tpu.models.moe import MoEMLP
+
+        if cfg.moe_axis is not None:
+            raise ValueError("num_experts (every expert on each device) "
+                             "and moe_axis (one expert a device) are two "
+                             "layouts; set one")
+        return MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
+                      axis_name=None, dtype=cfg.dtype,
+                      num_experts=cfg.num_experts,
+                      experts_per_token=cfg.experts_per_token,
+                      norm_topk_prob=cfg.norm_topk_prob,
+                      selection=cfg.moe_selection,
+                      num_shared_experts=cfg.num_shared_experts,
+                      experts_held=cfg.experts_held,
+                      param_dtype=cfg.param_dtype, name="moe_mlp")(
+                          y, valid=valid)
+    if cfg.moe_axis is not None:
+        from horovod_tpu.models.moe import MoEMLP
+
+        # Residual carries over-capacity (dropped) tokens unchanged.
+        return MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
+                      axis_name=cfg.moe_axis,
+                      capacity_factor=cfg.moe_capacity_factor,
+                      dtype=cfg.dtype, name="moe_mlp")(y)
+    return MLP(cfg, name="mlp")(y)
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     layer_type: str = "attention"
 
     @nn.compact
-    def __call__(self, x, positions, cache=None, return_kv=False):
+    def __call__(self, x, positions, cache=None, return_kv=False,
+                 valid=None):
         cfg = self.cfg
-        norm = lambda name: RMSNorm(  # noqa: E731
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-            epsilon=cfg.norm_eps, name=name)
+        norm = functools.partial(make_norm, cfg)
         try:
-            module, cls, name = MIXERS[self.layer_type]
+            module, cls, name, told = MIXERS[self.layer_type]
         except KeyError:
             raise ValueError(f"layer type {self.layer_type!r}; "
                              f"models/transformer.py has {sorted(MIXERS)}"
                              ) from None
-        mixer = getattr(importlib.import_module(module), cls)(cfg, name=name)
+        mixer = getattr(importlib.import_module(module), cls)(
+            cfg, name=name, **told)
         y = norm(f"{name}_norm")(x)
         kv = None
         if cache is not None or return_kv:
             mixed, kv = mixer(y, positions, cache=cache, return_kv=return_kv)
         else:
             mixed = mixer(y, positions)
-        x = x + _scaled(mixed, cfg.residual_multiplier)
-        y = norm("mlp_norm")(x)
-        if cfg.num_experts > 0:
-            from horovod_tpu.models.moe import MoEMLP
-
-            if cfg.moe_axis is not None:
-                raise ValueError("num_experts (every expert on each device) "
-                                 "and moe_axis (one expert a device) are two "
-                                 "layouts; set one")
-            ff = MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
-                        axis_name=None, dtype=cfg.dtype,
-                        num_experts=cfg.num_experts,
-                        experts_per_token=cfg.experts_per_token,
-                        norm_topk_prob=cfg.norm_topk_prob,
-                        param_dtype=cfg.param_dtype, name="moe_mlp")(y)
-        elif cfg.moe_axis is not None:
-            from horovod_tpu.models.moe import MoEMLP
-
-            # Residual carries over-capacity (dropped) tokens unchanged.
-            ff = MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
-                        axis_name=cfg.moe_axis,
-                        capacity_factor=cfg.moe_capacity_factor,
-                        dtype=cfg.dtype, name="moe_mlp")(y)
+        if cfg.parallel_block:
+            # one norm a layer: the feed-forward reads what the mixer read
+            # and both are added to the residual
+            out = x + _scaled(mixed + _feed_forward(cfg, y, valid),
+                              cfg.residual_multiplier)
         else:
-            ff = MLP(cfg, name="mlp")(y)
-        x = x + _scaled(ff, cfg.residual_multiplier)
+            x = x + _scaled(mixed, cfg.residual_multiplier)
+            out = x + _scaled(
+                _feed_forward(cfg, norm("mlp_norm")(x), valid),
+                cfg.residual_multiplier)
         if cache is not None or return_kv:
-            return x, kv
-        return x
+            return out, kv
+        return out
 
 
 class Transformer(nn.Module):
@@ -405,14 +549,22 @@ class Transformer(nn.Module):
       ``(logits [B, vocab], (k, v))`` with the caches advanced in place.
       The decode program's shapes are fixed by the slot count, so the
       jitted step never recompiles as sequences come and go.
+    * ``valid`` ([B, S] bool; a sparse model, ``num_experts`` > 0): which
+      positions hold a token.  A prefill bucket's padding and a slot with
+      no request are routed to no expert (models/moe.py).
     """
 
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, tokens, position_offset=0, positions=None,
-                 kv_cache=None, lengths=None, return_kv=False):
+                 kv_cache=None, lengths=None, return_kv=False, valid=None):
         cfg = self.cfg
+        if valid is not None and cfg.num_experts == 0:
+            raise ValueError("valid says which positions a sparse "
+                             "feed-forward routes: num_experts is 0")
+        # only a caller that says which positions hold a token tells a layer
+        told = {} if valid is None else {"valid": valid}
         decode = kv_cache is not None
         kinds = cfg.layer_kinds
         if (decode or return_kv) and set(kinds) - set(CACHED_MIXERS):
@@ -452,15 +604,14 @@ class Transformer(nn.Module):
             if decode:
                 x, kv = block(
                     x, positions,
-                    cache=(kv_cache[0][i], kv_cache[1][i], lengths))
+                    cache=(kv_cache[0][i], kv_cache[1][i], lengths), **told)
                 kvs.append(kv)
             elif return_kv:
-                x, kv = block(x, positions, return_kv=True)
+                x, kv = block(x, positions, return_kv=True, **told)
                 kvs.append(kv)
             else:
-                x = block(x, positions)
-        x = RMSNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    epsilon=cfg.norm_eps, name="final_norm")(x)
+                x = block(x, positions, **told)
+        x = make_norm(cfg, "final_norm")(x)
         # Head matmul in the compute dtype (bf16 hits the MXU at full rate;
         # f32 params, XLA accumulates in f32); logits upcast for the loss —
         # the standard LLM-trainer convention.  The f32 head matmul this

@@ -85,9 +85,22 @@ def _sub_bounds(k_len, q_min, q_max, ks_min, sub_k, nsub, causal):
     return hi, jnp.clip(interior_end, 0, hi)
 
 
+def _window_bounds(q_min, q_max, ks_min, sub_k, nsub, window):
+    """The band's other side, for a sliding window in which query ``i`` sees
+    keys ``i - window < j <= i``: ``lo`` is the first sub-tile that holds a
+    key some row of the q block still sees (tiles before it lie wholly
+    outside the band and are skipped, as tiles past the diagonal are), and
+    ``int_start`` the first sub-tile every row sees whole (before it the
+    band's lower edge cuts the tile and the mask is needed)."""
+    lo = jnp.clip((q_min - window + 1 - ks_min) // sub_k, 0, nsub)
+    int_start = jnp.clip(-((ks_min - (q_max - window + 1)) // sub_k), 0, nsub)
+    return lo, int_start
+
+
 def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                   m_ref, l_ref, *, block_q: int, block_k: int, sub_k: int,
-                  num_k_blocks: int, causal: bool, scale: float):
+                  num_k_blocks: int, causal: bool, scale: float,
+                  window: int | None = None):
     """One (batch·head, q-block, K-super-tile) program: online softmax.
 
     Two-level streaming: the grid's K axis moves (block_k, D) SUPER tiles
@@ -130,6 +143,9 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     # Sub-tile bounds (scalar arithmetic on SMEM values):
     hi, interior_end = _sub_bounds(meta_ref[2], q_min, q_max, ks_min,
                                    sub_k, nsub, causal)
+    if window is not None:
+        lo, int_start = _window_bounds(q_min, q_max, ks_min, sub_k, nsub,
+                                       window)
 
     # The s matmul runs on INPUT-dtype operands: under JAX's default TPU
     # matmul precision an f32×f32 dot already executes as a single bf16
@@ -156,6 +172,8 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
             mask = k_pos < meta_ref[2]                    # padding mask
             if causal:
                 mask = jnp.logical_and(mask, q_pos >= k_pos)
+            if window is not None:
+                mask = jnp.logical_and(mask, q_pos - k_pos < window)
             s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp2(s - m_new)
@@ -178,7 +196,25 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
         m_ref[0] = jnp.broadcast_to(m[:, 0][None, :], m_ref.shape[1:])
         l_ref[0] = jnp.broadcast_to(l[:, 0][None, :], l_ref.shape[1:])
 
-    if nsub == 1:
+    if window is not None:
+        # The band has two edges: sub-tiles in [lo, hi) run, of them those
+        # in [int_start, interior_end) mask-free.  Same static unroll.
+        for si in range(nsub):
+            inside = jnp.logical_and(si >= int_start, si < interior_end)
+
+            @pl.when(inside)
+            def _interior(si=si):
+                _writeback(*body(si, (m_ref[0, 0, :][:, None],
+                                      l_ref[0, 0, :][:, None]),
+                                 masked=False))
+
+            @pl.when(jnp.logical_and(
+                jnp.logical_and(si >= lo, si < hi), jnp.logical_not(inside)))
+            def _boundary(si=si):
+                _writeback(*body(si, (m_ref[0, 0, :][:, None],
+                                      l_ref[0, 0, :][:, None]),
+                                 masked=True))
+    elif nsub == 1:
         # Static single-tile case (the measured optimum): straight-line
         # bodies under pl.when — a dynamic-bound fori_loop here defeats
         # Mosaic's scheduling and costs ~5 MFU points (docs/benchmarks.md).
@@ -439,7 +475,7 @@ def _meta(q_offset, k_offset, s_k: int):
 
 
 def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                interpret, sub, out_dtype, scale=None):
+                interpret, sub, out_dtype, scale=None, window=None):
     """The forward kernel on [B, S, H, D] inputs, everything it read and
     wrote left in the kernels' layout, padded to whole blocks:
     ``(qb, kb, vb, ob, lse_b)`` with ``ob`` [B·H, S_q_pad, D] in
@@ -457,7 +493,7 @@ def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k, sub_k=sub_k,
         num_k_blocks=num_k_blocks, causal=causal,
-        scale=d ** -0.5 if scale is None else scale)
+        scale=d ** -0.5 if scale is None else scale, window=window)
     ob, lse_b = pl.pallas_call(
         kernel,
         grid=(qb.shape[0], num_q_blocks, num_k_blocks),
@@ -800,28 +836,36 @@ class _Lengths:
     s_k: int
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
-           interpret, scale):
+           interpret, scale, window):
     """One device, one call over the whole sequence: nothing sums its
     results again, so the kernels write the compute dtype themselves."""
     return _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                      sub, interpret, scale)[0]
+                      sub, interpret, scale, window)[0]
 
 
 def _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
-               interpret, scale):
+               interpret, scale, window):
     # The residuals stay as the forward call read and wrote them, in the
     # layout the backward kernel reads: nothing is laid out twice.
     qb, kb, vb, ob, lse_b = _forward_bh(
         q, k, v, causal, q_offset, k_offset, block_q, block_k, interpret,
-        sub, q.dtype, scale)
+        sub, q.dtype, scale, window)
     return _from_bh(ob, q.shape[0], q.shape[1]), (
         qb, kb, vb, ob, lse_b, q_offset, k_offset,
         _Lengths(q.shape[1], k.shape[1]))
 
 
-def _flash_bwd(causal, block_q, block_k, sub, interpret, scale, res, g):
+def _flash_bwd(causal, block_q, block_k, sub, interpret, scale, window, res,
+               g):
+    if window is not None:
+        raise NotImplementedError(
+            f"flash_attention(window={window}) has no backward: the "
+            f"sliding window is in the forward kernel alone (a serving "
+            f"prefill); the backward kernel knows the causal mask only. "
+            f"Differentiate dense_causal_attention(window=...) instead")
     qb, kb, vb, ob, lse_b, q_offset, k_offset, lengths = res
     b = g.shape[0]
     # Only the incoming cotangent is laid out here.
@@ -862,8 +906,14 @@ def _default_block_k(s_k: int, d: int) -> int:
 def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
                     block_q: int = 1024, block_k: int | None = None,
                     sub: int = 1024, interpret: bool | None = None,
-                    scale: float | None = None):
+                    scale: float | None = None, window: int | None = None):
     """Fused attention over [B, S, H, D] tensors.
+
+    ``window`` (causal only) is a sliding window: query ``i`` sees keys
+    ``i - window < j <= i``.  The forward kernel skips the sub-tiles that lie
+    wholly before the band as it skips those past the diagonal, and masks
+    the two edges.  The backward kernel has no window: differentiating a
+    windowed call raises ``NotImplementedError`` (docs/inference.md).
 
     ``scale`` is the softmax scale, ``d ** -0.5`` when None.  It reaches the
     kernels as the constant they fold into q (an argument, not a pre-scale
@@ -913,9 +963,12 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     block_k = min(block_k, max(k.shape[1], 1))
     block_q, block_k = clamp_blocks_to_vmem(
         block_q, block_k, q.shape[-1], sub, q.dtype.itemsize)
+    if window is not None and not causal:
+        raise ValueError("a sliding window is a causal band: causal=True")
     k, v = repeat_kv_heads(k, q.shape[2]), repeat_kv_heads(v, q.shape[2])
     return _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                  sub, interpret, None if scale is None else float(scale))
+                  sub, interpret, None if scale is None else float(scale),
+                  None if window is None else int(window))
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
@@ -955,7 +1008,8 @@ def make_flash_attention(block_q: int = 1024, block_k: int | None = None,
                          sub: int = 1024):
     """Adapter producing a ``TransformerConfig.attention_fn``.  block_k
     defaults per-call to min(S, 2048) at d<=128 (_default_block_k)."""
-    def attn(q, k, v, causal=True, scale=None):
+    def attn(q, k, v, causal=True, scale=None, window=None):
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
-                               block_k=block_k, sub=sub, scale=scale)
+                               block_k=block_k, sub=sub, scale=scale,
+                               window=window)
     return attn

@@ -8,8 +8,9 @@ rejoins via a JOIN ticket and pulls the weights from its ring neighbor
 over the bulk data plane — no disk.
 
 Knobs (utils/env.py table): ``HVD_TPU_SERVE_BACKEND`` (``transformer`` —
-a small real model on the KV-cache decode path — or ``stub``, the
-jax-free token automaton), ``HVD_TPU_SERVE_QPS``,
+a small real model on the KV-cache decode path, or the model a JSON file of
+``TransformerConfig`` fields describes, ``HVD_TPU_SERVE_MODEL`` — or
+``stub``, the jax-free token automaton), ``HVD_TPU_SERVE_QPS``,
 ``HVD_TPU_SERVE_DURATION_S``, plus the scheduler shape knobs
 ``HVD_TPU_SERVE_SLOTS`` / ``_BUCKETS`` / ``_MAX_LEN`` and the fast-path
 knobs ``HVD_TPU_SERVE_PREFIX_PAGES`` / ``_PAGE_TOKENS`` (the
@@ -44,12 +45,20 @@ def _make_backend(cfg: ServingConfig):
 
     chip.enable_compile_cache()
 
-    mcfg = TransformerConfig(vocab_size=256, num_layers=2, num_heads=2,
-                             head_dim=16, embed_dim=32, mlp_dim=64,
-                             max_seq_len=cfg.max_seq_len)
+    if env_knobs.serve_model():
+        # a configuration's own model: every TransformerConfig field is the
+        # file's to set (layer types, window, parallel block, experts held)
+        with open(env_knobs.serve_model()) as f:
+            mcfg = TransformerConfig.from_dict(
+                {**json.load(f), "max_seq_len": cfg.max_seq_len})
+    else:
+        mcfg = TransformerConfig(vocab_size=256, num_layers=2, num_heads=2,
+                                 head_dim=16, embed_dim=32, mlp_dim=64,
+                                 max_seq_len=cfg.max_seq_len)
     model = Transformer(mcfg)
     toks = jax.numpy.zeros((1, cfg.buckets[0]), jax.numpy.int32)
-    params = model.init(jax.random.PRNGKey(0), toks)
+    init = jax.jit(model.init) if env_knobs.serve_model() else model.init
+    params = init(jax.random.PRNGKey(0), toks)
     if cfg.prefix_cache_pages > 0:
         from horovod_tpu.serving.engine import PagedTransformerBackend
 
@@ -100,7 +109,8 @@ def main() -> int:
                          seed=rank,
                          prompt_lens=tuple(
                              b - 2 for b in cfg.buckets[:3]),
-                         vocab=256)
+                         vocab=backend.model.cfg.vocab_size
+                         if hasattr(backend, "model") else 256)
     if eng is None:
         rep = loadgen.run_load(serving, w, max_wall_s=w.duration_s * 20)
     else:

@@ -247,7 +247,20 @@ class TransformerBackend:
     Inactive slots decode garbage at position 0; the engine masks their
     output and the next prefill overwrites their cache.  Sampling is
     greedy (argmax) — deterministic, which the bit-exactness test needs.
+
+    Prefill attends densely (one ``[1, H, S, S]`` float32 logits array)
+    while that array fits :data:`DENSE_PREFILL_LOGITS_BYTES` at
+    ``max_seq_len``, and through the flash forward kernel
+    (ops/flash_attention.py) for a model whose heads make it larger: chosen
+    from the model's shape, for every bucket alike.  A model with a sparse
+    feed-forward (``num_experts`` > 0) also hands back, each call, the pairs
+    each held expert of each layer was given, ``last_expert_pairs``
+    ([L, held]; the running sums are ``moe_counters``).
     """
+
+    # the prefill's dense attention logits, [1, H, max_seq_len, max_seq_len]
+    # float32, may take this much; past it prefill runs the flash forward
+    DENSE_PREFILL_LOGITS_BYTES = 2 * 2 ** 30
 
     def __init__(self, model, params, model_cfg, num_slots: int,
                  max_seq_len: int):
@@ -258,19 +271,58 @@ class TransformerBackend:
         self.num_slots, self.max_seq_len = num_slots, max_seq_len
         from horovod_tpu.models.transformer import init_kv_cache
 
+        self.prefill_model = model
+        self.flash_prefill = (
+            model_cfg.attention_fn is None and 4 * model_cfg.num_heads
+            * max_seq_len ** 2 > self.DENSE_PREFILL_LOGITS_BYTES)
+        if self.flash_prefill:
+            from horovod_tpu.ops.flash_attention import make_flash_attention
+
+            self.prefill_model = type(model)(dataclasses.replace(
+                model_cfg, attention_fn=make_flash_attention()))
+        self.sparse = model_cfg.num_experts > 0
+        self.last_expert_pairs = None
+        # calls, (token, expert) pairs routed (a prompt's own positions and
+        # the slots that hold a request: padding and empty slots route
+        # nowhere), and of them the pairs on the experts held here, over
+        # every prefill and decode call
+        self.moe_counters = {"calls": 0, "pairs": 0, "held_pairs": 0}
+        self._pairs_per_token = (model_cfg.num_layers
+                                 * model_cfg.experts_per_token)
         self.kk, self.vv = init_kv_cache(model_cfg, num_slots, max_seq_len)
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
         self._decode = jax.jit(self._decode_fn, donate_argnums=(1, 2))
         self._verify = jax.jit(self._verify_fn, donate_argnums=(1, 2))
 
+    def _apply(self, model, params, tokens, **kwargs):
+        """``model.apply``; for a sparse model also the pairs each held
+        expert was given, as its expert layers sowed them, stacked over the
+        layers [L, held]; else None."""
+        if not self.sparse:
+            return model.apply(params, tokens, **kwargs), None
+        from horovod_tpu.models.moe import MOE_STATS
+
+        out, sown = model.apply(params, tokens, mutable=[MOE_STATS],
+                                **kwargs)
+        layers = [sown[MOE_STATS][f"layer_{i}"]["moe_mlp"]
+                  for i in range(len(sown[MOE_STATS]))]
+        return out, self._jax.numpy.stack(
+            [lay["expert_pairs"][0] for lay in layers])
+
     def _prefill_fn(self, params, kk, vv, padded, length, slot):
         jax, jnp = self._jax, self._jax.numpy
-        logits, (pk, pv) = self.model.apply(params, padded, return_kv=True)
+        # a sparse model routes the prompt's own positions, not the bucket's
+        # padding (and below, the slots that hold a request, not the rest)
+        told = {"valid": jnp.arange(padded.shape[1])[None, :] < length} \
+            if self.sparse else {}
+        (logits, (pk, pv)), pairs = self._apply(
+            self.prefill_model, params, padded, return_kv=True, **told)
         kk = jax.lax.dynamic_update_slice(kk, pk, (0, slot, 0, 0, 0))
         vv = jax.lax.dynamic_update_slice(vv, pv, (0, slot, 0, 0, 0))
         last = jax.lax.dynamic_slice(
             logits, (0, length - 1, 0), (1, 1, logits.shape[-1]))[0, 0]
-        return kk, vv, jnp.argmax(last).astype(jnp.int32), last
+        out = kk, vv, jnp.argmax(last).astype(jnp.int32), last
+        return out if pairs is None else out + (pairs,)
 
     def _decode_fn(self, params, kk, vv, last_tokens, lengths):
         jnp = self._jax.numpy
@@ -279,10 +331,19 @@ class TransformerBackend:
         # = lengths - 1.  Passing lengths unshifted would write K/V one
         # slot too far, leaving a hole the mask still covers — zeros on a
         # fresh slot, a previous occupant's stale K/V on a reused one.
-        logits, (kk, vv) = self.model.apply(
-            params, last_tokens[:, None], kv_cache=(kk, vv),
-            lengths=jnp.maximum(lengths - 1, 0))
-        return kk, vv, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+        told = {"valid": (lengths > 0)[:, None]} if self.sparse else {}
+        (logits, (kk, vv)), pairs = self._apply(
+            self.model, params, last_tokens[:, None], kv_cache=(kk, vv),
+            lengths=jnp.maximum(lengths - 1, 0), **told)
+        out = kk, vv, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+        return out if pairs is None else out + (pairs,)
+
+    def _count_pairs(self, pairs, tokens: int) -> None:
+        self.last_expert_pairs = pairs = np.asarray(pairs)
+        c = self.moe_counters
+        c["calls"] += 1
+        c["pairs"] += tokens * self._pairs_per_token
+        c["held_pairs"] += int(pairs.sum())
 
     def _verify_fn(self, params, kk, vv, tok_block, lengths):
         jnp = self._jax.numpy
@@ -300,16 +361,20 @@ class TransformerBackend:
 
     def prefill(self, padded: np.ndarray, length: int, slot: int):
         jnp = self._jax.numpy
-        self.kk, self.vv, first, logits = self._prefill(
+        self.kk, self.vv, first, logits, *pairs = self._prefill(
             self.params, self.kk, self.vv, jnp.asarray(padded),
             length, slot)
+        if pairs:
+            self._count_pairs(pairs[0], int(length))
         return int(first), np.asarray(logits)
 
     def decode(self, last_tokens: np.ndarray, lengths: np.ndarray):
         jnp = self._jax.numpy
-        self.kk, self.vv, nxt, logits = self._decode(
+        self.kk, self.vv, nxt, logits, *pairs = self._decode(
             self.params, self.kk, self.vv, jnp.asarray(last_tokens),
             jnp.asarray(lengths))
+        if pairs:
+            self._count_pairs(pairs[0], int((lengths > 0).sum()))
         return np.asarray(nxt), np.asarray(logits)
 
     def verify(self, tok_block: np.ndarray, lengths: np.ndarray):

@@ -193,6 +193,11 @@ aliases; the TPU-specific defaults differ where the hardware does:
 * ``HVD_TPU_SERVE_BACKEND`` — ``transformer`` (default: small real model
   on the KV-cache decode path) or ``stub`` (jax-free token automaton)
   for ``python -m horovod_tpu.serving`` replicas.
+* ``HVD_TPU_SERVE_MODEL`` — path of a JSON file of ``TransformerConfig``
+  fields (``TransformerConfig.from_dict``) for the ``transformer``
+  backend to serve in place of its small default model: layer types, a
+  sliding window, a parallel block, sparse experts and the experts held
+  here are all fields (docs/inference.md).
 """
 
 from __future__ import annotations
@@ -593,6 +598,13 @@ def _serve_number(name: str, default, cast, floor=None):
             f"default {default}", RuntimeWarning, stacklevel=3)
         return default
     return value
+
+
+def serve_model() -> str | None:
+    """``HVD_TPU_SERVE_MODEL`` — path of a JSON file of
+    ``TransformerConfig`` fields the ``transformer`` serving backend
+    builds its model from; unset: the small default model."""
+    return _get("SERVE_MODEL") or None
 
 
 def serve_slots() -> int:

@@ -51,6 +51,7 @@ MOE_ROUTE = "hvd_moe_route"     # models/moe: router matmul, softmax, top-k,
 MOE_DISPATCH = "hvd_moe_dispatch"   # ... tokens gathered into expert order
 MOE_EXPERTS = "hvd_moe_experts"     # ... the grouped matmuls, the activation
 MOE_COMBINE = "hvd_moe_combine"     # ... back to token order, gate-weighted sum
+MOE_SHARED = "hvd_moe_shared"       # ... the experts every token visits
 SSM_PROJ = "hvd_ssm_proj"       # models/mamba: the in and out projections
 SSM_CONV = "hvd_ssm_conv"       # ... causal depthwise conv, bias, silu
 SSM_SCAN = "hvd_ssm_scan"       # ... ops/ssd_scan: softplus, the decays' sums,

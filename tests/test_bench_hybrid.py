@@ -278,12 +278,13 @@ def test_an_ssm_reader_reports_nothing_where_there_is_nothing_to_read(
 def test_the_manifest_holds_the_cell_and_its_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    cell = manifest["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        "granite4hm-s8192", "granite-4.0-h-micro", "hybrid-pretrain-1x8192",
-        1)
-    entry = manifest["configs"][-1]
-    assert entry["name"] == cell["config"]
+    # by name: a later cell, configuration or metric goes after these
+    cell = next(c for c in manifest["workloads"]
+                if c["name"] == "granite4hm-s8192")
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "granite-4.0-h-micro", "hybrid-pretrain-1x8192", 1)
+    entry = next(c for c in manifest["configs"]
+                 if c["name"] == cell["config"])
     assert entry["reduced"] == ["num_hidden_layers", "layer_types",
                                 "vocab_size"]
     with open(os.path.join(ROOT, entry["file"])) as f:
@@ -325,7 +326,9 @@ def test_the_manifest_holds_the_cell_and_its_metrics():
         <= reported
     assert not {"flash_ms", "flash_roofline", "flash_dq_ms", "flash_dkv_ms",
                 "moe_ms", "img_per_s", "allreduce_ms"} & reported
-    for m in manifest["per_layer"][-6:]:
+    ssm = [m for m in manifest["per_layer"] if m["name"] in SSM_READERS]
+    assert len(ssm) == 6
+    for m in ssm:
         assert m["layer"] == "models (models/mamba.py)"
         assert m["workloads"] == [cell["name"]]
 
