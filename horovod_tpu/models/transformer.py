@@ -22,6 +22,7 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from horovod_tpu.ops.flash_attention import repeat_kv_heads
 
@@ -282,7 +283,12 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     continuous-batching scheduler (serving/engine.py) admits a request
     into a free slot (prefill writes positions ``0..len``) and decode
     appends one position per step, so the buffer is allocated once and
-    the jitted programs never see a shape change."""
+    the jitted programs never see a shape change.  A cache call
+    (``Transformer.__call__(kv_cache=...)``) hands these two arrays through
+    its layers whole and writes the new positions into them
+    (:func:`write_kv_block`); a caller that donates them to its jitted
+    program (``donate_argnums``) has them updated where they lie, one that
+    does not pays a copy of both a call."""
     s = max_len or cfg.max_seq_len
     shape = (cfg.num_layers, num_slots, s, cfg.kv_heads, cfg.head_dim)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
@@ -298,6 +304,25 @@ def init_kv_pages(cfg: TransformerConfig, num_pages: int, page_size: int):
     shape = (cfg.num_layers, num_pages, page_size, cfg.kv_heads,
              cfg.head_dim)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+
+
+def write_kv_block(pool, block, layer: int, lengths):
+    """``pool`` [L, B, S, H, D] with ``block`` [B, S_q, H, D] written at
+    ``(layer, b, lengths[b])``: one ``dynamic_update_slice`` a slot, each of
+    the block's own bytes.  Nothing else of the pool is read or written, so
+    where the caller donated the pool XLA updates it where it lies.
+
+    The pool keeps the layout it came in.  Left to itself XLA:TPU lays a
+    grouped-query pool out [L, B, H, S, D] for the attention products and
+    copies all of it in and out again every call, more than the stack of
+    updated slices this replaces (19.3 ms a decode step of
+    ``cmdaplus-code8k-open``'s program against 19.4 before and 16.0 with
+    the layout held; PERF.md section 6, PR 38)."""
+    for b in range(block.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, block[b][None, None], (layer, b, lengths[b], 0, 0))
+    return with_layout_constraint(
+        pool, Layout(major_to_minor=tuple(range(pool.ndim))))
 
 
 def cached_decode_attention(q, k_cache, v_cache, lengths,
@@ -389,19 +414,18 @@ class Attention(nn.Module):
                                  dtype=cfg.dtype,
                                  param_dtype=cfg.param_dtype, name="o")
         if cache is not None:
-            # Incremental decode: x is [B, 1, E]; write this position's K/V
-            # into each slot's cache at its current length, attend over the
-            # cache.  K/V at a position depend only on that position's token
-            # and rotary phase, so cached entries match what a full forward
-            # pass would compute there.
-            k_cache, v_cache, lengths = cache
-            upd = lambda c, u, i: jax.lax.dynamic_update_slice(  # noqa: E731
-                c, u, (i, 0, 0))
-            k_cache = jax.vmap(upd)(k_cache, k, lengths)
-            v_cache = jax.vmap(upd)(v_cache, v, lengths)
-            out = cached_decode_attention(q, k_cache, v_cache, lengths,
-                                          **told)
-            return o_proj(out), (k_cache, v_cache)
+            # Incremental decode: x is [B, S_q, E]; write the block's K/V
+            # into the whole pool at (this layer, slot, its current length),
+            # attend over this layer's view of the pool, hand the pool on.
+            # K/V at a position depend only on that position's token and
+            # rotary phase, so cached entries match what a full forward pass
+            # would compute there.
+            k_pool, v_pool, lengths, layer = cache
+            k_pool = write_kv_block(k_pool, k, layer, lengths)
+            v_pool = write_kv_block(v_pool, v, layer, lengths)
+            out = cached_decode_attention(q, k_pool[layer], v_pool[layer],
+                                          lengths, **told)
+            return o_proj(out), (k_pool, v_pool)
         attn = cfg.attention_fn
         if attn is None and cfg.context_axis and cfg.context_plan is not None:
             if cfg.kv_heads != cfg.num_heads or told:
@@ -546,7 +570,14 @@ class Transformer(nn.Module):
     * ``kv_cache=(k, v)`` + ``lengths`` — one incremental decode step:
       ``tokens`` is ``[B, 1]`` (the last sampled token per slot),
       ``lengths`` ``[B]`` the position each slot is decoding at; returns
-      ``(logits [B, vocab], (k, v))`` with the caches advanced in place.
+      ``(logits [B, vocab], (k, v))`` with the caches advanced in place:
+      the two whole ``[L, B, S, H, D]`` arrays go through the layers, layer
+      ``i`` writes its block at ``(i, b, lengths[b])``
+      (:func:`write_kv_block`), attends over its own view ``k[i]`` and
+      hands the arrays on.  No layer's slice is rebuilt and nothing is
+      stacked, so a jitted caller that DONATES ``k`` and ``v`` gets them
+      back in the same memory with the block's rows written; without the
+      donation XLA copies both arrays once a call.
       The decode program's shapes are fixed by the slot count, so the
       jitted step never recompiles as sequences come and go.
     * ``valid`` ([B, S] bool; a sparse model, ``num_experts`` > 0): which
@@ -602,10 +633,10 @@ class Transformer(nn.Module):
         for i, kind in enumerate(kinds):
             block = block_cls(cfg, kind, name=f"layer_{i}")
             if decode:
-                x, kv = block(
-                    x, positions,
-                    cache=(kv_cache[0][i], kv_cache[1][i], lengths), **told)
-                kvs.append(kv)
+                # the whole pool goes through every layer: layer i writes
+                # its block into it and reads its own view of it
+                x, kv_cache = block(
+                    x, positions, cache=(*kv_cache, lengths, i), **told)
             elif return_kv:
                 x, kv = block(x, positions, return_kv=True, **told)
                 kvs.append(kv)
@@ -624,13 +655,11 @@ class Transformer(nn.Module):
         logits = _scaled(logits, 1.0 / cfg.logits_scaling).astype(
             cfg.logits_dtype)
         if decode:
-            kv_out = (jnp.stack([kv[0] for kv in kvs]),
-                      jnp.stack([kv[1] for kv in kvs]))
             if tokens.shape[1] == 1:
-                return logits[:, 0], kv_out
+                return logits[:, 0], kv_cache
             # Multi-token cache call (speculative verify / suffix
             # prefill): the caller needs every block position's logits.
-            return logits, kv_out
+            return logits, kv_cache
         if return_kv:
             return logits, (jnp.stack([kv[0] for kv in kvs]),
                             jnp.stack([kv[1] for kv in kvs]))

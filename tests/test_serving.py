@@ -288,6 +288,86 @@ def test_hot_swap_changes_output_without_recompile(small_model):
     assert a.tokens != b.tokens  # the swap actually took
 
 
+def test_decode_program_advances_the_pool_in_place():
+    # The decode program writes one position a layer and slot into the
+    # donated [L, B, S, H, D] buffers: both are aliased to outputs and the
+    # program keeps no second copy of the pool (a stack of updated layer
+    # slices was two buffers of temporaries).  The CPU compiler copies one
+    # layer's view for the products, a twelfth of a buffer here.
+    from horovod_tpu.models.transformer import (Transformer,
+                                                TransformerConfig)
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    cfg = TransformerConfig(vocab_size=64, num_layers=12, num_heads=2,
+                            head_dim=8, embed_dim=16, mlp_dim=32,
+                            max_seq_len=256, dtype=jnp.float32,
+                            logits_dtype=jnp.float32)
+    model = Transformer(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    backend = TransformerBackend(model, params, cfg, 4, max_seq_len=256)
+    kv = jax.ShapeDtypeStruct(backend.kk.shape, backend.kk.dtype)
+    i32 = jax.ShapeDtypeStruct((4,), jnp.int32)
+    mem = backend._decode.lower(params, kv, kv, i32,
+                                i32).compile().memory_analysis()
+    buffer_bytes = backend.kk.nbytes
+    assert mem.alias_size_in_bytes >= 2 * buffer_bytes
+    assert mem.temp_size_in_bytes < buffer_bytes / 4, (
+        mem.temp_size_in_bytes, buffer_bytes)
+
+
+@pytest.mark.parametrize("case", ["one_position", "verify_block",
+                                  "grouped_query", "sliding_layer"])
+def test_cache_call_writes_its_block_alone(case):
+    # model.apply(kv_cache=...) hands back the pool it was given with the
+    # block's K/V at (layer, slot, lengths[slot] ...) and every other entry
+    # as it was; K/V and logits there are the full forward pass's.
+    from horovod_tpu.models.transformer import (Transformer,
+                                                TransformerConfig)
+
+    s_q = 1 if case == "one_position" else 3
+    told = {"grouped_query": {"num_heads": 4, "num_kv_heads": 2},
+            "sliding_layer": {"layer_types": ("sliding_attention",
+                                              "full_attention"),
+                              "sliding_window": 4}}.get(case, {})
+    cfg = TransformerConfig(**(dict(
+        vocab_size=64, num_layers=2, num_heads=2, head_dim=8, embed_dim=16,
+        mlp_dim=32, max_seq_len=32, dtype=jnp.float32,
+        logits_dtype=jnp.float32) | told))
+    model = Transformer(cfg)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    rng = np.random.RandomState(7)
+    lens = [5, 11, 0]                   # a slot's cached prefix; one empty
+    seqs = [rng.randint(0, 64, n + s_q) for n in lens]
+    shape = (cfg.num_layers, len(lens), 32, cfg.kv_heads, cfg.head_dim)
+    # no entry of the pool is zero, so an entry left alone is told from
+    # one written over
+    k_in = rng.uniform(1.0, 2.0, shape).astype(np.float32)
+    v_in = rng.uniform(1.0, 2.0, shape).astype(np.float32)
+    full = []
+    for b, (n, seq) in enumerate(zip(lens, seqs)):
+        logits, (fk, fv) = model.apply(params, jnp.asarray(seq[None]),
+                                       return_kv=True)
+        full.append((np.asarray(logits[0]), np.asarray(fk[:, 0]),
+                     np.asarray(fv[:, 0])))
+        k_in[:, b, :n], v_in[:, b, :n] = full[b][1][:, :n], full[b][2][:, :n]
+    block = jnp.asarray(np.stack([seq[n:] for n, seq in zip(lens, seqs)]))
+    logits, (k_out, v_out) = model.apply(
+        params, block, kv_cache=(jnp.asarray(k_in), jnp.asarray(v_in)),
+        lengths=jnp.asarray(lens, jnp.int32))
+    logits = np.asarray(logits).reshape(len(lens), s_q, -1)
+    written = np.zeros(shape, bool)
+    for b, n in enumerate(lens):
+        written[:, b, n:n + s_q] = True
+        np.testing.assert_allclose(logits[b], full[b][0][n:], rtol=2e-5,
+                                   atol=2e-5)
+        for out, ref in ((k_out, full[b][1]), (v_out, full[b][2])):
+            np.testing.assert_allclose(np.asarray(out)[:, b, n:n + s_q],
+                                       ref[:, n:], rtol=2e-5, atol=2e-5)
+    for out, given in ((k_out, k_in), (v_out, v_in)):
+        assert np.array_equal(np.asarray(out)[~written], given[~written])
+        assert (np.asarray(out)[written] != given[written]).all()
+
+
 # ---------------------------------------------------------------------------
 # Prefix cache: radix-trie refcounting + bit-exact prefix-attached decode
 # ---------------------------------------------------------------------------

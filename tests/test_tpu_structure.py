@@ -301,3 +301,65 @@ def test_grouped_flash_attention_at_head_size_64_compiles_for_the_chip(
     root = [line for line in text.splitlines() if "ROOT" in line][-1]
     assert "bf16[1,8192,32,64]" in root and root.count(
         "bf16[1,8192,8,64]") == 2
+
+
+# the two served shapes (heads, KV heads, layer types, window, slots, S) at
+# a sixth of the dense model's depth and the sparse one's one period, and
+# how many copies of one layer's view XLA may make: none where the products
+# are a loop fusion over the pool as it lies (one query a head), one for K
+# and one for V a layer where they are a convolution (a group of queries a
+# KV head), whose operand XLA:TPU does not take from a slice of the pool
+@pytest.mark.parametrize("heads,kv_heads,layer_types,window,slots,s,views", [
+    (16, 16, None, None, 8, 4352, 0),
+    (128, 8, ("sliding_attention",) * 3 + ("full_attention",), 4096, 8,
+     8448, 8)])
+def test_decode_program_keeps_no_copy_of_the_kv_pool(
+        one_chip_mesh, heads, kv_heads, layer_types, window, slots, s, views):
+    """``TransformerBackend``'s decode program at the served widths, as the
+    chip's compiler leaves it (PR 38): the two donated ``[L, B, S, KV, D]``
+    buffers are aliased to outputs and stay in the layout they came in,
+    every op whose result is as large as the pool is the in-place update of
+    one slot's rows, and the program's temporaries are under a quarter of
+    one buffer beside the two layer views a grouped-query model copies (its
+    parent sliced every layer out and stacked them again: two buffers of
+    temporaries, 74% of a decode step)."""
+    from horovod_tpu.models.transformer import (Transformer,
+                                                TransformerConfig)
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    one_chip = NamedSharding(one_chip_mesh, P())
+    cfg = TransformerConfig(
+        vocab_size=32256, num_layers=4, num_heads=heads, head_dim=128,
+        num_kv_heads=kv_heads, embed_dim=2048, mlp_dim=5504, max_seq_len=s,
+        layer_types=layer_types, sliding_window=window,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    model = Transformer(cfg)
+    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    backend = TransformerBackend.__new__(TransformerBackend)
+    backend._jax, backend.model, backend.sparse = jax, model, False
+    kv = on_chip(jax.ShapeDtypeStruct((4, slots, s, kv_heads, 128),
+                                      jnp.bfloat16))
+    i32 = on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32))
+    compiled = jax.jit(backend._decode_fn, donate_argnums=(1, 2)).lower(
+        params, kv, kv, i32, i32).compile()
+    view = math.prod(kv.shape[1:])          # elements; bf16 is 2 bytes
+    buffer_bytes, view_bytes = 2 * math.prod(kv.shape), 2 * view
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * buffer_bytes
+    # a copied view of K and one of V are live at a time
+    assert mem.temp_size_in_bytes < buffer_bytes / 4 + (
+        2 * view_bytes if views else 0)
+    large = [i for i in _entry_instructions(compiled.as_text())
+             if i["opcode"] not in ("parameter", "tuple", "get-tuple-element")
+             and any(n >= view for _, n in _arrays(i["shape"]))]
+    whole = [i for i in large
+             if any(n > view for _, n in _arrays(i["shape"]))]
+    assert whole and all(
+        i["opcode"] == "fusion" and "dynamic-update-slice" in i["name"]
+        and "{4,3,2,1,0" in i["shape"] for i in whole), [
+            (i["name"], i["shape"]) for i in whole]
+    assert len(large) - len(whole) <= views, [
+        i["name"] for i in large if i not in whole]
