@@ -3,8 +3,9 @@
 This is what ``run.py --serve`` launches: a continuous-batching replica
 that joins the fleet's control plane (env-based rendezvous, identical to
 a training rank), serves a self-generated Poisson workload, and prints a
-one-line JSON report.  A relaunched seat (``HVD_TPU_ELASTIC_JOIN=1``)
-rejoins via a JOIN ticket and pulls the weights from its ring neighbor
+one-line JSON report: the load generator's latencies, ``serving_stats()``
+and, under ``"spans"``, ``ServingEngine.span_summary()``.  A relaunched
+seat (``HVD_TPU_ELASTIC_JOIN=1``) rejoins via a JOIN ticket and pulls the weights from its ring neighbor
 over the bulk data plane — no disk.
 
 Knobs (utils/env.py table): ``HVD_TPU_SERVE_BACKEND`` (``transformer`` —
@@ -115,7 +116,8 @@ def main() -> int:
         rep = loadgen.run_load(serving, w, max_wall_s=w.duration_s * 20)
     else:
         rep = _serve_fleet(serving, w, params)
-    out = {"rank": rank, **rep, **serving.stats()}
+    out = {"rank": rank, **rep, **serving.stats(),
+           "spans": serving.span_summary()}
     print("SERVE_REPORT " + json.dumps(out), flush=True)
     if eng is not None:
         em.peek_engine().shutdown()

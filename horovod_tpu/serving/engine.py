@@ -35,6 +35,18 @@ RECONFIG, protocol-driven drain on QUIT) is model-checked by
 re-derives both historical serving bugs from pre-fix models as pinned
 regression traces — see docs/static_analysis.md "Protocol model
 checking" and tests/golden/traces/.
+
+What the engine records of itself, beside its counters: with a native
+``collective`` attached, SERVING_ADMIT / _EVICT / _REJECT / _PREFIX_HIT /
+_SPEC_ACCEPT instants on the coordination plane's timeline; always, on the
+compiled path's side (``utils/profiling.py``: a ``TraceAnnotation`` where
+jax is loaded, and a record in its bounded ring either way), the spans
+``hvd_srv_request`` (submit → eviction), ``hvd_srv_queued`` (submit → the
+start of its prefill call), ``hvd_srv_step``, ``hvd_srv_prefill`` /
+``hvd_srv_decode`` / ``hvd_srv_verify`` around the backend's calls and,
+inside a model backend's call, ``hvd_srv_h2d``, ``hvd_srv_dispatch``,
+``hvd_srv_wait``, ``hvd_srv_fetch`` (docs/inference.md "What the engine
+records", :meth:`ServingEngine.span_summary`).
 """
 
 from __future__ import annotations
@@ -48,6 +60,7 @@ from typing import Any, Callable
 import numpy as np
 
 from horovod_tpu.serving.prefix_cache import PrefixCache
+from horovod_tpu.utils import profiling
 
 _ACTIVE = None  # most recently constructed ServingEngine, for serving_stats()
 
@@ -67,11 +80,13 @@ _FLOAT_STATS = frozenset((
 
 def _pctile(xs, q: float) -> float:
     """Nearest-rank percentile; 0.0 on empty — jax-free, matches the
-    loadgen's reporting so engine and client percentiles are comparable."""
-    if not xs:
+    loadgen's reporting so engine and client percentiles are comparable.
+    A selection, not a sort: the autoscaling rank reads ``stats()`` every
+    tick."""
+    if len(xs) == 0:
         return 0.0
-    xs = sorted(xs)
-    return float(xs[min(len(xs) - 1, int(q / 100.0 * len(xs)))])
+    k = min(len(xs) - 1, int(q / 100.0 * len(xs)))
+    return float(np.partition(np.asarray(xs, np.float64), k)[k])
 
 
 @dataclasses.dataclass
@@ -99,6 +114,8 @@ class Request:
     ttft_s: float | None = None
     token_lat_s: list[float] = dataclasses.field(default_factory=list)
     _last_token_t: float = 0.0
+    # its hvd_srv_request record, open from submit() to eviction
+    _span: Any = dataclasses.field(default=None, repr=False, compare=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,6 +253,26 @@ class StubBackend:
         return preds, logits
 
 
+def _wait_and_fetch(results: list) -> tuple[np.ndarray, ...]:
+    """The last two of a model backend call's four leaf spans (the first
+    two, ``hvd_srv_h2d`` around the copies in and ``hvd_srv_dispatch``
+    around the jitted call, which returns when the work is enqueued, are
+    each backend's ``_call``).  ``hvd_srv_wait`` is the wait for the device,
+    taken as it always was: by asking for the tokens, a few bytes that
+    arrive when the program has run.  (A ``block_until_ready`` before the
+    fetches is one more trip to the runtime, 0.2 ms a call on a v5e:
+    PERF.md, PR 39.)  ``hvd_srv_fetch`` is the rest of the results' way to
+    the host, the logits and, sparse, the pair counts, with the bytes that
+    was; the device's copies are let go inside it."""
+    with profiling.span(profiling.SRV_WAIT):
+        tokens = np.asarray(results[0])
+    with profiling.span(profiling.SRV_FETCH) as s:
+        rest = tuple(np.asarray(r) for r in results[1:])
+        s.fields["bytes"] = sum(a.nbytes for a in rest)
+        results.clear()
+    return (tokens,) + rest
+
+
 class TransformerBackend:
     """Real-model backend on the KV-cache path of models/transformer.py.
 
@@ -338,8 +375,8 @@ class TransformerBackend:
         out = kk, vv, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
         return out if pairs is None else out + (pairs,)
 
-    def _count_pairs(self, pairs, tokens: int) -> None:
-        self.last_expert_pairs = pairs = np.asarray(pairs)
+    def _count_pairs(self, pairs: np.ndarray, tokens: int) -> None:
+        self.last_expert_pairs = pairs
         c = self.moe_counters
         c["calls"] += 1
         c["pairs"] += tokens * self._pairs_per_token
@@ -359,30 +396,34 @@ class TransformerBackend:
             lengths=jnp.maximum(lengths - 1, 0))
         return kk, vv, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
 
-    def prefill(self, padded: np.ndarray, length: int, slot: int):
+    def _call(self, program, host_inputs, passed=()):
+        """``program`` over the donated pool: the four leaf spans in order,
+        the pool kept, and on the host (tokens, logits) and, sparse, the
+        pair counts."""
         jnp = self._jax.numpy
-        self.kk, self.vv, first, logits, *pairs = self._prefill(
-            self.params, self.kk, self.vv, jnp.asarray(padded),
-            length, slot)
+        with profiling.span(profiling.SRV_H2D):
+            copied = [jnp.asarray(x) for x in host_inputs]
+        with profiling.span(profiling.SRV_DISPATCH):
+            self.kk, self.vv, *results = program(
+                self.params, self.kk, self.vv, *copied, *passed)
+        return _wait_and_fetch(results)
+
+    def prefill(self, padded: np.ndarray, length: int, slot: int):
+        first, logits, *pairs = self._call(self._prefill, (padded,),
+                                           (length, slot))
         if pairs:
             self._count_pairs(pairs[0], int(length))
-        return int(first), np.asarray(logits)
+        return int(first), logits
 
     def decode(self, last_tokens: np.ndarray, lengths: np.ndarray):
-        jnp = self._jax.numpy
-        self.kk, self.vv, nxt, logits, *pairs = self._decode(
-            self.params, self.kk, self.vv, jnp.asarray(last_tokens),
-            jnp.asarray(lengths))
+        nxt, logits, *pairs = self._call(self._decode,
+                                         (last_tokens, lengths))
         if pairs:
             self._count_pairs(pairs[0], int((lengths > 0).sum()))
-        return np.asarray(nxt), np.asarray(logits)
+        return nxt, logits
 
     def verify(self, tok_block: np.ndarray, lengths: np.ndarray):
-        jnp = self._jax.numpy
-        self.kk, self.vv, preds, logits = self._verify(
-            self.params, self.kk, self.vv, jnp.asarray(tok_block),
-            jnp.asarray(lengths))
-        return np.asarray(preds), np.asarray(logits)
+        return self._call(self._verify, (tok_block, lengths))
 
     def swap_params(self, params) -> None:
         """Zero-downtime weight hot-swap: the next step (prefill or
@@ -522,31 +563,31 @@ class PagedTransformerBackend:
     def prefill(self, padded: np.ndarray, length: int, slot: int):
         return self.prefill_prefixed(padded, length, slot, 0)
 
+    def _call(self, program, host_inputs):
+        """``program`` over the donated pages: the four leaf spans in
+        order, the pages kept, (tokens, logits) back on the host."""
+        jnp = self._jax.numpy
+        with profiling.span(profiling.SRV_H2D):
+            copied = [jnp.asarray(x) for x in host_inputs]
+        with profiling.span(profiling.SRV_DISPATCH):
+            self.pk, self.pv, *results = program(
+                self.params, self.pk, self.pv, *copied)
+        return _wait_and_fetch(results)
+
     def prefill_prefixed(self, padded: np.ndarray, suffix_len: int,
                          slot: int, prefix_len: int, prompt=None):
-        jnp = self._jax.numpy
-        row = jnp.asarray(self.page_tables[slot])
-        self.pk, self.pv, first, logits = self._prefill(
-            self.params, self.pk, self.pv, row, jnp.asarray(padded),
-            jnp.asarray(suffix_len, jnp.int32),
-            jnp.asarray(prefix_len, jnp.int32))
-        return int(first), np.asarray(logits)
+        first, logits = self._call(
+            self._prefill, (self.page_tables[slot], padded,
+                            np.int32(suffix_len), np.int32(prefix_len)))
+        return int(first), logits
 
     def decode(self, last_tokens: np.ndarray, lengths: np.ndarray):
-        jnp = self._jax.numpy
-        self.pk, self.pv, nxt, logits = self._decode(
-            self.params, self.pk, self.pv,
-            jnp.asarray(self.page_tables), jnp.asarray(last_tokens),
-            jnp.asarray(lengths))
-        return np.asarray(nxt), np.asarray(logits)
+        return self._call(self._decode,
+                          (self.page_tables, last_tokens, lengths))
 
     def verify(self, tok_block: np.ndarray, lengths: np.ndarray):
-        jnp = self._jax.numpy
-        self.pk, self.pv, preds, logits = self._verify(
-            self.params, self.pk, self.pv,
-            jnp.asarray(self.page_tables), jnp.asarray(tok_block),
-            jnp.asarray(lengths))
-        return np.asarray(preds), np.asarray(logits)
+        return self._call(self._verify,
+                          (self.page_tables, tok_block, lengths))
 
     def swap_params(self, params) -> None:
         self.params = params
@@ -607,8 +648,10 @@ class ServingEngine:
             ("admitted", "evicted", "completed", "rejected", "retried",
              "steps", "tokens", "prompt_tokens", "prefix_hits",
              "prefix_hit_tokens", "spec_drafted", "spec_accepted"), 0)
-        self._ttft_s: list[float] = []
-        self._token_s: list[float] = []
+        # the last SPAN_CAPACITY first-token and token latencies: what
+        # stats() takes its percentiles over
+        self._ttft_s: deque[float] = deque(maxlen=profiling.SPAN_CAPACITY)
+        self._token_s: deque[float] = deque(maxlen=profiling.SPAN_CAPACITY)
         self._rid = itertools.count()
         self.fleet: dict[str, float] = {}
         # Set by drivers that know their request stream is exhausted; rides
@@ -629,6 +672,8 @@ class ServingEngine:
         req = Request(rid=next(self._rid) if rid is None else rid,
                       prompt=list(prompt), max_new_tokens=max_new_tokens,
                       submitted_t=self.clock())
+        req._span = profiling.open_span(profiling.SRV_REQUEST, rid=req.rid,
+                                        prompt=len(req.prompt))
         if retry:
             self.counters["retried"] += 1
         if len(req.prompt) > max(self.config.buckets) or \
@@ -641,6 +686,7 @@ class ServingEngine:
                 f"(max_seq_len={self.config.max_seq_len}; raise with "
                 f"HVD_TPU_SERVE_MAX_LEN)")
             self.counters["rejected"] += 1
+            req._span.close(finish=req.finish_reason, tokens=0)
             if self.collective is not None:
                 self.collective.timeline_instant(
                     "SERVING_REJECT",
@@ -660,14 +706,20 @@ class ServingEngine:
     # -- the tick ---------------------------------------------------------
 
     def step(self) -> list[Request]:
+        with profiling.span(profiling.SRV_STEP, queued=len(self.queue)):
+            return self._step()
+
+    def _step(self) -> list[Request]:
         done: list[Request] = []
         self._admit(done)
         if any(r is not None for r in self.slots):
             if self._spec_ready():
                 self._spec_step(done)
             else:
-                nxt, logits = self.backend.decode(self.last_tokens,
-                                                  self.lengths)
+                with profiling.span(profiling.SRV_DECODE,
+                                    **self._in_slots()):
+                    nxt, logits = self.backend.decode(self.last_tokens,
+                                                      self.lengths)
                 now = self.clock()
                 for s, req in enumerate(self.slots):
                     if req is None:
@@ -721,11 +773,20 @@ class ServingEngine:
             bucket = self._bucket(len(suffix))
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :len(suffix)] = suffix
-            if self.prefix is not None:
-                first, logits = self.backend.prefill_prefixed(
-                    padded, len(suffix), s, hit, req.prompt)
-            else:
-                first, logits = self.backend.prefill(padded, len(suffix), s)
+            with profiling.span(
+                    profiling.SRV_PREFILL, cause=req._span.id, rid=req.rid,
+                    bucket=bucket, length=len(suffix),
+                    prompt=len(req.prompt), hit=hit) as call:
+                # queued until this call began, to the same reading
+                profiling.open_span(
+                    profiling.SRV_QUEUED, start=req._span.start,
+                    cause=req._span.id, rid=req.rid).close(call.start)
+                if self.prefix is not None:
+                    first, logits = self.backend.prefill_prefixed(
+                        padded, len(suffix), s, hit, req.prompt)
+                else:
+                    first, logits = self.backend.prefill(padded,
+                                                         len(suffix), s)
             now = self.clock()
             req.state, req.slot = "ACTIVE", s
             req.ttft_s = now - req.submitted_t
@@ -812,7 +873,8 @@ class ServingEngine:
                 drafts[s] = self._propose(req, k)
         tok_block = np.concatenate([self.last_tokens[:, None], drafts],
                                    axis=1)
-        preds, logits = self.backend.verify(tok_block, self.lengths)
+        with profiling.span(profiling.SRV_VERIFY, **self._in_slots()):
+            preds, logits = self.backend.verify(tok_block, self.lengths)
         now = self.clock()
         for s, req in enumerate(self.slots):
             if req is None:
@@ -853,6 +915,7 @@ class ServingEngine:
             self.backend.release_slot(slot)
         self.counters["evicted"] += 1
         self.counters["completed"] += 1
+        req._span.close(finish=req.finish_reason, tokens=len(req.tokens))
         if self.collective is not None:
             self.collective.timeline_instant(
                 "SERVING_EVICT", f"req={req.rid} slot={slot} "
@@ -893,12 +956,25 @@ class ServingEngine:
     def _active_count(self) -> int:
         return sum(r is not None for r in self.slots)
 
+    def _in_slots(self) -> dict:
+        """The counts of a decode or verify call's boundary: the slots in
+        use, the requests in them, and the cached tokens they hold."""
+        rids = tuple(r.rid for r in self.slots if r is not None)
+        # (an empty slot's length is 0: _evict)
+        return {"slots": len(rids), "live_tokens": int(self.lengths.sum()),
+                "rids": rids}
+
     def _occupancy(self) -> float:
         return float(np.sum(self.lengths)) / (
             self.config.num_slots * self.config.max_seq_len)
 
     def stats(self) -> dict:
+        """The counters, and the latency percentiles over the last
+        ``profiling.SPAN_CAPACITY`` first tokens and tokens (every one, on
+        a run shorter than that)."""
         c = self.counters
+        ttft_s, token_s = (np.fromiter(xs, np.float64, len(xs))
+                           for xs in (self._ttft_s, self._token_s))
         return {
             "active_slots": self._active_count(),
             "queue_depth": len(self.queue),
@@ -906,10 +982,10 @@ class ServingEngine:
             "completed": c["completed"], "rejected": c["rejected"],
             "retried": c["retried"], "steps": c["steps"],
             "tokens": c["tokens"],
-            "ttft_p50_ms": _pctile(self._ttft_s, 50) * 1e3,
-            "ttft_p99_ms": _pctile(self._ttft_s, 99) * 1e3,
-            "token_p50_ms": _pctile(self._token_s, 50) * 1e3,
-            "token_p99_ms": _pctile(self._token_s, 99) * 1e3,
+            "ttft_p50_ms": _pctile(ttft_s, 50) * 1e3,
+            "ttft_p99_ms": _pctile(ttft_s, 99) * 1e3,
+            "token_p50_ms": _pctile(token_s, 50) * 1e3,
+            "token_p99_ms": _pctile(token_s, 99) * 1e3,
             "kv_slot_occupancy": self._occupancy(),
             "prefix_hits": c["prefix_hits"],
             "prefix_hit_tokens": c["prefix_hit_tokens"],
@@ -921,6 +997,16 @@ class ServingEngine:
             "spec_accept_rate": (c["spec_accepted"]
                                  / max(c["spec_drafted"], 1)),
         }
+
+    @staticmethod
+    def span_summary() -> dict[str, dict]:
+        """Where the serving path's time went, for an operator: per span
+        name (``hvd_srv_step``, ``hvd_srv_wait``, ...) the ``count``,
+        ``total_s`` and ``p50_ms`` / ``p95_ms`` / ``max_ms`` over the
+        records the process's span ring holds (the last
+        ``profiling.SPAN_CAPACITY``, every engine's; bounded memory
+        however long the process serves)."""
+        return profiling.summarize(profiling.spans())
 
 
 def serving_stats() -> dict:
@@ -935,7 +1021,9 @@ def serving_stats() -> dict:
 
     ``admitted``/``evicted`` count slot transitions (every eviction also
     lands as a SERVING_EVICT timeline instant); ``kv_slot_occupancy`` is
-    the filled fraction of the preallocated KV cache.  All zeros when no
+    the filled fraction of the preallocated KV cache; the four percentiles
+    are over the last ``profiling.SPAN_CAPACITY`` (65 536) first tokens
+    and tokens, every one on a shorter run.  All zeros when no
     ``ServingEngine`` has been constructed in this process — mirrors the
     ``control_plane_stats()`` contract."""
     if _ACTIVE is None:

@@ -9,7 +9,8 @@ parent -> worker::
 
     REQ <rid> <max_new> <t0,t1,...>   submit a request (R suffix = retry)
     SWAP <version>                    rank 0: hot-swap new weights fleet-wide
-    STATS                             dump serving_stats() as one line
+    STATS                             dump serving_stats() as one line,
+                                      with span_summary() under "spans"
     QUIT                              drain and exit 0
 
 worker -> parent::
@@ -101,6 +102,11 @@ def _say(line: str) -> None:
     print(line, flush=True)
 
 
+def _stats(serving) -> dict:
+    """What a STATS line holds: the counters, and where the time went."""
+    return {**serving.stats(), "spans": serving.span_summary()}
+
+
 def _reader(q: "queue.Queue[str]") -> None:
     for line in sys.stdin:
         q.put(line.strip())
@@ -182,7 +188,7 @@ def main(argv=None) -> int:
         if cmd == "QUIT":
             quitting = True
         elif cmd == "STATS":
-            _say(f"STATS {serving.stats()!r}")
+            _say(f"STATS {_stats(serving)!r}")
         elif cmd and cmd.startswith("SWAP "):
             version = int(cmd.split()[1])
             state = make_weights(version)
@@ -240,7 +246,7 @@ def main(argv=None) -> int:
                      f"via={via}")
         if mine_done:
             time.sleep(0.001)
-    _say(f"STATS {serving.stats()!r}")
+    _say(f"STATS {_stats(serving)!r}")
     eng.shutdown()
     return 0
 

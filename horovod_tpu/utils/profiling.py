@@ -21,14 +21,28 @@ of the traced program: it reaches the ``op_name`` of every instruction made
 under it and costs nothing on the device.  A span (:func:`annotate`) is a
 ``TraceAnnotation``: with no profiler session open it is a branch on an
 atomic.  "On" is a profiler session.
+
+The serving path's spans (:func:`span`, the ``hvd_srv_*`` names) are the
+same annotation and, besides, one record each in a bounded ring this module
+keeps (:func:`spans`): an untraced run can then be read too, by the
+benchmark (``benchmarks/serve_spans.py``) and by an operator
+(``ServingEngine.span_summary()``).  The ring is always there, holds
+:data:`SPAN_CAPACITY` records and drops the oldest; importing this module,
+or writing a span, never imports jax.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
+import itertools
 import math
 import re
+import sys
+import threading
+import time
+import typing
 
 from horovod_tpu import basics
 
@@ -65,11 +79,27 @@ LOADER_PRODUCE = "hvd_loader_produce"   # ... next(source), producer thread
 H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
 # (``hvd_chain_gate`` is collective_ops.CHAIN_GATE_SCOPE, older than this
 # table; examples/overlap_audit.py counts it.)
+# The serving path's spans (serving/engine.py), written through :func:`span`:
+SRV_REQUEST = "hvd_srv_request"     # ServingEngine: submit() -> eviction
+SRV_QUEUED = "hvd_srv_queued"       # ... submit() -> its prefill call's start
+SRV_STEP = "hvd_srv_step"           # ... one step(): admit, decode, evict
+SRV_PREFILL = "hvd_srv_prefill"     # ... around backend.prefill[_prefixed]
+SRV_DECODE = "hvd_srv_decode"       # ... around backend.decode
+SRV_VERIFY = "hvd_srv_verify"       # ... around backend.verify (speculation)
+SRV_H2D = "hvd_srv_h2d"             # a backend call: the copies in
+SRV_DISPATCH = "hvd_srv_dispatch"   # ... the jitted call, until enqueued
+SRV_WAIT = "hvd_srv_wait"           # ... until the tokens are on the host
+SRV_FETCH = "hvd_srv_fetch"         # ... logits and pair counts to the host
 
 FLASH_PASSES = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD)
 MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
 SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE)
 SSD_PASSES = (SSD_FWD, SSD_BWD)
+SRV_CALLS = (SRV_PREFILL, SRV_DECODE, SRV_VERIFY)   # the backend's calls
+SRV_LEAVES = (SRV_H2D, SRV_DISPATCH, SRV_WAIT, SRV_FETCH)   # in call order
+# records the span ring holds before it drops the oldest: a 35 s serving
+# run writes about 12 000
+SPAN_CAPACITY = 65536
 # XLA:TPU replaces ``lax.ragged_dot`` with Mosaic kernels of its own and
 # names them afresh (``op_name="ragged-dot-none"``, and
 # ``"ragged-dot-metadata"`` for the tile table they share): the scope the
@@ -103,6 +133,128 @@ def annotate(name: str):
     import jax
 
     return jax.profiler.TraceAnnotation(name)
+
+
+# -- Spans that are also records ---------------------------------------------
+
+_ring: collections.deque = collections.deque(maxlen=SPAN_CAPACITY)
+_ids = itertools.count(1)
+_open = threading.local()       # .stack: this thread's open ``with`` spans
+
+
+class Record(typing.NamedTuple):
+    """One record of the ring, as :func:`spans` hands it out: ``start`` and
+    ``end`` are ``time.perf_counter`` seconds, ``cause`` the id of the span
+    that caused it (the enclosing one unless the writer named another, 0
+    for none), ``rid`` the request it belongs to if it belongs to one, and
+    ``fields`` the counts of the boundary (``bucket``, ``length``,
+    ``slots``, ``live_tokens``, ``rids``, ``bytes``)."""
+    name: str
+    start: float
+    end: float
+    id: int
+    cause: int
+    rid: int | None
+    fields: dict
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
+class Span:
+    """A span while it is open; closing it writes its :class:`Record`.
+
+    ``with span(name, ...) as s:`` is the ``TraceAnnotation``
+    :func:`annotate` opens (if jax is loaded: on the XLA profiler's host
+    plane, on its clock, beside the device plane; ``rid`` and ``fields``
+    become the event's stats) and one record in the ring, written when the
+    block ends; its ``cause`` is the enclosing span unless one is named, and
+    ``s.fields`` may be added to inside the block.  A span that is no
+    ``with`` block on one thread is :func:`open_span`'s."""
+
+    __slots__ = ("name", "start", "end", "id", "cause", "rid", "fields",
+                 "_annotation")
+
+    def __init__(self, name: str, *, cause: int | None = None,
+                 rid: int | None = None, **fields):
+        self.name, self.start, self.end = name, None, None
+        self.id, self.cause, self.rid = next(_ids), cause, rid
+        self.fields = fields
+        self._annotation = None
+
+    def close(self, end: float | None = None, **fields) -> None:
+        """Write the record; ``end`` defaults to now.  What the ring keeps
+        is a plain tuple of numbers, strings and tuples of them, which the
+        garbage collector stops tracking at its first look: a ring of
+        objects grew the old generation by some 8 000 a 30 s window, enough
+        to move a full collection, an 88 ms pause of the serving loop, into
+        every window of the benchmark (PERF.md, PR 39)."""
+        self.end = time.perf_counter() if end is None else end
+        self.fields.update(fields)
+        _ring.append((self.name, self.start, self.end, self.id, self.cause,
+                      self.rid, tuple(self.fields.items())))
+
+    def __enter__(self) -> "Span":
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        if self.cause is None:
+            self.cause = stack[-1].id if stack else 0
+        stack.append(self)
+        jax = sys.modules.get("jax")    # never imported from here
+        if jax is not None:
+            stats = self.fields if self.rid is None \
+                else {**self.fields, "rid": self.rid}
+            self._annotation = jax.profiler.TraceAnnotation(self.name,
+                                                            **stats)
+            self._annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        _open.stack.pop()
+
+
+span = Span     # ``with profiling.span(SRV_STEP, queued=3):``
+
+
+def open_span(name: str, *, start: float | None = None, cause: int = 0,
+              rid: int | None = None, **fields) -> Span:
+    """A span that is no ``with`` block on one thread (a request from
+    ``submit()`` to its eviction): a record only, no annotation, written
+    when its ``close()`` is called.  Its ``id`` is there from the start, to
+    be the ``cause`` of what it brings about."""
+    record = Span(name, cause=cause, rid=rid, **fields)
+    record.start = time.perf_counter() if start is None else start
+    return record
+
+
+def spans() -> list[Record]:
+    """The ring's records, oldest first: at most :data:`SPAN_CAPACITY`, in
+    the order they closed."""
+    return [Record(*kept[:6], dict(kept[6])) for kept in list(_ring)]
+
+
+def summarize(records) -> dict[str, dict]:
+    """Per span name: ``count``, ``total_s`` and the nearest-rank
+    ``p50_ms`` / ``p95_ms`` / ``max_ms`` of the durations."""
+    took: dict[str, list] = {}
+    for r in records:
+        took.setdefault(r.name, []).append(r.end - r.start)
+    out = {}
+    for name, xs in took.items():
+        xs.sort()
+        rank = lambda q: (  # noqa: E731
+            1e3 * xs[min(len(xs) - 1, int(q * len(xs)))])
+        out[name] = {"count": len(xs), "total_s": sum(xs),
+                     "p50_ms": rank(0.50), "p95_ms": rank(0.95),
+                     "max_ms": 1e3 * xs[-1]}
+    return out
 
 
 def bucket_scope(k) -> str:
