@@ -285,19 +285,29 @@ class TransformerBackend:
     output and the next prefill overwrites their cache.  Sampling is
     greedy (argmax) — deterministic, which the bit-exactness test needs.
 
-    Prefill attends densely (one ``[1, H, S, S]`` float32 logits array)
-    while that array fits :data:`DENSE_PREFILL_LOGITS_BYTES` at
-    ``max_seq_len``, and through the flash forward kernel
-    (ops/flash_attention.py) for a model whose heads make it larger: chosen
-    from the model's shape, for every bucket alike.  A model with a sparse
+    Which attention a prefill runs is chosen a bucket, from the bucket's
+    own shape (:meth:`prefill_attention`): densely (one ``[1, H, S, S]``
+    float32 logits array a layer, through HBM) while that array fits
+    :data:`FLASH_PREFILL_LOGITS_BYTES`, through the flash forward kernel
+    (ops/flash_attention.py), which never writes it, past that.  One exact
+    causal softmax either way, one parameter tree, the same K and V into the
+    cache; a menu that straddles the limit serves its short prompts through
+    one form and its long ones through the other.  A model with a sparse
     feed-forward (``num_experts`` > 0) also hands back, each call, the pairs
     each held expert of each layer was given, ``last_expert_pairs``
     ([L, held]; the running sums are ``moe_counters``).
     """
 
-    # the prefill's dense attention logits, [1, H, max_seq_len, max_seq_len]
-    # float32, may take this much; past it prefill runs the flash forward
-    DENSE_PREFILL_LOGITS_BYTES = 2 * 2 ** 30
+    # A bucket whose dense attention logits, [1, H, S, S] float32, are larger
+    # than this prefills through the flash forward.  It is where the two
+    # forms cross on a v5e: deepseek-coder-1.3b (24 layers, 16 heads x 128,
+    # bf16, 8 slots of 4352), unloaded, host ms a prefill call dense / flash
+    # (PERF.md section 5, PR 41): S = 512 (16 MiB) 11.9 / 12.1, 1024 (64 MiB)
+    # 20.5 / 21.2, 1152 (81 MiB) 23.4 / 28.3, 1280 (100 MiB) 30.9 / 29.5,
+    # 1536 (144 MiB) 38.2 / 32.8, 2048 (256 MiB) 84.5 / 41.2, 4096 (1 GiB)
+    # 254.2 / 86.1.  The kernel works whole 1024-row tiles, so its time
+    # steps up past 1024 where the dense form's grows by the square.
+    FLASH_PREFILL_LOGITS_BYTES = 96 * 2 ** 20
 
     def __init__(self, model, params, model_cfg, num_slots: int,
                  max_seq_len: int):
@@ -308,15 +318,8 @@ class TransformerBackend:
         self.num_slots, self.max_seq_len = num_slots, max_seq_len
         from horovod_tpu.models.transformer import init_kv_cache
 
-        self.prefill_model = model
-        self.flash_prefill = (
-            model_cfg.attention_fn is None and 4 * model_cfg.num_heads
-            * max_seq_len ** 2 > self.DENSE_PREFILL_LOGITS_BYTES)
-        if self.flash_prefill:
-            from horovod_tpu.ops.flash_attention import make_flash_attention
-
-            self.prefill_model = type(model)(dataclasses.replace(
-                model_cfg, attention_fn=make_flash_attention()))
+        self._model_cfg = model_cfg
+        self._flash_model = None    # built for the first bucket that asks
         self.sparse = model_cfg.num_experts > 0
         self.last_expert_pairs = None
         # calls, (token, expert) pairs routed (a prompt's own positions and
@@ -330,6 +333,42 @@ class TransformerBackend:
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
         self._decode = jax.jit(self._decode_fn, donate_argnums=(1, 2))
         self._verify = jax.jit(self._verify_fn, donate_argnums=(1, 2))
+
+    def prefill_attention(self, bucket: int) -> str:
+        """The attention a prefill of ``bucket`` positions runs: ``"flash"``
+        where the bucket's own dense logits pass
+        :data:`FLASH_PREFILL_LOGITS_BYTES`, ``"dense"`` below; ``"own"`` in
+        every bucket for a model that brought its ``attention_fn``."""
+        if self._model_cfg.attention_fn is not None:
+            return "own"
+        logits_bytes = 4 * self._model_cfg.num_heads * int(bucket) ** 2
+        return ("flash" if logits_bytes > self.FLASH_PREFILL_LOGITS_BYTES
+                else "dense")
+
+    @property
+    def flash_prefill(self) -> bool:
+        """Whether a prompt of ``max_seq_len`` would take the kernel."""
+        return self.prefill_attention(self.max_seq_len) == "flash"
+
+    def _prefill_model(self, bucket: int):
+        """The model a bucket's prefill program is traced through: the
+        bucket's length is static there, so the choice costs the program
+        nothing.  Both share ``params`` (``attention_fn`` is no parameter)."""
+        if self.prefill_attention(bucket) != "flash":
+            return self.model
+        if self._flash_model is None:
+            from horovod_tpu.ops.flash_attention import make_flash_attention
+
+            # jitted, so that a program's layers share one tracing and
+            # lowering of the kernel: pallas_call is otherwise traced and
+            # lowered once a layer, 17.3 s a 24-layer bucket on the chip's
+            # host on every start where this takes 1.3 (PERF.md section 6,
+            # PR 41)
+            attn = self._jax.jit(make_flash_attention(), static_argnames=(
+                "causal", "scale", "window"))
+            self._flash_model = type(self.model)(dataclasses.replace(
+                self._model_cfg, attention_fn=attn))
+        return self._flash_model
 
     def _apply(self, model, params, tokens, **kwargs):
         """``model.apply``; for a sparse model also the pairs each held
@@ -353,7 +392,8 @@ class TransformerBackend:
         told = {"valid": jnp.arange(padded.shape[1])[None, :] < length} \
             if self.sparse else {}
         (logits, (pk, pv)), pairs = self._apply(
-            self.prefill_model, params, padded, return_kv=True, **told)
+            self._prefill_model(padded.shape[1]), params, padded,
+            return_kv=True, **told)
         kk = jax.lax.dynamic_update_slice(kk, pk, (0, slot, 0, 0, 0))
         vv = jax.lax.dynamic_update_slice(vv, pv, (0, slot, 0, 0, 0))
         last = jax.lax.dynamic_slice(
@@ -773,10 +813,14 @@ class ServingEngine:
             bucket = self._bucket(len(suffix))
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :len(suffix)] = suffix
+            # a backend that chooses its prefill's attention by the bucket
+            # says which form this call runs
+            chosen = getattr(self.backend, "prefill_attention", None)
+            attn = {"attn": chosen(bucket)} if chosen else {}
             with profiling.span(
                     profiling.SRV_PREFILL, cause=req._span.id, rid=req.rid,
                     bucket=bucket, length=len(suffix),
-                    prompt=len(req.prompt), hit=hit) as call:
+                    prompt=len(req.prompt), hit=hit, **attn) as call:
                 # queued until this call began, to the same reading
                 profiling.open_span(
                     profiling.SRV_QUEUED, start=req._span.start,
@@ -1005,8 +1049,22 @@ class ServingEngine:
         ``total_s`` and ``p50_ms`` / ``p95_ms`` / ``max_ms`` over the
         records the process's span ring holds (the last
         ``profiling.SPAN_CAPACITY``, every engine's; bounded memory
-        however long the process serves)."""
-        return profiling.summarize(profiling.spans())
+        however long the process serves).  Where a backend chose its
+        prefill's attention by the bucket, ``hvd_srv_prefill`` also has
+        ``attn``: per form (``"flash"``, ``"dense"``) the ``calls`` and the
+        ``prompt_tokens`` they prefilled."""
+        records = profiling.spans()
+        out = profiling.summarize(records)
+        by_attn: dict[str, dict] = {}
+        for r in records:
+            if r.name == profiling.SRV_PREFILL and "attn" in r.fields:
+                row = by_attn.setdefault(r.fields["attn"],
+                                         {"calls": 0, "prompt_tokens": 0})
+                row["calls"] += 1
+                row["prompt_tokens"] += r.fields["length"]
+        if by_attn:
+            out[profiling.SRV_PREFILL]["attn"] = by_attn
+        return out
 
 
 def serving_stats() -> dict:

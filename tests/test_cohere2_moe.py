@@ -320,7 +320,7 @@ def test_sigmoid_routing_and_an_absent_pick():
 
 def test_the_backend_serves_through_the_flash_prefill(family, reference,
                                                       monkeypatch):
-    """``TransformerBackend`` chooses the flash forward from the model's
+    """``TransformerBackend`` chooses the flash forward from a bucket's own
     shape; served greedily through the engine, prompt longer than the
     window, each token's logits are the reference's."""
     from horovod_tpu.serving import ServingConfig, ServingEngine
@@ -330,10 +330,12 @@ def test_the_backend_serves_through_the_flash_prefill(family, reference,
     model, mcfg, params, w = setup(family, cfg)
     dense = TransformerBackend(model, params, mcfg, 2, 64)
     assert not dense.flash_prefill
-    monkeypatch.setattr(TransformerBackend, "DENSE_PREFILL_LOGITS_BYTES",
-                        4 * 16 * 64 ** 2 - 1)
+    # both buckets' logits past the limit: 16 heads x 16**2 x 4 bytes
+    monkeypatch.setattr(TransformerBackend, "FLASH_PREFILL_LOGITS_BYTES",
+                        4 * 16 * 16 ** 2 - 1)
     backend = TransformerBackend(model, params, mcfg, 2, 64)
     assert backend.flash_prefill and backend.sparse
+    assert {backend.prefill_attention(b) for b in (16, 32)} == {"flash"}
     engine = ServingEngine(backend, ServingConfig(
         num_slots=2, buckets=(16, 32), max_seq_len=64, record_logits=True))
     prompt = [int(t) for t in tokens_of(6, 20)]

@@ -25,6 +25,10 @@ The load-bearing claims, each pinned here:
   plain-decode stream on both the reject path (positional stub: nothing
   ever accepted) and the accept path (periodic stub: fewer steps, same
   tokens), and bit-exact tokens on the real transformer.
+* **Prefill attention by bucket** — ``TransformerBackend`` picks dense or
+  flash from the bucket's own logits bytes; a menu that straddles the
+  limit serves what an all-dense backend serves, and the
+  ``hvd_srv_prefill`` span says which form ran.
 
 The chaos soak (grow + SIGKILL under load, serving/soak.py) runs under
 ``-m slow``; SERVING_SOAK_REPS repeats it.
@@ -513,6 +517,196 @@ def test_spec_decode_bit_exact_vs_plain(small_model):
         assert len(solo.logits) == len(req.logits)
         for a, b in zip(solo.logits, req.logits):
             np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# Which attention a prefill bucket runs (PR 41): chosen from the bucket's own
+# dense logits, 4 * heads * S**2 bytes, against one constant; a menu that
+# straddles the constant serves what an all-dense backend serves; the
+# hvd_srv_prefill span says which form ran
+# ---------------------------------------------------------------------------
+
+MIB = 2 ** 20
+
+
+def _shape_only(heads: int, max_seq_len: int):
+    """A backend of ``heads`` query heads that holds no weights: the choice
+    reads the configuration and the bucket, nothing else."""
+    from horovod_tpu.models.transformer import (Transformer,
+                                                TransformerConfig)
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    cfg = TransformerConfig(vocab_size=8, num_layers=1, num_heads=heads,
+                            head_dim=2, embed_dim=4, mlp_dim=4,
+                            max_seq_len=max_seq_len)
+    return TransformerBackend(Transformer(cfg), None, cfg, 1, max_seq_len)
+
+
+# (query heads, bucket, its dense logits, the form): dsc1p3b-code-0.8knee's
+# four buckets, cmdaplus-code8k-open's smallest and largest, the CPU tests'
+@pytest.mark.parametrize("heads, bucket, logits_bytes, form", [
+    (16, 512, 16 * MIB, "dense"),
+    (16, 1024, 64 * MIB, "dense"),
+    (16, 2048, 256 * MIB, "flash"),
+    (16, 4096, 1024 * MIB, "flash"),
+    (128, 512, 128 * MIB, "flash"),
+    (128, 8192, 32768 * MIB, "flash"),
+    (2, 16, 2048, "dense"),
+    (4, 32, 16384, "dense"),
+])
+def test_a_bucket_s_attention_follows_its_own_logits(heads, bucket,
+                                                     logits_bytes, form):
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    assert 4 * heads * bucket ** 2 == logits_bytes
+    assert (logits_bytes > TransformerBackend.FLASH_PREFILL_LOGITS_BYTES) \
+        == (form == "flash")
+    # whatever max_seq_len is, shorter than the bucket or far longer
+    for max_seq_len in (8, 8448):
+        assert _shape_only(heads, max_seq_len).prefill_attention(bucket) \
+            == form
+
+
+def test_the_limit_is_one_constant_below_every_sparse_bucket():
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    limit = TransformerBackend.FLASH_PREFILL_LOGITS_BYTES
+    # cmdaplus-code8k-open's 512 bucket (128 heads) stays on the kernel,
+    # and the KB-sized shapes of the CPU tests stay dense
+    assert 64 * MIB <= limit < 128 * MIB
+    assert not hasattr(TransformerBackend, "DENSE_PREFILL_LOGITS_BYTES")
+
+
+def test_at_the_limit_a_bucket_is_dense_and_a_byte_past_it_flash(monkeypatch):
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    backend = _shape_only(16, 64)
+    monkeypatch.setattr(TransformerBackend, "FLASH_PREFILL_LOGITS_BYTES",
+                        4 * 16 * 1024 ** 2)
+    assert backend.prefill_attention(1024) == "dense"
+    assert backend.prefill_attention(1025) == "flash"
+    assert not backend.flash_prefill            # a prompt of max_seq_len 64
+    monkeypatch.setattr(TransformerBackend, "FLASH_PREFILL_LOGITS_BYTES",
+                        4 * 16 * 64 ** 2 - 1)
+    assert backend.flash_prefill
+
+
+def test_a_model_s_own_attention_fn_is_kept_in_every_bucket():
+    from horovod_tpu.models.transformer import (Transformer,
+                                                TransformerConfig,
+                                                dense_causal_attention)
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    cfg = TransformerConfig(vocab_size=8, num_layers=1, num_heads=128,
+                            head_dim=2, embed_dim=4, mlp_dim=4,
+                            max_seq_len=16,
+                            attention_fn=dense_causal_attention)
+    backend = TransformerBackend(Transformer(cfg), None, cfg, 1, 16)
+    assert backend.prefill_attention(8192) == "own"
+    assert backend._prefill_model(8192) is backend.model
+    assert not backend.flash_prefill
+
+
+def _bucketed_engine(small_model) -> ServingEngine:
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    model, params, cfg = small_model
+    return ServingEngine(
+        TransformerBackend(model, params, cfg, 2, 64),
+        ServingConfig(num_slots=2, buckets=(16, 32), max_seq_len=64,
+                      record_logits=True))
+
+
+SHORT = [5, 9, 2, 7, 11, 3, 40, 41, 8]
+LONG = [int(t) for t in np.random.RandomState(7).randint(0, 64, 27)]
+
+
+@pytest.fixture()
+def straddling(small_model, monkeypatch):
+    """The all-dense engine, then one whose menu straddles the limit: the
+    16 bucket's logits (4 * 2 * 16**2 bytes) are at it, the 32 bucket's
+    past it."""
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    dense = _bucketed_engine(small_model)
+    assert [dense.backend.prefill_attention(b) for b in (16, 32)] == [
+        "dense", "dense"]
+    monkeypatch.setattr(TransformerBackend, "FLASH_PREFILL_LOGITS_BYTES",
+                        4 * 2 * 16 ** 2)
+    mixed = _bucketed_engine(small_model)
+    assert [mixed.backend.prefill_attention(b) for b in (16, 32)] == [
+        "dense", "flash"]
+    return dense, mixed
+
+
+def test_a_menu_that_straddles_the_limit_serves_what_dense_serves(straddling):
+    dense, mixed = straddling
+    served = {}
+    for name, eng in (("dense", dense), ("mixed", mixed)):
+        reqs = [eng.submit(SHORT, 6), eng.submit(LONG, 6)]
+        eng.run_until_idle()
+        served[name] = reqs
+    rel = lambda got, want: float(  # noqa: E731
+        np.max(np.abs(got - want)) / np.max(np.abs(want)))
+    for got, want in zip(served["mixed"], served["dense"]):
+        assert got.tokens == want.tokens and len(got.tokens) == 6
+        for a, b in zip(got.logits, want.logits):
+            assert rel(a, b) < 2e-4
+    # the short prompt went through the same dense program: bit for bit
+    assert all(np.array_equal(a, b) for a, b in zip(
+        served["mixed"][0].logits, served["dense"][0].logits))
+    # the rows the two forms wrote into the cache (slot 1 held LONG): a
+    # layer's K and V are its input's projections, so layer 0's are the
+    # same numbers and layer 1's differ by what the attention's rounding
+    # differs by, far inside a bfloat16's spacing (2**-8)
+    n = len(LONG)
+    for pool_m, pool_d in ((mixed.backend.kk, dense.backend.kk),
+                           (mixed.backend.vv, dense.backend.vv)):
+        rows_m, rows_d = (np.asarray(p[:, 1, :n]) for p in (pool_m, pool_d))
+        assert np.array_equal(rows_m[0], rows_d[0])
+        assert 0 <= rel(rows_m[1], rows_d[1]) < 1e-5 < 2 ** -8
+
+
+def test_the_prefill_span_carries_attn_and_the_summary_counts_by_it(
+        straddling):
+    from horovod_tpu.utils import profiling
+
+    _, mixed = straddling
+    profiling._ring.clear()
+    for prompt in (SHORT, LONG, LONG[:20], SHORT[:4]):
+        mixed.submit(prompt, 2)
+    mixed.run_until_idle()
+    prefills = [r for r in profiling.spans()
+                if r.name == profiling.SRV_PREFILL]
+    assert [(r.fields["bucket"], r.fields["length"], r.fields["attn"])
+            for r in prefills] == [(16, 9, "dense"), (32, 27, "flash"),
+                                   (32, 20, "flash"), (16, 4, "dense")]
+    row = mixed.span_summary()[profiling.SRV_PREFILL]
+    assert row["count"] == 4
+    assert row["attn"] == {"dense": {"calls": 2, "prompt_tokens": 13},
+                           "flash": {"calls": 2, "prompt_tokens": 47}}
+
+
+@pytest.mark.parametrize("kind", ["stub", "paged"])
+def test_a_backend_that_chooses_nothing_writes_no_attn(kind, small_model):
+    from horovod_tpu.serving.engine import PagedTransformerBackend
+    from horovod_tpu.utils import profiling
+
+    if kind == "stub":
+        backend = StubBackend(2)
+    else:
+        model, params, cfg = small_model
+        backend = PagedTransformerBackend(model, params, cfg, 2, 64,
+                                          cache_pages=4, page_size=8)
+    eng = ServingEngine(backend, ServingConfig(
+        num_slots=2, buckets=(16,), max_seq_len=64, page_size=8))
+    profiling._ring.clear()
+    eng.submit(SHORT, 2)
+    eng.run_until_idle()
+    (prefill,) = [r for r in profiling.spans()
+                  if r.name == profiling.SRV_PREFILL]
+    assert set(prefill.fields) == {"bucket", "length", "prompt", "hit"}
+    assert "attn" not in eng.span_summary()[profiling.SRV_PREFILL]
 
 
 # ---------------------------------------------------------------------------
