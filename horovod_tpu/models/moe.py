@@ -141,6 +141,9 @@ class MoEMLP(nn.Module):
     # (the expert leaves are [hi - lo, ...]; the router keeps every output).
     # None holds them all.
     experts_held: tuple | None = None
+    # what a token's gate weights are multiplied by, after norm_topk_prob
+    # (a "routed scaling factor"); at 1 no op at all
+    routed_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, valid=None):
@@ -239,6 +242,8 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
         gates = jnp.take_along_axis(probs, picks, axis=-1)
         if m.norm_topk_prob:
             gates = gates / gates.sum(axis=-1, keepdims=True)
+        if m.routed_scale != 1.0:
+            gates = gates * m.routed_scale
         everything = held == e and valid is None
         if everything:
             local = picks

@@ -17,6 +17,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib
+import math
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
 from horovod_tpu.ops.flash_attention import repeat_kv_heads
+from horovod_tpu.utils import profiling
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,7 +36,8 @@ class TransformerConfig:
     num_heads: int = 8
     head_dim: int = 64
     embed_dim: int = 512
-    # width of the dense GLU MLP; of ONE expert when num_experts > 0
+    # width of the dense GLU MLP; of ONE expert too when num_experts > 0,
+    # unless moe_mlp_dim gives the experts a width of their own
     mlp_dim: int = 2048
     max_seq_len: int = 2048
     dtype: Any = jnp.bfloat16
@@ -137,6 +140,33 @@ class TransformerConfig:
     moe_selection: str = "softmax"
     num_shared_experts: int = 0
     experts_held: tuple | None = None
+    # The width of one routed (and of one shared) expert where it is not
+    # mlp_dim; the first first_dense_layers layers of a sparse model keep the
+    # dense GLU MLP of width mlp_dim; a token's gate weights, after
+    # norm_topk_prob, are multiplied by moe_routed_scale.
+    moe_mlp_dim: int | None = None
+    first_dense_layers: int = 0
+    moe_routed_scale: float = 1.0
+    # Latent attention ("latent_attention" layers, :class:`LatentAttention`):
+    # q through a norm at q_lora_rank, K and V through one at kv_lora_rank
+    # beside one rotary key of qk_rope_head_dim for all heads; a head's query
+    # and key are qk_nope_head_dim + qk_rope_head_dim wide, its value
+    # v_head_dim.  head_dim is not read by such a layer.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN on the rotary frequencies (:func:`yarn_frequencies`): (factor,
+    # original positions, beta_fast, beta_slow, mscale, mscale_all_dim).
+    # Read by latent attention, whose softmax scale it also sets.
+    rope_yarn: tuple | None = None
+    # A forward pass without a cache (training, a serving prefill) over more
+    # positions than this runs each layer's feed-forward, with its norm, a
+    # chunk of this many positions at a time: the rows to and from the
+    # experts and a wide dense layer's gate and up are then a chunk's, not
+    # the sequence's.  Attention sees the whole sequence.  None: in one piece.
+    feed_forward_chunk: int | None = None
     # Mamba-2 sizes ("mamba" layers): heads x head size inner channels,
     # a state of state_dim a channel, B and C shared by heads / groups heads,
     # the causal convolution's taps, the scan's chunk.
@@ -176,6 +206,16 @@ class TransformerConfig:
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads or self.num_heads
+
+    @property
+    def latent(self) -> bool:
+        """Every layer is latent attention: the cache is one of latents."""
+        kinds = set(self.layer_kinds)
+        if "latent_attention" in kinds and len(kinds) > 1:
+            raise NotImplementedError(
+                f"latent attention beside {sorted(kinds - {'latent_attention'})}"
+                f" layers: the cache pool has one shape for all layers")
+        return kinds == {"latent_attention"}
 
 
 class RMSNorm(nn.Module):
@@ -234,15 +274,50 @@ def make_norm(cfg: "TransformerConfig", name: str):
                epsilon=cfg.norm_eps, name=name)
 
 
-def rope(x, positions, theta: float, interleaved: bool = False):
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor: 0.1 mscale ln(factor) + 1 past factor 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(d: int, theta: float, yarn: tuple):
+    """([d/2] float32 rotary frequencies, what cos and sin are multiplied
+    by) under YaRN, ``yarn`` as ``TransformerConfig.rope_yarn``.  Pair i's
+    frequency is theta^(-2i/d) where the pair turns more than beta_fast
+    times over the original positions, that over ``factor`` where it turns
+    fewer than beta_slow times, and between the two correction dimensions
+    their blend by a linear ramp."""
+    factor, original, beta_fast, beta_slow, mscale, mscale_all_dim = yarn
+
+    def correction_dim(turns):      # the pair that turns this many times
+        return d * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), d - 1)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    return (freq / factor * ramp + freq * (1.0 - ramp),
+            yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim))
+
+
+def rope(x, positions, theta: float, interleaved: bool = False,
+         yarn: tuple | None = None):
     """Rotary embeddings; x: [B, S, H, D], positions: [B, S] (f32 math).
     Pair i is (x[i], x[i + D/2]), or with ``interleaved`` the adjacent
-    (x[2i], x[2i + 1]); the same angle either way."""
+    (x[2i], x[2i + 1]); the same angle either way.  ``yarn``
+    (``TransformerConfig.rope_yarn``) stretches the frequencies
+    (:func:`yarn_frequencies`)."""
     d = x.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    amplitude = 1.0
+    if yarn is not None:
+        freq, amplitude = yarn_frequencies(d, theta, yarn)
     angles = positions[..., None].astype(jnp.float32) * freq  # [B, S, d/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     if interleaved:
         xf = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
         x1, x2 = xf[..., 0], xf[..., 1]
@@ -278,8 +353,12 @@ def dense_causal_attention(q, k, v, causal: bool = True,
 def init_kv_cache(cfg: TransformerConfig, num_slots: int,
                   max_len: int | None = None):
     """Preallocated per-slot K/V cache for incremental decode
-    (docs/inference.md "Serving loop"): two ``[L, slots, S, H, D]`` arrays
-    in the compute dtype.  One slot is one serving sequence — the
+    (docs/inference.md "Serving loop"): two arrays in the compute dtype
+    whose shape the model gives: keys and values ``[L, slots, S, H, D]``,
+    or for a model of latent attention the latents ``[L, slots, S,
+    kv_lora_rank]`` and the one rotary key of all heads ``[L, slots, S,
+    qk_rope_head_dim]`` (what a cache call writes and reads as they lie;
+    K and V are never stored).  One slot is one serving sequence — the
     continuous-batching scheduler (serving/engine.py) admits a request
     into a free slot (prefill writes positions ``0..len``) and decode
     appends one position per step, so the buffer is allocated once and
@@ -289,8 +368,11 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     (:func:`write_kv_block`); a caller that donates them to its jitted
     program (``donate_argnums``) has them updated where they lie, one that
     does not pays a copy of both a call."""
-    s = max_len or cfg.max_seq_len
-    shape = (cfg.num_layers, num_slots, s, cfg.kv_heads, cfg.head_dim)
+    lead = (cfg.num_layers, num_slots, max_len or cfg.max_seq_len)
+    if cfg.latent:
+        return (jnp.zeros(lead + (cfg.kv_lora_rank,), cfg.dtype),
+                jnp.zeros(lead + (cfg.qk_rope_head_dim,), cfg.dtype))
+    shape = lead + (cfg.kv_heads, cfg.head_dim)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
 
 
@@ -301,15 +383,21 @@ def init_kv_pages(cfg: TransformerConfig, num_pages: int, page_size: int):
     slot is a row of page ids (its page table) and a page holding a
     shared prompt-prefix chunk can appear in many slots' rows at once.
     Page 0 is the scratch page inactive slots point at."""
+    if cfg.latent:
+        raise NotImplementedError(
+            "a paged pool of latents (init_kv_pages, "
+            "PagedTransformerBackend) is not built: latent attention "
+            "serves from init_kv_cache's dense pool")
     shape = (cfg.num_layers, num_pages, page_size, cfg.kv_heads,
              cfg.head_dim)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
 
 
 def write_kv_block(pool, block, layer: int, lengths):
-    """``pool`` [L, B, S, H, D] with ``block`` [B, S_q, H, D] written at
-    ``(layer, b, lengths[b])``: one ``dynamic_update_slice`` a slot, each of
-    the block's own bytes.  Nothing else of the pool is read or written, so
+    """``pool`` [L, B, S, ...] with ``block`` [B, S_q, ...] written at
+    ``(layer, b, lengths[b])`` (``...`` is H, D for keys and values, one
+    width for latents): one ``dynamic_update_slice`` a slot, each of the
+    block's own bytes.  Nothing else of the pool is read or written, so
     where the caller donated the pool XLA updates it where it lies.
 
     The pool keeps the layout it came in.  Left to itself XLA:TPU lays a
@@ -320,7 +408,8 @@ def write_kv_block(pool, block, layer: int, lengths):
     the layout held; PERF.md section 6, PR 38)."""
     for b in range(block.shape[0]):
         pool = jax.lax.dynamic_update_slice(
-            pool, block[b][None, None], (layer, b, lengths[b], 0, 0))
+            pool, block[b][None, None],
+            (layer, b, lengths[b]) + (0,) * (pool.ndim - 3))
     return with_layout_constraint(
         pool, Layout(major_to_minor=tuple(range(pool.ndim))))
 
@@ -443,6 +532,125 @@ class Attention(nn.Module):
         return o_proj(out)
 
 
+class LatentAttention(nn.Module):
+    """Causal latent attention (MLA), two forms of one parameter tree.
+
+    Down: ``c_q = norm(x W_DQ)`` [q_lora_rank]; ``x W_DKV`` is a latent
+    ``c_kv = norm(.)`` [kv_lora_rank] beside ONE rotary key ``k_rope``
+    [qk_rope_head_dim] that all heads share.  A head's query is ``c_q
+    W_UQ`` = (nope | rope, the rope part rotated); ``W_UKV`` = [W_UK | W_UV]
+    by head takes the latent to a head's key (nope) and value.
+
+    *Expanded* (no cache: training, a serving prefill): K = [c_kv W_UK |
+    k_rope for every head] and V = c_kv W_UV are built for the whole
+    sequence and go to the attention function with keys wider than values
+    (``ops/flash_attention``'s forward takes that).  ``return_kv`` hands
+    back (c_kv, k_rope): the cache holds those, never K and V.
+
+    *Absorbed* (a cache call: decode, a verify window): the query is carried
+    into the latent space, ``q_lat = q_nope W_UK^T`` [H, kv_lora_rank], the
+    scores are ``q_lat c_kv^T + q_rope k_rope^T`` over the cached latents as
+    they lie (one "KV head" read once for all heads), the weighted sum is
+    taken over the latents too and leaves through ``W_UV``.  The same
+    numbers: (q_nope W_UK^T) c_kv^T = q_nope (c_kv W_UK)^T.
+
+    The softmax scale is (nope + rope)^-1/2, times YaRN's mscale squared
+    where ``rope_yarn`` is set (``attention_scale`` wins when given)."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions, cache=None, return_kv=False):
+        cfg = self.cfg
+        h, r = cfg.num_heads, cfg.kv_lora_rank
+        nope, rot, d_v = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                          cfg.v_head_dim)
+        if not (cfg.q_lora_rank and r and nope and rot and d_v):
+            raise ValueError(
+                "a latent_attention layer needs TransformerConfig's "
+                "q_lora_rank, kv_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim")
+        dense = functools.partial(nn.DenseGeneral, use_bias=False,
+                                  dtype=cfg.dtype,
+                                  param_dtype=cfg.param_dtype)
+        norm = functools.partial(RMSNorm, dtype=cfg.dtype,
+                                 param_dtype=cfg.param_dtype,
+                                 epsilon=cfg.norm_eps)
+        turn = functools.partial(rope, theta=cfg.rope_theta,
+                                 interleaved=cfg.rope_interleaved,
+                                 yarn=cfg.rope_yarn)
+        scale = cfg.attention_scale
+        if scale is None:
+            scale = (nope + rot) ** -0.5
+            if cfg.rope_yarn is not None:
+                scale *= yarn_mscale(cfg.rope_yarn[0], cfg.rope_yarn[5]) ** 2
+        w_ukv = self.param(
+            "kv_up", nn.initializers.lecun_normal(in_axis=0,
+                                                  out_axis=(1, 2)),
+            (r, h, nope + d_v), cfg.param_dtype).astype(cfg.dtype)
+        o_proj = dense(cfg.embed_dim, axis=(-2, -1), name="o")
+
+        with jax.named_scope(profiling.MLA_DOWN):
+            c_q = norm(name="q_norm")(dense(cfg.q_lora_rank,
+                                            name="q_down")(x))
+            down = dense(r + rot, name="kv_down")(x)
+            c_kv = norm(name="kv_norm")(down[..., :r])
+            k_rope = turn(down[..., None, r:], positions)[..., 0, :]
+        with jax.named_scope(profiling.MLA_UP):
+            q = dense((h, nope + rot), name="q_up")(c_q)
+            q_nope, q_rope = q[..., :nope], turn(q[..., nope:], positions)
+
+        if cache is not None:
+            latents, rope_keys, lengths, layer = cache
+            with jax.named_scope(profiling.MLA_DOWN):
+                latents = write_kv_block(latents, c_kv, layer, lengths)
+                rope_keys = write_kv_block(rope_keys, k_rope, layer, lengths)
+            with jax.named_scope(profiling.MLA_ABSORB):
+                q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope,
+                                   w_ukv[..., :nope])
+            with jax.named_scope(profiling.MLA_ATTN):
+                o_lat = absorbed_decode_attention(
+                    q_lat, q_rope, latents[layer], rope_keys[layer],
+                    lengths, scale)
+            with jax.named_scope(profiling.MLA_ABSORB):
+                out = jnp.einsum("bqhr,rhd->bqhd", o_lat, w_ukv[..., nope:])
+            return o_proj(out), (latents, rope_keys)
+
+        if cfg.context_axis and cfg.context_plan is not None:
+            raise NotImplementedError(
+                "ring / zigzag attention over a context axis takes values "
+                "as wide as the keys; latent attention's are not")
+        with jax.named_scope(profiling.MLA_UP):
+            kv = jnp.einsum("bsr,rhd->bshd", c_kv, w_ukv)
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                k_rope[:, :, None, :], kv.shape[:3] + (rot,))], axis=-1)
+            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        attn = cfg.attention_fn or dense_causal_attention
+        out = o_proj(attn(q, k, kv[..., nope:], causal=True, scale=scale))
+        return (out, (c_kv, k_rope)) if return_kv else out
+
+
+def absorbed_decode_attention(q_lat, q_rope, latents, rope_keys, lengths,
+                              scale: float):
+    """Block attention over a per-slot cache of latents, the query already
+    in the latent space: ``q_lat`` [B, S_q, H, R], ``q_rope`` [B, S_q, H,
+    Dr], ``latents`` [B, S, R], ``rope_keys`` [B, S, Dr]; query row ``i`` of
+    slot ``b`` sits at position ``lengths[b] + i``.  Returns the weighted
+    sum of the latents [B, S_q, H, R].  :func:`cached_decode_attention`'s
+    arithmetic: float32 scores and softmax, -1e30 behind the mask."""
+    s, s_q = latents.shape[1], q_lat.shape[1]
+    qpos = lengths[:, None] + jnp.arange(s_q)[None, :]         # [B, S_q]
+    mask = (jnp.arange(s)[None, None, :]
+            <= qpos[:, :, None])[:, None, :, :]                # [B,1,S_q,S]
+    logits = (jnp.einsum("bqhr,bkr->bhqk", q_lat, latents,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhd,bkd->bhqk", q_rope, rope_keys,
+                           preferred_element_type=jnp.float32)) * scale
+    logits = jnp.where(mask, logits, -1e30)
+    probs = nn.softmax(logits, axis=-1).astype(q_lat.dtype)
+    return jnp.einsum("bhqk,bkr->bqhr", probs, latents)
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
@@ -468,8 +676,11 @@ MIXERS = {
     "sliding_attention": _ATTENTION + ({"layer_type": "sliding_attention"},),
     "full_attention": _ATTENTION + ({"layer_type": "full_attention"},),
     "mamba": ("horovod_tpu.models.mamba", "Mamba2Mixer", "mamba", {}),
+    "latent_attention": ("horovod_tpu.models.transformer",
+                         "LatentAttention", "attn", {}),
 }
-CACHED_MIXERS = ("attention", "sliding_attention", "full_attention")
+CACHED_MIXERS = ("attention", "sliding_attention", "full_attention",
+                 "latent_attention")
 
 
 def _scaled(x, multiplier: float):
@@ -480,41 +691,61 @@ def _scaled(x, multiplier: float):
     return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
 
 
-def _feed_forward(cfg: TransformerConfig, y, valid=None):
-    """The layer's feed-forward on its normed input: the dense GLU MLP or
-    one of the two sparse layouts.  A function inside :class:`Block`'s
-    compact call, so that flax adds no method's name to the module path."""
-    if cfg.num_experts > 0:
+def _feed_forward(cfg: TransformerConfig, dense: bool = False):
+    """The layer's feed-forward as ``ff(y, valid)`` on its normed input: the
+    dense GLU MLP (always, in a layer that is ``dense``: one of a sparse
+    model's ``first_dense_layers``) or one of the two sparse layouts.  Made
+    inside :class:`Block`'s compact call, once a layer, by a function, so
+    that flax adds no method's name to the module path; ``ff`` may then be
+    called a chunk of the sequence at a time."""
+    if cfg.num_experts > 0 and not dense:
         from horovod_tpu.models.moe import MoEMLP
 
         if cfg.moe_axis is not None:
             raise ValueError("num_experts (every expert on each device) "
                              "and moe_axis (one expert a device) are two "
                              "layouts; set one")
-        return MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
-                      axis_name=None, dtype=cfg.dtype,
-                      num_experts=cfg.num_experts,
-                      experts_per_token=cfg.experts_per_token,
-                      norm_topk_prob=cfg.norm_topk_prob,
-                      selection=cfg.moe_selection,
-                      num_shared_experts=cfg.num_shared_experts,
-                      experts_held=cfg.experts_held,
-                      param_dtype=cfg.param_dtype, name="moe_mlp")(
-                          y, valid=valid)
+        moe = MoEMLP(embed_dim=cfg.embed_dim,
+                     mlp_dim=cfg.moe_mlp_dim or cfg.mlp_dim,
+                     axis_name=None, dtype=cfg.dtype,
+                     num_experts=cfg.num_experts,
+                     experts_per_token=cfg.experts_per_token,
+                     norm_topk_prob=cfg.norm_topk_prob,
+                     selection=cfg.moe_selection,
+                     num_shared_experts=cfg.num_shared_experts,
+                     experts_held=cfg.experts_held,
+                     routed_scale=cfg.moe_routed_scale,
+                     param_dtype=cfg.param_dtype, name="moe_mlp")
+        return lambda y, valid: moe(y, valid=valid)
     if cfg.moe_axis is not None:
         from horovod_tpu.models.moe import MoEMLP
 
         # Residual carries over-capacity (dropped) tokens unchanged.
-        return MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
-                      axis_name=cfg.moe_axis,
-                      capacity_factor=cfg.moe_capacity_factor,
-                      dtype=cfg.dtype, name="moe_mlp")(y)
-    return MLP(cfg, name="mlp")(y)
+        moe = MoEMLP(embed_dim=cfg.embed_dim, mlp_dim=cfg.mlp_dim,
+                     axis_name=cfg.moe_axis,
+                     capacity_factor=cfg.moe_capacity_factor,
+                     dtype=cfg.dtype, name="moe_mlp")
+        return lambda y, valid: moe(y)
+    mlp = MLP(cfg, name="mlp")
+    return lambda y, valid: mlp(y)
+
+
+def _in_chunks(fn, chunk: int | None, x, valid):
+    """``fn(x, valid)`` over [B, S, ...], ``chunk`` positions at a time where
+    the sequence is longer (``fn`` is position-wise: the same numbers)."""
+    s = x.shape[1]
+    if not chunk or s <= chunk:
+        return fn(x, valid)
+    return jnp.concatenate(
+        [fn(x[:, a:a + chunk], None if valid is None
+            else valid[:, a:a + chunk]) for a in range(0, s, chunk)], axis=1)
 
 
 class Block(nn.Module):
     cfg: TransformerConfig
     layer_type: str = "attention"
+    # the dense GLU MLP in a sparse model (one of its first_dense_layers)
+    dense_ff: bool = False
 
     @nn.compact
     def __call__(self, x, positions, cache=None, return_kv=False,
@@ -535,16 +766,19 @@ class Block(nn.Module):
             mixed, kv = mixer(y, positions, cache=cache, return_kv=return_kv)
         else:
             mixed = mixer(y, positions)
+        ff = _feed_forward(cfg, self.dense_ff)
         if cfg.parallel_block:
             # one norm a layer: the feed-forward reads what the mixer read
             # and both are added to the residual
-            out = x + _scaled(mixed + _feed_forward(cfg, y, valid),
-                              cfg.residual_multiplier)
+            out = x + _scaled(
+                mixed + _in_chunks(ff, cfg.feed_forward_chunk, y, valid),
+                cfg.residual_multiplier)
         else:
             x = x + _scaled(mixed, cfg.residual_multiplier)
-            out = x + _scaled(
-                _feed_forward(cfg, norm("mlp_norm")(x), valid),
-                cfg.residual_multiplier)
+            mlp_norm = norm("mlp_norm")
+            out = x + _scaled(_in_chunks(
+                lambda x, valid: ff(mlp_norm(x), valid),
+                cfg.feed_forward_chunk, x, valid), cfg.residual_multiplier)
         if cache is not None or return_kv:
             return out, kv
         return out
@@ -583,14 +817,26 @@ class Transformer(nn.Module):
     * ``valid`` ([B, S] bool; a sparse model, ``num_experts`` > 0): which
       positions hold a token.  A prefill bucket's padding and a slot with
       no request are routed to no expert (models/moe.py).
+    * ``logits_at`` ([B] positions; not with a cache): the final norm and
+      the head run on that one position of each row and the logits are
+      ``[B, vocab]``: a prefill needs its prompt's last position alone.
+
+    A model whose layers are "latent_attention" keeps latents in the cache
+    (``return_kv`` and ``kv_cache`` are then :func:`init_kv_cache`'s two
+    latent arrays, ``[L, B, S, kv_lora_rank]`` and ``[L, B, S,
+    qk_rope_head_dim]``): :class:`LatentAttention`.
     """
 
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, tokens, position_offset=0, positions=None,
-                 kv_cache=None, lengths=None, return_kv=False, valid=None):
+                 kv_cache=None, lengths=None, return_kv=False, valid=None,
+                 logits_at=None):
         cfg = self.cfg
+        if logits_at is not None and kv_cache is not None:
+            raise ValueError("logits_at picks a position of a pass without "
+                             "a cache; a cache call's block is its own")
         if valid is not None and cfg.num_experts == 0:
             raise ValueError("valid says which positions a sparse "
                              "feed-forward routes: num_experts is 0")
@@ -631,7 +877,8 @@ class Transformer(nn.Module):
         block_cls = nn.remat(Block) if remat_on else Block
         kvs = []
         for i, kind in enumerate(kinds):
-            block = block_cls(cfg, kind, name=f"layer_{i}")
+            block = block_cls(cfg, kind, i < cfg.first_dense_layers,
+                              name=f"layer_{i}")
             if decode:
                 # the whole pool goes through every layer: layer i writes
                 # its block into it and reads its own view of it
@@ -642,6 +889,9 @@ class Transformer(nn.Module):
                 kvs.append(kv)
             else:
                 x = block(x, positions, **told)
+        if logits_at is not None:
+            x = jnp.take_along_axis(
+                x, jnp.asarray(logits_at)[:, None, None], axis=1)[:, 0]
         x = make_norm(cfg, "final_norm")(x)
         # Head matmul in the compute dtype (bf16 hits the MXU at full rate;
         # f32 params, XLA accumulates in f32); logits upcast for the loss —

@@ -326,20 +326,23 @@ _vmem_clamp_warned: set = set()
 
 
 def _vmem_estimate_bytes(block_q: int, block_k: int, d: int,
-                         sub: int = 1024, itemsize: int = 2) -> int:
+                         sub: int = 1024, itemsize: int = 2,
+                         d_v: int | None = None) -> int:
     """Resident-set model that sizes the tiles (the entry points' clamp
     and ``ContextPlan``'s), priced for a pass of the forward's layout
     that also streams dO and Δ — more than the forward holds, on purpose:
     double-buffered K/V streaming super tiles, q/dO tiles, the f32
     accumulator, the sublane-replicated lse/Δ rows, and two live
     [block_q, sub] f32 compute tiles (Mosaic fuses the elementwise chain,
-    so s/p share ~two buffers in practice).  The backward's resident set,
-    whose dq accumulator grows with S_q, is
+    so s/p share ~two buffers in practice).  ``d`` is the width of q and
+    k, ``d_v`` that of v, dO and the accumulator (None: ``d``).  The
+    backward's resident set, whose dq accumulator grows with S_q, is
     :func:`_bwd_vmem_estimate_bytes`."""
+    d_v = d if d_v is None else d_v
     sub_k = min(sub, max(block_k, 1))
-    kv = 2 * 2 * block_k * d * itemsize          # K+V, double-buffered
-    qdo = 2 * 2 * block_q * d * itemsize         # q + dO tiles
-    acc = block_q * d * 4                        # f32 dq/o accumulator
+    kv = 2 * block_k * (d + d_v) * itemsize      # K+V, double-buffered
+    qdo = 2 * block_q * (d + d_v) * itemsize     # q + dO tiles
+    acc = block_q * d_v * 4                      # f32 dq/o accumulator
     stats = 2 * 8 * block_q * 4                  # lse + Δ, sublane-replicated
     tiles = 2 * block_q * sub_k * 4              # live f32 compute tiles
     return kv + qdo + acc + stats + tiles
@@ -411,14 +414,15 @@ def _bwd_q_rows_per_call(block_q: int, block_k: int, d: int, s_q: int,
 
 def clamp_blocks_to_vmem(block_q: int, block_k: int, d: int,
                          sub: int = 1024, itemsize: int = 2,
-                         where: str = "flash_attention") -> tuple[int, int]:
+                         where: str = "flash_attention",
+                         d_v: int | None = None) -> tuple[int, int]:
     """Halve (block_k first — the K/V tiles dominate — then block_q, never
     below 128) until :func:`_vmem_estimate_bytes` fits the VMEM budget.
     One-line rank-0 warning per distinct clamp; ``ContextPlan`` routes
     through the same estimate so planned configs never trip it."""
     bq, bk = block_q, block_k
     budget = int(VMEM_FIT_BUDGET_MB * 2 ** 20)
-    while _vmem_estimate_bytes(bq, bk, d, sub, itemsize) > budget:
+    while _vmem_estimate_bytes(bq, bk, d, sub, itemsize, d_v) > budget:
         if bk > _VMEM_MIN_BLOCK and bk >= bq:
             bk //= 2
         elif bq > _VMEM_MIN_BLOCK:
@@ -456,6 +460,20 @@ def repeat_kv_heads(x, num_heads: int):
     return jnp.repeat(x, num_heads // kv, axis=2)
 
 
+def _need_equal_widths(k, v, who: str) -> None:
+    """Only the forward kernel takes values of another width than the keys
+    (a serving prefill of latent attention); everything that differentiates
+    or merges partial results says so by name."""
+    if v.shape[-1] != k.shape[-1]:
+        raise NotImplementedError(
+            f"{who} takes values as wide as the keys: value width "
+            f"{v.shape[-1]} against key width {k.shape[-1]} is the forward "
+            f"kernel's alone (flash_attention, a serving prefill); the "
+            f"backward kernel and ring / zigzag attention size dk, dv and "
+            f"the merged output by one width.  Differentiate "
+            f"dense_causal_attention instead")
+
+
 def _to_bh(x):
     """[B, S, H, D] → [B·H, S, D], the kernels' layout."""
     b, s, h, d = x.shape
@@ -478,10 +496,12 @@ def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
                 interpret, sub, out_dtype, scale=None, window=None):
     """The forward kernel on [B, S, H, D] inputs, everything it read and
     wrote left in the kernels' layout, padded to whole blocks:
-    ``(qb, kb, vb, ob, lse_b)`` with ``ob`` [B·H, S_q_pad, D] in
+    ``(qb, kb, vb, ob, lse_b)`` with ``ob`` [B·H, S_q_pad, D_v] in
     ``out_dtype`` and ``lse_b`` [B·H, 8, S_q_pad] float32 (sublane-
-    replicated).  The backward reads all five as they are."""
-    d = q.shape[-1]
+    replicated).  The backward reads all five as they are.  ``v`` may be
+    narrower or wider than ``q`` and ``k`` (latent attention: keys of 192,
+    values of 128): its width is the output's and the accumulator's."""
+    d, d_v = q.shape[-1], v.shape[-1]
     s_k = k.shape[1]
     block_k, sub_k = _sub_fit(block_k, sub)
     qb = _pad_to(_to_bh(q), 1, block_q)
@@ -504,20 +524,20 @@ def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
                          lambda bh, qi, ki: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d),
                          lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d),
+            pl.BlockSpec((1, block_k, d_v),
                          lambda bh, qi, ki: (bh, ki, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, d),
+            pl.BlockSpec((1, block_q, d_v),
                          lambda bh, qi, ki: (bh, qi, 0)),
             stat_block,
         ),
         out_shape=(
-            jax.ShapeDtypeStruct(qb.shape, out_dtype),
+            jax.ShapeDtypeStruct(qb.shape[:2] + (d_v,), out_dtype),
             jax.ShapeDtypeStruct((qb.shape[0], 8, qb.shape[1]), jnp.float32),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),      # acc
+            pltpu.VMEM((block_q, d_v), jnp.float32),    # acc
             pltpu.VMEM((1, 8, block_q), jnp.float32),   # m carry
             pltpu.VMEM((1, 8, block_q), jnp.float32),   # l carry
         ],
@@ -813,6 +833,7 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
     after its last sum.  ``flash_attention`` itself differentiates through
     :func:`_backward_bh` and takes the compute dtype from the kernel.
     """
+    _need_equal_widths(k, v, "flash_attention_backward")
     b, s_q = q.shape[:2]
     s_k = k.shape[1]
 
@@ -867,6 +888,7 @@ def _flash_bwd(causal, block_q, block_k, sub, interpret, scale, window, res,
             f"prefill); the backward kernel knows the causal mask only. "
             f"Differentiate dense_causal_attention(window=...) instead")
     qb, kb, vb, ob, lse_b, q_offset, k_offset, lengths = res
+    _need_equal_widths(kb, vb, "flash_attention's backward")
     b = g.shape[0]
     # Only the incoming cotangent is laid out here.
     dob = _pad_to(_to_bh(g.astype(qb.dtype)), 1, ob.shape[1])
@@ -915,6 +937,11 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     the two edges.  The backward kernel has no window: differentiating a
     windowed call raises ``NotImplementedError`` (docs/inference.md).
 
+    ``v`` may have another width than ``q`` and ``k`` (latent attention's
+    expanded form: keys of 192, values of 128); the output has ``v``'s.  The
+    forward kernel alone takes that: differentiating such a call, and ring /
+    zigzag attention, raise ``NotImplementedError`` by name.
+
     ``scale`` is the softmax scale, ``d ** -0.5`` when None.  It reaches the
     kernels as the constant they fold into q (an argument, not a pre-scale
     of q outside: exact for any value, and no op of its own).  ``k`` and
@@ -962,7 +989,8 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     block_q = min(block_q, max(q.shape[1], 1))
     block_k = min(block_k, max(k.shape[1], 1))
     block_q, block_k = clamp_blocks_to_vmem(
-        block_q, block_k, q.shape[-1], sub, q.dtype.itemsize)
+        block_q, block_k, q.shape[-1], sub, q.dtype.itemsize,
+        d_v=v.shape[-1])
     if window is not None and not causal:
         raise ValueError("a sliding window is a causal band: causal=True")
     k, v = repeat_kv_heads(k, q.shape[2]), repeat_kv_heads(v, q.shape[2])
@@ -987,6 +1015,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
     ``flash_attention_backward`` per ring step with the globally-merged
     lse under its own vjp).
     """
+    _need_equal_widths(k, v, "flash_attention_with_lse")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if block_k is None:

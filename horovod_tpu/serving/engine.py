@@ -52,6 +52,7 @@ records", :meth:`ServingEngine.span_summary`).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import time
 from collections import deque
@@ -296,6 +297,13 @@ class TransformerBackend:
     feed-forward (``num_experts`` > 0) also hands back, each call, the pairs
     each held expert of each layer was given, ``last_expert_pairs``
     ([L, held]; the running sums are ``moe_counters``).
+
+    The pool (``kk``, ``vv``) is whatever ``init_kv_cache`` gives for the
+    model: for one of latent attention the latents and their rotary keys,
+    which prefill builds K and V from (expanded) and decode reads as they
+    lie (absorbed).  A model that sets ``feed_forward_chunk`` prefills a
+    longer bucket with its feed-forward a chunk at a time
+    (:meth:`prefill_chunks`) and its head on the last position alone.
     """
 
     # A bucket whose dense attention logits, [1, H, S, S] float32, are larger
@@ -327,8 +335,12 @@ class TransformerBackend:
         # nowhere), and of them the pairs on the experts held here, over
         # every prefill and decode call
         self.moe_counters = {"calls": 0, "pairs": 0, "held_pairs": 0}
-        self._pairs_per_token = (model_cfg.num_layers
+        self._sparse_layers = range(model_cfg.first_dense_layers,
+                                    model_cfg.num_layers)
+        self._pairs_per_token = (len(self._sparse_layers)
                                  * model_cfg.experts_per_token)
+        # the pool is the model's to shape: K and V [L, slots, S, KV, D], or
+        # latents and their rotary keys [L, slots, S, rank] / [.., rope]
         self.kk, self.vv = init_kv_cache(model_cfg, num_slots, max_seq_len)
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
         self._decode = jax.jit(self._decode_fn, donate_argnums=(1, 2))
@@ -344,6 +356,12 @@ class TransformerBackend:
         logits_bytes = 4 * self._model_cfg.num_heads * int(bucket) ** 2
         return ("flash" if logits_bytes > self.FLASH_PREFILL_LOGITS_BYTES
                 else "dense")
+
+    def prefill_chunks(self, bucket: int) -> int:
+        """In how many pieces a prefill of ``bucket`` positions runs its
+        feed-forward layers (``TransformerConfig.feed_forward_chunk``)."""
+        chunk = self._model_cfg.feed_forward_chunk
+        return -(-int(bucket) // chunk) if chunk else 1
 
     @property
     def flash_prefill(self) -> bool:
@@ -372,18 +390,20 @@ class TransformerBackend:
 
     def _apply(self, model, params, tokens, **kwargs):
         """``model.apply``; for a sparse model also the pairs each held
-        expert was given, as its expert layers sowed them, stacked over the
+        expert was given, as its expert layers sowed them (once a chunk
+        where the feed-forward ran in chunks), stacked over the sparse
         layers [L, held]; else None."""
         if not self.sparse:
             return model.apply(params, tokens, **kwargs), None
         from horovod_tpu.models.moe import MOE_STATS
 
+        jnp = self._jax.numpy
         out, sown = model.apply(params, tokens, mutable=[MOE_STATS],
                                 **kwargs)
         layers = [sown[MOE_STATS][f"layer_{i}"]["moe_mlp"]
-                  for i in range(len(sown[MOE_STATS]))]
-        return out, self._jax.numpy.stack(
-            [lay["expert_pairs"][0] for lay in layers])
+                  for i in self._sparse_layers]
+        return out, jnp.stack([functools.reduce(jnp.add, lay["expert_pairs"])
+                               for lay in layers])
 
     def _prefill_fn(self, params, kk, vv, padded, length, slot):
         jax, jnp = self._jax, self._jax.numpy
@@ -391,12 +411,19 @@ class TransformerBackend:
         # padding (and below, the slots that hold a request, not the rest)
         told = {"valid": jnp.arange(padded.shape[1])[None, :] < length} \
             if self.sparse else {}
+        # a model that takes its long prompts' feed-forward in chunks has
+        # no room for every position's logits either: the head runs on the
+        # prompt's last position alone
+        chunked = self._model_cfg.feed_forward_chunk is not None
+        if chunked:
+            told["logits_at"] = jnp.reshape(length - 1, (1,))
         (logits, (pk, pv)), pairs = self._apply(
             self._prefill_model(padded.shape[1]), params, padded,
             return_kv=True, **told)
-        kk = jax.lax.dynamic_update_slice(kk, pk, (0, slot, 0, 0, 0))
-        vv = jax.lax.dynamic_update_slice(vv, pv, (0, slot, 0, 0, 0))
-        last = jax.lax.dynamic_slice(
+        at_slot = lambda pool: (0, slot) + (0,) * (pool.ndim - 2)  # noqa: E731
+        kk = jax.lax.dynamic_update_slice(kk, pk, at_slot(kk))
+        vv = jax.lax.dynamic_update_slice(vv, pv, at_slot(vv))
+        last = logits[0] if chunked else jax.lax.dynamic_slice(
             logits, (0, length - 1, 0), (1, 1, logits.shape[-1]))[0, 0]
         out = kk, vv, jnp.argmax(last).astype(jnp.int32), last
         return out if pairs is None else out + (pairs,)
@@ -817,6 +844,11 @@ class ServingEngine:
             # says which form this call runs
             chosen = getattr(self.backend, "prefill_attention", None)
             attn = {"attn": chosen(bucket)} if chosen else {}
+            # ... and in how many chunks, where that is more than one
+            pieces = getattr(self.backend, "prefill_chunks", None)
+            chunks = pieces(bucket) if pieces else 1
+            if chunks > 1:
+                attn["chunks"] = chunks
             with profiling.span(
                     profiling.SRV_PREFILL, cause=req._span.id, rid=req.rid,
                     bucket=bucket, length=len(suffix),

@@ -66,6 +66,14 @@ MOE_DISPATCH = "hvd_moe_dispatch"   # ... tokens gathered into expert order
 MOE_EXPERTS = "hvd_moe_experts"     # ... the grouped matmuls, the activation
 MOE_COMBINE = "hvd_moe_combine"     # ... back to token order, gate-weighted sum
 MOE_SHARED = "hvd_moe_shared"       # ... the experts every token visits
+MLA_DOWN = "hvd_mla_down"       # models/transformer.LatentAttention: W_DQ,
+                                # W_DKV, the two norms, the rotary key, the
+                                # cache's write
+MLA_UP = "hvd_mla_up"           # ... W_UQ and its rotation; expanded: W_UKV
+MLA_ABSORB = "hvd_mla_absorb"   # ... absorbed: q W_UK^T and o_lat W_UV
+MLA_ATTN = "hvd_mla_attn"       # ... absorbed: the products and the softmax
+                                # over the pool (expanded: the attention
+                                # function's own names, FLASH_FWD)
 SSM_PROJ = "hvd_ssm_proj"       # models/mamba: the in and out projections
 SSM_CONV = "hvd_ssm_conv"       # ... causal depthwise conv, bias, silu
 SSM_SCAN = "hvd_ssm_scan"       # ... ops/ssd_scan: softplus, the decays' sums,
@@ -93,6 +101,7 @@ SRV_FETCH = "hvd_srv_fetch"         # ... logits and pair counts to the host
 
 FLASH_PASSES = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD)
 MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
+MLA_SCOPES = (MLA_DOWN, MLA_UP, MLA_ABSORB, MLA_ATTN)
 SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE)
 SSD_PASSES = (SSD_FWD, SSD_BWD)
 SRV_CALLS = (SRV_PREFILL, SRV_DECODE, SRV_VERIFY)   # the backend's calls

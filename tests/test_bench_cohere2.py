@@ -63,7 +63,7 @@ def test_the_manifest_holds_the_cell_and_its_metrics():
     cell = next(c for c in m["workloads"] if c["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "command-a-plus-05-2026", "code8k-open", 1)
-    assert len(m["workloads"]) == 8
+    assert len(m["workloads"]) == 9
     assert sum(c["chips"] == 4 for c in m["workloads"]) == 1
     entry = next(c for c in m["configs"] if c["name"] == cell["config"])
     assert entry["reduced"] == ["num_hidden_layers", "layer_types",
@@ -138,7 +138,9 @@ def test_the_manifest_holds_the_cell_and_its_metrics():
     assert not {"tokens_per_s", "moe_ms", "flash_ms"} & reported
     for e in m["per_layer"]:
         if e["name"] in new:
-            assert e["workloads"] == [CELL] and e["moves"] == "ttft_ms_mean"
+            # (a later sparse cell reads the readers that ask nothing of a
+            # configuration's keys too: appended behind this one)
+            assert e["workloads"][0] == CELL and e["moves"] == "ttft_ms_mean"
 
 
 def test_the_schedule_is_typical_of_its_long_run():

@@ -34,7 +34,8 @@ NEW = {"decode_h2d_ms.srv": "serving backend", "decode_dispatch_ms.srv":
        "scheduler", "engine_queue_ms_p95.srv": "scheduler",
        "longest_wait_ms.srv": "serving backend", "longest_host_ms.srv":
        "serving backend", "idle_named_share.srv": "device"}
-SERVING_CELLS = ["dsc1p3b-code-0.8knee", "cmdaplus-code8k-open"]
+SERVING_CELLS = ["dsc1p3b-code-0.8knee", "cmdaplus-code8k-open",
+                 "axk1-longdoc16k-open"]
 
 
 def since(mark: int) -> list:
@@ -177,6 +178,11 @@ def test_every_list_the_engine_holds_is_bounded():
     # the serving loop
     import gc
 
+    # twice: a pass untracks a tuple once it has found its items untracked,
+    # so a record that holds its fields as an inner tuple may need the second
+    # (which of the two a pass meets first depends on what else the worker's
+    # process holds: the one pass failed in two whole runs of three, PR 42)
+    gc.collect()
     gc.collect()
     assert not any(gc.is_tracked(kept) for kept in profiling._ring)
     stats = eng.stats()
