@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from flax.traverse_util import flatten_dict
 from jax import lax
 
+from horovod_tpu.ops.token_sum import add_rows_by_token
 from horovod_tpu.parallel.common import shard_init_rng
 from horovod_tpu.parallel.expert import expert_parallel_moe
 from horovod_tpu.utils import profiling
@@ -51,7 +52,9 @@ from horovod_tpu.utils import profiling
 MOE_LOSSES = "moe_losses"   # "load_balance", "router_z": f32 scalars
 MOE_STATS = "moe_stats"     # "expert_pairs": [E] int32, pairs per expert
                             # (profiling.expert_load reads it); "picks":
-                            # [B, S, k] int32, each token's experts
+                            # [B, S, k] int32, each token's experts;
+                            # "rows_visited": int32, the rows the layer
+                            # gathered, multiplied and combined for its pairs
 
 
 def moe_aux_loss(cfg, collections) -> jax.Array:
@@ -104,6 +107,86 @@ def _permute(x, perm, inverse):
 
 _permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
                 lambda inverse, g: (g[inverse], None, None))
+
+
+# rows a tile of XLA:TPU's grouped matmul (lax.ragged_dot) holds
+GROUPED_ROW_TILE = 512
+
+
+def held_block_rows(pairs: int, held: int, experts: int) -> int:
+    """Rows in a block of the walk (``_walk_held``), from the static shapes alone:
+    twice the ``pairs * held / experts`` that ``held`` of ``experts`` get
+    when the routing is even, up to a whole :data:`GROUPED_ROW_TILE`.  A
+    layer whose block would hold all its ``pairs`` (``>= pairs``) does not
+    walk."""
+    twice_even = -(-2 * pairs * held // experts)
+    return -(-twice_even // GROUPED_ROW_TILE) * GROUPED_ROW_TILE
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _walk_blocks(static, tokens, order, pairs, gates, w_gate, w_up, w_down):
+    """The routed sum [T, D] float32 of a layer that holds a share of the
+    experts, over its own pairs alone.  ``order`` [T*k] has the held pairs
+    first, by expert and inside an expert by token; ``pairs`` [held] is each
+    expert's count.  Block ``i`` is sorted positions ``[i*C, (i+1)*C)``:
+    its tokens gathered, its part of each expert's group through the three
+    grouped matmuls, its rows weighted by their pairs' ``gates`` [T, k] in
+    float32 and added to their tokens.  As many blocks run as the held
+    pairs fill, ``ceil(pairs.sum() / C)``: one while the routing is near
+    even, ``T*k / C`` if every pair were held; none is dropped at any load
+    and no array has ``T*k`` rows."""
+    k, c = static
+    t, d = tokens.shape
+    n_held = pairs.sum()
+    ends = jnp.cumsum(pairs)
+    starts = ends - pairs
+    # to whole blocks: a position past the held pairs counts for nothing
+    order = jnp.pad(order, (0, -(t * k) % c))
+    flat_gates = gates.reshape(t * k)
+
+    def block(carry):
+        i, out = carry
+        at = i * c
+        with jax.named_scope(profiling.MOE_DISPATCH):
+            pair = lax.dynamic_slice(order, (at,), (c,))
+            token = pair // k
+            rows = tokens[token]                               # [C, D]
+        with jax.named_scope(profiling.MOE_EXPERTS):
+            sizes = (jnp.clip(ends - at, 0, c)
+                     - jnp.clip(starts - at, 0, c))
+            grouped = functools.partial(lax.ragged_dot, group_sizes=sizes)
+            hidden = nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
+            out_rows = grouped(hidden, w_down)                 # [C, D]
+        with jax.named_scope(profiling.MOE_COMBINE):
+            # rows past the last group are in no product: whatever the
+            # grouped matmul left there is taken out, not weighted
+            live = at + jnp.arange(c) < n_held
+            out = add_rows_by_token(out, out_rows, flat_gates[pair], token,
+                                    live)
+        return i + 1, out
+
+    _, out = lax.while_loop(lambda carry: carry[0] * c < n_held, block,
+                            (jnp.int32(0), jnp.zeros((t, d), jnp.float32)))
+    return out
+
+
+def _walk_blocks_fwd(static, *operands):
+    return _walk_blocks(static, *operands), None
+
+
+def _walk_blocks_bwd(static, _, g):
+    raise NotImplementedError(
+        f"MoEMLP(experts_held=...) walks its held pairs in blocks of "
+        f"{static[1]} rows (models/moe.py, _walk_held) and has no backward: "
+        f"a share of the experts is a serving layout.  Differentiate the "
+        f"layer with every expert held (experts_held=None)")
+
+
+_walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
+# jitted, so that a program's layers (and a long bucket's chunks) share one
+# tracing and one lowering of the walk: twenty a program otherwise, host
+# seconds of every process's set-up (PERF.md section 6, PR 43)
+_walk_held = jax.jit(_walk_blocks, static_argnums=0)
 
 
 # how the router's logits [T, E] become an expert's score for a token
@@ -259,11 +342,21 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
             local = jnp.where(on_chip, picks - lo, held)
         pair_expert = local.reshape(t * k)      # pair p: token p // k
         order = jnp.argsort(pair_expert, stable=True)
-        inverse = jnp.argsort(order)
+        # A share of the experts visits its own pairs only, block_rows of
+        # the sorted order at a time (_walk_held); where one block would
+        # hold every pair (all experts here, a decode step, a short
+        # bucket) the three scopes below carry the T*k rows as they are.
+        block_rows = held_block_rows(t * k, held, e)
+        walks = not everything and block_rows < t * k
+        if not walks:
+            inverse = jnp.argsort(order)
         pairs = (local[..., None] == jnp.arange(held)).sum(
             axis=(0, 1), dtype=jnp.int32)                     # [held]
+        rows_visited = block_rows * (-(-pairs.sum() // block_rows)) \
+            if walks else jnp.int32(t * k)
         if not m.is_initializing():  # init returns parameters only
             m.sow(MOE_STATS, "expert_pairs", pairs)
+            m.sow(MOE_STATS, "rows_visited", rows_visited)
             m.sow(MOE_STATS, "picks", picks.reshape(b, s, k))
             if m.selection == "softmax":
                 # E * sum_e f_e P_e: f_e the share of the pairs on expert e
@@ -280,24 +373,31 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
                     f"{m.selection!r} the layer sows none: do not make "
                     f"{MOE_LOSSES!r} mutable")
 
-    with jax.named_scope(profiling.MOE_DISPATCH):
-        rows = _dispatch(tokens, order, inverse, k)           # [T*k, D]
-
-    with jax.named_scope(profiling.MOE_EXPERTS):
-        grouped = functools.partial(lax.ragged_dot, group_sizes=pairs)
-        hidden = (nn.silu(grouped(rows, w_gate.astype(m.dtype)))
-                  * grouped(rows, w_up.astype(m.dtype)))
-        out_rows = grouped(hidden, w_down.astype(m.dtype))  # [T*k, D]
-
-    with jax.named_scope(profiling.MOE_COMBINE):
-        by_token = _permute(out_rows, inverse, order).reshape(t, k, d)
-        if not everything:
-            # rows past the last group are in no product: whatever the
-            # grouped matmul left there is taken out, not weighted
-            by_token = jnp.where(on_chip[..., None], by_token, 0)
-        out = (by_token.astype(jnp.float32) * gates[..., None]).sum(1)
+    if walks:
+        out = _walk_held((k, block_rows), tokens, order, pairs, gates,
+                         w_gate.astype(m.dtype), w_up.astype(m.dtype),
+                         w_down.astype(m.dtype))
         if not n_shared:
             return out.reshape(b, s, d).astype(x.dtype)
+    else:
+        with jax.named_scope(profiling.MOE_DISPATCH):
+            rows = _dispatch(tokens, order, inverse, k)       # [T*k, D]
+
+        with jax.named_scope(profiling.MOE_EXPERTS):
+            grouped = functools.partial(lax.ragged_dot, group_sizes=pairs)
+            hidden = (nn.silu(grouped(rows, w_gate.astype(m.dtype)))
+                      * grouped(rows, w_up.astype(m.dtype)))
+            out_rows = grouped(hidden, w_down.astype(m.dtype))  # [T*k, D]
+
+        with jax.named_scope(profiling.MOE_COMBINE):
+            by_token = _permute(out_rows, inverse, order).reshape(t, k, d)
+            if not everything:
+                # rows past the last group are in no product: whatever the
+                # grouped matmul left there is taken out, not weighted
+                by_token = jnp.where(on_chip[..., None], by_token, 0)
+            out = (by_token.astype(jnp.float32) * gates[..., None]).sum(1)
+            if not n_shared:
+                return out.reshape(b, s, d).astype(x.dtype)
 
     with jax.named_scope(profiling.MOE_SHARED):
         act = (nn.silu(tokens @ sw_gate.astype(m.dtype))

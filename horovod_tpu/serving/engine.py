@@ -296,7 +296,9 @@ class TransformerBackend:
     one form and its long ones through the other.  A model with a sparse
     feed-forward (``num_experts`` > 0) also hands back, each call, the pairs
     each held expert of each layer was given, ``last_expert_pairs``
-    ([L, held]; the running sums are ``moe_counters``).
+    ([L, held]), and the rows its expert layers visited for them (the
+    running sums are ``moe_counters``; the call's span carries ``moe_rows``
+    and ``moe_held``).
 
     The pool (``kk``, ``vv``) is whatever ``init_kv_cache`` gives for the
     model: for one of latent attention the latents and their rotary keys,
@@ -332,9 +334,13 @@ class TransformerBackend:
         self.last_expert_pairs = None
         # calls, (token, expert) pairs routed (a prompt's own positions and
         # the slots that hold a request: padding and empty slots route
-        # nowhere), and of them the pairs on the experts held here, over
-        # every prefill and decode call
-        self.moe_counters = {"calls": 0, "pairs": 0, "held_pairs": 0}
+        # nowhere), of them the pairs on the experts held here, and the rows
+        # the expert layers gathered, multiplied and combined for those
+        # (models/moe.py: whole blocks of the held pairs, or every pair's
+        # row where one block holds them all), over every prefill and
+        # decode call
+        self.moe_counters = {"calls": 0, "pairs": 0, "held_pairs": 0,
+                             "rows_visited": 0}
         self._sparse_layers = range(model_cfg.first_dense_layers,
                                     model_cfg.num_layers)
         self._pairs_per_token = (len(self._sparse_layers)
@@ -389,10 +395,11 @@ class TransformerBackend:
         return self._flash_model
 
     def _apply(self, model, params, tokens, **kwargs):
-        """``model.apply``; for a sparse model also the pairs each held
-        expert was given, as its expert layers sowed them (once a chunk
-        where the feed-forward ran in chunks), stacked over the sparse
-        layers [L, held]; else None."""
+        """``model.apply``; for a sparse model also what its expert layers
+        sowed (once a chunk where the feed-forward ran in chunks), stacked
+        over the sparse layers [L, held + 1]: the pairs each held expert
+        was given and, last, the rows the layer visited (one array, one
+        transfer to the host); else None."""
         if not self.sparse:
             return model.apply(params, tokens, **kwargs), None
         from horovod_tpu.models.moe import MOE_STATS
@@ -402,8 +409,10 @@ class TransformerBackend:
                                 **kwargs)
         layers = [sown[MOE_STATS][f"layer_{i}"]["moe_mlp"]
                   for i in self._sparse_layers]
-        return out, jnp.stack([functools.reduce(jnp.add, lay["expert_pairs"])
-                               for lay in layers])
+        total = lambda sown: functools.reduce(jnp.add, sown)  # noqa: E731
+        return out, jnp.stack([
+            jnp.append(total(lay["expert_pairs"]), total(lay["rows_visited"]))
+            for lay in layers])
 
     def _prefill_fn(self, params, kk, vv, padded, length, slot):
         jax, jnp = self._jax, self._jax.numpy
@@ -442,12 +451,20 @@ class TransformerBackend:
         out = kk, vv, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
         return out if pairs is None else out + (pairs,)
 
-    def _count_pairs(self, pairs: np.ndarray, tokens: int) -> None:
-        self.last_expert_pairs = pairs
+    def _count_pairs(self, counted: np.ndarray, tokens: int) -> None:
+        """``counted`` [L, held + 1] is a call's: into the running sums, and
+        onto the ``hvd_srv_prefill`` / ``hvd_srv_decode`` span around it."""
+        self.last_expert_pairs = pairs = counted[:, :-1]
+        held, rows = int(pairs.sum()), int(counted[:, -1].sum())
         c = self.moe_counters
         c["calls"] += 1
         c["pairs"] += tokens * self._pairs_per_token
-        c["held_pairs"] += int(pairs.sum())
+        c["held_pairs"] += held
+        c["rows_visited"] += rows
+        call = profiling.current_span()
+        if call is not None and call.name in (profiling.SRV_PREFILL,
+                                              profiling.SRV_DECODE):
+            call.fields.update(moe_rows=rows, moe_held=held)
 
     def _verify_fn(self, params, kk, vv, tok_block, lengths):
         jnp = self._jax.numpy
@@ -1084,7 +1101,10 @@ class ServingEngine:
         however long the process serves).  Where a backend chose its
         prefill's attention by the bucket, ``hvd_srv_prefill`` also has
         ``attn``: per form (``"flash"``, ``"dense"``) the ``calls`` and the
-        ``prompt_tokens`` they prefilled."""
+        ``prompt_tokens`` they prefilled.  Where the model's feed-forward is
+        sparse, ``hvd_srv_prefill`` and ``hvd_srv_decode`` have ``moe``: the
+        ``rows`` the expert layers visited, the ``held_pairs`` they visited
+        them for, and ``rows_per_held_pair`` (1 would waste nothing)."""
         records = profiling.spans()
         out = profiling.summarize(records)
         by_attn: dict[str, dict] = {}
@@ -1096,6 +1116,14 @@ class ServingEngine:
                 row["prompt_tokens"] += r.fields["length"]
         if by_attn:
             out[profiling.SRV_PREFILL]["attn"] = by_attn
+        for name in (profiling.SRV_PREFILL, profiling.SRV_DECODE):
+            sparse = [r.fields for r in records
+                      if r.name == name and "moe_rows" in r.fields]
+            if sparse:
+                rows = sum(f["moe_rows"] for f in sparse)
+                held = sum(f["moe_held"] for f in sparse)
+                out[name]["moe"] = {"rows": rows, "held_pairs": held,
+                                    "rows_per_held_pair": rows / max(held, 1)}
         return out
 
 

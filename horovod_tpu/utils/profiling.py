@@ -66,6 +66,9 @@ MOE_DISPATCH = "hvd_moe_dispatch"   # ... tokens gathered into expert order
 MOE_EXPERTS = "hvd_moe_experts"     # ... the grouped matmuls, the activation
 MOE_COMBINE = "hvd_moe_combine"     # ... back to token order, gate-weighted sum
 MOE_SHARED = "hvd_moe_shared"       # ... the experts every token visits
+TOKEN_SUM = "hvd_token_sum"     # ops/token_sum: the kernel that adds a walked
+                                # share's rows to their tokens, launched
+                                # under MOE_COMBINE
 MLA_DOWN = "hvd_mla_down"       # models/transformer.LatentAttention: W_DQ,
                                 # W_DKV, the two norms, the rotary key, the
                                 # cache's write
@@ -157,7 +160,9 @@ class Record(typing.NamedTuple):
     that caused it (the enclosing one unless the writer named another, 0
     for none), ``rid`` the request it belongs to if it belongs to one, and
     ``fields`` the counts of the boundary (``bucket``, ``length``,
-    ``slots``, ``live_tokens``, ``rids``, ``bytes``)."""
+    ``slots``, ``live_tokens``, ``rids``, ``bytes``; of a sparse model's
+    prefill or decode call ``moe_rows`` and ``moe_held``: the rows its
+    expert layers visited and the held pairs they visited them for)."""
     name: str
     start: float
     end: float
@@ -230,6 +235,13 @@ class Span:
 
 
 span = Span     # ``with profiling.span(SRV_STEP, queued=3):``
+
+
+def current_span() -> Span | None:
+    """This thread's innermost open ``with`` span, for what is called
+    inside one to add to its ``fields``."""
+    stack = getattr(_open, "stack", None)
+    return stack[-1] if stack else None
 
 
 def open_span(name: str, *, start: float | None = None, cause: int = 0,
@@ -338,8 +350,8 @@ class Scope:
     module: str                 # module_of(op_name)
     bucket: str | None = None   # "0".., "all": a collective under hvd_bucket_
     kernel: str | None = None   # a kernel's name: a FLASH_PASSES or
-                                # SSD_PASSES pass, or MOE_EXPERTS (XLA's own
-                                # grouped matmul)
+                                # SSD_PASSES pass, TOKEN_SUM, or MOE_EXPERTS
+                                # (XLA's own grouped matmul)
     bytes: int = 0              # of the result, from its shape
 
     @property
@@ -378,7 +390,7 @@ def scope_table(compiled) -> dict[str, Scope]:
     (the head's matmul with the loss and its gradient) and is
     ``forward+backward``.  A collective is ``collective`` by
     opcode whatever its scope, and carries its ``hvd_bucket_<k>``; a kernel
-    (custom call) under ``hvd_flash_*`` or ``hvd_ssd_*`` carries that pass, and one that
+    (custom call) under ``hvd_flash_*``, ``hvd_ssd_*`` or ``hvd_token_sum`` carries that name, and one that
     XLA:TPU made of a ``ragged_dot`` carries ``hvd_moe_experts``.  ``while`` and
     ``conditional`` bodies are computations like the entry: their
     instructions are in the table under their own names.
@@ -429,7 +441,7 @@ def scope_table(compiled) -> dict[str, Scope]:
             kernel = None
             if opcode == "custom-call":
                 kernel = next((k for k in FLASH_PASSES + SSD_PASSES
-                               if k in op_name),
+                               + (TOKEN_SUM,) if k in op_name),
                               MOE_EXPERTS
                               if op_name.startswith(_RAGGED_DOT_KERNEL)
                               else None)

@@ -363,3 +363,52 @@ def test_decode_program_keeps_no_copy_of_the_kv_pool(
             (i["name"], i["shape"]) for i in whole]
     assert len(large) - len(whole) <= views, [
         i["name"] for i in large if i not in whole]
+
+
+@pytest.mark.parametrize("t,d,f,e,held,shared,block", [
+    (4096, 7168, 2048, 192, 12, 1, 4096),   # A.X-K1's chunk, a sixteenth
+    (8192, 4096, 4096, 128, 16, 4, 16384),  # command-a-plus's 8192 bucket
+    (512, 4096, 4096, 128, 16, 4, 1024)])   # ... and its shortest
+def test_a_share_of_the_experts_keeps_no_array_of_all_its_pairs(
+        one_chip_mesh, monkeypatch, t, d, f, e, held, shared, block):
+    """A layer that holds a share of the experts walks its held pairs in
+    blocks (PR 43): compiled for the chip at the served cells' widths, the
+    program holds the block's ``[C, D]`` and ``[C, F]`` rows, the token-sum
+    kernel under its name inside one ``while``, and no ``[T * k, D]`` or
+    ``[T * k, F]`` array; the same layer with every expert of ``held`` held
+    carries them all, as it did."""
+    from horovod_tpu.models import moe
+    from horovod_tpu.utils import profiling
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    k = 8
+    assert moe.held_block_rows(t * k, held, e) == block
+
+    def text_of(experts, experts_held):
+        m = moe.MoEMLP(embed_dim=d, mlp_dim=f, axis_name=None,
+                       dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                       num_experts=experts, experts_per_token=k,
+                       selection="sigmoid", norm_topk_prob=True,
+                       num_shared_experts=shared, experts_held=experts_held)
+        x = jax.ShapeDtypeStruct((1, t, d), jnp.bfloat16, sharding=one_chip)
+        valid = jax.ShapeDtypeStruct((1, t), jnp.bool_, sharding=one_chip)
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0),
+                                          jnp.zeros((1, t, d), jnp.bfloat16))))
+        return jax.jit(lambda p, x, v: m.apply(p, x, valid=v)).lower(
+            params, x, valid).compile().as_text()
+
+    all_pairs = re.compile(rf"(?:bf16|f32)\[{t * k},(?:{d}|{f})\]")
+    walked = text_of(e, (0, held))
+    assert not all_pairs.search(walked)
+    assert re.search(rf"bf16\[{block},{d}\]", walked)
+    kernels = [line for line in walked.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and profiling.TOKEN_SUM in line]
+    assert len(kernels) == 1 and profiling.MOE_COMBINE in kernels[0]
+    assert walked.count(" while(") >= 1
+    carried = text_of(held, None)
+    assert all_pairs.search(carried) and profiling.TOKEN_SUM not in carried
