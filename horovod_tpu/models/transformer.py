@@ -167,6 +167,23 @@ class TransformerConfig:
     # experts and a wide dense layer's gate and up are then a chunk's, not
     # the sequence's.  Attention sees the whole sequence.  None: in one piece.
     feed_forward_chunk: int | None = None
+    # EVA attention ("eva_attention" layers, :class:`EvaAttention`): a query
+    # sees the positions of its own window of eva_window exactly and, for
+    # every window that is wholly behind it, one learned summary (k̄, v̄) a
+    # chunk of eva_chunk positions, under one softmax.  Its cache is a ring
+    # of eva_window rows and one row a chunk (:func:`init_kv_cache`).
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # The one lm_head gives num_pred_heads x vocab_size outputs: head i
+    # (columns i * vocab_size ...) predicts the token at t + 1 + i.
+    num_pred_heads: int = 1
+    # A norm's weight is norm_offset + its parameter (1.0: the "unit
+    # offset", the parameter then starts at 0).
+    norm_offset: float = 0.0
+    # dtype of the residual stream and of its adds; the norms take their
+    # statistics on it and hand the compute dtype on.  None: the compute
+    # dtype, the stream every other field of this class describes.
+    residual_dtype: Any = None
     # Mamba-2 sizes ("mamba" layers): heads x head size inner channels,
     # a state of state_dim a channel, B and C shared by heads / groups heads,
     # the causal convolution's taps, the scan's chunk.
@@ -188,7 +205,7 @@ class TransformerConfig:
             raise ValueError(f"TransformerConfig has no field {unknown}")
         out = {k: tuple(v) if isinstance(v, list) else v
                for k, v in fields.items()}
-        for k in ("dtype", "param_dtype", "logits_dtype"):
+        for k in ("dtype", "param_dtype", "logits_dtype", "residual_dtype"):
             if isinstance(out.get(k), str):
                 out[k] = jnp.dtype(out[k]).type
         return cls(**out)
@@ -217,6 +234,30 @@ class TransformerConfig:
                 f" layers: the cache pool has one shape for all layers")
         return kinds == {"latent_attention"}
 
+    @property
+    def eva(self) -> bool:
+        """Every layer is EVA attention: the cache is a ring and summaries."""
+        kinds = set(self.layer_kinds)
+        if "eva_attention" in kinds and len(kinds) > 1:
+            raise NotImplementedError(
+                f"EVA attention beside {sorted(kinds - {'eva_attention'})} "
+                f"layers: the cache pool has one shape for all layers")
+        return kinds == {"eva_attention"}
+
+
+def _norm_scale(norm, width: int):
+    """A norm's ``scale`` parameter: it starts at 1, or where the norm's
+    weight is ``offset`` + the parameter at 1 - offset."""
+    start = nn.initializers.constant(1.0 - norm.offset) if norm.offset \
+        else nn.initializers.ones
+    return norm.param("scale", start, (width,), norm.param_dtype)
+
+
+def _norm_weight(norm, scale):
+    """A norm's float32 weight: the parameter, plus ``offset`` if any."""
+    scale = scale.astype(jnp.float32)
+    return scale + norm.offset if norm.offset else scale
+
 
 class RMSNorm(nn.Module):
     """RMSNorm over the last axis with one ``scale`` vector (``nn.RMSNorm``'s
@@ -227,16 +268,16 @@ class RMSNorm(nn.Module):
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     epsilon: float = 1e-6
+    offset: float = 0.0
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           self.param_dtype)
+        scale = _norm_scale(self, x.shape[-1])
         x = x.astype(self.dtype)
         xf = x.astype(jnp.float32)
         inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
                             + self.epsilon)
-        return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)
+        return (xf * inv * _norm_weight(self, scale)).astype(x.dtype)
 
 
 class LayerNorm(nn.Module):
@@ -247,31 +288,38 @@ class LayerNorm(nn.Module):
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
     epsilon: float = 1e-5
+    offset: float = 0.0
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           self.param_dtype)
+        scale = _norm_scale(self, x.shape[-1])
         x = x.astype(self.dtype)
         xf = x.astype(jnp.float32)
         xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
         inv = jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
                             + self.epsilon)
-        return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)
+        return (xf * inv * _norm_weight(self, scale)).astype(x.dtype)
 
 
 NORMS = {"rms": RMSNorm, "layer": LayerNorm}
 
 
 def make_norm(cfg: "TransformerConfig", name: str):
-    """The model's norm (``cfg.norm``) under ``name``."""
+    """The model's norm (``cfg.norm``) under ``name``.  Over a residual
+    stream of its own dtype (``cfg.residual_dtype``) the statistics are
+    taken in that dtype and the result handed on in the compute dtype."""
     try:
         cls = NORMS[cfg.norm]
     except KeyError:
         raise ValueError(f"norm {cfg.norm!r}; models/transformer.py has "
                          f"{sorted(NORMS)}") from None
-    return cls(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-               epsilon=cfg.norm_eps, name=name)
+    told = {"offset": cfg.norm_offset} if cfg.norm_offset else {}
+    norm = cls(dtype=cfg.residual_dtype or cfg.dtype,
+               param_dtype=cfg.param_dtype, epsilon=cfg.norm_eps, name=name,
+               **told)
+    if cfg.residual_dtype is None:
+        return norm
+    return lambda x: norm(x).astype(cfg.dtype)
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -358,7 +406,10 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     or for a model of latent attention the latents ``[L, slots, S,
     kv_lora_rank]`` and the one rotary key of all heads ``[L, slots, S,
     qk_rope_head_dim]`` (what a cache call writes and reads as they lie;
-    K and V are never stored).  One slot is one serving sequence — the
+    K and V are never stored), or for a model of EVA attention K-side and
+    V-side rows ``[L, slots, eva_window + S / eva_chunk, H, D]``: a ring of
+    the current window's exact keys and values, then one summary a chunk.
+    One slot is one serving sequence — the
     continuous-batching scheduler (serving/engine.py) admits a request
     into a free slot (prefill writes positions ``0..len``) and decode
     appends one position per step, so the buffer is allocated once and
@@ -368,6 +419,14 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     (:func:`write_kv_block`); a caller that donates them to its jitted
     program (``donate_argnums``) has them updated where they lie, one that
     does not pays a copy of both a call."""
+    if cfg.eva:
+        # a slot holds, a layer, a ring of the window's exact rows and then
+        # one summary row a chunk of the positions it may reach: not a row
+        # a position (:class:`EvaAttention`)
+        rows = cfg.eva_window + -(-(max_len or cfg.max_seq_len)
+                                  // cfg.eva_chunk)
+        shape = (cfg.num_layers, num_slots, rows, cfg.kv_heads, cfg.head_dim)
+        return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
     lead = (cfg.num_layers, num_slots, max_len or cfg.max_seq_len)
     if cfg.latent:
         return (jnp.zeros(lead + (cfg.kv_lora_rank,), cfg.dtype),
@@ -388,6 +447,12 @@ def init_kv_pages(cfg: TransformerConfig, num_pages: int, page_size: int):
             "a paged pool of latents (init_kv_pages, "
             "PagedTransformerBackend) is not built: latent attention "
             "serves from init_kv_cache's dense pool")
+    if cfg.eva:
+        raise NotImplementedError(
+            "a paged pool of EVA attention's ring and summaries "
+            "(init_kv_pages, PagedTransformerBackend) is not built: a page "
+            "of positions is no unit of that cache; it serves from "
+            "init_kv_cache's pool")
     shape = (cfg.num_layers, num_pages, page_size, cfg.kv_heads,
              cfg.head_dim)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
@@ -650,6 +715,250 @@ def absorbed_decode_attention(q_lat, q_rope, latents, rope_keys, lengths,
     probs = nn.softmax(logits, axis=-1).astype(q_lat.dtype)
     return jnp.einsum("bhqk,bkr->bqhr", probs, latents)
 
+# A pass without a cache runs EVA attention densely, one mask over its
+# [1, H, S, S + S / chunk] float32 logits, while those fit this many bytes,
+# and as two partial attentions merged by their log-sum-exp past that
+# (:func:`eva_attention_form`).  TransformerBackend's crossover for the
+# dense and the flash form of causal attention (serving/engine.py,
+# FLASH_PREFILL_LOGITS_BYTES: read on a v5e, PERF.md section 5, PR 41).
+EVA_DENSE_LOGITS_BYTES = 96 * 2 ** 20
+
+
+def eva_attention_form(cfg: TransformerConfig, s: int) -> str:
+    """``"dense"`` or ``"merged"``: how a pass without a cache over ``s``
+    positions runs EVA attention, from its shape alone.  The merged form
+    takes whole windows."""
+    logits_bytes = 4 * cfg.num_heads * s * (s + s // cfg.eva_chunk)
+    return ("merged" if s % cfg.eva_window == 0
+            and logits_bytes > EVA_DENSE_LOGITS_BYTES else "dense")
+
+
+def eva_chunk_summaries(k, v, phi, mu, chunk: int, scale: float,
+                        rows_at_a_time: int | None = None):
+    """One summary a whole chunk, from the chunk's own rows alone: ``k``,
+    ``v`` [B, n * chunk, H, D] -> (k̄, v̄) [B, n, H, D] in their dtypes, with
+    a = softmax over the chunk's rows m of (scale phi_h . k_m), k̄ = sum a_m
+    k_m + mu_h, v̄ = sum a_m v_m; the softmax and the sums in float32.  A
+    sequence of several times ``rows_at_a_time`` rows (whole chunks) is
+    summarised that many rows at a time, the same numbers: its float32
+    intermediates are then a piece's and not the sequence's."""
+    b, s, h, d = k.shape
+    if rows_at_a_time and s > rows_at_a_time and s % rows_at_a_time == 0:
+        pieces = lambda x: jnp.moveaxis(  # noqa: E731
+            x.reshape(b, s // rows_at_a_time, rows_at_a_time, h, d), 1, 0)
+        kbar, vbar = jax.lax.map(
+            lambda kv: eva_chunk_summaries(*kv, phi, mu, chunk, scale),
+            (pieces(k), pieces(v)))
+        whole = lambda x: jnp.moveaxis(x, 0, 1).reshape(  # noqa: E731
+            b, s // chunk, h, d)
+        return whole(kbar), whole(vbar)
+    kc = k.reshape(b, s // chunk, chunk, h, d)
+    vc = v.reshape(b, s // chunk, chunk, h, d)
+    a = nn.softmax(jnp.einsum(
+        "bnchd,hd->bnch", kc, phi.astype(k.dtype),
+        preferred_element_type=jnp.float32) * scale, axis=2)
+    # the weights go to the rows' dtype and the sums are taken in float32
+    mean = lambda rows: jnp.einsum(  # noqa: E731
+        "bnch,bnchd->bnhd", a.astype(rows.dtype), rows,
+        preferred_element_type=jnp.float32)
+    kbar, vbar = mean(kc) + mu.astype(jnp.float32), mean(vc)
+    return kbar.astype(k.dtype), vbar.astype(v.dtype)
+
+
+def eva_dense_attention(q, k, v, kbar, vbar, window: int, chunk: int,
+                        scale: float):
+    """EVA attention over a whole sequence, one mask: ``q``, ``k``, ``v``
+    [B, S, H, D], ``kbar``, ``vbar`` [B, n, H, D] (n >= the chunks of the
+    completed windows).  Query t sees keys m with t's window's start <= m <=
+    t and summaries j < (window / chunk) * (t // window), under one float32
+    softmax (:func:`dense_causal_attention`'s arithmetic)."""
+    s, n = q.shape[1], kbar.shape[1]
+    t = jnp.arange(s)[:, None]
+    m = jnp.arange(s)[None, :]
+    mask = jnp.concatenate(
+        [(m <= t) & (m // window == t // window),
+         jnp.arange(n)[None, :] < (window // chunk) * (t // window)], axis=1)
+    logits = jnp.concatenate(
+        [jnp.einsum("bqhd,bkhd->bhqk", q, k),
+         jnp.einsum("bqhd,bkhd->bhqk", q, kbar)], axis=-1).astype(
+        jnp.float32) * scale
+    probs = nn.softmax(jnp.where(mask, logits, -1e30), axis=-1).astype(
+        q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs[..., :s], v) \
+        + jnp.einsum("bhqk,bkhd->bqhd", probs[..., s:], vbar)
+
+
+@functools.partial(jax.jit, static_argnames=("window", "chunk"))
+def eva_merged_attention(q, k, v, kbar, vbar, window: int, chunk: int):
+    """:func:`eva_dense_attention`'s numbers (at the d^-1/2 scale) without
+    its logits: a window at a time, the causal triangle of the window's own
+    keys and the rectangle of the summaries of the windows behind it, each a
+    partial attention of the flash forward kernel
+    (``ops/flash_attention.flash_attention_with_lse``; the rectangle over
+    all the summaries with the kernel's valid length at the window's
+    count), merged by their log-sum-exp.  ``S`` is whole windows.  Jitted,
+    so that a program's layers share one tracing of the two kernels
+    (PERF.md section 6, PR 41).  Forward only."""
+    from horovod_tpu.ops.flash_attention import flash_attention_with_lse
+
+    b, s, h, d = q.shape
+    nw = s // window
+    if nw * window != s:
+        raise ValueError(f"the merged form takes whole windows of {window} "
+                         f"positions; the sequence has {s}")
+    by_window = lambda x: jnp.moveaxis(  # noqa: E731
+        x.reshape(b, nw, window, h, d), 1, 0)
+
+    def one(args):
+        w, qw, kw, vw = args
+        own, own_lse = flash_attention_with_lse(qw, kw, vw, causal=True)
+        if nw == 1:
+            return own.astype(q.dtype)
+        # the summaries of windows 0 .. w - 1: the first w * window / chunk
+        past, past_lse = flash_attention_with_lse(
+            qw, kbar, vbar, causal=False, k_len=w * (window // chunk))
+        lse = jnp.logaddexp(own_lse, past_lse)
+        return (own * jnp.exp(own_lse - lse)[..., None]
+                + past * jnp.exp(past_lse - lse)[..., None]).astype(q.dtype)
+
+    out = jax.lax.map(one, (jnp.arange(nw), by_window(q), by_window(k),
+                            by_window(v)))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, h, d)
+
+
+def eva_decode_attention(q, k_rows, v_rows, lengths, window: int,
+                         chunk: int, scale: float):
+    """One position a slot over its ring and summaries: ``q`` [B, 1, H, D]
+    at position ``lengths[b]``, ``k_rows`` / ``v_rows`` [B, window + n, H,
+    D] (ring row r holds the position of the current window with m mod
+    window = r; row window + j chunk j's summary).  Seen: ring rows 0 ..
+    lengths mod window and summaries j < (window / chunk) * (lengths //
+    window); a ring row left from the window before and a summary of the
+    current window (or of a slot's previous occupant) lie behind the mask.
+    :func:`cached_decode_attention`'s arithmetic."""
+    t = lengths[:, None]
+    r = jnp.arange(k_rows.shape[1])[None, :]
+    mask = jnp.where(r < window, r <= t % window,
+                     r - window < (window // chunk) * (t // window))
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k_rows).astype(
+        jnp.float32) * scale
+    probs = nn.softmax(jnp.where(mask[:, None, None, :], logits, -1e30),
+                       axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v_rows)
+
+
+class EvaAttention(nn.Module):
+    """EVA attention (Zheng et al., "Efficient Attention via Control
+    Variates", as the EvaByte family runs it): per head, exact softmax terms
+    for the keys of the query's own window (``eva_window`` positions, causal)
+    and one summary (k̄_j, v̄_j) a chunk of ``eva_chunk`` positions for every
+    window wholly behind the query, under ONE normaliser.  A chunk's summary
+    is a softmax-weighted mean of its own rotated keys and of its values,
+    the weights from a learned per-head vector ``phi`` and k̄ shifted by a
+    learned ``mu`` (:func:`eva_chunk_summaries`).  One parameter tree, two
+    forms:
+
+    *Without a cache* (training, a serving prefill): the summaries of every
+    whole chunk (scope ``hvd_eva_summary``), then attention (scope
+    ``hvd_eva_attn``) densely with one mask or as merged partials, by the
+    sequence's shape (:func:`eva_attention_form`).  ``return_kv`` hands back
+    what the cache holds, K-side and V-side ``[B, eva_window + S //
+    eva_chunk, H, D]``: the rows of the window that position ``lengths[b]``
+    (default S) falls in, laid out as the ring, then the summaries.
+
+    *With a cache* (one position a slot): the new key and value go to ring
+    row ``t mod eva_window``; the summary of the chunk t is in is taken from
+    the ring's rows of that chunk and written at its row every step (whole,
+    so right, at the chunk's last position; nothing sees it before its
+    window is complete); attention over the ring rows up to t's and the
+    summaries of the completed windows.  Stale rows are masked, never
+    cleared.  A block of more than one position (verify, suffix prefill) is
+    refused by name."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, x, positions, cache=None, return_kv=False,
+                 lengths=None):
+        cfg = self.cfg
+        w, c, h, d = (cfg.eva_window, cfg.eva_chunk, cfg.num_heads,
+                      cfg.head_dim)
+        if not (w and c) or w % c or cfg.kv_heads != h:
+            raise ValueError(
+                "an eva_attention layer needs TransformerConfig's eva_window "
+                "a multiple of eva_chunk, and as many KV heads as heads")
+        scale = d ** -0.5 if cfg.attention_scale is None \
+            else cfg.attention_scale
+        proj = lambda name: nn.DenseGeneral(  # noqa: E731
+            (h, d), use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name=name)
+        q = rope(proj("q")(x), positions, cfg.rope_theta)
+        k = rope(proj("k")(x), positions, cfg.rope_theta)
+        v = proj("v")(x)
+        vector = lambda name: self.param(  # noqa: E731
+            name, nn.initializers.normal(0.02), (h, d), cfg.param_dtype)
+        phi, mu = vector("phi"), vector("mu")
+        o_proj = nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), use_bias=False,
+                                 dtype=cfg.dtype,
+                                 param_dtype=cfg.param_dtype, name="o")
+        if cache is not None:
+            if x.shape[1] != 1:
+                raise NotImplementedError(
+                    "eva_attention decodes one position a cache call: a "
+                    "block of more (speculative verify, a prefix-attached "
+                    "suffix prefill) through the ring and summaries is not "
+                    "built")
+            k_pool, v_pool, lengths, layer = cache
+            row = lengths % w
+            k_pool = write_kv_block(k_pool, k, layer, row)
+            v_pool = write_kv_block(v_pool, v, layer, row)
+            with jax.named_scope(profiling.EVA_SUMMARY):
+                first = row - row % c       # of the chunk t is in, in the ring
+                rows = lambda pool: jnp.stack([  # noqa: E731
+                    jax.lax.dynamic_slice(
+                        pool, (layer, b, first[b], 0, 0), (1, 1, c, h, d)
+                    )[0, 0] for b in range(x.shape[0])])
+                kbar, vbar = eva_chunk_summaries(rows(k_pool), rows(v_pool),
+                                                 phi, mu, c, scale)
+                k_pool = write_kv_block(k_pool, kbar, layer, w + lengths // c)
+                v_pool = write_kv_block(v_pool, vbar, layer, w + lengths // c)
+            with jax.named_scope(profiling.EVA_ATTN):
+                out = eva_decode_attention(q, k_pool[layer], v_pool[layer],
+                                           lengths, w, c, scale)
+            return o_proj(out), (k_pool, v_pool)
+
+        if cfg.context_axis and cfg.context_plan is not None:
+            raise NotImplementedError(
+                "ring / zigzag attention over a context axis is causal "
+                "attention's; eva_attention's windows and summaries are not "
+                "sharded over one")
+        s = x.shape[1]
+        with jax.named_scope(profiling.EVA_SUMMARY):
+            whole = s - s % c
+            kbar, vbar = eva_chunk_summaries(k[:, :whole], v[:, :whole], phi,
+                                             mu, c, scale, rows_at_a_time=w)
+        with jax.named_scope(profiling.EVA_ATTN):
+            if eva_attention_form(cfg, s) == "merged" \
+                    and cfg.attention_scale is None:
+                out = eva_merged_attention(q, k, v, kbar, vbar, window=w,
+                                           chunk=c)
+            else:
+                out = eva_dense_attention(q, k, v, kbar, vbar, w, c, scale)
+        if not return_kv:
+            return o_proj(out)
+        # the ring as a decode step at position lengths[b] expects it: the
+        # rows of the window that position is in (for a prompt that ends on
+        # a window's last position nothing of the ring is seen again)
+        at = jnp.full((x.shape[0],), s) if lengths is None else lengths
+        start = (at // w) * w
+        pad = -s % w
+        ring = lambda y: jnp.stack([  # noqa: E731
+            jax.lax.dynamic_slice_in_dim(
+                jnp.pad(y[b], ((0, pad), (0, 0), (0, 0))) if pad else y[b],
+                start[b], w, axis=0) for b in range(x.shape[0])])
+        return o_proj(out), (jnp.concatenate([ring(k), kbar], axis=1),
+                             jnp.concatenate([ring(v), vbar], axis=1))
+
 
 class MLP(nn.Module):
     cfg: TransformerConfig
@@ -678,9 +987,11 @@ MIXERS = {
     "mamba": ("horovod_tpu.models.mamba", "Mamba2Mixer", "mamba", {}),
     "latent_attention": ("horovod_tpu.models.transformer",
                          "LatentAttention", "attn", {}),
+    "eva_attention": ("horovod_tpu.models.transformer", "EvaAttention",
+                      "attn", {}),
 }
 CACHED_MIXERS = ("attention", "sliding_attention", "full_attention",
-                 "latent_attention")
+                 "latent_attention", "eva_attention")
 
 
 def _scaled(x, multiplier: float):
@@ -749,7 +1060,7 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, cache=None, return_kv=False,
-                 valid=None):
+                 valid=None, lengths=None):
         cfg = self.cfg
         norm = functools.partial(make_norm, cfg)
         try:
@@ -763,7 +1074,11 @@ class Block(nn.Module):
         y = norm(f"{name}_norm")(x)
         kv = None
         if cache is not None or return_kv:
-            mixed, kv = mixer(y, positions, cache=cache, return_kv=return_kv)
+            # a prefill's own length, for a mixer whose cache is not a row a
+            # position, is told by name, to that mixer alone
+            mixed, kv = mixer(y, positions, cache=cache, return_kv=return_kv,
+                              **({} if lengths is None
+                                 else {"lengths": lengths}))
         else:
             mixed = mixer(y, positions)
         ff = _feed_forward(cfg, self.dense_ff)
@@ -817,6 +1132,12 @@ class Transformer(nn.Module):
     * ``valid`` ([B, S] bool; a sparse model, ``num_experts`` > 0): which
       positions hold a token.  A prefill bucket's padding and a slot with
       no request are routed to no expert (models/moe.py).
+    * ``kv_into=(k_pool, v_pool, slot)`` beside ``return_kv`` (one row of
+      tokens): each layer's block is written into the two pools at ``(layer,
+      slot)`` as soon as the layer has run, and the pools are what is
+      returned in place of the stacked blocks.  For a model whose block a
+      layer is a slot's whole extent (EVA attention: 16 layers' rings and
+      summaries stacked would be a second slot beside the pool).
     * ``logits_at`` ([B] positions; not with a cache): the final norm and
       the head run on that one position of each row and the logits are
       ``[B, vocab]``: a prefill needs its prompt's last position alone.
@@ -824,7 +1145,13 @@ class Transformer(nn.Module):
     A model whose layers are "latent_attention" keeps latents in the cache
     (``return_kv`` and ``kv_cache`` are then :func:`init_kv_cache`'s two
     latent arrays, ``[L, B, S, kv_lora_rank]`` and ``[L, B, S,
-    qk_rope_head_dim]``): :class:`LatentAttention`.
+    qk_rope_head_dim]``): :class:`LatentAttention`.  One whose layers are
+    "eva_attention" keeps a ring of its window's keys and values and one
+    summary a chunk (``[L, B, eva_window + S / eva_chunk, H, D]`` twice;
+    ``lengths`` beside ``return_kv`` says where each row's prompt ends, so
+    that the ring is the one a decode step there expects):
+    :class:`EvaAttention`.  With ``num_pred_heads`` > 1 the logits' last axis
+    is ``num_pred_heads * vocab_size`` wide, head 0 (the next token) first.
     """
 
     cfg: TransformerConfig
@@ -832,7 +1159,7 @@ class Transformer(nn.Module):
     @nn.compact
     def __call__(self, tokens, position_offset=0, positions=None,
                  kv_cache=None, lengths=None, return_kv=False, valid=None,
-                 logits_at=None):
+                 logits_at=None, kv_into=None):
         cfg = self.cfg
         if logits_at is not None and kv_cache is not None:
             raise ValueError("logits_at picks a position of a pass without "
@@ -853,6 +1180,8 @@ class Transformer(nn.Module):
         embed = nn.Embed(cfg.vocab_size, cfg.embed_dim, dtype=cfg.dtype,
                          param_dtype=cfg.param_dtype, name="embed")
         x = _scaled(embed(tokens), cfg.embedding_multiplier)
+        if cfg.residual_dtype is not None:
+            x = x.astype(cfg.residual_dtype)
         if decode:
             # Block row i of a cache call decodes position lengths + i:
             # S=1 is plain decode, S>1 is a speculative verify window or a
@@ -876,6 +1205,10 @@ class Transformer(nn.Module):
             and not return_kv
         block_cls = nn.remat(Block) if remat_on else Block
         kvs = []
+        # where each row's prompt ends, beside return_kv: EVA attention alone
+        # reads it, and lays its cache block out for a decode step there
+        ends = {"lengths": jnp.asarray(lengths)} \
+            if return_kv and lengths is not None and cfg.eva else {}
         for i, kind in enumerate(kinds):
             block = block_cls(cfg, kind, i < cfg.first_dense_layers,
                               name=f"layer_{i}")
@@ -885,8 +1218,20 @@ class Transformer(nn.Module):
                 x, kv_cache = block(
                     x, positions, cache=(*kv_cache, lengths, i), **told)
             elif return_kv:
-                x, kv = block(x, positions, return_kv=True, **told)
-                kvs.append(kv)
+                x, kv = block(x, positions, return_kv=True, **told, **ends)
+                if kv_into is None:
+                    kvs.append(kv)
+                    continue
+                # the layer's block goes into the pool before the next
+                # layer runs (the barrier holds XLA to that order), so no
+                # layer's block outlives its layer
+                *pools, slot = kv_into
+                pools = [jax.lax.dynamic_update_slice(
+                    pool, block_[None].astype(pool.dtype),
+                    (i, slot) + (0,) * (pool.ndim - 2))
+                    for pool, block_ in zip(pools, kv)]
+                x, pools = jax.lax.optimization_barrier((x, pools))
+                kv_into = (*pools, slot)
             else:
                 x = block(x, positions, **told)
         if logits_at is not None:
@@ -900,7 +1245,8 @@ class Transformer(nn.Module):
         if cfg.tie_embeddings:
             logits = embed.attend(x)
         else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+            logits = nn.Dense(cfg.vocab_size * cfg.num_pred_heads,
+                              use_bias=False, dtype=cfg.dtype,
                               param_dtype=cfg.param_dtype, name="lm_head")(x)
         logits = _scaled(logits, 1.0 / cfg.logits_scaling).astype(
             cfg.logits_dtype)
@@ -910,6 +1256,8 @@ class Transformer(nn.Module):
             # Multi-token cache call (speculative verify / suffix
             # prefill): the caller needs every block position's logits.
             return logits, kv_cache
+        if return_kv and kv_into is not None:
+            return logits, tuple(kv_into[:2])
         if return_kv:
             return logits, (jnp.stack([kv[0] for kv in kvs]),
                             jnp.stack([kv[1] for kv in kvs]))

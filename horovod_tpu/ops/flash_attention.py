@@ -493,14 +493,17 @@ def _meta(q_offset, k_offset, s_k: int):
 
 
 def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                interpret, sub, out_dtype, scale=None, window=None):
+                interpret, sub, out_dtype, scale=None, window=None,
+                k_len=None):
     """The forward kernel on [B, S, H, D] inputs, everything it read and
     wrote left in the kernels' layout, padded to whole blocks:
     ``(qb, kb, vb, ob, lse_b)`` with ``ob`` [B·H, S_q_pad, D_v] in
     ``out_dtype`` and ``lse_b`` [B·H, 8, S_q_pad] float32 (sublane-
     replicated).  The backward reads all five as they are.  ``v`` may be
     narrower or wider than ``q`` and ``k`` (latent attention: keys of 192,
-    values of 128): its width is the output's and the accumulator's."""
+    values of 128): its width is the output's and the accumulator's.
+    ``k_len`` (may be traced) is how many of the keys count, where that is
+    fewer than all: the rest lie behind the padding mask."""
     d, d_v = q.shape[-1], v.shape[-1]
     s_k = k.shape[1]
     block_k, sub_k = _sub_fit(block_k, sub)
@@ -546,7 +549,7 @@ def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=profiling.FLASH_FWD,
-    )(_meta(q_offset, k_offset, s_k), qb, kb, vb)
+    )(_meta(q_offset, k_offset, s_k if k_len is None else k_len), qb, kb, vb)
     return qb, kb, vb, ob, lse_b
 
 
@@ -1002,9 +1005,13 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
 def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
                              k_offset=0, block_q: int = 1024,
                              block_k: int | None = None, sub: int = 1024,
-                             interpret: bool | None = None):
+                             interpret: bool | None = None, k_len=None):
     """Forward-only fused attention returning (out, lse): a PARTIAL
-    attention, for a caller that merges several of them.
+    attention, for a caller that merges several of them.  ``k_len`` (a
+    traced scalar may be given) counts the leading keys that are seen, for
+    a caller whose programs share one shape and differ in how much of ``k``
+    is filled (EVA attention's summaries, a window at a time); a row that
+    sees no key has lse NEG_INF and out 0.
 
     ``lse[b, s, h] = logsumexp_k(q·kᵀ·scale)`` (NEG_INF for rows that
     attended to nothing) — the combiner state ring attention needs to merge
@@ -1027,7 +1034,8 @@ def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
         where="flash_attention_with_lse")
     b, s_q = q.shape[:2]
     *_, ob, lse_b = _forward_bh(q, k, v, causal, q_offset, k_offset, block_q,
-                                block_k, interpret, sub, jnp.float32)
+                                block_k, interpret, sub, jnp.float32,
+                                k_len=k_len)
     # [B·H, 8, S_pad] (sublane-replicated) → [B, S, H]
     lse = lse_b[:, 0, :s_q].reshape(b, -1, s_q).transpose(0, 2, 1)
     return _from_bh(ob, b, s_q), lse

@@ -305,7 +305,15 @@ class TransformerBackend:
     which prefill builds K and V from (expanded) and decode reads as they
     lie (absorbed).  A model that sets ``feed_forward_chunk`` prefills a
     longer bucket with its feed-forward a chunk at a time
-    (:meth:`prefill_chunks`) and its head on the last position alone.
+    (:meth:`prefill_chunks`) and its head on the last position alone.  For
+    a model of EVA attention a slot's extent is a ring of ``eva_window``
+    exact rows and one summary row a chunk, not a row a position; the
+    model picks its prefill's form a bucket (``"dense"`` / ``"merged"``),
+    lays the ring out for the prompt's own length, and the running counts of
+    what the calls did to that cache are ``eva_counters`` (the call's span
+    carries ``windows`` and ``summaries``, or ``chunks_closed`` and
+    ``rollovers``).  Of a model with more than one prediction head all the
+    logits leave the program and head 0's, the next token's, are sampled.
     """
 
     # A bucket whose dense attention logits, [1, H, S, S] float32, are larger
@@ -345,6 +353,12 @@ class TransformerBackend:
                                     model_cfg.num_layers)
         self._pairs_per_token = (len(self._sparse_layers)
                                  * model_cfg.experts_per_token)
+        self.eva = model_cfg.eva
+        # over every call: the windows the prompts reached into and the
+        # whole chunks they summarised (prefill); the chunks decode steps
+        # closed and the windows they rolled over into
+        self.eva_counters = {"windows": 0, "summaries": 0,
+                             "chunks_closed": 0, "rollovers": 0}
         # the pool is the model's to shape: K and V [L, slots, S, KV, D], or
         # latents and their rotary keys [L, slots, S, rank] / [.., rope]
         self.kk, self.vv = init_kv_cache(model_cfg, num_slots, max_seq_len)
@@ -356,9 +370,15 @@ class TransformerBackend:
         """The attention a prefill of ``bucket`` positions runs: ``"flash"``
         where the bucket's own dense logits pass
         :data:`FLASH_PREFILL_LOGITS_BYTES`, ``"dense"`` below; ``"own"`` in
-        every bucket for a model that brought its ``attention_fn``."""
+        every bucket for a model that brought its ``attention_fn``; for a
+        model of EVA attention what the mixer itself picks from the bucket's
+        shape, ``"dense"`` or ``"merged"``."""
         if self._model_cfg.attention_fn is not None:
             return "own"
+        if self.eva:    # the mixer's own two forms, by the same kind of rule
+            from horovod_tpu.models.transformer import eva_attention_form
+
+            return eva_attention_form(self._model_cfg, int(bucket))
         logits_bytes = 4 * self._model_cfg.num_heads * int(bucket) ** 2
         return ("flash" if logits_bytes > self.FLASH_PREFILL_LOGITS_BYTES
                 else "dense")
@@ -426,15 +446,26 @@ class TransformerBackend:
         chunked = self._model_cfg.feed_forward_chunk is not None
         if chunked:
             told["logits_at"] = jnp.reshape(length - 1, (1,))
+        if self.eva:
+            # the ring is laid out for a decode step at length, and a
+            # layer's ring and summaries, a slot's whole extent, go into the
+            # pool as the layer ends
+            told.update(lengths=jnp.reshape(length, (1,)),
+                        kv_into=(kk, vv, slot))
         (logits, (pk, pv)), pairs = self._apply(
             self._prefill_model(padded.shape[1]), params, padded,
             return_kv=True, **told)
-        at_slot = lambda pool: (0, slot) + (0,) * (pool.ndim - 2)  # noqa: E731
-        kk = jax.lax.dynamic_update_slice(kk, pk, at_slot(kk))
-        vv = jax.lax.dynamic_update_slice(vv, pv, at_slot(vv))
+        if self.eva:
+            kk, vv = pk, pv
+        else:
+            at_slot = lambda pool: (0, slot) + (0,) * (  # noqa: E731
+                pool.ndim - 2)
+            kk = jax.lax.dynamic_update_slice(kk, pk, at_slot(kk))
+            vv = jax.lax.dynamic_update_slice(vv, pv, at_slot(vv))
         last = logits[0] if chunked else jax.lax.dynamic_slice(
             logits, (0, length - 1, 0), (1, 1, logits.shape[-1]))[0, 0]
-        out = kk, vv, jnp.argmax(last).astype(jnp.int32), last
+        out = (kk, vv, jnp.argmax(self._next_head(last)).astype(jnp.int32),
+               last)
         return out if pairs is None else out + (pairs,)
 
     def _decode_fn(self, params, kk, vv, last_tokens, lengths):
@@ -448,8 +479,24 @@ class TransformerBackend:
         (logits, (kk, vv)), pairs = self._apply(
             self.model, params, last_tokens[:, None], kv_cache=(kk, vv),
             lengths=jnp.maximum(lengths - 1, 0), **told)
-        out = kk, vv, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
+        out = (kk, vv, jnp.argmax(self._next_head(logits),
+                                  axis=-1).astype(jnp.int32), logits)
         return out if pairs is None else out + (pairs,)
+
+    def _next_head(self, logits):
+        """The logits that predict the next token: head 0 of
+        ``num_pred_heads``, the first ``vocab_size`` columns."""
+        cfg = self.model.cfg
+        return logits if cfg.num_pred_heads == 1 \
+            else logits[..., :cfg.vocab_size]
+
+    def _count_eva(self, name: str, **counts) -> None:
+        """A call's counts into ``eva_counters`` and onto its span."""
+        for k, v in counts.items():
+            self.eva_counters[k] += v
+        call = profiling.current_span()
+        if call is not None and call.name == name:
+            call.fields.update(counts)
 
     def _count_pairs(self, counted: np.ndarray, tokens: int) -> None:
         """``counted`` [L, held + 1] is a call's: into the running sums, and
@@ -480,28 +527,49 @@ class TransformerBackend:
             lengths=jnp.maximum(lengths - 1, 0))
         return kk, vv, jnp.argmax(logits, axis=-1).astype(jnp.int32), logits
 
-    def _call(self, program, host_inputs, passed=()):
+    def _call(self, program, host_inputs, passed=(), meanwhile=None):
         """``program`` over the donated pool: the four leaf spans in order,
         the pool kept, and on the host (tokens, logits) and, sparse, the
-        pair counts."""
+        pair counts.  ``meanwhile()`` is bookkeeping that needs no result:
+        it runs once the program is enqueued, while the device works."""
         jnp = self._jax.numpy
         with profiling.span(profiling.SRV_H2D):
             copied = [jnp.asarray(x) for x in host_inputs]
         with profiling.span(profiling.SRV_DISPATCH):
             self.kk, self.vv, *results = program(
                 self.params, self.kk, self.vv, *copied, *passed)
+        if meanwhile is not None:
+            meanwhile()
         return _wait_and_fetch(results)
 
+    def _count_eva_prefill(self, length: int) -> None:
+        cfg = self._model_cfg
+        self._count_eva(profiling.SRV_PREFILL,
+                        windows=-(-length // cfg.eva_window),
+                        summaries=length // cfg.eva_chunk)
+
+    def _count_eva_step(self, lengths: np.ndarray) -> None:
+        cfg = self._model_cfg
+        at = lengths[lengths > 0] - 1       # the positions this step writes
+        self._count_eva(
+            profiling.SRV_DECODE,
+            chunks_closed=int((at % cfg.eva_chunk == cfg.eva_chunk - 1).sum()),
+            rollovers=int(((at > 0) & (at % cfg.eva_window == 0)).sum()))
+
     def prefill(self, padded: np.ndarray, length: int, slot: int):
-        first, logits, *pairs = self._call(self._prefill, (padded,),
-                                           (length, slot))
+        first, logits, *pairs = self._call(
+            self._prefill, (padded,), (length, slot),
+            meanwhile=functools.partial(self._count_eva_prefill, int(length))
+            if self.eva else None)
         if pairs:
             self._count_pairs(pairs[0], int(length))
         return int(first), logits
 
     def decode(self, last_tokens: np.ndarray, lengths: np.ndarray):
-        nxt, logits, *pairs = self._call(self._decode,
-                                         (last_tokens, lengths))
+        nxt, logits, *pairs = self._call(
+            self._decode, (last_tokens, lengths),
+            meanwhile=functools.partial(self._count_eva_step, lengths)
+            if self.eva else None)
         if pairs:
             self._count_pairs(pairs[0], int((lengths > 0).sum()))
         return nxt, logits

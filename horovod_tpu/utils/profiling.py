@@ -75,6 +75,11 @@ MLA_DOWN = "hvd_mla_down"       # models/transformer.LatentAttention: W_DQ,
 MLA_UP = "hvd_mla_up"           # ... W_UQ and its rotation; expanded: W_UKV
 MLA_ABSORB = "hvd_mla_absorb"   # ... absorbed: q W_UK^T and o_lat W_UV
 MLA_ATTN = "hvd_mla_attn"       # ... absorbed: the products and the softmax
+EVA_SUMMARY = "hvd_eva_summary"     # models/transformer.EvaAttention: a
+                                # chunk's weighted mean of keys and of values
+EVA_ATTN = "hvd_eva_attn"       # ... the window's exact keys and the
+                                # summaries under one softmax (merged form:
+                                # the flash forward kernels it launches)
                                 # over the pool (expanded: the attention
                                 # function's own names, FLASH_FWD)
 SSM_PROJ = "hvd_ssm_proj"       # models/mamba: the in and out projections

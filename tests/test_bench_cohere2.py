@@ -63,7 +63,7 @@ def test_the_manifest_holds_the_cell_and_its_metrics():
     cell = next(c for c in m["workloads"] if c["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "command-a-plus-05-2026", "code8k-open", 1)
-    assert len(m["workloads"]) == 9
+    assert len(m["workloads"]) >= 9     # later PRs append theirs
     assert sum(c["chips"] == 4 for c in m["workloads"]) == 1
     entry = next(c for c in m["configs"] if c["name"] == cell["config"])
     assert entry["reduced"] == ["num_hidden_layers", "layer_types",
