@@ -47,7 +47,9 @@ class TransformerConfig:
     param_dtype: Any = jnp.float32
     # attention_fn(q, k, v, causal) -> out; shapes [B, S, H, D].  None = dense
     # causal attention.  parallel/ring_attention.py provides a drop-in for
-    # sequence-sharded q/k/v.
+    # sequence-sharded q/k/v.  A prefill that says where its prompts end
+    # (Transformer(..., return_kv=True, lengths=)) also passes q_len and
+    # k_len by name: hvd.make_flash_attention()'s function takes them.
     attention_fn: Callable | None = None
     # Base of the rotary embedding's frequencies.
     rope_theta: float = 10000.0
@@ -530,7 +532,8 @@ class Attention(nn.Module):
     layer_type: str = "attention"
 
     @nn.compact
-    def __call__(self, x, positions, cache=None, return_kv=False):
+    def __call__(self, x, positions, cache=None, return_kv=False,
+                 lengths=None):
         cfg = self.cfg
         rotary = {"attention": cfg.rotary, "sliding_attention": True,
                   "full_attention": False}[self.layer_type]
@@ -590,11 +593,24 @@ class Attention(nn.Module):
             from horovod_tpu.parallel.context import context_attention_fn
 
             attn = context_attention_fn(cfg.context_axis, cfg.context_plan)
+        told.update(_prompt_end(cfg, lengths))
         attn = attn or dense_causal_attention
         out = attn(q, k, v, causal=True, **told)
         if return_kv:
             return o_proj(out), (k, v)
         return o_proj(out)
+
+
+def _prompt_end(cfg: TransformerConfig, lengths) -> dict:
+    """What a prefill that said where its prompts end (``lengths`` [B]
+    beside ``return_kv``) tells the model's own attention function: that
+    many rows and keys count (``ops/flash_attention`` then runs no tile of
+    the bucket's padding).  Nothing for the dense default, which computes
+    whole arrays whatever it is told."""
+    if lengths is None or cfg.attention_fn is None:
+        return {}
+    end = jnp.max(lengths)      # one bound a call: the longest row's
+    return {"q_len": end, "k_len": end}
 
 
 class LatentAttention(nn.Module):
@@ -625,7 +641,8 @@ class LatentAttention(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, x, positions, cache=None, return_kv=False):
+    def __call__(self, x, positions, cache=None, return_kv=False,
+                 lengths=None):
         cfg = self.cfg
         h, r = cfg.num_heads, cfg.kv_lora_rank
         nope, rot, d_v = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
@@ -691,7 +708,8 @@ class LatentAttention(nn.Module):
                 k_rope[:, :, None, :], kv.shape[:3] + (rot,))], axis=-1)
             q = jnp.concatenate([q_nope, q_rope], axis=-1)
         attn = cfg.attention_fn or dense_causal_attention
-        out = o_proj(attn(q, k, kv[..., nope:], causal=True, scale=scale))
+        out = o_proj(attn(q, k, kv[..., nope:], causal=True, scale=scale,
+                          **_prompt_end(cfg, lengths)))
         return (out, (c_kv, k_rope)) if return_kv else out
 
 
@@ -789,16 +807,22 @@ def eva_dense_attention(q, k, v, kbar, vbar, window: int, chunk: int,
 
 
 @functools.partial(jax.jit, static_argnames=("window", "chunk"))
-def eva_merged_attention(q, k, v, kbar, vbar, window: int, chunk: int):
+def eva_merged_attention(q, k, v, kbar, vbar, window: int, chunk: int,
+                         length=None):
     """:func:`eva_dense_attention`'s numbers (at the d^-1/2 scale) without
     its logits: a window at a time, the causal triangle of the window's own
     keys and the rectangle of the summaries of the windows behind it, each a
     partial attention of the flash forward kernel
     (``ops/flash_attention.flash_attention_with_lse``; the rectangle over
     all the summaries with the kernel's valid length at the window's
-    count), merged by their log-sum-exp.  ``S`` is whole windows.  Jitted,
-    so that a program's layers share one tracing of the two kernels
-    (PERF.md section 6, PR 41).  Forward only."""
+    count, where its sweep ends), merged by their log-sum-exp.  ``S`` is
+    whole windows.  ``length`` (a traced scalar may be given) is where the
+    prompt ends in a padded sequence: window ``w`` then tells both kernels
+    that ``clip(length - w * window, 0, window)`` of its rows count, a
+    window wholly past the prompt runs neither, and the rows past the
+    prompt come out 0.  Jitted, so that a program's layers share one
+    tracing of the two kernels (PERF.md section 6, PR 41).  Forward
+    only."""
     from horovod_tpu.ops.flash_attention import flash_attention_with_lse
 
     b, s, h, d = q.shape
@@ -811,12 +835,17 @@ def eva_merged_attention(q, k, v, kbar, vbar, window: int, chunk: int):
 
     def one(args):
         w, qw, kw, vw = args
-        own, own_lse = flash_attention_with_lse(qw, kw, vw, causal=True)
+        # how many of this window's rows (and own keys) are the prompt's
+        count = None if length is None \
+            else jnp.clip(length - w * window, 0, window)
+        own, own_lse = flash_attention_with_lse(
+            qw, kw, vw, causal=True, q_len=count, k_len=count)
         if nw == 1:
             return own.astype(q.dtype)
         # the summaries of windows 0 .. w - 1: the first w * window / chunk
         past, past_lse = flash_attention_with_lse(
-            qw, kbar, vbar, causal=False, k_len=w * (window // chunk))
+            qw, kbar, vbar, causal=False, q_len=count,
+            k_len=w * (window // chunk))
         lse = jnp.logaddexp(own_lse, past_lse)
         return (own * jnp.exp(own_lse - lse)[..., None]
                 + past * jnp.exp(past_lse - lse)[..., None]).astype(q.dtype)
@@ -940,8 +969,9 @@ class EvaAttention(nn.Module):
         with jax.named_scope(profiling.EVA_ATTN):
             if eva_attention_form(cfg, s) == "merged" \
                     and cfg.attention_scale is None:
-                out = eva_merged_attention(q, k, v, kbar, vbar, window=w,
-                                           chunk=c)
+                out = eva_merged_attention(
+                    q, k, v, kbar, vbar, window=w, chunk=c,
+                    length=None if lengths is None else jnp.max(lengths))
             else:
                 out = eva_dense_attention(q, k, v, kbar, vbar, w, c, scale)
         if not return_kv:
@@ -1074,8 +1104,9 @@ class Block(nn.Module):
         y = norm(f"{name}_norm")(x)
         kv = None
         if cache is not None or return_kv:
-            # a prefill's own length, for a mixer whose cache is not a row a
-            # position, is told by name, to that mixer alone
+            # where a prefill's prompts end is told by name, only by a
+            # caller that says it: the mixer's kernels then stop there, and
+            # one whose cache is not a row a position lays it out for it
             mixed, kv = mixer(y, positions, cache=cache, return_kv=return_kv,
                               **({} if lengths is None
                                  else {"lengths": lengths}))
@@ -1116,6 +1147,12 @@ class Transformer(nn.Module):
     * ``return_kv=True`` — a prefill pass: also return the per-layer
       rotary-embedded K and raw V as two stacked ``[L, B, S, H, D]``
       arrays, for writing into a slot of an :func:`init_kv_cache` buffer.
+      ``lengths`` ([B]) beside it says where each row's prompt ends in a
+      padded sequence: ``cfg.attention_fn``, where the model has one, is
+      then called with ``q_len`` and ``k_len`` (the longest row's), and
+      ``ops/flash_attention`` runs no tile of the padding; the rows past
+      a prompt's end come out of the kernel 0, not what the padding would
+      attend to.  The dense default is told nothing and computes it all.
     * ``kv_cache=(k, v)`` + ``lengths`` — one incremental decode step:
       ``tokens`` is ``[B, 1]`` (the last sampled token per slot),
       ``lengths`` ``[B]`` the position each slot is decoding at; returns
@@ -1205,10 +1242,11 @@ class Transformer(nn.Module):
             and not return_kv
         block_cls = nn.remat(Block) if remat_on else Block
         kvs = []
-        # where each row's prompt ends, beside return_kv: EVA attention alone
-        # reads it, and lays its cache block out for a decode step there
+        # where each row's prompt ends, beside return_kv: a mixer whose
+        # attention is a kernel stops it there, and EVA attention also lays
+        # its cache block out for a decode step there
         ends = {"lengths": jnp.asarray(lengths)} \
-            if return_kv and lengths is not None and cfg.eva else {}
+            if return_kv and lengths is not None else {}
         for i, kind in enumerate(kinds):
             block = block_cls(cfg, kind, i < cfg.first_dense_layers,
                               name=f"layer_{i}")

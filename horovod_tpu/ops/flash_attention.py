@@ -68,15 +68,22 @@ LOG2E = 1.4426950408889634  # log2(e): folded into the q scale so the
 LN2 = 0.6931471805599453
 
 
-def _sub_bounds(k_len, q_min, q_max, ks_min, sub_k, nsub, causal):
+def _sub_bounds(k_len, q_min, q_max, ks_min, sub_k, nsub, causal,
+                q_len=None):
     """The forward kernel's sub-tile split bounds: ``hi``
     is the causal sweep end (tiles past the diagonal contribute p == 0),
     ``interior_end`` the mask-free prefix (entirely below the diagonal and
-    inside the valid K range)."""
+    inside the valid K range).  A call that says how many of its rows and
+    keys count (``q_len`` given) also ends the sweep at the last sub-tile
+    that holds a counted key, and runs none for a q block that holds no
+    counted row."""
     if causal:
         hi = jnp.clip((q_max - ks_min) // sub_k + 1, 0, nsub)
     else:
         hi = nsub
+    if q_len is not None:
+        hi = jnp.minimum(hi, jnp.clip(-((ks_min - k_len) // sub_k), 0, nsub))
+        hi = jnp.where(q_min < q_len, hi, 0)
     valid_end = (k_len - ks_min) // sub_k
     if causal:
         interior_end = jnp.minimum((q_min - ks_min + 1) // sub_k, valid_end)
@@ -98,9 +105,9 @@ def _window_bounds(q_min, q_max, ks_min, sub_k, nsub, window):
 
 
 def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
-                  m_ref, l_ref, *, block_q: int, block_k: int, sub_k: int,
-                  num_k_blocks: int, causal: bool, scale: float,
-                  window: int | None = None):
+                  m_ref, l_ref, *qs_ref, block_q: int, block_k: int,
+                  sub_k: int, num_k_blocks: int, causal: bool, scale: float,
+                  window: int | None = None, bounded: bool = False):
     """One (batch·head, q-block, K-super-tile) program: online softmax.
 
     Two-level streaming: the grid's K axis moves (block_k, D) SUPER tiles
@@ -111,7 +118,30 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     independent of S.
 
     meta_ref (SMEM int32[3]): [q_offset, k_offset, k_len] — global position
-    offsets (sequence parallelism) and the unpadded K length.
+    offsets (sequence parallelism) and the unpadded K length.  ``bounded``
+    (a caller said how many of its rows or keys count: a serving prefill's
+    prompt in its padded bucket) makes it int32[4] with ``q_len`` last, the
+    position one past the last counted query row, and the kernel then runs
+    no sub-tile that holds no counted key, and none at all for a q block
+    whose rows are all at or past ``q_len``.  The meta is then prefetched
+    (a scalar-prefetch operand, in SMEM before the grid starts) so that the
+    K / V index map reads it too and hands a step that runs nothing the
+    tile of the step before (:func:`_kv_tile_run`): the tiles of the
+    bucket's padding, and in this call those past the diagonal and before
+    the band too, cost their grid steps, no copy and no arithmetic (a step
+    that only copied its 1024-row K and V tiles in took 1.5 us beside 5.4
+    for a computed one, v5e, keys of 192; PERF.md section 6, PR 45); and
+    the q tile is scaled once a q block, into a VMEM scratch of its own
+    (``qs_ref``), where the unbounded kernel scales it anew every step.
+    ``_init`` and ``_finish`` run all the same, so a skipped q block writes
+    o = 0 and lse = NEG_INF, and ``_finish`` writes the same for the rows at
+    and past ``q_len`` of the block the prompt ends in: finite, whatever lay
+    in the padded rows of q, because a prefill's padded rows go on through
+    the layers into the cache, where a decode step's dense products multiply
+    a probability of exactly 0 by whatever lies there.  Rows below ``q_len``
+    see the sub-tiles the unbounded kernel gives them, in the same order,
+    less only those whose every probability was exactly 0: the same numbers
+    to the bit.  Without ``bounded`` the kernel's text is what it was.
 
     The sub-tile loop is SPLIT: an interior prefix (entirely below the
     causal diagonal and inside the valid K range) runs a mask-free body —
@@ -131,22 +161,6 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     qi, ki = pl.program_id(1), pl.program_id(2)
     nsub = block_k // sub_k
 
-    @pl.when(ki == 0)
-    def _init():
-        m_ref[0] = jnp.full_like(m_ref[0], NEG_INF)
-        l_ref[0] = jnp.zeros_like(l_ref[0])
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q_min = meta_ref[0] + qi * block_q
-    q_max = q_min + block_q - 1
-    ks_min = meta_ref[1] + ki * block_k   # super-tile base position
-    # Sub-tile bounds (scalar arithmetic on SMEM values):
-    hi, interior_end = _sub_bounds(meta_ref[2], q_min, q_max, ks_min,
-                                   sub_k, nsub, causal)
-    if window is not None:
-        lo, int_start = _window_bounds(q_min, q_max, ks_min, sub_k, nsub,
-                                       window)
-
     # The s matmul runs on INPUT-dtype operands: under JAX's default TPU
     # matmul precision an f32×f32 dot already executes as a single bf16
     # MXU pass (measured — the dtype of the operands does not change the
@@ -155,14 +169,41 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     # with log2(e) — scores live in the log2 domain so the hot
     # exponentials are exp2, see LOG2E) with one rounding to the input
     # dtype (f32 inputs round-trip exactly).
-    q = (q_ref[0].astype(jnp.float32) * (scale * LOG2E)).astype(q_ref.dtype)
+    def scaled_q():
+        return (q_ref[0].astype(jnp.float32)
+                * (scale * LOG2E)).astype(q_ref.dtype)
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[0] = jnp.full_like(m_ref[0], NEG_INF)
+        l_ref[0] = jnp.zeros_like(l_ref[0])
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        if bounded:
+            # once a q block, into scratch: a step that runs no sub-tile
+            # then does no vector work at all
+            qs_ref[0][...] = scaled_q()
+
+    q_min = meta_ref[0] + qi * block_q
+    q_max = q_min + block_q - 1
+    ks_min = meta_ref[1] + ki * block_k   # super-tile base position
+    # Sub-tile bounds (scalar arithmetic on SMEM values):
+    hi, interior_end = _sub_bounds(meta_ref[2], q_min, q_max, ks_min,
+                                   sub_k, nsub, causal,
+                                   meta_ref[3] if bounded else None)
+    if window is not None:
+        lo, int_start = _window_bounds(q_min, q_max, ks_min, sub_k, nsub,
+                                       window)
+
+    # (the unbounded kernel scales its q tile anew every step)
+    q = None if bounded else scaled_q()
 
     def body(si, carry, masked):
         m, l = carry
+        qs = qs_ref[0][...] if bounded else q
         k = k_ref[0, pl.ds(si * sub_k, sub_k), :]         # [sk, D]
         v = v_ref[0, pl.ds(si * sub_k, sub_k), :]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            qs, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)           # [bq, sk]
         if masked:
             q_pos = (q_min + jax.lax.broadcasted_iota(
@@ -255,7 +296,15 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     def _finish():
         m = m_ref[0, 0, :][:, None]
         l = l_ref[0, 0, :][:, None]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        o = acc_ref[...] / jnp.maximum(l, 1e-30)
+        if bounded:
+            # the rows at and past q_len of the block the prompt ends in
+            # leave as a skipped block's do
+            counted = (q_min + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, 1), 0)) < meta_ref[3]
+            o = jnp.where(counted, o, 0.0)
+            l = jnp.where(counted, l, 0.0)
+        o_ref[0] = o.astype(o_ref.dtype)
         # log-sum-exp per query row (NEG_INF where a row attended to
         # nothing) — lets callers combine partial attentions exactly
         # (ring attention).  m carries log2-domain scores (LOG2E fold),
@@ -485,16 +534,43 @@ def _from_bh(x, b: int, s: int):
     return x[:, :s].reshape(b, -1, s, x.shape[-1]).transpose(0, 2, 1, 3)
 
 
-def _meta(q_offset, k_offset, s_k: int):
-    """The kernels' SMEM int32[3]: [q_offset, k_offset, k_len]."""
+def _meta(q_offset, k_offset, s_k, s_q=None):
+    """The kernels' SMEM int32[3]: [q_offset, k_offset, k_len]; with
+    ``s_q`` (the forward kernel told how many of its rows count) int32[4],
+    ``q_len`` last.  Both lengths leave as positions, the offsets added."""
     k_offset = jnp.asarray(k_offset, jnp.int32)
-    return jnp.stack([jnp.asarray(q_offset, jnp.int32), k_offset,
-                      k_offset + s_k])
+    meta = [jnp.asarray(q_offset, jnp.int32), k_offset, k_offset + s_k]
+    if s_q is not None:
+        meta.append(meta[0] + s_q)
+    return jnp.stack(meta)
+
+
+def _kv_tile_run(meta_ref, qi, ki, block_q, block_k, num_k_blocks, causal,
+                 window):
+    """The K / V super tile the bounded kernel's grid step (qi, ki) is
+    handed: ``ki`` held inside the tiles that q block runs a sub-tile of
+    (:func:`_sub_bounds`, :func:`_window_bounds`, a super tile at a time).
+    A step outside them runs nothing and is handed the tile of the step
+    before it, which the pipeline does not copy again: a tile that is not
+    computed is not fetched either, past the prompt, past the diagonal and
+    before the band alike.  Tile 0 for a q block that holds no counted
+    row."""
+    q_min = meta_ref[0] + qi * block_q
+    last = (meta_ref[2] - 1 - meta_ref[1]) // block_k   # holds key k_len - 1
+    if causal:
+        last = jnp.minimum(last, (q_min + block_q - 1 - meta_ref[1])
+                           // block_k)
+    last = jnp.clip(last, 0, num_k_blocks - 1)
+    first = 0
+    if window is not None:
+        first = jnp.clip((q_min - window + 1 - meta_ref[1]) // block_k,
+                         0, last)
+    return jnp.where(q_min < meta_ref[3], jnp.clip(ki, first, last), 0)
 
 
 def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
                 interpret, sub, out_dtype, scale=None, window=None,
-                k_len=None):
+                q_len=None, k_len=None):
     """The forward kernel on [B, S, H, D] inputs, everything it read and
     wrote left in the kernels' layout, padded to whole blocks:
     ``(qb, kb, vb, ob, lse_b)`` with ``ob`` [B·H, S_q_pad, D_v] in
@@ -502,8 +578,13 @@ def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
     replicated).  The backward reads all five as they are.  ``v`` may be
     narrower or wider than ``q`` and ``k`` (latent attention: keys of 192,
     values of 128): its width is the output's and the accumulator's.
-    ``k_len`` (may be traced) is how many of the keys count, where that is
-    fewer than all: the rest lie behind the padding mask."""
+    ``q_len`` and ``k_len`` (may be traced) are how many of the rows and of
+    the keys count, where that is fewer than all.  Given either, the kernel
+    is the bounded one (:func:`_flash_kernel`): the keys past ``k_len`` lie
+    behind the padding mask and their sub-tiles are not run, the rows at and
+    past ``q_len`` come out o = 0, lse = NEG_INF and their q blocks run no
+    sub-tile.  Given neither, the call is what it was before they
+    existed."""
     d, d_v = q.shape[-1], v.shape[-1]
     s_k = k.shape[1]
     block_k, sub_k = _sub_fit(block_k, sub)
@@ -512,44 +593,65 @@ def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
     vb = _pad_to(_to_bh(v), 1, block_k)
     num_q_blocks = qb.shape[1] // block_q
     num_k_blocks = kb.shape[1] // block_k
-    stat_block = pl.BlockSpec((1, 8, block_q), lambda bh, qi, ki: (bh, 0, qi))
+    bounded = q_len is not None or k_len is not None
+    if bounded:
+        meta = _meta(q_offset, k_offset, s_k if k_len is None else k_len,
+                     q.shape[1] if q_len is None else q_len)
+    else:
+        meta = _meta(q_offset, k_offset, s_k)
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k, sub_k=sub_k,
         num_k_blocks=num_k_blocks, causal=causal,
-        scale=d ** -0.5 if scale is None else scale, window=window)
-    ob, lse_b = pl.pallas_call(
-        kernel,
+        scale=d ** -0.5 if scale is None else scale, window=window,
+        bounded=bounded)
+
+    # The index maps see the meta only in the bounded call, where it is
+    # prefetched (``*meta`` is then its one ref, else nothing).
+    def q_tile(bh, qi, ki, *meta):
+        return bh, qi, 0
+
+    def kv_tile(bh, qi, ki, *meta):
+        if meta:
+            ki = _kv_tile_run(meta[0], qi, ki, block_q, block_k,
+                              num_k_blocks, causal, window)
+        return bh, ki, 0
+
+    tiles = dict(
         grid=(qb.shape[0], num_q_blocks, num_k_blocks),
-        in_specs=[
-            pl.BlockSpec((3,), lambda bh, qi, ki: (0,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block_q, d),
-                         lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d),
-                         lambda bh, qi, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d_v),
-                         lambda bh, qi, ki: (bh, ki, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, block_q, d), q_tile),
+                  pl.BlockSpec((1, block_k, d), kv_tile),
+                  pl.BlockSpec((1, block_k, d_v), kv_tile)],
         out_specs=(
-            pl.BlockSpec((1, block_q, d_v),
-                         lambda bh, qi, ki: (bh, qi, 0)),
-            stat_block,
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct(qb.shape[:2] + (d_v,), out_dtype),
-            jax.ShapeDtypeStruct((qb.shape[0], 8, qb.shape[1]), jnp.float32),
-        ),
+            pl.BlockSpec((1, block_q, d_v), q_tile),
+            pl.BlockSpec((1, 8, block_q),
+                         lambda bh, qi, ki, *meta: (bh, 0, qi))),
         scratch_shapes=[
             pltpu.VMEM((block_q, d_v), jnp.float32),    # acc
             pltpu.VMEM((1, 8, block_q), jnp.float32),   # m carry
             pltpu.VMEM((1, 8, block_q), jnp.float32),   # l carry
-        ],
+        ])
+    if bounded:
+        # q, scaled: inside what _vmem_estimate_bytes prices for the dO
+        # tile, which no forward streams
+        tiles["scratch_shapes"].append(pltpu.VMEM((block_q, d), q.dtype))
+        tiles = {"grid_spec": pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, **tiles)}
+    else:
+        tiles["in_specs"].insert(0, pl.BlockSpec(
+            meta.shape, lambda bh, qi, ki: (0,), memory_space=pltpu.SMEM))
+    ob, lse_b = pl.pallas_call(
+        kernel,
+        out_shape=(
+            jax.ShapeDtypeStruct(qb.shape[:2] + (d_v,), out_dtype),
+            jax.ShapeDtypeStruct((qb.shape[0], 8, qb.shape[1]), jnp.float32),
+        ),
         # outer axes parallel, the innermost the sequential K sweep
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=profiling.FLASH_FWD,
-    )(_meta(q_offset, k_offset, s_k if k_len is None else k_len), qb, kb, vb)
+        **tiles,
+    )(meta, qb, kb, vb)
     return qb, kb, vb, ob, lse_b
 
 
@@ -855,31 +957,34 @@ def flash_attention_backward(q, k, v, dout, lse, delta, causal,
 @jax.tree_util.register_static
 @dataclasses.dataclass(frozen=True)
 class _Lengths:
-    """The unpadded lengths, carried beside the padded residuals."""
+    """The unpadded lengths, carried beside the padded residuals, and
+    whether the forward was told how many of them count."""
     s_q: int
     s_k: int
+    bounded: bool = False
 
 
 @functools.partial(jax.custom_vjp,
                    nondiff_argnums=(3, 6, 7, 8, 9, 10, 11))
 def _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
-           interpret, scale, window):
+           interpret, scale, window, q_len=None, k_len=None):
     """One device, one call over the whole sequence: nothing sums its
     results again, so the kernels write the compute dtype themselves."""
     return _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                      sub, interpret, scale, window)[0]
+                      sub, interpret, scale, window, q_len, k_len)[0]
 
 
 def _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
-               interpret, scale, window):
+               interpret, scale, window, q_len=None, k_len=None):
     # The residuals stay as the forward call read and wrote them, in the
     # layout the backward kernel reads: nothing is laid out twice.
     qb, kb, vb, ob, lse_b = _forward_bh(
         q, k, v, causal, q_offset, k_offset, block_q, block_k, interpret,
-        sub, q.dtype, scale, window)
+        sub, q.dtype, scale, window, q_len, k_len)
     return _from_bh(ob, q.shape[0], q.shape[1]), (
         qb, kb, vb, ob, lse_b, q_offset, k_offset,
-        _Lengths(q.shape[1], k.shape[1]))
+        _Lengths(q.shape[1], k.shape[1],
+                 q_len is not None or k_len is not None))
 
 
 def _flash_bwd(causal, block_q, block_k, sub, interpret, scale, window, res,
@@ -891,6 +996,13 @@ def _flash_bwd(causal, block_q, block_k, sub, interpret, scale, window, res,
             f"prefill); the backward kernel knows the causal mask only. "
             f"Differentiate dense_causal_attention(window=...) instead")
     qb, kb, vb, ob, lse_b, q_offset, k_offset, lengths = res
+    if lengths.bounded:
+        raise NotImplementedError(
+            "flash_attention(q_len=, k_len=) has no backward: how many rows "
+            "and keys count is the forward kernel's to know alone (a serving "
+            "prefill's prompt in its padded bucket); the backward kernel "
+            "would read lse = NEG_INF in the rows past q_len as a row that "
+            "attended.  Differentiate the call without them")
     _need_equal_widths(kb, vb, "flash_attention's backward")
     b = g.shape[0]
     # Only the incoming cotangent is laid out here.
@@ -909,7 +1021,8 @@ def _flash_bwd(causal, block_q, block_k, sub, interpret, scale, window, res,
     # barrier XLA:TPU moves a consumer's float32 cast (rope's backward)
     # ahead of the change of layout and copies the f32 array, twice the
     # bytes (read off the compiled text of an Attention layer, PR 31).
-    return jax.lax.optimization_barrier(grads) + (None, None)
+    # no cotangent for the two offsets and the two lengths
+    return jax.lax.optimization_barrier(grads) + (None,) * 4
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -931,8 +1044,21 @@ def _default_block_k(s_k: int, d: int) -> int:
 def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
                     block_q: int = 1024, block_k: int | None = None,
                     sub: int = 1024, interpret: bool | None = None,
-                    scale: float | None = None, window: int | None = None):
+                    scale: float | None = None, window: int | None = None,
+                    q_len=None, k_len=None):
     """Fused attention over [B, S, H, D] tensors.
+
+    ``q_len`` and ``k_len`` (traced scalars may be given) count the leading
+    rows of ``q`` and the leading keys that count, for a caller whose
+    programs share one shape and differ in how much of it is filled: a
+    serving prefill's prompt in its padded bucket passes its length as
+    both.  The forward kernel then runs no sub-tile that holds no counted
+    key and none for a q block past ``q_len``; the rows at and past
+    ``q_len`` come out 0 (finite whatever the padding held), the rows below
+    it to the bit what the call without the lengths gives them, where no
+    key past ``k_len`` was theirs to see.  Forward only: differentiating
+    such a call raises ``NotImplementedError``.  A call that names neither
+    is, to its jaxpr, the call from before they existed.
 
     ``window`` (causal only) is a sliding window: query ``i`` sees keys
     ``i - window < j <= i``.  The forward kernel skips the sub-tiles that lie
@@ -999,19 +1125,23 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     k, v = repeat_kv_heads(k, q.shape[2]), repeat_kv_heads(v, q.shape[2])
     return _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k,
                   sub, interpret, None if scale is None else float(scale),
-                  None if window is None else int(window))
+                  None if window is None else int(window), q_len, k_len)
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
                              k_offset=0, block_q: int = 1024,
                              block_k: int | None = None, sub: int = 1024,
-                             interpret: bool | None = None, k_len=None):
+                             interpret: bool | None = None, q_len=None,
+                             k_len=None):
     """Forward-only fused attention returning (out, lse): a PARTIAL
-    attention, for a caller that merges several of them.  ``k_len`` (a
-    traced scalar may be given) counts the leading keys that are seen, for
-    a caller whose programs share one shape and differ in how much of ``k``
-    is filled (EVA attention's summaries, a window at a time); a row that
-    sees no key has lse NEG_INF and out 0.
+    attention, for a caller that merges several of them.  ``q_len`` and
+    ``k_len`` (traced scalars may be given) count the leading rows that
+    count and the leading keys that are seen, for a caller whose programs
+    share one shape and differ in how much of it is filled (EVA attention's
+    windows and summaries, a window at a time): the sub-tiles past
+    ``k_len`` and the q blocks past ``q_len`` are not run
+    (:func:`flash_attention`); a row that sees no key, and every row at or
+    past ``q_len``, has lse NEG_INF and out 0.
 
     ``lse[b, s, h] = logsumexp_k(q·kᵀ·scale)`` (NEG_INF for rows that
     attended to nothing) — the combiner state ring attention needs to merge
@@ -1035,7 +1165,7 @@ def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
     b, s_q = q.shape[:2]
     *_, ob, lse_b = _forward_bh(q, k, v, causal, q_offset, k_offset, block_q,
                                 block_k, interpret, sub, jnp.float32,
-                                k_len=k_len)
+                                q_len=q_len, k_len=k_len)
     # [B·H, 8, S_pad] (sublane-replicated) → [B, S, H]
     lse = lse_b[:, 0, :s_q].reshape(b, -1, s_q).transpose(0, 2, 1)
     return _from_bh(ob, b, s_q), lse
@@ -1045,8 +1175,17 @@ def make_flash_attention(block_q: int = 1024, block_k: int | None = None,
                          sub: int = 1024):
     """Adapter producing a ``TransformerConfig.attention_fn``.  block_k
     defaults per-call to min(S, 2048) at d<=128 (_default_block_k)."""
-    def attn(q, k, v, causal=True, scale=None, window=None):
+    def attn(q, k, v, causal=True, scale=None, window=None, q_len=None,
+             k_len=None):
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, sub=sub, scale=scale,
-                               window=window)
+                               window=window, q_len=q_len, k_len=k_len)
     return attn
+
+
+def rows_worked(q_len: int, s_q: int, block_q: int = 1024) -> int:
+    """The query rows the forward kernel works for a call of ``s_q`` rows
+    told ``q_len`` of them count: the q blocks that hold a counted row,
+    whole (``block_q`` as the entry points clamp it to the sequence)."""
+    block_q = min(block_q, max(s_q, 1))
+    return min(-(-q_len // block_q) * block_q, s_q)

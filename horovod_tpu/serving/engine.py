@@ -383,6 +383,22 @@ class TransformerBackend:
         return ("flash" if logits_bytes > self.FLASH_PREFILL_LOGITS_BYTES
                 else "dense")
 
+    def prefill_attn_rows(self, bucket: int, length: int) -> int | None:
+        """The query rows a prefill's attention kernels are asked to work
+        for a prompt of ``length`` in ``bucket``: the kernel's q blocks up
+        to the one the prompt ends in, whole (the rest of the bucket's run
+        no tile); None where no kernel runs (the dense forms, a model's own
+        attention function)."""
+        if self.prefill_attention(bucket) not in ("flash", "merged"):
+            return None
+        from horovod_tpu.ops.flash_attention import rows_worked
+
+        if self.eva:    # a window a call: whole ones, then the prompt's last
+            whole, rest = divmod(int(length), self._model_cfg.eva_window)
+            return whole * self._model_cfg.eva_window + rows_worked(
+                rest, self._model_cfg.eva_window)
+        return rows_worked(int(length), int(bucket))
+
     def prefill_chunks(self, bucket: int) -> int:
         """In how many pieces a prefill of ``bucket`` positions runs its
         feed-forward layers (``TransformerConfig.feed_forward_chunk``)."""
@@ -446,12 +462,16 @@ class TransformerBackend:
         chunked = self._model_cfg.feed_forward_chunk is not None
         if chunked:
             told["logits_at"] = jnp.reshape(length - 1, (1,))
+        if self.prefill_attention(padded.shape[1]) != "own":
+            # where the prompt ends: a kernel stops there and works no tile
+            # of the bucket's padding (a model's own attention function is
+            # not known to take a length; the dense form ignores it)
+            told["lengths"] = jnp.reshape(length, (1,))
         if self.eva:
             # the ring is laid out for a decode step at length, and a
             # layer's ring and summaries, a slot's whole extent, go into the
             # pool as the layer ends
-            told.update(lengths=jnp.reshape(length, (1,)),
-                        kv_into=(kk, vv, slot))
+            told["kv_into"] = (kk, vv, slot)
         (logits, (pk, pv)), pairs = self._apply(
             self._prefill_model(padded.shape[1]), params, padded,
             return_kv=True, **told)
@@ -934,6 +954,12 @@ class ServingEngine:
             chunks = pieces(bucket) if pieces else 1
             if chunks > 1:
                 attn["chunks"] = chunks
+            # ... and how many of the bucket's query rows its attention
+            # kernels work, where a kernel runs and stops at the prompt
+            worked = getattr(self.backend, "prefill_attn_rows", None)
+            rows = worked(bucket, len(suffix)) if worked else None
+            if rows is not None:
+                attn["attn_rows"] = rows
             with profiling.span(
                     profiling.SRV_PREFILL, cause=req._span.id, rid=req.rid,
                     bucket=bucket, length=len(suffix),
@@ -1169,7 +1195,10 @@ class ServingEngine:
         however long the process serves).  Where a backend chose its
         prefill's attention by the bucket, ``hvd_srv_prefill`` also has
         ``attn``: per form (``"flash"``, ``"dense"``) the ``calls`` and the
-        ``prompt_tokens`` they prefilled.  Where the model's feed-forward is
+        ``prompt_tokens`` they prefilled, and, where its kernels stop at the
+        prompt's own length, the ``bucket_rows`` the calls padded to and the
+        ``attn_rows`` the kernels worked of them (one less their ratio is
+        the share of q rows skipped).  Where the model's feed-forward is
         sparse, ``hvd_srv_prefill`` and ``hvd_srv_decode`` have ``moe``: the
         ``rows`` the expert layers visited, the ``held_pairs`` they visited
         them for, and ``rows_per_held_pair`` (1 would waste nothing)."""
@@ -1182,6 +1211,11 @@ class ServingEngine:
                                          {"calls": 0, "prompt_tokens": 0})
                 row["calls"] += 1
                 row["prompt_tokens"] += r.fields["length"]
+                if "attn_rows" in r.fields:
+                    row["bucket_rows"] = row.get("bucket_rows", 0) \
+                        + r.fields["bucket"]
+                    row["attn_rows"] = row.get("attn_rows", 0) \
+                        + r.fields["attn_rows"]
         if by_attn:
             out[profiling.SRV_PREFILL]["attn"] = by_attn
         for name in (profiling.SRV_PREFILL, profiling.SRV_DECODE):

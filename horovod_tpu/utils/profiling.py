@@ -167,7 +167,9 @@ class Record(typing.NamedTuple):
     ``fields`` the counts of the boundary (``bucket``, ``length``,
     ``slots``, ``live_tokens``, ``rids``, ``bytes``; of a sparse model's
     prefill or decode call ``moe_rows`` and ``moe_held``: the rows its
-    expert layers visited and the held pairs they visited them for)."""
+    expert layers visited and the held pairs they visited them for; of a
+    prefill whose attention is a kernel ``attn_rows``: the query rows the
+    kernel was asked to work, whole q blocks up to the prompt's end)."""
     name: str
     start: float
     end: float

@@ -683,8 +683,10 @@ def test_the_prefill_span_carries_attn_and_the_summary_counts_by_it(
                                    (32, 20, "flash"), (16, 4, "dense")]
     row = mixed.span_summary()[profiling.SRV_PREFILL]
     assert row["count"] == 4
+    # a bucket of 32 is one q block of the kernel's: worked whole (PR 45)
     assert row["attn"] == {"dense": {"calls": 2, "prompt_tokens": 13},
-                           "flash": {"calls": 2, "prompt_tokens": 47}}
+                           "flash": {"calls": 2, "prompt_tokens": 47,
+                                     "bucket_rows": 64, "attn_rows": 64}}
 
 
 @pytest.mark.parametrize("kind", ["stub", "paged"])

@@ -303,6 +303,41 @@ def test_grouped_flash_attention_at_head_size_64_compiles_for_the_chip(
         "bf16[1,8192,8,64]") == 2
 
 
+# the forward told where a prompt ends (PR 45), at the shapes the four served
+# cells' longest buckets hand it: (S, heads, KV heads, key width, value
+# width, what else the call is told)
+@pytest.mark.parametrize("s,heads,kv_heads,d,d_v,told", [
+    (4096, 16, 16, 128, 128, {}),                       # dsc1p3b-code-0.8knee
+    (8192, 128, 8, 128, 128, {"window": 4096}),         # cmdaplus, sliding
+    (16384, 64, 64, 192, 128, {"scale": 0.1147}),       # axk1, expanded MLA
+    (2048, 32, 32, 128, 128, {"causal": False}),        # evabyte's rectangle
+], ids=["dense_mha", "banded_gqa", "keys_192_values_128", "rectangle"])
+def test_the_bounded_flash_forward_compiles_for_the_chip(
+        one_chip_mesh, monkeypatch, s, heads, kv_heads, d, d_v, told):
+    """The chip's compiler takes the forward kernel with ``q_len`` and
+    ``k_len`` traced: the meta prefetched into SMEM for the K / V index map,
+    the scaled q tile in VMEM scratch, one kernel and no backward."""
+    import importlib
+
+    from horovod_tpu.utils import profiling
+    fa = importlib.import_module("horovod_tpu.ops.flash_attention")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    shape = lambda h, w: jax.ShapeDtypeStruct(  # noqa: E731
+        (1, s, h, w), jnp.bfloat16, sharding=one_chip)
+    length = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    entry = fa.flash_attention if told.get("causal", True) \
+        else fa.flash_attention_with_lse
+    compiled = jax.jit(lambda q, k, v, n: entry(
+        q, k, v, q_len=n, k_len=n, **told)).lower(
+        shape(heads, d), shape(kv_heads, d), shape(kv_heads, d_v),
+        length).compile()
+    kernels = [line for line in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 1 and profiling.FLASH_FWD in kernels[0]
+
+
 # the two served shapes (heads, KV heads, layer types, window, slots, S) at
 # a sixth of the dense model's depth and the sparse one's one period, and
 # how many copies of one layer's view XLA may make: none where the products
