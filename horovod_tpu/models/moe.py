@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from flax.traverse_util import flatten_dict
 from jax import lax
 
+from horovod_tpu.models.transformer import _over_rows, row_blocks
 from horovod_tpu.ops.token_sum import add_rows_by_token
 from horovod_tpu.parallel.common import shard_init_rng
 from horovod_tpu.parallel.expert import expert_parallel_moe
@@ -311,11 +312,32 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
         sw_down = m.param("shared_down", lecun(), (n_shared * f, d),
                           m.param_dtype)
     t = b * s
+    # A caller that says which positions hold a token, over several row
+    # blocks of them (row_blocks: a served prefill's bucket or chunk; never
+    # training, which names none, nor a decode step): the position-wise
+    # parts of the layer, its router and its shared experts, run over the
+    # row blocks up to the last position that holds one
+    # (transformer._over_rows: one body in a loop), and the rows of the
+    # blocks past it are 0.  The routed experts visit the held pairs of the
+    # positions that hold a token whatever this does.  Not where the caller
+    # collects the losses: they read every position's probabilities.
+    live = None
+    if valid is not None and row_blocks(s) \
+            and not m.is_mutable_collection(MOE_LOSSES):
+        live = jnp.max(jnp.where(valid, jnp.arange(1, s + 1), 0))
 
-    # Everything the layer does is under one of five scopes
-    # (utils/profiling.py), so a trace's time in them is the layer's.
-    with jax.named_scope(profiling.MOE_ROUTE):
-        tokens = x.reshape(t, d).astype(m.dtype)
+    def by_rows(fn, tokens):
+        """``fn(tokens)`` for a position-wise ``fn`` [T', D] -> a tree of
+        [T', ...], a row block of ``x`` at a time up to ``live``."""
+        if live is None:
+            return fn(tokens)
+        out = _over_rows(
+            lambda x: jax.tree.map(
+                lambda y: y.reshape(b, -1, *y.shape[1:]),
+                fn(x.reshape(-1, d).astype(m.dtype))), live, x)
+        return jax.tree.map(lambda y: y.reshape(t, *y.shape[2:]), out)
+
+    def route(tokens):
         # in float32 whatever the compute dtype: a pick is a comparison
         logits = jnp.dot(tokens.astype(jnp.float32),
                          router_w.astype(jnp.float32),
@@ -327,6 +349,15 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
             gates = gates / gates.sum(axis=-1, keepdims=True)
         if m.routed_scale != 1.0:
             gates = gates * m.routed_scale
+        # the losses' operands leave no loop: nobody reads them there
+        return (picks, gates) if live is not None \
+            else (picks, gates, logits, probs)
+
+    # Everything the layer does is under one of five scopes
+    # (utils/profiling.py), so a trace's time in them is the layer's.
+    with jax.named_scope(profiling.MOE_ROUTE):
+        tokens = x.reshape(t, d).astype(m.dtype)
+        picks, gates, *every = by_rows(route, tokens)
         everything = held == e and valid is None
         if everything:
             local = picks
@@ -358,7 +389,8 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
             m.sow(MOE_STATS, "expert_pairs", pairs)
             m.sow(MOE_STATS, "rows_visited", rows_visited)
             m.sow(MOE_STATS, "picks", picks.reshape(b, s, k))
-            if m.selection == "softmax":
+            if m.selection == "softmax" and live is None:
+                logits, probs = every
                 # E * sum_e f_e P_e: f_e the share of the pairs on expert e
                 # (a constant to the gradient), P_e e's mean probability
                 m.sow(MOE_LOSSES, "load_balance", e * jnp.sum(
@@ -399,9 +431,12 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
             if not n_shared:
                 return out.reshape(b, s, d).astype(x.dtype)
 
-    with jax.named_scope(profiling.MOE_SHARED):
+    def shared_sum(tokens):
         act = (nn.silu(tokens @ sw_gate.astype(m.dtype))
                * (tokens @ sw_up.astype(m.dtype)))
-        shared = act @ sw_down.astype(m.dtype)   # the n experts' sum
+        return act @ sw_down.astype(m.dtype)     # the n experts' sum
+
+    with jax.named_scope(profiling.MOE_SHARED):
+        shared = by_rows(shared_sum, tokens)
         out = out + shared.astype(jnp.float32) * (1.0 / n_shared)
         return out.reshape(b, s, d).astype(x.dtype)

@@ -168,6 +168,8 @@ class TransformerConfig:
     # chunk of this many positions at a time: the rows to and from the
     # experts and a wide dense layer's gate and up are then a chunk's, not
     # the sequence's.  Attention sees the whole sequence.  None: in one piece.
+    # (A prefill told its prompts' lengths runs a dense feed-forward a row
+    # block at a time up to the prompt's end instead: Block, _over_rows.)
     feed_forward_chunk: int | None = None
     # EVA attention ("eva_attention" layers, :class:`EvaAttention`): a query
     # sees the positions of its own window of eva_window exactly and, for
@@ -306,19 +308,44 @@ class LayerNorm(nn.Module):
 NORMS = {"rms": RMSNorm, "layer": LayerNorm}
 
 
-def make_norm(cfg: "TransformerConfig", name: str):
+def _as_is(module):
+    return module
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _applied(free, told, variables, *args, **kwargs):
+    return free.apply(variables, *args, **dict(told), **kwargs)
+
+
+def _unbound(module, nameless: bool = False, **told):
+    """A bound flax ``module`` whose parameters are made, as a function of
+    its inputs alone (and of ``told``, what else it is always called with):
+    for the body of a ``lax`` loop, where flax lets no bound module be
+    called.  The same parameters under the same names.  Applied under one
+    ``jax.jit`` with the module static, so that a program's layers (alike
+    but for their parameters, and ``nameless`` for their names) share one
+    tracing of it: a served program is traced and lowered on every start
+    (PERF.md section 6, PR 41 and PR 47)."""
+    free = module.clone(parent=None, **({"name": None} if nameless else {}))
+    return functools.partial(_applied, free, tuple(sorted(told.items())),
+                             module.variables)
+
+
+def make_norm(cfg: "TransformerConfig", name: str, made=_as_is):
     """The model's norm (``cfg.norm``) under ``name``.  Over a residual
     stream of its own dtype (``cfg.residual_dtype``) the statistics are
-    taken in that dtype and the result handed on in the compute dtype."""
+    taken in that dtype and the result handed on in the compute dtype.
+    ``made`` is what the module is called through (:func:`_unbound`, for a
+    norm inside a loop's body)."""
     try:
         cls = NORMS[cfg.norm]
     except KeyError:
         raise ValueError(f"norm {cfg.norm!r}; models/transformer.py has "
                          f"{sorted(NORMS)}") from None
     told = {"offset": cfg.norm_offset} if cfg.norm_offset else {}
-    norm = cls(dtype=cfg.residual_dtype or cfg.dtype,
-               param_dtype=cfg.param_dtype, epsilon=cfg.norm_eps, name=name,
-               **told)
+    norm = made(cls(dtype=cfg.residual_dtype or cfg.dtype,
+                    param_dtype=cfg.param_dtype, epsilon=cfg.norm_eps,
+                    name=name, **told))
     if cfg.residual_dtype is None:
         return norm
     return lambda x: norm(x).astype(cfg.dtype)
@@ -543,33 +570,43 @@ class Attention(nn.Module):
                 raise ValueError("a sliding_attention layer needs "
                                  "TransformerConfig.sliding_window")
             window = int(cfg.sliding_window)
-        proj = lambda name, heads: nn.DenseGeneral(  # noqa: E731
+        # a served prefill that said where its prompt ends runs the two
+        # position-wise sides of the layer over the prompt's row blocks
+        rows, made = _prompt_rows(x, cache, return_kv, lengths)
+        proj = {name: made(nn.DenseGeneral(
             (heads, cfg.head_dim), use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name=name)
+            param_dtype=cfg.param_dtype, name=name))
+            for name, heads in (("q", cfg.num_heads), ("k", cfg.kv_heads),
+                                ("v", cfg.kv_heads))}
+        # over the whole projection, all heads as one
+        qk_norm = {name: made(RMSNorm(
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            epsilon=cfg.norm_eps, name=f"{name}_norm"))
+            for name in ("q", "k")} if cfg.qk_norm else {}
 
-        def rotated(name, heads):
-            y = proj(name, heads)(x)
-            if cfg.qk_norm:     # over the whole projection, all heads as one
-                y = RMSNorm(
-                    dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                    epsilon=cfg.norm_eps, name=f"{name}_norm")(
-                    y.reshape(*y.shape[:-2], -1)).reshape(y.shape)
-            if not rotary:
-                return y
-            return rope(y, positions, cfg.rope_theta,
-                        interleaved=cfg.rope_interleaved)
+        def heads(x, positions):
+            def rotated(name):
+                y = proj[name](x)
+                if cfg.qk_norm:
+                    y = qk_norm[name](
+                        y.reshape(*y.shape[:-2], -1)).reshape(y.shape)
+                if not rotary:
+                    return y
+                return rope(y, positions, cfg.rope_theta,
+                            interleaved=cfg.rope_interleaved)
 
-        q, k = rotated("q", cfg.num_heads), rotated("k", cfg.kv_heads)
-        v = proj("v", cfg.kv_heads)(x)
+            return rotated("q"), rotated("k"), proj["v"](x)
+
+        q, k, v = _over_rows(heads, rows, x, positions)
         # a caller's softmax scale and a layer's window go to the attention
         # function by name; without them the call is what it always was
         told = ({} if cfg.attention_scale is None
                 else {"scale": cfg.attention_scale})
         if window is not None:
             told["window"] = window
-        o_proj = nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), use_bias=False,
-                                 dtype=cfg.dtype,
-                                 param_dtype=cfg.param_dtype, name="o")
+        o_proj = made(nn.DenseGeneral(
+            cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="o"))
         if cache is not None:
             # Incremental decode: x is [B, S_q, E]; write the block's K/V
             # into the whole pool at (this layer, slot, its current length),
@@ -595,10 +632,8 @@ class Attention(nn.Module):
             attn = context_attention_fn(cfg.context_axis, cfg.context_plan)
         told.update(_prompt_end(cfg, lengths))
         attn = attn or dense_causal_attention
-        out = attn(q, k, v, causal=True, **told)
-        if return_kv:
-            return o_proj(out), (k, v)
-        return o_proj(out)
+        out = _over_rows(o_proj, rows, attn(q, k, v, causal=True, **told))
+        return (out, (k, v)) if return_kv else out
 
 
 def _prompt_end(cfg: TransformerConfig, lengths) -> dict:
@@ -652,12 +687,15 @@ class LatentAttention(nn.Module):
                 "a latent_attention layer needs TransformerConfig's "
                 "q_lora_rank, kv_lora_rank, qk_nope_head_dim, "
                 "qk_rope_head_dim and v_head_dim")
-        dense = functools.partial(nn.DenseGeneral, use_bias=False,
-                                  dtype=cfg.dtype,
-                                  param_dtype=cfg.param_dtype)
-        norm = functools.partial(RMSNorm, dtype=cfg.dtype,
-                                 param_dtype=cfg.param_dtype,
-                                 epsilon=cfg.norm_eps)
+        # as Attention: a served prefill's two position-wise sides run over
+        # the prompt's row blocks
+        rows, made = _prompt_rows(x, cache, return_kv, lengths)
+        dense = lambda *a, **kw: made(nn.DenseGeneral(  # noqa: E731
+            *a, use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, **kw))
+        norm = lambda name: made(RMSNorm(  # noqa: E731
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            epsilon=cfg.norm_eps, name=name))
         turn = functools.partial(rope, theta=cfg.rope_theta,
                                  interleaved=cfg.rope_interleaved,
                                  yarn=cfg.rope_yarn)
@@ -671,18 +709,31 @@ class LatentAttention(nn.Module):
                                                   out_axis=(1, 2)),
             (r, h, nope + d_v), cfg.param_dtype).astype(cfg.dtype)
         o_proj = dense(cfg.embed_dim, axis=(-2, -1), name="o")
+        q_down, q_norm = dense(cfg.q_lora_rank, name="q_down"), norm("q_norm")
+        kv_down, kv_norm = dense(r + rot, name="kv_down"), norm("kv_norm")
+        q_up = dense((h, nope + rot), name="q_up")
 
-        with jax.named_scope(profiling.MLA_DOWN):
-            c_q = norm(name="q_norm")(dense(cfg.q_lora_rank,
-                                            name="q_down")(x))
-            down = dense(r + rot, name="kv_down")(x)
-            c_kv = norm(name="kv_norm")(down[..., :r])
-            k_rope = turn(down[..., None, r:], positions)[..., 0, :]
-        with jax.named_scope(profiling.MLA_UP):
-            q = dense((h, nope + rot), name="q_up")(c_q)
-            q_nope, q_rope = q[..., :nope], turn(q[..., nope:], positions)
+        def down_and_up(x, positions):
+            with jax.named_scope(profiling.MLA_DOWN):
+                c_q = q_norm(q_down(x))
+                down = kv_down(x)
+                c_kv = kv_norm(down[..., :r])
+                k_rope = turn(down[..., None, r:], positions)[..., 0, :]
+            with jax.named_scope(profiling.MLA_UP):
+                q = q_up(c_q)
+                return (c_kv, k_rope, q[..., :nope],
+                        turn(q[..., nope:], positions))
+
+        def expanded(c_kv, k_rope, q_nope, q_rope):
+            with jax.named_scope(profiling.MLA_UP):
+                kv = jnp.einsum("bsr,rhd->bshd", c_kv, w_ukv)
+                k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
+                    k_rope[:, :, None, :], kv.shape[:3] + (rot,))], axis=-1)
+                return (jnp.concatenate([q_nope, q_rope], axis=-1), k,
+                        kv[..., nope:])
 
         if cache is not None:
+            c_kv, k_rope, q_nope, q_rope = down_and_up(x, positions)
             latents, rope_keys, lengths, layer = cache
             with jax.named_scope(profiling.MLA_DOWN):
                 latents = write_kv_block(latents, c_kv, layer, lengths)
@@ -702,14 +753,15 @@ class LatentAttention(nn.Module):
             raise NotImplementedError(
                 "ring / zigzag attention over a context axis takes values "
                 "as wide as the keys; latent attention's are not")
-        with jax.named_scope(profiling.MLA_UP):
-            kv = jnp.einsum("bsr,rhd->bshd", c_kv, w_ukv)
-            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-                k_rope[:, :, None, :], kv.shape[:3] + (rot,))], axis=-1)
-            q = jnp.concatenate([q_nope, q_rope], axis=-1)
+
+        def heads(x, positions):
+            c_kv, k_rope, q_nope, q_rope = down_and_up(x, positions)
+            return (*expanded(c_kv, k_rope, q_nope, q_rope), c_kv, k_rope)
+
+        q, k, v, c_kv, k_rope = _over_rows(heads, rows, x, positions)
         attn = cfg.attention_fn or dense_causal_attention
-        out = o_proj(attn(q, k, kv[..., nope:], causal=True, scale=scale,
-                          **_prompt_end(cfg, lengths)))
+        out = _over_rows(o_proj, rows, attn(
+            q, k, v, causal=True, scale=scale, **_prompt_end(cfg, lengths)))
         return (out, (c_kv, k_rope)) if return_kv else out
 
 
@@ -918,18 +970,23 @@ class EvaAttention(nn.Module):
                 "a multiple of eva_chunk, and as many KV heads as heads")
         scale = d ** -0.5 if cfg.attention_scale is None \
             else cfg.attention_scale
-        proj = lambda name: nn.DenseGeneral(  # noqa: E731
+        # as Attention: a served prefill's position-wise sides run over the
+        # prompt's row blocks, and its summaries over the prompt's windows
+        rows, made = _prompt_rows(x, cache, return_kv, lengths)
+        proj = {name: made(nn.DenseGeneral(
             (h, d), use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name=name)
-        q = rope(proj("q")(x), positions, cfg.rope_theta)
-        k = rope(proj("k")(x), positions, cfg.rope_theta)
-        v = proj("v")(x)
+            param_dtype=cfg.param_dtype, name=name)) for name in "qkv"}
+        q, k, v = _over_rows(
+            lambda x, positions: (
+                rope(proj["q"](x), positions, cfg.rope_theta),
+                rope(proj["k"](x), positions, cfg.rope_theta), proj["v"](x)),
+            rows, x, positions)
         vector = lambda name: self.param(  # noqa: E731
             name, nn.initializers.normal(0.02), (h, d), cfg.param_dtype)
         phi, mu = vector("phi"), vector("mu")
-        o_proj = nn.DenseGeneral(cfg.embed_dim, axis=(-2, -1), use_bias=False,
-                                 dtype=cfg.dtype,
-                                 param_dtype=cfg.param_dtype, name="o")
+        o_proj = made(nn.DenseGeneral(
+            cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="o"))
         if cache is not None:
             if x.shape[1] != 1:
                 raise NotImplementedError(
@@ -963,9 +1020,15 @@ class EvaAttention(nn.Module):
                 "sharded over one")
         s = x.shape[1]
         with jax.named_scope(profiling.EVA_SUMMARY):
-            whole = s - s % c
-            kbar, vbar = eva_chunk_summaries(k[:, :whole], v[:, :whole], phi,
-                                             mu, c, scale, rows_at_a_time=w)
+            if rows is not None and s % w == 0:
+                kbar, vbar = _over_rows(
+                    lambda k, v: eva_chunk_summaries(k, v, phi, mu, c, scale),
+                    rows, k, v, block=w)
+            else:
+                whole = s - s % c
+                kbar, vbar = eva_chunk_summaries(
+                    k[:, :whole], v[:, :whole], phi, mu, c, scale,
+                    rows_at_a_time=w)
         with jax.named_scope(profiling.EVA_ATTN):
             if eva_attention_form(cfg, s) == "merged" \
                     and cfg.attention_scale is None:
@@ -986,8 +1049,9 @@ class EvaAttention(nn.Module):
             jax.lax.dynamic_slice_in_dim(
                 jnp.pad(y[b], ((0, pad), (0, 0), (0, 0))) if pad else y[b],
                 start[b], w, axis=0) for b in range(x.shape[0])])
-        return o_proj(out), (jnp.concatenate([ring(k), kbar], axis=1),
-                             jnp.concatenate([ring(v), vbar], axis=1))
+        return _over_rows(o_proj, rows, out), (
+            jnp.concatenate([ring(k), kbar], axis=1),
+            jnp.concatenate([ring(v), vbar], axis=1))
 
 
 class MLP(nn.Module):
@@ -1032,13 +1096,15 @@ def _scaled(x, multiplier: float):
     return (x.astype(jnp.float32) * multiplier).astype(x.dtype)
 
 
-def _feed_forward(cfg: TransformerConfig, dense: bool = False):
+def _feed_forward(cfg: TransformerConfig, dense: bool = False,
+                  made=_as_is):
     """The layer's feed-forward as ``ff(y, valid)`` on its normed input: the
     dense GLU MLP (always, in a layer that is ``dense``: one of a sparse
     model's ``first_dense_layers``) or one of the two sparse layouts.  Made
     inside :class:`Block`'s compact call, once a layer, by a function, so
     that flax adds no method's name to the module path; ``ff`` may then be
-    called a chunk of the sequence at a time."""
+    called a chunk of the sequence at a time.  The dense MLP is called
+    through ``made`` (:func:`_unbound`, inside a loop's body)."""
     if cfg.num_experts > 0 and not dense:
         from horovod_tpu.models.moe import MoEMLP
 
@@ -1067,7 +1133,7 @@ def _feed_forward(cfg: TransformerConfig, dense: bool = False):
                      capacity_factor=cfg.moe_capacity_factor,
                      dtype=cfg.dtype, name="moe_mlp")
         return lambda y, valid: moe(y)
-    mlp = MLP(cfg, name="mlp")
+    mlp = made(MLP(cfg, name="mlp"))
     return lambda y, valid: mlp(y)
 
 
@@ -1080,6 +1146,79 @@ def _in_chunks(fn, chunk: int | None, x, valid):
     return jnp.concatenate(
         [fn(x[:, a:a + chunk], None if valid is None
             else valid[:, a:a + chunk]) for a in range(0, s, chunk)], axis=1)
+
+
+# Rows in a block of a served prefill's position-wise work: the flash
+# forward's q block, the unit ``attn_rows`` counts in (ops/flash_attention).
+ROW_BLOCK = 1024
+
+
+def row_blocks(s: int) -> int:
+    """In how many row blocks a served prefill's position-wise work over
+    ``s`` positions may run (:func:`_over_rows`); 0 where it runs in one
+    piece.  More than two whole blocks: a bucket of two, on a ladder that
+    doubles, holds only prompts that reach into its second, so a loop there
+    would skip nothing, and a block's result is copied into the loop's
+    buffer where the whole call's is written once (read on a v5e, unloaded,
+    the 2048 bucket whole against looped: deepseek-coder-1.3b 41.0 / 45.8
+    ms, EvaByte 94.8 / 97.6, A.X-K1 69.2 / 70.8, command-a-plus 76.6 /
+    77.1; PERF.md section 6, PR 47)."""
+    return s // ROW_BLOCK if s > 2 * ROW_BLOCK and s % ROW_BLOCK == 0 else 0
+
+
+def _prompt_rows(x, cache, return_kv: bool, lengths):
+    """(rows, made) for a pass over ``x`` [B, S, ...].  In a served prefill
+    of several row blocks (:func:`row_blocks`; a pass without a cache whose
+    caller said where its prompts end, ``lengths`` beside ``return_kv``)
+    ``rows`` is how many leading positions hold a prompt (the longest
+    row's, a traced scalar), for :func:`_over_rows`, and ``made`` is
+    :func:`_unbound`, what a submodule is called through inside that loop.
+    For every other pass
+    (training, evaluation, a cache call, ``model.init``, a short bucket)
+    ``rows`` is None and ``made`` hands the module back: the position-wise
+    work runs over the whole sequence in the lines it always had."""
+    if cache is not None or not return_kv or lengths is None \
+            or not row_blocks(x.shape[1]):
+        return None, _as_is
+    return jnp.max(lengths), _unbound
+
+
+def _over_rows(fn, rows, *xs, block: int = ROW_BLOCK):
+    """``fn(*xs)`` for a position-wise ``fn`` over arrays [B, S, ...], where
+    ``rows`` (a traced scalar; None: in one piece, as written) says how many
+    leading positions count: ONE body in a loop of ``ceil(rows / block)``
+    trips, each over ``block`` positions (the block the count ends in runs
+    whole) written into buffers of the sequence's extent that start as
+    zeros.  Positions ``< rows`` get the numbers the whole call would give
+    them; those of the blocks never visited stay exactly 0, whatever lies
+    in ``xs`` there.  ``fn`` may give fewer rows than it takes (one a chunk)
+    and any tree of arrays.
+
+    The buffers are ``jnp.zeros`` and the loop a ``fori_loop`` on purpose.
+    Left uninitialised (``lax.empty``) with a zero block written past the
+    count, every block written once, XLA:TPU fixed their layout and put two
+    copies of the bucket's rows a layer in front of EVA attention's kernels:
+    ``evabyte-code32k-open``'s 32768 bucket 1678 -> 1714 ms and its 2048
+    bucket 97.6 -> 106.9 (PERF.md section 6, PR 47)."""
+    if rows is None:
+        return fn(*xs)
+    blocks = xs[0].shape[1] // block
+    piece = lambda x, i: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+        x, i * block, block, axis=1)
+    one = jax.eval_shape(fn, *(jax.ShapeDtypeStruct(
+        x.shape[:1] + (block,) + x.shape[2:], x.dtype) for x in xs))
+
+    def body(i, out):
+        return jax.tree.map(
+            lambda whole, part: jax.lax.dynamic_update_slice_in_dim(
+                whole, part, i * part.shape[1], axis=1),
+            out, fn(*(piece(x, i) for x in xs)))
+
+    return jax.lax.fori_loop(
+        0, (rows + block - 1) // block, body, jax.tree.map(
+            lambda a: jnp.zeros(
+                (a.shape[0], blocks * a.shape[1]) + a.shape[2:], a.dtype),
+            one))
 
 
 class Block(nn.Module):
@@ -1112,19 +1251,39 @@ class Block(nn.Module):
                                  else {"lengths": lengths}))
         else:
             mixed = mixer(y, positions)
-        ff = _feed_forward(cfg, self.dense_ff)
+        # A served prefill that said where its prompt ends (the mixers' rule)
+        # runs what follows the mixer over the prompt's row blocks, the
+        # loop's blocks in the chunks' place.
+        rows, made = _prompt_rows(x, cache, return_kv, lengths)
+        if cfg.parallel_block and rows is not None:
+            # ... and after the mixer: XLA would else run a sparse
+            # feed-forward first, with the mixer's loops' buffers and the
+            # attention's output waiting beside its own
+            mixed, y = jax.lax.optimization_barrier((mixed, y))
+        if cfg.moe_axis or (cfg.num_experts and not self.dense_ff):
+            # a sparse feed-forward keeps these lines and its chunks: its
+            # routed experts walk the whole chunk's pairs, and its
+            # position-wise parts stop at the last position that holds a
+            # token by themselves (models/moe.py)
+            rows, made = None, _as_is
+        ff = _feed_forward(cfg, self.dense_ff, made)
+        chunk = cfg.feed_forward_chunk if rows is None else None
         if cfg.parallel_block:
             # one norm a layer: the feed-forward reads what the mixer read
             # and both are added to the residual
-            out = x + _scaled(
-                mixed + _in_chunks(ff, cfg.feed_forward_chunk, y, valid),
-                cfg.residual_multiplier)
+            out = _over_rows(lambda x, mixed, y: x + _scaled(
+                mixed + _in_chunks(ff, chunk, y, valid),
+                cfg.residual_multiplier), rows, x, mixed, y)
         else:
-            x = x + _scaled(mixed, cfg.residual_multiplier)
-            mlp_norm = norm("mlp_norm")
-            out = x + _scaled(_in_chunks(
-                lambda x, valid: ff(mlp_norm(x), valid),
-                cfg.feed_forward_chunk, x, valid), cfg.residual_multiplier)
+            mlp_norm = make_norm(cfg, "mlp_norm", made)
+
+            def rest(x, mixed):
+                x = x + _scaled(mixed, cfg.residual_multiplier)
+                return x + _scaled(_in_chunks(
+                    lambda x, valid: ff(mlp_norm(x), valid), chunk, x, valid),
+                    cfg.residual_multiplier)
+
+            out = _over_rows(rest, rows, x, mixed)
         if cache is not None or return_kv:
             return out, kv
         return out
@@ -1153,6 +1312,11 @@ class Transformer(nn.Module):
       ``ops/flash_attention`` runs no tile of the padding; the rows past
       a prompt's end come out of the kernel 0, not what the padding would
       attend to.  The dense default is told nothing and computes it all.
+      Over several row blocks (:func:`row_blocks`) the position-wise
+      work of every layer also stops at the block the longest
+      prompt ends in (:func:`_over_rows`): the rows of the blocks past it
+      are 0 in the streams and in the returned blocks, the rows below the
+      lengths what they are without ``lengths``, to the bit.
     * ``kv_cache=(k, v)`` + ``lengths`` — one incremental decode step:
       ``tokens`` is ``[B, 1]`` (the last sampled token per slot),
       ``lengths`` ``[B]`` the position each slot is decoding at; returns
@@ -1256,7 +1420,18 @@ class Transformer(nn.Module):
                 x, kv_cache = block(
                     x, positions, cache=(*kv_cache, lengths, i), **told)
             elif return_kv:
-                x, kv = block(x, positions, return_kv=True, **told, **ends)
+                if ends and cfg.num_experts == 0 \
+                        and row_blocks(tokens.shape[1]):
+                    # a layer of loops is traced once a kind of layer, not
+                    # once a layer (a sparse layer sows, and is called as
+                    # it lies)
+                    with jax.named_scope(block.name):
+                        x, kv = _unbound(block, nameless=True,
+                                         return_kv=True)(
+                            x, positions, **ends)
+                else:
+                    x, kv = block(x, positions, return_kv=True, **told,
+                                  **ends)
                 if kv_into is None:
                     kvs.append(kv)
                     continue

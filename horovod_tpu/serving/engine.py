@@ -309,7 +309,10 @@ class TransformerBackend:
     a model of EVA attention a slot's extent is a ring of ``eva_window``
     exact rows and one summary row a chunk, not a row a position; the
     model picks its prefill's form a bucket (``"dense"`` / ``"merged"``),
-    lays the ring out for the prompt's own length, and the running counts of
+    lays the ring out for the prompt's own length (and its layers' cache
+    blocks go into the pool layer by layer, as any model's do in a bucket
+    whose layers loop over the prompt's row blocks: :meth:`prefill_rows`),
+    and the running counts of
     what the calls did to that cache are ``eva_counters`` (the call's span
     carries ``windows`` and ``summaries``, or ``chunks_closed`` and
     ``rollovers``).  Of a model with more than one prediction head all the
@@ -399,11 +402,31 @@ class TransformerBackend:
                 rest, self._model_cfg.eva_window)
         return rows_worked(int(length), int(bucket))
 
+    def prefill_rows(self, bucket: int, length: int) -> int | None:
+        """The rows a prefill's position-wise layers (projections, MLPs,
+        shared experts) run over for a prompt of ``length`` in ``bucket``:
+        the model's row blocks up to the one the prompt ends in, whole
+        (models/transformer.py, ``_over_rows``); None where the program has
+        no such loop (a bucket of one or two blocks, a model's own attention
+        function: told no length)."""
+        from horovod_tpu.models.transformer import ROW_BLOCK, row_blocks
+        from horovod_tpu.ops.flash_attention import rows_worked
+
+        bucket = int(bucket)
+        if self.prefill_attention(bucket) == "own" or not row_blocks(bucket):
+            return None
+        return rows_worked(int(length), bucket, ROW_BLOCK)
+
     def prefill_chunks(self, bucket: int) -> int:
         """In how many pieces a prefill of ``bucket`` positions runs its
-        feed-forward layers (``TransformerConfig.feed_forward_chunk``)."""
+        feed-forward layers (``TransformerConfig.feed_forward_chunk``); a
+        dense feed-forward that runs over the prompt's row blocks
+        (:meth:`prefill_rows`) takes those in the chunks' place: 1."""
         chunk = self._model_cfg.feed_forward_chunk
-        return -(-int(bucket) // chunk) if chunk else 1
+        if not chunk or (not self.sparse
+                         and self.prefill_rows(bucket, bucket) is not None):
+            return 1
+        return -(-int(bucket) // chunk)
 
     @property
     def flash_prefill(self) -> bool:
@@ -467,15 +490,18 @@ class TransformerBackend:
             # of the bucket's padding (a model's own attention function is
             # not known to take a length; the dense form ignores it)
             told["lengths"] = jnp.reshape(length, (1,))
-        if self.eva:
+        into_pool = self.eva or self.prefill_rows(padded.shape[1], 1)
+        if into_pool:
             # the ring is laid out for a decode step at length, and a
             # layer's ring and summaries, a slot's whole extent, go into the
-            # pool as the layer ends
+            # pool as the layer ends; so does a layer's block where the
+            # layers loop over the prompt's row blocks: stacked, the loops'
+            # buffers would each be copied out first, and wait for it
             told["kv_into"] = (kk, vv, slot)
         (logits, (pk, pv)), pairs = self._apply(
             self._prefill_model(padded.shape[1]), params, padded,
             return_kv=True, **told)
-        if self.eva:
+        if into_pool:
             kk, vv = pk, pv
         else:
             at_slot = lambda pool: (0, slot) + (0,) * (  # noqa: E731
@@ -955,11 +981,14 @@ class ServingEngine:
             if chunks > 1:
                 attn["chunks"] = chunks
             # ... and how many of the bucket's query rows its attention
-            # kernels work, where a kernel runs and stops at the prompt
-            worked = getattr(self.backend, "prefill_attn_rows", None)
-            rows = worked(bucket, len(suffix)) if worked else None
-            if rows is not None:
-                attn["attn_rows"] = rows
+            # kernels work, where a kernel runs and stops at the prompt, and
+            # how many its position-wise layers, where they stop there too
+            for field, counted in (("attn_rows", "prefill_attn_rows"),
+                                   ("rows_worked", "prefill_rows")):
+                worked = getattr(self.backend, counted, None)
+                rows = worked(bucket, len(suffix)) if worked else None
+                if rows is not None:
+                    attn[field] = rows
             with profiling.span(
                     profiling.SRV_PREFILL, cause=req._span.id, rid=req.rid,
                     bucket=bucket, length=len(suffix),
@@ -1198,7 +1227,10 @@ class ServingEngine:
         ``prompt_tokens`` they prefilled, and, where its kernels stop at the
         prompt's own length, the ``bucket_rows`` the calls padded to and the
         ``attn_rows`` the kernels worked of them (one less their ratio is
-        the share of q rows skipped).  Where the model's feed-forward is
+        the share of q rows skipped).  Where its position-wise layers stop
+        there too, ``hvd_srv_prefill`` has ``rows``: the ``calls`` that ran
+        them so, the ``bucket_rows`` those padded to and the ``rows_worked``
+        of them.  Where the model's feed-forward is
         sparse, ``hvd_srv_prefill`` and ``hvd_srv_decode`` have ``moe``: the
         ``rows`` the expert layers visited, the ``held_pairs`` they visited
         them for, and ``rows_per_held_pair`` (1 would waste nothing)."""
@@ -1218,6 +1250,14 @@ class ServingEngine:
                         + r.fields["attn_rows"]
         if by_attn:
             out[profiling.SRV_PREFILL]["attn"] = by_attn
+        looped = [r.fields for r in records
+                  if r.name == profiling.SRV_PREFILL
+                  and "rows_worked" in r.fields]
+        if looped:
+            out[profiling.SRV_PREFILL]["rows"] = {
+                "calls": len(looped),
+                "bucket_rows": sum(f["bucket"] for f in looped),
+                "rows_worked": sum(f["rows_worked"] for f in looped)}
         for name in (profiling.SRV_PREFILL, profiling.SRV_DECODE):
             sparse = [r.fields for r in records
                       if r.name == name and "moe_rows" in r.fields]

@@ -447,3 +447,94 @@ def test_a_share_of_the_experts_keeps_no_array_of_all_its_pairs(
     assert walked.count(" while(") >= 1
     carried = text_of(held, None)
     assert all_pairs.search(carried) and profiling.TOKEN_SUM not in carried
+
+
+# The four serving cells cut to two layers at their own widths (for A.X-K1
+# the dense layer and one sparse one; for command-a-plus a sliding and a full
+# layer), two slots: (cell, what the cut replaces, {bucket: (the ``while``
+# ops the compiled prefill holds, the same program's peak at PR 45's tree
+# [commit 7d461c2], bytes)}), at the 4096 bucket and at the cell's longest.
+# The loops a layer: the mixer's two sides and the dense feed-forward with
+# its residual adds, 3; a sparse feed-forward has its router's and its
+# shared experts' in the third's place, once a chunk, beside the walk of its
+# held pairs; EVA attention has its summaries' too, beside its merged form's
+# own map over the windows.
+PREFILL_CELLS = [
+    ("dsc1p3b-code-0.8knee", {}, {4096: (2 * 3, 873636352)}),
+    ("cmdaplus-code8k-open",
+     {"layer_types": ("sliding_attention", "full_attention")},
+     {4096: (2 * 5, 5666695168), 8192: (2 * 5, 6327314432)}),
+    ("axk1-longdoc16k-open", {"layer_types": ("latent_attention",) * 2},
+     {4096: (3 + 2 + 3, 3726478848), 16384: (3 + 2 + 4 * 3, 5359136768)}),
+    ("evabyte-code32k-open", {"layer_types": ("eva_attention",) * 2},
+     {4096: (2 * 5, 1338628608), 32768: (2 * 5, 3517683712)}),
+]
+
+
+@pytest.mark.parametrize("cell,cut,bucket", [
+    (cell, cut, bucket) for cell, cut, buckets in PREFILL_CELLS
+    for bucket in buckets], ids=lambda v: str(v) if not isinstance(v, dict)
+    else "")
+def test_a_served_prefill_holds_one_loop_a_call_site_and_no_wider_buffer(
+        one_chip_mesh, monkeypatch, cell, cut, bucket):
+    """A prefill bucket of several row blocks, as the chip's compiler leaves
+    it (PR 47): the position-wise layers are ONE loop body a call site, as
+    many ``while`` ops at 4096 as at the cell's longest bucket (a chunk's
+    apart), no ``[bucket, mlp_dim]`` array is left (a block's instead), and
+    the program's peak is no larger than its parent's."""
+    import dataclasses
+    import os
+
+    from benchmarks import run as harness
+    from horovod_tpu.models import transformer as T
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    manifest = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "BENCHMARK.json")
+    _, _, config, traffic = harness.load_cell(manifest, cell)
+    family = harness.load_module("families", config["family"])
+    cfg = dataclasses.replace(family.model_config(config, traffic),
+                              num_layers=2, **cut)
+    model = T.Transformer(cfg)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    slots, max_len = 2, int(traffic["max_seq_len"])
+    pool = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: T.init_kv_cache(cfg, slots, max_len)))
+    with monkeypatch.context() as m:    # no pool is made: nothing runs
+        m.setattr(T, "init_kv_cache", lambda *a, **kw: (None, None))
+        backend = TransformerBackend(model, None, cfg, slots, max_len)
+    assert backend.prefill_rows(bucket, bucket) == bucket
+    i32 = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    compiled = backend._prefill.lower(
+        params, *pool, on_chip(jax.ShapeDtypeStruct((1, bucket), jnp.int32)),
+        i32, i32).compile()
+    text = compiled.as_text()
+    whiles, parent_peak = dict(
+        (c, b) for c, _, b in PREFILL_CELLS)[cell][bucket]
+    assert len(re.findall(r" while\(", text)) == whiles
+    # the widths of a feed-forward's hidden rows, where [bucket, width] is
+    # no other array's shape: not a weight's (a bucket as long as the stream
+    # is wide) nor the block of the walk of the held pairs
+    from horovod_tpu.models import moe
+
+    sparse = (cfg.moe_mlp_dim or cfg.mlp_dim) * max(cfg.num_shared_experts, 1)
+    held = cfg.experts_held[1] - cfg.experts_held[0] if cfg.experts_held \
+        else 0
+    walked = held and moe.held_block_rows(
+        min(bucket, cfg.feed_forward_chunk or bucket)
+        * cfg.experts_per_token, held, cfg.num_experts)
+    widths = {cfg.mlp_dim, sparse} - {cfg.embed_dim} \
+        - ({sparse} if walked == bucket else set())
+    assert widths and (bucket == cfg.embed_dim or not re.search(
+        rf"(?:bf16|f32)\[(?:1,)?{bucket},(?:{'|'.join(map(str, widths))})\]",
+        text))
+    assert re.search(rf"bf16\[(?:1,)?{T.ROW_BLOCK},"
+                     rf"(?:{'|'.join(map(str, widths))})\]", text)
+    # (a megabyte for what is no array: the loops' counters, the code)
+    assert compiled.memory_analysis().peak_memory_in_bytes \
+        <= parent_peak + 2 ** 20
