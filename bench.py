@@ -1,175 +1,89 @@
-"""Benchmark driver — eager hot-path latency + ResNet-50 synthetic throughput.
+"""Host-side phases: what the control plane and the recovery paths cost.
 
-Two phases, one JSON metric line each:
+Nothing here times the accelerator.  Training rates and served latencies are
+``benchmarks/run.py``'s (``BENCHMARK.json``; the driver's readings are in
+``PERF_LEDGER.jsonl``, their meaning in ``PERF.md``).  These phases run the
+native engine, the checkpoint manager, the bulk data plane and the
+coordinator tree on the host's CPU, one JSON line each, and every line says
+so: ``"platform": "host"`` for a phase that never imports jax,
+``jax.default_backend()`` for the one that does (checkpoint).
+``docs/benchmarks.md`` records a reading of each.
 
-1. **Eager small-tensor microbench** — 256 × 4 KiB engine allreduces with a
-   warm response cache vs the same run under ``HOROVOD_CACHE_CAPACITY=0``
-   (docs/response_cache.md).  Reports the warm per-op p50::
+``python bench.py`` runs four, each skipped by its ``BENCH_SKIP_*=1``:
+
+1. **Eager small-tensor latency** (``BENCH_SKIP_EAGER``) — 256 x 4 KiB
+   engine allreduces with a warm response cache against the same run under
+   ``HOROVOD_CACHE_CAPACITY=0`` (docs/response_cache.md)::
 
        {"metric": "eager_allreduce_p50_us", "value": N, "unit": "us",
-        "vs_baseline": <cold_p50 / warm_p50>}
+        "vs_baseline": <cold_p50 / warm_p50>, "cold_p50_us": M}
 
-   ``vs_baseline`` here is the speedup over the uncached engine on the SAME
-   run — the acceptance bar is >= 2x (docs/benchmarks.md).
+   ``BENCH_EAGER_OPS`` / ``BENCH_EAGER_ELEMS`` resize it.
 
-2. **ResNet-50 synthetic throughput** — the reference's in-tree harness
-   semantics (reference examples/pytorch_synthetic_benchmark.py:14-107):
-   synthetic ImageNet-shaped data, full training step (forward + backward +
-   DistributedOptimizer update), 10 warmup batches, then 10 timed iterations
-   of 10 batches each, reporting mean images/sec::
-
-       {"metric": "resnet50_synthetic_train_throughput", "value": N,
-        "unit": "img/s/chip", "vs_baseline": N,
-        "overlap_plan": {...}}
-
-   ``vs_baseline`` divides by the only per-device figure the reference
-   publishes (docs/benchmarks.md:34-38: ResNet-101, 1656.82 img/s on 16
-   Pascal GPUs = 103.55 img/s/GPU; hardware era differs — the ratio is
-   recorded for trend tracking, not as a same-silicon comparison).
-   ``overlap_plan`` is the schedule planner's decision for the traced
-   step (``hvd.overlap_plan()``, ops/schedule_plan.py) — the headline
-   number is meaningless without knowing whether the bucket chain was
-   engaged, at what depth, and why.
-
-2b. **Width-1 overlap-plan microbench** — lowers a small training step
-   over a ONE-device mesh and asserts the adaptive planner bypassed the
-   dependency chain (zero gate ops in the stablehlo; the r5 −4.3%
-   single-chip ResNet regression, pinned in the harness itself)::
-
-       {"metric": "overlap_width1_chain_gates", "value": 0, "unit": "ops",
-        "vs_baseline": <gates the r5 static default emitted>,
-        "plan": {...}}
-
-2c. **Checkpoint snapshot-stall microbench** — times what the TRAIN LOOP
-   pays per checkpoint under the async persist split
-   (``HVD_TPU_CKPT_ASYNC=1``, checkpoint.CheckpointManager: snapshot at
-   the step barrier, commit on the background persist thread) against
-   the synchronous save of the SAME state on the same run::
+2. **Checkpoint snapshot stall** (``BENCH_SKIP_CKPT``) — what the train
+   loop pays per checkpoint under the async persist split
+   (``HVD_TPU_CKPT_ASYNC=1``, checkpoint.CheckpointManager: snapshot at the
+   step barrier, commit on the persist thread) against the synchronous save
+   of the same state::
 
        {"metric": "checkpoint_stall_ms", "value": N, "unit": "ms",
         "vs_baseline": <sync_ms / stall_ms>, "checkpoint_sync_ms": M,
         "state_bytes": B}
 
-   ``BENCH_CKPT_BYTES`` sizes the state (default 64 MiB; use
-   ``1872000000`` for the 468M-param f32 config the docs row records);
-   the acceptance bar is stall < one step time at that config
-   (docs/benchmarks.md).
+   ``BENCH_CKPT_BYTES`` sizes the state (default 64 MiB),
+   ``BENCH_CKPT_STEPS`` the saves.
 
-2d. **Replication data-plane bench** — engine-only multi-process jobs (2
-   then 4 ranks) replicate ``BENCH_DP_BYTES`` of state per step over the
-   rank-to-rank bulk data plane (dataplane.py, ZeRO-sharded
-   replication.py) and report what ONE rank ships per snapshot::
+3. **Replication data plane** (``BENCH_SKIP_DATAPLANE``) — engine-only jobs
+   of 2 then 4 ranks replicate ``BENCH_DP_BYTES`` a step
+   (``BENCH_DP_STEPS`` steps) over the rank-to-rank bulk data plane
+   (dataplane.py, ZeRO-sharded replication.py) and report what ONE rank
+   ships a snapshot::
 
        {"metric": "dataplane_replication_bytes_per_rank", "value": N,
         "unit": "bytes", "vs_baseline": <whole_replica_bytes / value>,
-        "bytes_per_rank_n2": M, "relay_bytes": 0,
-        "bandwidth_mb_s": B}
+        "bytes_per_rank_n2": M, "relay_bytes": 0, "bandwidth_mb_s": B}
 
-   ``vs_baseline`` is the reduction over the pre-shard design, which
-   shipped the WHOLE encoded snapshot per rank (so ~N at N ranks); the
-   harness asserts the ~1/N scaling from 2 -> 4 ranks and that steady
-   state moved ZERO payload bytes through the coordinator star
-   (``replication_stats()["bytes_shipped_relay"] == 0`` on every rank).
+   Asserted, not only reported: the ~1/N scaling from 2 to 4 ranks, and
+   zero payload bytes through the coordinator star in steady state.
 
-2e. **Long-context transformer bench** — trains the planner-wired
-   long-context transformer (one ``plan_context`` decision per size:
-   layout, VMEM-fit kernel tiles, remat — nothing hand-set) at
-   ``BENCH_LONGCTX_SEQS`` (default 8K/32K/128K; 128K is the 8-chip
-   headline target), one JSON line per size::
-
-       {"metric": "longctx_train_tokens_per_s", "value": N,
-        "unit": "tok/s", "seq_len": S, "mfu": F,
-        "vs_baseline": <mfu / r5 42% hand-tuned baseline>,
-        "plan": {...}}
-
-   ``mfu`` divides achieved model FLOP/s by the chip's bf16 peak, looked
-   up by ``device_kind`` (horovod_tpu/utils/chip.py); the acceptance bar
-   is >= 55% at S=32K plus a completing S=128K demo across 8 chips
-   (docs/benchmarks.md).  Without a TPU the phase raises.
-
-2f. **Control-plane scaling** — the deviceless fleet simulator
-   (core/src/fleet_sim.cc: the real root/relay protocol code, scripted
-   member processes, thread-CPU busy accounting) measures the negotiated
-   coordination tick of the hierarchical tree at 4096 protocol-only
-   ranks against the rank-0 star at the reference's demonstrated
-   512-worker scale::
+4. **Control-plane scaling** (``BENCH_SKIP_CONTROL_PLANE``) — the deviceless
+   fleet simulator (core/src/fleet_sim.cc: the real root/relay protocol
+   code, scripted members, thread-CPU busy accounting) gives the negotiated
+   coordination tick of the tree at 4096 protocol-only ranks against the
+   rank-0 star at 512::
 
        {"metric": "control_plane_tick_us", "value": N, "unit": "us",
         "vs_baseline": <star_512_tick_us / value>, "p": 4096,
         "topology": "tree", "fanout": F, "num_groups": G, "depth": 2,
         "star_512_tick_us": M, "agg_frames_per_tick": G}
 
-   The acceptance bar is value < 5000 (one HOROVOD_CYCLE_TIME budget)
-   at depth >= 2 while the 512-star baseline already exceeds it
-   (docs/benchmarks.md "Control-plane scaling").  ``BENCH_CP_RANKS`` /
-   ``BENCH_CP_FANOUT`` / ``BENCH_CP_TICKS`` resize the run.
+   The bar is value < 5000 (one HOROVOD_CYCLE_TIME) at depth >= 2 while the
+   512-star already exceeds it (docs/benchmarks.md "Control-plane
+   scaling").  ``BENCH_CP_RANKS`` / ``BENCH_CP_FANOUT`` / ``BENCH_CP_TICKS``
+   resize the run.
 
-2b. **Serving** (``bench.py serving`` runs it alone) — the
-   continuous-batching inference phase (serving/).  A small real
-   Transformer on the KV-cache decode path serves an open-loop Poisson
-   workload at three arrival rates around the measured saturation
-   point, plus four asserted shape-level properties::
+``python bench.py --fault`` — **failure-detection MTTR**: a two-process
+engine job; rank 1 is SIGKILLed at steady state and the survivor's
+peer-failure abort (heartbeats + hardened frames, docs/fault_tolerance.md)
+is timed end to end::
 
-       {"metric": "serving_continuous_vs_static", "value": R, "unit": "x",
-        "continuous_tokens_per_s": ..., "static_tokens_per_s": ...}
-       {"metric": "serving_tokens_per_s", "value": N, "unit": "tok/s",
-        "qps": Q, "ttft_p50_ms": ..., "ttft_p99_ms": ...,
-        "token_p50_ms": ..., "token_p99_ms": ...}          (x3 QPS levels)
-       {"metric": "serving_tick_cache_hits", ...}   (zero NEGOTIATED)
-       {"metric": "serving_prefix_ttft", "cache": "on|off",
-        "shared_frac": F, "prefix_hit_rate": ..., "ttft_p50_ms": ...}
-                                                    (x2 sharing fractions)
-       {"metric": "serving_spec_decode_uplift", "value": U, "unit": "x",
-        "spec_accept_rate": ...}
-       {"metric": "serving_router_slo", "model": ..., "slo_attainment": ...}
-                                                    (x2 models)
-       {"metric": "serving_autoscale_soak", ...}    (lost=0, disk_reads=0)
+    {"metric": "failure_detection_ms", "value": N, "unit": "ms",
+     "vs_baseline": <60 s stall window / value>,
+     "wire_drop_silence_ms": <heartbeat-timeout path>}
 
-   Asserted, not just reported: continuous batching >= 2x the static
-   drain barrier's tokens/s at saturation; every steady-state
-   ``serving.tick`` is a response-cache hit; the prefix cache strictly
-   lowers TTFT p50 at high prompt sharing; speculative decoding lifts
-   tokens/s >= 1.3x on a repetitive-suffix workload; the soak's joiner
-   clones weights over the data plane with zero disk reads and a
-   SIGKILLed replica (with prefix cache + speculation ON) loses zero
-   accepted requests.  ``BENCH_SERVE_DURATION_S`` resizes the sweep.
+``python bench.py --fault --elastic`` — **elastic recovery**: a
+three-process job under ``HVD_TPU_ELASTIC=1``; a rank is SIGKILLed at steady
+state and the survivors' in-place recovery is timed kill -> training again,
+beside the full restart from a checkpoint on the same scenario.  Rank 2
+(plain shrink) and rank 0 (standby promotion, succession-port re-bind,
+re-rendezvous; docs/fault_tolerance.md "Coordinator failover")::
 
-``BENCH_SKIP_EAGER=1`` / ``BENCH_SKIP_RESNET=1`` / ``BENCH_SKIP_PLAN=1``
-/ ``BENCH_SKIP_CKPT=1`` / ``BENCH_SKIP_DATAPLANE=1`` /
-``BENCH_SKIP_LONGCTX=1`` / ``BENCH_SKIP_CONTROL_PLANE=1`` /
-``BENCH_SKIP_SERVING=1`` skip individual phases.
+    {"metric": "elastic_recovery_ms", "value": N, "unit": "ms",
+     "vs_baseline": <full_restart_recovery_ms / value>,
+     "full_restart_recovery_ms": M}
+    {"metric": "coordinator_failover_ms", ...same keys...}
 
-3. **Fault-detection MTTR** (``bench.py --fault``) — two-process engine
-   job; rank 1 is SIGKILLed at steady state and the survivor's
-   peer-failure abort (heartbeats + hardened frames,
-   docs/fault_tolerance.md) is timed end to end::
-
-       {"metric": "failure_detection_ms", "value": N, "unit": "ms",
-        "vs_baseline": <60 s stall window / value>,
-        "wire_drop_silence_ms": <heartbeat-timeout path>}
-
-   ``vs_baseline`` is the MTTR improvement over the pre-heartbeat story,
-   where a dead peer sat invisible until the 60 s stall detector fired.
-
-4. **Elastic recovery** (``bench.py --fault --elastic``) — three-process
-   engine job under ``HVD_TPU_ELASTIC=1``; a rank is SIGKILLed at steady
-   state and the survivors' in-place recovery is timed kill → survivors
-   training again, next to the full restart-from-checkpoint path on the
-   same scenario.  Two kills are measured: rank 2 (plain shrink,
-   docs/fault_tolerance.md "In-place recovery") and rank 0 (standby
-   promotion + succession-port re-bind + survivor re-rendezvous,
-   docs/fault_tolerance.md "Coordinator failover")::
-
-       {"metric": "elastic_recovery_ms", "value": N, "unit": "ms",
-        "vs_baseline": <full_restart_recovery_ms / value>,
-        "full_restart_recovery_ms": M}
-       {"metric": "coordinator_failover_ms", "value": N', "unit": "ms",
-        "vs_baseline": <full_restart_recovery_ms / value>,
-        "full_restart_recovery_ms": M}
-
-   ``vs_baseline`` is the speedup of recovering in place over tearing
-   every process down and relaunching from the newest checkpoint (the
-   PR-1 recovery story); the acceptance bar is >= 5x for both metrics.
+The bar is >= 5x over the full restart for both.
 """
 
 from __future__ import annotations
@@ -183,14 +97,20 @@ import sys
 import textwrap
 import time
 
-BASELINE_IMG_PER_SEC_PER_DEVICE = 1656.82 / 16  # reference docs/benchmarks.md:34-38
-
 # Every worker this file spawns is engine-only (NativeEngine + numpy; none
 # imports jax — tests/test_chip_bringup.py holds them to it).  A chip belongs
 # to one process and this parent may hold it, so the children are pinned to
 # the CPU platform: a later edit that pulls jax into a worker then gets a CPU
 # backend instead of hanging on a TPU it cannot have.
 CHILD_ENV = {"JAX_PLATFORMS": "cpu"}
+
+
+def _free_port() -> int:
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    return port
 
 
 def eager_microbench() -> None:
@@ -231,6 +151,7 @@ def eager_microbench() -> None:
         "metric": "eager_allreduce_p50_us",
         "value": round(warm_p50, 1),
         "unit": "us",
+        "platform": "host",
         "vs_baseline": round(cold_p50 / warm_p50, 3),
         "cold_p50_us": round(cold_p50, 1),
     }))
@@ -269,10 +190,7 @@ def fault_bench() -> None:
     here = os.path.dirname(os.path.abspath(__file__))
 
     def run(extra_env):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        port = s.getsockname()[1]
-        s.close()
+        port = _free_port()
         env = {**os.environ, **CHILD_ENV, "PYTHONPATH": here,
                "HVD_TPU_HEARTBEAT_MS": "50",
                "HVD_TPU_HEARTBEAT_TIMEOUT_MS": "1000",
@@ -310,6 +228,7 @@ def fault_bench() -> None:
         "metric": "failure_detection_ms",
         "value": round(detect_ms, 1),
         "unit": "ms",
+        "platform": "host",
         "vs_baseline": round(stall_window_ms / max(detect_ms, 1e-9), 1),
         "wire_drop_silence_ms": round(silence_ms, 1),
     }))
@@ -404,19 +323,12 @@ def elastic_bench() -> None:
                 "HVD_TPU_ABORT_GRACE_MS": "100",
                 "HVD_TPU_CONNECT_TIMEOUT": "60"}
 
-    def port():
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        p = s.getsockname()[1]
-        s.close()
-        return p
-
     def in_place_mttr(kill_rank: int, watch_rank: int) -> float:
         """SIGKILL ``kill_rank`` at steady state; wall-clock ms until
         ``watch_rank``'s first post-shrink collective completes."""
         env = {**base_env, "HVD_TPU_ELASTIC": "1",
                "HVD_TPU_RECONFIG_TIMEOUT_MS": "20000"}
-        p0_port = port()
+        p0_port = _free_port()
         procs = [subprocess.Popen(
             [sys.executable, "-c", _ELASTIC_WORKER, str(r), str(p0_port),
              "3"],
@@ -460,6 +372,7 @@ def elastic_bench() -> None:
         "metric": "elastic_recovery_ms",
         "value": round(elastic_ms, 1),
         "unit": "ms",
+        "platform": "host",
         "vs_baseline": round(restart_ms / max(elastic_ms, 1e-9), 1),
         "full_restart_recovery_ms": round(restart_ms, 1),
     }))
@@ -467,6 +380,7 @@ def elastic_bench() -> None:
         "metric": "coordinator_failover_ms",
         "value": round(failover_ms, 1),
         "unit": "ms",
+        "platform": "host",
         "vs_baseline": round(restart_ms / max(failover_ms, 1e-9), 1),
         "full_restart_recovery_ms": round(restart_ms, 1),
     }))
@@ -484,6 +398,7 @@ def checkpoint_bench() -> None:
     import shutil
     import tempfile
 
+    import jax
     import numpy as np
 
     from horovod_tpu import checkpoint as hvd_checkpoint
@@ -525,6 +440,7 @@ def checkpoint_bench() -> None:
         "metric": "checkpoint_stall_ms",
         "value": round(stall_ms, 1),
         "unit": "ms",
+        "platform": jax.default_backend(),
         "vs_baseline": round(sync_ms / max(stall_ms, 1e-9), 1),
         "checkpoint_sync_ms": round(sync_ms, 1),
         "state_bytes": nbytes,
@@ -579,15 +495,8 @@ def dataplane_bench() -> None:
     ships exactly its own 1/N shard per snapshot, rank-to-rank.  Asserted
     here, not just reported: bytes per rank halve from N=2 to N=4, and
     the coordinator relayed ZERO payload bytes in steady state."""
-    def port() -> int:
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        p = s.getsockname()[1]
-        s.close()
-        return p
-
     def run(n: int) -> list[dict]:
-        cp = port()
+        cp = _free_port()
         env = {**os.environ, **CHILD_ENV, "PYTHONPATH": os.path.dirname(
             os.path.abspath(__file__))}
         procs = [subprocess.Popen(
@@ -618,6 +527,7 @@ def dataplane_bench() -> None:
         "metric": "dataplane_replication_bytes_per_rank",
         "value": int(per_rank4),
         "unit": "bytes",
+        "platform": "host",
         "vs_baseline": round(whole / max(per_rank4, 1), 2),
         "bytes_per_rank_n2": int(per_rank2),
         "relay_bytes": 0,
@@ -662,6 +572,7 @@ def control_plane_bench() -> None:
         "metric": "control_plane_tick_us",
         "value": round(tree["modeled_tick_us"], 1),
         "unit": "us",
+        "platform": "host",
         "vs_baseline": round(star["modeled_tick_us"]
                              / max(tree["modeled_tick_us"], 1e-9), 2),
         "p": ranks,
@@ -674,452 +585,7 @@ def control_plane_bench() -> None:
     }))
 
 
-def overlap_plan_microbench() -> None:
-    """Width-1 planner check, in the harness where the regression lived:
-    lower a small training step over a ONE-device mesh and assert the
-    adaptive planner bypassed the bucket chain — zero ``is_finite`` gate
-    ops in the stablehlo (the chain's anti-combining gate is the lowered
-    program's only source of that op).  The r5 static default emitted
-    depth−1 of them at width 1 and cost −4.3% on the single-chip ResNet
-    headline; this line keeps that structurally impossible to ship."""
-    import horovod_tpu as hvd
-    from horovod_tpu.ops import schedule_plan
-
-    hvd.init()
-    from examples.overlap_audit import audit_cpu_sim_width1
-
-    audit = audit_cpu_sim_width1()
-    gates, plan = audit["gate_is_finite_ops"], audit["plan"]
-    assert gates == 0 and plan is not None and not plan["chained"], (
-        "width-1 lowering still carries the bucket chain", audit)
-    print(json.dumps({
-        "metric": "overlap_width1_chain_gates",
-        "value": gates,
-        "unit": "ops",
-        "vs_baseline": schedule_plan.DEFAULT_CHAIN_DEPTH - 1,
-        "plan": plan,
-    }))
-
-
-R5_LONGCTX_MFU = 0.42  # hand-tuned S=8K zigzag run, docs/benchmarks.md r5
-
-
-def longctx_bench() -> None:
-    """Long-context transformer throughput with the planner in charge.
-
-    For each sequence length, ONE ``plan_long_context`` call decides the
-    layout (zigzag for causal multi-shard), the flash tiles (VMEM-fit-
-    clamped), and the remat policy; the model wires itself from the plan
-    (``TransformerConfig.context_plan``).  The per-size JSON line carries
-    the plan next to the number — a tokens/s figure is uninterpretable
-    without knowing which layout and tiles produced it.  MFU counts
-    matmul FLOPs (6·P per token fwd+bwd) plus the causal attention
-    FLOPs (6·L·S·H·D) against the chip's bf16 peak (utils/chip.py).
-    """
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-    from jax.sharding import Mesh, PartitionSpec as P
-
-    import horovod_tpu as hvd
-    from horovod_tpu.models import Transformer, TransformerConfig
-    from horovod_tpu.parallel import plan_long_context, shard_sequence
-    from horovod_tpu.utils import chip
-
-    chip.require_tpu("bench.py long-context phase")
-    hvd.init()
-    n = hvd.num_chips()
-    mesh = Mesh(np.array(jax.devices()), ("sp",))
-    seqs = [int(s) for s in os.environ.get(
-        "BENCH_LONGCTX_SEQS", "8192,32768,131072").split(",")]
-    layers, heads, embed = 8, 16, 2048
-    steps = int(os.environ.get("BENCH_LONGCTX_STEPS", "10"))
-    head_dim, mlp = embed // heads, 4 * embed
-    peak = chip.peak_bf16_flops()
-
-    for seq in seqs:
-        if seq % (2 * n):
-            seq = max(2 * n, seq - seq % (2 * n))
-        s_local = seq // n
-        plan = plan_long_context(
-            seq_len=seq, num_heads=heads, head_dim=head_dim, width=n,
-            embed_dim=embed, mlp_dim=mlp, num_layers=layers)
-        base = dict(vocab_size=32000, num_layers=layers, num_heads=heads,
-                    head_dim=head_dim, embed_dim=embed, mlp_dim=mlp,
-                    max_seq_len=seq)
-        model = Transformer(TransformerConfig(**base, context_axis="sp",
-                                              context_plan=plan))
-        params = Transformer(TransformerConfig(**base)).init(
-            jax.random.PRNGKey(0), jnp.zeros((1, s_local), jnp.int32))
-        opt = optax.adamw(3e-4)
-        opt_state = opt.init(params)
-
-        def sharded(params, tokens):
-            def loss_fn(p):
-                ce = optax.softmax_cross_entropy_with_integer_labels
-                logits = model.apply(p, tokens)
-                if plan.layout == "zigzag":
-                    c = s_local // 2
-                    loss = 0.5 * (
-                        ce(logits[:, :c - 1], tokens[:, 1:c]).mean()
-                        + ce(logits[:, c:-1], tokens[:, c + 1:]).mean())
-                else:
-                    loss = ce(logits[:, :-1], tokens[:, 1:]).mean()
-                return jax.lax.pmean(loss, "sp")
-
-            loss, grads = jax.value_and_grad(loss_fn)(params)
-            return jax.tree.map(lambda g: jax.lax.pmean(g, "sp"),
-                                grads), loss
-
-        @jax.jit
-        def train_step(params, opt_state, tokens):
-            grads, loss = jax.shard_map(
-                sharded, mesh=mesh, in_specs=(P(), P(None, "sp")),
-                out_specs=(P(), P()), check_vma=False)(params, tokens)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            return optax.apply_updates(params, updates), opt_state, loss
-
-        tokens = shard_sequence(
-            jnp.asarray(np.random.RandomState(0).randint(
-                0, 32000, (1, seq))), plan)
-        params, opt_state, loss = train_step(params, opt_state, tokens)
-        float(loss)  # compile + warm step, hard sync
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            params, opt_state, loss = train_step(params, opt_state, tokens)
-        float(loss)
-        tok_s = seq * steps / (time.perf_counter() - t0)
-
-        hd = heads * head_dim
-        p_matmul = layers * (4 * embed * hd + 3 * embed * mlp) + embed * 32000
-        flops_per_tok = 6 * p_matmul + 6 * layers * seq * hd
-        mfu = round(flops_per_tok * tok_s / (n * peak), 4)
-        print(json.dumps({
-            "metric": "longctx_train_tokens_per_s",
-            "value": round(tok_s, 1),
-            "unit": "tok/s",
-            "seq_len": seq,
-            "mfu": mfu,
-            "vs_baseline": round(mfu / R5_LONGCTX_MFU, 3),
-            "plan": plan.as_dict(),
-        }))
-
-
-def serving_bench() -> None:
-    """Continuous-batching serving: latency/throughput at several arrival
-    rates, continuous vs static batching at saturation, response-cache
-    warmth of the steady-state decode tick, and the autoscale chaos soak.
-
-    The model is a small real Transformer on the KV-cache decode path:
-    the numbers are not headline figures, but every ratio
-    asserted here — continuous >= 2x static at saturation, zero
-    steady-state negotiations, prefix cache strictly lowering TTFT at
-    high sharing, speculation >= 1.3x tokens/s on a predictable stream,
-    zero disk reads on the clone path, zero lost requests through a
-    SIGKILL — is shape-level and carries.  The prefix/spec/router legs
-    use the stub backend (synthetic per-token prefill and per-step decode
-    cost) so the ratios measure scheduling, not XLA dispatch jitter."""
-    import jax
-    import jax.numpy as jnp
-
-    from horovod_tpu.core.engine import NativeEngine
-    from horovod_tpu.core.executors import local_executor
-    from horovod_tpu.models.transformer import Transformer, TransformerConfig
-    from horovod_tpu.serving import loadgen, soak
-    from horovod_tpu.serving.engine import (ServingConfig, ServingEngine,
-                                            StubBackend, TransformerBackend)
-    from horovod_tpu.serving.router import ModelSpec, Router
-    from horovod_tpu.utils import chip
-
-    chip.require_tpu("bench.py serving phase")
-    cfg = ServingConfig(num_slots=8, buckets=(16, 32, 64), max_seq_len=128)
-    mcfg = TransformerConfig(vocab_size=256, num_layers=2, num_heads=2,
-                             head_dim=16, embed_dim=32, mlp_dim=64,
-                             max_seq_len=cfg.max_seq_len)
-    model = Transformer(mcfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.zeros((1, cfg.buckets[0]), jnp.int32))
-
-    def make_engine(static: bool, collective=None) -> ServingEngine:
-        backend = TransformerBackend(model, params, mcfg, cfg.num_slots,
-                                     cfg.max_seq_len)
-        c = ServingConfig(num_slots=cfg.num_slots, buckets=cfg.buckets,
-                          max_seq_len=cfg.max_seq_len, static_batching=static)
-        return ServingEngine(backend, c, collective=collective)
-
-    # Mixed lengths with a fat tail: the regime where a drain barrier
-    # hurts (slots idle while the straggler finishes).
-    w = loadgen.Workload(qps=1.0, duration_s=1.0, seed=0,
-                         prompt_lens=(6, 14, 30), short_new=2, long_new=48,
-                         long_frac=0.125, vocab=256)
-
-    def saturate(static: bool) -> float:
-        """Closed-loop service throughput: submit a fixed mixed batch,
-        drain, report tokens/s (arrival noise excluded by design).  Each
-        slot-group carries exactly one long straggler — the drain
-        barrier's worst case is its COMMON case in mixed traffic, and a
-        deterministic mix keeps the two runs comparable."""
-        import random as _random
-
-        eng = make_engine(static)
-        rng = _random.Random(1)
-        for _ in range(6):  # 6 waves of num_slots requests
-            group = [96] + [4] * (cfg.num_slots - 1)
-            for max_new in group:
-                plen = rng.choice(w.prompt_lens)
-                prompt = [rng.randrange(256) for _ in range(plen)]
-                eng.submit(prompt, max_new)
-        eng.step()  # compile prefill+decode outside the timed window
-        t0 = time.perf_counter()
-        done = eng.run_until_idle()
-        wall = time.perf_counter() - t0
-        return sum(len(r.tokens) for r in done) / max(wall, 1e-9)
-
-    cont_tps = saturate(static=False)
-    stat_tps = saturate(static=True)
-    ratio = cont_tps / max(stat_tps, 1e-9)
-    assert ratio >= 2.0, (
-        f"continuous batching must beat the drain barrier >= 2x at "
-        f"saturation: continuous={cont_tps:.1f} static={stat_tps:.1f} tok/s")
-    print(json.dumps({
-        "metric": "serving_continuous_vs_static",
-        "value": round(ratio, 2),
-        "unit": "x",
-        "vs_baseline": round(ratio, 2),
-        "continuous_tokens_per_s": round(cont_tps, 1),
-        "static_tokens_per_s": round(stat_tps, 1),
-    }))
-
-    # Open-loop Poisson sweep: sub-saturation, near-saturation, and
-    # over-saturation arrival rates around the measured service capacity.
-    # The capacity estimate must come from an OPEN-loop calibration run —
-    # the closed-loop figure above excludes per-request prefill dispatch
-    # and arrival handling, which dominate at this model size.
-    dur = float(os.environ.get("BENCH_SERVE_DURATION_S", "2"))
-    # One backend for calibration + sweep: its jitted prefill (one program
-    # per bucket) and decode compile during calibration, so the sweep's
-    # latencies measure SERVING, not XLA compilation.
-    sweep_backend = TransformerBackend(model, params, mcfg, cfg.num_slots,
-                                       cfg.max_seq_len)
-    warm = ServingEngine(sweep_backend, cfg)
-    for plen in w.prompt_lens:  # one compile per prefill bucket + decode
-        warm.submit(list(range(plen)), 2)
-    warm.run_until_idle()
-    cal = loadgen.run_load(
-        ServingEngine(sweep_backend, cfg),
-        loadgen.Workload(qps=500.0, duration_s=1.0, seed=3,
-                         prompt_lens=w.prompt_lens, short_new=w.short_new,
-                         long_new=w.long_new, long_frac=w.long_frac,
-                         vocab=256),
-        max_wall_s=30.0)
-    sat = loadgen.saturating_qps(cal["tokens_per_s"], w)
-    for frac in (0.25, 0.5, 1.0):
-        q = max(sat * frac, 1.0)
-        eng = ServingEngine(sweep_backend, cfg)
-        wq = loadgen.Workload(qps=q, duration_s=dur, seed=2,
-                              prompt_lens=w.prompt_lens,
-                              short_new=w.short_new, long_new=w.long_new,
-                              long_frac=w.long_frac, vocab=256)
-        rep = loadgen.run_load(eng, wq, max_wall_s=dur * 20)
-        print(json.dumps({
-            "metric": "serving_tokens_per_s",
-            "value": round(rep["tokens_per_s"], 1),
-            "unit": "tok/s",
-            "qps": round(q, 1),
-            "qps_frac_of_saturation": frac,
-            "offered": rep["offered"],
-            "completed": rep["completed"],
-            "ttft_p50_ms": round(rep["ttft_p50_ms"], 2),
-            "ttft_p99_ms": round(rep["ttft_p99_ms"], 2),
-            "token_p50_ms": round(rep["token_p50_ms"], 3),
-            "token_p99_ms": round(rep["token_p99_ms"], 3),
-        }))
-
-    # Cache warmth: the serving.tick collective is ONE fixed
-    # name/shape/dtype allreduce per decode step, so after the first tick
-    # negotiates, steady state must be all response-cache hits — zero
-    # NEGOTIATED instants on the hot path.
-    def port() -> int:
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        p = s.getsockname()[1]
-        s.close()
-        return p
-
-    coll = NativeEngine(0, 1, executor=local_executor,
-                        coordinator_host="127.0.0.1",
-                        coordinator_port=port(), cycle_time_ms=1.0)
-    try:
-        eng = make_engine(static=False, collective=coll)
-        for k in range(8):
-            eng.submit([(7 * k + i) % 256 for i in range(6)], 12)
-        eng.run_until_idle()
-        cs = coll.cache_stats()
-        steps = eng.counters["steps"]
-        assert steps > 0, "cache-warm probe served nothing"
-        assert cs["misses"] <= 1 and cs["hits"] >= steps - 1, (
-            f"steady-state serving ticks must be response-cache hits "
-            f"(zero NEGOTIATED): {cs} over {steps} steps")
-        print(json.dumps({
-            "metric": "serving_tick_cache_hits",
-            "value": cs["hits"],
-            "unit": "ticks",
-            "misses": cs["misses"],
-            "steps": steps,
-        }))
-    finally:
-        coll.shutdown()
-
-    # Prefix cache: shared-system-prompt traffic at two sharing
-    # fractions, cache ON vs OFF.  The stub backend charges synthetic
-    # prefill compute per prefilled token, so the TTFT saving measures
-    # exactly what the cache removes: re-prefilling the shared prefix.
-    # The completion streams are identical either way (the stub's first
-    # token is a function of the FULL prompt) — only latency moves.
-    import random as _random
-
-    prefix_rows = {}
-    for frac in (0.5, 0.9):
-        for cache_on in (False, True):
-            scfg = ServingConfig(num_slots=8, buckets=(16, 32, 64, 96),
-                                 max_seq_len=128,
-                                 prefix_cache_pages=32 if cache_on else 0,
-                                 page_size=8)
-            seng = ServingEngine(
-                StubBackend(scfg.num_slots, 256, step_s=0.0002,
-                            prefill_s_per_token=0.0008), scfg)
-            wq = loadgen.Workload(qps=30.0, duration_s=dur, seed=5,
-                                  prompt_lens=(6, 14, 30), short_new=4,
-                                  long_new=16, long_frac=0.1, vocab=256,
-                                  shared_frac=frac, shared_prefix_len=48)
-            rep = loadgen.run_load(seng, wq, max_wall_s=dur * 30)
-            st = seng.stats()
-            prefix_rows[(frac, cache_on)] = rep
-            print(json.dumps({
-                "metric": "serving_prefix_ttft",
-                "value": round(rep["ttft_p50_ms"], 2),
-                "unit": "ms",
-                "cache": "on" if cache_on else "off",
-                "shared_frac": frac,
-                "prefix_hit_rate": round(st["prefix_hit_rate"], 3),
-                "prefix_evictions": st["prefix_evictions"],
-                "ttft_p99_ms": round(rep["ttft_p99_ms"], 2),
-                "tokens_per_s": round(rep["tokens_per_s"], 1),
-                "completed": rep["completed"],
-            }))
-            if cache_on:
-                assert st["prefix_hit_rate"] > 0.2, (
-                    f"shared_frac={frac}: prefix cache barely hit "
-                    f"({st['prefix_hit_rate']:.3f})")
-    on_p50 = prefix_rows[(0.9, True)]["ttft_p50_ms"]
-    off_p50 = prefix_rows[(0.9, False)]["ttft_p50_ms"]
-    assert on_p50 < off_p50, (
-        f"prefix cache must strictly lower TTFT p50 at 90% sharing: "
-        f"on={on_p50:.2f}ms off={off_p50:.2f}ms")
-
-    # Speculative decoding: a periodic token stream the n-gram proposer
-    # can actually predict.  Closed-loop (submit all, drain) so tokens/s
-    # isolates decode-step count; the stub charges step_s per decode AND
-    # per verify step, so the uplift comes only from accepted drafts
-    # collapsing steps — the honest accounting.
-    def spec_run(k: int):
-        scfg = ServingConfig(num_slots=8, buckets=(16, 32),
-                             max_seq_len=128, spec_k=k)
-        seng = ServingEngine(StubBackend(scfg.num_slots, 256, step_s=0.002,
-                                         period=8), scfg)
-        rng = _random.Random(7)
-        for _ in range(16):
-            plen = rng.choice((6, 10))
-            seng.submit([rng.randrange(8) for _ in range(plen)], 48)
-        t0 = time.perf_counter()
-        done = seng.run_until_idle()
-        wall = time.perf_counter() - t0
-        toks = sum(len(r.tokens) for r in done)
-        return toks / max(wall, 1e-9), seng.stats()
-
-    plain_tps, _ = spec_run(0)
-    spec_tps, spec_st = spec_run(4)
-    uplift = spec_tps / max(plain_tps, 1e-9)
-    assert uplift >= 1.3, (
-        f"speculation must lift tokens/s >= 1.3x on the repetitive "
-        f"stream: plain={plain_tps:.1f} spec={spec_tps:.1f} tok/s")
-    assert spec_st["spec_accept_rate"] > 0.3, spec_st
-    print(json.dumps({
-        "metric": "serving_spec_decode_uplift",
-        "value": round(uplift, 2),
-        "unit": "x",
-        "plain_tokens_per_s": round(plain_tps, 1),
-        "spec_tokens_per_s": round(spec_tps, 1),
-        "spec_k": 4,
-        "spec_accept_rate": round(spec_st["spec_accept_rate"], 3),
-        "spec_drafted": spec_st["spec_drafted"],
-        "spec_accepted": spec_st["spec_accepted"],
-    }))
-
-    # Multi-model router: a fast chat model (2 replicas, tight SLO) and a
-    # slow code model (1 replica, loose SLO) behind one admission door;
-    # per-model TTFT SLO attainment is the row the router exists to move.
-    router = Router()
-
-    def stub_engine(step_s: float) -> ServingEngine:
-        rcfg = ServingConfig(num_slots=4, buckets=(16, 32), max_seq_len=64)
-        return ServingEngine(StubBackend(rcfg.num_slots, 256,
-                                         step_s=step_s), rcfg)
-
-    router.add_model(ModelSpec("chat", slo_ttft_ms=40.0),
-                     [stub_engine(0.0005), stub_engine(0.0005)])
-    router.add_model(ModelSpec("code", slo_ttft_ms=200.0),
-                     [stub_engine(0.004)])
-    rrng = _random.Random(11)
-    submitted = {"chat": 0, "code": 0}
-    for i in range(40):
-        name = "chat" if i % 2 == 0 else "code"
-        plen = rrng.choice((6, 12))
-        router.submit(name, [rrng.randrange(256) for _ in range(plen)], 8)
-        submitted[name] += 1
-    router.run_until_idle()
-    for name, st in router.stats().items():
-        assert st["completed"] == submitted[name], (name, st)
-        print(json.dumps({
-            "metric": "serving_router_slo",
-            "value": round(st["slo_attainment"], 3),
-            "unit": "frac",
-            "model": name,
-            "replicas": st["replicas"],
-            "slo_ttft_ms": st["slo_ttft_ms"],
-            "ttft_p50_ms": round(st["ttft_p50_ms"], 2),
-            "ttft_p99_ms": round(st["ttft_p99_ms"], 2),
-            "completed": st["completed"],
-        }))
-
-    # Autoscale chaos soak: grow under load (weights cloned over the bulk
-    # data plane, zero disk reads) + SIGKILL mid-traffic (zero lost) —
-    # with the prefix cache and speculation ON, the fast paths must not
-    # cost a single completion either.
-    r = soak.run_fleet(n=2, qps=30.0, duration_s=3.0, kill=True, join=True,
-                       swap=False, seed=0, prefix_cache=True, spec_k=3)
-    assert r["lost"] == 0 and r["join_disk_reads"] == 0, r
-    print(json.dumps({
-        "metric": "serving_autoscale_soak",
-        "value": r["completed"],
-        "unit": "requests",
-        "accepted": r["accepted"],
-        "lost": r["lost"],
-        "retried": r["retried"],
-        "join_disk_reads": r["join_disk_reads"],
-        "join_ms": round(r["join_ms"], 1) if r["join_ms"] else None,
-        "wall_s": round(r["wall_s"], 2),
-    }))
-
-
 def main() -> None:
-    from horovod_tpu.utils import chip
-
-    chip.enable_compile_cache()
-    if "serving" in sys.argv:
-        serving_bench()
-        return
     if "--fault" in sys.argv:
         if "--elastic" in sys.argv:
             elastic_bench()
@@ -1128,127 +594,12 @@ def main() -> None:
         return
     if os.environ.get("BENCH_SKIP_EAGER") != "1":
         eager_microbench()
-    if os.environ.get("BENCH_SKIP_PLAN") != "1":
-        overlap_plan_microbench()
     if os.environ.get("BENCH_SKIP_CKPT") != "1":
         checkpoint_bench()
     if os.environ.get("BENCH_SKIP_DATAPLANE") != "1":
         dataplane_bench()
     if os.environ.get("BENCH_SKIP_CONTROL_PLANE") != "1":
         control_plane_bench()
-    if os.environ.get("BENCH_SKIP_LONGCTX") != "1":
-        longctx_bench()
-    if os.environ.get("BENCH_SKIP_SERVING") != "1":
-        serving_bench()
-    if os.environ.get("BENCH_SKIP_RESNET") == "1":
-        return
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    import optax
-    from jax.sharding import PartitionSpec as P
-
-    import horovod_tpu as hvd
-    from horovod_tpu import faults
-    from horovod_tpu.models import ResNet50
-
-    chip.require_tpu("bench.py ResNet-50 phase")
-    hvd.init()
-    batch = int(os.environ.get("BENCH_BATCH", "128"))
-    warmup = int(os.environ.get("BENCH_WARMUP", "10"))
-    iters = int(os.environ.get("BENCH_ITERS", "10"))
-    batches_per_iter = int(os.environ.get("BENCH_BATCHES_PER_ITER", "10"))
-    # Steps executed inside ONE compiled program via lax.scan — the
-    # idiomatic TPU training loop (device loop, host out of the way).
-    steps_per_call = max(1, int(os.environ.get("BENCH_STEPS_PER_CALL", "8")))
-
-    n_chips = hvd.num_chips()
-    model = ResNet50(num_classes=1000, dtype=jnp.bfloat16)
-    rng = jax.random.PRNGKey(0)
-    x = jax.random.normal(rng, (batch * n_chips, 224, 224, 3), jnp.float32)
-    y = jax.random.randint(rng, (batch * n_chips,), 0, 1000)
-    variables = model.init(rng, x[:2], train=True)
-    params = variables["params"]
-    batch_stats = variables["batch_stats"]
-    opt = hvd.DistributedOptimizer(optax.sgd(0.01, momentum=0.9),
-                                   compression=hvd.Compression.none)
-    opt_state = opt.init(params)
-
-
-    def train_step(carry, x, y):
-        params, batch_stats, opt_state = carry
-
-        def loss_fn(p):
-            logits, mutated = model.apply(
-                {"params": p, "batch_stats": batch_stats}, x, train=True,
-                mutable=["batch_stats"])
-            return optax.softmax_cross_entropy_with_integer_labels(
-                logits, y).mean(), mutated["batch_stats"]
-
-        (loss, new_stats), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return (optax.apply_updates(params, updates), new_stats,
-                opt_state), loss
-
-    def k_steps(params, batch_stats, opt_state, x, y):
-        # The synthetic protocol reuses the same batch every step
-        # (reference pytorch_synthetic_benchmark.py:61-66 likewise feeds
-        # one tensor), so x/y ride as scan-invariant shard-local args — no
-        # steps_per_call-times replicated input buffer.
-        (params, batch_stats, opt_state), losses = jax.lax.scan(
-            lambda c, _: train_step(c, x, y),
-            (params, batch_stats, opt_state), None, length=steps_per_call)
-        return params, batch_stats, opt_state, losses[-1]
-
-    step = jax.jit(hvd.shard(
-        k_steps,
-        in_specs=(P(), P(), P(), hvd.batch_spec(4), hvd.batch_spec(1)),
-        out_specs=(P(), P(), P(), P())),
-        donate_argnums=(0, 1, 2))
-
-    bench_step = 0
-
-    def run_one():
-        nonlocal params, batch_stats, opt_state, bench_step
-        # Fault-injection clock (faults.py): HVD_TPU_FAULT_* scenarios —
-        # kill/stall/delay this rank at a given dispatch — replay
-        # deterministically against the benchmark, so robustness drills use
-        # the same harness as the throughput numbers.  Free when disarmed.
-        faults.step(bench_step)
-        bench_step += 1
-        params, batch_stats, opt_state, loss = step(
-            params, batch_stats, opt_state, x, y)
-        return loss
-
-    loss = None
-    for _ in range(warmup):
-        loss = run_one()
-    if loss is not None:
-        float(loss)  # hard sync: device-to-host fetch
-
-    # Each timed window ends with a host fetch of the final loss, which
-    # waits for every step dispatched in it.
-    rates = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        for _ in range(batches_per_iter):
-            loss = run_one()
-        float(loss)
-        dt = time.perf_counter() - t0
-        rates.append(batch * n_chips * batches_per_iter * steps_per_call / dt)
-
-    total = float(np.mean(rates))
-    per_chip = total / n_chips
-    print(json.dumps({
-        "metric": "resnet50_synthetic_train_throughput",
-        "value": round(per_chip, 2),
-        "unit": "img/s/chip",
-        "vs_baseline": round(per_chip / BASELINE_IMG_PER_SEC_PER_DEVICE, 3),
-        # The planner's decision for the step just timed — a throughput
-        # number is uninterpretable without the chain depth behind it.
-        "overlap_plan": hvd.overlap_plan(),
-    }))
 
 
 if __name__ == "__main__":

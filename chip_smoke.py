@@ -9,15 +9,15 @@ with the leg's name:
             donated state) with hvd.DistributedOptimizer(adamw) inside, the
             12-layer/768/6x128/3072/32000 bf16 transformer with the flash
             kernels at library-default tiles, S=1024, 8 sequences per chip
-            (examples/jax_transformer_benchmark.py's step, one optimizer
-            step per call).  Asserts: the compiled step holds Mosaic custom
+            (the dense training cells' step at a smaller model, one
+            optimizer step per call).  Asserts: the compiled step holds Mosaic custom
             calls (the kernels were compiled, not interpreted) and, on more
             than one chip, all-reduces; parameters and optimizer state come
             back replicated on every chip and the batch is split across
             them; the loss is finite and lower after four steps.
 ``resnet``  ResNet-50 bf16, batch 128 per chip, 8 steps scanned inside one
             program, DistributedOptimizer(sgd+momentum), donated state —
-            the step bench.py's headline phase builds — three calls.
+            the step the resnet50-imagenet cell builds — three calls.
 ``serve``   ServingEngine over TransformerBackend as
             ``python -m horovod_tpu.serving`` builds it, at the 162M
             widths: 8 slots, one prefill bucket, 6 requests of mixed

@@ -4,7 +4,7 @@ message size over the global mesh, plus the dispatch floor.
 This is ingredient (a) of the scaling-efficiency story (BASELINE.md: >=90%
 ResNet-50 scaling on v5e-64, matching reference README.md:45-51): measure
 what the collectives actually sustain, then project step-time dilution from
-gradient bytes (docs/benchmarks.md "Scaling efficiency projection").
+gradient bytes.
 
 On one real chip the data axis has width 1, so psum lowers to a no-op:
 what the harness records there is the DISPATCH floor (per-call latency of

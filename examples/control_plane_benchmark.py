@@ -29,8 +29,8 @@ scale, reference README.md:45-51).
 
     python examples/control_plane_benchmark.py --star 63,128,256,512
 
-Numbers recorded in docs/benchmarks.md (rounds 4-5) with the projected
-star ceiling.
+Numbers recorded in docs/benchmarks.md "Control-plane scaling: the rank-0
+star".
 """
 
 from __future__ import annotations

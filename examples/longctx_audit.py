@@ -1,6 +1,6 @@
 """Audit: is the ring K/V rotation issued before the step's kernel?
 
-The long-context round (docs/benchmarks.md) claims the ring attention
+The long-context round claimed the ring attention
 steps hide their ICI transfer behind the flash kernel: each scan step
 issues the ``ppermute`` for the NEXT step's K/V shard before calling this
 step's kernel, so the transfer and the compute can run concurrently.  On

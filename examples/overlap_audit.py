@@ -1,6 +1,6 @@
 """Audit: does XLA overlap the gradient AllReduce with backward compute?
 
-The scaling projection (docs/benchmarks.md) once listed comm/compute
+An early scaling projection listed comm/compute
 overlap inside the jitted step as a structural reason realized efficiency
 lands above the zero-overlap column.  This harness MEASURES that claim
 instead of assuming it, by compiling a real ``DistributedOptimizer`` step
@@ -24,7 +24,7 @@ Measured results:
   every gradient bucket into ONE synchronous tuple all-reduce scheduled
   after all backward compute — zero HLO-level overlap, on both the TPU
   (v5e:2x4, RotatedPincer ring emitter) and CPU backends.
-* Round 5 (this harness, recorded in docs/benchmarks.md): chaining the
+* Round 5 (this harness): chaining the
   bucket psums (collective_ops._chained_allreduce, now the
   DistributedOptimizer default) makes them uncombinable, and the
   schedule interleaves them with backward — 16 of 17 surviving

@@ -16,7 +16,7 @@ step (forward + backward + DistributedOptimizer update) with
 If the roofline story is right, ``nostats`` should claw back a large
 fraction of the ~31 ms (≈ +2/3 of the gap to the conv-only floor); if
 throughput barely moves, the floor is elsewhere and the claim dies.
-Numbers recorded in docs/benchmarks.md (round 4).
+What the `resnet50-imagenet` cell reads today is in PERF.md.
 
 Run on the real chip:  python examples/resnet_bn_experiment.py
 """

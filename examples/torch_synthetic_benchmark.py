@@ -15,8 +15,7 @@ reference are TPU-environment facts, not protocol changes:
   host-staged path (numpy views → device/TCP data plane) — this harness
   exists precisely to record what that path delivers.  Throughput-
   critical training belongs on the compiled jax path
-  (docs/benchmarks.md "torch binding throughput";
-  docs/troubleshooting.md steers migrators there).
+  (docs/troubleshooting.md steers migrators there).
 
 Run single-process, or under the launcher like the reference under
 mpirun:

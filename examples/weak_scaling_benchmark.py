@@ -1,6 +1,6 @@
 """Weak-scaling harness for the eager (engine) data plane.
 
-Ingredient (b) of the scaling-efficiency story (docs/benchmarks.md): run
+Ingredient (b) of the scaling-efficiency story: run
 the same per-rank work at -np 1/2/4/8 under the launcher and watch per-rank
 throughput — with a bandwidth-optimal allreduce the communication term per
 rank is ~2n bytes REGARDLESS of rank count (core/device_reduce.py), so

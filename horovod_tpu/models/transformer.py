@@ -1454,7 +1454,7 @@ class Transformer(nn.Module):
         # Head matmul in the compute dtype (bf16 hits the MXU at full rate;
         # f32 params, XLA accumulates in f32); logits upcast for the loss —
         # the standard LLM-trainer convention.  The f32 head matmul this
-        # replaces was ~15% of step time (docs/benchmarks.md profile).
+        # replaces was ~15% of step time (round 3's chip profile).
         if cfg.tie_embeddings:
             logits = embed.attend(x)
         else:

@@ -371,7 +371,7 @@ def _chained_allreduce(vals: list, axes, n_buckets: int) -> list:
     16 of 17 surviving all-reduces scheduled before the last backward
     fusion at default flags; with the async options, 4 explicit
     async-pair splits on top — examples/overlap_audit.py,
-    docs/benchmarks.md round 5).
+    round 5).
 
     The gate is ``where(isfinite(s), s, 0) * 0``: exactly 0.0 even for
     inf/NaN gradients (no cross-bucket poisoning), yet data-dependent and
@@ -386,7 +386,7 @@ def _chained_allreduce(vals: list, axes, n_buckets: int) -> list:
     Memory trade: pulling the reductions into backward extends gradient
     live ranges, raising peak HBM by up to a few hundred MB on large
     models (measured: 468M/B=16 OOMs by 79 MB with the default chain and
-    fits without it — docs/benchmarks.md round 5).  The schedule planner
+    fits without it — round 5's chip).  The schedule planner
     (ops/schedule_plan.py) budgets exactly this cost against the probed
     device headroom and degrades the depth — or bypasses the chain — when
     it would not fit, so chain memory pressure is a planner input, not a
@@ -430,7 +430,7 @@ def _chained_allreduce(vals: list, axes, n_buckets: int) -> list:
 
 
 # The load-bearing flag set for async bucket all-reduces (measured on the
-# v5e:2x4 AOT audit — docs/benchmarks.md round 5).  One source of truth:
+# v5e:2x4 AOT audit, round 5).  One source of truth:
 # overlap_compiler_options() serves runtime callers, and the deviceless
 # AOT audit (examples/overlap_audit.py) imports this constant directly so
 # its recorded numbers always describe the shipped flags.
@@ -495,7 +495,7 @@ def grouped_allreduce(tensors: Sequence, average: bool = True,
             # packing (a flat fusion buffer duplicates the backend's
             # batching and charges a pack+unpack pass over every gradient
             # byte — removing it measured +2.5 MFU points on the 162M
-            # transformer, docs/benchmarks.md round 4).  Whether the psums
+            # transformer on round 4's chip).  Whether the psums
             # are dependency-chained into buckets (overlapping backward,
             # round 5) or left free-combining is the schedule planner's
             # call, made here at trace time from the gradient manifest,

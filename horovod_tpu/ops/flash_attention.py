@@ -258,7 +258,7 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     elif nsub == 1:
         # Static single-tile case (the measured optimum): straight-line
         # bodies under pl.when — a dynamic-bound fori_loop here defeats
-        # Mosaic's scheduling and costs ~5 MFU points (docs/benchmarks.md).
+        # Mosaic's scheduling and costs ~5 MFU points (round 5's chip).
         run = hi >= 1
         interior = interior_end >= 1
 
@@ -329,7 +329,7 @@ def _pad_to(x, axis, multiple):
 
 
 # The kernels unroll the sub-tile sweep statically (a dynamic-bound
-# fori_loop defeats Mosaic's scheduling, docs/benchmarks.md round 5), so
+# fori_loop defeats Mosaic's scheduling, measured in round 5), so
 # each extra sub-tile emits TWO more guarded matmul bodies (interior +
 # boundary).  Past this many sub-tiles the code-size/compile-time bill
 # grows with no measured MFU return — warn instead of silently bloating.
@@ -366,7 +366,7 @@ def _sub_fit(block: int, sub: int) -> tuple[int, int]:
 # fit budget sits below the limit because this estimate cannot see
 # Mosaic's scheduling windows — exactly how the hand-set block_k=4096
 # passed review at S=8192 and then overflowed the remat backward at
-# S=32768 (docs/benchmarks.md round 5).  Requested blocks whose estimated
+# S=32768 (round 5's chip).  Requested blocks whose estimated
 # resident set exceeds the budget are halved with a warning instead of
 # failing inside Pallas.  Another device kind needs its own confirmation.
 VMEM_FIT_BUDGET_MB = 13.0
@@ -1037,7 +1037,7 @@ def _default_block_k(s_k: int, d: int) -> int:
     tile that compiles on EVERY shipped long-context config — pass
     block_k=4096 explicitly for the last bit at S ≤ 8192.  At d > 128
     the K/V tile bytes scale with d; the proven 1024 stays.
-    docs/benchmarks.md round 5."""
+    Readings of round 5's chip."""
     return min(max(s_k, 1), 2048 if d <= 128 else 1024)
 
 
@@ -1089,8 +1089,8 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     DMAs and few grid steps — while the in-kernel loop computes over
     ``sub``-sized slices so the [block_q, sub] intermediates bound scoped
     VMEM independent of S (the round-2 whole-sequence layout hit the
-    16 MiB wall at block_k >= 1024).  See docs/benchmarks.md for the
-    measured sweep.  ``block_k=None`` (the default) resolves to
+    16 MiB wall at block_k >= 1024).  The sweep was measured on round
+    5's chip.  ``block_k=None`` (the default) resolves to
     ``min(S, 2048)`` at d ≤ 128 (:func:`_default_block_k`): the larger
     streaming tile amortizes per-grid-step cost — 57.4 → 59.6 % MFU at
     S=8192 vs the 1024-tile baseline; ``block_k=4096`` (explicit)

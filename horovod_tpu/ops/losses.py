@@ -13,7 +13,7 @@ probability-scale entries (|d| ≤ 1) — noise below what mixed-precision
 backward already carries (accuracy pinned vs optax in
 tests/test_losses.py).
 
-Measured honestly (docs/benchmarks.md round-3 transformer profile): at
+Measured honestly (round 3's transformer profile, an earlier chip): at
 the 162M/32k-vocab benchmark size this is PERF-NEUTRAL — XLA still
 keeps an f32 logits-sized intermediate inside the CE fusion, and the
 loss chain overlaps with async DMA, so it sits off the critical path.

@@ -17,9 +17,9 @@ with the same headroom gets the same plan.
 
 * At data-parallel **width 1** ``psum`` is the identity: there is nothing to
   overlap and a chain only constrains the scheduler (-4.3% img/s on the
-  single-chip ResNet, docs/benchmarks.md round 5).  The planner bypasses it.
+  single-chip ResNet, round 5's chip).  The planner bypasses it.
 * The chain raises peak HBM (a 468M transformer ran out of memory by 79 MB
-  under a depth-4 chain and fit without it, same source).  The planner
+  under a depth-4 chain and fit without it, same round).  The planner
   estimates the chain's extra live bytes and halves the depth, down to the
   bypass, until the estimate fits the headroom.
 * With real width and slack headroom it keeps :data:`DEFAULT_CHAIN_DEPTH`.
@@ -455,8 +455,8 @@ def _reset_for_tests() -> None:
 # The same trace-time discipline as BucketPlan, applied to sequence
 # parallelism: shard width, plain-vs-zigzag layout, the flash kernel's
 # block_q/block_k, and the remat policy are one decision from one memory
-# model, not four hand-set knobs.  The motivating failure (BENCH r5,
-# docs/benchmarks.md): block_k=4096 wins at S=8192 but VMEM-OOMs the remat
+# model, not four hand-set knobs.  The motivating failure (round 5's
+# chip): block_k=4096 wins at S=8192 but VMEM-OOMs the remat
 # backward at S=32768 — tile choices must be VMEM-fit-clamped per workload.
 
 # Deterministic remat fallback when no headroom estimate exists (CPU/sim/

@@ -131,9 +131,6 @@ class ServingConfig:
     buckets: tuple[int, ...] = (16, 32, 64, 128)
     max_seq_len: int = 256
     eos_id: int | None = None
-    # Baseline mode for the bench: admit only into a fully drained batch
-    # (the classic static-batching barrier) instead of per-step.
-    static_batching: bool = False
     # Keep per-step logits on each request (tests only — unbounded).
     record_logits: bool = False
     # Shared-prefix KV reuse (serving/prefix_cache.py): pages of cache
@@ -947,8 +944,6 @@ class ServingEngine:
 
     def _admit(self, done: list[Request]) -> None:
         cfg = self.config
-        if cfg.static_batching and any(r is not None for r in self.slots):
-            return  # the drain barrier continuous batching removes
         for s in range(cfg.num_slots):
             if self.slots[s] is not None or not self.queue:
                 continue

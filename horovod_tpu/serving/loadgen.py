@@ -6,12 +6,11 @@ so queueing delay shows up in TTFT instead of being hidden by a closed
 feedback loop — the standard methodology for serving benchmarks.
 
 The workload is deterministic from its seed (arrival times, prompt
-lengths, output lengths), so continuous vs static batching — and a
+lengths, output lengths), so two engines under comparison — and a
 replica that retries a request after a kill — see the byte-identical
 request stream.  Output lengths are bimodal (mostly short, a long tail):
-the mix that makes static batching pay for its drain barrier, because a
-whole batch waits on its longest member while continuous batching
-refills the freed slots.
+the mix in which a batch that waited on its longest member would idle
+most of its slots, and continuous batching refills them.
 """
 
 from __future__ import annotations
@@ -99,8 +98,7 @@ def run_load(engine: ServingEngine, workload: Workload,
 
 def report(done, wall_s: float, offered: int = 0,
            timed_out: bool = False) -> dict:
-    """Latency/throughput summary over completed requests — the headline
-    row format docs/benchmarks.md "Serving" records."""
+    """Latency/throughput summary over completed requests."""
     ttft = [r.ttft_s for r in done if r.ttft_s is not None]
     tok = [s for r in done for s in r.token_lat_s]
     tokens = sum(len(r.tokens) for r in done)

@@ -28,7 +28,7 @@ Composition notes:
 
 ``stats()`` reports per-model queue depth, occupancy, TTFT percentiles
 and SLO attainment (fraction of completions whose TTFT met the model's
-``slo_ttft_ms``) — the rows ``bench.py serving`` sweeps.
+``slo_ttft_ms``).
 """
 
 from __future__ import annotations

@@ -21,7 +21,7 @@ survive:
   lost or corrupted**, the continuous-batching analog of PR-5's
   "survivors shrink and keep training".
 
-Used by the slow test (tests/test_serving.py), ``bench.py serving``, and
+Used by the slow test (tests/test_serving.py) and
 the ``make ci`` serving-soak leg (SERVING_SOAK_SKIP / SERVING_SOAK_REPS).
 """
 

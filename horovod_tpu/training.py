@@ -14,9 +14,9 @@ This is the TPU-native analog of the reference's L4 surface:
   with ``hvd.overlap_compiler_options()`` at jit time the TPU backend
   executes them as async continuation fusions — real comm/compute
   overlap, reproducing the reference's defining runtime property
-  (examples/overlap_audit.py, tests/test_overlap.py; docs/benchmarks.md).
-  The scaling projection still quotes its zero-overlap column as the
-  conservative floor.
+  (examples/overlap_audit.py, tests/test_overlap.py).  Whether the
+  overlap pays on four chips is the ``dsc1p3b-dp4`` cell's to say
+  (PERF.md).
 * ``broadcast_parameters`` / ``broadcast_optimizer_state`` — pytree-wide
   broadcast from a root worker, the state-bootstrap contract every reference
   binding ships (torch/__init__.py:153-301, tensorflow/__init__.py:90-133,

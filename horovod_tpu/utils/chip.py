@@ -1,14 +1,10 @@
 """What a measurement needs to know about the accelerator it runs on.
 
-Three small things every chip-facing entry point shares (``chip_smoke.py``,
-``bench.py``, ``python -m horovod_tpu.serving`` and the benchmark examples);
-the test suite calls none of them:
+Two small things the chip-facing entry points share (``chip_smoke.py``,
+``benchmarks/``, ``python -m horovod_tpu.serving``):
 
 * :func:`require_tpu` — a device phase that finds no chip raises instead of
   timing the CPU backend under a device metric's name;
-* :func:`peak_bf16_flops` — the utilization denominator, looked up by
-  ``device_kind`` in one table; a kind that is not in it is an error, not a
-  default;
 * :func:`enable_compile_cache` — JAX's persistent compilation cache at a
   path that can be placed from outside and never moves on its own.
 """
@@ -16,13 +12,6 @@ the test suite calls none of them:
 from __future__ import annotations
 
 import os
-
-# device_kind (as ``jax.devices()[0].device_kind`` reports it) -> peak dense
-# bf16 FLOP/s of one chip.  Source: Google Cloud documentation, "TPU v5e"
-# system architecture page (197 TFLOP/s bf16 per chip).
-PEAK_BF16_FLOPS = {
-    "TPU v5 lite": 197e12,
-}
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -41,23 +30,6 @@ def require_tpu(what: str) -> None:
             f"{os.environ.get('JAX_PLATFORMS', '')!r}).  Run it on a machine "
             f"with a TPU; a CPU timing is not reported under a device "
             f"metric's name.")
-
-
-def peak_bf16_flops(device_kind: str | None = None) -> float:
-    """Peak bf16 FLOP/s of one chip of ``device_kind`` (default: the first
-    device JAX reports)."""
-    if device_kind is None:
-        import jax
-
-        device_kind = jax.devices()[0].device_kind
-    try:
-        return PEAK_BF16_FLOPS[device_kind]
-    except KeyError:
-        raise ValueError(
-            f"no peak FLOP/s on record for device_kind {device_kind!r} "
-            f"(known: {sorted(PEAK_BF16_FLOPS)}); add it to "
-            f"horovod_tpu/utils/chip.py with its source before reporting a "
-            f"utilization on it") from None
 
 
 def enable_compile_cache() -> str:

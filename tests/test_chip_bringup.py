@@ -1,9 +1,14 @@
 """What the chip bring-up added to the program, checked without a chip:
-the compile-cache helper, the peak table, the no-TPU refusals, the
-launcher's one-process-per-chip rule, and the jax-free bench workers."""
+the compile-cache helper, the no-TPU refusals, the launcher's
+one-process-per-chip rule, the jax-free bench workers, and the one
+yardstick: bench.py times no device, the documents name files that exist,
+and PERF.md stays a file one read holds."""
 
+import ast
+import glob
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -76,14 +81,6 @@ def test_a_cached_program_comes_back_under_its_own_names(tmp_path):
     assert [p.stdout.strip() for p in seen] == [
         "['hvd_before']", "['hvd_after']", "['hvd_before']"], seen[1].stderr
     assert len(os.listdir(tmp_path)) >= 2       # and both were cached
-
-
-def test_peak_table_raises_for_unknown_device_kind():
-    assert chip.peak_bf16_flops("TPU v5 lite") == 197e12
-    with pytest.raises(ValueError, match="TPU v9000"):
-        chip.peak_bf16_flops("TPU v9000")
-    with pytest.raises(ValueError, match="cpu"):
-        chip.peak_bf16_flops()  # the suite's own device is not a chip
 
 
 def test_require_tpu_names_the_refused_phase():
@@ -179,3 +176,90 @@ def test_bench_and_soak_workers_never_import_jax():
                          capture_output=True, text=True, timeout=scaled(60))
     assert res.returncode == 0, res.stderr
     assert bench.CHILD_ENV == {"JAX_PLATFORMS": "cpu"}
+
+
+def _benchmark_names():
+    """(workloads, end-to-end metrics, every metric name a result line may
+    carry: a per-layer ``stall_share.lm`` is printed as ``stall_share``)."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    e2e = [m["name"] for m in spec["end_to_end"]]
+    keys = {n for m in spec["end_to_end"] + spec["per_layer"]
+            for n in (m["name"], m["name"].split(".")[0])}
+    return [w["name"] for w in spec["workloads"]], e2e, keys
+
+
+def test_bench_times_no_device(monkeypatch):
+    """bench.py is the host's phases and nothing else: ``main`` reaches
+    every phase it defines without asking for a TPU, no phase builds a model
+    or a serving engine, and every line a phase prints says what platform it
+    ran on under no name that is a metric of BENCHMARK.json."""
+    import bench
+
+    def refuse(what):
+        raise AssertionError(f"bench.py asked for a TPU: {what}")
+
+    monkeypatch.setattr(chip, "require_tpu", refuse)
+    phases = sorted(n for n, f in vars(bench).items()
+                    if callable(f) and n.endswith("bench"))
+    ran = []
+    for name in phases:
+        monkeypatch.setattr(bench, name, lambda name=name: ran.append(name))
+    for name in list(os.environ):
+        if name.startswith("BENCH_"):
+            monkeypatch.delenv(name)
+    for argv in ([], ["--fault"], ["--fault", "--elastic"]):
+        monkeypatch.setattr(sys, "argv", ["bench.py"] + argv)
+        bench.main()
+    assert sorted(ran) == phases and len(phases) == 6, (ran, phases)
+
+    with open(os.path.join(REPO, "bench.py")) as f:
+        tree = ast.parse(f.read())
+    imported = {a.name for n in ast.walk(tree) if isinstance(n, ast.Import)
+                for a in n.names}
+    imported |= {n.module for n in ast.walk(tree)
+                 if isinstance(n, ast.ImportFrom)}
+    assert not [m for m in imported if m.startswith((
+        "horovod_tpu.models", "horovod_tpu.serving", "horovod_tpu.utils.chip",
+        "flax", "optax"))], imported
+    _, _, metric_keys = _benchmark_names()
+    lines = [n.args[0] for n in ast.walk(tree)
+             if isinstance(n, ast.Call) and ast.unparse(n.func) == "json.dumps"]
+    assert len(lines) >= len(phases)
+    for line in lines:
+        fields = dict(zip((k.value for k in line.keys), line.values))
+        assert "platform" in fields, ast.unparse(line)
+        assert not (set(fields) | {fields["metric"].value}) & metric_keys, \
+            ast.unparse(line)
+
+
+def test_documents_name_files_that_exist():
+    """Every path of this repo that README.md or a docs/*.md names (a ``.py``
+    or ``docs/*.md`` under one of the tree's own directories, or a script at
+    the root) is there: a file deleted without its mention fails here.  The
+    upstream project's files are cited as ``reference <path>``."""
+    named = re.compile(
+        r"(reference\s+)?(?<![\w/.-])((?:examples|tests|benchmarks|docs|"
+        r"horovod_tpu)/(?:[\w.-]+/)*[\w-]+\.(?:py|md)"
+        r"|(?:bench|chip_smoke)\.py)\b")
+    missing = []
+    for doc in ["README.md"] + sorted(glob.glob("docs/*.md", root_dir=REPO)):
+        with open(os.path.join(REPO, doc)) as f:
+            for ref, path in named.findall(f.read()):
+                if not ref and not os.path.exists(os.path.join(REPO, path)):
+                    missing.append((doc, path))
+    assert not missing, missing
+
+
+def test_perf_md_keeps_its_own_rule():
+    """PERF.md is read whole by every session: under 300 lines, 1200
+    characters a line and 100000 bytes, and it names every workload and
+    every end-to-end metric of BENCHMARK.json."""
+    with open(os.path.join(REPO, "PERF.md"), encoding="utf-8") as f:
+        text = f.read()
+    lines = text.splitlines()
+    assert len(lines) < 300, len(lines)
+    assert max(map(len, lines)) <= 1200, max(map(len, lines))
+    assert len(text.encode("utf-8")) < 100_000
+    workloads, e2e, _ = _benchmark_names()
+    assert not [n for n in workloads + e2e if f"`{n}`" not in text]

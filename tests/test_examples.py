@@ -192,8 +192,8 @@ def test_jax_longseq_transformer_zigzag_remat():
 
 
 def test_weak_scaling_benchmark_np2():
-    """The weak-scaling harness (scaling-efficiency ingredient (b),
-    docs/benchmarks.md) runs under the launcher and reports per-rank rate
+    """The weak-scaling harness (scaling-efficiency ingredient (b))
+    runs under the launcher and reports per-rank rate
     plus the ~2V wire model."""
     out = _run_np2("weak_scaling_benchmark.py", "--grad-mb", "1",
                    "--compute-reps", "1", "--steps", "3", "--warmup", "1")

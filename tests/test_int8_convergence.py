@@ -9,7 +9,6 @@ the difference between converging near f32 and visibly biased training
 
 Reference contract being demonstrated: Compression = "lossy wire,
 unharmed training" (reference horovod/tensorflow/compression.py:42-63).
-Measured trajectories are recorded in docs/benchmarks.md (round 4).
 """
 
 import json
@@ -71,7 +70,7 @@ def test_width64_flat_ef_tracks_f32_trajectory():
     no-EF wire, which measurably wanders (stalls in the transient, then
     rides quantization noise) — trajectory deviation, not final loss, is
     the honest metric on a toy problem where any roughly-unbiased noise
-    still converges eventually (measured curves in docs/benchmarks.md)."""
+    still converges eventually."""
     r = _run("--width", "64", "--steps", "200")
     assert r["per_worker_levels"] == 1
     f32, ef, noef = r["f32"], r["int8_ef"], r["int8_noef"]

@@ -10,7 +10,7 @@ and the schedule interleaves the early buckets' all-reduces with backward
 all-reduces before the last backward fusion at default flags);
 ``hvd.overlap_compiler_options()`` additionally makes them async
 start/done pairs and continuation fusions on the real v5e backend —
-examples/overlap_audit.py, docs/benchmarks.md round 5.
+examples/overlap_audit.py.
 
 These tests pin both sides on the CPU sim: the shipped default keeps the
 bucket all-reduces split and interleaved; the same step without the chain

@@ -101,20 +101,6 @@ def test_continuous_admission_backfills_freed_slots():
                for r in done) or eng.counters["completed"] == 4
 
 
-def test_static_batching_holds_admissions_until_drain():
-    eng = ServingEngine(StubBackend(2), ServingConfig(
-        num_slots=2, buckets=(8,), max_seq_len=64, static_batching=True))
-    eng.submit([1], 3)
-    eng.submit([2], 3)
-    eng.submit([3], 3)
-    eng.step()
-    assert eng.counters["admitted"] == 2  # batch formed...
-    eng.step()
-    assert eng.counters["admitted"] == 2  # ...and the barrier holds
-    eng.run_until_idle()
-    assert eng.counters["completed"] == 3
-
-
 def test_over_length_evicted_mid_batch():
     eng = ServingEngine(StubBackend(1), ServingConfig(
         num_slots=1, buckets=(8,), max_seq_len=10))
@@ -453,6 +439,33 @@ def test_prefix_cache_bit_exact_vs_cold(small_model):
                 "prefix-attached decode diverged bitwise from cold prefill"
 
 
+def test_prefix_cache_lowers_ttft_at_high_sharing():
+    # Nine prompts in ten open with one 48-token system prompt.  The stub
+    # charges prefill by the token, so the cache's saving is the shared
+    # prefix it does not prefill again: the same arrivals see their first
+    # token sooner, and the streams are the same either way.
+    from horovod_tpu.serving import loadgen
+
+    w = loadgen.Workload(qps=30.0, duration_s=1.0, seed=5,
+                         prompt_lens=(6, 14, 30), short_new=4, long_new=16,
+                         long_frac=0.1, vocab=256, shared_frac=0.9,
+                         shared_prefix_len=48)
+
+    def run(pages):
+        eng = ServingEngine(
+            StubBackend(8, 256, step_s=0.0002, prefill_s_per_token=0.0008),
+            ServingConfig(num_slots=8, buckets=(16, 32, 64, 96),
+                          max_seq_len=128, prefix_cache_pages=pages,
+                          page_size=8))
+        rep = loadgen.run_load(eng, w, max_wall_s=60.0)
+        assert rep["completed"] == rep["offered"] > 0, rep
+        return rep["ttft_p50_ms"], eng.stats()["prefix_hit_rate"]
+
+    (off_p50, off_rate), (on_p50, on_rate) = run(0), run(32)
+    assert off_rate == 0.0 and on_rate > 0.2, (off_rate, on_rate)
+    assert on_p50 < off_p50, (on_p50, off_p50)
+
+
 # ---------------------------------------------------------------------------
 # Speculative decoding: lossless greedy acceptance, both paths
 # ---------------------------------------------------------------------------
@@ -473,23 +486,34 @@ def test_spec_decode_reject_path_identical_stream():
     assert st["spec_drafted"] > 0 and st["spec_accepted"] == 0
 
 
-def test_spec_decode_accept_path_same_tokens_fewer_steps():
+@pytest.mark.parametrize("slots, period, k, requests, max_new, uplift", [
+    (1, 4, 3, 1, 12, 1.0),
+    # the mix the retired serving bench timed: a decode and a verify step
+    # cost the same, so 1.3x fewer steps is 1.3x the tokens a second
+    (8, 8, 4, 16, 48, 1.3),
+])
+def test_spec_decode_accept_path_same_tokens_fewer_steps(
+        slots, period, k, requests, max_new, uplift):
     # The periodic stub is predictable, so the n-gram proposer's drafts
     # verify: same tokens as plain decode in strictly fewer steps.
-    def make(k):
-        return ServingEngine(StubBackend(1, period=4), ServingConfig(
-            num_slots=1, buckets=(8,), max_seq_len=64, spec_k=k))
+    import random
 
-    plain, spec = make(0), make(3)
-    prompt = [1, 2, 3]
-    a = plain.submit(prompt, 12)
-    plain.run_until_idle()
-    b = spec.submit(prompt, 12)
-    spec.run_until_idle()
-    assert a.tokens == b.tokens
+    def run(spec_k):
+        eng = ServingEngine(StubBackend(slots, period=period), ServingConfig(
+            num_slots=slots, buckets=(16,), max_seq_len=128, spec_k=spec_k))
+        rng = random.Random(7)
+        reqs = [eng.submit([rng.randrange(period)
+                            for _ in range(rng.choice((6, 10)))], max_new)
+                for _ in range(requests)]
+        eng.run_until_idle()
+        return eng, [r.tokens for r in reqs]
+
+    (plain, a), (spec, b) = run(0), run(k)
+    assert a == b
     st = spec.stats()
-    assert st["spec_accepted"] > 0 and st["spec_accept_rate"] > 0.0
+    assert st["spec_accepted"] > 0 and st["spec_accept_rate"] > 0.3
     assert spec.counters["steps"] < plain.counters["steps"]
+    assert plain.counters["steps"] >= uplift * spec.counters["steps"]
 
 
 def test_spec_decode_bit_exact_vs_plain(small_model):
