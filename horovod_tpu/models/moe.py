@@ -228,6 +228,15 @@ class MoEMLP(nn.Module):
     # what a token's gate weights are multiplied by, after norm_topk_prob
     # (a "routed scaling factor"); at 1 no op at all
     routed_scale: float = 1.0
+    # "noaux_tc" routing: a bias an expert (parameter ``expert_bias`` [E])
+    # is added to the scores that PICK a token's experts; the gate weights
+    # are taken from the scores without it
+    expert_bias: bool = False
+    # group-limited picks: the experts in ``groups`` groups of E / groups,
+    # a group's score the sum of its two best picking scores, a token's
+    # experts taken inside its ``topk_groups`` best groups.  0: no groups.
+    groups: int = 0
+    topk_groups: int = 0
 
     @nn.compact
     def __call__(self, x, valid=None):
@@ -294,8 +303,18 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
     if not 0 <= lo < hi <= e:
         raise ValueError(f"experts_held={m.experts_held} of num_experts={e}")
     held = hi - lo
+    if m.groups and (e % m.groups or not 0 < m.topk_groups <= m.groups
+                     or e // m.groups < 2
+                     or k > m.topk_groups * (e // m.groups)):
+        raise ValueError(
+            f"groups={m.groups}, topk_groups={m.topk_groups}: {e} experts "
+            f"in whole groups of two or more, and {k} picks inside the "
+            f"groups kept")
     lecun = nn.initializers.lecun_normal
     router_w = m.param("router", lecun(), (d, e), m.param_dtype)
+    if m.expert_bias:
+        pick_bias = m.param("expert_bias", nn.initializers.zeros, (e,),
+                            m.param_dtype)
     # fan-in is axis 1 of [E, in, out]; the experts are a batch
     stacked = lecun(in_axis=1, out_axis=2, batch_axis=0)
     w_gate = m.param("gate", stacked, (held, d, f), m.param_dtype)
@@ -343,7 +362,17 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
                          router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
         probs = SELECTIONS[m.selection](logits)               # [T, E]
-        _, picks = lax.top_k(lax.stop_gradient(probs), k)     # [T, k]
+        chosen_by = probs       # what picks; the gates are probs' own
+        if m.expert_bias:
+            chosen_by = chosen_by + pick_bias.astype(jnp.float32)
+        if m.groups:
+            by_group = chosen_by.reshape(-1, m.groups, e // m.groups)
+            best_two = lax.top_k(by_group, 2)[0].sum(axis=-1)  # [T, G]
+            kept = lax.top_k(best_two, m.topk_groups)[1]
+            kept = (kept[..., None] == jnp.arange(m.groups)).any(axis=-2)
+            chosen_by = jnp.where(kept[..., None], by_group,
+                                  -jnp.inf).reshape(chosen_by.shape)
+        _, picks = lax.top_k(lax.stop_gradient(chosen_by), k)  # [T, k]
         gates = jnp.take_along_axis(probs, picks, axis=-1)
         if m.norm_topk_prob:
             gates = gates / gates.sum(axis=-1, keepdims=True)

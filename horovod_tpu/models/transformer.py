@@ -97,9 +97,10 @@ class TransformerConfig:
     # The ContextPlan (ops/schedule_plan.plan_context) that decided the
     # layout, kernel tiles, and remat policy for this model.
     context_plan: Any = None
-    # The sequence mixer of each layer, by name: "attention" (Attention) or
-    # "mamba" (models/mamba.py: Mamba-2).  None is "attention" num_layers
-    # times; otherwise one entry a layer.  A layer type owns its parameters
+    # The sequence mixer of each layer, by name: "attention" (Attention),
+    # "mamba" (models/mamba.py: Mamba-2), "kda" (models/kda.py) or another
+    # key of MIXERS.  None is "attention" num_layers times; otherwise one
+    # entry a layer.  A layer type owns its parameters
     # and its sizes below; every layer is followed by the same feed-forward.
     layer_types: tuple | None = None
     # Grouped-query attention: K and V are projected at num_kv_heads heads
@@ -197,6 +198,31 @@ class TransformerConfig:
     mamba_groups: int = 1
     mamba_conv_width: int = 4
     mamba_chunk: int = 256
+    # Latent attention, further: q_lora_rank 0 projects the queries straight
+    # from the stream (no down-projection, no q norm); latent_qk_norm puts an
+    # RMSNorm over each head's whole query and one over the rotary key, both
+    # before the rotation (the nope part of a key comes from a latent that is
+    # normed already); attention_gate "head_wise" multiplies each head's
+    # output by the sigmoid of one more projection of the layer's input.
+    latent_qk_norm: bool = False
+    attention_gate: str | None = None
+    # Kimi Delta Attention ("kda" layers, models/kda.py): heads x head size
+    # for keys and values alike, the taps of the causal convolution on q, k
+    # and v, the lower bound of a step's log decay.  Its cache is a state [heads, head size, head size] float32 and the
+    # convolution's last taps - 1 inputs a slot, nothing a position.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_width: int = 4
+    kda_lower_bound: float = -5.0
+    # Sparse feed-forward, the routing of the DeepSeek-V3 family
+    # ("noaux_tc"; models/moe.py): moe_expert_bias adds a bias an expert
+    # (parameter ``expert_bias``) to the scores that PICK, never to the ones
+    # that weigh; moe_groups > 0 limits the picks to the moe_topk_groups
+    # groups of num_experts / moe_groups experts whose two best biased
+    # scores sum highest.
+    moe_expert_bias: bool = False
+    moe_groups: int = 0
+    moe_topk_groups: int = 0
 
     @classmethod
     def from_dict(cls, fields: dict) -> "TransformerConfig":
@@ -232,11 +258,37 @@ class TransformerConfig:
     def latent(self) -> bool:
         """Every layer is latent attention: the cache is one of latents."""
         kinds = set(self.layer_kinds)
-        if "latent_attention" in kinds and len(kinds) > 1:
+        others = kinds - {"latent_attention", "kda"}
+        if "latent_attention" in kinds and others:
             raise NotImplementedError(
-                f"latent attention beside {sorted(kinds - {'latent_attention'})}"
+                f"latent attention beside {sorted(others)}"
                 f" layers: the cache pool has one shape for all layers")
         return kinds == {"latent_attention"}
+
+    @property
+    def cache_layout(self) -> tuple | None:
+        """For a model with "kda" layers: ``(cache kind, index among the
+        layers of that kind)`` a layer.  Its pool is not two like-shaped
+        arrays but two trees with an entry a kind (:func:`init_kv_cache`):
+        a state a slot for the "kda" layers beside a row a position for the
+        others, each stacked over ITS layers alone.  None for every other
+        model: one kind, two arrays, indexed by the layer."""
+        kinds = self.layer_kinds
+        if "kda" not in kinds:
+            return None
+        cached = {CACHE_KINDS.get(kind) for kind in kinds}
+        if None in cached or "eva" in cached or len(cached) > 2:
+            raise NotImplementedError(
+                f"kda layers beside {sorted(set(kinds) - {'kda'})}: a pool of "
+                f"two kinds holds kda states beside keys and values or "
+                f"beside latents")
+        seen: dict = {}
+        layout = []
+        for kind in kinds:
+            kind = CACHE_KINDS[kind]
+            layout.append((kind, seen.get(kind, 0)))
+            seen[kind] = layout[-1][1] + 1
+        return tuple(layout)
 
     @property
     def eva(self) -> bool:
@@ -247,6 +299,13 @@ class TransformerConfig:
                 f"EVA attention beside {sorted(kinds - {'eva_attention'})} "
                 f"layers: the cache pool has one shape for all layers")
         return kinds == {"eva_attention"}
+
+
+# what a layer type keeps in the cache pool; a type that is not here (a
+# "mamba" layer) serves from no cache
+CACHE_KINDS = {"attention": "kv", "sliding_attention": "kv",
+               "full_attention": "kv", "latent_attention": "latent",
+               "eva_attention": "eva", "kda": "kda"}
 
 
 def _norm_scale(norm, width: int):
@@ -438,6 +497,12 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     K and V are never stored), or for a model of EVA attention K-side and
     V-side rows ``[L, slots, eva_window + S / eva_chunk, H, D]``: a ring of
     the current window's exact keys and values, then one summary a chunk.
+    A model with "kda" layers (``cfg.cache_layout``) gets two TREES in
+    their place, an entry a cache kind: ``{"kda": states [Lk, slots, H, D,
+    D] float32, "latent": latents [Ll, slots, S, rank]}`` and ``{"kda": the
+    convolutions' last inputs [Lk, slots, taps - 1, 3 H D], "latent":
+    rotary keys [Ll, slots, S, rope]}`` (``"kv"`` keys and values where its
+    other layers are plain attention).
     One slot is one serving sequence — the
     continuous-batching scheduler (serving/engine.py) admits a request
     into a free slot (prefill writes positions ``0..len``) and decode
@@ -448,6 +513,33 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     (:func:`write_kv_block`); a caller that donates them to its jitted
     program (``donate_argnums``) has them updated where they lie, one that
     does not pays a copy of both a call."""
+    layout = cfg.cache_layout
+    if layout is not None:
+        # a model with "kda" layers: two trees with an entry a cache kind,
+        # each stacked over the layers of that kind alone.  The first holds
+        # each kda layer's state a slot (float32: it is summed into over
+        # every position) and the second the last inputs of its causal
+        # convolution; beside them the other layers' rows a position.
+        count = lambda kind: sum(k == kind for k, _ in layout)  # noqa: E731
+        inner = cfg.kda_heads * cfg.kda_head_dim
+        first = {"kda": jnp.zeros(
+            (count("kda"), num_slots, cfg.kda_heads, cfg.kda_head_dim,
+             cfg.kda_head_dim), jnp.float32)}
+        second = {"kda": jnp.zeros(
+            (count("kda"), num_slots, cfg.kda_conv_width - 1, 3 * inner),
+            cfg.dtype)}
+        lead = (num_slots, max_len or cfg.max_seq_len)
+        if count("latent"):
+            first["latent"] = jnp.zeros(
+                (count("latent"),) + lead + (cfg.kv_lora_rank,), cfg.dtype)
+            second["latent"] = jnp.zeros(
+                (count("latent"),) + lead + (cfg.qk_rope_head_dim,),
+                cfg.dtype)
+        if count("kv"):
+            shape = (count("kv"),) + lead + (cfg.kv_heads, cfg.head_dim)
+            first["kv"] = jnp.zeros(shape, cfg.dtype)
+            second["kv"] = jnp.zeros(shape, cfg.dtype)
+        return first, second
     if cfg.eva:
         # a slot holds, a layer, a ring of the window's exact rows and then
         # one summary row a chunk of the positions it may reach: not a row
@@ -471,6 +563,13 @@ def init_kv_pages(cfg: TransformerConfig, num_pages: int, page_size: int):
     slot is a row of page ids (its page table) and a page holding a
     shared prompt-prefix chunk can appear in many slots' rows at once.
     Page 0 is the scratch page inactive slots point at."""
+    if "kda" in cfg.layer_kinds:
+        raise NotImplementedError(
+            "a paged pool beside a kda layer's recurrent state "
+            "(init_kv_pages, PagedTransformerBackend, the prefix cache) is "
+            "not built: a page of positions is no unit of a state, and a "
+            "shared prefix would need the state at its end; a model with "
+            "kda layers serves from init_kv_cache's pool")
     if cfg.latent:
         raise NotImplementedError(
             "a paged pool of latents (init_kv_pages, "
@@ -671,7 +770,14 @@ class LatentAttention(nn.Module):
     numbers: (q_nope W_UK^T) c_kv^T = q_nope (c_kv W_UK)^T.
 
     The softmax scale is (nope + rope)^-1/2, times YaRN's mscale squared
-    where ``rope_yarn`` is set (``attention_scale`` wins when given)."""
+    where ``rope_yarn`` is set (``attention_scale`` wins when given).
+
+    Further, each by a field of its own: ``q_lora_rank`` 0 projects the
+    queries straight from ``x`` (``q_up`` alone); ``latent_qk_norm`` puts an
+    RMSNorm over each head's whole query and one over the rotary key before
+    the rotation (both forms see the same normed numbers: the cache holds the
+    normed, rotated key); ``attention_gate`` "head_wise" multiplies head h's
+    output, in either form, by ``sigmoid(x w_gate,h)`` before ``W_O``."""
 
     cfg: TransformerConfig
 
@@ -682,11 +788,15 @@ class LatentAttention(nn.Module):
         h, r = cfg.num_heads, cfg.kv_lora_rank
         nope, rot, d_v = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                           cfg.v_head_dim)
-        if not (cfg.q_lora_rank and r and nope and rot and d_v):
+        if not (r and nope and rot and d_v):
             raise ValueError(
                 "a latent_attention layer needs TransformerConfig's "
-                "q_lora_rank, kv_lora_rank, qk_nope_head_dim, "
-                "qk_rope_head_dim and v_head_dim")
+                "kv_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
+                "v_head_dim (q_lora_rank 0: the queries straight from the "
+                "stream)")
+        if cfg.attention_gate not in (None, "head_wise"):
+            raise ValueError(f"attention_gate {cfg.attention_gate!r}; "
+                             f"latent attention has 'head_wise'")
         # as Attention: a served prefill's two position-wise sides run over
         # the prompt's row blocks
         rows, made = _prompt_rows(x, cache, return_kv, lengths)
@@ -709,20 +819,40 @@ class LatentAttention(nn.Module):
                                                   out_axis=(1, 2)),
             (r, h, nope + d_v), cfg.param_dtype).astype(cfg.dtype)
         o_proj = dense(cfg.embed_dim, axis=(-2, -1), name="o")
-        q_down, q_norm = dense(cfg.q_lora_rank, name="q_down"), norm("q_norm")
+        if cfg.q_lora_rank:
+            q_down = dense(cfg.q_lora_rank, name="q_down")
+            q_norm = norm("q_norm")
         kv_down, kv_norm = dense(r + rot, name="kv_down"), norm("kv_norm")
         q_up = dense((h, nope + rot), name="q_up")
+        if cfg.latent_qk_norm:
+            # over a head's whole query and over the one rotary key, before
+            # the rotation; one weight for all heads
+            q_head_norm, k_rope_norm = norm("q_head_norm"), norm("k_rope_norm")
+        if cfg.attention_gate:
+            gate_proj = dense(h, name="gate")
 
         def down_and_up(x, positions):
             with jax.named_scope(profiling.MLA_DOWN):
-                c_q = q_norm(q_down(x))
+                c_q = q_norm(q_down(x)) if cfg.q_lora_rank else x
                 down = kv_down(x)
                 c_kv = kv_norm(down[..., :r])
-                k_rope = turn(down[..., None, r:], positions)[..., 0, :]
+                k_rope = down[..., None, r:]
+                if cfg.latent_qk_norm:
+                    k_rope = k_rope_norm(k_rope)
+                k_rope = turn(k_rope, positions)[..., 0, :]
             with jax.named_scope(profiling.MLA_UP):
                 q = q_up(c_q)
+                if cfg.latent_qk_norm:
+                    q = q_head_norm(q)
                 return (c_kv, k_rope, q[..., :nope],
                         turn(q[..., nope:], positions))
+
+        def gated(out, x):
+            """A head's output times the sigmoid of its gate's logit."""
+            if not cfg.attention_gate:
+                return out
+            gate = jax.nn.sigmoid(gate_proj(x).astype(jnp.float32))
+            return (out * gate[..., None]).astype(out.dtype)
 
         def expanded(c_kv, k_rope, q_nope, q_rope):
             with jax.named_scope(profiling.MLA_UP):
@@ -747,7 +877,7 @@ class LatentAttention(nn.Module):
                     lengths, scale)
             with jax.named_scope(profiling.MLA_ABSORB):
                 out = jnp.einsum("bqhr,rhd->bqhd", o_lat, w_ukv[..., nope:])
-            return o_proj(out), (latents, rope_keys)
+            return o_proj(gated(out, x)), (latents, rope_keys)
 
         if cfg.context_axis and cfg.context_plan is not None:
             raise NotImplementedError(
@@ -760,8 +890,13 @@ class LatentAttention(nn.Module):
 
         q, k, v, c_kv, k_rope = _over_rows(heads, rows, x, positions)
         attn = cfg.attention_fn or dense_causal_attention
-        out = _over_rows(o_proj, rows, attn(
-            q, k, v, causal=True, scale=scale, **_prompt_end(cfg, lengths)))
+        out = attn(q, k, v, causal=True, scale=scale,
+                   **_prompt_end(cfg, lengths))
+        if cfg.attention_gate:
+            out = _over_rows(lambda out, x: o_proj(gated(out, x)), rows,
+                             out, x)
+        else:
+            out = _over_rows(o_proj, rows, out)
         return (out, (c_kv, k_rope)) if return_kv else out
 
 
@@ -1083,9 +1218,10 @@ MIXERS = {
                          "LatentAttention", "attn", {}),
     "eva_attention": ("horovod_tpu.models.transformer", "EvaAttention",
                       "attn", {}),
+    "kda": ("horovod_tpu.models.kda", "KDAMixer", "kda", {}),
 }
 CACHED_MIXERS = ("attention", "sliding_attention", "full_attention",
-                 "latent_attention", "eva_attention")
+                 "latent_attention", "eva_attention", "kda")
 
 
 def _scaled(x, multiplier: float):
@@ -1122,6 +1258,9 @@ def _feed_forward(cfg: TransformerConfig, dense: bool = False,
                      num_shared_experts=cfg.num_shared_experts,
                      experts_held=cfg.experts_held,
                      routed_scale=cfg.moe_routed_scale,
+                     expert_bias=cfg.moe_expert_bias,
+                     groups=cfg.moe_groups,
+                     topk_groups=cfg.moe_topk_groups,
                      param_dtype=cfg.param_dtype, name="moe_mlp")
         return lambda y, valid: moe(y, valid=valid)
     if cfg.moe_axis is not None:
@@ -1219,6 +1358,36 @@ def _over_rows(fn, rows, *xs, block: int = ROW_BLOCK):
             lambda a: jnp.zeros(
                 (a.shape[0], blocks * a.shape[1]) + a.shape[2:], a.dtype),
             one))
+
+
+def _over_rows_carrying(fn, rows, carry, *xs, block: int = ROW_BLOCK):
+    """:func:`_over_rows` for a ``fn`` that is position-wise but for a
+    state it hands from block to block (a recurrent mixer):
+    ``fn(carry, *xs) -> (carry, out)`` over arrays [B, S, ...], a row block
+    at a time in order, up to the block ``rows`` ends in.  Returns (the
+    carry after the last block visited, out [B, S, ...] with the rows of
+    the blocks never visited 0).  ``rows`` None: one call over the whole
+    sequence."""
+    if rows is None:
+        return fn(carry, *xs)
+    blocks = xs[0].shape[1] // block
+    piece = lambda x, i: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+        x, i * block, block, axis=1)
+    _, one = jax.eval_shape(fn, carry, *(jax.ShapeDtypeStruct(
+        x.shape[:1] + (block,) + x.shape[2:], x.dtype) for x in xs))
+
+    def body(i, state):
+        carry, out = state
+        carry, part = fn(carry, *(piece(x, i) for x in xs))
+        return carry, jax.tree.map(
+            lambda whole, part: jax.lax.dynamic_update_slice_in_dim(
+                whole, part, i * part.shape[1], axis=1), out, part)
+
+    return jax.lax.fori_loop(
+        0, (rows + block - 1) // block, body, (carry, jax.tree.map(
+            lambda a: jnp.zeros(
+                (a.shape[0], blocks * a.shape[1]) + a.shape[2:], a.dtype),
+            one)))
 
 
 class Block(nn.Module):
@@ -1351,8 +1520,14 @@ class Transformer(nn.Module):
     summary a chunk (``[L, B, eva_window + S / eva_chunk, H, D]`` twice;
     ``lengths`` beside ``return_kv`` says where each row's prompt ends, so
     that the ring is the one a decode step there expects):
-    :class:`EvaAttention`.  With ``num_pred_heads`` > 1 the logits' last axis
-    is ``num_pred_heads * vocab_size`` wide, head 0 (the next token) first.
+    :class:`EvaAttention`.  One with "kda" layers (``models/kda.py``) keeps
+    a recurrent state and a convolution tail a slot for each of them beside
+    its other layers' rows: ``kv_cache``, ``kv_into`` and what ``return_kv``
+    hands back are then two TREES with an entry a cache kind
+    (:func:`init_kv_cache`, ``cfg.cache_layout``), a layer given its own
+    kind's two arrays at its index among that kind's layers.  With
+    ``num_pred_heads`` > 1 the logits' last axis is ``num_pred_heads *
+    vocab_size`` wide, head 0 (the next token) first.
     """
 
     cfg: TransformerConfig
@@ -1406,6 +1581,9 @@ class Transformer(nn.Module):
             and not return_kv
         block_cls = nn.remat(Block) if remat_on else Block
         kvs = []
+        # a model with "kda" layers: the pool is two trees with an entry a
+        # cache kind, and a layer is one of ITS kind's (cfg.cache_layout)
+        layout = cfg.cache_layout if decode or return_kv else None
         # where each row's prompt ends, beside return_kv: a mixer whose
         # attention is a kernel stops it there, and EVA attention also lays
         # its cache block out for a decode step there
@@ -1414,7 +1592,17 @@ class Transformer(nn.Module):
         for i, kind in enumerate(kinds):
             block = block_cls(cfg, kind, i < cfg.first_dense_layers,
                               name=f"layer_{i}")
-            if decode:
+            if decode and layout is not None:
+                # ... of two kinds: the layer's own kind's two arrays go
+                # through it, at its index among that kind's layers
+                kind, at = layout[i]
+                x, (one, two) = block(
+                    x, positions, cache=(kv_cache[0][kind],
+                                         kv_cache[1][kind], lengths, at),
+                    **told)
+                kv_cache = ({**kv_cache[0], kind: one},
+                            {**kv_cache[1], kind: two})
+            elif decode:
                 # the whole pool goes through every layer: layer i writes
                 # its block into it and reads its own view of it
                 x, kv_cache = block(
@@ -1439,11 +1627,15 @@ class Transformer(nn.Module):
                 # layer runs (the barrier holds XLA to that order), so no
                 # layer's block outlives its layer
                 *pools, slot = kv_into
-                pools = [jax.lax.dynamic_update_slice(
+                kind, at = (None, i) if layout is None else layout[i]
+                own = pools if layout is None else [p[kind] for p in pools]
+                own = [jax.lax.dynamic_update_slice(
                     pool, block_[None].astype(pool.dtype),
-                    (i, slot) + (0,) * (pool.ndim - 2))
-                    for pool, block_ in zip(pools, kv)]
-                x, pools = jax.lax.optimization_barrier((x, pools))
+                    (at, slot) + (0,) * (pool.ndim - 2))
+                    for pool, block_ in zip(own, kv)]
+                x, own = jax.lax.optimization_barrier((x, own))
+                pools = own if layout is None else [
+                    {**p, kind: one} for p, one in zip(pools, own)]
                 kv_into = (*pools, slot)
             else:
                 x = block(x, positions, **told)
@@ -1471,6 +1663,12 @@ class Transformer(nn.Module):
             return logits, kv_cache
         if return_kv and kv_into is not None:
             return logits, tuple(kv_into[:2])
+        if return_kv and layout is not None:
+            # an entry a cache kind, each stacked over its own layers
+            return logits, tuple(
+                {kind: jnp.stack([kv[side] for kv, (k, _) in zip(kvs, layout)
+                                  if k == kind])
+                 for kind in dict(layout)} for side in (0, 1))
         if return_kv:
             return logits, (jnp.stack([kv[0] for kv in kvs]),
                             jnp.stack([kv[1] for kv in kvs]))

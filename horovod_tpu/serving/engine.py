@@ -359,8 +359,14 @@ class TransformerBackend:
         # closed and the windows they rolled over into
         self.eva_counters = {"windows": 0, "summaries": 0,
                              "chunks_closed": 0, "rollovers": 0}
+        # a model with "kda" layers keeps a state a slot beside its other
+        # layers' rows a position: over every call, the row blocks the state
+        # crossed in the prefills and the slots whose state a decode step
+        # advanced for a request
+        self.kda_counters = {"kda_blocks": 0, "state_slots": 0}
         # the pool is the model's to shape: K and V [L, slots, S, KV, D], or
-        # latents and their rotary keys [L, slots, S, rank] / [.., rope]
+        # latents and their rotary keys [L, slots, S, rank] / [.., rope], or
+        # for a model with "kda" layers two trees with an entry a cache kind
         self.kk, self.vv = init_kv_cache(model_cfg, num_slots, max_seq_len)
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
         self._decode = jax.jit(self._decode_fn, donate_argnums=(1, 2))
@@ -426,6 +432,11 @@ class TransformerBackend:
         return -(-int(bucket) // chunk)
 
     @property
+    def recurrent(self) -> bool:
+        """Whether the model has "kda" layers: its pool is of two kinds."""
+        return "kda" in self._model_cfg.layer_kinds
+
+    @property
     def flash_prefill(self) -> bool:
         """Whether a prompt of ``max_seq_len`` would take the kernel."""
         return self.prefill_attention(self.max_seq_len) == "flash"
@@ -487,13 +498,15 @@ class TransformerBackend:
             # of the bucket's padding (a model's own attention function is
             # not known to take a length; the dense form ignores it)
             told["lengths"] = jnp.reshape(length, (1,))
-        into_pool = self.eva or self.prefill_rows(padded.shape[1], 1)
+        into_pool = self.eva or self.recurrent \
+            or self.prefill_rows(padded.shape[1], 1)
         if into_pool:
             # the ring is laid out for a decode step at length, and a
             # layer's ring and summaries, a slot's whole extent, go into the
             # pool as the layer ends; so does a layer's block where the
             # layers loop over the prompt's row blocks: stacked, the loops'
-            # buffers would each be copied out first, and wait for it
+            # buffers would each be copied out first, and wait for it; and
+            # a pool of two kinds is written a layer into its own kind's
             told["kv_into"] = (kk, vv, slot)
         (logits, (pk, pv)), pairs = self._apply(
             self._prefill_model(padded.shape[1]), params, padded,
@@ -533,10 +546,11 @@ class TransformerBackend:
         return logits if cfg.num_pred_heads == 1 \
             else logits[..., :cfg.vocab_size]
 
-    def _count_eva(self, name: str, **counts) -> None:
-        """A call's counts into ``eva_counters`` and onto its span."""
+    def _count_on_span(self, name: str, counters: dict, **counts) -> None:
+        """A call's counts into ``counters`` (the running sums) and onto its
+        span."""
         for k, v in counts.items():
-            self.eva_counters[k] += v
+            counters[k] += v
         call = profiling.current_span()
         if call is not None and call.name == name:
             call.fields.update(counts)
@@ -587,32 +601,57 @@ class TransformerBackend:
 
     def _count_eva_prefill(self, length: int) -> None:
         cfg = self._model_cfg
-        self._count_eva(profiling.SRV_PREFILL,
-                        windows=-(-length // cfg.eva_window),
-                        summaries=length // cfg.eva_chunk)
+        self._count_on_span(profiling.SRV_PREFILL, self.eva_counters,
+                            windows=-(-length // cfg.eva_window),
+                            summaries=length // cfg.eva_chunk)
 
     def _count_eva_step(self, lengths: np.ndarray) -> None:
         cfg = self._model_cfg
         at = lengths[lengths > 0] - 1       # the positions this step writes
-        self._count_eva(
-            profiling.SRV_DECODE,
+        self._count_on_span(
+            profiling.SRV_DECODE, self.eva_counters,
             chunks_closed=int((at % cfg.eva_chunk == cfg.eva_chunk - 1).sum()),
             rollovers=int(((at > 0) & (at % cfg.eva_window == 0)).sum()))
 
+    def kda_blocks(self, bucket: int, length: int) -> int:
+        """The row blocks a "kda" layer's state is handed across in a
+        prefill of ``length`` in ``bucket``: the loop's trips
+        (:meth:`prefill_rows`), or the one piece a short bucket runs in."""
+        from horovod_tpu.models.transformer import ROW_BLOCK
+
+        rows = self.prefill_rows(bucket, length)
+        return rows // ROW_BLOCK if rows else 1
+
+    def _count_kda_prefill(self, bucket: int, length: int) -> None:
+        self._count_on_span(profiling.SRV_PREFILL, self.kda_counters,
+                            kda_blocks=self.kda_blocks(bucket, length))
+
+    def _count_kda_step(self, lengths: np.ndarray) -> None:
+        self._count_on_span(profiling.SRV_DECODE, self.kda_counters,
+                            state_slots=int((lengths > 0).sum()))
+
     def prefill(self, padded: np.ndarray, length: int, slot: int):
+        meanwhile = None
+        if self.eva:
+            meanwhile = functools.partial(self._count_eva_prefill,
+                                          int(length))
+        elif self.recurrent:
+            meanwhile = functools.partial(
+                self._count_kda_prefill, int(padded.shape[1]), int(length))
         first, logits, *pairs = self._call(
-            self._prefill, (padded,), (length, slot),
-            meanwhile=functools.partial(self._count_eva_prefill, int(length))
-            if self.eva else None)
+            self._prefill, (padded,), (length, slot), meanwhile=meanwhile)
         if pairs:
             self._count_pairs(pairs[0], int(length))
         return int(first), logits
 
     def decode(self, last_tokens: np.ndarray, lengths: np.ndarray):
+        meanwhile = None
+        if self.eva:
+            meanwhile = functools.partial(self._count_eva_step, lengths)
+        elif self.recurrent:
+            meanwhile = functools.partial(self._count_kda_step, lengths)
         nxt, logits, *pairs = self._call(
-            self._decode, (last_tokens, lengths),
-            meanwhile=functools.partial(self._count_eva_step, lengths)
-            if self.eva else None)
+            self._decode, (last_tokens, lengths), meanwhile=meanwhile)
         if pairs:
             self._count_pairs(pairs[0], int((lengths > 0).sum()))
         return nxt, logits

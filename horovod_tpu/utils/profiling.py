@@ -90,6 +90,15 @@ SSM_SCAN = "hvd_ssm_scan"       # ... ops/ssd_scan: softplus, the decays' sums,
 SSM_GATE = "hvd_ssm_gate"       # ... y * silu(z) and the gated RMSNorm
 SSD_FWD = "hvd_ssd_fwd"         # ops/ssd_scan: forward kernel, launched under
 SSD_BWD = "hvd_ssd_bwd"         # SSM_SCAN; ... backward kernel (no flash pass)
+KDA_PROJ = "hvd_kda_proj"       # models/kda: the six projections in (q, k, v,
+                                # decay, step size, output gate), the one out
+KDA_CONV = "hvd_kda_conv"       # ... causal depthwise conv of q, k, v over
+                                # the carried tail, silu, the l2 norms
+KDA_GATE = "hvd_kda_gate"       # ... the decay a key channel, the step size
+KDA_SCAN = "hvd_kda_scan"       # ... ops/kda_scan: the delta rule, chunked
+                                # (a pass without a cache) or one step (a
+                                # cache call), and the state's write
+KDA_OUT = "hvd_kda_out"         # ... the norm a head and the output gate
 LOADER_WAIT = "hvd_loader_wait"         # data.BackgroundLoader: q.get()
 LOADER_PRODUCE = "hvd_loader_produce"   # ... next(source), producer thread
 H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
@@ -112,6 +121,7 @@ MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
 MLA_SCOPES = (MLA_DOWN, MLA_UP, MLA_ABSORB, MLA_ATTN)
 SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE)
 SSD_PASSES = (SSD_FWD, SSD_BWD)
+KDA_SCOPES = (KDA_PROJ, KDA_CONV, KDA_GATE, KDA_SCAN, KDA_OUT)
 SRV_CALLS = (SRV_PREFILL, SRV_DECODE, SRV_VERIFY)   # the backend's calls
 SRV_LEAVES = (SRV_H2D, SRV_DISPATCH, SRV_WAIT, SRV_FETCH)   # in call order
 # records the span ring holds before it drops the oldest: a 35 s serving

@@ -35,7 +35,8 @@ NEW = {"decode_h2d_ms.srv": "serving backend", "decode_dispatch_ms.srv":
        "longest_wait_ms.srv": "serving backend", "longest_host_ms.srv":
        "serving backend", "idle_named_share.srv": "device"}
 SERVING_CELLS = ["dsc1p3b-code-0.8knee", "cmdaplus-code8k-open",
-                 "axk1-longdoc16k-open", "evabyte-code32k-open"]
+                 "axk1-longdoc16k-open", "evabyte-code32k-open",
+                 "ling3f-longdoc32k-open"]
 
 
 def since(mark: int) -> list:
