@@ -54,7 +54,8 @@ from horovod_tpu.models.mamba import _a_log_init, _dt_bias_init, _uniform
 from horovod_tpu.models.transformer import (RMSNorm, TransformerConfig,
                                             _over_rows_carrying,
                                             _prompt_rows)
-from horovod_tpu.ops.kda_scan import CHUNK, kda_chunked, kda_step
+from horovod_tpu.ops.kda_scan import (CHUNK, kda_chunked, kda_step,
+                                      scan_form)
 from horovod_tpu.utils import profiling
 
 F32 = jnp.float32
@@ -199,4 +200,7 @@ def kda_plan(cfg: TransformerConfig) -> dict:
             "conv_tail_bytes_per_layer_and_slot":
                 (cfg.kda_conv_width - 1) * 3 * inner
                 * jnp.dtype(cfg.dtype).itemsize,
-            "form": {"prefill": "chunked", "decode": "step"}}
+            "form": {"prefill": "chunked", "decode": "step"},
+            # what the chunked form runs as at these widths: "kernel", "xla"
+            "scan": scan_form(cfg.kda_heads, cfg.kda_head_dim,
+                              cfg.kda_head_dim)}

@@ -99,6 +99,8 @@ KDA_SCAN = "hvd_kda_scan"       # ... ops/kda_scan: the delta rule, chunked
                                 # (a pass without a cache) or one step (a
                                 # cache call), and the state's write
 KDA_OUT = "hvd_kda_out"         # ... the norm a head and the output gate
+KDA_CHUNK = "hvd_kda_chunk"     # ops/kda_scan: the chunked form's kernel,
+                                # launched under KDA_SCAN (no flash pass)
 LOADER_WAIT = "hvd_loader_wait"         # data.BackgroundLoader: q.get()
 LOADER_PRODUCE = "hvd_loader_produce"   # ... next(source), producer thread
 H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
@@ -367,8 +369,8 @@ class Scope:
     module: str                 # module_of(op_name)
     bucket: str | None = None   # "0".., "all": a collective under hvd_bucket_
     kernel: str | None = None   # a kernel's name: a FLASH_PASSES or
-                                # SSD_PASSES pass, TOKEN_SUM, or MOE_EXPERTS
-                                # (XLA's own grouped matmul)
+                                # SSD_PASSES pass, TOKEN_SUM, KDA_CHUNK, or
+                                # MOE_EXPERTS (XLA's own grouped matmul)
     bytes: int = 0              # of the result, from its shape
 
     @property
@@ -458,7 +460,7 @@ def scope_table(compiled) -> dict[str, Scope]:
             kernel = None
             if opcode == "custom-call":
                 kernel = next((k for k in FLASH_PASSES + SSD_PASSES
-                               + (TOKEN_SUM,) if k in op_name),
+                               + (TOKEN_SUM, KDA_CHUNK) if k in op_name),
                               MOE_EXPERTS
                               if op_name.startswith(_RAGGED_DOT_KERNEL)
                               else None)

@@ -26,6 +26,7 @@ from benchmarks.families import kda_mla_moe_serve as family  # noqa: E402
 from benchmarks.reference import kda_mla_moe_serve as reference  # noqa: E402
 from horovod_tpu.models import Transformer, TransformerConfig  # noqa: E402
 from horovod_tpu.models import transformer as T  # noqa: E402
+from horovod_tpu.ops import kda_scan  # noqa: E402
 from horovod_tpu.ops.kda_scan import (kda_chunked, kda_recurrent,  # noqa: E402
                                       kda_step)
 from horovod_tpu.serving import ServingConfig, ServingEngine  # noqa: E402
@@ -48,13 +49,36 @@ def drawn(key, b, s, h, dk, dv, near_bound):
     return q, k, v, g, beta, jax.random.normal(ks[5], (b, h, dk, dv))
 
 
+# heads, key width, value width: a tiny model's, which runs the XLA form, and
+# the served cell's, which runs the kernel (interpret mode off the TPU)
+XLA_WIDTHS, KERNEL_WIDTHS = (3, 16, 8), (2, 128, 128)
+BOTH_FORMS = pytest.mark.parametrize(
+    "widths", [XLA_WIDTHS, KERNEL_WIDTHS], ids=["xla", "kernel"])
+
+
+def test_the_form_follows_from_the_shapes():
+    """The rule: whole 128-lane tiles of keys and values, an even number
+    of heads, chunks of 64 in sub-blocks of 16; anything else is XLA's."""
+    form = kda_scan.scan_form
+    assert form(*KERNEL_WIDTHS) == form(32, 128, 128, 64, 16) == "kernel"
+    assert form(*XLA_WIDTHS) == "xla"
+    assert form(TINY["num_attention_heads"], TINY["head_dim"],
+                TINY["head_dim"]) == "xla"
+    assert form(3, 128, 128) == form(2, 64, 128) == form(2, 128, 256) \
+        == form(2, 128, 128, 32, 16) == form(2, 128, 128, 64, 8) == "xla"
+    assert kda_scan.head_block(32) == 8 and kda_scan.head_block(6) == 6
+    assert kda_scan.head_block(20) == 4
+
+
 @pytest.mark.parametrize("near_bound", [False, True])
 @pytest.mark.parametrize("s", [1, 17, 64, 100, 200])
-def test_the_chunked_form_is_the_recurrence(s, near_bound):
+@BOTH_FORMS
+def test_the_chunked_form_is_the_recurrence(widths, s, near_bound):
     """(a): chunks of 64 in sub-blocks of 16, lengths that are no whole
-    chunks, and decays at the lower bound, where e^(+-sum g) over a chunk
-    is e^(+-320) and only pairwise decays are representable."""
-    args = drawn(jax.random.PRNGKey(s), 2, s, 3, 16, 8, near_bound)
+    chunks, a state that enters, and decays at the lower bound, where
+    e^(+-sum g) over a chunk is e^(+-320) and only pairwise decays are
+    representable."""
+    args = drawn(jax.random.PRNGKey(s), 2, s, *widths, near_bound)
     with jax.default_matmul_precision("highest"):
         o_r, s_r = kda_recurrent(*args)
         o_c, s_c = jax.jit(kda_chunked)(*args)
@@ -65,10 +89,12 @@ def test_the_chunked_form_is_the_recurrence(s, near_bound):
         assert float(jnp.min(args[3])) < -4.99
 
 
-def test_masked_positions_pass_the_state_unchanged():
+@BOTH_FORMS
+def test_masked_positions_pass_the_state_unchanged(widths):
     """A position with decay 1 and step 0 changes no state: how a padded
     bucket hands over the state at the prompt's own length."""
-    q, k, v, g, beta, s0 = drawn(jax.random.PRNGKey(5), 1, 90, 2, 8, 8, False)
+    q, k, v, g, beta, s0 = drawn(jax.random.PRNGKey(5), 1, 90, *widths,
+                                 False)
     live = jnp.arange(90) < 37
     _, masked = kda_chunked(q, k, v, jnp.where(live[None, :, None, None], g,
                                                0.0),
@@ -76,6 +102,38 @@ def test_masked_positions_pass_the_state_unchanged():
     _, short = kda_chunked(q[:, :37], k[:, :37], v[:, :37], g[:, :37],
                            beta[:, :37], s0)
     np.testing.assert_allclose(masked, short, atol=1e-6)
+
+
+def test_a_state_carried_between_two_calls_is_one_call():
+    """The kernel's state leaves a call as it would have stayed in VMEM:
+    two row blocks of 1024 with the state handed on are one call of 2048
+    (what ``_over_rows_carrying`` does with a long prompt)."""
+    *rows, s0 = drawn(jax.random.PRNGKey(6), 1, 2048, *KERNEL_WIDTHS, False)
+    scan = jax.jit(kda_chunked)
+    o_whole, s_whole = scan(*rows, s0)
+    o_1, s_1 = scan(*(x[:, :1024] for x in rows), s0)
+    o_2, s_2 = scan(*(x[:, 1024:] for x in rows), s_1)
+    np.testing.assert_array_equal(jnp.concatenate([o_1, o_2], 1), o_whole)
+    np.testing.assert_array_equal(s_2, s_whole)
+
+
+def test_the_kernel_form_is_differentiated_as_the_xla_form():
+    """``jax.grad`` through the kernel (a ``custom_vjp`` whose backward is
+    JAX's of the XLA form) is ``jax.grad`` through the XLA form."""
+    args = drawn(jax.random.PRNGKey(8), 1, 128, *KERNEL_WIDTHS, False)
+    weight = jax.random.normal(jax.random.PRNGKey(1), (1, 128, 2, 128))
+
+    def loss(scan, *args):
+        o, state = scan(*args)
+        return (o * weight).sum() + (state ** 2).sum()
+
+    every = tuple(range(6))
+    ours = jax.jit(jax.grad(lambda *a: loss(kda_chunked, *a), every))(*args)
+    xla = jax.jit(jax.grad(lambda *a: loss(kda_scan._scan_xla, *a), every))(
+        *args)
+    for name, mine, theirs in zip("q k v g beta state".split(), ours, xla):
+        np.testing.assert_allclose(mine, theirs, rtol=1e-4, atol=2e-5,
+                                   err_msg=name)
 
 
 def test_the_one_step_form_is_the_chunked_form():

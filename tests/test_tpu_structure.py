@@ -166,6 +166,109 @@ def test_the_scans_kernels_compile_wherever_their_rule_sends_them(
     assert kernels_of(2 * q, 32) == []
 
 
+def _kernels_named(text: str, name: str) -> list[str]:
+    """The ``op_name`` of every Mosaic custom call of ``text`` that holds
+    ``name``."""
+    return [line.split("op_name=")[1].split('"')[1]
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and name in line.split("op_name=")[1]]
+
+
+@pytest.mark.parametrize("b,s,heads,d", [
+    (1, 1024, 32, 128),     # a row block of Ling-3.0-flash's mixer
+    (1, 2048, 32, 128),     # its 2048 bucket: no loop, 32 chunks a call
+    (2, 64, 2, 128),        # the rule's least: one pair of heads, one chunk
+    (1, 100, 20, 128)])     # blocks of four heads of twenty, a padded length
+def test_the_delta_rules_kernel_compiles_wherever_its_rule_sends_it(
+        one_chip_mesh, monkeypatch, b, s, heads, d):
+    """``kda_chunked`` decides by shape alone whether the kernel runs, so
+    every shape its rule admits has to be one the chip's compiler takes,
+    within Mosaic's default VMEM (the kernel asks for no limit of its own):
+    one custom call under its name, the state out as it came in; off the
+    rule there is none."""
+    from horovod_tpu.ops import kda_scan
+    from horovod_tpu.utils import profiling
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.float32, sharding=one_chip)
+
+    def kernels_of(heads, d):
+        args = (*(shape(b, s, heads, d),) * 4, shape(b, s, heads),
+                shape(b, heads, d, d))
+        o, state = jax.eval_shape(kda_scan.kda_chunked, *args)
+        assert (o.shape, state.shape) == ((b, s, heads, d), args[-1].shape)
+        text = jax.jit(kda_scan.kda_chunked).lower(*args).compile().as_text()
+        return _kernels_named(text, profiling.KDA_CHUNK)
+
+    assert kda_scan.scan_form(heads, d, d) == "kernel"
+    assert len(kernels_of(heads, d)) == 1
+    for off in ((heads + 1, d), (heads, d // 2)):
+        assert kda_scan.scan_form(*off, off[1]) == "xla"
+        assert kernels_of(*off) == []
+
+
+def _served_prefill(mesh, monkeypatch, cell: str, bucket: int, max_len=None,
+                    **cut):
+    """(the model's configuration, the backend, the compiled prefill program
+    of ``bucket`` positions) of a served cell with ``cut`` replaced in its
+    configuration, beside a pool of two slots; nothing runs."""
+    import dataclasses
+    import os
+
+    from benchmarks import run as harness
+    from horovod_tpu.models import transformer as T
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(mesh, P())
+    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    manifest = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "BENCHMARK.json")
+    _, _, config, traffic = harness.load_cell(manifest, cell)
+    family = harness.load_module("families", config["family"])
+    cfg = dataclasses.replace(family.model_config(config, traffic), **cut)
+    model = T.Transformer(cfg)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    slots, max_len = 2, max_len or int(traffic["max_seq_len"])
+    pool = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: T.init_kv_cache(cfg, slots, max_len)))
+    with monkeypatch.context() as m:    # no pool is made: nothing runs
+        m.setattr(T, "init_kv_cache", lambda *a, **kw: (None, None))
+        backend = TransformerBackend(model, None, cfg, slots, max_len)
+    i32 = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
+    return cfg, backend, backend._prefill.lower(
+        params, *pool, on_chip(jax.ShapeDtypeStruct((1, bucket), jnp.int32)),
+        i32, i32).compile()
+
+
+def test_a_served_kda_prefill_holds_one_kernel_a_layer_under_its_scope(
+        one_chip_mesh, monkeypatch):
+    """The served cell's prefill of several row blocks, two "kda" layers of
+    it and the latent one: one custom call of the kernel's name a layer, inside
+    the loop over the prompt's blocks, its ``op_name`` under the layer's
+    ``hvd_kda_scan`` (which is how PR 34's rule files its time under that
+    scope's metrics)."""
+    from horovod_tpu.utils import profiling
+
+    _, _, compiled = _served_prefill(
+        one_chip_mesh, monkeypatch, "ling3f-longdoc32k-open", 4096,
+        max_len=8192, num_layers=3, first_dense_layers=2, vocab_size=1024,
+        layer_types=("kda", "kda", "latent_attention"))
+    text = compiled.as_text()
+    kernels = _kernels_named(text, profiling.KDA_CHUNK)
+    assert len(kernels) == 2
+    for layer, name in enumerate(sorted(kernels)):
+        assert f"/layer_{layer}/kda/while/body/{profiling.KDA_SCAN}/" in name
+        assert profiling.module_of(name) == (
+            f"Transformer/layer_N/kda/{profiling.KDA_SCAN}/"
+            f"{profiling.KDA_CHUNK}")
+
+
 def _arrays(shape: str) -> list[tuple[str, int]]:
     """[(dtype, elements)] of every array in a shape's text."""
     from horovod_tpu.utils.profiling import _ARRAY
@@ -482,37 +585,11 @@ def test_a_served_prefill_holds_one_loop_a_call_site_and_no_wider_buffer(
     many ``while`` ops at 4096 as at the cell's longest bucket (a chunk's
     apart), no ``[bucket, mlp_dim]`` array is left (a block's instead), and
     the program's peak is no larger than its parent's."""
-    import dataclasses
-    import os
-
-    from benchmarks import run as harness
     from horovod_tpu.models import transformer as T
-    from horovod_tpu.serving.engine import TransformerBackend
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = NamedSharding(one_chip_mesh, P())
-    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-        a.shape, a.dtype, sharding=one_chip)
-    manifest = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BENCHMARK.json")
-    _, _, config, traffic = harness.load_cell(manifest, cell)
-    family = harness.load_module("families", config["family"])
-    cfg = dataclasses.replace(family.model_config(config, traffic),
-                              num_layers=2, **cut)
-    model = T.Transformer(cfg)
-    params = jax.tree.map(on_chip, jax.eval_shape(
-        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
-    slots, max_len = 2, int(traffic["max_seq_len"])
-    pool = jax.tree.map(on_chip, jax.eval_shape(
-        lambda: T.init_kv_cache(cfg, slots, max_len)))
-    with monkeypatch.context() as m:    # no pool is made: nothing runs
-        m.setattr(T, "init_kv_cache", lambda *a, **kw: (None, None))
-        backend = TransformerBackend(model, None, cfg, slots, max_len)
+    cfg, backend, compiled = _served_prefill(
+        one_chip_mesh, monkeypatch, cell, bucket, num_layers=2, **cut)
     assert backend.prefill_rows(bucket, bucket) == bucket
-    i32 = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
-    compiled = backend._prefill.lower(
-        params, *pool, on_chip(jax.ShapeDtypeStruct((1, bucket), jnp.int32)),
-        i32, i32).compile()
     text = compiled.as_text()
     whiles, parent_peak = dict(
         (c, b) for c, _, b in PREFILL_CELLS)[cell][bucket]
