@@ -16,7 +16,14 @@ dense GLU MLP, in one of two layouts:
   result does not depend on what else is in the batch.  The layer sows the
   two auxiliary losses a sparse model is trained with and its per-expert
   load (``MOE_LOSSES``, ``MOE_STATS``; docs/parallelism.md).  Data-parallel
-  like any other layer: every chip holds all experts.
+  like any other layer: every chip holds all experts.  A chip's SHARE of
+  the experts (``experts_held``, a serving layout) routes over all of them
+  and computes its own; where its pairs are a small part of the ``T * k`` it
+  walks them in blocks (``_walk_held``), and there the three products are
+  Pallas kernels over a work list of small row tiles (ops/grouped_matmul.py,
+  ``hvd_moe_grouped``).  Every other path keeps XLA:TPU's own kernels for
+  ``lax.ragged_dot``: every expert held (training needs their backward), a
+  decode step, a bucket one block holds.
 * **one expert per device** (``num_experts`` = 0; ``axis_name`` a bound mesh
   axis, ``TransformerConfig.moe_axis``): a router picks one expert per token
   (switch routing), tokens travel to the device holding their expert over
@@ -43,6 +50,8 @@ from flax.traverse_util import flatten_dict
 from jax import lax
 
 from horovod_tpu.models.transformer import _over_rows, row_blocks
+from horovod_tpu.ops.grouped_matmul import (grouped_glu, grouped_matmul,
+                                            visited_rows)
 from horovod_tpu.ops.token_sum import add_rows_by_token
 from horovod_tpu.parallel.common import shard_init_rng
 from horovod_tpu.parallel.expert import expert_parallel_moe
@@ -55,7 +64,10 @@ MOE_STATS = "moe_stats"     # "expert_pairs": [E] int32, pairs per expert
                             # (profiling.expert_load reads it); "picks":
                             # [B, S, k] int32, each token's experts;
                             # "rows_visited": int32, the rows the layer
-                            # gathered, multiplied and combined for its pairs
+                            # gathered, multiplied and combined for its pairs;
+                            # where it walked them (_walk_held) "tile_rows":
+                            # int32, the rows of the row tiles its grouped
+                            # matmul worked (a product's visits x tile rows)
 
 
 def moe_aux_loss(cfg, collections) -> jax.Array:
@@ -110,7 +122,9 @@ _permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
                 lambda inverse, g: (g[inverse], None, None))
 
 
-# rows a tile of XLA:TPU's grouped matmul (lax.ragged_dot) holds
+# rows a tile of XLA:TPU's grouped matmul (lax.ragged_dot) holds: the kernels
+# of every path that does not walk (every expert held, a decode step, a bucket
+# of one block), and what a walked block is a whole number of
 GROUPED_ROW_TILE = 512
 
 
@@ -124,6 +138,17 @@ def held_block_rows(pairs: int, held: int, experts: int) -> int:
     return -(-twice_even // GROUPED_ROW_TILE) * GROUPED_ROW_TILE
 
 
+# rows a row tile of the walk's grouped matmuls holds (ops/grouped_matmul.py):
+# the MXU's own edge.  Swept on the chip at 64 to 512 rows over the three
+# served shapes, 32 to 520 rows an expert (PERF.md section 6, PR 51): 128 was
+# the fastest or within 0.1% of it at every one, because the work list adapts
+# to the rows a group holds by itself (a group of 500 rows is four visits
+# that share one fetch of its weights, a group of 30 shares its tile with
+# three others), so nothing is left for a rule over the static shapes to
+# choose; 512, XLA:TPU's own, was 1.4-1.9 times slower.
+WALK_ROW_TILE = 128
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _walk_blocks(static, tokens, order, pairs, gates, w_gate, w_up, w_down):
     """The routed sum [T, D] float32 of a layer that holds a share of the
@@ -135,7 +160,9 @@ def _walk_blocks(static, tokens, order, pairs, gates, w_gate, w_up, w_down):
     float32 and added to their tokens.  As many blocks run as the held
     pairs fill, ``ceil(pairs.sum() / C)``: one while the routing is near
     even, ``T*k / C`` if every pair were held; none is dropped at any load
-    and no array has ``T*k`` rows."""
+    and no array has ``T*k`` rows.  The grouped matmuls are
+    ops/grouped_matmul.py's, in row tiles of :data:`WALK_ROW_TILE`: gate, up
+    and the activation one kernel (float32, rounded once), down another."""
     k, c = static
     t, d = tokens.shape
     n_held = pairs.sum()
@@ -155,12 +182,13 @@ def _walk_blocks(static, tokens, order, pairs, gates, w_gate, w_up, w_down):
         with jax.named_scope(profiling.MOE_EXPERTS):
             sizes = (jnp.clip(ends - at, 0, c)
                      - jnp.clip(starts - at, 0, c))
-            grouped = functools.partial(lax.ragged_dot, group_sizes=sizes)
-            hidden = nn.silu(grouped(rows, w_gate)) * grouped(rows, w_up)
-            out_rows = grouped(hidden, w_down)                 # [C, D]
+            hidden = grouped_glu(rows, w_gate, w_up, sizes,
+                                 tile=WALK_ROW_TILE)
+            out_rows = grouped_matmul(hidden, w_down, sizes,   # [C, D]
+                                      tile=WALK_ROW_TILE)
         with jax.named_scope(profiling.MOE_COMBINE):
             # rows past the last group are in no product: whatever the
-            # grouped matmul left there is taken out, not weighted
+            # kernels left there is taken out, not weighted
             live = at + jnp.arange(c) < n_held
             out = add_rows_by_token(out, out_rows, flat_gates[pair], token,
                                     live)
@@ -417,6 +445,11 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
         if not m.is_initializing():  # init returns parameters only
             m.sow(MOE_STATS, "expert_pairs", pairs)
             m.sow(MOE_STATS, "rows_visited", rows_visited)
+            if walks:
+                # (a block is whole tiles, so the blocks' visits are those
+                # of the held pairs laid end to end)
+                m.sow(MOE_STATS, "tile_rows",
+                      visited_rows(pairs, WALK_ROW_TILE))
             m.sow(MOE_STATS, "picks", picks.reshape(b, s, k))
             if m.selection == "softmax" and live is None:
                 logits, probs = every

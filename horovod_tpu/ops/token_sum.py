@@ -34,10 +34,10 @@ ROWS, TOKENS = 128, 128     # a visit: a chunk of sorted rows, a token block
 MAX_LANES = 2048            # of D a program instance works at a time
 
 
-def _lane_tile(d: int) -> int:
+def _lane_tile(d: int, at_most: int = MAX_LANES) -> int:
     """The widest multiple of 128 that divides ``d`` and is at most
-    :data:`MAX_LANES`; ``d`` itself where none does."""
-    for lanes in range(min(d, MAX_LANES) // 128 * 128, 0, -128):
+    ``at_most``; ``d`` itself where none does."""
+    for lanes in range(min(d, at_most) // 128 * 128, 0, -128):
         if d % lanes == 0:
             return lanes
     return d

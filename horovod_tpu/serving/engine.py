@@ -295,7 +295,9 @@ class TransformerBackend:
     each held expert of each layer was given, ``last_expert_pairs``
     ([L, held]), and the rows its expert layers visited for them (the
     running sums are ``moe_counters``; the call's span carries ``moe_rows``
-    and ``moe_held``).
+    and ``moe_held``, and where the layers walked their pairs in blocks
+    ``moe_tile_rows``: the rows of the row tiles their grouped matmul
+    worked).
 
     The pool (``kk``, ``vv``) is whatever ``init_kv_cache`` gives for the
     model: for one of latent attention the latents and their rotary keys,
@@ -348,11 +350,13 @@ class TransformerBackend:
         # row where one block holds them all), over every prefill and
         # decode call
         self.moe_counters = {"calls": 0, "pairs": 0, "held_pairs": 0,
-                             "rows_visited": 0}
+                             "rows_visited": 0, "tile_rows": 0}
         self._sparse_layers = range(model_cfg.first_dense_layers,
                                     model_cfg.num_layers)
         self._pairs_per_token = (len(self._sparse_layers)
                                  * model_cfg.experts_per_token)
+        lo, hi = model_cfg.experts_held or (0, model_cfg.num_experts)
+        self._experts_held = hi - lo
         self.eva = model_cfg.eva
         # over every call: the windows the prompts reached into and the
         # whole chunks they summarised (prefill); the chunks decode steps
@@ -465,8 +469,10 @@ class TransformerBackend:
         """``model.apply``; for a sparse model also what its expert layers
         sowed (once a chunk where the feed-forward ran in chunks), stacked
         over the sparse layers [L, held + 1]: the pairs each held expert
-        was given and, last, the rows the layer visited (one array, one
-        transfer to the host); else None."""
+        was given and the rows the layer visited (one array, one transfer
+        to the host); [L, held + 2] where the layers walked their pairs in
+        blocks, the rows of the tiles their grouped matmul worked last;
+        else None."""
         if not self.sparse:
             return model.apply(params, tokens, **kwargs), None
         from horovod_tpu.models.moe import MOE_STATS
@@ -477,9 +483,15 @@ class TransformerBackend:
         layers = [sown[MOE_STATS][f"layer_{i}"]["moe_mlp"]
                   for i in self._sparse_layers]
         total = lambda sown: functools.reduce(jnp.add, sown)  # noqa: E731
-        return out, jnp.stack([
-            jnp.append(total(lay["expert_pairs"]), total(lay["rows_visited"]))
-            for lay in layers])
+        # (a program's sparse layers share their shapes: all walk or none)
+        walked = all("tile_rows" in lay for lay in layers)
+
+        def counted(lay):
+            row = jnp.append(total(lay["expert_pairs"]),
+                             total(lay["rows_visited"]))
+            return jnp.append(row, total(lay["tile_rows"])) if walked else row
+
+        return out, jnp.stack([counted(lay) for lay in layers])
 
     def _prefill_fn(self, params, kk, vv, padded, length, slot):
         jax, jnp = self._jax, self._jax.numpy
@@ -556,19 +568,26 @@ class TransformerBackend:
             call.fields.update(counts)
 
     def _count_pairs(self, counted: np.ndarray, tokens: int) -> None:
-        """``counted`` [L, held + 1] is a call's: into the running sums, and
-        onto the ``hvd_srv_prefill`` / ``hvd_srv_decode`` span around it."""
-        self.last_expert_pairs = pairs = counted[:, :-1]
-        held, rows = int(pairs.sum()), int(counted[:, -1].sum())
+        """``counted`` [L, held + 1 or + 2] is a call's (:meth:`_apply`):
+        into the running sums, and onto the ``hvd_srv_prefill`` /
+        ``hvd_srv_decode`` span around it."""
+        self.last_expert_pairs = pairs = counted[:, :self._experts_held]
+        held = int(pairs.sum())
+        rows, *walked = (int(n) for n in
+                         counted[:, self._experts_held:].sum(axis=0))
+        fields = {"moe_rows": rows, "moe_held": held}
+        if walked:
+            fields["moe_tile_rows"] = walked[0]
         c = self.moe_counters
         c["calls"] += 1
         c["pairs"] += tokens * self._pairs_per_token
         c["held_pairs"] += held
         c["rows_visited"] += rows
+        c["tile_rows"] += sum(walked)
         call = profiling.current_span()
         if call is not None and call.name in (profiling.SRV_PREFILL,
                                               profiling.SRV_DECODE):
-            call.fields.update(moe_rows=rows, moe_held=held)
+            call.fields.update(fields)
 
     def _verify_fn(self, params, kk, vv, tok_block, lengths):
         jnp = self._jax.numpy
@@ -1267,7 +1286,10 @@ class ServingEngine:
         of them.  Where the model's feed-forward is
         sparse, ``hvd_srv_prefill`` and ``hvd_srv_decode`` have ``moe``: the
         ``rows`` the expert layers visited, the ``held_pairs`` they visited
-        them for, and ``rows_per_held_pair`` (1 would waste nothing)."""
+        them for, and ``rows_per_held_pair`` (1 would waste nothing); over
+        the calls whose layers walked their pairs in blocks also
+        ``tile_rows``, the rows of the row tiles their grouped matmul
+        worked, and ``tile_rows_per_held_pair``."""
         records = profiling.spans()
         out = profiling.summarize(records)
         by_attn: dict[str, dict] = {}
@@ -1300,6 +1322,13 @@ class ServingEngine:
                 held = sum(f["moe_held"] for f in sparse)
                 out[name]["moe"] = {"rows": rows, "held_pairs": held,
                                     "rows_per_held_pair": rows / max(held, 1)}
+                walked = [f for f in sparse if "moe_tile_rows" in f]
+                if walked:
+                    tile_rows = sum(f["moe_tile_rows"] for f in walked)
+                    out[name]["moe"].update(
+                        tile_rows=tile_rows,
+                        tile_rows_per_held_pair=tile_rows / max(sum(
+                            f["moe_held"] for f in walked), 1))
         return out
 
 

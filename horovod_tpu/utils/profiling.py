@@ -69,6 +69,10 @@ MOE_SHARED = "hvd_moe_shared"       # ... the experts every token visits
 TOKEN_SUM = "hvd_token_sum"     # ops/token_sum: the kernel that adds a walked
                                 # share's rows to their tokens, launched
                                 # under MOE_COMBINE
+MOE_GROUPED = "hvd_moe_grouped"     # ops/grouped_matmul: the walked share's
+                                    # grouped matmuls over a work list of
+                                    # small row tiles, launched under
+                                    # MOE_EXPERTS
 MLA_DOWN = "hvd_mla_down"       # models/transformer.LatentAttention: W_DQ,
                                 # W_DKV, the two norms, the rotary key, the
                                 # cache's write
@@ -179,7 +183,9 @@ class Record(typing.NamedTuple):
     ``fields`` the counts of the boundary (``bucket``, ``length``,
     ``slots``, ``live_tokens``, ``rids``, ``bytes``; of a sparse model's
     prefill or decode call ``moe_rows`` and ``moe_held``: the rows its
-    expert layers visited and the held pairs they visited them for; of a
+    expert layers visited and the held pairs they visited them for, and
+    where they walked ``moe_tile_rows``: the rows of the row tiles the
+    grouped matmul worked; of a
     prefill whose attention is a kernel ``attn_rows``: the query rows the
     kernel was asked to work, whole q blocks up to the prompt's end)."""
     name: str
@@ -369,8 +375,9 @@ class Scope:
     module: str                 # module_of(op_name)
     bucket: str | None = None   # "0".., "all": a collective under hvd_bucket_
     kernel: str | None = None   # a kernel's name: a FLASH_PASSES or
-                                # SSD_PASSES pass, TOKEN_SUM, KDA_CHUNK, or
-                                # MOE_EXPERTS (XLA's own grouped matmul)
+                                # SSD_PASSES pass, TOKEN_SUM, KDA_CHUNK,
+                                # MOE_GROUPED, or MOE_EXPERTS (XLA's own
+                                # grouped matmul)
     bytes: int = 0              # of the result, from its shape
 
     @property
@@ -409,7 +416,8 @@ def scope_table(compiled) -> dict[str, Scope]:
     (the head's matmul with the loss and its gradient) and is
     ``forward+backward``.  A collective is ``collective`` by
     opcode whatever its scope, and carries its ``hvd_bucket_<k>``; a kernel
-    (custom call) under ``hvd_flash_*``, ``hvd_ssd_*`` or ``hvd_token_sum`` carries that name, and one that
+    (custom call) under ``hvd_flash_*``, ``hvd_ssd_*``, ``hvd_token_sum``,
+    ``hvd_kda_chunk`` or ``hvd_moe_grouped`` carries that name, and one that
     XLA:TPU made of a ``ragged_dot`` carries ``hvd_moe_experts``.  ``while`` and
     ``conditional`` bodies are computations like the entry: their
     instructions are in the table under their own names.
@@ -460,7 +468,8 @@ def scope_table(compiled) -> dict[str, Scope]:
             kernel = None
             if opcode == "custom-call":
                 kernel = next((k for k in FLASH_PASSES + SSD_PASSES
-                               + (TOKEN_SUM, KDA_CHUNK) if k in op_name),
+                               + (TOKEN_SUM, KDA_CHUNK, MOE_GROUPED)
+                               if k in op_name),
                               MOE_EXPERTS
                               if op_name.startswith(_RAGGED_DOT_KERNEL)
                               else None)

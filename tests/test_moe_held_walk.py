@@ -252,6 +252,49 @@ def test_the_backend_counts_the_rows_its_layers_visited():
     summary = engine.span_summary()[profiling.SRV_PREFILL]["moe"]
     assert summary["rows"] >= calls[0].fields["moe_rows"]
     assert summary["rows_per_held_pair"] >= 1.0
+    # the prefill walked: the rows of the row tiles its grouped matmuls
+    # worked, whole tiles that hold every held pair and at most a tile an
+    # expert more; a decode step walks nothing and says nothing
+    tile = moe.WALK_ROW_TILE
+    tile_rows = calls[0].fields["moe_tile_rows"]
+    held = calls[0].fields["moe_held"]
+    assert tile_rows == c["tile_rows"] and tile_rows % tile == 0
+    assert held <= tile_rows <= calls[0].fields["moe_rows"] + 2 * 8 * tile
+    assert all("moe_tile_rows" not in r.fields for r in calls[1:])
+    assert summary["tile_rows"] >= tile_rows
+    assert 1.0 <= summary["tile_rows_per_held_pair"]
+    assert "tile_rows" not in engine.span_summary()[
+        profiling.SRV_DECODE]["moe"]
+
+
+def test_the_walk_runs_the_grouped_kernel_and_the_rest_xlas():
+    """The walk's jaxpr holds the Pallas grouped matmul under its name (the
+    fused gate-and-up and the down product) and no ``ragged_dot``; a decode
+    step's and an every-expert layer's hold ``ragged_dot`` and no kernel."""
+    from horovod_tpu.utils import profiling
+
+    def text(held, x):
+        m, params, _ = layer(held)
+        return str(jax.make_jaxpr(lambda p, x: m.apply(
+            p, x, mutable=[MOE_STATS]))(params, x))
+
+    _, _, x = layer((8, 16))
+    walk = text((8, 16), x)
+    assert walk.count(f"name={profiling.MOE_GROUPED}") == 2
+    assert "ragged_dot" not in walk
+    for carried in (text((8, 16), x[:, :16]), text(None, x)):
+        assert carried.count("ragged_dot_general[") == 3
+        assert profiling.MOE_GROUPED not in carried
+    # and the walk sows what its tiles held, the carried layers do not
+    m, params, _ = layer((8, 16))
+    _, sown = m.apply(params, x, mutable=[MOE_STATS])
+    stats = sown[MOE_STATS]
+    tile = moe.WALK_ROW_TILE
+    n_held = int(stats["expert_pairs"][0].sum())
+    assert n_held <= int(stats["tile_rows"][0]) <= n_held + 8 * tile
+    assert int(stats["tile_rows"][0]) % tile == 0
+    _, sown = m.apply(params, x[:, :16], mutable=[MOE_STATS])
+    assert "tile_rows" not in sown[MOE_STATS]
 
 
 @pytest.mark.parametrize("t, c, d, dtype, live_share", [

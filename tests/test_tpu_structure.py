@@ -548,8 +548,23 @@ def test_a_share_of_the_experts_keeps_no_array_of_all_its_pairs(
                and profiling.TOKEN_SUM in line]
     assert len(kernels) == 1 and profiling.MOE_COMBINE in kernels[0]
     assert walked.count(" while(") >= 1
+    # the walk's products are the grouped kernel (PR 51: gate, up and the
+    # activation one call, down another), named under the experts' scope
+    # and the layer's path as the token-sum is under the combine's, so a
+    # join by module keeps their time in the layer's; XLA's own
+    # ``ragged-dot`` kernels are the carried layer's alone
+    grouped = [line for line in walked.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line
+               and profiling.MOE_GROUPED in line]
+    assert len(grouped) == 2
+    path = re.search(r'op_name="([^"]*)' + profiling.TOKEN_SUM,
+                     kernels[0]).group(1).split(profiling.MOE_COMBINE)[0]
+    assert all(f'op_name="{path}{profiling.MOE_EXPERTS}/' in line
+               for line in grouped)
+    assert "ragged-dot" not in walked
     carried = text_of(held, None)
     assert all_pairs.search(carried) and profiling.TOKEN_SUM not in carried
+    assert "ragged-dot" in carried and profiling.MOE_GROUPED not in carried
 
 
 # The four serving cells cut to two layers at their own widths (for A.X-K1
