@@ -265,15 +265,31 @@ class MoEMLP(nn.Module):
     # experts taken inside its ``topk_groups`` best groups.  0: no groups.
     groups: int = 0
     topk_groups: int = 0
+    # > 0: the router is no one matrix but an MLP with a state (the ``zaya``
+    # family's): r = x W_d + b_d, that wide, plus ``router_decay`` * the r
+    # of the layer before where the caller hands one (``router_state``);
+    # the experts' logits are W_3 gelu(W_2 gelu(W_1 norm(r))) with biases on
+    # the first two, norm an RMSNorm at ``norm_eps``; all in float32.  The
+    # call then returns (out, r [B, S, router_dim] float32) for the next
+    # layer.  Parameters ``router_down`` / ``_bias``, ``router_decay``,
+    # ``router_norm``, ``router_w1`` / ``_b1``, ``router_w2`` / ``_b2``,
+    # ``router_w3`` in place of ``router``.
+    router_dim: int = 0
+    norm_eps: float = 1e-6
 
     @nn.compact
-    def __call__(self, x, valid=None):
+    def __call__(self, x, valid=None, router_state=None):
         """``valid`` ([B, S] bool, ``num_experts`` > 0 only): positions that
         hold a token.  One that does not (a bucket's padding, a slot with no
         request) is routed to no expert: its pairs are in no group of the
-        grouped matmuls and in no count."""
+        grouped matmuls and in no count.  ``router_state`` ([B, S,
+        router_dim] float32, ``router_dim`` > 0 only): the state the layer
+        before returned."""
         if self.num_experts > 0:
-            return _all_experts_here(self, x, valid)
+            return _all_experts_here(self, x, valid, router_state)
+        if router_state is not None:
+            raise ValueError("router_state is the every-expert-here "
+                             "layout's MLP router's")
         if valid is not None:
             raise ValueError("valid is the every-expert-here layout's")
         n_experts = lax.axis_size(self.axis_name)
@@ -309,7 +325,7 @@ class MoEMLP(nn.Module):
         return out.reshape(b, s, d).astype(x.dtype)
 
 
-def _all_experts_here(m: MoEMLP, x, valid=None):
+def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
     """``MoEMLP.__call__`` for ``num_experts`` > 0 (a function, so that flax
     adds no method's name to the module path of what it traces)."""
     if m.axis_name is not None:
@@ -339,7 +355,25 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
             f"in whole groups of two or more, and {k} picks inside the "
             f"groups kept")
     lecun = nn.initializers.lecun_normal
-    router_w = m.param("router", lecun(), (d, e), m.param_dtype)
+    r_dim = m.router_dim
+    if router_state is not None and not r_dim:
+        raise ValueError("router_state is an MLP router's (router_dim > 0)")
+    if r_dim:
+        f32 = lambda name, init, *shape: m.param(  # noqa: E731
+            name, init, shape, m.param_dtype).astype(jnp.float32)
+        zeros, ones = nn.initializers.zeros, nn.initializers.ones
+        mlp_w = {"down": f32("router_down", lecun(), d, r_dim),
+                 "down_bias": f32("router_down_bias", zeros, r_dim),
+                 "norm": f32("router_norm", ones, r_dim),
+                 "w1": f32("router_w1", lecun(), r_dim, r_dim),
+                 "b1": f32("router_b1", zeros, r_dim),
+                 "w2": f32("router_w2", lecun(), r_dim, r_dim),
+                 "b2": f32("router_b2", zeros, r_dim),
+                 "w3": f32("router_w3", lecun(), r_dim, e)}
+        if router_state is not None:
+            mlp_w["decay"] = f32("router_decay", ones, r_dim)
+    else:
+        router_w = m.param("router", lecun(), (d, e), m.param_dtype)
     if m.expert_bias:
         pick_bias = m.param("expert_bias", nn.initializers.zeros, (e,),
                             m.param_dtype)
@@ -373,22 +407,42 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
             and not m.is_mutable_collection(MOE_LOSSES):
         live = jnp.max(jnp.where(valid, jnp.arange(1, s + 1), 0))
 
-    def by_rows(fn, tokens):
-        """``fn(tokens)`` for a position-wise ``fn`` [T', D] -> a tree of
-        [T', ...], a row block of ``x`` at a time up to ``live``."""
+    def by_rows(fn, tokens, *more):
+        """``fn(tokens, *more)`` for a position-wise ``fn`` [T', D] (and
+        further [T', ...] arrays) -> a tree of [T', ...], a row block of
+        ``x`` at a time up to ``live``."""
         if live is None:
-            return fn(tokens)
+            return fn(tokens, *more)
         out = _over_rows(
-            lambda x: jax.tree.map(
+            lambda x, *more: jax.tree.map(
                 lambda y: y.reshape(b, -1, *y.shape[1:]),
-                fn(x.reshape(-1, d).astype(m.dtype))), live, x)
+                fn(x.reshape(-1, d).astype(m.dtype),
+                   *(y.reshape(-1, y.shape[-1]) for y in more))), live, x,
+            *(y.reshape(b, s, -1) for y in more))
         return jax.tree.map(lambda y: y.reshape(t, *y.shape[2:]), out)
 
-    def route(tokens):
+    def mlp_logits(tokens, before):
+        """(the experts' logits [T, E], the state r [T, router_dim] this
+        layer hands the next) of the MLP router, float32 throughout."""
+        dot = functools.partial(jnp.dot, precision=lax.Precision.HIGHEST)
+        r = dot(tokens.astype(jnp.float32), mlp_w["down"]) \
+            + mlp_w["down_bias"]
+        if before:
+            r = r + mlp_w["decay"] * before[0]
+        y = r * lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True)
+                          + m.norm_eps) * mlp_w["norm"]
+        y = jax.nn.gelu(dot(y, mlp_w["w1"]) + mlp_w["b1"], approximate=False)
+        y = jax.nn.gelu(dot(y, mlp_w["w2"]) + mlp_w["b2"], approximate=False)
+        return dot(y, mlp_w["w3"]), r
+
+    def route(tokens, *before):
         # in float32 whatever the compute dtype: a pick is a comparison
-        logits = jnp.dot(tokens.astype(jnp.float32),
-                         router_w.astype(jnp.float32),
-                         precision=lax.Precision.HIGHEST)
+        if r_dim:
+            logits, handed = mlp_logits(tokens, before)
+        else:
+            logits = jnp.dot(tokens.astype(jnp.float32),
+                             router_w.astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST)
         probs = SELECTIONS[m.selection](logits)               # [T, E]
         chosen_by = probs       # what picks; the gates are probs' own
         if m.expert_bias:
@@ -407,14 +461,21 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
         if m.routed_scale != 1.0:
             gates = gates * m.routed_scale
         # the losses' operands leave no loop: nobody reads them there
-        return (picks, gates) if live is not None \
+        out = (picks, gates) if live is not None \
             else (picks, gates, logits, probs)
+        return ((handed,) if r_dim else ()) + out
 
     # Everything the layer does is under one of five scopes
     # (utils/profiling.py), so a trace's time in them is the layer's.
     with jax.named_scope(profiling.MOE_ROUTE):
         tokens = x.reshape(t, d).astype(m.dtype)
-        picks, gates, *every = by_rows(route, tokens)
+        before = () if router_state is None else (
+            router_state.reshape(t, r_dim).astype(jnp.float32),)
+        routed = by_rows(route, tokens, *before)
+        if r_dim:
+            handed, *routed = routed
+        picks, gates, *every = routed
+
         everything = held == e and valid is None
         if everything:
             local = picks
@@ -467,12 +528,17 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
                     f"{m.selection!r} the layer sows none: do not make "
                     f"{MOE_LOSSES!r} mutable")
 
+    def done(out):
+        """The layer's result, and for an MLP router its state beside."""
+        out = out.reshape(b, s, d).astype(x.dtype)
+        return (out, handed.reshape(b, s, r_dim)) if r_dim else out
+
     if walks:
         out = _walk_held((k, block_rows), tokens, order, pairs, gates,
                          w_gate.astype(m.dtype), w_up.astype(m.dtype),
                          w_down.astype(m.dtype))
         if not n_shared:
-            return out.reshape(b, s, d).astype(x.dtype)
+            return done(out)
     else:
         with jax.named_scope(profiling.MOE_DISPATCH):
             rows = _dispatch(tokens, order, inverse, k)       # [T*k, D]
@@ -491,7 +557,7 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
                 by_token = jnp.where(on_chip[..., None], by_token, 0)
             out = (by_token.astype(jnp.float32) * gates[..., None]).sum(1)
             if not n_shared:
-                return out.reshape(b, s, d).astype(x.dtype)
+                return done(out)
 
     def shared_sum(tokens):
         act = (nn.silu(tokens @ sw_gate.astype(m.dtype))
@@ -501,4 +567,4 @@ def _all_experts_here(m: MoEMLP, x, valid=None):
     with jax.named_scope(profiling.MOE_SHARED):
         shared = by_rows(shared_sum, tokens)
         out = out + shared.astype(jnp.float32) * (1.0 / n_shared)
-        return out.reshape(b, s, d).astype(x.dtype)
+        return done(out)

@@ -223,6 +223,26 @@ class TransformerConfig:
     moe_expert_bias: bool = False
     moe_groups: int = 0
     moe_topk_groups: int = 0
+    # Compressed convolutional attention ("cca" layers, models/cca.py):
+    # queries and keys at num_heads / num_kv_heads heads of head_dim, a
+    # latent narrower than the stream, through a depthwise and then a
+    # grouped causal convolution of cca_taps[0] and cca_taps[1] positions;
+    # rotary on the first rotary_fraction of a head's channels (read by
+    # "cca" layers alone).  Its cache is K and V rows a position AND, a
+    # slot, a tail: the convolutions' and the value shift's last inputs.
+    cca_taps: tuple = (2, 2)
+    rotary_fraction: float = 1.0
+    # Sparse feed-forward, a router that is an MLP with a state (models/
+    # moe.py): moe_router_dim > 0 replaces the router's one matrix by r =
+    # x W_d + b_d (that wide) plus, from the model's second sparse layer on,
+    # a learned vector times the r of the layer before (the state a layer
+    # hands the next beside the stream), and the experts' logits by a
+    # three-layer GELU MLP on norm(r), all in float32.
+    moe_router_dim: int = 0
+    # The residual merge, scaled: x <- (x + b_r) * s_r + (f + b_f) * s_f for
+    # each sublayer's output f, four learned vectors a sublayer (scales from
+    # 1, biases from 0).  Sequential blocks.
+    residual_scaling: bool = False
 
     @classmethod
     def from_dict(cls, fields: dict) -> "TransformerConfig":
@@ -267,13 +287,23 @@ class TransformerConfig:
 
     @property
     def cache_layout(self) -> tuple | None:
-        """For a model with "kda" layers: ``(cache kind, index among the
-        layers of that kind)`` a layer.  Its pool is not two like-shaped
-        arrays but two trees with an entry a kind (:func:`init_kv_cache`):
-        a state a slot for the "kda" layers beside a row a position for the
-        others, each stacked over ITS layers alone.  None for every other
-        model: one kind, two arrays, indexed by the layer."""
+        """For a model with "kda" or "cca" layers: ``(cache kind, index
+        among the layers of that kind)`` a layer.  Its pool is not two
+        like-shaped arrays but two trees with an entry a kind
+        (:func:`init_kv_cache`): a state a slot for the "kda" layers beside
+        a row a position for the others, each stacked over ITS layers alone.
+        A "cca" layer is of two kinds at once (:data:`CACHE_PARTS`), rows a
+        position and a tail a slot, and every layer of its model is one.
+        None for every other model: one kind, two arrays, indexed by the
+        layer."""
         kinds = self.layer_kinds
+        if "cca" in kinds:
+            if set(kinds) != {"cca"}:
+                raise NotImplementedError(
+                    f"cca layers beside {sorted(set(kinds) - {'cca'})}: a "
+                    f"pool of rows and tails is built for a model whose "
+                    f"every layer is cca; no configuration mixes them")
+            return tuple(("cca", i) for i in range(len(kinds)))
         if "kda" not in kinds:
             return None
         cached = {CACHE_KINDS.get(kind) for kind in kinds}
@@ -305,7 +335,23 @@ class TransformerConfig:
 # "mamba" layer) serves from no cache
 CACHE_KINDS = {"attention": "kv", "sliding_attention": "kv",
                "full_attention": "kv", "latent_attention": "latent",
-               "eva_attention": "eva", "kda": "kda"}
+               "eva_attention": "eva", "kda": "kda", "cca": "cca"}
+# the entries of the pool's two trees that make up a layer's cache where
+# they are more than the kind's own one
+CACHE_PARTS = {"cca": ("cca", "cca_tail")}
+
+
+def _own_cache(tree: dict, kind: str):
+    """A layer's view of one tree of a pool of kinds: its kind's array, or
+    for a kind of several parts (:data:`CACHE_PARTS`) a dict of them."""
+    if kind not in CACHE_PARTS:
+        return tree[kind]
+    return {part: tree[part] for part in CACHE_PARTS[kind]}
+
+
+def _with_cache(tree: dict, kind: str, new) -> dict:
+    """``tree`` with a layer's view of it (:func:`_own_cache`) replaced."""
+    return {**tree, **(new if kind in CACHE_PARTS else {kind: new})}
 
 
 def _norm_scale(norm, width: int):
@@ -502,7 +548,12 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     D] float32, "latent": latents [Ll, slots, S, rank]}`` and ``{"kda": the
     convolutions' last inputs [Lk, slots, taps - 1, 3 H D], "latent":
     rotary keys [Ll, slots, S, rope]}`` (``"kv"`` keys and values where its
-    other layers are plain attention).
+    other layers are plain attention).  So does one with "cca" layers
+    (models/cca.py), each of which keeps two kinds: ``{"cca": keys [Lc,
+    slots, S, KV D], "cca_tail": the two convolutions' last inputs [Lc,
+    slots, taps0 - 1 + taps1 - 1, (H + KV) D] float32}`` and ``{"cca":
+    values, "cca_tail": the value shift's last input [Lc, slots, 1, KV D /
+    2] float32}``.
     One slot is one serving sequence — the
     continuous-batching scheduler (serving/engine.py) admits a request
     into a free slot (prefill writes positions ``0..len``) and decode
@@ -514,6 +565,23 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     program (``donate_argnums``) has them updated where they lie, one that
     does not pays a copy of both a call."""
     layout = cfg.cache_layout
+    lead = (num_slots, max_len or cfg.max_seq_len)
+    if "cca" in cfg.layer_kinds:
+        # every layer is cca: K and V rows a position, a row its KV heads
+        # side by side (a decode step's products read it as it lies,
+        # models/cca.rows_decode_attention), and a slot's tail in float32 (a
+        # decode step convolves over it what a prefill convolved over the
+        # positions themselves)
+        rows = (len(layout),) + lead + (cfg.kv_heads * cfg.head_dim,)
+        return ({"cca": jnp.zeros(rows, cfg.dtype),
+                 "cca_tail": jnp.zeros(
+                     (len(layout), num_slots, sum(cfg.cca_taps) - 2,
+                      (cfg.num_heads + cfg.kv_heads) * cfg.head_dim),
+                     jnp.float32)},
+                {"cca": jnp.zeros(rows, cfg.dtype),
+                 "cca_tail": jnp.zeros(
+                     (len(layout), num_slots, 1,
+                      cfg.kv_heads * cfg.head_dim // 2), jnp.float32)})
     if layout is not None:
         # a model with "kda" layers: two trees with an entry a cache kind,
         # each stacked over the layers of that kind alone.  The first holds
@@ -528,7 +596,6 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
         second = {"kda": jnp.zeros(
             (count("kda"), num_slots, cfg.kda_conv_width - 1, 3 * inner),
             cfg.dtype)}
-        lead = (num_slots, max_len or cfg.max_seq_len)
         if count("latent"):
             first["latent"] = jnp.zeros(
                 (count("latent"),) + lead + (cfg.kv_lora_rank,), cfg.dtype)
@@ -563,6 +630,13 @@ def init_kv_pages(cfg: TransformerConfig, num_pages: int, page_size: int):
     slot is a row of page ids (its page table) and a page holding a
     shared prompt-prefix chunk can appear in many slots' rows at once.
     Page 0 is the scratch page inactive slots point at."""
+    if "cca" in cfg.layer_kinds:
+        raise NotImplementedError(
+            "a paged pool beside a cca layer's tail (init_kv_pages, "
+            "PagedTransformerBackend, the prefix cache) is not built: its "
+            "rows could lie in pages, but a shared prefix would need the "
+            "convolutions' and the value shift's tail at its end; a model "
+            "with cca layers serves from init_kv_cache's pool")
     if "kda" in cfg.layer_kinds:
         raise NotImplementedError(
             "a paged pool beside a kda layer's recurrent state "
@@ -1219,9 +1293,10 @@ MIXERS = {
     "eva_attention": ("horovod_tpu.models.transformer", "EvaAttention",
                       "attn", {}),
     "kda": ("horovod_tpu.models.kda", "KDAMixer", "kda", {}),
+    "cca": ("horovod_tpu.models.cca", "CCAMixer", "cca", {}),
 }
 CACHED_MIXERS = ("attention", "sliding_attention", "full_attention",
-                 "latent_attention", "eva_attention", "kda")
+                 "latent_attention", "eva_attention", "kda", "cca")
 
 
 def _scaled(x, multiplier: float):
@@ -1240,7 +1315,10 @@ def _feed_forward(cfg: TransformerConfig, dense: bool = False,
     inside :class:`Block`'s compact call, once a layer, by a function, so
     that flax adds no method's name to the module path; ``ff`` may then be
     called a chunk of the sequence at a time.  The dense MLP is called
-    through ``made`` (:func:`_unbound`, inside a loop's body)."""
+    through ``made`` (:func:`_unbound`, inside a loop's body).  Where the
+    router carries a state down the layers (``cfg.moe_router_dim``) it is
+    ``ff(y, valid, *state)`` -> ``(out, state)``: ``state`` the layer
+    before's, nothing for the first; a dense layer hands on what it got."""
     if cfg.num_experts > 0 and not dense:
         from horovod_tpu.models.moe import MoEMLP
 
@@ -1261,7 +1339,11 @@ def _feed_forward(cfg: TransformerConfig, dense: bool = False,
                      expert_bias=cfg.moe_expert_bias,
                      groups=cfg.moe_groups,
                      topk_groups=cfg.moe_topk_groups,
+                     router_dim=cfg.moe_router_dim, norm_eps=cfg.norm_eps,
                      param_dtype=cfg.param_dtype, name="moe_mlp")
+        if cfg.moe_router_dim:
+            return lambda y, valid, *state: moe(
+                y, valid=valid, router_state=state[0] if state else None)
         return lambda y, valid: moe(y, valid=valid)
     if cfg.moe_axis is not None:
         from horovod_tpu.models.moe import MoEMLP
@@ -1273,18 +1355,24 @@ def _feed_forward(cfg: TransformerConfig, dense: bool = False,
                      dtype=cfg.dtype, name="moe_mlp")
         return lambda y, valid: moe(y)
     mlp = made(MLP(cfg, name="mlp"))
+    if cfg.moe_router_dim:
+        return lambda y, valid, *state: (mlp(y), state[0] if state else None)
     return lambda y, valid: mlp(y)
 
 
-def _in_chunks(fn, chunk: int | None, x, valid):
-    """``fn(x, valid)`` over [B, S, ...], ``chunk`` positions at a time where
-    the sequence is longer (``fn`` is position-wise: the same numbers)."""
+def _in_chunks(fn, chunk: int | None, x, valid, *more):
+    """``fn(x, valid, *more)`` over [B, S, ...] (``more``: further arrays a
+    position), ``chunk`` positions at a time where the sequence is longer
+    (``fn`` is position-wise: the same numbers, whatever tree it gives)."""
     s = x.shape[1]
     if not chunk or s <= chunk:
-        return fn(x, valid)
-    return jnp.concatenate(
-        [fn(x[:, a:a + chunk], None if valid is None
-            else valid[:, a:a + chunk]) for a in range(0, s, chunk)], axis=1)
+        return fn(x, valid, *more)
+    return jax.tree.map(
+        lambda *ys: jnp.concatenate(ys, axis=1),
+        *[fn(x[:, a:a + chunk],
+             None if valid is None else valid[:, a:a + chunk],
+             *(m[:, a:a + chunk] for m in more))
+          for a in range(0, s, chunk)])
 
 
 # Rows in a block of a served prefill's position-wise work: the flash
@@ -1398,7 +1486,11 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, cache=None, return_kv=False,
-                 valid=None, lengths=None):
+                 valid=None, lengths=None, router_state=None):
+        """Where the router carries a state down the layers
+        (``cfg.moe_router_dim``) the stream this returns is the pair ``(x,
+        the state for the next layer)`` and ``router_state`` is the layer
+        before's (None: the first sparse layer)."""
         cfg = self.cfg
         norm = functools.partial(make_norm, cfg)
         try:
@@ -1437,22 +1529,54 @@ class Block(nn.Module):
             rows, made = None, _as_is
         ff = _feed_forward(cfg, self.dense_ff, made)
         chunk = cfg.feed_forward_chunk if rows is None else None
+        carrying = bool(cfg.moe_router_dim)
         if cfg.parallel_block:
+            if carrying or cfg.residual_scaling:
+                raise NotImplementedError(
+                    "a router state handed down the layers and the scaled "
+                    "residual merge are the sequential block's")
             # one norm a layer: the feed-forward reads what the mixer read
             # and both are added to the residual
             out = _over_rows(lambda x, mixed, y: x + _scaled(
                 mixed + _in_chunks(ff, chunk, y, valid),
                 cfg.residual_multiplier), rows, x, mixed, y)
         else:
+            # the two sublayers' merges, each with four vectors of its own
+            # where scaled; a router's state rides beside x through the row
+            # blocks and the chunks
+            vector = lambda name, init: self.param(  # noqa: E731
+                name, init, (cfg.embed_dim,), cfg.param_dtype
+            ).astype(jnp.float32)
+            scaled = {side: tuple(
+                vector(f"{side}_{part}", nn.initializers.zeros
+                       if part.endswith("bias") else nn.initializers.ones)
+                for part in ("residual_bias", "residual_scale",
+                             "branch_bias", "branch_scale"))
+                for side in (name, "mlp")} if cfg.residual_scaling else {}
             mlp_norm = make_norm(cfg, "mlp_norm", made)
 
-            def rest(x, mixed):
-                x = x + _scaled(mixed, cfg.residual_multiplier)
-                return x + _scaled(_in_chunks(
-                    lambda x, valid: ff(mlp_norm(x), valid), chunk, x, valid),
-                    cfg.residual_multiplier)
+            def merged(side, x, f):
+                if not scaled:
+                    return x + _scaled(f, cfg.residual_multiplier)
+                b_r, s_r, b_f, s_f = scaled[side]
+                return ((x.astype(jnp.float32) + b_r) * s_r
+                        + (f.astype(jnp.float32) + b_f) * s_f).astype(x.dtype)
 
-            out = _over_rows(rest, rows, x, mixed)
+            def rest(x, mixed, *state):
+                x = merged(name, x, mixed)
+                f = _in_chunks(
+                    lambda x, valid, *state: ff(mlp_norm(x), valid, *state),
+                    chunk, x, valid, *state)
+                if not carrying:
+                    return merged("mlp", x, f)
+                f, handed = f
+                return (merged("mlp", x, f),) + (
+                    () if handed is None else (handed,))
+
+            state = () if router_state is None else (router_state,)
+            out = _over_rows(rest, rows, x, mixed, *state)
+            if carrying:
+                out = (out[0], out[1] if len(out) > 1 else None)
         if cache is not None or return_kv:
             return out, kv
         return out
@@ -1589,19 +1713,26 @@ class Transformer(nn.Module):
         # its cache block out for a decode step there
         ends = {"lengths": jnp.asarray(lengths)} \
             if return_kv and lengths is not None else {}
+        # a router that carries a state down the layers: a layer's stream is
+        # then (x, the state), and the next layer is told the state
+        carrying = bool(cfg.moe_router_dim)
         for i, kind in enumerate(kinds):
             block = block_cls(cfg, kind, i < cfg.first_dense_layers,
                               name=f"layer_{i}")
+            if carrying and i:
+                x, told["router_state"] = x
             if decode and layout is not None:
                 # ... of two kinds: the layer's own kind's two arrays go
-                # through it, at its index among that kind's layers
+                # through it, at its index among that kind's layers (a
+                # kind of several parts: a dict of them)
                 kind, at = layout[i]
                 x, (one, two) = block(
-                    x, positions, cache=(kv_cache[0][kind],
-                                         kv_cache[1][kind], lengths, at),
+                    x, positions, cache=(_own_cache(kv_cache[0], kind),
+                                         _own_cache(kv_cache[1], kind),
+                                         lengths, at),
                     **told)
-                kv_cache = ({**kv_cache[0], kind: one},
-                            {**kv_cache[1], kind: two})
+                kv_cache = (_with_cache(kv_cache[0], kind, one),
+                            _with_cache(kv_cache[1], kind, two))
             elif decode:
                 # the whole pool goes through every layer: layer i writes
                 # its block into it and reads its own view of it
@@ -1628,17 +1759,21 @@ class Transformer(nn.Module):
                 # layer's block outlives its layer
                 *pools, slot = kv_into
                 kind, at = (None, i) if layout is None else layout[i]
-                own = pools if layout is None else [p[kind] for p in pools]
-                own = [jax.lax.dynamic_update_slice(
-                    pool, block_[None].astype(pool.dtype),
-                    (at, slot) + (0,) * (pool.ndim - 2))
+                own = pools if layout is None else [_own_cache(p, kind)
+                                                    for p in pools]
+                own = [jax.tree.map(
+                    lambda pool, block_: jax.lax.dynamic_update_slice(
+                        pool, block_[None].astype(pool.dtype),
+                        (at, slot) + (0,) * (pool.ndim - 2)), pool, block_)
                     for pool, block_ in zip(own, kv)]
                 x, own = jax.lax.optimization_barrier((x, own))
                 pools = own if layout is None else [
-                    {**p, kind: one} for p, one in zip(pools, own)]
+                    _with_cache(p, kind, one) for p, one in zip(pools, own)]
                 kv_into = (*pools, slot)
             else:
                 x = block(x, positions, **told)
+        if carrying:
+            x, _ = x
         if logits_at is not None:
             x = jnp.take_along_axis(
                 x, jnp.asarray(logits_at)[:, None, None], axis=1)[:, 0]
@@ -1666,9 +1801,12 @@ class Transformer(nn.Module):
         if return_kv and layout is not None:
             # an entry a cache kind, each stacked over its own layers
             return logits, tuple(
-                {kind: jnp.stack([kv[side] for kv, (k, _) in zip(kvs, layout)
-                                  if k == kind])
-                 for kind in dict(layout)} for side in (0, 1))
+                {part: jnp.stack([
+                    kv[side][part] if kind in CACHE_PARTS else kv[side]
+                    for kv, (k, _) in zip(kvs, layout) if k == kind])
+                 for kind in dict(layout)
+                 for part in CACHE_PARTS.get(kind, (kind,))}
+                for side in (0, 1))
         if return_kv:
             return logits, (jnp.stack([kv[0] for kv in kvs]),
                             jnp.stack([kv[1] for kv in kvs]))

@@ -131,7 +131,10 @@ class ServingConfig:
     buckets: tuple[int, ...] = (16, 32, 64, 128)
     max_seq_len: int = 256
     eos_id: int | None = None
-    # Keep per-step logits on each request (tests only — unbounded).
+    # Keep per-step logits on each request (tests only — unbounded).  The
+    # one way a model backend's prefill and decode logits reach the host:
+    # the tokens are sampled on the device, and without this the logits
+    # (slots x vocabulary float32 values a step) stay there.
     record_logits: bool = False
     # Shared-prefix KV reuse (serving/prefix_cache.py): pages of cache
     # slack beyond the slots' own working set that evicted requests'
@@ -251,7 +254,7 @@ class StubBackend:
         return preds, logits
 
 
-def _wait_and_fetch(results: list) -> tuple[np.ndarray, ...]:
+def _wait_and_fetch(results: list) -> tuple:
     """The last two of a model backend call's four leaf spans (the first
     two, ``hvd_srv_h2d`` around the copies in and ``hvd_srv_dispatch``
     around the jitted call, which returns when the work is enqueued, are
@@ -260,15 +263,19 @@ def _wait_and_fetch(results: list) -> tuple[np.ndarray, ...]:
     arrive when the program has run.  (A ``block_until_ready`` before the
     fetches is one more trip to the runtime, 0.2 ms a call on a v5e:
     PERF.md, PR 39.)  ``hvd_srv_fetch`` is the rest of the results' way to
-    the host, the logits and, sparse, the pair counts, with the bytes that
-    was; the device's copies are let go inside it."""
+    the host: sparse, the pair counts, with the bytes that was.  The logits
+    (``results[1]``: slots x vocabulary float32 values a decode step) are
+    handed back as they are, on the device, for whoever reads them to copy
+    (``ServingEngine._kept`` under ``ServingConfig.record_logits``); the
+    device's other copies are let go here."""
     with profiling.span(profiling.SRV_WAIT):
         tokens = np.asarray(results[0])
     with profiling.span(profiling.SRV_FETCH) as s:
-        rest = tuple(np.asarray(r) for r in results[1:])
+        rest = tuple(np.asarray(r) for r in results[2:])
         s.fields["bytes"] = sum(a.nbytes for a in rest)
+        logits = results[1]
         results.clear()
-    return (tokens,) + rest
+    return (tokens, logits) + rest
 
 
 class TransformerBackend:
@@ -510,7 +517,7 @@ class TransformerBackend:
             # of the bucket's padding (a model's own attention function is
             # not known to take a length; the dense form ignores it)
             told["lengths"] = jnp.reshape(length, (1,))
-        into_pool = self.eva or self.recurrent \
+        into_pool = self.eva or self._model_cfg.cache_layout is not None \
             or self.prefill_rows(padded.shape[1], 1)
         if into_pool:
             # the ring is laid out for a decode step at length, and a
@@ -578,13 +585,17 @@ class TransformerBackend:
         fields = {"moe_rows": rows, "moe_held": held}
         if walked:
             fields["moe_tile_rows"] = walked[0]
+        call = profiling.current_span()
+        if call is not None and call.name == profiling.SRV_DECODE:
+            # distinct experts the live slots picked, summed over the layers:
+            # whose weights the step's grouped products had to read
+            fields["experts_touched"] = int((pairs > 0).sum())
         c = self.moe_counters
         c["calls"] += 1
         c["pairs"] += tokens * self._pairs_per_token
         c["held_pairs"] += held
         c["rows_visited"] += rows
         c["tile_rows"] += sum(walked)
-        call = profiling.current_span()
         if call is not None and call.name in (profiling.SRV_PREFILL,
                                               profiling.SRV_DECODE):
             call.fields.update(fields)
@@ -605,9 +616,10 @@ class TransformerBackend:
 
     def _call(self, program, host_inputs, passed=(), meanwhile=None):
         """``program`` over the donated pool: the four leaf spans in order,
-        the pool kept, and on the host (tokens, logits) and, sparse, the
-        pair counts.  ``meanwhile()`` is bookkeeping that needs no result:
-        it runs once the program is enqueued, while the device works."""
+        the pool kept, and back (tokens on the host, logits on the device)
+        and, sparse, the pair counts on the host.  ``meanwhile()`` is
+        bookkeeping that needs no result: it runs once the program is
+        enqueued, while the device works."""
         jnp = self._jax.numpy
         with profiling.span(profiling.SRV_H2D):
             copied = [jnp.asarray(x) for x in host_inputs]
@@ -818,7 +830,8 @@ class PagedTransformerBackend:
 
     def _call(self, program, host_inputs):
         """``program`` over the donated pages: the four leaf spans in
-        order, the pages kept, (tokens, logits) back on the host."""
+        order, the pages kept, and back (tokens on the host, logits on the
+        device)."""
         jnp = self._jax.numpy
         with profiling.span(profiling.SRV_H2D):
             copied = [jnp.asarray(x) for x in host_inputs]
@@ -973,11 +986,12 @@ class ServingEngine:
                                     **self._in_slots()):
                     nxt, logits = self.backend.decode(self.last_tokens,
                                                       self.lengths)
+                    logits = self._kept(logits)
                 now = self.clock()
                 for s, req in enumerate(self.slots):
                     if req is None:
                         continue
-                    self._take_token(req, s, int(nxt[s]), logits[s], now)
+                    self._take_token(req, s, int(nxt[s]), logits, now, at=s)
                     if req.state == "DONE":
                         self._evict(req, s, done)
         self.counters["steps"] += 1
@@ -1056,6 +1070,7 @@ class ServingEngine:
                 else:
                     first, logits = self.backend.prefill(padded,
                                                          len(suffix), s)
+                logits = self._kept(logits)
             now = self.clock()
             req.state, req.slot = "ACTIVE", s
             req.ttft_s = now - req.submitted_t
@@ -1079,11 +1094,25 @@ class ServingEngine:
             if req.state == "DONE":  # max_new_tokens == 1
                 self._evict(req, s, done)
 
+    def _kept(self, logits):
+        """A backend call's logits on the host where ``record_logits`` keeps
+        them: a fetch of its own under the call's span, with the bytes that
+        was.  None where it does not: a model backend samples its tokens on
+        the device and hands the logits back there, and there they stay."""
+        if not self.config.record_logits:
+            return None
+        with profiling.span(profiling.SRV_FETCH) as s:
+            logits = np.asarray(logits)
+            s.fields["bytes"] = logits.nbytes
+        return logits
+
     def _take_token(self, req: Request, slot: int, token: int, logits,
-                    now: float) -> None:
+                    now: float, at=()) -> None:
+        """``logits``: what :meth:`_kept` gave of the call; ``at``: the
+        token's row of it."""
         req.tokens.append(token)
-        if self.config.record_logits:
-            req.logits.append(np.array(logits))
+        if logits is not None:
+            req.logits.append(np.array(logits[at]))
         if req._last_token_t:
             req.token_lat_s.append(now - req._last_token_t)
             self._token_s.append(req.token_lat_s[-1])
@@ -1144,6 +1173,7 @@ class ServingEngine:
                                    axis=1)
         with profiling.span(profiling.SRV_VERIFY, **self._in_slots()):
             preds, logits = self.backend.verify(tok_block, self.lengths)
+            logits = self._kept(logits)
         now = self.clock()
         for s, req in enumerate(self.slots):
             if req is None:
@@ -1159,12 +1189,12 @@ class ServingEngine:
             taken = 0
             while taken < k and req.state == "ACTIVE" and \
                     int(preds[s, taken]) == int(drafts[s, taken]):
-                self._take_token(req, s, int(drafts[s, taken]),
-                                 logits[s, taken], now)
+                self._take_token(req, s, int(drafts[s, taken]), logits, now,
+                                 at=(s, taken))
                 taken += 1
             if req.state == "ACTIVE":
-                self._take_token(req, s, int(preds[s, taken]),
-                                 logits[s, taken], now)
+                self._take_token(req, s, int(preds[s, taken]), logits, now,
+                                 at=(s, taken))
             self.counters["spec_drafted"] += k
             self.counters["spec_accepted"] += taken
             if self.collective is not None and taken:
@@ -1289,7 +1319,9 @@ class ServingEngine:
         them for, and ``rows_per_held_pair`` (1 would waste nothing); over
         the calls whose layers walked their pairs in blocks also
         ``tile_rows``, the rows of the row tiles their grouped matmul
-        worked, and ``tile_rows_per_held_pair``."""
+        worked, and ``tile_rows_per_held_pair``; over the decode steps
+        ``experts_touched``, the distinct experts the live slots picked
+        summed over layers and steps."""
         records = profiling.spans()
         out = profiling.summarize(records)
         by_attn: dict[str, dict] = {}
@@ -1322,6 +1354,10 @@ class ServingEngine:
                 held = sum(f["moe_held"] for f in sparse)
                 out[name]["moe"] = {"rows": rows, "held_pairs": held,
                                     "rows_per_held_pair": rows / max(held, 1)}
+                touched = [f["experts_touched"] for f in sparse
+                           if "experts_touched" in f]
+                if touched:     # decode steps: distinct experts a layer
+                    out[name]["moe"]["experts_touched"] = sum(touched)
                 walked = [f for f in sparse if "moe_tile_rows" in f]
                 if walked:
                     tile_rows = sum(f["moe_tile_rows"] for f in walked)

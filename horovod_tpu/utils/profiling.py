@@ -105,6 +105,16 @@ KDA_SCAN = "hvd_kda_scan"       # ... ops/kda_scan: the delta rule, chunked
 KDA_OUT = "hvd_kda_out"         # ... the norm a head and the output gate
 KDA_CHUNK = "hvd_kda_chunk"     # ops/kda_scan: the chunked form's kernel,
                                 # launched under KDA_SCAN (no flash pass)
+CCA_PROJ = "hvd_cca_proj"       # models/cca: q and k into the latent, the two
+                                # value halves
+CCA_CONV = "hvd_cca_conv"       # ... both causal convolutions over the
+                                # carried tail, the q-k mean, the value
+                                # shift, the tail's write
+CCA_ATTN = "hvd_cca_attn"       # ... a head's q and k to length, rotary,
+                                # the rows' write, the attention itself
+                                # (the attention function's own names
+                                # inside it: FLASH_FWD)
+CCA_OUT = "hvd_cca_out"         # ... the output projection
 LOADER_WAIT = "hvd_loader_wait"         # data.BackgroundLoader: q.get()
 LOADER_PRODUCE = "hvd_loader_produce"   # ... next(source), producer thread
 H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
@@ -128,6 +138,7 @@ MLA_SCOPES = (MLA_DOWN, MLA_UP, MLA_ABSORB, MLA_ATTN)
 SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE)
 SSD_PASSES = (SSD_FWD, SSD_BWD)
 KDA_SCOPES = (KDA_PROJ, KDA_CONV, KDA_GATE, KDA_SCAN, KDA_OUT)
+CCA_SCOPES = (CCA_PROJ, CCA_CONV, CCA_ATTN, CCA_OUT)
 SRV_CALLS = (SRV_PREFILL, SRV_DECODE, SRV_VERIFY)   # the backend's calls
 SRV_LEAVES = (SRV_H2D, SRV_DISPATCH, SRV_WAIT, SRV_FETCH)   # in call order
 # records the span ring holds before it drops the oldest: a 35 s serving

@@ -511,8 +511,9 @@ def test_every_name_of_the_vocabulary_is_written_once():
     # five of models/moe.py, one of ops/token_sum.py, four of models/mamba.py,
     # two of ops/ssd_scan.py, ten of serving/engine.py, four of
     # models/transformer.LatentAttention, two of EvaAttention, five of
-    # models/kda.py, one of ops/kda_scan.py, one of ops/grouped_matmul.py
-    assert len(names) == len(set(names)) == 46
+    # models/kda.py, one of ops/kda_scan.py, one of ops/grouped_matmul.py,
+    # four of models/cca.py
+    assert len(names) == len(set(names)) == 50
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for directory, _, files in itertools.chain(
             os.walk(os.path.join(root, "horovod_tpu")),

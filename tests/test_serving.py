@@ -36,6 +36,7 @@ The chaos soak (grow + SIGKILL under load, serving/soak.py) runs under
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import socket
 
@@ -232,6 +233,39 @@ def test_prefill_logits_match_full_forward(small_model):
                                rtol=2e-5, atol=2e-5)
     assert req.tokens[0] == int(np.argmax(np.asarray(
         full[0, len(prompt) - 1])))
+
+
+@pytest.mark.parametrize("record", [False, True])
+@pytest.mark.parametrize("kind", ["dense", "paged"])
+def test_logits_reach_the_host_only_where_they_are_recorded(small_model,
+                                                            kind, record):
+    """The programs sample on the device and a backend hands its logits
+    back there: a call's own ``hvd_srv_fetch`` holds none of them
+    (``bytes``), and without ``record_logits`` nothing else fetches them
+    and a request keeps none; with it the engine's fetch follows each
+    call's, and a decode step's holds every slot's."""
+    import jax
+
+    from horovod_tpu.utils import profiling
+
+    if kind == "dense":
+        eng = _make_engine(small_model, num_slots=2, record=record)
+    else:
+        eng = _make_paged_engine(small_model)
+        eng.config = dataclasses.replace(eng.config, record_logits=record)
+    before = len(profiling.spans())
+    req = eng.submit([5, 9, 2, 7, 11, 3], 4)
+    eng.run_until_idle()
+    assert len(req.tokens) == 4 and len(req.logits) == (4 if record else 0)
+    fetched = [r.fields["bytes"] for r in profiling.spans()[before:]
+               if r.name == profiling.SRV_FETCH]
+    # one prefill (a row of the vocabulary), three decode steps (every slot's)
+    assert fetched == ([0, 64 * 4] + [0, 2 * 64 * 4] * 3 if record
+                       else [0] * 4)
+    nxt, logits = eng.backend.decode(eng.last_tokens, eng.lengths)
+    assert isinstance(nxt, np.ndarray) and nxt.shape == (2,)
+    assert isinstance(logits, jax.Array)
+    assert logits.shape == (2, 64) and logits.dtype == np.float32
 
 
 def test_batched_decode_bit_exact_vs_sequential(small_model):

@@ -36,7 +36,7 @@ NEW = {"decode_h2d_ms.srv": "serving backend", "decode_dispatch_ms.srv":
        "serving backend", "idle_named_share.srv": "device"}
 SERVING_CELLS = ["dsc1p3b-code-0.8knee", "cmdaplus-code8k-open",
                  "axk1-longdoc16k-open", "evabyte-code32k-open",
-                 "ling3f-longdoc32k-open"]
+                 "ling3f-longdoc32k-open", "zaya1-reason8k-open"]
 
 
 def since(mark: int) -> list:
@@ -238,8 +238,13 @@ def toy_model():
     return model, params, cfg
 
 
+@pytest.mark.parametrize("recorded", [True, False])
 @pytest.mark.parametrize("kind", ["dense", "paged"])
-def test_a_backend_call_is_four_leaves_in_order(toy_model, kind):
+def test_a_backend_call_is_four_leaves_in_order(toy_model, kind, recorded):
+    """``recorded``: a backend call's ``hvd_srv_fetch`` carries no byte of
+    the logits, which stay on the device; under
+    ``ServingConfig.record_logits`` the engine's own fetch of them (every
+    slot's, a decode step) is a fifth span after the four (PR 52)."""
     from benchmarks.serving import Timed
 
     model, params, cfg = toy_model
@@ -249,7 +254,8 @@ def test_a_backend_call_is_four_leaves_in_order(toy_model, kind):
     timed = Timed(backend)      # as a cell wraps it: the leaves' cause is
     eng = ServingEngine(        # still the engine's span around the call
         timed if kind == "dense" else backend,
-        ServingConfig(num_slots=8, buckets=(32, 64), max_seq_len=256),
+        ServingConfig(num_slots=8, buckets=(32, 64), max_seq_len=256,
+                      record_logits=recorded),
         clock=time.perf_counter)
     for i in range(10):         # compiles both buckets and the decode step
         eng.submit(list(range(1, 20 + 4 * i)), 3)
@@ -267,11 +273,14 @@ def test_a_backend_call_is_four_leaves_in_order(toy_model, kind):
         covered = []
         for call in calls:
             leaves = [r for r in records if r.cause == call.id]
-            assert [r.name for r in leaves] == list(profiling.SRV_LEAVES)
+            assert [r.name for r in leaves] == list(profiling.SRV_LEAVES) \
+                + [profiling.SRV_FETCH] * recorded
             edges = [call.start] + [t for r in leaves
                                     for t in (r.start, r.end)] + [call.end]
             assert edges == sorted(edges)
-            assert leaves[-1].fields["bytes"] > 0
+            # a dense model counts no pairs: the logits are all a fetch holds
+            assert [r.fields["bytes"] > 0 for r in leaves[3:]] \
+                == [False] + [True] * recorded
             covered.append(sum(r.seconds for r in leaves) / call.seconds)
         assert statistics.median(covered) >= 0.97
     if kind == "dense":
@@ -287,8 +296,8 @@ def test_a_backend_call_is_four_leaves_in_order(toy_model, kind):
         fetched = [r.fields["bytes"] for r in records
                   if r.name == profiling.SRV_FETCH and r.cause in
                   {d.id for d in decodes}]
-        # the logits; the tokens came with the wait
-        assert set(fetched) == {8 * 8192 * 4}
+        # the logits, every slot's; the tokens came with the wait
+        assert set(fetched) == ({0, 8 * 8192 * 4} if recorded else {0})
 
 
 def test_the_profiler_s_host_plane_carries_the_same_names(toy_model, tmp_path):
