@@ -19,11 +19,13 @@ dense GLU MLP, in one of two layouts:
   like any other layer: every chip holds all experts.  A chip's SHARE of
   the experts (``experts_held``, a serving layout) routes over all of them
   and computes its own; where its pairs are a small part of the ``T * k`` it
-  walks them in blocks (``_walk_held``), and there the three products are
-  Pallas kernels over a work list of small row tiles (ops/grouped_matmul.py,
-  ``hvd_moe_grouped``).  Every other path keeps XLA:TPU's own kernels for
-  ``lax.ragged_dot``: every expert held (training needs their backward), a
-  decode step, a bucket one block holds.
+  walks them in blocks (``_walk_held``).  In a SERVED PREFILL, walked or not
+  (a share held, or ``valid`` given, over a bucket's rows:
+  :data:`GROUPED_ROW_TILE` pairs and more), the three products are Pallas
+  kernels over a work list of small row tiles (ops/grouped_matmul.py,
+  ``hvd_moe_grouped``).  Two paths keep XLA:TPU's own kernels for
+  ``lax.ragged_dot``: the training layer (every expert held and no
+  ``valid``: it needs their backward) and a decode step's few rows.
 * **one expert per device** (``num_experts`` = 0; ``axis_name`` a bound mesh
   axis, ``TransformerConfig.moe_axis``): a router picks one expert per token
   (switch routing), tokens travel to the device holding their expert over
@@ -65,9 +67,10 @@ MOE_STATS = "moe_stats"     # "expert_pairs": [E] int32, pairs per expert
                             # [B, S, k] int32, each token's experts;
                             # "rows_visited": int32, the rows the layer
                             # gathered, multiplied and combined for its pairs;
-                            # where it walked them (_walk_held) "tile_rows":
-                            # int32, the rows of the row tiles its grouped
-                            # matmul worked (a product's visits x tile rows)
+                            # where its products were hvd_moe_grouped's (a
+                            # served prefill) "tile_rows": int32, the rows of
+                            # the row tiles the kernel worked (a product's
+                            # visits x tile rows)
 
 
 def moe_aux_loss(cfg, collections) -> jax.Array:
@@ -123,8 +126,10 @@ _permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
 
 
 # rows a tile of XLA:TPU's grouped matmul (lax.ragged_dot) holds: the kernels
-# of every path that does not walk (every expert held, a decode step, a bucket
-# of one block), and what a walked block is a whole number of
+# of the training layer and of a decode step, what a walked block is a whole
+# number of, and the line between a step's rows (slots x k: 24 to 128 in the
+# served cells) and a bucket's (1024 pairs and more): a serving layer that
+# carries this many pairs multiplies them in hvd_moe_grouped's small tiles
 GROUPED_ROW_TILE = 512
 
 
@@ -132,20 +137,22 @@ def held_block_rows(pairs: int, held: int, experts: int) -> int:
     """Rows in a block of the walk (``_walk_held``), from the static shapes alone:
     twice the ``pairs * held / experts`` that ``held`` of ``experts`` get
     when the routing is even, up to a whole :data:`GROUPED_ROW_TILE`.  A
-    layer whose block would hold all its ``pairs`` (``>= pairs``) does not
-    walk."""
+    layer whose block would hold all its ``pairs`` (``>= pairs``: half the
+    experts held and more, a short bucket, a decode step) does not walk: it
+    carries them all, the dead ones behind the last group."""
     twice_even = -(-2 * pairs * held // experts)
     return -(-twice_even // GROUPED_ROW_TILE) * GROUPED_ROW_TILE
 
 
-# rows a row tile of the walk's grouped matmuls holds (ops/grouped_matmul.py):
-# the MXU's own edge.  Swept on the chip at 64 to 512 rows over the three
-# served shapes, 32 to 520 rows an expert (PERF.md section 6, PR 51): 128 was
-# the fastest or within 0.1% of it at every one, because the work list adapts
-# to the rows a group holds by itself (a group of 500 rows is four visits
-# that share one fetch of its weights, a group of 30 shares its tile with
-# three others), so nothing is left for a rule over the static shapes to
-# choose; 512, XLA:TPU's own, was 1.4-1.9 times slower.
+# rows a row tile of a served prefill's grouped matmuls holds, walked or
+# carried (ops/grouped_matmul.py): the MXU's own edge.  Swept on the chip at
+# 64 to 512 rows over the three shares' served shapes, 32 to 520 rows an
+# expert (PERF.md section 6, PR 51): 128 was the fastest or within 0.1% of it
+# at every one, because the work list adapts to the rows a group holds by
+# itself (a group of 500 rows is four visits that share one fetch of its
+# weights, a group of 30 shares its tile with three others), so nothing is
+# left for a rule over the static shapes to choose; 512, XLA:TPU's own, was
+# 1.4-1.9 times slower.
 WALK_ROW_TILE = 128
 
 
@@ -216,6 +223,28 @@ _walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
 # tracing and one lowering of the walk: twenty a program otherwise, host
 # seconds of every process's set-up (PERF.md section 6, PR 43)
 _walk_held = jax.jit(_walk_blocks, static_argnums=0)
+
+
+@jax.custom_vjp
+def _experts_in_tiles(rows, pairs, w_gate, w_up, w_down):
+    """The three products of a serving layer that carries a bucket's sorted
+    rows [T*k, D], ``pairs`` [held] of them each expert's and the dead ones
+    behind the last group: the walk's two kernels over them all."""
+    hidden = grouped_glu(rows, w_gate, w_up, pairs, tile=WALK_ROW_TILE)
+    return grouped_matmul(hidden, w_down, pairs, tile=WALK_ROW_TILE)
+
+
+def _experts_in_tiles_bwd(_, g):
+    raise NotImplementedError(
+        "MoEMLP given valid=... (or a share, experts_held=...) over a "
+        "bucket's rows multiplies them in hvd_moe_grouped's tiles "
+        "(ops/grouped_matmul.py), which has no backward: both are "
+        "serving's.  Differentiate the layer with every expert held "
+        "(experts_held=None) and no valid")
+
+
+_experts_in_tiles.defvjp(lambda *given: (_experts_in_tiles(*given), None),
+                         _experts_in_tiles_bwd)
 
 
 # how the router's logits [T, E] become an expert's score for a token
@@ -497,6 +526,16 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
         # bucket) the three scopes below carry the T*k rows as they are.
         block_rows = held_block_rows(t * k, held, e)
         walks = not everything and block_rows < t * k
+        # A serving layer that carries a bucket's rows (whole row tiles of
+        # them; a decode step has fewer) multiplies them in the walk's
+        # kernels: a visit a (row tile, expert) that meet, none for the
+        # padding behind the last group.  At 16 experts and top-1 a layer
+        # alone took 1.81 -> 0.87 ms at 2867 live rows of 4096, and walking
+        # them in one block 1.01: its sum's float32 buffer, second sort and
+        # hvd_token_sum cost more than _dispatch and _permute (PERF.md
+        # section 6, PR 53).
+        in_tiles = not everything and not walks \
+            and t * k >= GROUPED_ROW_TILE and t * k % WALK_ROW_TILE == 0
         if not walks:
             inverse = jnp.argsort(order)
         pairs = (local[..., None] == jnp.arange(held)).sum(
@@ -506,7 +545,7 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
         if not m.is_initializing():  # init returns parameters only
             m.sow(MOE_STATS, "expert_pairs", pairs)
             m.sow(MOE_STATS, "rows_visited", rows_visited)
-            if walks:
+            if walks or in_tiles:
                 # (a block is whole tiles, so the blocks' visits are those
                 # of the held pairs laid end to end)
                 m.sow(MOE_STATS, "tile_rows",
@@ -544,10 +583,16 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
             rows = _dispatch(tokens, order, inverse, k)       # [T*k, D]
 
         with jax.named_scope(profiling.MOE_EXPERTS):
-            grouped = functools.partial(lax.ragged_dot, group_sizes=pairs)
-            hidden = (nn.silu(grouped(rows, w_gate.astype(m.dtype)))
-                      * grouped(rows, w_up.astype(m.dtype)))
-            out_rows = grouped(hidden, w_down.astype(m.dtype))  # [T*k, D]
+            if in_tiles:
+                out_rows = _experts_in_tiles(
+                    rows, pairs, w_gate.astype(m.dtype),
+                    w_up.astype(m.dtype), w_down.astype(m.dtype))
+            else:
+                grouped = functools.partial(lax.ragged_dot,
+                                            group_sizes=pairs)
+                hidden = (nn.silu(grouped(rows, w_gate.astype(m.dtype)))
+                          * grouped(rows, w_up.astype(m.dtype)))
+                out_rows = grouped(hidden, w_down.astype(m.dtype))  # [T*k, D]
 
         with jax.named_scope(profiling.MOE_COMBINE):
             by_token = _permute(out_rows, inverse, order).reshape(t, k, d)

@@ -1,12 +1,15 @@
 """Grouped matmul over a work list of small row tiles:
 ``out[r] = rows[r] @ weights[group of r]``.
 
-The three products of a sparse layer that walks a share's pairs in blocks
-(models/moe.py, ``_walk_held``): C rows sorted by expert, ``sizes[g]`` of
-them expert g's, each expert a ``[K, N]`` matrix.  XLA:TPU's kernels for
-``lax.ragged_dot`` work 512-row tiles, and every (row tile, group) pair that
-meets is one whole 512-row product: at 60-250 rows an expert a walk pays for
-two to nine times its rows (PERF.md section 6, PR 51).  Here the row tile is
+The three products of a sparse layer in a served prefill (models/moe.py): a
+block of the walk of a share's pairs (``_walk_held``) or all the rows a
+serving layer carries (``_experts_in_tiles``: every expert held, a bucket's
+rows): C rows sorted by expert, ``sizes[g]`` of them expert g's and the
+rest behind the last group, each expert a ``[K, N]`` matrix.  XLA:TPU's
+kernels for ``lax.ragged_dot`` work 512-row tiles, and every (row tile,
+group) pair that meets is one whole 512-row product: at 60-250 rows an
+expert a layer pays for two to nine times its rows (PERF.md section 6, PR
+51 and PR 53).  Here the row tile is
 the caller's, far smaller, and the grid runs over a work list of (row tile,
 group) VISITS made outside the kernel from ``cumsum(sizes)``, at most
 ``C / tile + G`` of them, in group order:
@@ -28,7 +31,7 @@ K stays whole and N is cut so that a visit's weight tiles fit
 :data:`WEIGHT_TILE_BYTES`: a tile of K would be fetched again every visit,
 a tile of N once a group.  float32 accumulation, the result in the rows'
 dtype, as ``ragged_dot`` gives it.  A row past the last group holds whatever
-the kernel left there.  No backward: the walk has none.
+the kernel left there.  No backward: a served prefill has none.
 """
 
 from __future__ import annotations

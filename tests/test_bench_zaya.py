@@ -450,7 +450,8 @@ def test_a_tiny_cell_walks_serving_py_on_the_cpu(tmp_path):
     assert (moe["experts"], moe["experts_held"], moe["experts_per_token"]) \
         == (4, 4, 1)
     assert moe["router"] == {"mlp_width": 16, "state_carried": True}
-    # every expert held: every routed pair is held, nothing walks
+    # every expert held: every routed pair is held; its buckets of 16 to 64
+    # rows are under a bucket's (512 pairs): ragged_dot's, no tile counted
     assert moe["held_pairs"] == moe["pairs"] > 0 and moe["tile_rows"] == 0
     cca = json.loads(stdout.split("cca: ")[1].splitlines()[0])
     assert (cca["heads"], cca["kv_heads"], cca["head_dim"], cca["taps"]) \
@@ -473,6 +474,40 @@ def test_a_tiny_cell_walks_serving_py_on_the_cpu(tmp_path):
     # no logits came to the host: a fetch is the pair counts alone
     host = json.loads(stdout.split("serve_host: ")[1].splitlines()[0])
     assert host["decode_calls"] > 0
+
+
+def test_a_tiny_cells_prefills_run_the_grouped_kernel_and_say_so(capsys):
+    """The tiny cell given a 512 bucket, its feed-forward in one chunk (PR
+    53: over a served bucket's rows a prefill whose layers hold every expert
+    multiplies them in ``hvd_moe_grouped``'s tiles): its ``hvd_srv_prefill``
+    spans carry ``moe_tile_rows``, whole tiles that hold every live pair,
+    the 64 bucket's and every decode span none, and the ``moe:`` line sums
+    them."""
+    from horovod_tpu.utils import profiling
+
+    family = load_module("families", "cca_moe_serve")
+    traffic = dict(TRAFFIC, prefill_buckets=[64, 512], max_seq_len=640)
+    served = family.serve(dict(TINY, feed_forward_chunk=512), traffic, 1, 5)
+    served.warm()           # every bucket twice, then every slot decoding
+    calls = {name: [r.fields for r in profiling.spans() if r.name == name]
+             for name in (profiling.SRV_PREFILL, profiling.SRV_DECODE)}
+    long = [f for f in calls[profiling.SRV_PREFILL] if f["bucket"] == 512]
+    short = [f for f in calls[profiling.SRV_PREFILL] if f["bucket"] < 512]
+    assert len(long) == 2 and len(short) >= 2 and calls[profiling.SRV_DECODE]
+    for f in long:          # 3 layers, 4 experts, tiles of 128
+        assert f["moe_held"] == 3 * f["length"] and f["moe_rows"] == 3 * 512
+        assert f["moe_tile_rows"] % 128 == 0
+        assert f["moe_held"] <= f["moe_tile_rows"] <= 3 * (512 + 4 * 128)
+    assert not any("moe_tile_rows" in f
+                   for f in short + calls[profiling.SRV_DECODE])
+    summary = served.engine.span_summary()
+    assert summary[profiling.SRV_PREFILL]["moe"]["tile_rows"] \
+        == sum(f["moe_tile_rows"] for f in long)
+    assert "tile_rows" not in summary[profiling.SRV_DECODE]["moe"]
+    served.release()
+    moe = json.loads(capsys.readouterr().out.split("moe: ")[1].splitlines()[0])
+    assert moe["tile_rows"] == sum(f["moe_tile_rows"] for f in long)
+    assert moe["held_pairs"] == moe["pairs"] > 2 * 3 * 500
 
 
 def test_an_altered_served_token_is_not_correct(tmp_path):

@@ -174,8 +174,8 @@ def loops_in(m, params, *args, **kwargs):
                                   "a_short_bucket", "valid_alone"])
 def test_where_a_block_holds_every_pair_no_loop_is_traced(case):
     """``C >= T * k`` (a decode step's 16 slots, a short bucket), every
-    expert held, or ``valid`` with every expert held: the parent's program,
-    all ``T * k`` rows counted as visited."""
+    expert held, or ``valid`` with every expert held: no block is walked,
+    all ``T * k`` rows carried and counted as visited."""
     held = None if case in ("experts_held_none", "valid_alone") else (8, 16)
     m, params, x = layer(held)
     if case in ("decode_shaped", "a_short_bucket"):
@@ -270,7 +270,7 @@ def test_the_backend_counts_the_rows_its_layers_visited():
 def test_the_walk_runs_the_grouped_kernel_and_the_rest_xlas():
     """The walk's jaxpr holds the Pallas grouped matmul under its name (the
     fused gate-and-up and the down product) and no ``ragged_dot``; a decode
-    step's and an every-expert layer's hold ``ragged_dot`` and no kernel."""
+    step's and the training layer's hold ``ragged_dot`` and no kernel."""
     from horovod_tpu.utils import profiling
 
     def text(held, x):
@@ -295,6 +295,102 @@ def test_the_walk_runs_the_grouped_kernel_and_the_rest_xlas():
     assert int(stats["tile_rows"][0]) % tile == 0
     _, sown = m.apply(params, x[:, :16], mutable=[MOE_STATS])
     assert "tile_rows" not in sown[MOE_STATS]
+
+
+def every_expert(routing):
+    """An every-expert layer in bfloat16, as served: (the module, its
+    parameters, x [1, T, D] over ``T * k`` = 512 pairs, a bucket's least, and
+    for the MLP router the state the layer before handed on)."""
+    k, e, router_dim = {"top1_with_a_router_state": (1, 4, 16),
+                        "top8": (8, 16, 0)}[routing]
+    m = MoEMLP(embed_dim=D, mlp_dim=F, axis_name=None, dtype=jnp.bfloat16,
+               num_experts=e, experts_per_token=k, router_dim=router_dim)
+    x = inputs(jax.random.PRNGKey(0), 512 // k).astype(jnp.bfloat16)
+    more = {"router_state": jax.random.normal(
+        jax.random.PRNGKey(4), (*x.shape[:2], router_dim))} \
+        if router_dim else {}
+    return m, m.init(jax.random.PRNGKey(1), x, **more), x, more
+
+
+@pytest.mark.parametrize("hole", ["half_the_bucket_padding",
+                                  "an_expert_left_empty"])
+@pytest.mark.parametrize("routing", ["top1_with_a_router_state", "top8"])
+def test_every_expert_given_valid_takes_a_bucket_through_the_kernel(
+        routing, hole):
+    """A served prefill whose layer holds every expert (PR 53): given
+    ``valid`` over a bucket's rows it carries them all, walks nothing, and
+    multiplies them in the walk's kernels: two ``hvd_moe_grouped`` calls and
+    no ``ragged_dot``, ``tile_rows`` sown, the router's state handed on as
+    it was, a position that holds no token 0, and every other equal to the
+    ``ragged_dot`` layer's within bfloat16's rounding (the fused gate-and-up
+    rounds once where three products round three times: a dropped or
+    misplaced pair reads 0.1 and more of the output's size)."""
+    from horovod_tpu.utils import profiling
+
+    m, params, x, more = every_expert(routing)
+    t, k = x.shape[1], m.experts_per_token
+    carried, sown = jax.jit(lambda p, x: m.apply(
+        p, x, mutable=[MOE_STATS], **more))(params, x)
+    assert "tile_rows" not in sown[MOE_STATS]
+    if hole == "half_the_bucket_padding":
+        valid = jnp.arange(t)[None, :] < t // 2
+    else:       # no live token picks expert 0
+        valid = (sown[MOE_STATS]["picks"][0] != 0).all(-1)
+        assert t // 4 < int(valid.sum()) < t
+    apply = lambda p, x: m.apply(  # noqa: E731
+        p, x, valid=valid, mutable=[MOE_STATS], **more)
+    text = str(jax.make_jaxpr(apply)(params, x))
+    assert text.count(f"name={profiling.MOE_GROUPED}") == 2
+    assert "ragged_dot" not in text and "while[" not in text
+    tiled, sown = jax.jit(apply)(params, x)
+    if more:
+        (tiled, state), (carried, want_state) = tiled, carried
+        assert bool((state == want_state).all())
+    stats = sown[MOE_STATS]
+    n_live = int(valid.sum()) * k
+    pairs = np.asarray(stats["expert_pairs"][0])
+    assert pairs.sum() == n_live
+    assert (pairs[0] == 0) == (hole == "an_expert_left_empty")
+    assert int(stats["rows_visited"][0]) == t * k       # carried, all
+    tile = moe.WALK_ROW_TILE
+    assert n_live <= int(stats["tile_rows"][0]) <= n_live + len(pairs) * tile
+    assert int(stats["tile_rows"][0]) % tile == 0
+    got, want = (y[0].astype(jnp.float32) for y in (tiled, carried))
+    assert float(jnp.abs(got[~valid[0]]).max()) == 0.0
+    assert float(jnp.abs(got - want)[valid[0]].max()) \
+        < 2 ** -6 * float(jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("call", ["training", "a_decode_step"])
+@pytest.mark.parametrize("routing", ["top1_with_a_router_state", "top8"])
+def test_every_expert_in_training_and_in_a_decode_step_keeps_ragged_dot(
+        routing, call):
+    """The same layer with no ``valid`` (the training layer, at any size),
+    and given ``valid`` over a decode step's rows: three ``ragged_dot`` and
+    no kernel, no ``tile_rows``; the training form differentiates, and
+    the serving form over a bucket's rows refuses to by name."""
+    from horovod_tpu.utils import profiling
+
+    m, params, x, more = every_expert(routing)
+    if call == "a_decode_step":         # 24 slots, a position each
+        x = x[0, :24, None]
+        more = {n: v[0, :24, None] for n, v in more.items()}
+        more["valid"] = jnp.arange(24)[:, None] % 3 > 0
+    apply = lambda p: m.apply(p, x, mutable=[MOE_STATS], **more)  # noqa: E731
+    text = str(jax.make_jaxpr(apply)(params))
+    assert text.count("ragged_dot_general[") == 3
+    assert profiling.MOE_GROUPED not in text and "while[" not in text
+    assert "tile_rows" not in jax.eval_shape(apply, params)[1][MOE_STATS]
+    if call == "training":
+        first = lambda out: out[0] if more else out  # noqa: E731
+        grads = jax.grad(lambda p: first(m.apply(p, x, **more)).astype(
+            jnp.float32).sum())(params)
+        assert float(jnp.abs(grads["params"]["gate"]).max()) > 0
+        with pytest.raises(NotImplementedError,
+                           match="valid.*hvd_moe_grouped.*no backward"):
+            jax.grad(lambda p: first(m.apply(
+                p, x, valid=jnp.ones(x.shape[:2], bool), **more)).astype(
+                    jnp.float32).sum())(params)
 
 
 @pytest.mark.parametrize("t, c, d, dtype, live_share", [

@@ -514,7 +514,7 @@ def test_a_share_of_the_experts_keeps_no_array_of_all_its_pairs(
     program holds the block's ``[C, D]`` and ``[C, F]`` rows, the token-sum
     kernel under its name inside one ``while``, and no ``[T * k, D]`` or
     ``[T * k, F]`` array; the same layer with every expert of ``held`` held
-    carries them all, as it did."""
+    carries them all, as it did, through the same kernels (PR 53)."""
     from horovod_tpu.models import moe
     from horovod_tpu.utils import profiling
 
@@ -553,18 +553,57 @@ def test_a_share_of_the_experts_keeps_no_array_of_all_its_pairs(
     # and the layer's path as the token-sum is under the combine's, so a
     # join by module keeps their time in the layer's; XLA's own
     # ``ragged-dot`` kernels are the carried layer's alone
-    grouped = [line for line in walked.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line
-               and profiling.MOE_GROUPED in line]
+    grouped = _kernels_named(walked, profiling.MOE_GROUPED)
     assert len(grouped) == 2
     path = re.search(r'op_name="([^"]*)' + profiling.TOKEN_SUM,
                      kernels[0]).group(1).split(profiling.MOE_COMBINE)[0]
-    assert all(f'op_name="{path}{profiling.MOE_EXPERTS}/' in line
-               for line in grouped)
+    assert all(name.startswith(f"{path}{profiling.MOE_EXPERTS}/")
+               for name in grouped)
     assert "ragged-dot" not in walked
     carried = text_of(held, None)
     assert all_pairs.search(carried) and profiling.TOKEN_SUM not in carried
-    assert "ragged-dot" in carried and profiling.MOE_GROUPED not in carried
+    assert "ragged-dot" not in carried
+    assert len(_kernels_named(carried, profiling.MOE_GROUPED)) == 2
+
+
+@pytest.mark.parametrize("b,s,ours", [(1, 1024, 2), (1, 8192, 2), (24, 1, 0)],
+                         ids=["shortest_bucket", "longest_bucket",
+                              "a_decode_step"])
+def test_every_expert_held_compiles_a_buckets_products_as_the_kernel(
+        one_chip_mesh, monkeypatch, b, s, ours):
+    """ZAYA1-8B's expert layer (16 experts of 2048 x 2048, top-1 by an MLP
+    router with a state, every one held) given ``valid``, compiled for the
+    chip (PR 53): over its shortest and its longest bucket the two
+    ``hvd_moe_grouped`` calls under the experts' scope, no ``ragged-dot``
+    and no walk; over a decode step's 24 rows XLA's ``ragged-dot`` kernels
+    and none of ours."""
+    from horovod_tpu.models import moe
+    from horovod_tpu.utils import profiling
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    d, rd = 2048, 256
+    m = moe.MoEMLP(embed_dim=d, mlp_dim=d, axis_name=None,
+                   dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+                   num_experts=16, experts_per_token=1, router_dim=rd)
+    shaped = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: shaped(*a.shape, dtype=a.dtype),
+        jax.eval_shape(lambda: m.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, d), jnp.bfloat16),
+            router_state=jnp.zeros((1, 8, rd)))))
+    text = jax.jit(lambda p, x, v, r: m.apply(
+        p, x, valid=v, router_state=r)).lower(
+            params, shaped(b, s, d), shaped(b, s, dtype=jnp.bool_),
+            shaped(b, s, rd, dtype=jnp.float32)).compile().as_text()
+    kernels = _kernels_named(text, profiling.MOE_GROUPED)
+    assert len(kernels) == ours
+    assert all(f"/{profiling.MOE_EXPERTS}/" in name for name in kernels)
+    assert ("ragged-dot" in text) == (not ours)
+    # (nothing is walked: the only loop is the router's over the row blocks
+    # of the longest bucket, and no sum by token follows the products)
+    assert profiling.TOKEN_SUM not in text
 
 
 # The four serving cells cut to two layers at their own widths (for A.X-K1
