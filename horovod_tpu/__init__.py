@@ -28,6 +28,13 @@ multi-second jax import just to reach their rendezvous window.
 from __future__ import annotations
 
 import importlib
+import sys
+import time
+
+# the package's import as a span of its own (``hvd_setup_import``, written
+# at this file's end): from this line, and whether jax was there before,
+# since then jax's import is the caller's and not in it
+_import_began, _jax_loaded = time.perf_counter(), "jax" in sys.modules
 
 __version__ = "0.1.0"
 
@@ -119,3 +126,15 @@ def __getattr__(name: str):
 
 def __dir__():
     return sorted(set(__all__) | _SUBMODULES | set(_MODULE_ATTRS))
+
+
+def _record_import() -> None:
+    from horovod_tpu.utils import profiling
+
+    inside = profiling.current_span()
+    profiling.open_span(profiling.SETUP_IMPORT, start=_import_began,
+                        cause=inside.id if inside else 0,
+                        jax_loaded=_jax_loaded).close()
+
+
+_record_import()

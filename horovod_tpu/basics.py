@@ -148,76 +148,82 @@ def init(*, distributed: bool | None = None, coordinator_address: str | None = N
     with _lock:
         if _topology is not None:
             return
-        # Decide on jax.distributed BEFORE touching any jax API that would
-        # initialise the XLA backend (initialize() refuses to run after that).
-        if coordinator_address is None:
-            coordinator_address = os.environ.get("JAX_COORDINATOR_ADDRESS")
-        if num_processes is None and "JAX_NUM_PROCESSES" in os.environ:
-            num_processes = int(os.environ["JAX_NUM_PROCESSES"])
-        if process_id is None and "JAX_PROCESS_ID" in os.environ:
-            process_id = int(os.environ["JAX_PROCESS_ID"])
-        want_dist = distributed
-        if want_dist is None:
-            want_dist = coordinator_address is not None
-        if want_dist:
-            try:
-                jax.distributed.initialize(
-                    coordinator_address=coordinator_address,
-                    num_processes=num_processes,
-                    process_id=process_id,
-                )
-            except RuntimeError:
-                # Either the user already initialised jax.distributed (fine —
-                # topology below is still correct) or the backend was touched
-                # first in a genuinely single-process run.
-                if jax.process_count() == 1 and (num_processes or 1) > 1:
-                    raise
-        pid, nproc = jax.process_index(), jax.process_count()
-        if ranks is not None:
-            members = tuple(int(r) for r in ranks)
-            if len(set(members)) != len(members) or not members or any(
-                    r < 0 or r >= nproc for r in members):
-                raise ValueError(
-                    f"init(ranks={list(ranks)}): ranks must be distinct "
-                    f"process indices in [0, {nproc})")
-            if pid not in members:
-                raise ValueError(
-                    f"process {pid} is not in init(ranks={list(ranks)}); "
-                    f"every member passes the same list and non-members "
-                    f"must not init this job (no COMM_WORLD fallback on "
-                    f"the TPU rebuild — the mesh is restricted to members)")
-            rank_, size_ = members.index(pid), len(members)
-        else:
-            members = tuple(range(nproc))
-            rank_, size_ = pid, nproc
-        devices = [d for d in jax.devices()
-                   if getattr(d, "process_index", 0) in set(members)]
-        local = jax.local_devices()
-        cross_rank, cross_size = _detect_slices(devices)
-        # JAX runs one process per host, so the host-local "communicator"
-        # contains exactly this process; local_rank mirrors the reference's
-        # node-local rank used for device pinning (N/A on TPU, kept for API
-        # parity with reference common/__init__.py:104-121).
-        topo = Topology(
-            rank=rank_,
-            size=size_,
-            local_rank=0,
-            local_size=1,
-            cross_rank=cross_rank,
-            cross_size=cross_size,
-            num_chips=len(devices),
-            local_num_chips=len(local),
-            chips_per_slice=max(len(devices) // max(cross_size, 1), 1),
-            member_pids=members,
-        )
-        # Build the global mesh BEFORE publishing topology so a mesh failure
-        # leaves the process cleanly un-initialized (re-init can retry);
-        # mirrors comm setup at reference operations.cc:1484-1532.
-        from horovod_tpu import mesh as _mesh
+        # the work as a span (``hvd_setup_init``; a repeated call writes
+        # none), and the compile ledger listening before anything compiles
+        from horovod_tpu.utils import profiling
 
-        _mesh.build_global_mesh(mesh_axes, cross_size=cross_size,
-                                devices=devices)
-        _topology = topo
+        profiling.listen()
+        with profiling.span(profiling.SETUP_INIT):
+            # Decide on jax.distributed BEFORE touching any jax API that would
+            # initialise the XLA backend (initialize() refuses to run after that).
+            if coordinator_address is None:
+                coordinator_address = os.environ.get("JAX_COORDINATOR_ADDRESS")
+            if num_processes is None and "JAX_NUM_PROCESSES" in os.environ:
+                num_processes = int(os.environ["JAX_NUM_PROCESSES"])
+            if process_id is None and "JAX_PROCESS_ID" in os.environ:
+                process_id = int(os.environ["JAX_PROCESS_ID"])
+            want_dist = distributed
+            if want_dist is None:
+                want_dist = coordinator_address is not None
+            if want_dist:
+                try:
+                    jax.distributed.initialize(
+                        coordinator_address=coordinator_address,
+                        num_processes=num_processes,
+                        process_id=process_id,
+                    )
+                except RuntimeError:
+                    # Either the user already initialised jax.distributed (fine —
+                    # topology below is still correct) or the backend was touched
+                    # first in a genuinely single-process run.
+                    if jax.process_count() == 1 and (num_processes or 1) > 1:
+                        raise
+            pid, nproc = jax.process_index(), jax.process_count()
+            if ranks is not None:
+                members = tuple(int(r) for r in ranks)
+                if len(set(members)) != len(members) or not members or any(
+                        r < 0 or r >= nproc for r in members):
+                    raise ValueError(
+                        f"init(ranks={list(ranks)}): ranks must be distinct "
+                        f"process indices in [0, {nproc})")
+                if pid not in members:
+                    raise ValueError(
+                        f"process {pid} is not in init(ranks={list(ranks)}); "
+                        f"every member passes the same list and non-members "
+                        f"must not init this job (no COMM_WORLD fallback on "
+                        f"the TPU rebuild — the mesh is restricted to members)")
+                rank_, size_ = members.index(pid), len(members)
+            else:
+                members = tuple(range(nproc))
+                rank_, size_ = pid, nproc
+            devices = [d for d in jax.devices()
+                       if getattr(d, "process_index", 0) in set(members)]
+            local = jax.local_devices()
+            cross_rank, cross_size = _detect_slices(devices)
+            # JAX runs one process per host, so the host-local "communicator"
+            # contains exactly this process; local_rank mirrors the reference's
+            # node-local rank used for device pinning (N/A on TPU, kept for API
+            # parity with reference common/__init__.py:104-121).
+            topo = Topology(
+                rank=rank_,
+                size=size_,
+                local_rank=0,
+                local_size=1,
+                cross_rank=cross_rank,
+                cross_size=cross_size,
+                num_chips=len(devices),
+                local_num_chips=len(local),
+                chips_per_slice=max(len(devices) // max(cross_size, 1), 1),
+                member_pids=members,
+            )
+            # Build the global mesh BEFORE publishing topology so a mesh failure
+            # leaves the process cleanly un-initialized (re-init can retry);
+            # mirrors comm setup at reference operations.cc:1484-1532.
+            from horovod_tpu import mesh as _mesh
+
+            _mesh.build_global_mesh(mesh_axes, cross_size=cross_size,
+                                    devices=devices)
+            _topology = topo
     atexit.register(shutdown)  # reference common/__init__.py:69
 
 

@@ -31,7 +31,7 @@ from typing import Callable
 
 import numpy as np
 
-from horovod_tpu.utils import env
+from horovod_tpu.utils import env, profiling
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 # HVD_CORE_LIB selects an alternate build (e.g. libhvdcore_tsan.so).
@@ -211,11 +211,24 @@ _lib: ctypes.CDLL | None = None
 _lib_lock = threading.Lock()
 
 
+def _built_at() -> float | None:
+    try:
+        return os.stat(_LIB_PATH).st_mtime
+    except OSError:
+        return None
+
+
 def lib() -> ctypes.CDLL:
     global _lib
     with _lib_lock:
         if _lib is None:
-            _lib = _load_library()
+            # ``make`` and the load as a span (``hvd_setup_engine``): its
+            # cause is the span of whoever asked first, ``built`` whether
+            # make left a new library and did not find a current one
+            with profiling.span(profiling.SETUP_ENGINE) as s:
+                before = _built_at()
+                _lib = _load_library()
+                s.fields["built"] = _built_at() != before
         return _lib
 
 

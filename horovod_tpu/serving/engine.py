@@ -378,7 +378,11 @@ class TransformerBackend:
         # the pool is the model's to shape: K and V [L, slots, S, KV, D], or
         # latents and their rotary keys [L, slots, S, rank] / [.., rope], or
         # for a model with "kda" layers two trees with an entry a cache kind
-        self.kk, self.vv = init_kv_cache(model_cfg, num_slots, max_seq_len)
+        with profiling.span(profiling.SETUP_POOL) as made:
+            self.kk, self.vv = jax.block_until_ready(
+                init_kv_cache(model_cfg, num_slots, max_seq_len))
+            made.fields["bytes"] = sum(
+                int(x.nbytes) for x in jax.tree.leaves((self.kk, self.vv)))
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
         self._decode = jax.jit(self._decode_fn, donate_argnums=(1, 2))
         self._verify = jax.jit(self._verify_fn, donate_argnums=(1, 2))
@@ -736,7 +740,10 @@ class PagedTransformerBackend:
         from horovod_tpu.models.transformer import init_kv_pages
 
         num_pages = 1 + num_slots * self.pages_per_slot + cache_pages
-        self.pk, self.pv = init_kv_pages(model_cfg, num_pages, page_size)
+        with profiling.span(profiling.SETUP_POOL) as made:
+            self.pk, self.pv = jax.block_until_ready(
+                init_kv_pages(model_cfg, num_pages, page_size))
+            made.fields["bytes"] = int(self.pk.nbytes + self.pv.nbytes)
         # Host-side page tables: row s = the pages slot s reads/writes,
         # in sequence order.  Row of zeros = detached (scratch page 0).
         self.page_tables = np.zeros((num_slots, self.pages_per_slot),
@@ -880,6 +887,9 @@ class ServingEngine:
                  on_complete: Callable[[Request], None] | None = None,
                  tick_name: str | None = None):
         global _ACTIVE
+        # the compile ledger (profiling.listen) before this engine's first
+        # call compiles anything; nothing in a process without jax (a stub)
+        profiling.listen()
         self.backend = backend
         self.config = config or ServingConfig()
         self.collective = collective
@@ -1321,9 +1331,24 @@ class ServingEngine:
         ``tile_rows``, the rows of the row tiles their grouped matmul
         worked, and ``tile_rows_per_held_pair``; over the decode steps
         ``experts_touched``, the distinct experts the live slots picked
-        summed over layers and steps."""
+        summed over layers and steps.  A process that compiled anything
+        also has the compile ledger's names, and under
+        ``hvd_compile_backend`` ``after_first_token``: the ``count`` of
+        backend compiles that ended after the first prefill the records
+        hold had completed, and of the last 32 of them the ``programs``
+        (``fun_name``, ``cache``, ``seconds`` and, through the span that
+        caused it, ``span`` and ``bucket``).  Warm-up requests through the
+        engine are there with their buckets; once the engine serves, the
+        programs "never recompile", and a count that grows is a prompt
+        that met a shape nobody warmed."""
         records = profiling.spans()
         out = profiling.summarize(records)
+        if profiling.COMPILE_BACKEND in out:
+            first = min((r.end for r in records
+                         if r.name == profiling.SRV_PREFILL),
+                        default=float("inf"))
+            out[profiling.COMPILE_BACKEND]["after_first_token"] = \
+                profiling.compiles_after(records, first)
         by_attn: dict[str, dict] = {}
         for r in records:
             if r.name == profiling.SRV_PREFILL and "attn" in r.fields:

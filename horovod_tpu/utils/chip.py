@@ -22,7 +22,13 @@ def require_tpu(what: str) -> None:
     phase, so the error says which measurement was refused."""
     import jax
 
-    backend = jax.default_backend()
+    from horovod_tpu.utils import profiling
+
+    # a span (``hvd_setup_backend``): the first ask of the backend attaches
+    # the TPU runtime, seconds of a start; after the caller's own
+    # ``jax.devices()`` it is a mark of where that ended
+    with profiling.span(profiling.SETUP_BACKEND):
+        backend = jax.default_backend()
     if backend != "tpu":
         raise RuntimeError(
             f"{what} measures the accelerator and found none: JAX's default "
@@ -38,7 +44,10 @@ def enable_compile_cache() -> str:
     With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads it and this
     sets nothing.  Otherwise the cache lives at ``.jax_cache/`` in the
     checkout — a fixed path, because the path is part of how a cached
-    program is found again.  Call before the first compile.
+    program is found again.  Call before the first compile: it also
+    registers the compile ledger's listener (``profiling.listen``), so that
+    every compile after it leaves its ``hvd_compile_*`` records, with the
+    cache's hit or miss.
 
     The names a program gives its work (``op_name``: module paths, the
     ``hvd_*`` scopes of utils/profiling.py) are made part of the key.  JAX
@@ -48,6 +57,9 @@ def enable_compile_cache() -> str:
     scopes out of this one's executable."""
     import jax
 
+    from horovod_tpu.utils import profiling
+
+    profiling.listen()
     jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:

@@ -29,6 +29,18 @@ benchmark (``benchmarks/serve_spans.py``) and by an operator
 (``ServingEngine.span_summary()``).  The ring is always there, holds
 :data:`SPAN_CAPACITY` records and drops the oldest; importing this module,
 or writing a span, never imports jax.
+
+Start-up is read the same way.  The ``hvd_setup_*`` spans are written where
+the work happens (the package's import, ``basics.init``, the native
+engine's build and load, a serving backend's pool, ``chip.require_tpu``'s
+ask of the backend), and :func:`listen`
+registers ONE listener of ``jax.monitoring`` that writes a record a compile
+stage (``hvd_compile_trace`` / ``_lower`` / ``_backend``: the function's
+name, the persistent cache's hit or miss, the span that caused it).  Both
+kinds go to a store of their own beside the ring, of at most
+:data:`STARTUP_CAPACITY` records, so that an hour of ``hvd_srv_*`` spans
+does not push a process's start out of :func:`spans`;
+:func:`startup_summary` adds them up.
 """
 
 from __future__ import annotations
@@ -131,6 +143,26 @@ SRV_H2D = "hvd_srv_h2d"             # a backend call: the copies in
 SRV_DISPATCH = "hvd_srv_dispatch"   # ... the jitted call, until enqueued
 SRV_WAIT = "hvd_srv_wait"           # ... until the tokens are on the host
 SRV_FETCH = "hvd_srv_fetch"         # ... logits and pair counts to the host
+# Start-up, each where the work happens, written through :func:`span`:
+SETUP_IMPORT = "hvd_setup_import"   # horovod_tpu/__init__.py, first line to
+                                    # last; ``jax_loaded``: jax was imported
+                                    # before, so its import is the caller's
+SETUP_INIT = "hvd_setup_init"       # basics.init, the call that does the work
+SETUP_ENGINE = "hvd_setup_engine"   # core/engine.lib: ``make`` and the load;
+                                    # ``built``: make produced a new library
+SETUP_POOL = "hvd_setup_pool"       # serving backend: the pool made on the
+                                    # device; ``bytes``
+SETUP_BACKEND = "hvd_setup_backend"     # chip.require_tpu asking jax for its
+                                    # backend: the TPU runtime's attach where
+                                    # nobody asked before; after the caller's
+                                    # own ``jax.devices()`` a mark of its end
+# The compile ledger, written by :func:`listen`'s listener, records only:
+COMPILE_TRACE = "hvd_compile_trace"     # a function traced to a jaxpr
+COMPILE_LOWER = "hvd_compile_lower"     # a jaxpr lowered to an MLIR module
+COMPILE_BACKEND = "hvd_compile_backend"     # XLA / Mosaic compiling it, or
+                                    # the persistent cache's load; ``cache``
+                                    # ("hit", "miss", "off"), ``retrieval_s``,
+                                    # ``saved_s``.  All three: ``fun_name``
 
 FLASH_PASSES = (FLASH_FWD, FLASH_DQ, FLASH_DKV, FLASH_BWD)
 MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
@@ -141,9 +173,19 @@ KDA_SCOPES = (KDA_PROJ, KDA_CONV, KDA_GATE, KDA_SCAN, KDA_OUT)
 CCA_SCOPES = (CCA_PROJ, CCA_CONV, CCA_ATTN, CCA_OUT)
 SRV_CALLS = (SRV_PREFILL, SRV_DECODE, SRV_VERIFY)   # the backend's calls
 SRV_LEAVES = (SRV_H2D, SRV_DISPATCH, SRV_WAIT, SRV_FETCH)   # in call order
+SETUP_SPANS = (SETUP_IMPORT, SETUP_INIT, SETUP_ENGINE, SETUP_POOL,
+               SETUP_BACKEND)
+COMPILE_STAGES = (COMPILE_TRACE, COMPILE_LOWER, COMPILE_BACKEND)
 # records the span ring holds before it drops the oldest: a 35 s serving
 # run writes about 12 000
 SPAN_CAPACITY = 65536
+# ``hvd_setup_*`` and ``hvd_compile_*`` records kept beside the ring, the
+# EARLIEST of the process: a benchmark cell's start writes 1 600 (a sparse
+# training step) to 35 000 (four prefill programs of a 40-layer served model:
+# every inner ``jax.jit`` is a trace record once a signature).  Past the
+# bound a later one goes into the ring like any other record, so a process
+# that recompiles for ever holds at most the two bounds together.
+STARTUP_CAPACITY = 65536
 # XLA:TPU replaces ``lax.ragged_dot`` with Mosaic kernels of its own and
 # names them afresh (``op_name="ragged-dot-none"``, and
 # ``"ragged-dot-metadata"`` for the tile table they share): the scope the
@@ -182,6 +224,9 @@ def annotate(name: str):
 # -- Spans that are also records ---------------------------------------------
 
 _ring: collections.deque = collections.deque(maxlen=SPAN_CAPACITY)
+_startup: list = []             # at most STARTUP_CAPACITY, the earliest
+_startup_lock = threading.Lock()
+_STARTUP_NAMES = frozenset(SETUP_SPANS + COMPILE_STAGES)
 _ids = itertools.count(1)
 _open = threading.local()       # .stack: this thread's open ``with`` spans
 
@@ -242,8 +287,8 @@ class Span:
         every window of the benchmark (PERF.md, PR 39)."""
         self.end = time.perf_counter() if end is None else end
         self.fields.update(fields)
-        _ring.append((self.name, self.start, self.end, self.id, self.cause,
-                      self.rid, tuple(self.fields.items())))
+        _keep((self.name, self.start, self.end, self.id, self.cause,
+               self.rid, tuple(self.fields.items())))
 
     def __enter__(self) -> "Span":
         stack = getattr(_open, "stack", None)
@@ -273,6 +318,17 @@ class Span:
 span = Span     # ``with profiling.span(SRV_STEP, queued=3):``
 
 
+def _keep(kept: tuple) -> None:
+    """A closed span's tuple into the ring, or, a start-up record while
+    there is room, into the store the ring's turnover does not reach."""
+    if kept[0] in _STARTUP_NAMES and len(_startup) < STARTUP_CAPACITY:
+        with _startup_lock:
+            if len(_startup) < STARTUP_CAPACITY:
+                _startup.append(kept)
+                return
+    _ring.append(kept)
+
+
 def current_span() -> Span | None:
     """This thread's innermost open ``with`` span, for what is called
     inside one to add to its ``fields``."""
@@ -292,9 +348,234 @@ def open_span(name: str, *, start: float | None = None, cause: int = 0,
 
 
 def spans() -> list[Record]:
-    """The ring's records, oldest first: at most :data:`SPAN_CAPACITY`, in
-    the order they closed."""
-    return [Record(*kept[:6], dict(kept[6])) for kept in list(_ring)]
+    """The records held, in one list by the time they ended: the start-up
+    store's (at most :data:`STARTUP_CAPACITY`, the process's earliest
+    ``hvd_setup_*`` and ``hvd_compile_*``) and the ring's (the last
+    :data:`SPAN_CAPACITY` of every other name)."""
+    held = sorted(list(_startup) + list(_ring), key=lambda kept: kept[2])
+    return [Record(*kept[:6], dict(kept[6])) for kept in held]
+
+
+# -- Start-up and the compile ledger ------------------------------------------
+
+# jax.monitoring's names (jax 0.9): the three stages of a compile, each a
+# duration with ``fun_name``; the persistent cache's account of a backend
+# compile, which the compiling thread emits INSIDE that compile's duration
+_STAGE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": COMPILE_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": COMPILE_LOWER,
+    "/jax/core/compile/backend_compile_duration": COMPILE_BACKEND}
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s"}
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_compiling = threading.local()  # .cache: what this thread's open backend
+                                # compile has heard from the cache so far
+_listening = False
+
+
+def _on_duration(event: str, seconds: float, **kw) -> None:
+    """One record a compile stage: it ends now, on the ring's clock, and
+    began ``seconds`` ago; its cause is the span this thread is inside."""
+    name = _STAGE_OF.get(event)
+    if name is None:
+        key = _CACHE_SECONDS.get(event)
+        if key is not None:
+            heard = getattr(_compiling, "cache", None)
+            if heard is not None:
+                heard[key] = seconds
+        return
+    end = time.perf_counter()
+    fields = {"fun_name": str(kw.get("fun_name", ""))}
+    if name == COMPILE_BACKEND:
+        heard = getattr(_compiling, "cache", None)
+        fields["cache"] = "off"
+        # (jax asks its cache layer with no directory set too: no cache)
+        if heard is not None and \
+                sys.modules["jax"].config.jax_compilation_cache_dir:
+            fields.update(heard)
+        _compiling.cache = None
+    stack = getattr(_open, "stack", None)
+    _keep((name, end - seconds, end, next(_ids),
+           stack[-1].id if stack else 0, None, tuple(fields.items())))
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_ASKED:       # a compile that uses the cache begins:
+        _compiling.cache = {"cache": "miss"}    # a miss unless a hit follows
+    elif event == _CACHE_HIT:
+        heard = getattr(_compiling, "cache", None)
+        if heard is not None:
+            heard["cache"] = "hit"
+
+
+def listen() -> bool:
+    """Register the compile ledger's listener with ``jax.monitoring``, once
+    however often it is called; False, and nothing done, in a process that
+    has not imported jax (this never does).  Called by the program's own
+    code that runs before a first compile: ``chip.enable_compile_cache``,
+    ``basics.init``, ``ServingEngine.__init__``."""
+    global _listening
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    with _startup_lock:
+        if not _listening:
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
+            jax.monitoring.register_event_listener(_on_event)
+            _listening = True
+    return True
+
+
+def _union(intervals) -> list[tuple[float, float]]:
+    out: list[list[float]] = []
+    for lo, hi in sorted(intervals):
+        if out and lo <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], hi)
+        elif hi > lo:
+            out.append([lo, hi])
+    return [(lo, hi) for lo, hi in out]
+
+
+def _seconds(union) -> float:
+    return sum(hi - lo for lo, hi in union)
+
+
+def _shared(a: list, b: list) -> list[tuple[float, float]]:
+    """The parts two unions of intervals share."""
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if lo < hi:
+            out.append((lo, hi))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
+_CAUSES = SETUP_SPANS + SRV_CALLS   # the spans a compile is said to be under
+
+
+def under(record: Record, by_id: dict) -> dict:
+    """Who caused ``record``: up its ``cause`` chain, the first span that is
+    a start-up span or a backend call (``span``), and the first ``bucket``
+    on the way (a compile inside ``hvd_srv_dispatch`` inside
+    ``hvd_srv_prefill{bucket=8192}`` belongs to that bucket).  Empty for a
+    record nobody's span caused: the caller's own code."""
+    out: dict = {}
+    seen = set()
+    while record.cause and record.cause not in seen:
+        seen.add(record.cause)
+        record = by_id.get(record.cause)
+        if record is None:
+            break
+        if "bucket" in record.fields:
+            out.setdefault("bucket", record.fields["bucket"])
+        if record.name in _CAUSES:
+            out.setdefault("span", record.name)
+            break
+    return out
+
+
+def _programs(compiles: list, records) -> list[dict]:
+    """``hvd_compile_backend`` records as a reader wants them: ``fun_name``,
+    ``cache``, ``seconds`` and who caused each (:func:`under`)."""
+    by_id = {r.id: r for r in records}
+    return [{"fun_name": r.fields.get("fun_name", ""),
+             "cache": r.fields.get("cache", "off"),
+             "seconds": r.seconds, **under(r, by_id)} for r in compiles]
+
+
+def compiles_after(records, t: float, keep: int = 32) -> dict:
+    """The backend compiles among ``records`` that ended after ``t``: their
+    ``count`` and the last ``keep`` of them (:func:`_programs`)."""
+    late = [r for r in records if r.name == COMPILE_BACKEND and r.end > t]
+    return {"count": len(late), "programs": _programs(late[-keep:], records)}
+
+
+def _stretches_between(named: list, held: list, since, until,
+                       keep: int) -> list[dict]:
+    """The longest stretches between ``named`` (a union of intervals), with
+    the record of ``held`` each begins and ends at."""
+    if not named:
+        return []
+    lo = named[0][0] if since is None else since
+    edges = [lo] + [t for iv in named for t in iv] \
+        + [named[-1][1] if until is None else until]
+    label = lambda r: " ".join(  # noqa: E731
+        [r.name, r.fields["fun_name"]] if "fun_name" in r.fields
+        else [r.name])
+    ended = {r.end: label(r) for r in held}
+    began = {max(r.start, lo): label(r) for r in reversed(held)}
+    gaps = sorted(((b - a, a, b) for a, b in zip(edges[::2], edges[1::2])
+                   if b > a), reverse=True)[:keep]
+    return [{"seconds": took, "at_s": a - lo, "after": ended.get(a),
+             "before": began.get(b)} for took, a, b in gaps]
+
+
+def startup_summary(records=None, *, since: float | None = None,
+                    until: float | None = None, longest: int = 5) -> dict:
+    """Where a process's start went, from its own records (:func:`spans`
+    unless ``records`` is given): those that ENDED by ``until`` (default:
+    all), cut at ``since``.  Seconds are of the union of a name's
+    intervals, since the parts nest (a jit traced inside a jit lies inside
+    its caller's record, a compile inside ``hvd_setup_init`` or inside a
+    warm-up call):
+
+    ``import_s`` / ``init_s`` / ``engine_s`` / ``pool_s`` / ``backend_s``
+    the five ``hvd_setup_*`` spans; ``trace_s`` / ``lower_s`` / ``backend_compile_s``
+    the three stages of the compile ledger, with ``traces`` and ``programs``
+    the counts of ``hvd_compile_trace`` and ``hvd_compile_backend`` records,
+    ``cache_retrieval_s`` the sum of ``retrieval_s`` (inside
+    ``backend_compile_s``) and ``cache_misses`` the backend compiles the
+    persistent cache did not hold (0 on a warm start; a miss on a machine
+    that has run the program before: docs/timeline.md); ``warm_s`` the
+    serving backend's calls (``SRV_CALLS``) less the compile records inside
+    them: warm-up requests being served; ``named_s`` the union of every
+    record above: what of the process's start has a name; ``longest`` the
+    longest backend compiles (:func:`_programs`) and ``unnamed`` the
+    longest stretches no record covers (from ``since`` and up to ``until``
+    where given), each with its ``seconds``, where it began (``at_s``) and
+    the records it lies between (``after`` / ``before``: a name, and the
+    ``fun_name`` of a compile stage); ``spans`` how many of
+    each ``hvd_setup_*`` span and backend call there were (an absent one
+    was not written: its seconds are no reading of 0)."""
+    if records is None:
+        records = spans()
+    held = [r for r in records if (until is None or r.end <= until)
+            and (since is None or r.end > since)]
+    floor = -math.inf if since is None else since
+    by_name: dict[str, list] = {}
+    for r in held:
+        by_name.setdefault(r.name, []).append((max(r.start, floor), r.end))
+    cover = lambda *names: _union(  # noqa: E731
+        [iv for n in names for iv in by_name.get(n, ())])
+    backend = [r for r in held if r.name == COMPILE_BACKEND]
+    took = lambda *names: _seconds(cover(*names))  # noqa: E731
+    calls, compiles = cover(*SRV_CALLS), cover(*COMPILE_STAGES)
+    named = cover(*SETUP_SPANS, *COMPILE_STAGES, *SRV_CALLS)
+    return {
+        "import_s": took(SETUP_IMPORT), "init_s": took(SETUP_INIT),
+        "engine_s": took(SETUP_ENGINE), "pool_s": took(SETUP_POOL),
+        "backend_s": took(SETUP_BACKEND),
+        "trace_s": took(COMPILE_TRACE),
+        "traces": len(by_name.get(COMPILE_TRACE, ())),
+        "lower_s": took(COMPILE_LOWER),
+        "backend_compile_s": took(COMPILE_BACKEND),
+        "cache_retrieval_s": sum(r.fields.get("retrieval_s", 0.0)
+                                 for r in backend),
+        "cache_misses": sum(r.fields.get("cache") == "miss"
+                            for r in backend),
+        "programs": len(backend),
+        "warm_s": _seconds(calls) - _seconds(_shared(calls, compiles)),
+        "named_s": _seconds(named),
+        "unnamed": _stretches_between(named, held, since, until, longest),
+        "longest": _programs(sorted(
+            backend, key=lambda r: -r.seconds)[:longest], records),
+        "spans": {n: len(by_name[n]) for n in _CAUSES if n in by_name}}
 
 
 def summarize(records) -> dict[str, dict]:

@@ -39,6 +39,15 @@ SERVING_CELLS = ["dsc1p3b-code-0.8knee", "cmdaplus-code8k-open",
                  "ling3f-longdoc32k-open", "zaya1-reason8k-open"]
 
 
+# the start-up spans and the compile ledger (PR 54) are kept in a store of
+# their own beside the ring and handed out with it: tests/test_setup_spans.py
+STARTUP = set(profiling.SETUP_SPANS + profiling.COMPILE_STAGES)
+
+
+def in_the_ring(records: list) -> list:
+    return [r for r in records if r.name not in STARTUP]
+
+
 def since(mark: int) -> list:
     return [r for r in profiling.spans() if r.id > mark]
 
@@ -85,8 +94,10 @@ def jax_free():
 
 def test_a_stub_fleet_s_process_never_imports_jax(jax_free):
     assert jax_free["jax"] is False
+    # (and the package's own import, the one start-up span of such a process)
     assert {"hvd_srv_request", "hvd_srv_queued", "hvd_srv_step",
-            "hvd_srv_prefill", "hvd_srv_decode"} == set(jax_free["summary"])
+            "hvd_srv_prefill", "hvd_srv_decode", "hvd_setup_import"} \
+        == set(jax_free["summary"])
     row = jax_free["summary"]["hvd_srv_decode"]
     assert set(row) == {"count", "total_s", "p50_ms", "p95_ms", "max_ms"}
     assert row["count"] > 0 and row["p50_ms"] <= row["p95_ms"] <= row["max_ms"]
@@ -151,7 +162,7 @@ def test_the_ring_holds_its_capacity_and_no_more():
     k = 10
     for _ in range(profiling.SPAN_CAPACITY + k):
         profiling.open_span("filler").close()
-    ring = profiling.spans()
+    ring = in_the_ring(profiling.spans())
     assert len(ring) == profiling.SPAN_CAPACITY >= 65536
     assert [r.id for r in ring] == list(range(ring[0].id,
                                               ring[0].id + len(ring)))
@@ -172,7 +183,7 @@ def test_every_list_the_engine_holds_is_bounded():
     assert finished == 100_000 and eng.counters["tokens"] == 200_000
     cap = profiling.SPAN_CAPACITY
     assert len(eng._ttft_s) == len(eng._token_s) == cap
-    assert len(profiling.spans()) == cap
+    assert len(in_the_ring(profiling.spans())) == cap
     assert not eng.queue and not eng._undelivered
     # what the ring keeps, the garbage collector need not walk: a full
     # ring of tracked objects lengthens every full collection, a pause of
@@ -188,7 +199,7 @@ def test_every_list_the_engine_holds_is_bounded():
     assert not any(gc.is_tracked(kept) for kept in profiling._ring)
     stats = eng.stats()
     assert stats["completed"] == 100_000 and stats["ttft_p99_ms"] > 0
-    assert set(eng.span_summary()) == {
+    assert set(eng.span_summary()) - STARTUP == {
         "hvd_srv_request", "hvd_srv_queued", "hvd_srv_step",
         "hvd_srv_prefill", "hvd_srv_decode"}
 
@@ -462,7 +473,15 @@ def test_the_four_decode_leaves_sum_to_the_decode_step(toy_run):
     hosts = [k for k, ln in enumerate(lines) if ln.startswith("serve_host: ")]
     assert len(hosts) == 1 and hosts[0] < len(lines) - 1
     said = json.loads(lines[hosts[0]][len("serve_host: "):])
-    assert said["ring_whole"] and said["decode_calls"] > 20
+    # every decode call the harness timed in the window is a span of the
+    # program's there (a call that straddles an edge may fall to one side:
+    # the span opens outside ``Timed``'s stamp).  How MANY there are is the
+    # host's speed and no property of the spans: a loaded host batches the
+    # same requests into fewer steps
+    window = next(ln for ln in lines if ln.startswith("window: "))
+    timed = int(window.split(" decode_steps=")[1].split()[0])
+    assert said["ring_whole"] and timed > 0
+    assert abs(said["decode_calls"] - timed) <= 2
     assert set(said["decode_leaf_mean_ms"]) == set(
         said["prefill_leaf_mean_ms"]) == {"h2d", "dispatch", "wait", "fetch"}
     assert said["traced_decode_leaf_mean_ms"]["wait"] > 0
