@@ -20,12 +20,14 @@ dense GLU MLP, in one of two layouts:
   the experts (``experts_held``, a serving layout) routes over all of them
   and computes its own; where its pairs are a small part of the ``T * k`` it
   walks them in blocks (``_walk_held``).  In a SERVED PREFILL, walked or not
-  (a share held, or ``valid`` given, over a bucket's rows:
-  :data:`GROUPED_ROW_TILE` pairs and more), the three products are Pallas
-  kernels over a work list of small row tiles (ops/grouped_matmul.py,
-  ``hvd_moe_grouped``).  Two paths keep XLA:TPU's own kernels for
-  ``lax.ragged_dot``: the training layer (every expert held and no
-  ``valid``: it needs their backward) and a decode step's few rows.
+  (a share held, or ``valid`` given, over a bucket's rows), and in the
+  TRAINING layer (every expert held and no ``valid``), wherever the layer
+  carries :data:`GROUPED_ROW_TILE` pairs and more, the three products are
+  Pallas kernels over a work list of row tiles (ops/grouped_matmul.py,
+  ``hvd_moe_grouped``: :func:`grouped_row_tile` rows a tile), forward and,
+  where the layer is differentiated, backward (PR 55).  Fewer pairs, a
+  decode step's slots or a test's few tokens, keep XLA:TPU's own kernels
+  for ``lax.ragged_dot``.
 * **one expert per device** (``num_experts`` = 0; ``axis_name`` a bound mesh
   axis, ``TransformerConfig.moe_axis``): a router picks one expert per token
   (switch routing), tokens travel to the device holding their expert over
@@ -68,7 +70,8 @@ MOE_STATS = "moe_stats"     # "expert_pairs": [E] int32, pairs per expert
                             # "rows_visited": int32, the rows the layer
                             # gathered, multiplied and combined for its pairs;
                             # where its products were hvd_moe_grouped's (a
-                            # served prefill) "tile_rows": int32, the rows of
+                            # served prefill, a training step) "tile_rows":
+                            # int32, the rows of
                             # the row tiles the kernel worked (a product's
                             # visits x tile rows)
 
@@ -126,10 +129,10 @@ _permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
 
 
 # rows a tile of XLA:TPU's grouped matmul (lax.ragged_dot) holds: the kernels
-# of the training layer and of a decode step, what a walked block is a whole
-# number of, and the line between a step's rows (slots x k: 24 to 128 in the
-# served cells) and a bucket's (1024 pairs and more): a serving layer that
-# carries this many pairs multiplies them in hvd_moe_grouped's small tiles
+# of a decode step, what a walked block is a whole number of, and the line
+# between a step's rows (slots x k: 24 to 128 in the served cells) and a
+# bucket's or a training step's (1024 pairs and more): a layer that carries
+# this many pairs multiplies them in hvd_moe_grouped's smaller tiles
 GROUPED_ROW_TILE = 512
 
 
@@ -154,6 +157,17 @@ def held_block_rows(pairs: int, held: int, experts: int) -> int:
 # left for a rule over the static shapes to choose; 512, XLA:TPU's own, was
 # 1.4-1.9 times slower.
 WALK_ROW_TILE = 128
+# ... and where the even share of the pairs, ``pairs // experts``, is
+# WIDE_EVEN_SHARE rows and more.  One shape has been read there, the training
+# layer of olmoe-s4096 (131072 pairs over 64 experts: 2048 rows an even share,
+# 5 to 11705 by the cell's own routing, max over mean 5.7), the eight kernels
+# of one jitted forward-and-backward (PERF.md section 6, PR 55): 27.13 ms at
+# 128 rows, 26.73 at 256, 28.95 at 512 (XLA:TPU's ragged_dot kernels 40.59).
+# A group of thousands of rows amortises a 256-row visit's fixed part (the
+# grid step, the mask, the result's read-back) over twice the product and
+# wastes at most 64 x 256 of 131072 rows; at 512 the wasted rows outweigh it.
+WIDE_ROW_TILE = 256
+WIDE_EVEN_SHARE = 1024
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -225,26 +239,25 @@ _walk_blocks.defvjp(_walk_blocks_fwd, _walk_blocks_bwd)
 _walk_held = jax.jit(_walk_blocks, static_argnums=0)
 
 
-@jax.custom_vjp
-def _experts_in_tiles(rows, pairs, w_gate, w_up, w_down):
-    """The three products of a serving layer that carries a bucket's sorted
-    rows [T*k, D], ``pairs`` [held] of them each expert's and the dead ones
-    behind the last group: the walk's two kernels over them all."""
-    hidden = grouped_glu(rows, w_gate, w_up, pairs, tile=WALK_ROW_TILE)
-    return grouped_matmul(hidden, w_down, pairs, tile=WALK_ROW_TILE)
+def grouped_row_tile(pairs: int, experts: int) -> int:
+    """Rows a row tile of ``hvd_moe_grouped`` holds where a layer carries
+    the ``pairs`` rows of a routing over ``experts``, from the static shapes
+    alone: the even share ``pairs // experts`` decides.  Under
+    :data:`WIDE_EVEN_SHARE` rows an expert :data:`WALK_ROW_TILE` (the served
+    buckets: 32 to 520); from it on :data:`WIDE_ROW_TILE` where the pairs
+    are whole tiles of it (the training layer: 2048)."""
+    if pairs // experts >= WIDE_EVEN_SHARE and pairs % WIDE_ROW_TILE == 0:
+        return WIDE_ROW_TILE
+    return WALK_ROW_TILE
 
 
-def _experts_in_tiles_bwd(_, g):
-    raise NotImplementedError(
-        "MoEMLP given valid=... (or a share, experts_held=...) over a "
-        "bucket's rows multiplies them in hvd_moe_grouped's tiles "
-        "(ops/grouped_matmul.py), which has no backward: both are "
-        "serving's.  Differentiate the layer with every expert held "
-        "(experts_held=None) and no valid")
-
-
-_experts_in_tiles.defvjp(lambda *given: (_experts_in_tiles(*given), None),
-                         _experts_in_tiles_bwd)
+def _experts_in_tiles(tile, rows, pairs, w_gate, w_up, w_down):
+    """The three products of a layer that carries its sorted rows [T*k, D],
+    ``pairs`` [held] of them each expert's and the dead ones (a serving
+    layer's) behind the last group: the walk's two kernels over them all,
+    and where the layer is differentiated their backward's."""
+    hidden = grouped_glu(rows, w_gate, w_up, pairs, tile=tile)
+    return grouped_matmul(hidden, w_down, pairs, tile=tile)
 
 
 # how the router's logits [T, E] become an expert's score for a token
@@ -526,16 +539,19 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
         # bucket) the three scopes below carry the T*k rows as they are.
         block_rows = held_block_rows(t * k, held, e)
         walks = not everything and block_rows < t * k
-        # A serving layer that carries a bucket's rows (whole row tiles of
-        # them; a decode step has fewer) multiplies them in the walk's
-        # kernels: a visit a (row tile, expert) that meet, none for the
-        # padding behind the last group.  At 16 experts and top-1 a layer
+        # A layer that carries a bucket's or a training step's rows (whole
+        # row tiles of them; a decode step has fewer) multiplies them in the
+        # walk's kernels: a visit a (row tile, expert) that meet, none for
+        # the padding behind the last group.  At 16 experts and top-1 a layer
         # alone took 1.81 -> 0.87 ms at 2867 live rows of 4096, and walking
         # them in one block 1.01: its sum's float32 buffer, second sort and
         # hvd_token_sum cost more than _dispatch and _permute (PERF.md
         # section 6, PR 53).
-        in_tiles = not everything and not walks \
-            and t * k >= GROUPED_ROW_TILE and t * k % WALK_ROW_TILE == 0
+        # (not while init runs the layer for its parameters' shapes: it
+        # would trace and lower two kernels that nothing runs)
+        in_tiles = not walks and t * k >= GROUPED_ROW_TILE \
+            and t * k % WALK_ROW_TILE == 0 and not m.is_initializing()
+        tile = WALK_ROW_TILE if walks else grouped_row_tile(t * k, e)
         if not walks:
             inverse = jnp.argsort(order)
         pairs = (local[..., None] == jnp.arange(held)).sum(
@@ -548,8 +564,7 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
             if walks or in_tiles:
                 # (a block is whole tiles, so the blocks' visits are those
                 # of the held pairs laid end to end)
-                m.sow(MOE_STATS, "tile_rows",
-                      visited_rows(pairs, WALK_ROW_TILE))
+                m.sow(MOE_STATS, "tile_rows", visited_rows(pairs, tile))
             m.sow(MOE_STATS, "picks", picks.reshape(b, s, k))
             if m.selection == "softmax" and live is None:
                 logits, probs = every
@@ -584,9 +599,10 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
 
         with jax.named_scope(profiling.MOE_EXPERTS):
             if in_tiles:
-                out_rows = _experts_in_tiles(
-                    rows, pairs, w_gate.astype(m.dtype),
-                    w_up.astype(m.dtype), w_down.astype(m.dtype))
+                # (the kernels' wrappers cast the weights themselves, so
+                # that their gradients come back in the parameters' dtype)
+                out_rows = _experts_in_tiles(tile, rows, pairs, w_gate,
+                                             w_up, w_down)
             else:
                 grouped = functools.partial(lax.ragged_dot,
                                             group_sizes=pairs)

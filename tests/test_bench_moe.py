@@ -94,6 +94,38 @@ def test_the_family_agrees_with_the_reference_in_float32(hvd, family,
     assert built.flash_calls == [dict(b=2, h=4, s=256, d=16, causal=True)] * 2
 
 
+def test_a_step_through_the_grouped_kernels_passes_the_familys_comparison(
+        hvd, family):
+    """The small OLMoE configuration as the chip runs it (bfloat16, the
+    family's own tolerances), a sequence of 256 tokens a device: 512 pairs a
+    layer, so the expert products and their backward are ``hvd_moe_grouped``'s
+    (PR 55; interpreted here) and no ``ragged_dot`` is left in the step.
+    The compiled step's scope table files what the kernels do, forward and
+    backward, under the layer's module path inside ``hvd_moe_experts``,
+    where ``moe_experts_ms`` reads it."""
+    chips = hvd.num_chips()
+    traffic = {**TRAFFIC, "per_chip": 1, "compare_seq_len": 256}
+    built = family.build(TINY, traffic, chips, 2**31 + 13)
+    params = built.init_model()
+    checks = {c["name"]: c for c in built.compare(params)}
+    # (a model 64 wide rounds its router's input too coarsely in bfloat16
+    # for the tie check, kernels or none: PR 54's tree reads the same 1.108)
+    gap = checks.pop("routing_disagreement_log_prob_gap")
+    assert len(checks) == 4 and all(c["ok"] for c in checks.values()), checks
+    assert gap["error"] == pytest.approx(1.1079, abs=1e-3)
+    assert built.notes["expert_load"]["pairs"] == 256 * 2
+    state = built.init_train(params)
+    tokens = jax.device_put(built.pool[0][0], built.batch_shardings[0])
+    lowered = built.step.lower(state, tokens)
+    assert "ragged_dot" not in lowered.as_text()
+    table = profiling.scope_table(lowered.compile())
+    ours = [s for s in table.values() if profiling.MOE_GROUPED in s.op_name]
+    assert {s.phase for s in ours} >= {"forward", "backward"}
+    assert {s.module for s in ours} == {
+        f"Transformer/layer_N/moe_mlp/{profiling.MOE_EXPERTS}/"
+        f"{profiling.MOE_GROUPED}"}
+
+
 def test_the_reference_blocked_is_the_reference_unblocked(family):
     reference = load_module("reference", "moe_lm")
     cfg = family.reference_config(TINY)

@@ -8,6 +8,8 @@ activation in float32 and rounds once, so it lies within one bfloat16
 rounding of today's ``silu(ragged_dot) * ragged_dot``, which rounds three
 times, and nearer to the float32 arithmetic than that does."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -144,8 +146,7 @@ def test_each_pass_over_a_column_tile_fetches_its_own_weights(monkeypatch):
     """N in two column tiles: the list is walked once a tile, each pass
     starting with nothing on its way and leaving nothing."""
     monkeypatch.setattr(gm, "WEIGHT_TILE_BYTES", 64 * 128 * 2 * 2)
-    gm.grouped_matmul.clear_cache()
-    gm.grouped_glu.clear_cache()
+    jax.clear_caches()
     rng = np.random.default_rng(2)
     sizes = jnp.asarray([0, 100, 3, 0, 60, 93], jnp.int32)
     rows = jnp.asarray(rng.standard_normal((256, 64)), jnp.bfloat16)
@@ -156,8 +157,7 @@ def test_each_pass_over_a_column_tile_fetches_its_own_weights(monkeypatch):
         got = gm.grouped_glu(rows, w_gate, w_up, sizes, tile=64)
         down = gm.grouped_matmul(rows, w_gate, sizes, tile=64)
     finally:        # (the jitted functions read the constant as they trace)
-        gm.grouped_matmul.clear_cache()
-        gm.grouped_glu.clear_cache()
+        jax.clear_caches()
     with jax.default_matmul_precision("highest"):
         exact = (lambda a, b: jax.nn.silu(a) * b)(*(lax.ragged_dot(
             rows.astype(jnp.float32), w.astype(jnp.float32), sizes)
@@ -167,3 +167,143 @@ def test_each_pass_over_a_column_tile_fetches_its_own_weights(monkeypatch):
     assert float((np.abs(f32(got) - f32(exact)) / scale).max()) <= 2 ** -8
     assert float(np.abs(f32(down) - f32(plain)).max()) <= 2 ** -8 * max(
         float(np.abs(f32(plain)).max()), 1.0)
+
+
+# -- the backward (PR 55) ---------------------------------------------------
+def _layer(grouped_glu, grouped_matmul, cotangent, live):
+    """sum(cotangent * down(glu(rows))) over the live rows, through the given
+    two grouped functions."""
+    def loss(rows, w_gate, w_up, w_down):
+        out = grouped_matmul(grouped_glu(rows, w_gate, w_up), w_down)
+        return (out[:live].astype(jnp.float32) * cotangent[:live]).sum()
+    return jax.grad(loss, argnums=(0, 1, 2, 3))
+
+
+def _both_gradients(sizes, dtype, tile, seed=3):
+    """(ours, ``jax.grad`` of the ``ragged_dot`` form at "highest") of rows
+    [C, K], gate and up [G, K, N] and down [G, N, K]."""
+    rows, (w_gate, w_up), sizes = drawn(sizes, dtype, seed)
+    rng = np.random.default_rng(seed + 1)
+    w_down = jnp.asarray(rng.standard_normal((len(sizes), N, K)) * N ** -0.5,
+                         dtype)
+    cotangent = jnp.asarray(rng.standard_normal((C, K)), jnp.float32)
+    live = int(sizes.sum())
+    ours = _layer(
+        lambda x, a, b: gm.grouped_glu(x, a, b, sizes, tile=tile),
+        lambda x, w: gm.grouped_matmul(x, w, sizes, tile=tile),
+        cotangent, live)(rows, w_gate, w_up, w_down)
+    ragged = functools.partial(lax.ragged_dot, group_sizes=sizes,
+                               precision="highest")
+    want = _layer(
+        lambda x, a, b: jax.nn.silu(ragged(x, a)) * ragged(x, b), ragged,
+        cotangent, live)(rows, w_gate, w_up, w_down)
+    return ours, want, live
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(SIZES))
+def test_the_backward_equals_the_gradient_of_the_ragged_dot_form(case, dtype):
+    """The rows' gradient by the forward's work list and the weights'
+    accumulated a group: every leaf against ``jax.grad`` of ``silu(ragged_dot)
+    * ragged_dot`` into ``ragged_dot``; the rows behind the last group get
+    exactly 0, and so does every weight of a group without a row."""
+    ours, want, live = _both_gradients(SIZES[case], dtype, tile=128)
+    # float32: sums in another order; bfloat16: each product rounded once
+    # here, three times there, and the cotangents after them
+    tol = 2e-6 if dtype == jnp.float32 else 2 ** -6
+    for got, exact in zip(ours, want):
+        assert got.shape == exact.shape and got.dtype == dtype
+        size = max(float(np.abs(f32(exact)).max()), 1e-3)
+        assert float(np.abs(f32(got) - f32(exact)).max()) <= tol * size
+    assert float(np.abs(f32(ours[0][live:])).max(initial=0.0)) == 0.0
+    for g, n in enumerate(SIZES[case]):
+        if n == 0:
+            assert all(float(np.abs(f32(d[g])).max()) == 0.0
+                       for d in ours[1:])
+
+
+def test_the_backward_of_a_weight_cut_by_columns(monkeypatch):
+    """K and N in two column tiles each: the rows' gradient cuts the weights'
+    ROWS, the weights' gradient its accumulators, each pass over the list
+    standing alone."""
+    monkeypatch.setattr(gm, "WEIGHT_TILE_BYTES", 64 * 128 * 2 * 2)
+    jax.clear_caches()
+    rng = np.random.default_rng(5)
+    sizes = jnp.asarray([0, 100, 3, 0, 60, 29], jnp.int32)
+    rows = jnp.asarray(rng.standard_normal((256, 256)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((6, 256, 256)) / 16, jnp.bfloat16)
+    cotangent = jnp.asarray(rng.standard_normal((256, 256)), jnp.float32)
+    assert gm._column_tile(256, 256, 2) == 128
+    loss = lambda fn: lambda x, w: (  # noqa: E731
+        fn(x, w)[:192].astype(jnp.float32) * cotangent[:192]).sum()
+    try:
+        ours = jax.grad(loss(lambda x, w: gm.grouped_matmul(
+            x, w, sizes, tile=64)), argnums=(0, 1))(rows, w)
+    finally:        # (the jitted functions read the constant as they trace)
+        jax.clear_caches()
+    want = jax.grad(loss(lambda x, w: lax.ragged_dot(
+        x, w, sizes, precision="highest")), argnums=(0, 1))(rows, w)
+    for got, exact in zip(ours, want):
+        assert float(np.abs(f32(got) - f32(exact)).max()) \
+            <= 2 ** -7 * float(np.abs(f32(exact)).max())
+    assert float(np.abs(f32(ours[0][192:])).max()) == 0.0
+    assert float(np.abs(f32(ours[1][jnp.asarray([0, 3])])).max()) == 0.0
+
+
+def test_the_backward_lists_leave_no_block_unwritten():
+    """The two work lists behind the forward's: every row tile is some
+    visit's first or a dead visit's, every group closes once or gets a dead
+    visit, and what is left repeats the last block written."""
+    tile, n_tiles = 32, C // 32
+    for sizes in SIZES.values():
+        given = jnp.asarray(sizes, jnp.int32)
+        row_tile, _, _, _, live, *_, fresh, dead = (
+            np.asarray(v) for v in gm._whole_visits(given, n_tiles, tile))
+        written = np.concatenate([row_tile[fresh == 1], row_tile[dead == 1]])
+        assert sorted(written) == list(range(n_tiles))
+        assert not (live & dead).any() and not (fresh & ~live).any()
+        after = np.flatnonzero((live | dead) == 0)
+        assert (row_tile[after] == n_tiles - 1).all() or not dead.any()
+        _, group, _, _, live, opens, closes, dead = (
+            np.asarray(v) for v in gm._group_visits(given, n_tiles, tile))
+        assert sorted(np.concatenate([group[closes == 1], group[dead == 1]])
+                      ) == list(range(len(sizes)))
+        assert list(group[opens == 1]) == list(group[closes == 1]) \
+            == [g for g, n in enumerate(sizes) if n]
+        assert list(group[dead == 1]) == [g for g, n in enumerate(sizes)
+                                          if not n]
+        last = np.flatnonzero(live | dead).max()
+        assert (group[last:] == group[last]).all()
+
+
+def test_float32_weights_under_bfloat16_rows_get_their_gradient_unrounded():
+    """The training layer's parameters: float32 leaves multiplied in the
+    rows' bfloat16.  The forward is the product with the cast weights to the
+    bit; the weights' gradient comes back float32 from the float32 sums, so
+    it lies nearer the exact one than the bfloat16 gradient cast up."""
+    rows, (w, _), sizes = drawn(SIZES["a_group_across_several_tiles"],
+                                jnp.bfloat16, seed=6)
+    w32 = w.astype(jnp.float32) * (1 + 2.0 ** -10)  # not bfloat16's own
+    cotangent = jnp.asarray(        # one the result's dtype holds
+        np.random.default_rng(7).standard_normal((C, N)),
+        jnp.bfloat16).astype(jnp.float32)
+    live = int(sizes.sum())
+    loss = lambda x, w: (gm.grouped_matmul(  # noqa: E731
+        x, w, sizes, tile=128)[:live].astype(jnp.float32)
+        * cotangent[:live]).sum()
+    cast = w32.astype(jnp.bfloat16)
+    assert bool((gm.grouped_matmul(rows, w32, sizes, tile=128)[:live]
+                 == gm.grouped_matmul(rows, cast, sizes, tile=128)[:live]
+                 ).all())
+    d_rows, d_w = jax.grad(loss, argnums=(0, 1))(rows, w32)
+    rounded_rows, rounded = jax.grad(loss, argnums=(0, 1))(rows, cast)
+    assert d_w.dtype == jnp.float32 and rounded.dtype == jnp.bfloat16
+    assert bool((d_rows == rounded_rows).all())
+    exact = jax.grad(lambda w: (lax.ragged_dot(
+        rows.astype(jnp.float32), w, sizes, precision="highest")[:live]
+        * cotangent[:live]).sum())(cast.astype(jnp.float32))
+    assert float(np.abs(f32(d_w) - f32(exact)).max()) \
+        <= 1e-5 * float(np.abs(f32(exact)).max())
+    assert float(np.abs(f32(rounded) - f32(exact)).max()) \
+        > 1e-3 * float(np.abs(f32(exact)).max())

@@ -11,6 +11,8 @@ the plain function sum a token's pairs in another order, so they agree to
 rounding (1e-6 of the output's size); a dropped pair, a pair weighted by
 another's gate or a row added to another token reads 1e-2 or more."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -270,7 +272,8 @@ def test_the_backend_counts_the_rows_its_layers_visited():
 def test_the_walk_runs_the_grouped_kernel_and_the_rest_xlas():
     """The walk's jaxpr holds the Pallas grouped matmul under its name (the
     fused gate-and-up and the down product) and no ``ragged_dot``; a decode
-    step's and the training layer's hold ``ragged_dot`` and no kernel."""
+    step's holds ``ragged_dot`` and no kernel; the training layer's 2048
+    pairs are carried through the same two kernels, no loop (PR 55)."""
     from horovod_tpu.utils import profiling
 
     def text(held, x):
@@ -282,9 +285,12 @@ def test_the_walk_runs_the_grouped_kernel_and_the_rest_xlas():
     walk = text((8, 16), x)
     assert walk.count(f"name={profiling.MOE_GROUPED}") == 2
     assert "ragged_dot" not in walk
-    for carried in (text((8, 16), x[:, :16]), text(None, x)):
-        assert carried.count("ragged_dot_general[") == 3
-        assert profiling.MOE_GROUPED not in carried
+    carried = text((8, 16), x[:, :16])
+    assert carried.count("ragged_dot_general[") == 3
+    assert profiling.MOE_GROUPED not in carried
+    trained = text(None, x)
+    assert trained.count(f"name={profiling.MOE_GROUPED}") == 2
+    assert "ragged_dot" not in trained and "while[" not in trained
     # and the walk sows what its tiles held, the carried layers do not
     m, params, _ = layer((8, 16))
     _, sown = m.apply(params, x, mutable=[MOE_STATS])
@@ -316,7 +322,7 @@ def every_expert(routing):
                                   "an_expert_left_empty"])
 @pytest.mark.parametrize("routing", ["top1_with_a_router_state", "top8"])
 def test_every_expert_given_valid_takes_a_bucket_through_the_kernel(
-        routing, hole):
+        routing, hole, monkeypatch):
     """A served prefill whose layer holds every expert (PR 53): given
     ``valid`` over a bucket's rows it carries them all, walks nothing, and
     multiplies them in the walk's kernels: two ``hvd_moe_grouped`` calls and
@@ -329,8 +335,11 @@ def test_every_expert_given_valid_takes_a_bucket_through_the_kernel(
 
     m, params, x, more = every_expert(routing)
     t, k = x.shape[1], m.experts_per_token
-    carried, sown = jax.jit(lambda p, x: m.apply(
-        p, x, mutable=[MOE_STATS], **more))(params, x)
+    with monkeypatch.context() as patch:    # the ragged_dot layer: these
+        # 512 pairs on a decode step's side of the line
+        patch.setattr(moe, "GROUPED_ROW_TILE", 1024)
+        carried, sown = jax.jit(lambda p, x: m.apply(
+            p, x, mutable=[MOE_STATS], **more))(params, x)
     assert "tile_rows" not in sown[MOE_STATS]
     if hole == "half_the_bucket_padding":
         valid = jnp.arange(t)[None, :] < t // 2
@@ -364,33 +373,65 @@ def test_every_expert_given_valid_takes_a_bucket_through_the_kernel(
 @pytest.mark.parametrize("call", ["training", "a_decode_step"])
 @pytest.mark.parametrize("routing", ["top1_with_a_router_state", "top8"])
 def test_every_expert_in_training_and_in_a_decode_step_keeps_ragged_dot(
-        routing, call):
-    """The same layer with no ``valid`` (the training layer, at any size),
-    and given ``valid`` over a decode step's rows: three ``ragged_dot`` and
-    no kernel, no ``tile_rows``; the training form differentiates, and
-    the serving form over a bucket's rows refuses to by name."""
+        routing, call, monkeypatch):
+    """The same layer given ``valid`` over a decode step's rows keeps three
+    ``ragged_dot`` and no kernel, and so does the training layer (no
+    ``valid``) under 512 pairs.  At 512 pairs and more the training layer's
+    products are the kernels too (PR 55): two ``hvd_moe_grouped`` calls
+    forward (gate and up fused, down) and four more backward, ``tile_rows``
+    sown, every gradient equal to the ``ragged_dot`` layer's within
+    bfloat16's rounding; and the serving form over a bucket's rows, which
+    refused a gradient by name, differentiates alike."""
     from horovod_tpu.utils import profiling
 
     m, params, x, more = every_expert(routing)
+    first = lambda out: out[0] if more else out  # noqa: E731
     if call == "a_decode_step":         # 24 slots, a position each
         x = x[0, :24, None]
         more = {n: v[0, :24, None] for n, v in more.items()}
         more["valid"] = jnp.arange(24)[:, None] % 3 > 0
-    apply = lambda p: m.apply(p, x, mutable=[MOE_STATS], **more)  # noqa: E731
+    apply = lambda p, x=x, more=more: m.apply(  # noqa: E731
+        p, x, mutable=[MOE_STATS], **more)
+    grouped = f"name={profiling.MOE_GROUPED}"
+    if call == "a_decode_step":
+        text = str(jax.make_jaxpr(apply)(params))
+        assert text.count("ragged_dot_general[") == 3
+        assert profiling.MOE_GROUPED not in text and "while[" not in text
+        assert "tile_rows" not in jax.eval_shape(apply, params)[1][MOE_STATS]
+        return
+    # half the tokens, 256 pairs: XLA's kernels as before
+    few = {n: v[:, :x.shape[1] // 2] for n, v in more.items()}
+    text = str(jax.make_jaxpr(lambda p: apply(
+        p, x[:, :x.shape[1] // 2], few))(params))
+    assert text.count("ragged_dot_general[") == 3 and grouped not in text
     text = str(jax.make_jaxpr(apply)(params))
-    assert text.count("ragged_dot_general[") == 3
-    assert profiling.MOE_GROUPED not in text and "while[" not in text
-    assert "tile_rows" not in jax.eval_shape(apply, params)[1][MOE_STATS]
-    if call == "training":
-        first = lambda out: out[0] if more else out  # noqa: E731
-        grads = jax.grad(lambda p: first(m.apply(p, x, **more)).astype(
-            jnp.float32).sum())(params)
-        assert float(jnp.abs(grads["params"]["gate"]).max()) > 0
-        with pytest.raises(NotImplementedError,
-                           match="valid.*hvd_moe_grouped.*no backward"):
-            jax.grad(lambda p: first(m.apply(
-                p, x, valid=jnp.ones(x.shape[:2], bool), **more)).astype(
-                    jnp.float32).sum())(params)
+    assert text.count(grouped) == 2
+    assert "ragged_dot" not in text and "while[" not in text
+    sown = jax.jit(apply)(params)[1][MOE_STATS]
+    pairs, tile = np.asarray(sown["expert_pairs"][0]), moe.WALK_ROW_TILE
+    assert pairs.sum() == 512 and int(sown["rows_visited"][0]) == 512
+    assert 512 <= int(sown["tile_rows"][0]) <= 512 + len(pairs) * tile
+    assert int(sown["tile_rows"][0]) % tile == 0
+
+    cotangent = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+    loss = lambda p, **given: (first(m.apply(  # noqa: E731
+        p, x, **more, **given)).astype(jnp.float32) * cotangent).sum()
+    text = str(jax.make_jaxpr(jax.grad(loss))(params))
+    assert text.count(grouped) == 6 and "ragged_dot" not in text
+    ours = jax.jit(jax.grad(loss))(params)
+    served = jax.jit(jax.grad(functools.partial(
+        loss, valid=jnp.ones(x.shape[:2], bool))))(params)
+    # the ragged_dot layer: the line between a step's rows and a bucket's
+    # moved past these 512
+    monkeypatch.setattr(moe, "GROUPED_ROW_TILE", 1024)
+    assert "ragged_dot" in str(jax.make_jaxpr(jax.grad(loss))(params))
+    want = jax.grad(loss)(params)
+    for name, exact in want["params"].items():
+        size = float(jnp.abs(exact).max())
+        assert size > 0
+        for got in (ours, served):
+            assert float(jnp.abs(got["params"][name] - exact).max()) \
+                < 2 ** -5 * size, name
 
 
 @pytest.mark.parametrize("t, c, d, dtype, live_share", [

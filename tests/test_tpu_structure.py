@@ -606,6 +606,44 @@ def test_every_expert_held_compiles_a_buckets_products_as_the_kernel(
     assert profiling.TOKEN_SUM not in text
 
 
+def test_the_training_layer_compiles_its_products_and_their_backward_as_the_kernel(
+        one_chip_mesh, monkeypatch):
+    """OLMoE's expert layer as ``olmoe-s4096`` trains it (64 experts of 2048
+    x 1024, top-8, 4 x 4096 tokens: 131072 pairs, 2048 an even share, so
+    256-row tiles), forward and backward compiled for the chip (PR 55): six
+    ``hvd_moe_grouped`` calls (gate and up fused: the forward, the sum of
+    their rows' gradients, their two weights' gradients; down's three) and
+    no ``ragged-dot``; the scope table files each as that kernel under the
+    layer's path inside ``hvd_moe_experts``, two forward and four backward."""
+    from horovod_tpu.models import moe
+    from horovod_tpu.utils import profiling
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    d, f, e, k, b, s = 2048, 1024, 64, 8, 4, 4096
+    assert moe.grouped_row_tile(b * s * k, e) == moe.WIDE_ROW_TILE == 256
+    m = moe.MoEMLP(embed_dim=d, mlp_dim=f, axis_name=None, dtype=jnp.bfloat16,
+                   num_experts=e, experts_per_token=k)
+    shaped = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree.map(shaped, jax.eval_shape(lambda: m.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, d), jnp.bfloat16))))
+    x = jax.ShapeDtypeStruct((b, s, d), jnp.bfloat16, sharding=one_chip)
+    loss = lambda p, x: m.apply(p, x).astype(jnp.float32).sum()  # noqa: E731
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile()
+    text = compiled.as_text()
+    kernels = _kernels_named(text, profiling.MOE_GROUPED)
+    assert len(kernels) == 6 and "ragged-dot" not in text
+    assert all(f"/{profiling.MOE_EXPERTS}/" in name for name in kernels)
+    ours = [scope for scope in profiling.scope_table(compiled).values()
+            if scope.kernel == profiling.MOE_GROUPED]
+    assert sorted(scope.phase for scope in ours) \
+        == ["backward"] * 4 + ["forward"] * 2
+    assert {scope.module for scope in ours} == {
+        f"MoEMLP/{profiling.MOE_EXPERTS}/{profiling.MOE_GROUPED}"}
+
+
 # The four serving cells cut to two layers at their own widths (for A.X-K1
 # the dense layer and one sparse one; for command-a-plus a sliding and a full
 # layer), two slots: (cell, what the cut replaces, {bucket: (the ``while``
