@@ -25,9 +25,10 @@ dense GLU MLP, in one of two layouts:
   carries :data:`GROUPED_ROW_TILE` pairs and more, the three products are
   Pallas kernels over a work list of row tiles (ops/grouped_matmul.py,
   ``hvd_moe_grouped``: :func:`grouped_row_tile` rows a tile), forward and,
-  where the layer is differentiated, backward (PR 55).  Fewer pairs, a
-  decode step's slots or a test's few tokens, keep XLA:TPU's own kernels
-  for ``lax.ragged_dot``.
+  where the layer is differentiated, backward (PR 55); so does a DECODE
+  program that carries that many (a block-diffusion model's pass: slots x
+  block positions, PR 56).  Fewer pairs, a one-token decode step's slots or
+  a test's few tokens, keep XLA:TPU's own kernels for ``lax.ragged_dot``.
 * **one expert per device** (``num_experts`` = 0; ``axis_name`` a bound mesh
   axis, ``TransformerConfig.moe_axis``): a router picks one expert per token
   (switch routing), tokens travel to the device holding their expert over
@@ -132,7 +133,15 @@ _permute.defvjp(lambda x, perm, inverse: (x[perm], inverse),
 # of a decode step, what a walked block is a whole number of, and the line
 # between a step's rows (slots x k: 24 to 128 in the served cells) and a
 # bucket's or a training step's (1024 pairs and more): a layer that carries
-# this many pairs multiplies them in hvd_moe_grouped's smaller tiles
+# this many pairs multiplies them in hvd_moe_grouped's smaller tiles.  The
+# rule is over the static shapes alone (t * k pairs, whole 128-row tiles) and
+# a decode program may cross it: a block-diffusion model's pass carries
+# slots x block x k pairs, 1536 at 48 slots of 4 positions and top-8 of 128
+# experts, 12 rows an expert, and there the kernel took 10.3 ms of a pass's
+# six layers where lax.ragged_dot's took 26.4, every slot live or half of
+# them (v5e, at sdar30b-chat4k-open's size; PERF.md section 6, PR 56): few
+# rows an expert are no reason to leave the work list, which visits a (row
+# tile, expert) pair that meet and fetches an expert's weights once.
 GROUPED_ROW_TILE = 512
 
 

@@ -55,9 +55,11 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     # RMSNorm's epsilon, every norm of the model.
     norm_eps: float = 1e-6
-    # RMSNorm (with a scale) on the whole q and k projections, before the
-    # split into heads and the rotary embedding (OLMoE's q_norm / k_norm).
-    qk_norm: bool = False
+    # RMSNorm (with a scale) on q and k before the rotary embedding.  True:
+    # over the whole projection, all heads as one, a scale of num_heads *
+    # head_dim (OLMoE's q_norm / k_norm).  "head": over each head's head_dim
+    # channels, one scale of head_dim shared by the heads (Qwen3's).
+    qk_norm: bool | str = False
     # Sparse feed-forward with every expert on each device (models/moe.py):
     # num_experts GLU experts of width mlp_dim replace the dense MLP (0 = the
     # dense MLP), a token visits its experts_per_token most probable ones,
@@ -243,6 +245,14 @@ class TransformerConfig:
     # each sublayer's output f, four learned vectors a sublayer (scales from
     # 1, biases from 0).  Sequential blocks.
     residual_scaling: bool = False
+    # A block-causal mask ("attention" layers): position i sees position j
+    # iff j < (i // attention_block + 1) * attention_block, every earlier
+    # block and the whole of its own (a model that generates by diffusion
+    # over blocks; docs/inference.md).  None: the causal mask.
+    # mask_token_id is the id such a model reads at a position not yet made
+    # final; a serving backend never samples it.
+    attention_block: int | None = None
+    mask_token_id: int | None = None
 
     @classmethod
     def from_dict(cls, fields: dict) -> "TransformerConfig":
@@ -512,16 +522,26 @@ def rope(x, positions, theta: float, interleaved: bool = False,
 
 def dense_causal_attention(q, k, v, causal: bool = True,
                            scale: float | None = None,
-                           window: int | None = None):
+                           window: int | None = None,
+                           block: int | None = None):
     """Reference attention: one softmax(QKᵀ)V, causal-masked. [B, S, H, D];
     k and v may have fewer heads (grouped-query).  With ``window`` (causal
-    only) query i sees keys i - window < j <= i."""
+    only) query i sees keys i - window < j <= i.  With ``block`` (causal
+    only, no window) the mask is block-causal: query i sees every key of
+    its own block of ``block`` positions and of the blocks before it."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     k, v = repeat_kv_heads(k, q.shape[2]), repeat_kv_heads(v, q.shape[2])
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if window is not None and not causal:
         raise ValueError("a sliding window is a causal band: causal=True")
-    if causal:
+    if block is not None and (window is not None or not causal):
+        raise ValueError("a block-causal mask is causal and has no window")
+    if block is not None:
+        s_q, s_k = logits.shape[-2], logits.shape[-1]
+        q_pos = jnp.arange(s_q)[:, None] + (s_k - s_q)
+        mask = jnp.arange(s_k)[None, :] < (q_pos // block + 1) * block
+        logits = jnp.where(mask, logits, -1e30)
+    elif causal:
         s_q, s_k = logits.shape[-2], logits.shape[-1]
         mask = jnp.tril(jnp.ones((s_q, s_k), dtype=bool), k=s_k - s_q)
         if window is not None:
@@ -630,6 +650,14 @@ def init_kv_pages(cfg: TransformerConfig, num_pages: int, page_size: int):
     slot is a row of page ids (its page table) and a page holding a
     shared prompt-prefix chunk can appear in many slots' rows at once.
     Page 0 is the scratch page inactive slots point at."""
+    if cfg.attention_block:
+        raise NotImplementedError(
+            "a paged pool for a model of a block-causal mask "
+            "(attention_block; init_kv_pages, PagedTransformerBackend, the "
+            "prefix cache) is not built: a block is overwritten where it "
+            "lies until its commit, so a shared page would need the block's "
+            "boundary and its commit; such a model serves from "
+            "init_kv_cache's pool")
     if "cca" in cfg.layer_kinds:
         raise NotImplementedError(
             "a paged pool beside a cca layer's tail (init_kv_pages, "
@@ -683,7 +711,8 @@ def write_kv_block(pool, block, layer: int, lengths):
 
 def cached_decode_attention(q, k_cache, v_cache, lengths,
                             scale: float | None = None,
-                            window: int | None = None):
+                            window: int | None = None,
+                            block: int | None = None):
     """Block attention over a per-slot KV cache.
 
     ``q``: [B, S_q, H, D] — the block of positions being decoded per
@@ -696,10 +725,16 @@ def cached_decode_attention(q, k_cache, v_cache, lengths,
     :func:`dense_causal_attention`, so an incrementally decoded position
     matches the full forward pass.  The caches may hold fewer heads than
     ``q`` (grouped-query).  With ``window`` a row at position p sees cache
-    positions p - window < j <= p alone."""
+    positions p - window < j <= p alone.  With ``block`` a row sees the
+    cache up to the end of its own block of ``block`` positions (the
+    block-causal mask: the rows of one block see each other whole)."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
     s, s_q = k_cache.shape[1], q.shape[1]
     qpos = lengths[:, None] + jnp.arange(s_q)[None, :]         # [B, S_q]
+    if block is not None:
+        if window is not None:
+            raise ValueError("a block-causal mask has no window")
+        qpos = (qpos // block + 1) * block - 1      # its block's last position
     mask = (jnp.arange(s)[None, None, :]
             <= qpos[:, :, None])[:, None, :, :]                # [B,1,S_q,S]
     if window is not None:
@@ -751,7 +786,11 @@ class Attention(nn.Module):
             param_dtype=cfg.param_dtype, name=name))
             for name, heads in (("q", cfg.num_heads), ("k", cfg.kv_heads),
                                 ("v", cfg.kv_heads))}
-        # over the whole projection, all heads as one
+        if cfg.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm is False, True (the whole projection) "
+                             f"or \"head\"; got {cfg.qk_norm!r}")
+        # True: over the whole projection, all heads as one; "head": over
+        # each head's own channels, one scale for all heads
         qk_norm = {name: made(RMSNorm(
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             epsilon=cfg.norm_eps, name=f"{name}_norm"))
@@ -760,7 +799,9 @@ class Attention(nn.Module):
         def heads(x, positions):
             def rotated(name):
                 y = proj[name](x)
-                if cfg.qk_norm:
+                if cfg.qk_norm == "head":
+                    y = qk_norm[name](y)
+                elif cfg.qk_norm:
                     y = qk_norm[name](
                         y.reshape(*y.shape[:-2], -1)).reshape(y.shape)
                 if not rotary:
@@ -777,6 +818,12 @@ class Attention(nn.Module):
                 else {"scale": cfg.attention_scale})
         if window is not None:
             told["window"] = window
+        if cfg.attention_block:
+            if self.layer_type != "attention":
+                raise NotImplementedError(
+                    f"attention_block beside a {self.layer_type} layer: the "
+                    f"block-causal mask is built for \"attention\" layers")
+            told["block"] = int(cfg.attention_block)
         o_proj = made(nn.DenseGeneral(
             cfg.embed_dim, axis=(-2, -1), use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="o"))
@@ -818,6 +865,9 @@ def _prompt_end(cfg: TransformerConfig, lengths) -> dict:
     if lengths is None or cfg.attention_fn is None:
         return {}
     end = jnp.max(lengths)      # one bound a call: the longest row's
+    if cfg.attention_block:
+        # a row sees its block whole: the keys count to the block's end
+        end = -(-end // cfg.attention_block) * cfg.attention_block
     return {"q_len": end, "k_len": end}
 
 
@@ -1671,6 +1721,11 @@ class Transformer(nn.Module):
         told = {} if valid is None else {"valid": valid}
         decode = kv_cache is not None
         kinds = cfg.layer_kinds
+        if cfg.attention_block and set(kinds) != {"attention"}:
+            raise NotImplementedError(
+                f"attention_block beside {sorted(set(kinds) - {'attention'})}"
+                f" layers: the block-causal mask is built for a model whose "
+                f"every layer is \"attention\"")
         if (decode or return_kv) and set(kinds) - set(CACHED_MIXERS):
             raise NotImplementedError(
                 f"decode and serving through a recurrent layer are not "

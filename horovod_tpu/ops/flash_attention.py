@@ -107,7 +107,8 @@ def _window_bounds(q_min, q_max, ks_min, sub_k, nsub, window):
 def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
                   m_ref, l_ref, *qs_ref, block_q: int, block_k: int,
                   sub_k: int, num_k_blocks: int, causal: bool, scale: float,
-                  window: int | None = None, bounded: bool = False):
+                  window: int | None = None, bounded: bool = False,
+                  block: int | None = None):
     """One (batch·head, q-block, K-super-tile) program: online softmax.
 
     Two-level streaming: the grid's K axis moves (block_k, D) SUPER tiles
@@ -142,6 +143,15 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
     see the sub-tiles the unbounded kernel gives them, in the same order,
     less only those whose every probability was exactly 0: the same numbers
     to the bit.  Without ``bounded`` the kernel's text is what it was.
+
+    ``block`` (a power of two that divides the sub-tile; the q offset a
+    multiple of it) makes the in-tile mask block-causal: a row sees the keys
+    up to the end of its own block of ``block`` positions, ``k_pos <= q_pos
+    | (block - 1)``.  Which (q tile, K sub-tile) pairs run, and which of
+    them run mask-free, is the causal sweep's: a block never straddles a
+    tile, so a tile wholly below the diagonal is seen whole by both masks
+    and one past it by neither.  Without ``block`` the kernel's text is what
+    it was.
 
     The sub-tile loop is SPLIT: an interior prefix (entirely below the
     causal diagonal and inside the valid K range) runs a mask-free body —
@@ -211,7 +221,9 @@ def _flash_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref,
             k_pos = (ks_min + si * sub_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, sub_k), 1))
             mask = k_pos < meta_ref[2]                    # padding mask
-            if causal:
+            if block is not None:
+                mask = jnp.logical_and(mask, (q_pos | (block - 1)) >= k_pos)
+            elif causal:
                 mask = jnp.logical_and(mask, q_pos >= k_pos)
             if window is not None:
                 mask = jnp.logical_and(mask, q_pos - k_pos < window)
@@ -570,7 +582,7 @@ def _kv_tile_run(meta_ref, qi, ki, block_q, block_k, num_k_blocks, causal,
 
 def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
                 interpret, sub, out_dtype, scale=None, window=None,
-                q_len=None, k_len=None):
+                q_len=None, k_len=None, block=None):
     """The forward kernel on [B, S, H, D] inputs, everything it read and
     wrote left in the kernels' layout, padded to whole blocks:
     ``(qb, kb, vb, ob, lse_b)`` with ``ob`` [B·H, S_q_pad, D_v] in
@@ -588,6 +600,16 @@ def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
     d, d_v = q.shape[-1], v.shape[-1]
     s_k = k.shape[1]
     block_k, sub_k = _sub_fit(block_k, sub)
+    told = {}
+    if block is not None:
+        if not causal or window is not None or block & (block - 1) \
+                or sub_k % block or block_q % block:
+            raise ValueError(
+                f"flash_attention(block={block}): the block-causal mask is "
+                f"causal, has no window, and its block is a power of two "
+                f"that divides the q tile ({block_q}) and the K sub-tile "
+                f"({sub_k}); dense_causal_attention(block=) takes any")
+        told["block"] = block
     qb = _pad_to(_to_bh(q), 1, block_q)
     kb = _pad_to(_to_bh(k), 1, block_k)
     vb = _pad_to(_to_bh(v), 1, block_k)
@@ -603,7 +625,7 @@ def _forward_bh(q, k, v, causal, q_offset, k_offset, block_q, block_k,
         _flash_kernel, block_q=block_q, block_k=block_k, sub_k=sub_k,
         num_k_blocks=num_k_blocks, causal=causal,
         scale=d ** -0.5 if scale is None else scale, window=window,
-        bounded=bounded)
+        bounded=bounded, **told)
 
     # The index maps see the meta only in the bounded call, where it is
     # prefetched (``*meta`` is then its one ref, else nothing).
@@ -965,30 +987,37 @@ class _Lengths:
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(3, 6, 7, 8, 9, 10, 11, 12))
 def _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
-           interpret, scale, window, q_len=None, k_len=None):
+           interpret, scale, window, block, q_len=None, k_len=None):
     """One device, one call over the whole sequence: nothing sums its
     results again, so the kernels write the compute dtype themselves."""
     return _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k,
-                      sub, interpret, scale, window, q_len, k_len)[0]
+                      sub, interpret, scale, window, block, q_len, k_len)[0]
 
 
 def _flash_fwd(q, k, v, causal, q_offset, k_offset, block_q, block_k, sub,
-               interpret, scale, window, q_len=None, k_len=None):
+               interpret, scale, window, block, q_len=None, k_len=None):
     # The residuals stay as the forward call read and wrote them, in the
     # layout the backward kernel reads: nothing is laid out twice.
     qb, kb, vb, ob, lse_b = _forward_bh(
         q, k, v, causal, q_offset, k_offset, block_q, block_k, interpret,
-        sub, q.dtype, scale, window, q_len, k_len)
+        sub, q.dtype, scale, window, q_len, k_len,
+        **({} if block is None else {"block": block}))
     return _from_bh(ob, q.shape[0], q.shape[1]), (
         qb, kb, vb, ob, lse_b, q_offset, k_offset,
         _Lengths(q.shape[1], k.shape[1],
                  q_len is not None or k_len is not None))
 
 
-def _flash_bwd(causal, block_q, block_k, sub, interpret, scale, window, res,
-               g):
+def _flash_bwd(causal, block_q, block_k, sub, interpret, scale, window,
+               block, res, g):
+    if block is not None:
+        raise NotImplementedError(
+            f"flash_attention(block={block}) has no backward: the "
+            f"block-causal mask is in the forward kernel alone (a serving "
+            f"prefill); the backward kernel knows the causal mask only. "
+            f"Differentiate dense_causal_attention(block=...) instead")
     if window is not None:
         raise NotImplementedError(
             f"flash_attention(window={window}) has no backward: the "
@@ -1045,7 +1074,7 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
                     block_q: int = 1024, block_k: int | None = None,
                     sub: int = 1024, interpret: bool | None = None,
                     scale: float | None = None, window: int | None = None,
-                    q_len=None, k_len=None):
+                    q_len=None, k_len=None, block: int | None = None):
     """Fused attention over [B, S, H, D] tensors.
 
     ``q_len`` and ``k_len`` (traced scalars may be given) count the leading
@@ -1065,6 +1094,13 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
     wholly before the band as it skips those past the diagonal, and masks
     the two edges.  The backward kernel has no window: differentiating a
     windowed call raises ``NotImplementedError`` (docs/inference.md).
+
+    ``block`` (causal only, no window; a power of two) makes the mask
+    block-causal: query ``i`` sees the keys of its own block of ``block``
+    positions and of every block before it (a model that generates by
+    diffusion over blocks).  The forward kernel runs the causal sweep's
+    tiles and masks the diagonal ones by the block; forward only, as the
+    window is: differentiating such a call raises ``NotImplementedError``.
 
     ``v`` may have another width than ``q`` and ``k`` (latent attention's
     expanded form: keys of 192, values of 128); the output has ``v``'s.  The
@@ -1122,10 +1158,13 @@ def flash_attention(q, k, v, causal: bool = True, q_offset=0, k_offset=0,
         d_v=v.shape[-1])
     if window is not None and not causal:
         raise ValueError("a sliding window is a causal band: causal=True")
+    if block is not None and window is not None:
+        raise ValueError("a block-causal mask has no window")
     k, v = repeat_kv_heads(k, q.shape[2]), repeat_kv_heads(v, q.shape[2])
     return _flash(q, k, v, causal, q_offset, k_offset, block_q, block_k,
                   sub, interpret, None if scale is None else float(scale),
-                  None if window is None else int(window), q_len, k_len)
+                  None if window is None else int(window),
+                  None if block is None else int(block), q_len, k_len)
 
 
 def flash_attention_with_lse(q, k, v, causal: bool = True, q_offset=0,
@@ -1176,10 +1215,11 @@ def make_flash_attention(block_q: int = 1024, block_k: int | None = None,
     """Adapter producing a ``TransformerConfig.attention_fn``.  block_k
     defaults per-call to min(S, 2048) at d<=128 (_default_block_k)."""
     def attn(q, k, v, causal=True, scale=None, window=None, q_len=None,
-             k_len=None):
+             k_len=None, block=None):
         return flash_attention(q, k, v, causal=causal, block_q=block_q,
                                block_k=block_k, sub=sub, scale=scale,
-                               window=window, q_len=q_len, k_len=k_len)
+                               window=window, q_len=q_len, k_len=k_len,
+                               block=block)
     return attn
 
 

@@ -114,6 +114,12 @@ class Request:
     error: str | None = None
     ttft_s: float | None = None
     token_lat_s: list[float] = dataclasses.field(default_factory=list)
+    # A block-diffusion model's (``TransformerBackend.block``): the passes
+    # its slot has been through, and how many of them before its first
+    # tokens were handed over (the masks of its first block under the static
+    # rule: a block is handed over when it is whole).
+    passes: int = 0
+    first_token_passes: int | None = None
     _last_token_t: float = 0.0
     # its hvd_srv_request record, open from submit() to eviction
     _span: Any = dataclasses.field(default=None, repr=False, compare=False)
@@ -151,6 +157,18 @@ class ServingConfig:
     spec_k: int = 0
     # n-gram order the proposer matches on before falling back to 1.
     spec_ngram: int = 2
+    # Generation by diffusion over blocks (docs/inference.md "Serving a
+    # block-diffusion model"), for a backend whose model has a block-causal
+    # mask; the block's length and the mask id are the backend's (``block``,
+    # ``mask_id``: the model's own).  A decode step is then a PASS over
+    # every slot's current block, which returns a token and a confidence a
+    # position; unmask_rule says which masked positions a pass makes final:
+    # "low_confidence_static" the block / denoise_steps most confident
+    # (denoise_steps 0: a position a pass), "low_confidence_dynamic" every
+    # one above confidence_threshold and at least that many.
+    denoise_steps: int = 0
+    unmask_rule: str = "low_confidence_static"
+    confidence_threshold: float = 0.9
 
     @staticmethod
     def from_env(**overrides) -> "ServingConfig":
@@ -187,12 +205,24 @@ class StubBackend:
 
     def __init__(self, num_slots: int, vocab_size: int = 256,
                  step_s: float = 0.0, period: int | None = None,
-                 prefill_s_per_token: float = 0.0):
+                 prefill_s_per_token: float = 0.0, block: int = 0,
+                 mask_id: int | None = None, confidences=None):
+        """``block`` > 0 is the block form (a block-diffusion model's
+        scheduler without a model): :meth:`decode` takes ``[slots, block]``
+        ids and answers a token and a confidence a position.  The token at
+        a position is a function of the position alone (never ``mask_id``);
+        ``confidences(call, tok_block, lengths) -> [slots, block]`` scripts
+        the confidences of the stub's ``call``-th pass (default: falling
+        from the block's first position to its last, so a block fills in
+        order)."""
         self.num_slots = num_slots
         self.vocab_size = vocab_size
         self.step_s = step_s
         self.period = period
         self.prefill_s_per_token = prefill_s_per_token
+        self.block, self.mask_id = block, mask_id
+        self.confidences = confidences
+        self.passes = 0
 
     @staticmethod
     def _next(prev: int, pos: int, vocab: int) -> int:
@@ -226,7 +256,32 @@ class StubBackend:
         logits[first] = 1.0
         return first, logits
 
-    def decode(self, last_tokens: np.ndarray, lengths: np.ndarray):
+    def block_token(self, position: int) -> int:
+        """The block form's token at ``position``: never the mask id."""
+        tok = (int(position) * 7 + 1) % self.vocab_size
+        return tok if tok != self.mask_id else (tok + 1) % self.vocab_size
+
+    def _decode_block(self, tok_block: np.ndarray, lengths: np.ndarray):
+        if self.step_s:
+            time.sleep(self.step_s)
+        b = self.block
+        at = np.asarray(lengths)[:, None] + np.arange(b)[None, :]
+        tokens = np.vectorize(self.block_token, otypes=[np.int32])(at)
+        if self.confidences is None:
+            conf = np.broadcast_to(1.0 / (2.0 + np.arange(b)),
+                                   tokens.shape).astype(np.float32)
+        else:
+            conf = np.asarray(self.confidences(self.passes, tok_block,
+                                               lengths), np.float32)
+        self.passes += 1
+        logits = np.zeros(tokens.shape + (self.vocab_size,), np.float32)
+        np.put_along_axis(logits, tokens[..., None], 1.0, axis=-1)
+        return tokens, logits, conf
+
+    def decode(self, last_tokens: np.ndarray, lengths: np.ndarray,
+               live=None):
+        if self.block:
+            return self._decode_block(last_tokens, lengths)
         if self.step_s:
             time.sleep(self.step_s)
         nxt = np.array([self._next_tok(int(t), int(p))
@@ -284,11 +339,28 @@ class TransformerBackend:
     One jitted prefill per bucket shape (full forward with
     ``return_kv=True``, cache written into the admitted slot with
     ``dynamic_update_slice``) and ONE jitted decode whose shapes are fixed
-    by the slot count — it runs every tick whatever the active set is, so
-    it compiles exactly once and its collective signature never changes.
-    Inactive slots decode garbage at position 0; the engine masks their
-    output and the next prefill overwrites their cache.  Sampling is
-    greedy (argmax) — deterministic, which the bit-exactness test needs.
+    by the slot count (``[slots]`` tokens; for a block-diffusion model,
+    below, by the slot count and the block: ``[slots, block]``) — it runs
+    every tick whatever the active set is, so it compiles exactly once and
+    its collective signature never changes.  Inactive slots decode garbage
+    at position 0; the engine masks their output and the next prefill
+    overwrites their cache.  Sampling is greedy (argmax) on the device —
+    deterministic, which the bit-exactness test needs; a block model's
+    argmax leaves its mask id out and comes with its softmax probability,
+    the confidence.
+
+    A model with a block-causal mask (``TransformerConfig.attention_block``
+    and ``mask_token_id``: generation by diffusion over blocks) is served
+    through the same two programs in another form (:attr:`block`).  Its
+    prefill is told ``length`` = the prompt's WHOLE blocks, caches them and
+    samples nothing (the head is dead code there).  Its decode is a PASS:
+    ``decode(tok_block [slots, block], lengths, live)`` runs every slot's
+    block at positions ``[lengths, lengths + block)`` against the cache, the
+    rows of a block seeing each other whole, writes the block's keys and
+    values there — every pass, simply overwritten until the pass over the
+    final block, the commit, after which the engine moves ``lengths`` on —
+    and returns tokens and confidences ``[slots, block]``.  No verify
+    program, no paged pool, no prefix cache (refused by name).
 
     Which attention a prefill runs is chosen a bucket, from the bucket's
     own shape (:meth:`prefill_attention`): densely (one ``[1, H, S, S]``
@@ -347,6 +419,15 @@ class TransformerBackend:
 
         self._model_cfg = model_cfg
         self._flash_model = None    # built for the first bucket that asks
+        # a block-diffusion model's block length (0: a token a step) and the
+        # id its passes never sample
+        self.block = int(model_cfg.attention_block or 0)
+        self.mask_id = model_cfg.mask_token_id
+        if self.block and self.mask_id is None:
+            raise ValueError(
+                "a model with attention_block generates by diffusion over "
+                "blocks and needs TransformerConfig.mask_token_id, the id a "
+                "position holds until a pass makes it final")
         self.sparse = model_cfg.num_experts > 0
         self.last_expert_pairs = None
         # calls, (token, expert) pairs routed (a prompt's own positions and
@@ -384,8 +465,11 @@ class TransformerBackend:
             made.fields["bytes"] = sum(
                 int(x.nbytes) for x in jax.tree.leaves((self.kk, self.vv)))
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
-        self._decode = jax.jit(self._decode_fn, donate_argnums=(1, 2))
-        self._verify = jax.jit(self._verify_fn, donate_argnums=(1, 2))
+        self._decode = jax.jit(
+            self._block_decode_fn if self.block else self._decode_fn,
+            donate_argnums=(1, 2))
+        if not self.block:      # a block is not verified: nothing is drafted
+            self._verify = jax.jit(self._verify_fn, donate_argnums=(1, 2))
 
     def prefill_attention(self, bucket: int) -> str:
         """The attention a prefill of ``bucket`` positions runs: ``"flash"``
@@ -471,7 +555,7 @@ class TransformerBackend:
             # host on every start where this takes 1.3 (PERF.md section 6,
             # PR 41)
             attn = self._jax.jit(make_flash_attention(), static_argnames=(
-                "causal", "scale", "window"))
+                "causal", "scale", "window", "block"))
             self._flash_model = type(self.model)(dataclasses.replace(
                 self._model_cfg, attention_fn=attn))
         return self._flash_model
@@ -514,7 +598,13 @@ class TransformerBackend:
         # no room for every position's logits either: the head runs on the
         # prompt's last position alone
         chunked = self._model_cfg.feed_forward_chunk is not None
-        if chunked:
+        block_model = bool(self._model_cfg.attention_block)
+        if block_model:
+            # a block model's prefill samples nothing: the head's one row is
+            # asked for so that no [bucket, vocabulary] logits are traced,
+            # and, unread below, is dead code to the compiler
+            told["logits_at"] = jnp.zeros((1,), jnp.int32)
+        elif chunked:
             told["logits_at"] = jnp.reshape(length - 1, (1,))
         if self.prefill_attention(padded.shape[1]) != "own":
             # where the prompt ends: a kernel stops there and works no tile
@@ -541,6 +631,12 @@ class TransformerBackend:
                 pool.ndim - 2)
             kk = jax.lax.dynamic_update_slice(kk, pk, at_slot(kk))
             vv = jax.lax.dynamic_update_slice(vv, pv, at_slot(vv))
+        if block_model:
+            # what _call waits for, and no logits: the whole blocks' rows
+            # are in the pool and the first pass reads them
+            out = (kk, vv, jnp.asarray(length, jnp.int32),
+                   jnp.zeros((0,), jnp.float32))
+            return out if pairs is None else out + (pairs,)
         last = logits[0] if chunked else jax.lax.dynamic_slice(
             logits, (0, length - 1, 0), (1, 1, logits.shape[-1]))[0, 0]
         out = (kk, vv, jnp.argmax(self._next_head(last)).astype(jnp.int32),
@@ -560,6 +656,33 @@ class TransformerBackend:
             lengths=jnp.maximum(lengths - 1, 0), **told)
         out = (kk, vv, jnp.argmax(self._next_head(logits),
                                   axis=-1).astype(jnp.int32), logits)
+        return out if pairs is None else out + (pairs,)
+
+    def _block_decode_fn(self, params, kk, vv, tok_block, lengths, live):
+        """One pass of a block-diffusion model over every slot's block
+        ``tok_block`` [slots, block] (final ids, the mask id elsewhere) at
+        positions ``[lengths, lengths + block)``: the cache call
+        :meth:`_verify_fn` makes, under the block-causal mask, so row j sees
+        the cache below ``lengths`` and the WHOLE block.  The block's keys
+        and values land at those positions every pass and are overwritten
+        by the next one (:meth:`_verify_fn`'s discipline) until the pass
+        over the final block, the commit, whose rows stay.  Returns, a
+        position, the best token with the mask id left out (a position that
+        drew it would never leave its mask) and its softmax probability over
+        the whole vocabulary; the logits stay on the device."""
+        jax, jnp = self._jax, self._jax.numpy
+        told = {"valid": jnp.broadcast_to(live[:, None], tok_block.shape)} \
+            if self.sparse else {}
+        (logits, (kk, vv)), pairs = self._apply(
+            self.model, params, tok_block, kv_cache=(kk, vv),
+            lengths=lengths, **told)
+        logits = self._next_head(logits).astype(jnp.float32)
+        # (a select the two reductions fuse, not a second copy of the logits)
+        kept = jnp.where(jnp.arange(logits.shape[-1]) == self.mask_id,
+                         -jnp.inf, logits)
+        best = jnp.max(kept, axis=-1)
+        out = (kk, vv, jnp.argmax(kept, axis=-1).astype(jnp.int32), logits,
+               jnp.exp(best - jax.nn.logsumexp(logits, axis=-1)))
         return out if pairs is None else out + (pairs,)
 
     def _next_head(self, logits):
@@ -612,7 +735,10 @@ class TransformerBackend:
         # produced, preds[:, j] is bit-identical to plain decode's
         # output at that position.  K/V for rejected rows land in the
         # cache as garbage past the accepted length — masked until the
-        # next step's block (which always spans them) overwrites.
+        # next step's block (which always spans them) overwrites: rows past
+        # ``lengths`` are nobody's until a later call writes them again,
+        # the one discipline a block model's passes also lean on
+        # (_block_decode_fn: a block overwritten until its commit).
         logits, (kk, vv) = self.model.apply(
             params, tok_block, kv_cache=(kk, vv),
             lengths=jnp.maximum(lengths - 1, 0))
@@ -679,7 +805,20 @@ class TransformerBackend:
             self._count_pairs(pairs[0], int(length))
         return int(first), logits
 
-    def decode(self, last_tokens: np.ndarray, lengths: np.ndarray):
+    def decode(self, last_tokens: np.ndarray, lengths: np.ndarray,
+               live: np.ndarray | None = None):
+        """One decode step: ``(tokens [slots], logits)``.  For a block model
+        (:attr:`block`) one pass: ``last_tokens`` is ``[slots, block]``,
+        ``lengths`` the blocks' first positions, ``live`` [slots] bool the
+        slots that hold a request (a block may start at position 0), and
+        the answer ``(tokens [slots, block], logits, confidences [slots,
+        block])``."""
+        if self.block:
+            nxt, logits, conf, *pairs = self._call(
+                self._decode, (last_tokens, lengths, live))
+            if pairs:
+                self._count_pairs(pairs[0], int(live.sum()) * self.block)
+            return nxt, logits, conf
         meanwhile = None
         if self.eva:
             meanwhile = functools.partial(self._count_eva_step, lengths)
@@ -692,6 +831,11 @@ class TransformerBackend:
         return nxt, logits
 
     def verify(self, tok_block: np.ndarray, lengths: np.ndarray):
+        if self.block:
+            raise NotImplementedError(
+                "speculative decoding (verify) for a block-diffusion model "
+                "(attention_block): a pass already makes several positions "
+                "final, and nothing drafts a block")
         return self._call(self._verify, (tok_block, lengths))
 
     def swap_params(self, params) -> None:
@@ -878,9 +1022,32 @@ class ServingEngine:
     allreduce, which both keeps the response cache warm and gives every
     replica the fleet-aggregate counters the autoscaler reads; admissions
     and evictions land as SERVING_ADMIT / SERVING_EVICT instants on its
-    timeline."""
+    timeline.
+
+    Over a backend with a ``block`` (a block-diffusion model: docs/
+    inference.md "Serving a block-diffusion model") a slot carries the
+    state of its current block (``block_tokens``, ``block_masked``) and
+    ``lengths[slot]`` is the block's first position, the positions cached
+    before it.  Admission prefills the prompt's WHOLE blocks and yields no
+    token; the block then holds the prompt's tail and masks.  (ii) is a
+    PASS over every slot's block (:meth:`_block_step`): a slot whose block
+    still has masks takes the pass's tokens at the positions the unmasking
+    rule picks (a *denoising* pass: 0 to ``block`` tokens become final,
+    out of order, and never return to masks); a slot whose block is final
+    takes nothing, its block's keys and values now lie in the cache, and it
+    moves on ``block`` positions to a block of masks (the *commit* pass).
+    A block is handed to the request when it is WHOLE, its tokens in order
+    at one stamp (a final token waits for its block, as the cache does:
+    the order in which a block's positions become final is the
+    confidences', and on weights that were not trained it is noise), so
+    ``ttft_s``, the stamp of the first token handed over, is the first
+    block's last denoising pass, ``Request.first_token_passes`` after the
+    prefill.  A request whose last asked token is handed over is evicted
+    without committing that block (the rest of the block is dropped), and
+    ``max_seq_len`` is guarded a block ahead."""
 
     TICK_NAME = "serving.tick"
+    UNMASK_RULES = ("low_confidence_static", "low_confidence_dynamic")
 
     def __init__(self, backend, config: ServingConfig | None = None,
                  collective=None, clock: Callable[[], float] = time.monotonic,
@@ -920,10 +1087,26 @@ class ServingEngine:
             self.prefix = PrefixCache(cfg.num_slots,
                                       cfg.max_seq_len // cfg.page_size,
                                       cfg.prefix_cache_pages, cfg.page_size)
+        self.block = self._block_len()
+        if self.block:
+            # the current block a slot: its ids (the mask id where masked),
+            # which are masked, how many of its leading positions are the
+            # request's already (the prompt's tail, in its first block), and
+            # the passes the slot's request has been through
+            self.block_tokens = np.full((cfg.num_slots, self.block),
+                                        backend.mask_id, np.int32)
+            self.block_masked = np.zeros((cfg.num_slots, self.block), bool)
+            self._tail = np.zeros(cfg.num_slots, np.int32)
+            self._passes = np.zeros(cfg.num_slots, np.int32)
         self.counters = dict.fromkeys(
             ("admitted", "evicted", "completed", "rejected", "retried",
              "steps", "tokens", "prompt_tokens", "prefix_hits",
-             "prefix_hit_tokens", "spec_drafted", "spec_accepted"), 0)
+             "prefix_hit_tokens", "spec_drafted", "spec_accepted",
+             # a block model's: live slot-passes that made tokens final,
+             # live slot-passes that committed a block, the tokens made final
+             "denoise_passes", "commit_passes", "tokens_final"), 0)
+        if self.block:      # tokens_final over both kinds of slot-pass
+            self.counters["tokens_per_pass"] = 0.0
         # the last SPAN_CAPACITY first-token and token latencies: what
         # stats() takes its percentiles over
         self._ttft_s: deque[float] = deque(maxlen=profiling.SPAN_CAPACITY)
@@ -941,6 +1124,37 @@ class ServingEngine:
         self._undelivered: list[Request] = []
         _ACTIVE = self
 
+    def _block_len(self) -> int:
+        """The backend's block length (0: a token a step) once the rest of
+        the configuration is known to fit it; what a block model cannot be
+        served with is refused here, by name."""
+        cfg = self.config
+        block = int(getattr(self.backend, "block", 0) or 0)
+        if not block:
+            return 0
+        if cfg.spec_k:
+            raise NotImplementedError(
+                "speculative decoding (spec_k) beside a block-diffusion "
+                "model (a backend with a block): a pass already makes "
+                "several positions final, and nothing drafts a block")
+        if self.prefix is not None:
+            raise NotImplementedError(
+                "the prefix cache (prefix_cache_pages, a paged backend) "
+                "beside a block-diffusion model (a backend with a block): a "
+                "block is overwritten where it lies until its commit, and "
+                "no page is shared before that")
+        steps = cfg.denoise_steps or block
+        if self.backend.mask_id is None \
+                or cfg.unmask_rule not in self.UNMASK_RULES \
+                or not 1 <= steps <= block or block % steps:
+            raise ValueError(
+                f"a block-diffusion model needs the backend's mask_id, an "
+                f"unmask_rule of {self.UNMASK_RULES} and denoise_steps "
+                f"dividing the block; got mask_id={self.backend.mask_id}, "
+                f"unmask_rule={cfg.unmask_rule!r}, denoise_steps="
+                f"{cfg.denoise_steps}, block={block}")
+        return block
+
     # -- request intake ---------------------------------------------------
 
     def submit(self, prompt, max_new_tokens: int, rid: int | None = None,
@@ -952,8 +1166,12 @@ class ServingEngine:
                                         prompt=len(req.prompt))
         if retry:
             self.counters["retried"] += 1
+        # (a block model's first block, the one the prompt ends in, must
+        # fit whole: the guard of max_seq_len is a block ahead)
         if len(req.prompt) > max(self.config.buckets) or \
-                len(req.prompt) >= self.config.max_seq_len:
+                len(req.prompt) >= self.config.max_seq_len or (
+                    self.block and len(req.prompt) // self.block * self.block
+                    + self.block > self.config.max_seq_len):
             req.state, req.finish_reason = "DONE", "rejected"
             req.error = (
                 f"prompt of {len(req.prompt)} tokens exceeds the largest "
@@ -982,14 +1200,17 @@ class ServingEngine:
     # -- the tick ---------------------------------------------------------
 
     def step(self) -> list[Request]:
-        with profiling.span(profiling.SRV_STEP, queued=len(self.queue)):
-            return self._step()
+        with profiling.span(profiling.SRV_STEP,
+                            queued=len(self.queue)) as step:
+            return self._step(step)
 
-    def _step(self) -> list[Request]:
+    def _step(self, step) -> list[Request]:
         done: list[Request] = []
         self._admit(done)
         if any(r is not None for r in self.slots):
-            if self._spec_ready():
+            if self.block:
+                self._block_step(done, step)
+            elif self._spec_ready():
                 self._spec_step(done)
             else:
                 with profiling.span(profiling.SRV_DECODE,
@@ -1045,6 +1266,11 @@ class ServingEngine:
                 if getattr(self.backend, "paged", False):
                     self.backend.attach_slot(s, adm.page_row)
             suffix = req.prompt[hit:]
+            if self.block:
+                # the prompt's whole blocks are prefilled; its tail opens
+                # the first block that is denoised
+                suffix = req.prompt[:len(req.prompt) // self.block
+                                    * self.block]
             bucket = self._bucket(len(suffix))
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :len(suffix)] = suffix
@@ -1052,6 +1278,8 @@ class ServingEngine:
             # says which form this call runs
             chosen = getattr(self.backend, "prefill_attention", None)
             attn = {"attn": chosen(bucket)} if chosen else {}
+            if self.block:
+                attn["cached"] = len(suffix)
             # ... and in how many chunks, where that is more than one
             pieces = getattr(self.backend, "prefill_chunks", None)
             chunks = pieces(bucket) if pieces else 1
@@ -1083,10 +1311,11 @@ class ServingEngine:
                 logits = self._kept(logits)
             now = self.clock()
             req.state, req.slot = "ACTIVE", s
-            req.ttft_s = now - req.submitted_t
-            self._ttft_s.append(req.ttft_s)
+            if not self.block:      # (a block model's first token: a pass's)
+                req.ttft_s = now - req.submitted_t
+                self._ttft_s.append(req.ttft_s)
             self.slots[s] = req
-            self.lengths[s] = len(req.prompt)
+            self.lengths[s] = len(suffix) if self.block else len(req.prompt)
             self.counters["admitted"] += 1
             self.counters["prompt_tokens"] += len(req.prompt)
             if hit:
@@ -1100,6 +1329,10 @@ class ServingEngine:
                     self.collective.timeline_instant(
                         "SERVING_PREFIX_HIT", f"req={req.rid} slot={s} "
                         f"tokens={hit} suffix={len(suffix)}")
+            if self.block:
+                self._passes[s] = 0
+                self._open_block(s, req.prompt[len(suffix):])
+                continue
             self._take_token(req, s, first, logits, now)
             if req.state == "DONE":  # max_new_tokens == 1
                 self._evict(req, s, done)
@@ -1120,23 +1353,121 @@ class ServingEngine:
                     now: float, at=()) -> None:
         """``logits``: what :meth:`_kept` gave of the call; ``at``: the
         token's row of it."""
+        self.last_tokens[slot] = token
+        self.lengths[slot] += 1
+        self._hand_over(req, token, logits, now, at)
+        if req.state != "DONE" and len(req.prompt) + len(req.tokens) \
+                >= self.config.max_seq_len:
+            req.state, req.finish_reason = "DONE", "max_seq_len"
+
+    def _hand_over(self, req: Request, token: int, logits, now: float,
+                   at=()) -> None:
+        """``token`` is the request's next, stamped ``now``: onto its
+        record, and DONE where it is its last (an EOS, the last asked)."""
         req.tokens.append(token)
         if logits is not None:
             req.logits.append(np.array(logits[at]))
+        if req.ttft_s is None:      # a block model's first: a pass made it
+            req.ttft_s = now - req.submitted_t
+            req.first_token_passes = int(self._passes[req.slot])
+            self._ttft_s.append(req.ttft_s)
         if req._last_token_t:
             req.token_lat_s.append(now - req._last_token_t)
             self._token_s.append(req.token_lat_s[-1])
         req._last_token_t = now
-        self.last_tokens[slot] = token
-        self.lengths[slot] += 1
         self.counters["tokens"] += 1
-        total = len(req.prompt) + len(req.tokens)
         if self.config.eos_id is not None and token == self.config.eos_id:
             req.state, req.finish_reason = "DONE", "eos"
         elif len(req.tokens) >= req.max_new_tokens:
             req.state, req.finish_reason = "DONE", "max_new_tokens"
-        elif total >= self.config.max_seq_len:
-            req.state, req.finish_reason = "DONE", "max_seq_len"
+
+    # -- a block-diffusion model's passes -----------------------------------
+
+    def _open_block(self, slot: int, tail=()) -> None:
+        """The slot's next block: ``tail`` (the prompt's last positions,
+        past its whole blocks) and masks in the rest."""
+        self.block_tokens[slot] = self.backend.mask_id
+        self.block_tokens[slot, :len(tail)] = tail
+        self.block_masked[slot] = np.arange(self.block) >= len(tail)
+        self._tail[slot] = len(tail)
+
+    def _unmasked(self, conf: np.ndarray) -> np.ndarray:
+        """[slots, block] bool: the masked positions a pass with
+        confidences ``conf`` makes final, every slot at once: of each
+        block's masked positions the rule's count of the most confident
+        (ties to the earlier position), at least ``block /
+        denoise_steps`` while that many are masked."""
+        cfg, masked = self.config, self.block_masked
+        seen = np.where(masked, conf, -np.inf)
+        n = np.full(len(seen), self.block // (cfg.denoise_steps or self.block))
+        if cfg.unmask_rule == "low_confidence_dynamic":
+            n = np.maximum(n, (seen > cfg.confidence_threshold).sum(axis=1))
+        order = np.argsort(-seen, axis=1, kind="stable")
+        rank = np.argsort(order, axis=1, kind="stable")
+        return masked & (rank < n[:, None])
+
+    def _block_step(self, done: list[Request], step) -> None:
+        """One pass over every slot's block (the class docstring); ``step``
+        is the ``hvd_srv_step`` span it runs in, which takes what only the
+        pass's results say (``made_final``, ``handed``).  What the rule
+        decides, and the commits, are done for all slots at once, in numpy,
+        and the loop visits the slots whose REQUEST has something to record
+        (a block that became whole, its end): a pass's scheduling is host
+        time in which the chip idles."""
+        live = np.array([r is not None for r in self.slots])
+        masked = self.block_masked          # (an empty slot's: all False)
+        commits = live & ~masked.any(axis=1)
+        with profiling.span(
+                profiling.SRV_DECODE, **self._in_slots(), block=self.block,
+                masked_in=int(masked.sum()), commits=int(commits.sum())):
+            tokens, logits, conf = self.backend.decode(
+                self.block_tokens, self.lengths, live)
+            logits = self._kept(logits)
+        now = self.clock()
+        picked = self._unmasked(np.asarray(conf))
+        np.copyto(self.block_tokens, tokens, where=picked)
+        masked &= ~picked
+        self._passes += live
+        made, handed = int(picked.sum()), 0
+        c = self.counters
+        c["tokens_final"] += made
+        c["commit_passes"] += int(commits.sum())
+        c["denoise_passes"] += int(live.sum() - commits.sum())
+        c["tokens_per_pass"] = c["tokens_final"] / (
+            c["denoise_passes"] + c["commit_passes"])
+        # the blocks this pass made whole: theirs to hand over
+        whole = live & ~commits & ~masked.any(axis=1)
+        # the commits: that pass was over the slot's final block, whose keys
+        # and values are the cache's now, and the slot moves on to a block
+        # of masks, if one more fits (max_seq_len, guarded a block ahead)
+        self.lengths[commits] += self.block
+        self.block_tokens[commits] = self.backend.mask_id
+        masked[commits] = True
+        self._tail[commits] = 0
+        ended = commits & (self.lengths + self.block
+                           > self.config.max_seq_len)
+        for s in np.flatnonzero(whole | ended).tolist():
+            req = self.slots[s]
+            if ended[s]:
+                req.state, req.finish_reason = "DONE", "max_seq_len"
+            else:
+                handed += self._hand_block(req, s, logits, now)
+            if req.state == "DONE":
+                self._evict(req, s, done)
+        step.fields.update(made_final=made, handed=handed)
+
+    def _hand_block(self, req: Request, slot: int, logits, now: float) -> int:
+        """The slot's block is whole: its tokens (past the prompt's tail, in
+        a request's first block) are handed over, in order and at one
+        stamp, until the request's last asked token (the rest of the block
+        is dropped).  Returns how many were handed over."""
+        first = int(self._tail[slot])
+        for j in range(first, self.block):
+            if req.state == "DONE":
+                return j - first
+            self._hand_over(req, int(self.block_tokens[slot, j]), logits,
+                            now, at=(slot, j))
+        return self.block - first
 
     def _spec_ready(self) -> bool:
         """Speculate this step?  Needs a verify-capable backend, a draft
@@ -1224,7 +1555,14 @@ class ServingEngine:
             self.backend.release_slot(slot)
         self.counters["evicted"] += 1
         self.counters["completed"] += 1
-        req._span.close(finish=req.finish_reason, tokens=len(req.tokens))
+        passes = {}
+        if self.block:
+            self.block_masked[slot] = False
+            req.passes = int(self._passes[slot])
+            passes = {"passes": req.passes,
+                      "first_token_passes": req.first_token_passes}
+        req._span.close(finish=req.finish_reason, tokens=len(req.tokens),
+                        **passes)
         if self.collective is not None:
             self.collective.timeline_instant(
                 "SERVING_EVICT", f"req={req.rid} slot={slot} "
@@ -1331,7 +1669,14 @@ class ServingEngine:
         ``tile_rows``, the rows of the row tiles their grouped matmul
         worked, and ``tile_rows_per_held_pair``; over the decode steps
         ``experts_touched``, the distinct experts the live slots picked
-        summed over layers and steps.  A process that compiled anything
+        summed over layers and steps.  Where the decode calls were a
+        block-diffusion model's passes, ``hvd_srv_decode`` has ``block``:
+        the live slot-passes by kind, ``denoise_passes`` and
+        ``commit_passes``, the ``tokens_final`` they made, the tokens
+        ``handed`` to requests, and ``tokens_per_pass`` (``tokens_final``
+        over both kinds: 0.8 under the static rule at a block of 4 in 4
+        steps, four denoising passes and a commit a block).  A process that
+        compiled anything
         also has the compile ledger's names, and under
         ``hvd_compile_backend`` ``after_first_token``: the ``count`` of
         backend compiles that ended after the first prefill the records
@@ -1371,6 +1716,21 @@ class ServingEngine:
                 "calls": len(looped),
                 "bucket_rows": sum(f["bucket"] for f in looped),
                 "rows_worked": sum(f["rows_worked"] for f in looped)}
+        passes = [r.fields for r in records
+                  if r.name == profiling.SRV_DECODE and "block" in r.fields]
+        if passes:
+            commits = sum(f["commits"] for f in passes)
+            live = sum(f["slots"] for f in passes)
+            # (what a pass's results say is on the step it ran in)
+            steps = [r.fields for r in records
+                     if r.name == profiling.SRV_STEP
+                     and "made_final" in r.fields]
+            final = sum(f["made_final"] for f in steps)
+            out[profiling.SRV_DECODE]["block"] = {
+                "denoise_passes": live - commits, "commit_passes": commits,
+                "tokens_final": final,
+                "handed": sum(f["handed"] for f in steps),
+                "tokens_per_pass": final / max(live, 1)}
         for name in (profiling.SRV_PREFILL, profiling.SRV_DECODE):
             sparse = [r.fields for r in records
                       if r.name == name and "moe_rows" in r.fields]

@@ -176,7 +176,11 @@ def test_the_manifest_holds_the_cell_and_its_metrics():
     layers = {e["layer"] for e in m["per_layer"][:at]} | {
         "models (models/cca.py)"}
     for e in m["per_layer"][at:at + 6]:
-        assert e["workloads"] == [CELL] and e["moves"] == "ttft_ms_mean"
+        # (a later cell whose program writes the same counter appends
+        # itself: PR 56 joined moe_experts_touched_share.srv)
+        assert e["workloads"][0] == CELL and e["moves"] == "ttft_ms_mean"
+        assert e["workloads"] == [CELL] or e["name"] == \
+            "moe_experts_touched_share.srv"
         assert e["layer"] in layers
         assert os.path.exists(os.path.join(
             ROOT, "benchmarks", "metrics", e["name"].split(".")[0] + ".py"))

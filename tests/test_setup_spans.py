@@ -31,7 +31,8 @@ TRAINING_CELLS = ["dsc1p3b-s2048", "resnet50-imagenet", "dsc1p3b-s16384",
                   "dsc1p3b-dp4", "olmoe-s4096", "granite4hm-s8192"]
 SERVING_CELLS = ["dsc1p3b-code-0.8knee", "cmdaplus-code8k-open",
                  "axk1-longdoc16k-open", "evabyte-code32k-open",
-                 "ling3f-longdoc32k-open", "zaya1-reason8k-open"]
+                 "ling3f-longdoc32k-open", "zaya1-reason8k-open",
+                 "sdar30b-chat4k-open"]
 EVERY_CELL = TRAINING_CELLS[:5] + SERVING_CELLS[:1] + TRAINING_CELLS[5:] \
     + SERVING_CELLS[1:]        # the manifest's own order
 # metric -> (unit, source, its cells)
@@ -565,4 +566,5 @@ def test_every_cell_has_a_part_of_its_setup_and_setup_s_has_its_parts():
     assert [e["name"] for e in moved] == [
         n for n, (_, _, cells) in NEW.items() if cells]     # the table's order
     assert [w["name"] for w in m["workloads"]] == EVERY_CELL
-    assert len(m["per_layer"]) == 112 + len(moved) == 124
+    # (the parent's 112 and these 12; later PRs append theirs)
+    assert 112 + len(moved) == 124 <= len(m["per_layer"])
