@@ -57,6 +57,7 @@ from jax import lax
 from horovod_tpu.models.transformer import _over_rows, row_blocks
 from horovod_tpu.ops.grouped_matmul import (grouped_glu, grouped_matmul,
                                             visited_rows)
+from horovod_tpu.ops.moe_rows import combine_rows, dispatch_rows
 from horovod_tpu.ops.token_sum import add_rows_by_token
 from horovod_tpu.parallel.common import shard_init_rng
 from horovod_tpu.parallel.expert import expert_parallel_moe
@@ -74,7 +75,11 @@ MOE_STATS = "moe_stats"     # "expert_pairs": [E] int32, pairs per expert
                             # served prefill, a training step) "tile_rows":
                             # int32, the rows of
                             # the row tiles the kernel worked (a product's
-                            # visits x tile rows)
+                            # visits x tile rows); "kernel_rows": int32, the
+                            # rows hvd_moe_rows moved between token order and
+                            # expert order in this forward (2 * T * k in the
+                            # training layer from GROUPED_ROW_TILE pairs on,
+                            # 0 wherever _dispatch and _permute move them)
 
 
 def moe_aux_loss(cfg, collections) -> jax.Array:
@@ -561,8 +566,14 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
         in_tiles = not walks and t * k >= GROUPED_ROW_TILE \
             and t * k % WALK_ROW_TILE == 0 and not m.is_initializing()
         tile = WALK_ROW_TILE if walks else grouped_row_tile(t * k, e)
-        if not walks:
-            inverse = jnp.argsort(order)
+        # ... and where no row is dead (every expert held, no ``valid``: the
+        # training layer) its two row moves and their backwards are
+        # hvd_moe_rows' kernels, which carry no mask (ops/moe_rows.py:
+        # 22.5 -> 13 ms of olmoe-s4096's step, PERF.md section 6, PR 57);
+        # everywhere else _dispatch and _permute, XLA's gathers.
+        in_kernels = everything and in_tiles
+        if not walks and not in_kernels:
+            inverse = jnp.argsort(order)    # (the kernels go by ``order``)
         pairs = (local[..., None] == jnp.arange(held)).sum(
             axis=(0, 1), dtype=jnp.int32)                     # [held]
         rows_visited = block_rows * (-(-pairs.sum() // block_rows)) \
@@ -574,6 +585,8 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
                 # (a block is whole tiles, so the blocks' visits are those
                 # of the held pairs laid end to end)
                 m.sow(MOE_STATS, "tile_rows", visited_rows(pairs, tile))
+            m.sow(MOE_STATS, "kernel_rows",
+                  jnp.int32(2 * t * k if in_kernels else 0))
             m.sow(MOE_STATS, "picks", picks.reshape(b, s, k))
             if m.selection == "softmax" and live is None:
                 logits, probs = every
@@ -604,7 +617,8 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
             return done(out)
     else:
         with jax.named_scope(profiling.MOE_DISPATCH):
-            rows = _dispatch(tokens, order, inverse, k)       # [T*k, D]
+            rows = dispatch_rows(tokens, order, k) if in_kernels \
+                else _dispatch(tokens, order, inverse, k)     # [T*k, D]
 
         with jax.named_scope(profiling.MOE_EXPERTS):
             if in_tiles:
@@ -620,12 +634,22 @@ def _all_experts_here(m: MoEMLP, x, valid=None, router_state=None):
                 out_rows = grouped(hidden, w_down.astype(m.dtype))  # [T*k, D]
 
         with jax.named_scope(profiling.MOE_COMBINE):
-            by_token = _permute(out_rows, inverse, order).reshape(t, k, d)
-            if not everything:
-                # rows past the last group are in no product: whatever the
-                # grouped matmul left there is taken out, not weighted
-                by_token = jnp.where(on_chip[..., None], by_token, 0)
-            out = (by_token.astype(jnp.float32) * gates[..., None]).sum(1)
+            if in_kernels:
+                # (where nothing is added to the sum in float32, it leaves
+                # the kernel in the dtype it is handed on in: one rounding,
+                # done()'s, and no pass of XLA's over [T, D] to make it)
+                out = combine_rows(out_rows, gates, order,
+                                   jnp.float32 if n_shared else x.dtype)
+            else:
+                by_token = _permute(out_rows, inverse, order).reshape(
+                    t, k, d)
+                if not everything:
+                    # rows past the last group are in no product: whatever
+                    # the grouped matmul left there is taken out, not
+                    # weighted
+                    by_token = jnp.where(on_chip[..., None], by_token, 0)
+                out = (by_token.astype(jnp.float32)
+                       * gates[..., None]).sum(1)
             if not n_shared:
                 return done(out)
 

@@ -581,10 +581,21 @@ class TransformerBackend:
         # (a program's sparse layers share their shapes: all walk or none)
         walked = all("tile_rows" in lay for lay in layers)
 
+        # ... and a count of the rows hvd_moe_rows moved, where a layer sowed
+        # one that is not the constant 0 (the kernels are the training
+        # layer's, and a layer that runs them sows tile_rows too: a served
+        # program is the same text with or without the counter)
+        moved = walked and any(
+            isinstance(n, self._jax.core.Tracer) or int(n)
+            for lay in layers for n in lay["kernel_rows"])
+
         def counted(lay):
             row = jnp.append(total(lay["expert_pairs"]),
                              total(lay["rows_visited"]))
-            return jnp.append(row, total(lay["tile_rows"])) if walked else row
+            if walked:
+                row = jnp.append(row, total(lay["tile_rows"]))
+            return jnp.append(row, total(lay["kernel_rows"])) if moved \
+                else row
 
         return out, jnp.stack([counted(lay) for lay in layers])
 
@@ -702,14 +713,15 @@ class TransformerBackend:
             call.fields.update(counts)
 
     def _count_pairs(self, counted: np.ndarray, tokens: int) -> None:
-        """``counted`` [L, held + 1 or + 2] is a call's (:meth:`_apply`):
-        into the running sums, and onto the ``hvd_srv_prefill`` /
-        ``hvd_srv_decode`` span around it."""
+        """``counted`` [L, held + 1, + 2 or + 3] is a call's
+        (:meth:`_apply`): into the running sums, and onto the
+        ``hvd_srv_prefill`` / ``hvd_srv_decode`` span around it."""
         self.last_expert_pairs = pairs = counted[:, :self._experts_held]
         held = int(pairs.sum())
         rows, *walked = (int(n) for n in
                          counted[:, self._experts_held:].sum(axis=0))
-        fields = {"moe_rows": rows, "moe_held": held}
+        fields = {"moe_rows": rows, "moe_held": held,
+                  "moe_kernel_rows": sum(walked[1:])}
         if walked:
             fields["moe_tile_rows"] = walked[0]
         call = profiling.current_span()
@@ -722,7 +734,7 @@ class TransformerBackend:
         c["pairs"] += tokens * self._pairs_per_token
         c["held_pairs"] += held
         c["rows_visited"] += rows
-        c["tile_rows"] += sum(walked)
+        c["tile_rows"] += sum(walked[:1])
         if call is not None and call.name in (profiling.SRV_PREFILL,
                                               profiling.SRV_DECODE):
             call.fields.update(fields)
@@ -1664,7 +1676,9 @@ class ServingEngine:
         of them.  Where the model's feed-forward is
         sparse, ``hvd_srv_prefill`` and ``hvd_srv_decode`` have ``moe``: the
         ``rows`` the expert layers visited, the ``held_pairs`` they visited
-        them for, and ``rows_per_held_pair`` (1 would waste nothing); over
+        them for, ``rows_per_held_pair`` (1 would waste nothing) and
+        ``kernel_rows``, the rows ``hvd_moe_rows`` moved (0 in a served
+        layer: the kernels are the training layer's); over
         the calls whose layers walked their pairs in blocks also
         ``tile_rows``, the rows of the row tiles their grouped matmul
         worked, and ``tile_rows_per_held_pair``; over the decode steps
@@ -1737,8 +1751,11 @@ class ServingEngine:
             if sparse:
                 rows = sum(f["moe_rows"] for f in sparse)
                 held = sum(f["moe_held"] for f in sparse)
-                out[name]["moe"] = {"rows": rows, "held_pairs": held,
-                                    "rows_per_held_pair": rows / max(held, 1)}
+                out[name]["moe"] = {
+                    "rows": rows, "held_pairs": held,
+                    "rows_per_held_pair": rows / max(held, 1),
+                    "kernel_rows": sum(f["moe_kernel_rows"]
+                                       for f in sparse)}
                 touched = [f["experts_touched"] for f in sparse
                            if "experts_touched" in f]
                 if touched:     # decode steps: distinct experts a layer

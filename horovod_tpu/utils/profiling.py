@@ -85,6 +85,10 @@ MOE_GROUPED = "hvd_moe_grouped"     # ops/grouped_matmul: the walked share's
                                     # grouped matmuls over a work list of
                                     # small row tiles, launched under
                                     # MOE_EXPERTS
+MOE_ROWS = "hvd_moe_rows"   # ops/moe_rows: the kernels that move a training
+                            # layer's rows between token order and expert
+                            # order, launched under MOE_DISPATCH and
+                            # MOE_COMBINE, forward and backward
 MLA_DOWN = "hvd_mla_down"       # models/transformer.LatentAttention: W_DQ,
                                 # W_DKV, the two norms, the rotary key, the
                                 # cache's write
@@ -668,7 +672,7 @@ class Scope:
     bucket: str | None = None   # "0".., "all": a collective under hvd_bucket_
     kernel: str | None = None   # a kernel's name: a FLASH_PASSES or
                                 # SSD_PASSES pass, TOKEN_SUM, KDA_CHUNK,
-                                # MOE_GROUPED, or MOE_EXPERTS (XLA's own
+                                # MOE_GROUPED, MOE_ROWS, or MOE_EXPERTS (XLA's own
                                 # grouped matmul)
     bytes: int = 0              # of the result, from its shape
 
@@ -760,7 +764,8 @@ def scope_table(compiled) -> dict[str, Scope]:
             kernel = None
             if opcode == "custom-call":
                 kernel = next((k for k in FLASH_PASSES + SSD_PASSES
-                               + (TOKEN_SUM, KDA_CHUNK, MOE_GROUPED)
+                               + (TOKEN_SUM, KDA_CHUNK, MOE_GROUPED,
+                                  MOE_ROWS)
                                if k in op_name),
                               MOE_EXPERTS
                               if op_name.startswith(_RAGGED_DOT_KERNEL)

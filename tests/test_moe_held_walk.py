@@ -267,6 +267,11 @@ def test_the_backend_counts_the_rows_its_layers_visited():
     assert 1.0 <= summary["tile_rows_per_held_pair"]
     assert "tile_rows" not in engine.span_summary()[
         profiling.SRV_DECODE]["moe"]
+    # no served layer moves its rows by hvd_moe_rows (PR 57): the counter
+    # is on every sparse call's span and in both summaries, 0
+    assert all(r.fields["moe_kernel_rows"] == 0 for r in calls)
+    assert summary["kernel_rows"] == 0 == engine.span_summary()[
+        profiling.SRV_DECODE]["moe"]["kernel_rows"]
 
 
 def test_the_walk_runs_the_grouped_kernel_and_the_rest_xlas():
@@ -361,6 +366,9 @@ def test_every_expert_given_valid_takes_a_bucket_through_the_kernel(
     assert pairs.sum() == n_live
     assert (pairs[0] == 0) == (hole == "an_expert_left_empty")
     assert int(stats["rows_visited"][0]) == t * k       # carried, all
+    # ... by _dispatch and _permute: a served layer's rows may be dead, and
+    # the move kernels (ops/moe_rows.py, PR 57) carry no mask
+    assert int(stats["kernel_rows"][0]) == 0 and profiling.MOE_ROWS not in text
     tile = moe.WALK_ROW_TILE
     assert n_live <= int(stats["tile_rows"][0]) <= n_live + len(pairs) * tile
     assert int(stats["tile_rows"][0]) % tile == 0
@@ -379,9 +387,13 @@ def test_every_expert_in_training_and_in_a_decode_step_keeps_ragged_dot(
     ``valid``) under 512 pairs.  At 512 pairs and more the training layer's
     products are the kernels too (PR 55): two ``hvd_moe_grouped`` calls
     forward (gate and up fused, down) and four more backward, ``tile_rows``
-    sown, every gradient equal to the ``ragged_dot`` layer's within
-    bfloat16's rounding; and the serving form over a bucket's rows, which
-    refused a gradient by name, differentiates alike."""
+    sown, every gradient (the rows', the router's, the three weights') equal
+    to the ``ragged_dot`` layer's within bfloat16's rounding; and the
+    serving form over a bucket's rows, which refused a gradient by name,
+    differentiates alike.  The training layer also moves its rows by kernel
+    (PR 57): four ``hvd_moe_rows`` calls forward (the dispatch's tiles and
+    fetch, the combine's send and sum), four more backward, ``kernel_rows``
+    = 2 T k sown, 0 by every other form."""
     from horovod_tpu.utils import profiling
 
     m, params, x, more = every_expert(routing)
@@ -397,40 +409,56 @@ def test_every_expert_in_training_and_in_a_decode_step_keeps_ragged_dot(
         text = str(jax.make_jaxpr(apply)(params))
         assert text.count("ragged_dot_general[") == 3
         assert profiling.MOE_GROUPED not in text and "while[" not in text
-        assert "tile_rows" not in jax.eval_shape(apply, params)[1][MOE_STATS]
+        assert profiling.MOE_ROWS not in text
+        sown = jax.jit(apply)(params)[1][MOE_STATS]
+        assert "tile_rows" not in sown and int(sown["kernel_rows"][0]) == 0
         return
     # half the tokens, 256 pairs: XLA's kernels as before
     few = {n: v[:, :x.shape[1] // 2] for n, v in more.items()}
     text = str(jax.make_jaxpr(lambda p: apply(
         p, x[:, :x.shape[1] // 2], few))(params))
     assert text.count("ragged_dot_general[") == 3 and grouped not in text
+    assert profiling.MOE_ROWS not in text
+    moved = f"name={profiling.MOE_ROWS}"
     text = str(jax.make_jaxpr(apply)(params))
-    assert text.count(grouped) == 2
+    assert text.count(grouped) == 2 and text.count(moved) == 4
     assert "ragged_dot" not in text and "while[" not in text
     sown = jax.jit(apply)(params)[1][MOE_STATS]
+    assert int(sown["kernel_rows"][0]) == 2 * 512
     pairs, tile = np.asarray(sown["expert_pairs"][0]), moe.WALK_ROW_TILE
     assert pairs.sum() == 512 and int(sown["rows_visited"][0]) == 512
     assert 512 <= int(sown["tile_rows"][0]) <= 512 + len(pairs) * tile
     assert int(sown["tile_rows"][0]) % tile == 0
 
     cotangent = jax.random.normal(jax.random.PRNGKey(7), x.shape)
-    loss = lambda p, **given: (first(m.apply(  # noqa: E731
+    loss = lambda p, x, **given: (first(m.apply(  # noqa: E731
         p, x, **more, **given)).astype(jnp.float32) * cotangent).sum()
-    text = str(jax.make_jaxpr(jax.grad(loss))(params))
+    both = jax.grad(loss, argnums=(0, 1))
+    text = str(jax.make_jaxpr(both)(params, x))
     assert text.count(grouped) == 6 and "ragged_dot" not in text
-    ours = jax.jit(jax.grad(loss))(params)
-    served = jax.jit(jax.grad(functools.partial(
-        loss, valid=jnp.ones(x.shape[:2], bool))))(params)
+    # (four calls forward, four backward)
+    assert text.count(moved) == 8
+    ours = jax.jit(both)(params, x)
+    over_a_bucket = functools.partial(loss, valid=jnp.ones(x.shape[:2], bool))
+    assert profiling.MOE_ROWS not in str(jax.make_jaxpr(jax.grad(
+        over_a_bucket, argnums=(0, 1)))(params, x))
+    served = jax.jit(jax.grad(over_a_bucket, argnums=(0, 1)))(params, x)
     # the ragged_dot layer: the line between a step's rows and a bucket's
     # moved past these 512
     monkeypatch.setattr(moe, "GROUPED_ROW_TILE", 1024)
-    assert "ragged_dot" in str(jax.make_jaxpr(jax.grad(loss))(params))
-    want = jax.grad(loss)(params)
-    for name, exact in want["params"].items():
+    both = jax.grad(loss, argnums=(0, 1))    # (traced anew)
+    text = str(jax.make_jaxpr(both)(params, x))
+    assert "ragged_dot" in text and profiling.MOE_ROWS not in text
+    want = both(params, x)
+    leaves = lambda grads: {**grads[0]["params"],  # noqa: E731
+                            "the rows": grads[1]}
+    assert {"gate", "up", "down"} < set(leaves(want))
+    for name, exact in leaves(want).items():
         size = float(jnp.abs(exact).max())
         assert size > 0
         for got in (ours, served):
-            assert float(jnp.abs(got["params"][name] - exact).max()) \
+            assert float(jnp.abs(leaves(got)[name].astype(jnp.float32)
+                                 - exact.astype(jnp.float32)).max()) \
                 < 2 ** -5 * size, name
 
 

@@ -606,32 +606,47 @@ def test_every_expert_held_compiles_a_buckets_products_as_the_kernel(
     assert profiling.TOKEN_SUM not in text
 
 
+_OLMOE_LAYER = {}
+
+
+def _olmoe_training_layer(one_chip_mesh, monkeypatch):
+    """OLMoE's expert layer as ``olmoe-s4096`` trains it (64 experts of 2048
+    x 1024, top-8, 4 x 4096 tokens: 131072 pairs), its gradient to the
+    parameters and the rows compiled for the chip, once a module."""
+    from horovod_tpu.models import moe
+
+    if not _OLMOE_LAYER:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        one_chip = NamedSharding(one_chip_mesh, P())
+        d, f, e, k, b, s = 2048, 1024, 64, 8, 4, 4096
+        assert moe.grouped_row_tile(b * s * k, e) == moe.WIDE_ROW_TILE == 256
+        m = moe.MoEMLP(embed_dim=d, mlp_dim=f, axis_name=None,
+                       dtype=jnp.bfloat16, num_experts=e, experts_per_token=k)
+        shaped = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+            a.shape, a.dtype, sharding=one_chip)
+        params = jax.tree.map(shaped, jax.eval_shape(lambda: m.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, d), jnp.bfloat16))))
+        x = jax.ShapeDtypeStruct((b, s, d), jnp.bfloat16, sharding=one_chip)
+        # (not linear in the layer's result, so that its forward stays)
+        loss = lambda p, x: jnp.square(m.apply(  # noqa: E731
+            p, x).astype(jnp.float32)).sum()
+        _OLMOE_LAYER["compiled"] = jax.jit(
+            jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
+    return _OLMOE_LAYER["compiled"]
+
+
 def test_the_training_layer_compiles_its_products_and_their_backward_as_the_kernel(
         one_chip_mesh, monkeypatch):
-    """OLMoE's expert layer as ``olmoe-s4096`` trains it (64 experts of 2048
-    x 1024, top-8, 4 x 4096 tokens: 131072 pairs, 2048 an even share, so
-    256-row tiles), forward and backward compiled for the chip (PR 55): six
-    ``hvd_moe_grouped`` calls (gate and up fused: the forward, the sum of
-    their rows' gradients, their two weights' gradients; down's three) and
-    no ``ragged-dot``; the scope table files each as that kernel under the
-    layer's path inside ``hvd_moe_experts``, two forward and four backward."""
-    from horovod_tpu.models import moe
+    """OLMoE's expert layer as ``olmoe-s4096`` trains it (2048 rows an even
+    share, so 256-row tiles), forward and backward compiled for the chip
+    (PR 55): six ``hvd_moe_grouped`` calls (gate and up fused: the forward,
+    the sum of their rows' gradients, their two weights' gradients; down's
+    three) and no ``ragged-dot``; the scope table files each as that kernel
+    under the layer's path inside ``hvd_moe_experts``, two forward and four
+    backward."""
     from horovod_tpu.utils import profiling
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = NamedSharding(one_chip_mesh, P())
-    d, f, e, k, b, s = 2048, 1024, 64, 8, 4, 4096
-    assert moe.grouped_row_tile(b * s * k, e) == moe.WIDE_ROW_TILE == 256
-    m = moe.MoEMLP(embed_dim=d, mlp_dim=f, axis_name=None, dtype=jnp.bfloat16,
-                   num_experts=e, experts_per_token=k)
-    shaped = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-        a.shape, a.dtype, sharding=one_chip)
-    params = jax.tree.map(shaped, jax.eval_shape(lambda: m.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8, d), jnp.bfloat16))))
-    x = jax.ShapeDtypeStruct((b, s, d), jnp.bfloat16, sharding=one_chip)
-    loss = lambda p, x: m.apply(p, x).astype(jnp.float32).sum()  # noqa: E731
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, x).compile()
+    compiled = _olmoe_training_layer(one_chip_mesh, monkeypatch)
     text = compiled.as_text()
     kernels = _kernels_named(text, profiling.MOE_GROUPED)
     assert len(kernels) == 6 and "ragged-dot" not in text
@@ -642,6 +657,39 @@ def test_the_training_layer_compiles_its_products_and_their_backward_as_the_kern
         == ["backward"] * 4 + ["forward"] * 2
     assert {scope.module for scope in ours} == {
         f"MoEMLP/{profiling.MOE_EXPERTS}/{profiling.MOE_GROUPED}"}
+
+
+def test_the_training_layer_moves_its_rows_by_kernel_and_gathers_none(
+        one_chip_mesh, monkeypatch):
+    """The same compiled layer (PR 57): its four row moves are eight
+    ``hvd_moe_rows`` calls, Mosaic's at these shapes (a row a tile of 8 x
+    128 words): the dispatch's two forward (the tokens as tiles, the fetch)
+    and its backward's two (the cotangent rows sent to their pairs' slots,
+    the sum over k) under ``hvd_moe_dispatch``, the combine's send and sum
+    forward and its backward's two (``gates * dout`` spread with the gates'
+    gradient, the fetch into expert order) under ``hvd_moe_combine``; and
+    XLA gathers no ``[131072, 2048]`` array any more, nor a ``[131072]``
+    one."""
+    from horovod_tpu.utils import profiling
+
+    compiled = _olmoe_training_layer(one_chip_mesh, monkeypatch)
+    text = compiled.as_text()
+    kernels = _kernels_named(text, profiling.MOE_ROWS)
+    assert len(kernels) == 8
+    ours = [scope for scope in profiling.scope_table(compiled).values()
+            if scope.kernel == profiling.MOE_ROWS]
+    assert sorted((scope.module, scope.phase) for scope in ours) == sorted(
+        (f"MoEMLP/{where}/{profiling.MOE_ROWS}", phase)
+        for where, phase in [(profiling.MOE_DISPATCH, "forward"),
+                             (profiling.MOE_DISPATCH, "forward"),
+                             (profiling.MOE_DISPATCH, "backward"),
+                             (profiling.MOE_DISPATCH, "backward"),
+                             (profiling.MOE_COMBINE, "forward"),
+                             (profiling.MOE_COMBINE, "forward"),
+                             (profiling.MOE_COMBINE, "backward"),
+                             (profiling.MOE_COMBINE, "backward")])
+    assert not re.search(r"\[131072\]\S* gather\(", text)
+    assert not re.search(r"\[131072,2048\]\S* gather\(", text)
 
 
 # The four serving cells cut to two layers at their own widths (for A.X-K1
