@@ -253,6 +253,19 @@ class TransformerConfig:
     # final; a serving backend never samples it.
     attention_block: int | None = None
     mask_token_id: int | None = None
+    # Manifold-constrained hyper-connections (models/hyper.py): the residual
+    # stream of a position is hyper_streams rows of embed_dim ([B, S, n, C]
+    # from the embedding, copied into every row, to the rows' sum before the
+    # final norm) and each sublayer reads one mix of the rows and writes into
+    # another, under coefficients made from the stream: sigmoids, and for
+    # the rows' own mix exp of logits clipped to hyper_res_clamp (lo, hi),
+    # then columns and rows divided by their sums (+ hyper_eps, which is
+    # also the flat norm's) hyper_sinkhorn_iters times.  0: one vector a
+    # position, the stream every other field of this class describes.
+    hyper_streams: int = 0
+    hyper_sinkhorn_iters: int = 20
+    hyper_eps: float = 1e-6
+    hyper_res_clamp: tuple = (-30.0, 30.0)
 
     @classmethod
     def from_dict(cls, fields: dict) -> "TransformerConfig":
@@ -1410,6 +1423,20 @@ def _feed_forward(cfg: TransformerConfig, dense: bool = False,
     return lambda y, valid: mlp(y)
 
 
+def _feed_forward_over(cfg: "TransformerConfig", dense_ff: bool, rows, made):
+    """(the block's feed-forward, the rows it runs over, its chunk) in a
+    pass whose position-wise work runs over ``rows`` through ``made``
+    (:func:`_prompt_rows`).  A dense feed-forward runs over the prompt's row
+    blocks where there are any, the loop's blocks in the chunks' place.  A
+    sparse one keeps its lines and its chunks: its routed experts walk the
+    whole chunk's pairs, and its position-wise parts stop at the last
+    position that holds a token by themselves (models/moe.py)."""
+    if cfg.moe_axis or (cfg.num_experts and not dense_ff):
+        rows, made = None, _as_is
+    return (_feed_forward(cfg, dense_ff, made), rows,
+            cfg.feed_forward_chunk if rows is None else None)
+
+
 def _in_chunks(fn, chunk: int | None, x, valid, *more):
     """``fn(x, valid, *more)`` over [B, S, ...] (``more``: further arrays a
     position), ``chunk`` positions at a time where the sequence is longer
@@ -1528,6 +1555,71 @@ def _over_rows_carrying(fn, rows, carry, *xs, block: int = ROW_BLOCK):
             one)))
 
 
+# what a stream of several rows (hyper_streams) is not built beside, and why
+HYPER_REFUSED = {
+    "parallel_block": "the rows are mixed around each of two sublayers in "
+                      "turn, and a parallel block has one",
+    "residual_scaling": "the scaled residual merge is the sequential "
+                        "block's one-row stream's; the rows' mixes stand in "
+                        "its place",
+    "moe_router_dim": "a router state handed down the layers rides beside "
+                      "a one-row stream",
+    "attention_block": "a block-diffusion model's passes are built for a "
+                       "one-row stream"}
+
+
+def _hyper_block(block, mixer, name: str, x, positions, cache, return_kv,
+                 valid, lengths):
+    """:class:`Block`'s call for a stream of several rows
+    (``cfg.hyper_streams``; models/hyper.py): ``x`` is [B, S, n, C], each
+    sublayer reads ``norm(h)``, h one mix of the rows, and what it gives
+    is written into another mix of them.  The coefficients and the mixes
+    are position-wise: in a served prefill of several row blocks they run
+    over the prompt's own blocks (:func:`_over_rows`), as a dense
+    feed-forward does; a sparse one keeps its chunks.  Called inside
+    ``Block``'s compact call, so the modules made here are the block's."""
+    from horovod_tpu.models import hyper
+
+    cfg = block.cfg
+    rows, made = _prompt_rows(x, cache, return_kv, lengths)
+
+    def entering(side: str):
+        """x -> (the sublayer's normed input, its coefficients, the column
+        error): the sublayer's own hyper-connection and its norm."""
+        coefficients = made(hyper.HyperConnection(
+            cfg.hyper_streams, cfg.hyper_sinkhorn_iters, cfg.hyper_eps,
+            tuple(cfg.hyper_res_clamp), cfg.param_dtype, name=f"{side}_hc"))
+        norm = make_norm(cfg, f"{side}_norm", made)
+
+        def enter(x):
+            coef, err = coefficients(x)
+            return norm(hyper.pre_mix(coef, x)), coef, err
+
+        return enter
+
+    def leave(x, f, coef):
+        return hyper.post_mix(coef, x, _scaled(f, cfg.residual_multiplier))
+
+    y, coef, err = _over_rows(entering(name), rows, x)
+    kv = None
+    if cache is not None or return_kv:
+        mixed, kv = mixer(y, positions, cache=cache, return_kv=return_kv,
+                          **({} if lengths is None else {"lengths": lengths}))
+    else:
+        mixed = mixer(y, positions)
+    x = _over_rows(leave, rows, x, mixed, coef)
+    y, coef, err_ff = _over_rows(entering("mlp"), rows, x)
+    ff, ff_rows, chunk = _feed_forward_over(cfg, block.dense_ff, rows, made)
+    f = _over_rows(lambda y: _in_chunks(ff, chunk, y, valid), ff_rows, y)
+    out = _over_rows(leave, rows, x, f, coef)
+    if not block.is_initializing():
+        block.sow(hyper.MHC_STATS, "col_sum_err",
+                  jnp.maximum(jnp.max(err), jnp.max(err_ff)))
+    if cache is not None or return_kv:
+        return out, kv
+    return out
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
     layer_type: str = "attention"
@@ -1551,6 +1643,9 @@ class Block(nn.Module):
                              ) from None
         mixer = getattr(importlib.import_module(module), cls)(
             cfg, name=name, **told)
+        if cfg.hyper_streams:
+            return _hyper_block(self, mixer, name, x, positions, cache,
+                                return_kv, valid, lengths)
         y = norm(f"{name}_norm")(x)
         kv = None
         if cache is not None or return_kv:
@@ -1571,20 +1666,17 @@ class Block(nn.Module):
             # feed-forward first, with the mixer's loops' buffers and the
             # attention's output waiting beside its own
             mixed, y = jax.lax.optimization_barrier((mixed, y))
-        if cfg.moe_axis or (cfg.num_experts and not self.dense_ff):
-            # a sparse feed-forward keeps these lines and its chunks: its
-            # routed experts walk the whole chunk's pairs, and its
-            # position-wise parts stop at the last position that holds a
-            # token by themselves (models/moe.py)
-            rows, made = None, _as_is
-        ff = _feed_forward(cfg, self.dense_ff, made)
-        chunk = cfg.feed_forward_chunk if rows is None else None
+        ff, rows, chunk = _feed_forward_over(cfg, self.dense_ff, rows, made)
+        if rows is None:
+            made = _as_is
         carrying = bool(cfg.moe_router_dim)
         if cfg.parallel_block:
             if carrying or cfg.residual_scaling:
                 raise NotImplementedError(
-                    "a router state handed down the layers and the scaled "
-                    "residual merge are the sequential block's")
+                    "parallel_block beside moe_router_dim or "
+                    "residual_scaling: a router state handed down the "
+                    "layers and the scaled residual merge are the "
+                    "sequential block's")
             # one norm a layer: the feed-forward reads what the mixer read
             # and both are added to the residual
             out = _over_rows(lambda x, mixed, y: x + _scaled(
@@ -1702,6 +1794,18 @@ class Transformer(nn.Module):
     kind's two arrays at its index among that kind's layers.  With
     ``num_pred_heads`` > 1 the logits' last axis is ``num_pred_heads *
     vocab_size`` wide, head 0 (the next token) first.
+
+    The stream between layers is one vector a position in every call form
+    above: ``[B, S, C]`` in a pass without a cache (``[B, S, C]`` with the
+    rows past the longest prompt's block 0 in a prefill that said its
+    ``lengths``), ``[B, S_q, C]`` in a cache call, beside a router's state
+    where ``moe_router_dim`` carries one.  With ``cfg.hyper_streams`` = n >
+    0 (models/hyper.py) it is n ROWS a position, ``[B, S, n, C]`` and ``[B,
+    S_q, n, C]`` (a decode step: ``[slots, 1, n, C]``): the embedding copied
+    into every row, two hyper-connections a layer mixing the rows around
+    its sublayers (:func:`_hyper_block`), the rows summed before the final
+    norm, after ``logits_at``'s pick where that is given.  The cache is the
+    mixers' and knows nothing of it.
     """
 
     cfg: TransformerConfig
@@ -1726,6 +1830,11 @@ class Transformer(nn.Module):
                 f"attention_block beside {sorted(set(kinds) - {'attention'})}"
                 f" layers: the block-causal mask is built for a model whose "
                 f"every layer is \"attention\"")
+        if cfg.hyper_streams:
+            for field, why in HYPER_REFUSED.items():
+                if getattr(cfg, field):
+                    raise NotImplementedError(
+                        f"hyper_streams beside {field}: {why}")
         if (decode or return_kv) and set(kinds) - set(CACHED_MIXERS):
             raise NotImplementedError(
                 f"decode and serving through a recurrent layer are not "
@@ -1737,6 +1846,10 @@ class Transformer(nn.Module):
         x = _scaled(embed(tokens), cfg.embedding_multiplier)
         if cfg.residual_dtype is not None:
             x = x.astype(cfg.residual_dtype)
+        if cfg.hyper_streams:
+            from horovod_tpu.models import hyper
+
+            x = hyper.spread(x, cfg.hyper_streams)
         if decode:
             # Block row i of a cache call decodes position lengths + i:
             # S=1 is plain decode, S>1 is a speculative verify window or a
@@ -1829,7 +1942,13 @@ class Transformer(nn.Module):
                 x = block(x, positions, **told)
         if carrying:
             x, _ = x
-        if logits_at is not None:
+        if cfg.hyper_streams:
+            # the rows' sum, of logits_at's one position where it is given
+            if logits_at is not None:
+                x = jnp.take_along_axis(x, jnp.asarray(
+                    logits_at)[:, None, None, None], axis=1)[:, 0]
+            x = hyper.gathered(x)
+        elif logits_at is not None:
             x = jnp.take_along_axis(
                 x, jnp.asarray(logits_at)[:, None, None], axis=1)[:, 0]
         x = make_norm(cfg, "final_norm")(x)

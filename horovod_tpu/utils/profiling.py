@@ -131,6 +131,14 @@ CCA_ATTN = "hvd_cca_attn"       # ... a head's q and k to length, rotary,
                                 # (the attention function's own names
                                 # inside it: FLASH_FWD)
 CCA_OUT = "hvd_cca_out"         # ... the output projection
+MHC_COEF = "hvd_mhc_coef"       # models/hyper: a sublayer's coefficients, the
+                                # flat norm of the stream's rows, the one
+                                # projection, the sigmoids
+MHC_SINKHORN = "hvd_mhc_sinkhorn"   # ... exp, then columns and rows divided
+                                # by their sums hyper_sinkhorn_iters times
+MHC_PRE = "hvd_mhc_pre"         # ... h, the mix of rows a sublayer reads
+MHC_POST = "hvd_mhc_post"       # ... X', the rows mixed and the sublayer's
+                                # output written into them
 LOADER_WAIT = "hvd_loader_wait"         # data.BackgroundLoader: q.get()
 LOADER_PRODUCE = "hvd_loader_produce"   # ... next(source), producer thread
 H2D_PUT = "hvd_h2d_put"         # data.prefetch_to_device: the device_put
@@ -175,6 +183,7 @@ SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE)
 SSD_PASSES = (SSD_FWD, SSD_BWD)
 KDA_SCOPES = (KDA_PROJ, KDA_CONV, KDA_GATE, KDA_SCAN, KDA_OUT)
 CCA_SCOPES = (CCA_PROJ, CCA_CONV, CCA_ATTN, CCA_OUT)
+MHC_SCOPES = (MHC_COEF, MHC_SINKHORN, MHC_PRE, MHC_POST)
 SRV_CALLS = (SRV_PREFILL, SRV_DECODE, SRV_VERIFY)   # the backend's calls
 SRV_LEAVES = (SRV_H2D, SRV_DISPATCH, SRV_WAIT, SRV_FETCH)   # in call order
 SETUP_SPANS = (SETUP_IMPORT, SETUP_INIT, SETUP_ENGINE, SETUP_POOL,

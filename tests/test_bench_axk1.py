@@ -189,7 +189,8 @@ def test_the_manifest_holds_the_cell_and_its_metrics():
     assert set(names[at:at + 5]) == NEW     # appended, together
     layers = {e["layer"] for e in m["per_layer"][:at]}
     for e in m["per_layer"][at:at + 5]:
-        assert e["workloads"] == [CELL] and e["moves"] == "ttft_ms_mean"
+        # (a later latent-attention cell's name may follow: PR 59)
+        assert e["workloads"][0] == CELL and e["moves"] == "ttft_ms_mean"
         assert e["layer"] in layers     # a layer the benchmark names already
         assert os.path.exists(os.path.join(
             ROOT, "benchmarks", "metrics", e["name"].split(".")[0] + ".py"))

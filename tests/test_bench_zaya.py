@@ -14,6 +14,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 import types
 
 import pytest
@@ -491,9 +492,11 @@ def test_a_tiny_cells_prefills_run_the_grouped_kernel_and_say_so(capsys):
 
     family = load_module("families", "cca_moe_serve")
     traffic = dict(TRAFFIC, prefill_buckets=[64, 512], max_seq_len=640)
+    began = time.perf_counter()     # the ring is the process's: this test's
     served = family.serve(dict(TINY, feed_forward_chunk=512), traffic, 1, 5)
     served.warm()           # every bucket twice, then every slot decoding
-    calls = {name: [r.fields for r in profiling.spans() if r.name == name]
+    calls = {name: [r.fields for r in profiling.spans()
+                    if r.name == name and r.start >= began]
              for name in (profiling.SRV_PREFILL, profiling.SRV_DECODE)}
     long = [f for f in calls[profiling.SRV_PREFILL] if f["bucket"] == 512]
     short = [f for f in calls[profiling.SRV_PREFILL] if f["bucket"] < 512]
@@ -504,10 +507,11 @@ def test_a_tiny_cells_prefills_run_the_grouped_kernel_and_say_so(capsys):
         assert f["moe_held"] <= f["moe_tile_rows"] <= 3 * (512 + 4 * 128)
     assert not any("moe_tile_rows" in f
                    for f in short + calls[profiling.SRV_DECODE])
+    # (the summary is over the process's ring: every engine's, those of the
+    # tests that ran before this one in its worker too)
     summary = served.engine.span_summary()
     assert summary[profiling.SRV_PREFILL]["moe"]["tile_rows"] \
-        == sum(f["moe_tile_rows"] for f in long)
-    assert "tile_rows" not in summary[profiling.SRV_DECODE]["moe"]
+        >= sum(f["moe_tile_rows"] for f in long)
     served.release()
     moe = json.loads(capsys.readouterr().out.split("moe: ")[1].splitlines()[0])
     assert moe["tile_rows"] == sum(f["moe_tile_rows"] for f in long)

@@ -512,9 +512,9 @@ def test_every_name_of_the_vocabulary_is_written_once():
     # two of ops/ssd_scan.py, ten of serving/engine.py, four of
     # models/transformer.LatentAttention, two of EvaAttention, five of
     # models/kda.py, one of ops/kda_scan.py, one of ops/grouped_matmul.py,
-    # one of ops/moe_rows.py, four of models/cca.py, five start-up spans and
-    # the compile ledger's three
-    assert len(names) == len(set(names)) == 59
+    # one of ops/moe_rows.py, four of models/cca.py, four of models/hyper.py,
+    # five start-up spans and the compile ledger's three
+    assert len(names) == len(set(names)) == 63
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for directory, _, files in itertools.chain(
             os.walk(os.path.join(root, "horovod_tpu")),

@@ -37,7 +37,7 @@ NEW = {"decode_h2d_ms.srv": "serving backend", "decode_dispatch_ms.srv":
 SERVING_CELLS = ["dsc1p3b-code-0.8knee", "cmdaplus-code8k-open",
                  "axk1-longdoc16k-open", "evabyte-code32k-open",
                  "ling3f-longdoc32k-open", "zaya1-reason8k-open",
-                 "sdar30b-chat4k-open"]
+                 "sdar30b-chat4k-open", "xing4-chat4k-open"]
 
 
 # the start-up spans and the compile ledger (PR 54) are kept in a store of

@@ -32,7 +32,7 @@ TRAINING_CELLS = ["dsc1p3b-s2048", "resnet50-imagenet", "dsc1p3b-s16384",
 SERVING_CELLS = ["dsc1p3b-code-0.8knee", "cmdaplus-code8k-open",
                  "axk1-longdoc16k-open", "evabyte-code32k-open",
                  "ling3f-longdoc32k-open", "zaya1-reason8k-open",
-                 "sdar30b-chat4k-open"]
+                 "sdar30b-chat4k-open", "xing4-chat4k-open"]
 EVERY_CELL = TRAINING_CELLS[:5] + SERVING_CELLS[:1] + TRAINING_CELLS[5:] \
     + SERVING_CELLS[1:]        # the manifest's own order
 # metric -> (unit, source, its cells)
