@@ -1,11 +1,14 @@
 """The Mamba-2 sequence mixer (arXiv:2405.21060), the ``"mamba"`` entry of
 ``TransformerConfig.layer_types``: one input projection into a gate ``z``, the
 convolved stream ``xBC`` and a step size per head; a causal depthwise
-convolution with bias and silu over ``xBC``; the state-space recurrence per
-head (``ops/ssd_scan.py``: chunked, as matrix products; two Pallas kernels
-where the widths meet their tiling rule, XLA's ops elsewhere, by shape alone:
-``ssm_plan(...)["scan"]`` says which); the gate and an RMSNorm over all inner
-channels; the output projection.
+convolution with bias and silu over ``xBC`` (``ops/causal_conv.py``: read
+from the projection's output where it lies, x, B and C written apart as the
+scan reads them; two Pallas kernels where the widths meet their rule, ``jnp``
+ops elsewhere, by shape alone: ``ssm_plan(...)["conv"]`` says which); the
+state-space recurrence per head (``ops/ssd_scan.py``: chunked, as matrix
+products; two Pallas kernels where the widths meet their tiling rule, XLA's
+ops elsewhere, by shape alone: ``ssm_plan(...)["scan"]`` says which); the gate
+and an RMSNorm over all inner channels; the output projection.
 
 Parameters, all the layer's own (transformers' names in brackets, for
 ``MambaMixer`` of Bamba / GraniteMoeHybrid):
@@ -21,8 +24,9 @@ Parameters, all the layer's own (transformers' names in brackets, for
 
 with I = heads x head size.  Everything the layer does is under one of four
 scopes (``utils/profiling.py``: ``hvd_ssm_proj`` / ``_conv`` / ``_scan`` /
-``_gate``), which backward and recomputed ops keep, and the scan's kernels
-with them (``hvd_ssd_fwd`` / ``hvd_ssd_bwd`` under ``hvd_ssm_scan``).
+``_gate``), which backward and recomputed ops keep, and the kernels with
+them (``hvd_causal_conv_fwd`` / ``_bwd`` under ``hvd_ssm_conv``,
+``hvd_ssd_fwd`` / ``hvd_ssd_bwd`` under ``hvd_ssm_scan``).
 
 Not supported yet: decode through the layer (it would carry the conv's last
 K-1 inputs and the state [H, P, N] in a cache of their own), and a sequence
@@ -38,6 +42,8 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import RMSNorm, TransformerConfig
+from horovod_tpu.ops.causal_conv import causal_conv  # noqa: F401 (its old home)
+from horovod_tpu.ops.causal_conv import causal_conv_silu, conv_form
 from horovod_tpu.ops.ssd_scan import (carried_state_bytes, scan_form,
                                       ssd_scan)
 from horovod_tpu.utils import profiling
@@ -60,20 +66,6 @@ def _dt_bias_init(key, shape, dtype=jnp.float32):
     dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
                                     math.log(1e-3), math.log(1e-1)))
     return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
-
-
-def causal_conv(x, kernel, bias):
-    """Depthwise convolution along S of x [B, S, C] with kernel [K, C]:
-    position t sees t-(K-1)..t, zeros before the start.  float32 inside one
-    fusion, result in x's dtype."""
-    k = kernel.shape[0]
-    s = x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    out = bias.astype(jnp.float32)
-    for tap in range(k):
-        out = out + padded[:, tap:tap + s].astype(jnp.float32) \
-            * kernel[tap].astype(jnp.float32)
-    return out.astype(x.dtype)
 
 
 class Mamba2Mixer(nn.Module):
@@ -102,13 +94,12 @@ class Mamba2Mixer(nn.Module):
         d_skip = own("D", nn.initializers.ones, (h,))
 
         with jax.named_scope(profiling.SSM_PROJ):
-            z, xbc, dt = jnp.split(
-                dense("in_proj", inner + conv_dim + h)(x),
-                [inner, inner + conv_dim], axis=-1)
+            stream = dense("in_proj", inner + conv_dim + h)(x)
+            z, dt = stream[..., :inner], stream[..., inner + conv_dim:]
         with jax.named_scope(profiling.SSM_CONV):
-            xbc = nn.silu(causal_conv(xbc, conv_kernel, conv_bias))
+            xs, b, c = causal_conv_silu(stream, conv_kernel, conv_bias, inner,
+                                        _conv_parts(cfg))
         with jax.named_scope(profiling.SSM_SCAN):
-            xs, b, c = jnp.split(xbc, [inner, inner + g * n], axis=-1)
             bsz, s = x.shape[:2]
             y = ssd_scan(
                 xs.reshape(bsz, s, h, p),
@@ -126,6 +117,12 @@ class Mamba2Mixer(nn.Module):
             return dense("out_proj", cfg.embed_dim)(y)
 
 
+def _conv_parts(cfg: TransformerConfig) -> tuple:
+    """The widths of x, B and C, side by side in the convolved stream."""
+    gn = cfg.mamba_groups * cfg.mamba_state_dim
+    return (cfg.mamba_heads * cfg.mamba_head_dim, gn, gn)
+
+
 def ssm_plan(cfg: TransformerConfig, seq_len: int) -> dict:
     """What the recurrent layers of ``cfg`` do with a sequence of ``seq_len``
     tokens, from the configuration alone (the benchmark's ``ssm:`` line)."""
@@ -138,4 +135,7 @@ def ssm_plan(cfg: TransformerConfig, seq_len: int) -> dict:
                 cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state_dim),
             "scan": scan_form(seq_len, cfg.mamba_chunk, cfg.mamba_heads,
                               cfg.mamba_groups, cfg.mamba_head_dim,
-                              cfg.mamba_state_dim)}
+                              cfg.mamba_state_dim),
+            "conv": conv_form(seq_len, cfg.mamba_conv_width,
+                              cfg.mamba_heads * cfg.mamba_head_dim,
+                              _conv_parts(cfg))}

@@ -110,6 +110,11 @@ SSM_SCAN = "hvd_ssm_scan"       # ... ops/ssd_scan: softplus, the decays' sums,
 SSM_GATE = "hvd_ssm_gate"       # ... y * silu(z) and the gated RMSNorm
 SSD_FWD = "hvd_ssd_fwd"         # ops/ssd_scan: forward kernel, launched under
 SSD_BWD = "hvd_ssd_bwd"         # SSM_SCAN; ... backward kernel (no flash pass)
+CAUSAL_CONV_FWD = "hvd_causal_conv_fwd"     # ops/causal_conv: the depthwise
+CAUSAL_CONV_BWD = "hvd_causal_conv_bwd"     # conv, bias and silu of a run of
+                                # a stream's columns, forward and backward
+                                # kernel, a call a part (mamba: x, B, C),
+                                # launched under SSM_CONV
 KDA_PROJ = "hvd_kda_proj"       # models/kda: the six projections in (q, k, v,
                                 # decay, step size, output gate), the one out
 KDA_CONV = "hvd_kda_conv"       # ... causal depthwise conv of q, k, v over
@@ -181,6 +186,7 @@ MOE_SCOPES = (MOE_ROUTE, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE)
 MLA_SCOPES = (MLA_DOWN, MLA_UP, MLA_ABSORB, MLA_ATTN)
 SSM_SCOPES = (SSM_PROJ, SSM_CONV, SSM_SCAN, SSM_GATE)
 SSD_PASSES = (SSD_FWD, SSD_BWD)
+CAUSAL_CONV_PASSES = (CAUSAL_CONV_FWD, CAUSAL_CONV_BWD)
 KDA_SCOPES = (KDA_PROJ, KDA_CONV, KDA_GATE, KDA_SCAN, KDA_OUT)
 CCA_SCOPES = (CCA_PROJ, CCA_CONV, CCA_ATTN, CCA_OUT)
 MHC_SCOPES = (MHC_COEF, MHC_SINKHORN, MHC_PRE, MHC_POST)
@@ -680,7 +686,8 @@ class Scope:
     module: str                 # module_of(op_name)
     bucket: str | None = None   # "0".., "all": a collective under hvd_bucket_
     kernel: str | None = None   # a kernel's name: a FLASH_PASSES or
-                                # SSD_PASSES pass, TOKEN_SUM, KDA_CHUNK,
+                                # SSD_PASSES or CAUSAL_CONV_PASSES pass,
+                                # TOKEN_SUM, KDA_CHUNK,
                                 # MOE_GROUPED, MOE_ROWS, or MOE_EXPERTS (XLA's own
                                 # grouped matmul)
     bytes: int = 0              # of the result, from its shape
@@ -721,7 +728,8 @@ def scope_table(compiled) -> dict[str, Scope]:
     (the head's matmul with the loss and its gradient) and is
     ``forward+backward``.  A collective is ``collective`` by
     opcode whatever its scope, and carries its ``hvd_bucket_<k>``; a kernel
-    (custom call) under ``hvd_flash_*``, ``hvd_ssd_*``, ``hvd_token_sum``,
+    (custom call) under ``hvd_flash_*``, ``hvd_ssd_*``,
+    ``hvd_causal_conv_*``, ``hvd_token_sum``,
     ``hvd_kda_chunk`` or ``hvd_moe_grouped`` carries that name, and one that
     XLA:TPU made of a ``ragged_dot`` carries ``hvd_moe_experts``.  ``while`` and
     ``conditional`` bodies are computations like the entry: their
@@ -773,6 +781,7 @@ def scope_table(compiled) -> dict[str, Scope]:
             kernel = None
             if opcode == "custom-call":
                 kernel = next((k for k in FLASH_PASSES + SSD_PASSES
+                               + CAUSAL_CONV_PASSES
                                + (TOKEN_SUM, KDA_CHUNK, MOE_GROUPED,
                                   MOE_ROWS)
                                if k in op_name),

@@ -105,7 +105,7 @@ def test_the_family_agrees_with_the_reference_in_float32(hvd, family,
     assert ssm == {"layers": {"attention": 1, "mamba": 2}, "chunk": 16,
                    "chunks_per_sequence": 7,
                    "carried_state_bytes_per_layer_and_sequence": 8 * 16 * 32 * 4,
-                   "scan": "xla"}
+                   "scan": "xla", "conv": "xla"}
 
 
 def test_the_family_as_the_chip_runs_it_passes_its_tolerances(hvd, family):
@@ -125,10 +125,11 @@ def drop_d_skip(monkeypatch):
 
 
 def drop_conv_bias(monkeypatch):
-    from horovod_tpu.models import mamba
+    """In the ``jnp`` form, which the tiny widths run."""
+    from horovod_tpu.ops import causal_conv as ops
 
-    conv = mamba.causal_conv
-    monkeypatch.setattr(mamba, "causal_conv", lambda x, kernel, bias:
+    conv = ops.causal_conv
+    monkeypatch.setattr(ops, "causal_conv", lambda x, kernel, bias:
                         conv(x, kernel, 0.0 * bias))
 
 
@@ -223,10 +224,14 @@ SSM_READERS = ("ssm_ms", "ssm_proj_ms", "ssm_conv_ms", "ssm_scan_ms",
                "ssm_gate_ms", "ssm_scan_roofline")
 
 
-def joined_run(module_s, steps=2):
+def joined_run(module_s, steps=2, kernel_s=None):
+    kernel_s = kernel_s or {}
+    pass_s: dict = {}
+    for (kernel, _), v in kernel_s.items():
+        pass_s[kernel] = pass_s.get(kernel, 0.0) + v
     j = scopes.Joined(chips=1, calls=steps, phase_s={}, module_s=module_s,
-                      pass_s={}, buckets={}, lead_s=0.0, tail_s=0.0,
-                      joined_share=1.0, span_s={})
+                      pass_s=pass_s, buckets={}, lead_s=0.0, tail_s=0.0,
+                      joined_share=1.0, span_s={}, kernel_s=kernel_s)
     built = types.SimpleNamespace(
         steps_per_call=1, flash_calls=[{}],
         notes={"ssd_scan_flops_per_step_a_chip": 197e12 * 4e-3,
@@ -260,6 +265,39 @@ def test_the_ssm_readers_split_the_mixers_time_by_the_programs_names(capsys):
     # least max(4 ms of FLOPs, 10 ms of bytes) over 40 ms
     assert reader("ssm_scan_roofline").read(run) == pytest.approx(25.0)
     assert "bound_by=bytes" in capsys.readouterr().out
+
+
+def test_the_convs_kernels_are_counted_under_the_convs_scope_alone():
+    """``hvd_causal_conv_fwd`` / ``_bwd`` are launched under
+    ``hvd_ssm_conv``: their time is ``ssm_conv_ms``'s (beside what XLA still
+    does there) and no other part's; the scan's kernels stay the scan's."""
+    mix = "Transformer/layer_N/mamba"
+    conv = f"{mix}/{profiling.SSM_CONV}"
+    scan = f"{mix}/{profiling.SSM_SCAN}"
+    run = joined_run(
+        {f"{mix}/{profiling.SSM_PROJ}/in_proj": 30e-3,
+         f"{conv}": 1e-3,                   # the taps' slices, XLA's
+         f"{scan}": 6e-3,
+         f"{mix}/{profiling.SSM_GATE}": 4e-3},
+        kernel_s={
+            (profiling.CAUSAL_CONV_FWD,
+             f"{conv}/{profiling.CAUSAL_CONV_FWD}"): 8e-3,
+            (profiling.CAUSAL_CONV_BWD,
+             f"{conv}/{profiling.CAUSAL_CONV_BWD}"): 7e-3,
+            (profiling.SSD_FWD, f"{scan}/{profiling.SSD_FWD}"): 10e-3,
+            (profiling.SSD_BWD, f"{scan}/{profiling.SSD_BWD}"): 20e-3})
+    assert run._scopes.kernel_module_s[
+        f"{conv}/{profiling.CAUSAL_CONV_FWD}"] == pytest.approx(8e-3)
+    proj, conv_ms, scan_ms, gate = (reader(f"ssm_{s}_ms").read(run)
+                                    for s in ("proj", "conv", "scan", "gate"))
+    assert conv_ms == pytest.approx((1.0 + 8.0 + 7.0) / 2)
+    assert (proj, scan_ms, gate) == pytest.approx((15.0, 18.0, 2.0))
+    assert reader("ssm_ms").read(run) == pytest.approx(
+        proj + conv_ms + scan_ms + gate)
+    # the conv's kernels are no flash pass and no part of the scan's roofline
+    assert "hvd_flash" not in profiling.CAUSAL_CONV_FWD
+    assert reader("ssm_scan_roofline").read(run) == pytest.approx(
+        100.0 * 10.0 / 18.0)
 
 
 @pytest.mark.parametrize("stem", SSM_READERS)

@@ -17,6 +17,7 @@ import pytest
 from horovod_tpu.models import Transformer, TransformerConfig
 from horovod_tpu.models import mamba
 from horovod_tpu.models.mamba import Mamba2Mixer, causal_conv, ssm_plan
+from horovod_tpu.ops.causal_conv import causal_conv_silu, conv_form
 from horovod_tpu.ops import ssd_scan as ssd_scan_module
 from horovod_tpu.ops.ssd_scan import head_block, scan_form, ssd_scan
 
@@ -224,22 +225,40 @@ def test_groups_must_divide_heads():
                  d, 16)
 
 
-def test_conv_is_causal_and_has_its_bias():
+def _conv_alone(x, kernel, bias):
+    return causal_conv(x, kernel, bias), lambda pre: pre
+
+
+def _conv_as_the_kernels(x, kernel, bias):
+    """The same convolution through ``ops/causal_conv``'s kernels (the run
+    is the whole stream, one part), which apply the silu as well."""
+    assert conv_form(x.shape[1], kernel.shape[0], 0, (x.shape[2],)) \
+        == "kernel"
+    (y,) = causal_conv_silu(x, kernel, bias, 0, (x.shape[2],))
+    return y, jax.nn.silu
+
+
+# the kernels at a length of two row tiles, the moved position a tile's last
+# but one: the taps that see it lie on both sides of the edge
+@pytest.mark.parametrize("conv,length,channels,at", [
+    (_conv_alone, 20, 6, 11), (_conv_as_the_kernels, 512, 128, 254)],
+    ids=["jnp", "kernel"])
+def test_conv_is_causal_and_has_its_bias(conv, length, channels, at):
     key = jax.random.PRNGKey(0)
-    x = jax.random.normal(key, (1, 20, 6))
-    kernel = jax.random.normal(jax.random.PRNGKey(1), (4, 6))
-    bias = jax.random.normal(jax.random.PRNGKey(2), (6,))
-    y = causal_conv(x, kernel, bias)
-    moved = causal_conv(x.at[0, 11].add(1.0), kernel, bias)
+    x = jax.random.normal(key, (1, length, channels))
+    kernel = jax.random.normal(jax.random.PRNGKey(1), (4, channels))
+    bias = jax.random.normal(jax.random.PRNGKey(2), (channels,))
+    y, act = conv(x, kernel, bias)
+    moved, _ = conv(x.at[0, at].add(1.0), kernel, bias)
     changed = np.abs(np.asarray(moved - y)).sum(-1)[0] > 0
-    assert not changed[:11].any()          # nothing before t moves
-    assert changed[11:15].all() and not changed[15:].any()     # 4 taps
+    assert not changed[:at].any()          # nothing before t moves
+    assert changed[at:at + 4].all() and not changed[at + 4:].any()  # 4 taps
     # position 0 sees zeros before it: the last tap and the bias alone
-    np.testing.assert_allclose(y[0, 0], bias + kernel[3] * x[0, 0],
-                               rtol=1e-6)
+    np.testing.assert_allclose(y[0, 0], act(bias + kernel[3] * x[0, 0]),
+                               rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(
-        y[0, 5], bias + sum(kernel[k] * x[0, 2 + k] for k in range(4)),
-        rtol=1e-5)
+        y[0, 5], act(bias + sum(kernel[k] * x[0, 2 + k] for k in range(4))),
+        rtol=1e-5, atol=1e-6)
 
 
 BASE = dict(vocab_size=64, num_layers=3, num_heads=4, head_dim=8,
@@ -362,24 +381,32 @@ def test_unknown_layer_type_and_wrong_count_are_refused():
             jax.random.PRNGKey(0), tokens)
 
 
-def test_mixer_alone_matches_its_equations():
+@pytest.mark.parametrize("widths,length,forms", [
+    (HYBRID, 21, "xla"), (KERNEL_HYBRID, 256, "kernel")],
+    ids=["xla", "kernel"])
+def test_mixer_alone_matches_its_equations(widths, length, forms):
     """``Mamba2Mixer`` against the equations written out with the sequential
-    recurrence, in float32."""
-    cfg = TransformerConfig(**HYBRID)
+    recurrence, in float32; at widths that meet the kernels' rules the conv
+    and the scan are both the kernels'."""
+    cfg = TransformerConfig(**widths)
+    plan = ssm_plan(cfg, length)
+    assert (plan["conv"], plan["scan"]) == (forms, forms)
+    h, p_, n = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_state_dim
+    inner, e = h * p_, cfg.embed_dim
     mixer = Mamba2Mixer(cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 21, 32))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, length, e))
     params = mixer.init(jax.random.PRNGKey(0), x)
     p = params["params"]
     with jax.default_matmul_precision("highest"):
         got = mixer.apply(params, x)
         zxbcdt = x @ p["in_proj"]["kernel"]
-        z, xbc, dt = jnp.split(zxbcdt, [64, 64 + 96], axis=-1)
+        z, xbc, dt = jnp.split(zxbcdt, [inner, 2 * inner + 2 * n], axis=-1)
         xbc = jax.nn.silu(causal_conv(xbc, p["conv_kernel"], p["conv_bias"]))
-        xs, b, c = jnp.split(xbc, [64, 80], axis=-1)
+        xs, b, c = jnp.split(xbc, [inner, inner + n], axis=-1)
         y = sequential(
-            xs.reshape(2, 21, 8, 8), jax.nn.softplus(dt + p["dt_bias"]),
+            xs.reshape(2, length, h, p_), jax.nn.softplus(dt + p["dt_bias"]),
             -jnp.exp(p["A_log"]), b[:, :, None], c[:, :, None], p["D"])
-        g = y.reshape(2, 21, 64) * jax.nn.silu(z)
+        g = y.reshape(2, length, inner) * jax.nn.silu(z)
         g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + 1e-6) \
             * p["norm"]["scale"]
         want = g @ p["out_proj"]["kernel"]
@@ -394,10 +421,12 @@ def test_ssm_plan_counts_layers_chunks_and_state():
     assert plan == {"layers": {"attention": 1, "mamba": 2}, "chunk": 256,
                     "chunks_per_sequence": 32,
                     "carried_state_bytes_per_layer_and_sequence": 2097152,
-                    "scan": "kernel"}
+                    "scan": "kernel", "conv": "kernel"}
     assert ssm_plan(cfg, 1000)["chunks_per_sequence"] == 4
+    assert ssm_plan(cfg, 1000)["conv"] == "xla"     # no row tile divides it
     # the tiny widths of the tests, and of the benchmark's rehearsal
-    assert ssm_plan(TransformerConfig(**HYBRID), 64)["scan"] == "xla"
+    tiny = ssm_plan(TransformerConfig(**HYBRID), 64)
+    assert (tiny["scan"], tiny["conv"]) == ("xla", "xla")
 
 
 def test_a_scan_refuses_a_sequence_sharded_over_chips():

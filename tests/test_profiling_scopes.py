@@ -397,6 +397,23 @@ def test_the_mamba_mixers_four_scopes_reach_the_table_in_every_phase():
         in modules
 
 
+def pallas_calls(jaxpr, outer=()):
+    """(kernel name, name stack) of every ``pallas_call``; an equation
+    inside a ``jit`` carries the stack from that ``jit`` inwards."""
+    for eqn in jaxpr.eqns:
+        stack = outer + tuple(
+            part for part in str(eqn.source_info.name_stack).split("/")
+            if part)
+        if eqn.primitive.name == "pallas_call":
+            yield eqn.params["name"], "/".join(stack)
+        inner = stack if eqn.primitive.name in ("pjit", "jit") else outer
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from pallas_calls(sub, inner)
+
+
 def test_the_scans_kernels_are_launched_inside_the_mixers_scan_scope():
     """At widths that meet the kernels' tiling rule the gradient of a tiny
     rematted hybrid holds three ``pallas_call``s a mamba layer (forward, the
@@ -420,23 +437,7 @@ def test_the_scans_kernels_are_launched_inside_the_mixers_scan_scope():
     jaxpr = jax.make_jaxpr(
         jax.grad(lambda p: model.apply(p, tokens).sum()))(params)
 
-    def kernels(jaxpr, outer=()):
-        """(kernel name, name stack) of every ``pallas_call``; an equation
-        inside a ``jit`` carries the stack from that ``jit`` inwards."""
-        for eqn in jaxpr.eqns:
-            stack = outer + tuple(
-                part for part in str(eqn.source_info.name_stack).split("/")
-                if part)
-            if eqn.primitive.name == "pallas_call":
-                yield eqn.params["name"], "/".join(stack)
-            inner = stack if eqn.primitive.name in ("pjit", "jit") else outer
-            for v in eqn.params.values():
-                for sub in v if isinstance(v, (list, tuple)) else [v]:
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        yield from kernels(sub, inner)
-
-    found = list(kernels(jaxpr.jaxpr))
+    found = list(pallas_calls(jaxpr.jaxpr))
     assert sorted(name for name, _ in found) == [
         profiling.SSD_BWD, profiling.SSD_FWD, profiling.SSD_FWD]
     for name, stack in found:
@@ -455,6 +456,52 @@ ENTRY %main (x: f32[8]) -> f32[8] {
     assert scope.kernel == profiling.SSD_BWD and scope.phase == "backward"
     assert scope.module == "Transformer/layer_N/mamba/hvd_ssm_scan/" \
         + profiling.SSD_BWD
+
+
+def test_the_convs_kernels_are_launched_inside_the_mixers_conv_scope():
+    """At a length of a row tile the same tiny hybrid's convolution is the
+    kernels' too: a call a part (x, B, C) a pass, each named by its constant
+    and under ``hvd_ssm_conv``, where ``ssm_conv_ms`` finds them; the scan's
+    stay under ``hvd_ssm_scan``."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import Transformer, TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=64, num_layers=1, num_heads=2, head_dim=8, embed_dim=16,
+        mlp_dim=32, dtype=jnp.float32, remat=True, layer_types=("mamba",),
+        mamba_heads=2, mamba_head_dim=64, mamba_state_dim=128,
+        mamba_chunk=128)
+    model = Transformer(cfg)
+    tokens = jnp.zeros((1, 256), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    found = list(pallas_calls(jax.make_jaxpr(
+        jax.grad(lambda p: model.apply(p, tokens).sum()))(params).jaxpr))
+    conv = [(name, stack) for name, stack in found
+            if name in profiling.CAUSAL_CONV_PASSES]
+    assert sorted(name for name, _ in conv) == \
+        [profiling.CAUSAL_CONV_BWD] * 3 + [profiling.CAUSAL_CONV_FWD] * 6
+    for name, stack in conv:
+        assert stack.split("/")[-4:] == ["layer_0", "mamba",
+                                         profiling.SSM_CONV, name]
+    assert sum("rematted_computation" in stack for _, stack in conv) == 3
+    assert sorted(name for name, _ in found if name not in
+                  profiling.CAUSAL_CONV_PASSES) == [
+        profiling.SSD_BWD, profiling.SSD_FWD, profiling.SSD_FWD]
+    assert not set(profiling.CAUSAL_CONV_PASSES) & set(
+        profiling.FLASH_PASSES + profiling.SSD_PASSES)
+    text = """HloModule m
+ENTRY %main (x: f32[8]) -> f32[8] {
+  %x = f32[8]{0} parameter(0)
+  ROOT %hvd_causal_conv_fwd.7 = f32[8]{0} custom-call(%x), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(Transformer))/jvp(Transformer)/checkpoint/rematted_computation/layer_3/mamba/hvd_ssm_conv/jit(_forward)/hvd_causal_conv_fwd/pallas_call"}
+}
+"""
+    scope = profiling.scope_table(text)["hvd_causal_conv_fwd.7"]
+    assert scope.kernel == profiling.CAUSAL_CONV_FWD
+    assert scope.phase == "recompute"
+    assert scope.module == "Transformer/layer_N/mamba/hvd_ssm_conv/" \
+        + profiling.CAUSAL_CONV_FWD
 
 
 def host_events(logdir):
@@ -509,12 +556,13 @@ def test_every_name_of_the_vocabulary_is_written_once():
     names = [v for k, v in vars(profiling).items()
              if k.isupper() and isinstance(v, str) and v.startswith("hvd_")]
     # five of models/moe.py, one of ops/token_sum.py, four of models/mamba.py,
-    # two of ops/ssd_scan.py, ten of serving/engine.py, four of
+    # two of ops/ssd_scan.py, two of ops/causal_conv.py, ten of
+    # serving/engine.py, four of
     # models/transformer.LatentAttention, two of EvaAttention, five of
     # models/kda.py, one of ops/kda_scan.py, one of ops/grouped_matmul.py,
     # one of ops/moe_rows.py, four of models/cca.py, four of models/hyper.py,
     # five start-up spans and the compile ledger's three
-    assert len(names) == len(set(names)) == 63
+    assert len(names) == len(set(names)) == 65
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     for directory, _, files in itertools.chain(
             os.walk(os.path.join(root, "horovod_tpu")),

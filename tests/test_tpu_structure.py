@@ -166,6 +166,54 @@ def test_the_scans_kernels_compile_wherever_their_rule_sends_them(
     assert kernels_of(2 * q, 32) == []
 
 
+@pytest.mark.parametrize("s,start,widths,taps,dtype", [
+    # granite-4.0-h-micro's mixer: x | B | C at column 4096 of 8512
+    (8192, 4096, (4096, 128, 128), 4, jnp.bfloat16),
+    (256, 0, (256,), 2, jnp.bfloat16),          # the rule's least tile
+    (1024, 128, (128, 512), 8, jnp.float32)])   # float32, the most taps
+def test_the_convs_kernels_compile_wherever_their_rule_sends_them(
+        one_chip_mesh, monkeypatch, s, start, widths, taps, dtype):
+    """``causal_conv_silu`` decides by shape alone whether the kernels run,
+    so what its rule admits has to be what the chip's compiler takes within
+    Mosaic's default VMEM: the cell's widths, the smallest tile, float32
+    with the most taps.  A call a part, forward and backward under their
+    names; the stream is an operand of the kernels as it stands (no copy, no
+    slice of it in the text); off the rule there is no kernel."""
+    from horovod_tpu.ops import causal_conv as cc
+    from horovod_tpu.utils import profiling
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, dtype, sharding=one_chip)
+    width = start + sum(widths) + 64
+
+    def compiled_text(s):
+        def loss(x, w, taps_, bias):
+            # the stream as a projection leaves it, not an entry parameter
+            stream = jnp.dot(x, w, preferred_element_type=dtype)
+            parts = cc.causal_conv_silu(stream, taps_, bias, start, widths)
+            return sum(jnp.square(part.astype(jnp.float32)).sum()
+                       for part in parts) \
+                + stream[..., :start].astype(jnp.float32).sum()
+        return jax.jit(jax.grad(loss, (0, 1, 2, 3))).lower(
+            shape(1, s, 128, dtype=dtype), shape(128, width, dtype=dtype),
+            shape(taps, sum(widths)), shape(sum(widths))).compile().as_text()
+
+    assert cc.conv_form(s, taps, start, widths) == "kernel"
+    text = compiled_text(s)
+    assert len(_kernels_named(text, profiling.CAUSAL_CONV_FWD)) \
+        == len(_kernels_named(text, profiling.CAUSAL_CONV_BWD)) \
+        == len(widths)
+    stream_shape = f"[1,{s},{width}]"
+    moved = [line for line in text.splitlines()
+             if re.search(r" (copy|slice)\(", line)
+             and stream_shape in line.split("=")[1]]
+    assert not moved, moved
+    assert cc.conv_form(s - 8, taps, start, widths) == "xla"
+    assert not _kernels_named(compiled_text(s - 8), "hvd_causal_conv")
+
+
 def _kernels_named(text: str, name: str) -> list[str]:
     """The ``op_name`` of every Mosaic custom call of ``text`` that holds
     ``name``."""
