@@ -147,7 +147,7 @@ def main():
                   f"({time.time() - t0:.1f}s)")
 
     # Every rank reports the globally-averaged final metric (identical by
-    # construction — multi-process CI asserts this, tests/test_examples.py).
+    # construction — multi-process CI asserts this, tests/test_examples_launched.py).
     final_loss = float(np.asarray(hvd.allreduce(
         jnp.asarray(0.0 if loss is None else float(loss)))))
     print(f"[rank {hvd.rank()}/{hvd.size()}] final loss={final_loss:.6f} "

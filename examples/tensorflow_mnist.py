@@ -84,7 +84,7 @@ def main():
             print(f"epoch {epoch}: loss={mean_loss:.4f}")
 
     # Every rank reports the globally-averaged final metric (identical by
-    # construction — multi-process CI asserts this, tests/test_examples.py).
+    # construction — multi-process CI asserts this, tests/test_examples_frameworks.py).
     print(f"[rank {hvd.rank()}/{hvd.size()}] final loss={mean_loss:.6f}",
           flush=True)
 

@@ -120,7 +120,7 @@ def main():
             print(f"epoch {epoch}: loss={float(loss):.4f}")
 
     # Every rank reports the globally-averaged final metric (identical by
-    # construction — multi-process CI asserts this, tests/test_examples.py).
+    # construction — multi-process CI asserts this, tests/test_examples_frameworks.py).
     final = hvd.allreduce(loss.detach() if loss is not None
                           else torch.zeros(()), average=True)
     print(f"[rank {hvd.rank()}/{hvd.size()}] final loss={float(final):.6f}",

@@ -30,6 +30,7 @@ from __future__ import annotations
 import atexit
 import dataclasses
 import os
+import sys
 import threading
 
 # jax is imported inside the functions that need it: the package root
@@ -224,7 +225,88 @@ def init(*, distributed: bool | None = None, coordinator_address: str | None = N
             _mesh.build_global_mesh(mesh_axes, cross_size=cross_size,
                                     devices=devices)
             _topology = topo
-    atexit.register(shutdown)  # reference common/__init__.py:69
+            if nproc > 1:
+                _note_exit_status()
+    atexit.register(_at_exit)  # reference common/__init__.py:69
+
+
+# ``sys.exit``'s argument once the interpreter's top level has taken it
+# (``_Leaving``), and the ``sys.exit`` that ``_note_exit_status`` wrapped.
+_exit_status = None
+_sys_exit = None
+
+
+class _Leaving(SystemExit):
+    """What ``sys.exit`` raises in the main thread of a ``jax.distributed``
+    process.  An exit handler is told neither the exit code nor that there
+    is one, so the exception notes its own when the interpreter's top level
+    has taken it: it is freed there, with no frame of Python running.  One
+    that was caught (a CLI's ``except SystemExit:``) is freed under the
+    frame that caught it and notes nothing, and the process goes on to the
+    exit it makes later.  ``raise SystemExit(n)`` and ``exit()`` are not
+    seen."""
+
+    def __del__(self):
+        global _exit_status
+        try:
+            sys._getframe(1)
+        except ValueError:
+            _exit_status = self.code
+
+
+def _note_exit_status() -> None:
+    global _sys_exit
+    if _sys_exit is not None:       # once, however often init() runs
+        return
+    _sys_exit = sys.exit
+
+    def exit(status=None):
+        if threading.current_thread() is threading.main_thread():
+            raise _Leaving(status)
+        _sys_exit(status)           # ends that thread, not the process
+
+    sys.exit = exit
+
+
+def _crash_code() -> int:
+    """The exit code of a process that is leaving by an uncaught exception
+    or ``sys.exit(<not 0>)``, else 0."""
+    crash = getattr(sys, "last_exc", None) or getattr(sys, "last_value", None)
+    # (an interactive session shows an exception and carries on)
+    if isinstance(crash, Exception) and not hasattr(sys, "ps1"):
+        return 1
+    if _exit_status is None or isinstance(_exit_status, int):
+        return (_exit_status or 0) & 0xFF
+    return 1
+
+
+def _at_exit() -> None:
+    """``shutdown`` at interpreter exit, and a crashed rank's way out.
+
+    jax's own exit handler runs after this one and waits at
+    ``jax.distributed``'s shutdown barrier until every process of the job
+    is there (five minutes by default): right for a job whose ranks finish
+    at different times, but a rank that has crashed would sit there, alive
+    to the launcher, while the others train on or wait in a collective for
+    it.  It leaves at once with its code instead, so that the launcher sees
+    the first abnormal exit when it happens and ends the job
+    (``run.py``).  ``os._exit`` runs none of the handlers registered before
+    ``init()``: the logging handlers, where a crashed rank's last lines
+    are, are flushed here, and what else a handler held is lost with the
+    rank."""
+    shutdown()
+    code = _crash_code()
+    if not code:
+        return
+    from jax._src import distributed
+
+    if distributed.global_state.client is not None:
+        import logging
+
+        logging.shutdown()
+        sys.stdout.flush()
+        sys.stderr.flush()
+        os._exit(code)
 
 
 def shutdown() -> None:

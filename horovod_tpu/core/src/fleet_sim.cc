@@ -394,11 +394,10 @@ void RunMux(MuxArgs a) {
             hvd::wire::ThreadCpuMicros() - b0, std::memory_order_relaxed);
       }
       if (!ok) {
-        if (a.plan == nullptr ||
-            (::close(m.fd), m.fd = -1,
-             !AttachMember(a, &m, true) ||
-                 !SendFrame(m.fd, FrameType::REQUEST, payload, Epoch16(),
-                            version))) {
+        // The aggregator died between two rounds and this member learns
+        // it from its send, not from an EOF while it waits: a reattach
+        // all the same, by the one path that counts it.
+        if (a.plan == nullptr || !ReattachResend(a, &m, payload)) {
           std::fprintf(stderr, "fleet_sim: member %d send failed\n", m.rank);
           a.shared->fail.store(true);
           return;

@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.models.transformer import RMSNorm, TransformerConfig
-from horovod_tpu.ops.causal_conv import causal_conv  # noqa: F401 (its old home)
 from horovod_tpu.ops.causal_conv import causal_conv_silu, conv_form
 from horovod_tpu.ops.ssd_scan import (carried_state_bytes, scan_form,
                                       ssd_scan)

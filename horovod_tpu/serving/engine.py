@@ -909,8 +909,10 @@ class PagedTransformerBackend:
     into the same dense ``[L, B, S, H, D]`` layout the plain backend
     uses, runs the identical model code, then scatters only the written
     positions back into their pages — so paging changes memory layout,
-    never arithmetic, and decode with the cache ON stays bit-exact vs a
-    cold dense prefill (pinned in tests/test_serving.py).  Shapes are
+    never arithmetic, and decode with the cache ON stays bit-exact vs
+    the same engine's cold prefill (pinned in tests/test_serving.py; vs
+    the dense backend, whose prefill reduces over a bucket's keys and
+    not a slot's extent, the tokens and the logits to 1e-5).  Shapes are
     still fixed by the slot count and bucket menu: the gather/scatter
     indices are data, not shape, so the compile cache stays the same
     small finite set.

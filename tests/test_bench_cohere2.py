@@ -9,7 +9,6 @@ that the tier-1 run holds them."""
 
 import json
 import os
-import subprocess
 import sys
 import types
 
@@ -21,6 +20,9 @@ if ROOT not in sys.path:
 
 from benchmarks import flops_cohere2  # noqa: E402
 from benchmarks.run import load_module  # noqa: E402
+
+from _rehearse import (assert_the_altered_record_is_not_correct,  # noqa: E402
+                       walk)
 
 CELL = "cmdaplus-code8k-open"
 TINY = {"family": "cohere2_moe_serve", "model_type": "cohere2_moe",
@@ -246,54 +248,15 @@ def test_the_new_readers_read_a_hand_made_run_and_nothing_without_it():
         assert reader(stem).read(run) is None, stem
 
 
-def rehearse(tmp_path, tag, env_extra=None):
-    base = tmp_path / tag
-    (base / "configs").mkdir(parents=True)
-    (base / "traffic").mkdir()
-    (base / "configs" / "tiny-cohere2.json").write_text(json.dumps(TINY))
-    (base / "traffic" / "tiny-open.json").write_text(json.dumps(TRAFFIC))
-    real = manifest()
-    m = {"command": real["command"], "paths": ["."], "run_seconds": 3,
-         "configs": [{"name": "tiny-cohere2", "source": "toy", "reduced": [],
-                      "file": "configs/tiny-cohere2.json",
-                      "why": "rehearsal"}],
-         "workloads": [{"name": "tiny-cohere2-1", "config": "tiny-cohere2",
-                        "traffic": "tiny-open", "chips": 1,
-                        "why": "rehearsal"}],
-         **{g: [{k: v for k, v in e.items() if k != "workloads"}
-                for e in real[g]
-                if "workloads" not in e or CELL in e["workloads"]]
-            for g in ("end_to_end", "per_layer")}}
-    (base / "BENCHMARK.json").write_text(json.dumps(m))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=1",
-               **(env_extra or {}))
-    proc = subprocess.run(
-        [sys.executable, "-c",
-         "import os, runpy, sys; sys.argv[0] = 'benchmarks/run.py'\n"
-         "if os.environ.get('ALTER_A_SERVED_TOKEN'):\n"
-         "    from horovod_tpu.serving.engine import ServingEngine\n"
-         "    take = ServingEngine._take_token\n"
-         "    def altered(self, req, slot, token, *a, **k):\n"
-         "        if len(req.tokens) == 2:\n"
-         "            token = (token + 101) % 256\n"
-         "        return take(self, req, slot, token, *a, **k)\n"
-         "    ServingEngine._take_token = altered\n"
-         "runpy.run_path('benchmarks/run.py', run_name='__main__')",
-         "--manifest", str(base / "BENCHMARK.json"), "--workload",
-         "tiny-cohere2-1", "--seed", str(2**31 + 7), "--seconds", "3",
-         "--trace", "1", "--out", str(tmp_path / "out"),
-         "--rehearse-on-cpu"],
-        env=env, cwd=ROOT, capture_output=True, text=True, timeout=900)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    last = proc.stdout.strip().splitlines()[-1]
-    marker = "REHEARSAL on cpu, no result: "
-    assert last.startswith(marker), last
-    return json.loads(last[len(marker):]), proc.stdout
+@pytest.fixture(scope="module")
+def walked(tmp_path_factory):
+    """The file's one walk of the tiny cell (``tests/_rehearse.py``)."""
+    return walk(tmp_path_factory.mktemp("walk"), "tiny-cohere2", TINY, TRAFFIC,
+                CELL)
 
 
-def test_a_tiny_cell_walks_serving_py_on_the_cpu(tmp_path):
-    result, stdout = rehearse(tmp_path, "sound")
+def test_a_tiny_cell_walks_serving_py_on_the_cpu(walked):
+    result, _, stdout = walked
     assert result["correct"], stdout[-3000:]
     assert result["failed"] == 0 and result["attempted"] >= 5
     names = set(result["metrics"])
@@ -317,9 +280,77 @@ def test_a_tiny_cell_walks_serving_py_on_the_cpu(tmp_path):
     assert checks[0]["longest"] > 24
 
 
-def test_an_altered_served_token_is_not_correct(tmp_path):
-    result, stdout = rehearse(tmp_path, "altered",
-                              {"ALTER_A_SERVED_TOKEN": "1"})
-    assert not result["correct"], stdout[-3000:]
-    gap, limit = result["compared"]["served_token_gap_below_reference_best"]
-    assert gap > limit
+def test_an_altered_served_token_is_not_correct(walked):
+    assert_the_altered_record_is_not_correct(walked)
+
+
+@pytest.mark.parametrize("altered", [False, True])
+def test_measure_says_of_a_run_what_its_comparison_says(
+        altered, tmp_path, monkeypatch):
+    """The walk above hands ``compare`` an altered record; this is the rest
+    of the line, in this process and with no model: ``serving.measure``
+    drives an engine over the token automaton, the ENGINE serves one wrong
+    token, and the harness's own sample of finished requests, judged by a
+    comparison that replays the automaton, makes the run not ``correct``
+    and puts the gap beside its limit in ``compared``."""
+    import argparse
+
+    import numpy as np
+
+    from benchmarks import serving
+    from benchmarks.built import Served
+    from horovod_tpu.serving.engine import (ServingConfig, ServingEngine,
+                                            StubBackend)
+
+    backend = StubBackend(3)
+
+    def replayed(prompt) -> list[int]:
+        padded = np.asarray(prompt)[None]
+        return [backend.prefill(padded, padded.shape[1], 0)[0]]
+
+    def compare(finished, seed):
+        gap = 0
+        for prompt, tokens in finished:
+            want = replayed(prompt)
+            while len(want) < len(tokens):
+                want.append(backend._next_tok(want[-1],
+                                              len(prompt) + len(want)))
+            gap += sum(int(a != b) for a, b in zip(want, tokens))
+        return [{"name": "served_token_gap_below_reference_best",
+                 "error": float(gap), "tolerance": 0.5, "ok": gap < 0.5,
+                 "requests": len(finished)}]
+
+    def serve(config, traffic, chips, seed):
+        engine = ServingEngine(
+            serving.Timed(backend),
+            ServingConfig(num_slots=3, buckets=(16, 32, 64), max_seq_len=96,
+                          eos_id=None), clock=serving.clock)
+        return Served(
+            engine=engine, warm=lambda: None, release=lambda: None,
+            compare=compare, vocab_size=256, parameters=0, num_slots=3,
+            kv_bytes_per_token=0, program_names={},
+            decode_scopes=lambda: None)
+
+    if altered:
+        take = ServingEngine._take_token
+
+        def wrong(self, req, slot, token, *a, **k):
+            if len(req.tokens) == 2:
+                token = (token + 101) % 256
+            return take(self, req, slot, token, *a, **k)
+
+        monkeypatch.setattr(ServingEngine, "_take_token", wrong)
+    harness = types.SimpleNamespace(
+        args=argparse.Namespace(seed=7, seconds=1.0, trace=0,
+                                out=str(tmp_path)),
+        cell={"name": "stub-1"}, config={"family": "stub"},
+        traffic=dict(TRAFFIC, rate=20.0, drain_s=5), chips=1,
+        family=types.SimpleNamespace(serve=serve), peaks=None,
+        compile_events=[], devices=[None],
+        dev=types.SimpleNamespace(platform="cpu", device_kind="cpu"))
+    outcome = serving.measure(harness)
+    assert outcome.attempted >= 5 and outcome.failed == 0
+    gap, limit = outcome.compared["served_token_gap_below_reference_best"]
+    assert outcome.correct is not altered
+    assert (gap > limit) is altered
+    assert outcome.compared["rejected"] == [0, 0]

@@ -10,7 +10,6 @@ not under ``benchmarks/tests``, so that the tier-1 run holds them."""
 
 import json
 import os
-import subprocess
 import sys
 import types
 
@@ -22,6 +21,9 @@ if ROOT not in sys.path:
 
 from benchmarks import flops_eva  # noqa: E402
 from benchmarks.run import load_cell, load_module  # noqa: E402
+
+from _rehearse import (assert_the_altered_record_is_not_correct,  # noqa: E402
+                       walk)
 
 CELL = "evabyte-code32k-open"
 TINY = {"family": "eva_serve", "model_type": "evabyte",
@@ -311,56 +313,15 @@ def test_the_new_readers_read_a_hand_made_run_and_nothing_without_it():
         assert reader(stem).read(run) is None, stem
 
 
-def rehearse(tmp_path, tag, env_extra=None):
-    """A manifest of one tiny cell beside files of its own names: the
-    harness finds the family, the reference, the traffic and the readers by
-    name, as it finds the real cell's."""
-    base = tmp_path / tag
-    (base / "configs").mkdir(parents=True)
-    (base / "traffic").mkdir()
-    (base / "configs" / "tiny-eva.json").write_text(json.dumps(TINY))
-    (base / "traffic" / "tiny-open.json").write_text(json.dumps(TRAFFIC))
-    real = manifest()
-    m = {"command": real["command"], "paths": ["."], "run_seconds": 3,
-         "configs": [{"name": "tiny-eva", "source": "toy", "reduced": [],
-                      "file": "configs/tiny-eva.json", "why": "rehearsal"}],
-         "workloads": [{"name": "tiny-eva-1", "config": "tiny-eva",
-                        "traffic": "tiny-open", "chips": 1,
-                        "why": "rehearsal"}],
-         **{g: [{k: v for k, v in e.items() if k != "workloads"}
-                for e in real[g]
-                if "workloads" not in e or CELL in e["workloads"]]
-            for g in ("end_to_end", "per_layer")}}
-    (base / "BENCHMARK.json").write_text(json.dumps(m))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=1",
-               **(env_extra or {}))
-    proc = subprocess.run(
-        [sys.executable, "-c",
-         "import os, runpy, sys; sys.argv[0] = 'benchmarks/run.py'\n"
-         "if os.environ.get('ALTER_A_SERVED_TOKEN'):\n"
-         "    from horovod_tpu.serving.engine import ServingEngine\n"
-         "    take = ServingEngine._take_token\n"
-         "    def altered(self, req, slot, token, *a, **k):\n"
-         "        if len(req.tokens) == 2:\n"
-         "            token = (token + 17) % 40\n"
-         "        return take(self, req, slot, token, *a, **k)\n"
-         "    ServingEngine._take_token = altered\n"
-         "runpy.run_path('benchmarks/run.py', run_name='__main__')",
-         "--manifest", str(base / "BENCHMARK.json"), "--workload",
-         "tiny-eva-1", "--seed", str(2**31 + 7), "--seconds", "3",
-         "--trace", "1", "--out", str(tmp_path / "out"),
-         "--rehearse-on-cpu"],
-        env=env, cwd=ROOT, capture_output=True, text=True, timeout=900)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    last = proc.stdout.strip().splitlines()[-1]
-    marker = "REHEARSAL on cpu, no result: "
-    assert last.startswith(marker), last
-    return json.loads(last[len(marker):]), proc.stdout
+@pytest.fixture(scope="module")
+def walked(tmp_path_factory):
+    """The file's one walk of the tiny cell (``tests/_rehearse.py``)."""
+    return walk(tmp_path_factory.mktemp("walk"), "tiny-eva", TINY, TRAFFIC,
+                CELL, alter=(17, 40))
 
 
-def test_a_tiny_cell_walks_serving_py_on_the_cpu(tmp_path):
-    result, stdout = rehearse(tmp_path, "sound")
+def test_a_tiny_cell_walks_serving_py_on_the_cpu(walked):
+    result, _, stdout = walked
     assert result["correct"], stdout[-3000:]
     assert result["failed"] == 0 and result["attempted"] >= 5
     names = set(result["metrics"])
@@ -388,16 +349,21 @@ def test_a_tiny_cell_walks_serving_py_on_the_cpu(tmp_path):
     assert checks[0]["requests"] == 4 and checks[0]["longest"] > 32
 
 
-def test_an_altered_served_byte_is_not_correct(tmp_path):
-    result, stdout = rehearse(tmp_path, "altered",
-                              {"ALTER_A_SERVED_TOKEN": "1"})
-    assert not result["correct"], stdout[-3000:]
-    gap, limit = result["compared"]["served_token_gap_below_reference_best"]
-    assert gap > limit
+def test_an_altered_served_byte_is_not_correct(walked):
+    assert_the_altered_record_is_not_correct(walked)
+
+
+@pytest.fixture(scope="module")
+def control_family():
+    """The family loaded once for the control's three seeds: its reference's
+    programs (``_PROGRAMS``: a layer, the head, a dtype each) are keyed on
+    shapes and numbers and not on the seed, whose weights are arguments, so
+    the second and third seeds compile nothing."""
+    return load_module("families", "eva_serve")
 
 
 @pytest.mark.parametrize("seed", [1, 2, 2**31 + 6])
-def test_the_float8_control_fails_the_comparison(seed):
+def test_the_float8_control_fails_the_comparison(seed, control_family):
     """The reference with float8 operands put in the program's place and
     judged by the run's own comparison and limit is not correct; the
     reference's own first choices, judged the same way, are (gap 0).  The
@@ -405,7 +371,7 @@ def test_the_float8_control_fails_the_comparison(seed):
     import jax.numpy as jnp
     import numpy as np
 
-    family = load_module("families", "eva_serve")
+    family = control_family
     cfg = dict(TINY, num_hidden_layers=12)
     traffic = dict(TRAFFIC, compare_requests=8)
     rng = np.random.default_rng(seed % 2**31)

@@ -438,10 +438,13 @@ def test_a_float8_product_is_another_result():
     params = {"embed_tokens": rnd(13, 256, 64), "norm": jnp.ones(64),
               "layers": [mamba, attention, mamba]}
     tokens = jax.random.randint(key, (48,), 0, 256)
-    loss, grads = reference.loss_and_grads(params, tokens, TINY)
+    # each form traced once and run as one program: eagerly every product
+    # of the reference and of its gradient is a program of its own
+    under = lambda **kw: jax.jit(lambda p, t: reference.loss_and_grads(  # noqa: E731
+        p, t, TINY, **kw))(params, tokens)
+    loss, grads = under()
     for low in (jnp.bfloat16, jnp.float8_e4m3fn):
-        lo_loss, lo_grads = reference.loss_and_grads(params, tokens, TINY,
-                                                     operand_dtype=low)
+        lo_loss, lo_grads = under(operand_dtype=low)
         worst = compare.check_tree("g", lo_grads, grads, 1.0)["error"]
         if low == jnp.bfloat16:
             assert worst < 0.05

@@ -70,20 +70,21 @@ def test_the_block_causal_forward_is_the_references(small):
     """Logits of a whole sequence, per-head QK-norm and all: float32 both
     sides, so the tolerance is summation order (1e-4 of the logits'
     spread); the causal mask in the mask's place is far outside it."""
+    import jax
     import jax.numpy as jnp
 
     from horovod_tpu.models import Transformer
 
     _, reference, mcfg, weights, params, ids = small
-    got = Transformer(mcfg).apply(params, ids[None])[0]
+    got = jax.jit(Transformer(mcfg).apply)(params, ids[None])[0]
     x, keys, _ = reference.sequence(weights, jnp.asarray(ids), TINY, 4,
                                     query_block=4)
     want = reference.head(x, weights, TINY)
     spread = float(jnp.std(want))
     assert float(jnp.abs(got - want).max()) < 1e-4 * spread
     assert keys.shape == (2, 20, 2, 16)
-    causal = Transformer(dataclasses.replace(
-        mcfg, attention_block=None)).apply(params, ids[None])[0]
+    causal = jax.jit(Transformer(dataclasses.replace(
+        mcfg, attention_block=None)).apply)(params, ids[None])[0]
     assert float(jnp.abs(causal - want).max()) > 0.05 * spread
 
 

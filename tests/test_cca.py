@@ -2,10 +2,9 @@
 state on the serving path (PR 52): the mixer and a whole model of "cca"
 layers against the plain reference (``benchmarks/reference/
 cca_moe_serve.py``), in float32 so that the tolerances are rounding's and
-not bfloat16's -- without a cache; prefill then decode through
-``ServingEngine`` (prompts of 1, 2 and 3 positions, before the tail is full,
-and one that ends inside a bucket); the one-loop prefill over row blocks
-against the one-piece form; the value shift and both convolutions at
+not bfloat16's -- without a cache (prefill then decode through
+``ServingEngine`` and the one-loop prefill over row blocks against the
+one-piece form are ``tests/test_cca_served.py``'s); the value shift and both convolutions at
 position 0 and across a row-block boundary; the q-k mean at 8 over 2; the
 router's state through three layers whole, by row blocks and a position a
 step; top-1 with a pick bias that changes the pick and not the gate; and
@@ -30,7 +29,6 @@ from horovod_tpu.models import Transformer  # noqa: E402
 from horovod_tpu.models import transformer as T  # noqa: E402
 from horovod_tpu.models.cca import CCAMixer, cca_sizes  # noqa: E402
 from horovod_tpu.models.moe import MOE_STATS, MoEMLP  # noqa: E402
-from horovod_tpu.serving import ServingConfig, ServingEngine  # noqa: E402
 from horovod_tpu.serving.engine import (PagedTransformerBackend,  # noqa: E402
                                         TransformerBackend)
 
@@ -109,106 +107,9 @@ def test_the_mixer_is_the_references_layer(built):
 def test_a_forward_pass_is_the_references(built):
     cfg, mcfg, model, weights, params = built
     tokens = np.random.default_rng(0).integers(0, 256, 45)
-    ours = model.apply(params, jnp.asarray(tokens)[None])[0]
+    ours = jax.jit(model.apply)(params, jnp.asarray(tokens)[None])[0]
     np.testing.assert_allclose(ours, reference_logits(cfg, weights, tokens),
                                atol=2e-4)
-
-
-def engine_of(built, slots=3):
-    cfg, mcfg, model, weights, params = built
-    backend = TransformerBackend(model, params, mcfg, slots, 128)
-    return backend, ServingEngine(backend, ServingConfig(
-        num_slots=slots, buckets=(16, 32, 64), max_seq_len=128, eos_id=None,
-        record_logits=True))
-
-
-@pytest.mark.parametrize("n", [1, 2, 3, 21])
-def test_prefill_then_decode_is_the_full_forward(built, n):
-    """Through ServingEngine, logits and not tokens.  A prompt of 1, 2 or 3
-    positions hands over a tail that is not full (zeros stand before
-    position 0); one of 21 ends inside its bucket of 32, and the tail is the
-    one at 21, not at the bucket's end.  Float32 throughout: 3e-4 is the
-    forward pass's own tolerance; a bfloat16 tail or router would miss it by
-    two orders."""
-    cfg, mcfg, model, weights, params = built
-    backend, engine = engine_of(built)
-    prompt = [int(t) for t in np.random.default_rng(n).integers(0, 256, n)]
-    req = engine.submit(prompt, 9)
-    engine.run_until_idle()
-    whole = reference_logits(cfg, weights, prompt + req.tokens)
-    for i, logits in enumerate(req.logits):
-        np.testing.assert_allclose(logits, whole[n - 1 + i], atol=3e-4)
-        assert req.tokens[i] == int(jnp.argmax(whole[n - 1 + i]))
-    # top-1 in 3 layers: a pair a position a layer; 8 decode steps of a slot
-    assert backend.moe_counters["pairs"] == (n + 8) * 3
-    assert backend.moe_counters["held_pairs"] == (n + 8) * 3
-    # (the ring is the process's: every engine's records)
-    decode = engine.span_summary()["hvd_srv_decode"]
-    # a live slot touches one expert a layer a step
-    assert decode["moe"]["experts_touched"] >= 8 * 3
-
-
-def test_a_slot_admitted_anew_and_an_idle_slot_beside_a_live_one(built):
-    """One slot serves three requests in turn (each admission starts from
-    its prefill's tail alone), with two idle slots decoding beside it; a
-    fresh engine with every slot busy gives each the same logits."""
-    rng = np.random.default_rng(2)
-    prompts = [[int(t) for t in rng.integers(0, 256, n)]
-               for n in (13, 30, 2)]
-    _, one_by_one = engine_of(built)
-    alone = []
-    for p in prompts:
-        r = one_by_one.submit(p, 7)
-        one_by_one.run_until_idle()
-        assert r.slot == 0
-        alone.append((r.tokens, r.logits))
-    _, together = engine_of(built)
-    reqs = [together.submit(p, 7) for p in prompts]
-    together.run_until_idle()
-    assert sorted(r.slot for r in reqs) == [0, 1, 2]
-    for r, (tokens, logits) in zip(reqs, alone):
-        assert r.tokens == tokens
-        np.testing.assert_allclose(np.stack(r.logits), np.stack(logits),
-                                   atol=1e-5)
-
-
-def test_the_tail_handed_over_the_prompts_row_blocks(built):
-    """The served prefill's loop (three row blocks of 1024 and more): a
-    prompt that ends in the third block of a 4096 bucket gives, to the bit
-    where tests/test_kda.py asks a tolerance, what a pass over the prompt
-    alone gives: the rows below the prompt's end, the tail at its end (the
-    blocks past it are not run and their rows stay 0), the logits at its
-    last position."""
-    cfg, mcfg, model, weights, params = built
-    long = dataclasses.replace(mcfg, max_seq_len=4200)
-    model = Transformer(long)
-    n = 2100
-    tokens = jnp.asarray(np.random.default_rng(4).integers(0, 256, 4096))
-    padded = tokens.at[n:].set(0)[None]
-    kk, vv = T.init_kv_cache(long, 1, 4200)
-    told = dict(return_kv=True, lengths=jnp.array([n]),
-                valid=jnp.arange(4096)[None] < n,
-                logits_at=jnp.array([n - 1]))
-    looped, (k_loop, v_loop) = jax.jit(
-        lambda p, t: model.apply(p, t, kv_into=(kk, vv, 0), **told))(
-        params, padded)
-    assert T.row_blocks(4096) == 4
-    exact, (k_one, v_one) = jax.jit(lambda p, t: model.apply(
-        p, t, return_kv=True, logits_at=jnp.array([n - 1])))(
-        params, tokens[None, :n])
-    np.testing.assert_allclose(looped, exact, atol=3e-4)
-    for loop, one in ((k_loop, k_one), (v_loop, v_one)):
-        # position-wise but for the carried tail: the same sums in the same
-        # order, so the rows and the tail agree to the bit
-        np.testing.assert_array_equal(loop["cca"][0, 0, :n], one["cca"][0, 0])
-        np.testing.assert_array_equal(loop["cca_tail"][0, 0],
-                                      one["cca_tail"][0, 0])
-        np.testing.assert_allclose(loop["cca"][1:, 0, :n], one["cca"][1:, 0],
-                                   atol=3e-4)
-        np.testing.assert_allclose(loop["cca_tail"][:, 0],
-                                   one["cca_tail"][:, 0], atol=3e-4)
-        # the fourth block was never visited
-        assert not np.asarray(loop["cca"][:, 0, 3072:4096]).any()
 
 
 def test_the_shift_and_both_convolutions_at_the_start_and_across_blocks(
@@ -221,10 +122,11 @@ def test_the_shift_and_both_convolutions_at_the_start_and_across_blocks(
     mixer, params, w = mixer_of(built)
     h = jax.random.normal(jax.random.PRNGKey(1), (1, 1100, 32), F32)
     pos = jnp.arange(1100)[None]
-    whole, (k_all, v_all) = mixer.apply(params, h, pos, return_kv=True)
+    rows_of = jax.jit(lambda p, h, pos: mixer.apply(p, h, pos,
+                                                    return_kv=True))
+    whole, (k_all, v_all) = rows_of(params, h, pos)
     # position 0 alone is position 0 of the whole: nothing leaks backwards
-    alone, (k_0, v_0) = mixer.apply(params, h[:, :1], pos[:, :1],
-                                    return_kv=True)
+    alone, (k_0, v_0) = rows_of(params, h[:, :1], pos[:, :1])
     np.testing.assert_allclose(k_0["cca"][0, 0], k_all["cca"][0, 0],
                                atol=1e-6)
     np.testing.assert_allclose(
@@ -245,10 +147,11 @@ def test_the_shift_and_both_convolutions_at_the_start_and_across_blocks(
     first = {**rows, "cca_tail": k_first["cca_tail"][None]}
     second = {"cca": jnp.zeros((1, 1, 1100, 16), F32).at[0, :, :1024].set(
         v_first["cca"]), "cca_tail": v_first["cca_tail"][None]}
+    step = jax.jit(lambda p, h, pos, first, second, t: mixer.apply(
+        p, h, pos, cache=(first, second, t, 0)))
     for t in range(1024, 1030):
-        out, (first, second) = mixer.apply(
-            params, h[:, t:t + 1], pos[:, t:t + 1],
-            cache=(first, second, jnp.array([t]), 0))
+        out, (first, second) = step(params, h[:, t:t + 1], pos[:, t:t + 1],
+                                    first, second, jnp.array([t]))
         np.testing.assert_allclose(out[0, 0], whole[0, t], atol=2e-5)
         np.testing.assert_allclose(first["cca"][0, 0, t], k_all["cca"][0, t],
                                    atol=1e-5)
@@ -272,8 +175,8 @@ def test_the_qk_mean_at_eight_over_two():
         {**floated(family.draw_layer(cfg, False, jax.random.PRNGKey(5))),
          "cca": w}, cfg)["cca"]}
     h = jax.random.normal(jax.random.PRNGKey(2), (1, 9, 32), F32)
-    out, (k, _) = CCAMixer(mcfg).apply(params, h, jnp.arange(9)[None],
-                                       return_kv=True)
+    out, (k, _) = jax.jit(lambda p, h: CCAMixer(mcfg).apply(
+        p, h, jnp.arange(9)[None], return_kv=True))(params, h)
     np.testing.assert_allclose(out[0], reference_cca(cfg, w, h[0]),
                                atol=2e-5)
     q_plain = (h[0] @ w["q_proj"]).reshape(9, 2, 4, 8)

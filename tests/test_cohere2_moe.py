@@ -107,7 +107,7 @@ def test_full_forward_matches_the_reference(family, reference, share):
     model, _, params, w = setup(family, cfg)
     tokens = tokens_of(1)
     want = reference_logits(reference, w, tokens, cfg)
-    got = model.apply(params, tokens[None])[0]
+    got = jax.jit(model.apply)(params, tokens[None])[0]
     assert rel(got, want) < TOL
     # the reference in query blocks is the reference
     blocked = reference_logits(reference, w, tokens, cfg, query_block=8)
@@ -126,18 +126,25 @@ FAULTS = {
 }
 
 
-@pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_each_piece_fails_the_comparison_when_wrong(family, reference, fault):
+@pytest.fixture(scope="module")
+def sound(family, reference):
+    """The uncut model's draw, its tokens and the reference's logits of them:
+    the same for every fault below, which changes the program alone."""
     model, mcfg, params, w = setup(family, CFG)
     tokens = tokens_of(1)
-    want = reference_logits(reference, w, tokens, CFG)
+    return mcfg, params, tokens, reference_logits(reference, w, tokens, CFG)
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_each_piece_fails_the_comparison_when_wrong(sound, fault):
+    mcfg, params, tokens, want = sound
     wrong = Transformer(dataclasses.replace(mcfg, **FAULTS[fault]))
     if fault == "a sequential block":   # it has a second norm a layer
         params = jax.tree.map(lambda x: x, params)
         for i in range(4):
             params["params"][f"layer_{i}"]["mlp_norm"] = {
                 "scale": jnp.ones(32)}
-    assert rel(wrong.apply(params, tokens[None])[0], want) > 0.05
+    assert rel(jax.jit(wrong.apply)(params, tokens[None])[0], want) > 0.05
 
 
 def test_a_lower_precision_than_stated_fails(family, reference):
@@ -159,8 +166,8 @@ def test_prefill_then_decode_past_the_window(family, reference, share):
     tokens = tokens_of(2)
     want = reference_logits(reference, w, tokens, cfg)
     prompt = 12
-    logits, (pk, pv) = model.apply(params, tokens[None, :prompt],
-                                   return_kv=True)
+    logits, (pk, pv) = jax.jit(lambda p, t: model.apply(
+        p, t, return_kv=True))(params, tokens[None, :prompt])
     assert rel(logits[0], want[:prompt]) < TOL
     kk, vv = init_kv_cache(mcfg, 2, 64)
     kk = kk.at[:, 1, :prompt].set(pk[:, 0])     # slot 1; slot 0 stays empty

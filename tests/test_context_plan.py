@@ -15,6 +15,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from _one_program import shard_map
+
 from horovod_tpu.models.transformer import dense_causal_attention
 from horovod_tpu.ops.schedule_plan import ContextWorkload, plan_context
 from horovod_tpu.parallel import (
@@ -167,7 +169,7 @@ def _plan_path_out(plan, q, k, v, causal=True):
     mesh = Mesh(np.array(jax.devices()[:plan.width]), ("sp",))
     attn = context_attention_fn("sp", plan)
     qp, kp, vp = (shard_sequence(x, plan) for x in (q, k, v))
-    out = jax.shard_map(
+    out = shard_map(
         lambda q, k, v: attn(q, k, v, causal=causal), mesh=mesh,
         in_specs=P(None, "sp"), out_specs=P(None, "sp"),
         check_vma=False)(qp, kp, vp)
@@ -234,7 +236,7 @@ def test_plain_causal_skips_masked_steps_exactly(hvd):
             block_q=plan.block_q, block_k=plan.block_k)
         return out, steps[None]
 
-    out, steps = jax.shard_map(
+    out, steps = shard_map(
         f, mesh=mesh, in_specs=(P(None, "sp"),) * 3,
         out_specs=(P(None, "sp"), P("sp")), check_vma=False)(q, k, v)
     # Rank r attends K shards 0..r only: r+1 kernels, never the full ring.

@@ -82,7 +82,11 @@ def _drain(procs, timeout):
 # Engine-only elastic worker: streams allreduces; on MembershipChanged it
 # reconfigures in place and resynchronizes its name counter through the
 # shared epoch (real training resynchronizes through the checkpoint step —
-# see the elastic_loop test below).  argv: rank port nprocs [total]
+# see the elastic_loop test below).  argv: rank port nprocs [total [epoch]]
+# With an epoch the stream also runs on until the membership has changed
+# that often (or the job has aborted): a test that kills a rank once the
+# job is STEADY then finds it still running, however late the test's own
+# process is given the CPU; 25 steps of 2 ms are no time to count on.
 ELASTIC_WORKER = textwrap.dedent("""
     import os, sys, time
     import numpy as np
@@ -94,13 +98,14 @@ ELASTIC_WORKER = textwrap.dedent("""
 
     rank, port, n = int(sys.argv[1]), int(sys.argv[2]), int(sys.argv[3])
     total = int(sys.argv[4]) if len(sys.argv) > 4 else 30
+    until_epoch = int(sys.argv[5]) if len(sys.argv) > 5 else 0
     eng = NativeEngine(rank, n, executor=local_executor,
                        coordinator_host="127.0.0.1", coordinator_port=port,
                        cycle_time_ms=2.0)
     elastic.attach(eng)
     pid = os.getpid()
     i, done = 0, 0
-    while done < total:
+    while done < total or eng.epoch < until_epoch:
         try:
             h = eng.enqueue(f"s{i}", np.ones(8, np.float32), OP_ALLREDUCE)
             eng.synchronize(h, timeout_s=120.0)
@@ -140,7 +145,7 @@ def test_shrink_in_place_reassigns_ranks_no_process_restart():
     contiguous re-assigned ranks (old rank 2 -> new rank 1), the epoch
     bumps to 1, collectives resume, and — the point of the PR — both
     survivors finish in the SAME process (pid unchanged, exit 0)."""
-    procs, _ = _spawn(ELASTIC_WORKER, 3, {})
+    procs, _ = _spawn(ELASTIC_WORKER, 3, {}, args=(30, 1))
     try:
         deadline = time.monotonic() + scaled(60)
         heads = [_wait_steady(p, deadline) for p in procs]
@@ -173,7 +178,7 @@ def test_coordinator_death_promotes_standby_in_place():
     succession verdict — the default standby (rank 1) re-binds its
     pre-announced port as the NEW rank 0, old rank 2 renumbers to 1, the
     epoch bumps, and both survivors finish in the SAME process."""
-    procs, _ = _spawn(ELASTIC_WORKER, 3, {})
+    procs, _ = _spawn(ELASTIC_WORKER, 3, {}, args=(30, 1))
     try:
         deadline = time.monotonic() + scaled(60)
         heads = [_wait_steady(p, deadline) for p in procs]
@@ -207,7 +212,8 @@ def test_standby_env_override_promotes_named_rank():
     """HVD_TPU_STANDBY=2 pins the succession: rank 2 (not the default
     lowest rank 1) is promoted to coordinator; rank 1 fills new rank 1 by
     the deterministic old-rank-order remap."""
-    procs, _ = _spawn(ELASTIC_WORKER, 3, {"HVD_TPU_STANDBY": "2"})
+    procs, _ = _spawn(ELASTIC_WORKER, 3, {"HVD_TPU_STANDBY": "2"},
+                      args=(30, 1))
     try:
         deadline = time.monotonic() + scaled(60)
         heads = [_wait_steady(p, deadline) for p in procs]
@@ -283,7 +289,8 @@ def test_min_size_floor_keeps_legacy_full_restart_path():
     """HVD_TPU_MIN_SIZE=2 with 2 processes: the shrink to 1 would cross
     the floor, so the legacy coordinated abort applies — survivor exits 75
     with a failure report naming the dead rank, and no RECONFIG fires."""
-    procs, _ = _spawn(ELASTIC_WORKER, 2, {"HVD_TPU_MIN_SIZE": "2"})
+    procs, _ = _spawn(ELASTIC_WORKER, 2, {"HVD_TPU_MIN_SIZE": "2"},
+                      args=(30, 1))
     try:
         deadline = time.monotonic() + scaled(60)
         heads = [_wait_steady(p, deadline) for p in procs]

@@ -65,7 +65,7 @@ def built(family):
     params = family.to_program(weights, TINY)
     model = Transformer(mcfg)
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, MAX_LEN), 0, 20)
-    full = np.asarray(model.apply(params, tokens))[0]
+    full = np.asarray(jax.jit(model.apply)(params, tokens))[0]
     return mcfg, model, params, weights, tokens, full
 
 
@@ -240,7 +240,7 @@ def test_the_engine_serves_bytes_from_head_zero(built):
     req = engine.submit(prompt, W + 6)
     engine.run_until_idle()
     seq = jnp.asarray([prompt + req.tokens])
-    want = np.asarray(model.apply(params, seq))[0, len(prompt) - 1:-1]
+    want = np.asarray(jax.jit(model.apply)(params, seq))[0, len(prompt) - 1:-1]
     assert np.asarray(req.logits).shape == (W + 6, 8 * 20)
     assert req.tokens == [int(t) for t in want[:, :20].argmax(-1)]
     assert all(t < 20 for t in req.tokens)

@@ -195,7 +195,7 @@ def test_the_layers_held_and_the_pool_of_two_kinds(built):
 def test_a_forward_pass_is_the_references(built):
     cfg, mcfg, model, weights, params = built
     tokens = np.random.default_rng(0).integers(0, 256, 45)
-    ours = model.apply(params, jnp.asarray(tokens)[None])[0]
+    ours = jax.jit(model.apply)(params, jnp.asarray(tokens)[None])[0]
     np.testing.assert_allclose(ours, reference_logits(cfg, weights, tokens),
                                atol=2e-4)
 
@@ -256,8 +256,18 @@ def test_a_state_carried_over_the_prompts_row_blocks(built):
     """The served prefill's loop (three row blocks of 1024 and more): a
     prompt that ends in the third block of a 4096 bucket hands over the
     state a pass over the prompt alone gives, the blocks past it not run."""
-    cfg, mcfg, model, weights, params = built
-    long = dataclasses.replace(mcfg, max_seq_len=4200)
+    # published layers 1 and 2 alone, a layer of each kind (the first dense,
+    # the second sparse): the pool of two kinds crosses the row blocks as the
+    # four layers' does, and the loops of two layers are traced, not of four
+    cfg = dict(TINY, num_hidden_layers=2, layers_held=[1, 3])
+    long = dataclasses.replace(
+        family.model_config(cfg, TRAFFIC), dtype=jnp.float32,
+        param_dtype=jnp.float32, max_seq_len=4200)
+    assert long.layer_kinds == ("kda", "latent_attention")
+    assert long.cache_layout == (("kda", 0), ("latent", 0))
+    assert long.first_dense_layers == 1 and long.num_experts
+    params = {"params": {k: v for k, v in built[4]["params"].items()
+                         if k not in ("layer_2", "layer_3")}}
     model = Transformer(long)
     n = 2100
     tokens = jnp.asarray(np.random.default_rng(4).integers(0, 256, 4096))

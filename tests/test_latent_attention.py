@@ -120,7 +120,7 @@ def test_full_forward_matches_the_reference(family, reference, share):
     model, mcfg, params, w = setup(family, cfg)
     tokens = tokens_of(1)
     want = reference_logits(reference, w, tokens, cfg)
-    assert rel(model.apply(params, tokens[None])[0], want) < TOL
+    assert rel(jax.jit(model.apply)(params, tokens[None])[0], want) < TOL
     # the reference in query blocks is the reference
     blocked = reference_logits(reference, w, tokens, cfg, query_block=8)
     assert rel(blocked, want) < 1e-5
@@ -135,11 +135,13 @@ def test_full_forward_matches_the_reference(family, reference, share):
         assert moe["shared_gate"].shape == (32, 16)
         assert moe["router"].shape == (32, 16)
     assert jax.tree.map(jnp.shape, params) == jax.tree.map(
-        jnp.shape, model.init(jax.random.PRNGKey(0), tokens[None]))
+        lambda x: x.shape,
+        jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens[None]))
     # the feed-forward a chunk at a time, and the head on one position
     chunked = Transformer(dataclasses.replace(mcfg, feed_forward_chunk=16))
-    assert rel(chunked.apply(params, tokens[None])[0], want) < TOL
-    at = chunked.apply(params, tokens[None], logits_at=jnp.array([29]))
+    assert rel(jax.jit(chunked.apply)(params, tokens[None])[0], want) < TOL
+    at = jax.jit(chunked.apply)(params, tokens[None],
+                                logits_at=jnp.array([29]))
     assert at.shape == (1, 64) and rel(at[0], want[29]) < TOL
 
 
@@ -155,17 +157,25 @@ FAULTS = {
 }
 
 
-@pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_each_piece_fails_the_comparison_when_wrong(family, reference, fault):
+@pytest.fixture(scope="module")
+def sound(family, reference):
+    """The uncut model's draw, its tokens and the reference's logits of them:
+    the same for every fault below, which changes the program alone."""
     model, mcfg, params, w = setup(family, CFG)
     tokens = tokens_of(1)
-    want = reference_logits(reference, w, tokens, CFG)
+    return mcfg, params, tokens, reference_logits(reference, w, tokens, CFG)
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_each_piece_fails_the_comparison_when_wrong(sound, fault):
+    mcfg, params, tokens, want = sound
     wrong = Transformer(dataclasses.replace(mcfg, **FAULTS[fault]))
     if fault == "no dense layer's width":
         with pytest.raises(Exception, match="shape|Shape"):
             wrong.apply(params, tokens[None])
         return
-    assert rel(wrong.apply(params, tokens[None])[0], want) > 0.02, fault
+    assert rel(jax.jit(wrong.apply)(params, tokens[None])[0], want) > 0.02, \
+        fault
 
 
 def test_a_lower_precision_than_stated_fails(family, reference):
@@ -189,8 +199,8 @@ def test_prefill_then_decode_through_the_latent_cache(family, reference,
     tokens = tokens_of(2)
     want = reference_logits(reference, w, tokens, cfg)
     prompt = 12
-    logits, (lat, rk) = model.apply(params, tokens[None, :prompt],
-                                    return_kv=True)
+    logits, (lat, rk) = jax.jit(lambda p, t: model.apply(
+        p, t, return_kv=True))(params, tokens[None, :prompt])
     assert rel(logits[0], want[:prompt]) < TOL
     assert lat.shape == (3, 1, prompt, 8) and rk.shape == (3, 1, prompt, 4)
     kk, vv = init_kv_cache(mcfg, 2, 64)
@@ -218,14 +228,15 @@ def test_absorbed_is_expanded_on_the_same_weights(family):
     the logits of the pass without a cache (expanded: K and V built)."""
     model, mcfg, params, _ = setup(family, CFG)
     tokens = tokens_of(3)
-    expanded = model.apply(params, tokens[None])
+    expanded = jax.jit(model.apply)(params, tokens[None])
     kk, vv = init_kv_cache(mcfg, 1, 64)
-    absorbed, (kk, vv) = model.apply(params, tokens[None],
-                                     kv_cache=(kk, vv),
-                                     lengths=jnp.array([0]))
+    absorbed, (kk, vv) = jax.jit(lambda p, t, kk, vv: model.apply(
+        p, t, kv_cache=(kk, vv), lengths=jnp.array([0])))(
+        params, tokens[None], kk, vv)
     assert rel(absorbed, expanded) < 1e-5
     # and the block left in the pool what a prefill hands back
-    _, (lat, rk) = model.apply(params, tokens[None], return_kv=True)
+    _, (lat, rk) = jax.jit(lambda p, t: model.apply(p, t, return_kv=True))(
+        params, tokens[None])
     assert rel(kk[:, :, :S], lat) < 1e-5 and rel(vv[:, :, :S], rk) < 1e-5
 
 

@@ -54,13 +54,27 @@ def test_vgg16_train_step_finite_grads():
     assert all(jnp.all(jnp.isfinite(g)) for g in jax.tree.leaves(grads))
 
 
-def test_inception_v3_forward_shape():
-    model = InceptionV3(num_classes=10, dtype=jnp.float32)
+@pytest.fixture(scope="module")
+def inception():
+    """One draw of Inception-v3's weights for the two cases that run it,
+    made under ``jit``: eagerly, ``init`` compiles and dispatches a program
+    for every layer's every operation.  139² is the smallest resolution
+    whose 17×17-level grid (7×7 here) survives the aux head's 5×5/3 VALID
+    pool."""
+    model = InceptionV3(num_classes=4, dtype=jnp.float32, aux_logits=True)
+    x = jnp.ones((1, 139, 139, 3))
+    return model, x, jax.jit(
+        lambda: model.init(jax.random.PRNGKey(0), x, train=True))()
+
+
+def test_inception_v3_forward_shape(inception):
+    # the model as it is built by default, without the aux head (whose
+    # weights in the shared draw go unread)
+    model = InceptionV3(num_classes=4, dtype=jnp.float32)
     x = jnp.ones((1, 96, 96, 3))  # ≥75×75 minimum; tiny keeps compile fast
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-    logits = model.apply(variables, x, train=False,
-                         mutable=False)
-    assert logits.shape == (1, 10)
+    logits = jax.jit(lambda v: model.apply(v, x, train=False, mutable=False))(
+        inception[2])
+    assert logits.shape == (1, 4)
 
 
 def test_inception_v3_channel_progression():
@@ -81,13 +95,9 @@ def test_inception_v3_channel_progression():
     assert inter["InceptionE_1"]["__call__"][0].shape == (1, 8, 8, 2048)
 
 
-def test_inception_v3_aux_head_and_grads():
-    model = InceptionV3(num_classes=4, dtype=jnp.float32, aux_logits=True)
-    # 139² is the smallest resolution whose 17×17-level grid (7×7 here)
-    # survives the aux head's 5×5/3 VALID pool.
-    x = jnp.ones((1, 139, 139, 3))
+def test_inception_v3_aux_head_and_grads(inception):
+    model, x, variables = inception
     y = jnp.zeros((1,), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), x, train=True)
 
     def loss_fn(p):
         (logits, aux), _ = model.apply(
@@ -96,11 +106,12 @@ def test_inception_v3_aux_head_and_grads():
         ce = optax.softmax_cross_entropy_with_integer_labels
         return ce(logits, y).mean() + 0.4 * ce(aux, y).mean()
 
-    loss, grads = jax.value_and_grad(loss_fn)(variables["params"])
+    loss, grads = jax.jit(jax.value_and_grad(loss_fn))(variables["params"])
     assert jnp.isfinite(loss)
     assert all(jnp.all(jnp.isfinite(g)) for g in jax.tree.leaves(grads))
     # eval mode returns bare logits (no aux head)
-    out = model.apply(variables, x, train=False, mutable=False)
+    out = jax.jit(lambda v: model.apply(v, x, train=False, mutable=False))(
+        variables)
     assert out.shape == (1, 4)
 
 

@@ -13,6 +13,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from _one_program import shard_map
+
 from horovod_tpu.models.transformer import dense_causal_attention
 from horovod_tpu.parallel import (
     hierarchical_allreduce,
@@ -31,7 +33,7 @@ def _qkv(b=2, s=32, h=4, d=8, dtype=jnp.float32):
 def test_ring_attention_matches_dense(hvd, causal):
     q, k, v = _qkv()
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("sp",))
-    sharded = jax.shard_map(
+    sharded = shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", causal=causal),
         mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"))
     out = sharded(q, k, v)
@@ -43,7 +45,7 @@ def test_ring_attention_matches_dense(hvd, causal):
 def test_ulysses_attention_matches_dense(hvd, causal):
     q, k, v = _qkv(h=8)
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("sp",))
-    sharded = jax.shard_map(
+    sharded = shard_map(
         lambda q, k, v: ulysses_attention(q, k, v, "sp", causal=causal),
         mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"))
     out = sharded(q, k, v)
@@ -55,7 +57,7 @@ def test_ulysses_rejects_indivisible_heads(hvd):
     q, k, v = _qkv(h=3)
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("sp",))
     with pytest.raises(ValueError, match="divisible"):
-        jax.shard_map(
+        shard_map(
             lambda q, k, v: ulysses_attention(q, k, v, "sp"),
             mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"))(q, k, v)
 
@@ -63,7 +65,7 @@ def test_ulysses_rejects_indivisible_heads(hvd):
 def test_ring_attention_bf16(hvd):
     q, k, v = _qkv(dtype=jnp.bfloat16)
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("sp",))
-    out = jax.shard_map(
+    out = shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", causal=True),
         mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"))(q, k, v)
     ref = dense_causal_attention(q, k, v, causal=True)
@@ -77,11 +79,11 @@ def test_hierarchical_allreduce_matches_flat_psum(hvd):
     mesh = Mesh(devs, ("dcn", "ici"))
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 256))
 
-    flat = jax.shard_map(lambda t: jax.lax.psum(t, ("dcn", "ici")),
+    flat = shard_map(lambda t: jax.lax.psum(t, ("dcn", "ici")),
                          mesh=mesh, in_specs=P(("dcn", "ici")), out_specs=P())
     # check_vma=False: the closing ici all_gather leaves values equal across
     # the axis but the vma system cannot prove it (hvd.shard defaults this).
-    hier = jax.shard_map(
+    hier = shard_map(
         lambda t: hierarchical_allreduce(t.reshape(-1),
                                          ("dcn", "ici")).reshape(t.shape),
         mesh=mesh, in_specs=P(("dcn", "ici")), out_specs=P(), check_vma=False)
@@ -94,9 +96,9 @@ def test_hierarchical_allreduce_ragged_length(hvd):
     devs = np.array(jax.devices()).reshape(2, 4)
     mesh = Mesh(devs, ("dcn", "ici"))
     x = jax.random.normal(jax.random.PRNGKey(2), (13,))
-    flat = jax.shard_map(lambda t: jax.lax.psum(t, ("dcn", "ici")),
+    flat = shard_map(lambda t: jax.lax.psum(t, ("dcn", "ici")),
                          mesh=mesh, in_specs=P(), out_specs=P())
-    hier = jax.shard_map(lambda t: hierarchical_allreduce(t, ("dcn", "ici")),
+    hier = shard_map(lambda t: hierarchical_allreduce(t, ("dcn", "ici")),
                          mesh=mesh, in_specs=P(), out_specs=P(),
                          check_vma=False)
     np.testing.assert_allclose(hier(x), flat(x), rtol=1e-5, atol=1e-5)
@@ -125,7 +127,7 @@ def test_transformer_with_ring_attention(hvd):
         offset = jax.lax.axis_index("sp") * s_local
         return ring_model.apply(params, toks, position_offset=offset)
 
-    out = jax.shard_map(fwd, mesh=mesh, in_specs=(P(), P(None, "sp")),
+    out = shard_map(fwd, mesh=mesh, in_specs=(P(), P(None, "sp")),
                         out_specs=P(None, "sp"))(params, tokens)
     np.testing.assert_allclose(out, ref, atol=2e-4, rtol=2e-4)
 
@@ -138,7 +140,7 @@ def test_ring_flash_attention_matches_dense(hvd, causal):
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("sp",))
     # check_vma=False: pallas_call outputs carry no vma info (hvd.shard's
     # default); required whenever the flash kernel runs inside shard_map.
-    out = jax.shard_map(
+    out = shard_map(
         lambda q, k, v: ring_flash_attention(  # hvd-lint: disable=HVD108
             q, k, v, "sp", causal, block_q=4, block_k=4),
         mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"),
@@ -154,7 +156,7 @@ def test_ring_flash_attention_grads_match(hvd):
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("sp",))
 
     def loss_flash(q, k, v):
-        out = jax.shard_map(
+        out = shard_map(
             lambda q, k, v: ring_flash_attention(  # hvd-lint: disable=HVD108
                 q, k, v, "sp", True, block_q=2, block_k=2),
             mesh=mesh, in_specs=P(None, "sp"),
@@ -180,7 +182,7 @@ def test_ulysses_flash_matches_dense(hvd, causal):
     q, k, v = _qkv(h=8)
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("sp",))
     attn = make_ulysses_flash_attention("sp", block_q=8, block_k=8)
-    sharded = jax.shard_map(
+    sharded = shard_map(
         lambda q, k, v: attn(q, k, v, causal=causal),
         mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"),
         check_vma=False)  # pallas_call outputs carry no vma metadata

@@ -24,8 +24,9 @@ from horovod_tpu.training import DistributedEFState, DistributedState
 
 
 def _chipwise(fn):
-    """Run fn per-chip under shard_map with one scalar-batch input row."""
-    return hvd.shard(fn, in_specs=hvd.batch_spec(2), out_specs=P())
+    """Run fn per-chip under shard_map with one scalar-batch input row (one
+    program: eagerly a shard_map dispatches an operation at a time)."""
+    return jax.jit(hvd.shard(fn, in_specs=hvd.batch_spec(2), out_specs=P()))
 
 
 def test_quantized_allreduce_within_quantization_bound(hvd):

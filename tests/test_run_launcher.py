@@ -12,6 +12,8 @@ import subprocess
 import sys
 import textwrap
 
+import pytest
+
 from _timing import scaled
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -34,7 +36,44 @@ CRASH_SCRIPT = textwrap.dedent("""
     hvd.init()
     if hvd.rank() == 1:
         sys.exit(7)
-    time.sleep(120)   # must be terminated by the launcher, not run out
+    time.sleep(600)   # must be terminated by the launcher, not run out
+""")
+
+# Rank 1 leaves on an uncaught exception, its last words in a logging
+# handler that holds them until it is flushed.
+RAISE_SCRIPT = textwrap.dedent("""
+    import logging, logging.handlers, sys, time
+    import horovod_tpu as hvd
+    hvd.init()
+    if hvd.rank() == 1:
+        held = logging.handlers.MemoryHandler(
+            100, target=logging.StreamHandler(sys.stdout))
+        logging.getLogger("rank").addHandler(held)
+        logging.getLogger("rank").warning("LAST WORDS")
+        raise RuntimeError("rank 1 is gone")
+    time.sleep(600)   # must be terminated by the launcher, not run out
+""")
+
+# A ``sys.exit`` that was caught (a CLI's, argparse's), here and in a
+# thread, is no exit: both ranks then end cleanly, rank 1 at once and rank 0
+# two seconds later, and rank 1 waits for it at the job's shutdown barrier
+# (jax's exit handler, registered after this script's: it has run when
+# LEFT is printed).
+CLEAN_SCRIPT = textwrap.dedent("""
+    import atexit, sys, threading, time
+    atexit.register(lambda: print(f"LEFT {time.time()}", flush=True))
+    import horovod_tpu as hvd
+    hvd.init()
+    try:
+        sys.exit(3)
+    except SystemExit:
+        pass
+    worker = threading.Thread(target=sys.exit, args=(5,))
+    worker.start()
+    worker.join()
+    if hvd.rank() == 0:
+        time.sleep(2)
+    print(f"DONE {time.time()}", flush=True)
 """)
 
 
@@ -56,6 +95,51 @@ def test_crashed_rank_aborts_job_with_its_exit_code():
     res = _launch(2, CRASH_SCRIPT, timeout=scaled(180))
     assert res.returncode == 7, res.stdout + res.stderr
     assert "rank 1 exited with code 7" in res.stderr
+
+
+def test_a_rank_s_uncaught_exception_aborts_the_job_with_its_last_words():
+    res = _launch(2, RAISE_SCRIPT, timeout=scaled(180))
+    assert res.returncode == 1, res.stdout + res.stderr
+    assert "rank 1 exited with code 1" in res.stderr
+    assert "[1]: RuntimeError: rank 1 is gone" in res.stdout, res.stdout
+    assert "[1]: LAST WORDS" in res.stdout, res.stdout
+
+
+def test_a_caught_exit_is_none_and_a_clean_rank_waits_for_its_peer():
+    res = _launch(2, CLEAN_SCRIPT, timeout=scaled(180))
+    assert res.returncode == 0, res.stdout + res.stderr
+    said = {(rank, word): float(line.split()[2])
+            for line in res.stdout.splitlines()
+            for rank, word in [(line[1], line.split()[1])]
+            if word in ("DONE", "LEFT")}
+    assert set(said) == {(r, w) for r in "01" for w in ("DONE", "LEFT")}
+    assert said["0", "DONE"] >= said["1", "DONE"] + 1.5
+    # rank 1 was still there when rank 0 came to the barrier
+    assert said["1", "LEFT"] >= said["0", "DONE"]
+
+
+def test_the_exit_wrapper_goes_on_once_and_a_caught_exit_notes_nothing(
+        monkeypatch):
+    import gc
+
+    from horovod_tpu import basics
+
+    plain = sys.exit
+    monkeypatch.setattr(sys, "exit", plain)
+    monkeypatch.setattr(basics, "_sys_exit", None)
+    monkeypatch.setattr(basics, "_exit_status", None)
+    for name in ("last_exc", "last_value"):     # a failed test's, earlier
+        monkeypatch.delattr(sys, name, raising=False)
+    basics._note_exit_status()
+    once = sys.exit
+    basics._note_exit_status()      # init -> shutdown -> init
+    assert sys.exit is once is not plain and basics._sys_exit is plain
+    with pytest.raises(SystemExit) as caught:
+        sys.exit(3)
+    assert caught.value.code == 3
+    del caught
+    gc.collect()
+    assert basics._exit_status is None and basics._crash_code() == 0
 
 
 def test_rejects_hosts_flag():

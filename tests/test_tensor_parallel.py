@@ -5,6 +5,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from _one_program import shard_map
+
 from horovod_tpu.parallel import ParallelMLP
 
 
@@ -22,7 +24,7 @@ def test_parallel_mlp_matches_dense(hvd):
     # the function is linear-consistent: y(2x) for the row+psum pipeline of
     # a linear (no-bias-effect) graph relates as expected.  Simplest strong
     # check: run with tp=1 semantics by comparing against a manual gather.
-    out, params = jax.shard_map(
+    out, params = shard_map(
         init_and_apply, mesh=mesh, in_specs=P(), out_specs=(P(), P("tp")),
         check_vma=False)(x)
 
@@ -62,7 +64,7 @@ def test_tp_with_data_axis(hvd):
         # data-parallel mean over the hvd axis composes with tp
         return jax.lax.pmean(y, "hvd")
 
-    out = jax.shard_map(fwd, mesh=mesh, in_specs=P("hvd"), out_specs=P("hvd"),
+    out = shard_map(fwd, mesh=mesh, in_specs=P("hvd"), out_specs=P("hvd"),
                         check_vma=False)(x)
     assert out.shape == (8, 4)
     assert np.isfinite(np.asarray(out)).all()

@@ -1,9 +1,14 @@
 """Structure of programs compiled for a TPU v5e that is described, not
 attached (``jax.experimental.topologies``): what the chip's own compiler
-makes of a step, read off its optimized text.  Nothing executes.  The one
-file of the suite that loads the TPU compiler: keep such tests here, and
-the topology inside the fixture (a module that describes it while being
-imported gives pytest-xdist's workers different tests to collect)."""
+makes of a step, read off its optimized text.  Nothing executes.  Three
+files of the suite load the TPU compiler, so that ``--dist loadfile`` can
+give them to three workers: this one (the kernels and the layers around
+them, and the helpers the other two import), ``test_tpu_structure_served.py``
+(the serving cells' prefill and decode programs) and
+``test_tpu_structure_trained.py`` (the sparse layer as it is trained and as a
+share of it is held).  Keep such tests in them, and the topology inside the
+fixture (a module that describes it while being imported gives
+pytest-xdist's workers different tests to collect)."""
 
 import math
 import re
@@ -15,7 +20,6 @@ import optax
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
 @pytest.fixture(scope="module")
 def one_chip_mesh():
     from jax.experimental import topologies
@@ -26,6 +30,57 @@ def one_chip_mesh():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return Mesh(np.asarray(topo.devices[:1]), ("hvd",))
 
+
+def _kernels_named(text: str, name: str) -> list[str]:
+    """The ``op_name`` of every Mosaic custom call of ``text`` that holds
+    ``name``."""
+    return [line.split("op_name=")[1].split('"')[1]
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            and name in line.split("op_name=")[1]]
+
+def _arrays(shape: str) -> list[tuple[str, int]]:
+    """[(dtype, elements)] of every array in a shape's text."""
+    from horovod_tpu.utils.profiling import _ARRAY
+
+    return [(t, math.prod(int(n) for n in dims.split(",") if n))
+            for t, _, dims in _ARRAY.findall(shape)]
+
+
+def _entry_instructions(text: str) -> list[dict]:
+    """The ENTRY computation's instructions: name, opcode, result shape,
+    the operands' shapes, and whether a matmul is inside (its own opcode,
+    or the computation a fusion calls)."""
+    from horovod_tpu.utils.profiling import _computations
+
+    comps = _computations(text)
+    shape_of = {name: shape for body in comps.values()
+                for name, _, _, _, shape in body}
+    with_matmul = {c for c, body in comps.items()
+                   if any(op in ("convolution", "dot")
+                          for _, op, _, _, _ in body)}
+    entry = text[text.index("\nENTRY "):]
+    out = []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"^\s+(?:ROOT )?%?([^\s=]+) = .*?\s([\w\-]+)\((.*)$", line)
+        if not m or m.group(1) not in shape_of:
+            continue
+        name, opcode, rest = m.groups()
+        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+        calls = re.search(r"calls=%?([\w.\-]+)", rest)
+        out.append({
+            "name": name, "opcode": opcode, "shape": shape_of[name],
+            "operands": [shape_of[o] for o in operands if o in shape_of],
+            "kernel": 'custom_call_target="tpu_custom_call"' in line,
+            "matmul": opcode in ("convolution", "dot")
+            or bool(calls and calls.group(1) in with_matmul)})
+    return out
+
+
+# (B, S): at most this many full-size ``copy`` ops, none of them float32,
+# and at most this many GB moved by XLA ops that are neither matmul nor
+# kernel.  The parent of PR 31 (f32 kernel outputs, residuals in
+# [B, S, H, D]): 6 copies, two of them f32, 3.35 GB; 0 copies, 1.84 GB.
 
 def test_width1_update_is_no_epilogue_of_a_weight_gradient_matmul(
         hvd, one_chip_mesh):
@@ -77,7 +132,6 @@ def test_width1_update_is_no_epilogue_of_a_weight_gradient_matmul(
     assert len(with_sqrt) >= 4, "adamw's sqrt is nowhere: wrong probe"
     assert not [ops for ops in with_sqrt if "convolution" in ops]
 
-
 @pytest.mark.parametrize("b,s", [(8, 2048), (4, 4096), (1, 16384),
                                   (1, 32768)])
 def test_fused_flash_backward_compiles_within_the_vmem_it_asks_for(
@@ -124,7 +178,6 @@ def test_fused_flash_backward_compiles_within_the_vmem_it_asks_for(
         assert kernels[0].split(" = ")[1].startswith(
             f"({dtype}[{b * 16},{s},128]")
 
-
 @pytest.mark.parametrize("q,n,heads,groups,dtype", [
     (256, 128, 64, 1, jnp.bfloat16),    # granite-4.0-h-micro's mixer
     (256, 256, 32, 1, jnp.bfloat16), (128, 128, 8, 2, jnp.bfloat16),
@@ -164,7 +217,6 @@ def test_the_scans_kernels_compile_wherever_their_rule_sends_them(
     assert kernels_of(q, 64) == [profiling.SSD_BWD, profiling.SSD_FWD]
     assert ssd.head_block(2 * q, heads // groups, 32, n) is None
     assert kernels_of(2 * q, 32) == []
-
 
 @pytest.mark.parametrize("s,start,widths,taps,dtype", [
     # granite-4.0-h-micro's mixer: x | B | C at column 4096 of 8512
@@ -213,16 +265,6 @@ def test_the_convs_kernels_compile_wherever_their_rule_sends_them(
     assert cc.conv_form(s - 8, taps, start, widths) == "xla"
     assert not _kernels_named(compiled_text(s - 8), "hvd_causal_conv")
 
-
-def _kernels_named(text: str, name: str) -> list[str]:
-    """The ``op_name`` of every Mosaic custom call of ``text`` that holds
-    ``name``."""
-    return [line.split("op_name=")[1].split('"')[1]
-            for line in text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line
-            and name in line.split("op_name=")[1]]
-
-
 @pytest.mark.parametrize("b,s,heads,d", [
     (1, 1024, 32, 128),     # a row block of Ling-3.0-flash's mixer
     (1, 2048, 32, 128),     # its 2048 bucket: no loop, 32 chunks a call
@@ -257,108 +299,6 @@ def test_the_delta_rules_kernel_compiles_wherever_its_rule_sends_it(
         assert kda_scan.scan_form(*off, off[1]) == "xla"
         assert kernels_of(*off) == []
 
-
-def _served_prefill(mesh, monkeypatch, cell: str, bucket: int, max_len=None,
-                    **cut):
-    """(the model's configuration, the backend, the compiled prefill program
-    of ``bucket`` positions) of a served cell with ``cut`` replaced in its
-    configuration, beside a pool of two slots; nothing runs."""
-    import dataclasses
-    import os
-
-    from benchmarks import run as harness
-    from horovod_tpu.models import transformer as T
-    from horovod_tpu.serving.engine import TransformerBackend
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = NamedSharding(mesh, P())
-    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-        a.shape, a.dtype, sharding=one_chip)
-    manifest = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BENCHMARK.json")
-    _, _, config, traffic = harness.load_cell(manifest, cell)
-    family = harness.load_module("families", config["family"])
-    cfg = dataclasses.replace(family.model_config(config, traffic), **cut)
-    model = T.Transformer(cfg)
-    params = jax.tree.map(on_chip, jax.eval_shape(
-        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
-    slots, max_len = 2, max_len or int(traffic["max_seq_len"])
-    pool = jax.tree.map(on_chip, jax.eval_shape(
-        lambda: T.init_kv_cache(cfg, slots, max_len)))
-    with monkeypatch.context() as m:    # no pool is made: nothing runs
-        m.setattr(T, "init_kv_cache", lambda *a, **kw: (None, None))
-        backend = TransformerBackend(model, None, cfg, slots, max_len)
-    i32 = on_chip(jax.ShapeDtypeStruct((), jnp.int32))
-    return cfg, backend, backend._prefill.lower(
-        params, *pool, on_chip(jax.ShapeDtypeStruct((1, bucket), jnp.int32)),
-        i32, i32).compile()
-
-
-def test_a_served_kda_prefill_holds_one_kernel_a_layer_under_its_scope(
-        one_chip_mesh, monkeypatch):
-    """The served cell's prefill of several row blocks, two "kda" layers of
-    it and the latent one: one custom call of the kernel's name a layer, inside
-    the loop over the prompt's blocks, its ``op_name`` under the layer's
-    ``hvd_kda_scan`` (which is how PR 34's rule files its time under that
-    scope's metrics)."""
-    from horovod_tpu.utils import profiling
-
-    _, _, compiled = _served_prefill(
-        one_chip_mesh, monkeypatch, "ling3f-longdoc32k-open", 4096,
-        max_len=8192, num_layers=3, first_dense_layers=2, vocab_size=1024,
-        layer_types=("kda", "kda", "latent_attention"))
-    text = compiled.as_text()
-    kernels = _kernels_named(text, profiling.KDA_CHUNK)
-    assert len(kernels) == 2
-    for layer, name in enumerate(sorted(kernels)):
-        assert f"/layer_{layer}/kda/while/body/{profiling.KDA_SCAN}/" in name
-        assert profiling.module_of(name) == (
-            f"Transformer/layer_N/kda/{profiling.KDA_SCAN}/"
-            f"{profiling.KDA_CHUNK}")
-
-
-def _arrays(shape: str) -> list[tuple[str, int]]:
-    """[(dtype, elements)] of every array in a shape's text."""
-    from horovod_tpu.utils.profiling import _ARRAY
-
-    return [(t, math.prod(int(n) for n in dims.split(",") if n))
-            for t, _, dims in _ARRAY.findall(shape)]
-
-
-def _entry_instructions(text: str) -> list[dict]:
-    """The ENTRY computation's instructions: name, opcode, result shape,
-    the operands' shapes, and whether a matmul is inside (its own opcode,
-    or the computation a fusion calls)."""
-    from horovod_tpu.utils.profiling import _computations
-
-    comps = _computations(text)
-    shape_of = {name: shape for body in comps.values()
-                for name, _, _, _, shape in body}
-    with_matmul = {c for c, body in comps.items()
-                   if any(op in ("convolution", "dot")
-                          for _, op, _, _, _ in body)}
-    entry = text[text.index("\nENTRY "):]
-    out = []
-    for line in entry.splitlines()[1:]:
-        m = re.match(r"^\s+(?:ROOT )?%?([^\s=]+) = .*?\s([\w\-]+)\((.*)$", line)
-        if not m or m.group(1) not in shape_of:
-            continue
-        name, opcode, rest = m.groups()
-        operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
-        calls = re.search(r"calls=%?([\w.\-]+)", rest)
-        out.append({
-            "name": name, "opcode": opcode, "shape": shape_of[name],
-            "operands": [shape_of[o] for o in operands if o in shape_of],
-            "kernel": 'custom_call_target="tpu_custom_call"' in line,
-            "matmul": opcode in ("convolution", "dot")
-            or bool(calls and calls.group(1) in with_matmul)})
-    return out
-
-
-# (B, S): at most this many full-size ``copy`` ops, none of them float32,
-# and at most this many GB moved by XLA ops that are neither matmul nor
-# kernel.  The parent of PR 31 (f32 kernel outputs, residuals in
-# [B, S, H, D]): 6 copies, two of them f32, 3.35 GB; 0 copies, 1.84 GB.
 @pytest.mark.parametrize("b,s,copies,glue_gb", [(8, 2048, 6, 2.8),
                                                 (1, 16384, 0, 1.75)])
 def test_attention_layer_keeps_no_f32_activation_between_its_kernels(
@@ -416,7 +356,6 @@ def test_attention_layer_keeps_no_f32_activation_between_its_kernels(
         for sh in [i["shape"]] + i["operands"])
     assert moved / 1e9 <= glue_gb
 
-
 def test_grouped_flash_attention_at_head_size_64_compiles_for_the_chip(
         one_chip_mesh, monkeypatch):
     """``granite4hm-s8192``'s one attention layer (PR 33): 32 query heads
@@ -457,6 +396,7 @@ def test_grouped_flash_attention_at_head_size_64_compiles_for_the_chip(
 # the forward told where a prompt ends (PR 45), at the shapes the four served
 # cells' longest buckets hand it: (S, heads, KV heads, key width, value
 # width, what else the call is told)
+
 @pytest.mark.parametrize("s,heads,kv_heads,d,d_v,told", [
     (4096, 16, 16, 128, 128, {}),                       # dsc1p3b-code-0.8knee
     (8192, 128, 8, 128, 128, {"window": 4096}),         # cmdaplus, sliding
@@ -495,311 +435,3 @@ def test_the_bounded_flash_forward_compiles_for_the_chip(
 # are a loop fusion over the pool as it lies (one query a head), one for K
 # and one for V a layer where they are a convolution (a group of queries a
 # KV head), whose operand XLA:TPU does not take from a slice of the pool
-@pytest.mark.parametrize("heads,kv_heads,layer_types,window,slots,s,views", [
-    (16, 16, None, None, 8, 4352, 0),
-    (128, 8, ("sliding_attention",) * 3 + ("full_attention",), 4096, 8,
-     8448, 8)])
-def test_decode_program_keeps_no_copy_of_the_kv_pool(
-        one_chip_mesh, heads, kv_heads, layer_types, window, slots, s, views):
-    """``TransformerBackend``'s decode program at the served widths, as the
-    chip's compiler leaves it (PR 38): the two donated ``[L, B, S, KV, D]``
-    buffers are aliased to outputs and stay in the layout they came in,
-    every op whose result is as large as the pool is the in-place update of
-    one slot's rows, and the program's temporaries are under a quarter of
-    one buffer beside the two layer views a grouped-query model copies (its
-    parent sliced every layer out and stacked them again: two buffers of
-    temporaries, 74% of a decode step)."""
-    from horovod_tpu.models.transformer import (Transformer,
-                                                TransformerConfig)
-    from horovod_tpu.serving.engine import TransformerBackend
-
-    one_chip = NamedSharding(one_chip_mesh, P())
-    cfg = TransformerConfig(
-        vocab_size=32256, num_layers=4, num_heads=heads, head_dim=128,
-        num_kv_heads=kv_heads, embed_dim=2048, mlp_dim=5504, max_seq_len=s,
-        layer_types=layer_types, sliding_window=window,
-        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
-    model = Transformer(cfg)
-    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-        a.shape, a.dtype, sharding=one_chip)
-    params = jax.tree.map(on_chip, jax.eval_shape(
-        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
-    backend = TransformerBackend.__new__(TransformerBackend)
-    backend._jax, backend.model, backend.sparse = jax, model, False
-    kv = on_chip(jax.ShapeDtypeStruct((4, slots, s, kv_heads, 128),
-                                      jnp.bfloat16))
-    i32 = on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32))
-    compiled = jax.jit(backend._decode_fn, donate_argnums=(1, 2)).lower(
-        params, kv, kv, i32, i32).compile()
-    view = math.prod(kv.shape[1:])          # elements; bf16 is 2 bytes
-    buffer_bytes, view_bytes = 2 * math.prod(kv.shape), 2 * view
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 2 * buffer_bytes
-    # a copied view of K and one of V are live at a time
-    assert mem.temp_size_in_bytes < buffer_bytes / 4 + (
-        2 * view_bytes if views else 0)
-    large = [i for i in _entry_instructions(compiled.as_text())
-             if i["opcode"] not in ("parameter", "tuple", "get-tuple-element")
-             and any(n >= view for _, n in _arrays(i["shape"]))]
-    whole = [i for i in large
-             if any(n > view for _, n in _arrays(i["shape"]))]
-    assert whole and all(
-        i["opcode"] == "fusion" and "dynamic-update-slice" in i["name"]
-        and "{4,3,2,1,0" in i["shape"] for i in whole), [
-            (i["name"], i["shape"]) for i in whole]
-    assert len(large) - len(whole) <= views, [
-        i["name"] for i in large if i not in whole]
-
-
-@pytest.mark.parametrize("t,d,f,e,held,shared,block", [
-    (4096, 7168, 2048, 192, 12, 1, 4096),   # A.X-K1's chunk, a sixteenth
-    (8192, 4096, 4096, 128, 16, 4, 16384),  # command-a-plus's 8192 bucket
-    (512, 4096, 4096, 128, 16, 4, 1024)])   # ... and its shortest
-def test_a_share_of_the_experts_keeps_no_array_of_all_its_pairs(
-        one_chip_mesh, monkeypatch, t, d, f, e, held, shared, block):
-    """A layer that holds a share of the experts walks its held pairs in
-    blocks (PR 43): compiled for the chip at the served cells' widths, the
-    program holds the block's ``[C, D]`` and ``[C, F]`` rows, the token-sum
-    kernel under its name inside one ``while``, and no ``[T * k, D]`` or
-    ``[T * k, F]`` array; the same layer with every expert of ``held`` held
-    carries them all, as it did, through the same kernels (PR 53)."""
-    from horovod_tpu.models import moe
-    from horovod_tpu.utils import profiling
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = NamedSharding(one_chip_mesh, P())
-    k = 8
-    assert moe.held_block_rows(t * k, held, e) == block
-
-    def text_of(experts, experts_held):
-        m = moe.MoEMLP(embed_dim=d, mlp_dim=f, axis_name=None,
-                       dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-                       num_experts=experts, experts_per_token=k,
-                       selection="sigmoid", norm_topk_prob=True,
-                       num_shared_experts=shared, experts_held=experts_held)
-        x = jax.ShapeDtypeStruct((1, t, d), jnp.bfloat16, sharding=one_chip)
-        valid = jax.ShapeDtypeStruct((1, t), jnp.bool_, sharding=one_chip)
-        params = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip),
-            jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0),
-                                          jnp.zeros((1, t, d), jnp.bfloat16))))
-        return jax.jit(lambda p, x, v: m.apply(p, x, valid=v)).lower(
-            params, x, valid).compile().as_text()
-
-    all_pairs = re.compile(rf"(?:bf16|f32)\[{t * k},(?:{d}|{f})\]")
-    walked = text_of(e, (0, held))
-    assert not all_pairs.search(walked)
-    assert re.search(rf"bf16\[{block},{d}\]", walked)
-    kernels = [line for line in walked.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line
-               and profiling.TOKEN_SUM in line]
-    assert len(kernels) == 1 and profiling.MOE_COMBINE in kernels[0]
-    assert walked.count(" while(") >= 1
-    # the walk's products are the grouped kernel (PR 51: gate, up and the
-    # activation one call, down another), named under the experts' scope
-    # and the layer's path as the token-sum is under the combine's, so a
-    # join by module keeps their time in the layer's; XLA's own
-    # ``ragged-dot`` kernels are the carried layer's alone
-    grouped = _kernels_named(walked, profiling.MOE_GROUPED)
-    assert len(grouped) == 2
-    path = re.search(r'op_name="([^"]*)' + profiling.TOKEN_SUM,
-                     kernels[0]).group(1).split(profiling.MOE_COMBINE)[0]
-    assert all(name.startswith(f"{path}{profiling.MOE_EXPERTS}/")
-               for name in grouped)
-    assert "ragged-dot" not in walked
-    carried = text_of(held, None)
-    assert all_pairs.search(carried) and profiling.TOKEN_SUM not in carried
-    assert "ragged-dot" not in carried
-    assert len(_kernels_named(carried, profiling.MOE_GROUPED)) == 2
-
-
-@pytest.mark.parametrize("b,s,ours", [(1, 1024, 2), (1, 8192, 2), (24, 1, 0)],
-                         ids=["shortest_bucket", "longest_bucket",
-                              "a_decode_step"])
-def test_every_expert_held_compiles_a_buckets_products_as_the_kernel(
-        one_chip_mesh, monkeypatch, b, s, ours):
-    """ZAYA1-8B's expert layer (16 experts of 2048 x 2048, top-1 by an MLP
-    router with a state, every one held) given ``valid``, compiled for the
-    chip (PR 53): over its shortest and its longest bucket the two
-    ``hvd_moe_grouped`` calls under the experts' scope, no ``ragged-dot``
-    and no walk; over a decode step's 24 rows XLA's ``ragged-dot`` kernels
-    and none of ours."""
-    from horovod_tpu.models import moe
-    from horovod_tpu.utils import profiling
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one_chip = NamedSharding(one_chip_mesh, P())
-    d, rd = 2048, 256
-    m = moe.MoEMLP(embed_dim=d, mlp_dim=d, axis_name=None,
-                   dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
-                   num_experts=16, experts_per_token=1, router_dim=rd)
-    shaped = lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
-        shape, dtype, sharding=one_chip)
-    params = jax.tree.map(
-        lambda a: shaped(*a.shape, dtype=a.dtype),
-        jax.eval_shape(lambda: m.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8, d), jnp.bfloat16),
-            router_state=jnp.zeros((1, 8, rd)))))
-    text = jax.jit(lambda p, x, v, r: m.apply(
-        p, x, valid=v, router_state=r)).lower(
-            params, shaped(b, s, d), shaped(b, s, dtype=jnp.bool_),
-            shaped(b, s, rd, dtype=jnp.float32)).compile().as_text()
-    kernels = _kernels_named(text, profiling.MOE_GROUPED)
-    assert len(kernels) == ours
-    assert all(f"/{profiling.MOE_EXPERTS}/" in name for name in kernels)
-    assert ("ragged-dot" in text) == (not ours)
-    # (nothing is walked: the only loop is the router's over the row blocks
-    # of the longest bucket, and no sum by token follows the products)
-    assert profiling.TOKEN_SUM not in text
-
-
-_OLMOE_LAYER = {}
-
-
-def _olmoe_training_layer(one_chip_mesh, monkeypatch):
-    """OLMoE's expert layer as ``olmoe-s4096`` trains it (64 experts of 2048
-    x 1024, top-8, 4 x 4096 tokens: 131072 pairs), its gradient to the
-    parameters and the rows compiled for the chip, once a module."""
-    from horovod_tpu.models import moe
-
-    if not _OLMOE_LAYER:
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        one_chip = NamedSharding(one_chip_mesh, P())
-        d, f, e, k, b, s = 2048, 1024, 64, 8, 4, 4096
-        assert moe.grouped_row_tile(b * s * k, e) == moe.WIDE_ROW_TILE == 256
-        m = moe.MoEMLP(embed_dim=d, mlp_dim=f, axis_name=None,
-                       dtype=jnp.bfloat16, num_experts=e, experts_per_token=k)
-        shaped = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
-            a.shape, a.dtype, sharding=one_chip)
-        params = jax.tree.map(shaped, jax.eval_shape(lambda: m.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 8, d), jnp.bfloat16))))
-        x = jax.ShapeDtypeStruct((b, s, d), jnp.bfloat16, sharding=one_chip)
-        # (not linear in the layer's result, so that its forward stays)
-        loss = lambda p, x: jnp.square(m.apply(  # noqa: E731
-            p, x).astype(jnp.float32)).sum()
-        _OLMOE_LAYER["compiled"] = jax.jit(
-            jax.grad(loss, argnums=(0, 1))).lower(params, x).compile()
-    return _OLMOE_LAYER["compiled"]
-
-
-def test_the_training_layer_compiles_its_products_and_their_backward_as_the_kernel(
-        one_chip_mesh, monkeypatch):
-    """OLMoE's expert layer as ``olmoe-s4096`` trains it (2048 rows an even
-    share, so 256-row tiles), forward and backward compiled for the chip
-    (PR 55): six ``hvd_moe_grouped`` calls (gate and up fused: the forward,
-    the sum of their rows' gradients, their two weights' gradients; down's
-    three) and no ``ragged-dot``; the scope table files each as that kernel
-    under the layer's path inside ``hvd_moe_experts``, two forward and four
-    backward."""
-    from horovod_tpu.utils import profiling
-
-    compiled = _olmoe_training_layer(one_chip_mesh, monkeypatch)
-    text = compiled.as_text()
-    kernels = _kernels_named(text, profiling.MOE_GROUPED)
-    assert len(kernels) == 6 and "ragged-dot" not in text
-    assert all(f"/{profiling.MOE_EXPERTS}/" in name for name in kernels)
-    ours = [scope for scope in profiling.scope_table(compiled).values()
-            if scope.kernel == profiling.MOE_GROUPED]
-    assert sorted(scope.phase for scope in ours) \
-        == ["backward"] * 4 + ["forward"] * 2
-    assert {scope.module for scope in ours} == {
-        f"MoEMLP/{profiling.MOE_EXPERTS}/{profiling.MOE_GROUPED}"}
-
-
-def test_the_training_layer_moves_its_rows_by_kernel_and_gathers_none(
-        one_chip_mesh, monkeypatch):
-    """The same compiled layer (PR 57): its four row moves are eight
-    ``hvd_moe_rows`` calls, Mosaic's at these shapes (a row a tile of 8 x
-    128 words): the dispatch's two forward (the tokens as tiles, the fetch)
-    and its backward's two (the cotangent rows sent to their pairs' slots,
-    the sum over k) under ``hvd_moe_dispatch``, the combine's send and sum
-    forward and its backward's two (``gates * dout`` spread with the gates'
-    gradient, the fetch into expert order) under ``hvd_moe_combine``; and
-    XLA gathers no ``[131072, 2048]`` array any more, nor a ``[131072]``
-    one."""
-    from horovod_tpu.utils import profiling
-
-    compiled = _olmoe_training_layer(one_chip_mesh, monkeypatch)
-    text = compiled.as_text()
-    kernels = _kernels_named(text, profiling.MOE_ROWS)
-    assert len(kernels) == 8
-    ours = [scope for scope in profiling.scope_table(compiled).values()
-            if scope.kernel == profiling.MOE_ROWS]
-    assert sorted((scope.module, scope.phase) for scope in ours) == sorted(
-        (f"MoEMLP/{where}/{profiling.MOE_ROWS}", phase)
-        for where, phase in [(profiling.MOE_DISPATCH, "forward"),
-                             (profiling.MOE_DISPATCH, "forward"),
-                             (profiling.MOE_DISPATCH, "backward"),
-                             (profiling.MOE_DISPATCH, "backward"),
-                             (profiling.MOE_COMBINE, "forward"),
-                             (profiling.MOE_COMBINE, "forward"),
-                             (profiling.MOE_COMBINE, "backward"),
-                             (profiling.MOE_COMBINE, "backward")])
-    assert not re.search(r"\[131072\]\S* gather\(", text)
-    assert not re.search(r"\[131072,2048\]\S* gather\(", text)
-
-
-# The four serving cells cut to two layers at their own widths (for A.X-K1
-# the dense layer and one sparse one; for command-a-plus a sliding and a full
-# layer), two slots: (cell, what the cut replaces, {bucket: (the ``while``
-# ops the compiled prefill holds, the same program's peak at PR 45's tree
-# [commit 7d461c2], bytes)}), at the 4096 bucket and at the cell's longest.
-# The loops a layer: the mixer's two sides and the dense feed-forward with
-# its residual adds, 3; a sparse feed-forward has its router's and its
-# shared experts' in the third's place, once a chunk, beside the walk of its
-# held pairs; EVA attention has its summaries' too, beside its merged form's
-# own map over the windows.
-PREFILL_CELLS = [
-    ("dsc1p3b-code-0.8knee", {}, {4096: (2 * 3, 873636352)}),
-    ("cmdaplus-code8k-open",
-     {"layer_types": ("sliding_attention", "full_attention")},
-     {4096: (2 * 5, 5666695168), 8192: (2 * 5, 6327314432)}),
-    ("axk1-longdoc16k-open", {"layer_types": ("latent_attention",) * 2},
-     {4096: (3 + 2 + 3, 3726478848), 16384: (3 + 2 + 4 * 3, 5359136768)}),
-    ("evabyte-code32k-open", {"layer_types": ("eva_attention",) * 2},
-     {4096: (2 * 5, 1338628608), 32768: (2 * 5, 3517683712)}),
-]
-
-
-@pytest.mark.parametrize("cell,cut,bucket", [
-    (cell, cut, bucket) for cell, cut, buckets in PREFILL_CELLS
-    for bucket in buckets], ids=lambda v: str(v) if not isinstance(v, dict)
-    else "")
-def test_a_served_prefill_holds_one_loop_a_call_site_and_no_wider_buffer(
-        one_chip_mesh, monkeypatch, cell, cut, bucket):
-    """A prefill bucket of several row blocks, as the chip's compiler leaves
-    it (PR 47): the position-wise layers are ONE loop body a call site, as
-    many ``while`` ops at 4096 as at the cell's longest bucket (a chunk's
-    apart), no ``[bucket, mlp_dim]`` array is left (a block's instead), and
-    the program's peak is no larger than its parent's."""
-    from horovod_tpu.models import transformer as T
-
-    cfg, backend, compiled = _served_prefill(
-        one_chip_mesh, monkeypatch, cell, bucket, num_layers=2, **cut)
-    assert backend.prefill_rows(bucket, bucket) == bucket
-    text = compiled.as_text()
-    whiles, parent_peak = dict(
-        (c, b) for c, _, b in PREFILL_CELLS)[cell][bucket]
-    assert len(re.findall(r" while\(", text)) == whiles
-    # the widths of a feed-forward's hidden rows, where [bucket, width] is
-    # no other array's shape: not a weight's (a bucket as long as the stream
-    # is wide) nor the block of the walk of the held pairs
-    from horovod_tpu.models import moe
-
-    sparse = (cfg.moe_mlp_dim or cfg.mlp_dim) * max(cfg.num_shared_experts, 1)
-    held = cfg.experts_held[1] - cfg.experts_held[0] if cfg.experts_held \
-        else 0
-    walked = held and moe.held_block_rows(
-        min(bucket, cfg.feed_forward_chunk or bucket)
-        * cfg.experts_per_token, held, cfg.num_experts)
-    widths = {cfg.mlp_dim, sparse} - {cfg.embed_dim} \
-        - ({sparse} if walked == bucket else set())
-    assert widths and (bucket == cfg.embed_dim or not re.search(
-        rf"(?:bf16|f32)\[(?:1,)?{bucket},(?:{'|'.join(map(str, widths))})\]",
-        text))
-    assert re.search(rf"bf16\[(?:1,)?{T.ROW_BLOCK},"
-                     rf"(?:{'|'.join(map(str, widths))})\]", text)
-    # (a megabyte for what is no array: the loops' counters, the code)
-    assert compiled.memory_analysis().peak_memory_in_bytes \
-        <= parent_peak + 2 ** 20

@@ -271,6 +271,7 @@ def test_master_weights_composes_with_int8_ef(hvd):
         return optax.apply_updates(params, u), state, l
 
     x = jax.random.normal(jax.random.PRNGKey(0), (2 * hvd.num_chips(), 64))
+    step = jax.jit(step)    # as users run it; eagerly, an op a dispatch
     p2, s2, l1 = step(params, state, x)
     p3, s3, l2 = step(p2, s2, x)
     assert p2["w"].dtype == jnp.bfloat16
@@ -312,7 +313,7 @@ def test_accumulate_composes_with_master_weights_and_int8_ef(hvd):
             u, state2 = opt.update(g, state, params)
             return optax.apply_updates(params, u), state2, l
 
-        return step
+        return jax.jit(step)    # as users run it; eagerly, an op a dispatch
 
     x = jax.random.normal(jax.random.PRNGKey(0), (4 * hvd.num_chips(), 64))
     p_full, s_full, l_full = make_step(1)(params, state, x)

@@ -12,8 +12,10 @@ Accounting model (total rx across all ranks, K iterations of n bytes):
   gather:  P*(P-1)*n*K      device:  2*(P-1)*n*K      ratio: P/2
 """
 
+import json
 import os
 import subprocess
+import sys
 
 import pytest
 
@@ -59,25 +61,75 @@ def _lo_rx_bytes() -> int:
     raise AssertionError("no loopback interface in /proc/net/dev")
 
 
+def _own_loopback() -> str:
+    """Move this process into a network namespace of its own, with its
+    loopback up: /proc/net/dev then counts this process's children and
+    nothing else.  Where the kernel will not allow it the process stays
+    where it is, and reads the machine's counter as before.  Returns which
+    counter it is, for the job's line: a ratio read off the shared one
+    beside other workers' jobs names its cause."""
+    import fcntl
+    import socket
+    import struct
+
+    try:
+        os.unshare(os.CLONE_NEWNET)
+        with socket.socket(socket.AF_INET, socket.SOCK_DGRAM) as s:
+            SIOCGIFFLAGS, SIOCSIFFLAGS, IFF_UP = 0x8913, 0x8914, 1
+            flags = struct.unpack("16sH", fcntl.ioctl(
+                s, SIOCGIFFLAGS, struct.pack("16sH", b"lo", 0)))[1]
+            fcntl.ioctl(s, SIOCSIFFLAGS,
+                        struct.pack("16sH", b"lo", flags | IFF_UP))
+    except (AttributeError, OSError) as refused:
+        return f"the machine's shared loopback ({refused!r})"
+    return "a loopback of its own"
+
+
+def _one_job(worker: str, env: dict) -> dict:
+    counter = _own_loopback()
+    before = _lo_rx_bytes()
+    try:
+        outs = _run_workers_once(worker, NPROCS, scaled(300), env)
+    except subprocess.TimeoutExpired:
+        return {"bytes": None, "err": "job timeout"}
+    ok = all(f"RANK{r} OK" in out for r, (out, _, _) in enumerate(outs))
+    return {"bytes": _lo_rx_bytes() - before if ok else None,
+            "counter": counter,
+            "err": "\n".join(err[-2000:] for _, err, _ in outs)}
+
+
 def _job_bytes(mode: str, algo: str | None = None,
                worker: str = WIRE_WORKER) -> int:
-    """Loopback rx bytes for one 4-process job.  Retries infra noise with a
-    FRESH counter read — a silent whole-job retry under one measurement
-    would double-count traffic and corrupt the ratio assertions."""
+    """Loopback rx bytes for one 4-process job, counted where no other
+    process's traffic is: the job runs under a child of this process that
+    has left for a network namespace of its own (the interface's counter is
+    the machine's, and five other workers' jobs speak over it meanwhile;
+    where the kernel refuses a namespace the shared counter is read as
+    before).  Retries infra noise with a FRESH counter read — a silent
+    whole-job retry under one measurement would double-count traffic and
+    corrupt the ratio assertions."""
     env = {"WB_MODE": mode, "WB_ELEMS": str(ELEMS), "WB_ITERS": str(ITERS)}
     if algo is not None:
         env["HVD_TPU_EAGER_REDUCE"] = algo
     last_err = ""
     for _attempt in range(2):
-        before = _lo_rx_bytes()
-        try:
-            outs = _run_workers_once(worker, NPROCS, scaled(300), env)
-        except subprocess.TimeoutExpired:
-            last_err = "job timeout"
+        res = subprocess.run(
+            [sys.executable, "-c",
+             "import json, sys, test_wire_bytes as t\n"
+             "worker, env = json.load(sys.stdin)\n"
+             "print('JOB=' + json.dumps(t._one_job(worker, env)))"],
+            input=json.dumps([worker, env]), capture_output=True, text=True,
+            cwd=os.path.dirname(os.path.abspath(__file__)))
+        said = [ln for ln in res.stdout.splitlines() if ln.startswith("JOB=")]
+        if not said:
+            last_err = res.stderr[-2000:]
             continue
-        if all(f"RANK{r} OK" in out for r, (out, _, _) in enumerate(outs)):
-            return _lo_rx_bytes() - before
-        last_err = "\n".join(err[-2000:] for _, err, _ in outs)
+        job = json.loads(said[-1][4:])
+        if job["bytes"] is not None:
+            print(f"job {mode}/{algo}: {job['bytes']} bytes over "
+                  f"{job['counter']}")
+            return job["bytes"]
+        last_err = job["err"]
     raise AssertionError(f"wire-byte job {mode}/{algo} failed twice:\n"
                          f"{last_err}")
 

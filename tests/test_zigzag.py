@@ -12,6 +12,8 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from _one_program import shard_map
+
 from horovod_tpu.models.transformer import dense_causal_attention
 from horovod_tpu.parallel import (
     zigzag_inverse_permutation,
@@ -33,15 +35,12 @@ def _sharded_zigzag(causal, s, block=2):
     perm = zigzag_permutation(s, N)
     inv = zigzag_inverse_permutation(s, N)
 
-    def run(q, k, v):
-        out = jax.shard_map(
-            lambda q, k, v: zigzag_ring_flash_attention(
-                q, k, v, "sp", causal, block, block),
-            mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"),
-            check_vma=False)(q[:, perm], k[:, perm], v[:, perm])
-        return out[:, inv]
-
-    return run
+    sharded = shard_map(
+        lambda q, k, v: zigzag_ring_flash_attention(
+            q, k, v, "sp", causal, block, block),
+        mesh=mesh, in_specs=P(None, "sp"), out_specs=P(None, "sp"),
+        check_vma=False)
+    return lambda q, k, v: sharded(q[:, perm], k[:, perm], v[:, perm])[:, inv]
 
 
 def test_permutation_round_trips():
@@ -79,8 +78,8 @@ def test_zigzag_grads_match_dense(hvd):
         return (dense_causal_attention(q, k, v, causal=True)
                 .astype(jnp.float32) ** 2).sum()
 
-    g1 = jax.grad(loss_zz, argnums=(0, 1, 2))(q, k, v)
-    g2 = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    g1 = jax.jit(jax.grad(loss_zz, argnums=(0, 1, 2)))(q, k, v)
+    g2 = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
 
@@ -115,7 +114,7 @@ def test_transformer_with_zigzag_attention(hvd):
         return zz_model.apply(params, toks,
                               positions=zigzag_positions(s_local, "sp"))
 
-    out = jax.shard_map(
+    out = shard_map(
         fwd, mesh=mesh, in_specs=(P(), P(None, "sp")),
         out_specs=P(None, "sp"), check_vma=False)(params, tokens[:, perm])
     np.testing.assert_allclose(out[:, inv], ref, atol=2e-4, rtol=2e-4)
