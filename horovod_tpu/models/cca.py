@@ -44,7 +44,8 @@ Three passes of one parameter tree:
   ``h W_v2`` at the last, in float32.
 * *with a cache* (one position a slot): the tail stands for the positions
   before, the new key and value go into the pool's rows at the slot's
-  length, attention reads the slot's rows (:func:`rows_decode_attention`)
+  length, attention reads the slot's rows
+  (``transformer.rows_decode_attention``)
   and the tail is replaced.  A block of more positions (speculative verify, a
   prefix-attached suffix) is refused by name.
 
@@ -52,7 +53,8 @@ The cache is of TWO kinds in one layer (``transformer.init_kv_cache``):
 ``"cca"`` rows a position, K in the first tree and V in the second, each
 row its KV heads side by side ``[KV D]``, and ``"cca_tail"`` a slot whatever
 its length, the convolutions' in the first and the shift's in the second.
-A decode step reads the rows AS THEY LIE (:func:`rows_decode_attention`): a
+A decode step reads the rows AS THEY LIE
+(``transformer.rows_decode_attention``, a block model's passes too): a
 pool kept ``[.., S, KV, D]`` and read through a product batched over the KV
 heads had each layer's view copied out first, 15.4 of a 27.2 ms step at 24
 slots of 10496 (read on a v5e, PERF.md section 6, PR 52).
@@ -71,6 +73,7 @@ from horovod_tpu.models.transformer import (TransformerConfig,
                                             _over_rows, _over_rows_carrying,
                                             _prompt_end, _prompt_rows,
                                             dense_causal_attention, rope,
+                                            rows_decode_attention,
                                             write_kv_block)
 from horovod_tpu.utils import profiling
 
@@ -101,35 +104,6 @@ def _to_length(x, eps: float):
     """Each vector of the last axis to length sqrt(its width), float32."""
     x = x.astype(F32)
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-
-
-def rows_decode_attention(q, k_rows, v_rows, lengths, scale=None):
-    """Block attention over a slot's cached rows as they lie: ``q`` [B, S_q,
-    H, D] at positions ``lengths[b] + i``, ``k_rows`` / ``v_rows`` [B, S, KV
-    D], a row its KV heads side by side.  A query head's vector is laid into
-    its KV head's D channels of a row-wide vector of zeros, so the scores of
-    all heads are ONE product over the row's whole width and the weighted
-    sum one more, whose output keeps each head's own D channels: twice the
-    operations of the grouped products (a decode step is bound by the rows'
-    bytes), the same numbers, and no operand re-laid head-major.
-    ``transformer.cached_decode_attention``'s arithmetic: float32 scores and
-    softmax, -1e30 behind the mask."""
-    b, s_q, h, d = q.shape
-    s, kv = k_rows.shape[1], k_rows.shape[2] // d
-    scale = d ** -0.5 if scale is None else scale
-    own = jnp.eye(kv, dtype=q.dtype)        # [a query's KV head, a slot]
-    wide = jnp.einsum("bqhgd,hj->bqhgjd", q.reshape(b, s_q, kv, h // kv, d),
-                      own).reshape(b, s_q, h, kv * d)
-    qpos = lengths[:, None] + jnp.arange(s_q)[None, :]         # [B, S_q]
-    mask = (jnp.arange(s)[None, None, :]
-            <= qpos[:, :, None])[:, None, :, :]                # [B,1,S_q,S]
-    logits = jnp.einsum("bqnf,bkf->bnqk", wide, k_rows).astype(F32) * scale
-    probs = nn.softmax(jnp.where(mask, logits, -1e30), axis=-1).astype(
-        q.dtype)
-    out = jnp.einsum("bnqk,bkf->bqnf", probs, v_rows)   # [B, S_q, H, KV D]
-    return jnp.einsum("bqhgjd,hj->bqhgd",
-                      out.reshape(b, s_q, kv, h // kv, kv, d),
-                      own.astype(out.dtype)).reshape(b, s_q, h, d)
 
 
 def _tail_at(seq, count, keep: int):

@@ -569,7 +569,11 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
                   max_len: int | None = None):
     """Preallocated per-slot K/V cache for incremental decode
     (docs/inference.md "Serving loop"): two arrays in the compute dtype
-    whose shape the model gives: keys and values ``[L, slots, S, H, D]``,
+    whose shape the model gives: keys and values ``[L, slots, S, H, D]``
+    (for a block model, ``cfg.attention_block``, the same bytes as ROWS,
+    ``[L, slots, S, H D]``: its every cache call brings block x heads query
+    rows a slot, and the products read a layer's view as it lies,
+    :func:`rows_decode_attention`; :func:`kv_pool_form` names the form),
     or for a model of latent attention the latents ``[L, slots, S,
     kv_lora_rank]`` and the one rotary key of all heads ``[L, slots, S,
     qk_rope_head_dim]`` (what a cache call writes and reads as they lie;
@@ -602,7 +606,7 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     if "cca" in cfg.layer_kinds:
         # every layer is cca: K and V rows a position, a row its KV heads
         # side by side (a decode step's products read it as it lies,
-        # models/cca.rows_decode_attention), and a slot's tail in float32 (a
+        # rows_decode_attention), and a slot's tail in float32 (a
         # decode step convolves over it what a prefill convolved over the
         # positions themselves)
         rows = (len(layout),) + lead + (cfg.kv_heads * cfg.head_dim,)
@@ -652,8 +656,28 @@ def init_kv_cache(cfg: TransformerConfig, num_slots: int,
     if cfg.latent:
         return (jnp.zeros(lead + (cfg.kv_lora_rank,), cfg.dtype),
                 jnp.zeros(lead + (cfg.qk_rope_head_dim,), cfg.dtype))
-    shape = lead + (cfg.kv_heads, cfg.head_dim)
+    if cfg.attention_block:
+        # a block model's pass brings block x heads query rows a slot: its
+        # pool is rows, a row its KV heads side by side, and every cache
+        # call reads a layer's view as it lies (:func:`rows_decode_attention`)
+        shape = lead + (cfg.kv_heads * cfg.head_dim,)
+    else:
+        shape = lead + (cfg.kv_heads, cfg.head_dim)
     return jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+
+
+def kv_pool_form(cfg: TransformerConfig) -> str:
+    """How a cached position lies in :func:`init_kv_cache`'s pool: ``"rows"``
+    where its keys and values are one row of KV D channels that a cache call
+    reads as it lies (:func:`rows_decode_attention`: a block model's
+    attention layers, "cca" layers), ``"heads"`` where they are ``[KV, D]``
+    and read through the grouped products
+    (:func:`cached_decode_attention`), ``"latents"`` for a model that caches
+    no keys or values at all."""
+    kept = {CACHE_KINDS.get(kind) for kind in cfg.layer_kinds}
+    if "cca" in kept or (cfg.attention_block and "kv" in kept):
+        return "rows"
+    return "heads" if kept & {"kv", "eva"} else "latents"
 
 
 def init_kv_pages(cfg: TransformerConfig, num_pages: int, page_size: int):
@@ -713,13 +737,50 @@ def write_kv_block(pool, block, layer: int, lengths):
     copies all of it in and out again every call, more than the stack of
     updated slices this replaces (19.3 ms a decode step of
     ``cmdaplus-code8k-open``'s program against 19.4 before and 16.0 with
-    the layout held; PERF.md section 6, PR 38)."""
+    the layout held; PERF.md section 6, PR 38).
+
+    The sequel (PR 62): held row-major, a ``[.., S, KV, D]`` pool still has
+    each layer's VIEW copied out head-major before the grouped products
+    (:func:`cached_decode_attention`; 12 copies of 264 MB a pass of
+    ``sdar30b-chat4k-open``, 9.6 of 29.4 ms).  A pool of ROWS ``[.., S, KV
+    D]`` (:func:`init_kv_cache`, a block model's; a "cca" layer's) is
+    written here just the same, a block reshaped to rows for free, and read
+    by :func:`rows_decode_attention` with nothing copied."""
     for b in range(block.shape[0]):
         pool = jax.lax.dynamic_update_slice(
             pool, block[b][None, None],
             (layer, b, lengths[b]) + (0,) * (pool.ndim - 3))
     return with_layout_constraint(
         pool, Layout(major_to_minor=tuple(range(pool.ndim))))
+
+
+def _cache_mask(lengths, s_q: int, s: int, window, block):
+    """Which of a slot's ``s`` cached positions each of a cache call's
+    ``s_q`` query rows sees, [B, 1, S_q, S]: row ``i`` sits at position
+    ``lengths[b] + i`` and sees the cache up to its own position; with
+    ``window`` positions p - window < j <= p alone; with ``block`` up to the
+    end of its own block of ``block`` positions (the block-causal mask: the
+    rows of one block see each other whole)."""
+    qpos = lengths[:, None] + jnp.arange(s_q)[None, :]         # [B, S_q]
+    if block is not None:
+        if window is not None:
+            raise ValueError("a block-causal mask has no window")
+        qpos = (qpos // block + 1) * block - 1      # its block's last position
+    mask = (jnp.arange(s)[None, None, :]
+            <= qpos[:, :, None])[:, None, :, :]                # [B,1,S_q,S]
+    if window is not None:
+        mask &= (jnp.arange(s)[None, None, :]
+                 > qpos[:, :, None] - window)[:, None, :, :]
+    return mask
+
+
+def as_pool_rows(block, pool):
+    """A prefill's cache block ``[.., S, KV, D]`` as ``pool`` holds a
+    position: itself where the ranks agree, rows ``[.., S, KV D]`` for a
+    pool of rows (:func:`init_kv_cache`, a block model's), a free reshape."""
+    if block.ndim == pool.ndim:
+        return block
+    return block.reshape(block.shape[:pool.ndim - 1] + (-1,))
 
 
 def cached_decode_attention(q, k_cache, v_cache, lengths,
@@ -742,17 +803,7 @@ def cached_decode_attention(q, k_cache, v_cache, lengths,
     cache up to the end of its own block of ``block`` positions (the
     block-causal mask: the rows of one block see each other whole)."""
     scale = q.shape[-1] ** -0.5 if scale is None else scale
-    s, s_q = k_cache.shape[1], q.shape[1]
-    qpos = lengths[:, None] + jnp.arange(s_q)[None, :]         # [B, S_q]
-    if block is not None:
-        if window is not None:
-            raise ValueError("a block-causal mask has no window")
-        qpos = (qpos // block + 1) * block - 1      # its block's last position
-    mask = (jnp.arange(s)[None, None, :]
-            <= qpos[:, :, None])[:, None, :, :]                # [B,1,S_q,S]
-    if window is not None:
-        mask &= (jnp.arange(s)[None, None, :]
-                 > qpos[:, :, None] - window)[:, None, :, :]
+    mask = _cache_mask(lengths, q.shape[1], k_cache.shape[1], window, block)
     # The query heads of a group ride one axis beside their KV head, so a
     # grouped-query cache is read once as it lies.  Repeated to the query
     # heads it would be written and read again every step, group times the
@@ -768,6 +819,40 @@ def cached_decode_attention(q, k_cache, v_cache, lengths,
     logits = jnp.where(mask[:, :, None], logits, -1e30)
     probs = nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v_cache).reshape(q.shape)
+
+
+def rows_decode_attention(q, k_rows, v_rows, lengths,
+                          scale: float | None = None,
+                          window: int | None = None,
+                          block: int | None = None):
+    """:func:`cached_decode_attention` over a slot's cached rows AS THEY
+    LIE: ``q`` [B, S_q, H, D] at positions ``lengths[b] + i``, ``k_rows`` /
+    ``v_rows`` [B, S, KV D], a row its KV heads side by side.  A query head's
+    vector is laid into its KV head's D channels of a row-wide vector of
+    zeros, so the scores of all heads are ONE product over the row's whole
+    width and the weighted sum one more, whose output keeps each head's own
+    D channels: KV times the operations of the grouped products (a cache
+    call is bound by the rows' bytes), the same numbers, and no operand
+    re-laid head-major: a pool kept ``[.., S, KV, D]`` and read through a
+    product batched over the KV heads has each layer's view copied out
+    first (PERF.md section 6, PR 52 and PR 62).  The same mask
+    (:func:`_cache_mask`: ``window``, ``block``) and arithmetic: float32
+    scores and softmax, -1e30 behind the mask."""
+    b, s_q, h, d = q.shape
+    s, kv = k_rows.shape[1], k_rows.shape[2] // d
+    scale = d ** -0.5 if scale is None else scale
+    own = jnp.eye(kv, dtype=q.dtype)        # [a query's KV head, a slot]
+    wide = jnp.einsum("bqhgd,hj->bqhgjd", q.reshape(b, s_q, kv, h // kv, d),
+                      own).reshape(b, s_q, h, kv * d)
+    mask = _cache_mask(lengths, s_q, s, window, block)
+    logits = jnp.einsum("bqnf,bkf->bnqk", wide, k_rows).astype(
+        jnp.float32) * scale
+    probs = nn.softmax(jnp.where(mask, logits, -1e30), axis=-1).astype(
+        q.dtype)
+    out = jnp.einsum("bnqk,bkf->bqnf", probs, v_rows)   # [B, S_q, H, KV D]
+    return jnp.einsum("bqhgjd,hj->bqhgd",
+                      out.reshape(b, s_q, kv, h // kv, kv, d),
+                      own.astype(out.dtype)).reshape(b, s_q, h, d)
 
 
 class Attention(nn.Module):
@@ -847,11 +932,18 @@ class Attention(nn.Module):
             # K/V at a position depend only on that position's token and
             # rotary phase, so cached entries match what a full forward pass
             # would compute there.
+            # The pool it was handed says how: rows [L, B, S, KV D] (a block
+            # model's, init_kv_cache) are written as rows, a free reshape,
+            # and read as they lie; [L, B, S, KV, D] (every plain decode, the
+            # paged backend's gathered arrays) through the grouped products.
             k_pool, v_pool, lengths, layer = cache
+            attend = cached_decode_attention
+            if k_pool.ndim == 4:
+                attend = rows_decode_attention
+                k, v = (y.reshape(*y.shape[:2], -1) for y in (k, v))
             k_pool = write_kv_block(k_pool, k, layer, lengths)
             v_pool = write_kv_block(v_pool, v, layer, lengths)
-            out = cached_decode_attention(q, k_pool[layer], v_pool[layer],
-                                          lengths, **told)
+            out = attend(q, k_pool[layer], v_pool[layer], lengths, **told)
             return o_proj(out), (k_pool, v_pool)
         attn = cfg.attention_fn
         if attn is None and cfg.context_axis and cfg.context_plan is not None:
@@ -1931,7 +2023,8 @@ class Transformer(nn.Module):
                                                     for p in pools]
                 own = [jax.tree.map(
                     lambda pool, block_: jax.lax.dynamic_update_slice(
-                        pool, block_[None].astype(pool.dtype),
+                        pool, as_pool_rows(block_[None], pool).astype(
+                            pool.dtype),
                         (at, slot) + (0,) * (pool.ndim - 2)), pool, block_)
                     for pool, block_ in zip(own, kv)]
                 x, own = jax.lax.optimization_barrier((x, own))

@@ -429,7 +429,8 @@ class TransformerBackend:
         self._jax = jax
         self.model, self.params = model, params
         self.num_slots, self.max_seq_len = num_slots, max_seq_len
-        from horovod_tpu.models.transformer import init_kv_cache
+        from horovod_tpu.models.transformer import (init_kv_cache,
+                                                    kv_pool_form)
 
         self._model_cfg = model_cfg
         self._flash_model = None    # built for the first bucket that asks
@@ -474,14 +475,16 @@ class TransformerBackend:
         # crossed in the prefills and the slots whose state a decode step
         # advanced for a request
         self.kda_counters = {"kda_blocks": 0, "state_slots": 0}
-        # the pool is the model's to shape: K and V [L, slots, S, KV, D], or
-        # latents and their rotary keys [L, slots, S, rank] / [.., rope], or
-        # for a model with "kda" layers two trees with an entry a cache kind
+        # the pool is the model's to shape: K and V [L, slots, S, KV, D] (a
+        # block model's as rows, [L, slots, S, KV D]), or latents and their
+        # rotary keys [L, slots, S, rank] / [.., rope], or for a model with
+        # "kda" layers two trees with an entry a cache kind
         with profiling.span(profiling.SETUP_POOL) as made:
             self.kk, self.vv = jax.block_until_ready(
                 init_kv_cache(model_cfg, num_slots, max_seq_len))
             made.fields["bytes"] = sum(
                 int(x.nbytes) for x in jax.tree.leaves((self.kk, self.vv)))
+            made.fields["pool_form"] = kv_pool_form(model_cfg)
         self._prefill = jax.jit(self._prefill_fn, donate_argnums=(1, 2))
         self._decode = jax.jit(
             self._block_decode_fn if self.block else self._decode_fn,
@@ -639,6 +642,8 @@ class TransformerBackend:
         return out, (jnp.stack([counted(lay) for lay in layers]),) + worst
 
     def _prefill_fn(self, params, kk, vv, padded, length, slot):
+        from horovod_tpu.models.transformer import as_pool_rows
+
         jax, jnp = self._jax, self._jax.numpy
         # a sparse model routes the prompt's own positions, not the bucket's
         # padding (and below, the slots that hold a request, not the rest)
@@ -679,8 +684,11 @@ class TransformerBackend:
         else:
             at_slot = lambda pool: (0, slot) + (0,) * (  # noqa: E731
                 pool.ndim - 2)
-            kk = jax.lax.dynamic_update_slice(kk, pk, at_slot(kk))
-            vv = jax.lax.dynamic_update_slice(vv, pv, at_slot(vv))
+            # (a block model's pool is rows: the blocks reshaped, for free)
+            kk = jax.lax.dynamic_update_slice(kk, as_pool_rows(pk, kk),
+                                              at_slot(kk))
+            vv = jax.lax.dynamic_update_slice(vv, as_pool_rows(pv, vv),
+                                              at_slot(vv))
         if block_model:
             # what _call waits for, and no logits: the whole blocks' rows
             # are in the pool and the first pass reads them
@@ -943,6 +951,7 @@ class PagedTransformerBackend:
             self.pk, self.pv = jax.block_until_ready(
                 init_kv_pages(model_cfg, num_pages, page_size))
             made.fields["bytes"] = int(self.pk.nbytes + self.pv.nbytes)
+            made.fields["pool_form"] = "heads"      # pages of [.., KV, D]
         # Host-side page tables: row s = the pages slot s reads/writes,
         # in sequence order.  Row of zeros = detached (scratch page 0).
         self.page_tables = np.zeros((num_slots, self.pages_per_slot),
@@ -1742,7 +1751,11 @@ class ServingEngine:
         caused it, ``span`` and ``bucket``).  Warm-up requests through the
         engine are there with their buckets; once the engine serves, the
         programs "never recompile", and a count that grows is a prompt
-        that met a shape nobody warmed."""
+        that met a shape nobody warmed.  ``hvd_setup_pool`` has ``pool_form``,
+        the newest backend's: ``"rows"`` where a cached position's keys and
+        values lie as one row that a cache call reads as it lies (a block
+        model, "cca" layers), ``"heads"`` where they are split by KV head,
+        ``"latents"`` where the model caches neither."""
         records = profiling.spans()
         out = profiling.summarize(records)
         if profiling.COMPILE_BACKEND in out:
@@ -1751,6 +1764,10 @@ class ServingEngine:
                         default=float("inf"))
             out[profiling.COMPILE_BACKEND]["after_first_token"] = \
                 profiling.compiles_after(records, first)
+        pools = [r.fields["pool_form"] for r in records
+                 if r.name == profiling.SETUP_POOL and "pool_form" in r.fields]
+        if pools:
+            out[profiling.SETUP_POOL]["pool_form"] = pools[-1]
         by_attn: dict[str, dict] = {}
         for r in records:
             if r.name == profiling.SRV_PREFILL and "attn" in r.fields:

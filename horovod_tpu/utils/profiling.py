@@ -168,7 +168,7 @@ SETUP_INIT = "hvd_setup_init"       # basics.init, the call that does the work
 SETUP_ENGINE = "hvd_setup_engine"   # core/engine.lib: ``make`` and the load;
                                     # ``built``: make produced a new library
 SETUP_POOL = "hvd_setup_pool"       # serving backend: the pool made on the
-                                    # device; ``bytes``
+                                    # device; ``bytes``, ``pool_form``
 SETUP_BACKEND = "hvd_setup_backend"     # chip.require_tpu asking jax for its
                                     # backend: the TPU runtime's attach where
                                     # nobody asked before; after the caller's

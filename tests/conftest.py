@@ -30,7 +30,7 @@ import pytest  # noqa: E402
 FILE_SECONDS = {
     "test_prefill_rows": 230, "test_mamba2": 230, "test_kda": 230,
     "test_flash_attention": 220, "test_examples": 210,
-    "test_examples_frameworks": 210, "test_tpu_structure_served": 190,
+    "test_examples_frameworks": 210, "test_tpu_structure_served": 205,
     "test_bench_hybrid": 190, "test_latent_attention": 180,
     "test_tpu_structure": 180, "test_examples_launched": 170,
     "test_bench_moe": 160, "test_cohere2_moe": 160, "test_cca_served": 160,
@@ -46,6 +46,7 @@ FILE_SECONDS = {
     "test_elastic": 60, "test_bench_evabyte": 60,
     "test_hyper_connections": 60, "test_parallel": 50, "test_setup_spans": 50,
     "test_bench_xing": 50, "test_failure_detection": 50, "test_zigzag": 40,
+    "test_kv_rows_pool": 40,
 }
 
 

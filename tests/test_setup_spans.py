@@ -302,7 +302,8 @@ def test_the_pool_is_a_span_with_its_bytes(toy_engine):
     pool = [r for r in profiling.spans()
             if r.name == profiling.SETUP_POOL and r.start >= began][0]
     # K and V: [layers, slots, positions, heads, head_dim] of bfloat16
-    assert pool.fields == {"bytes": 2 * 2 * 4 * 128 * 2 * 32 * 2}
+    assert pool.fields == {"bytes": 2 * 2 * 4 * 128 * 2 * 32 * 2,
+                           "pool_form": "heads"}
     assert pool.fields["bytes"] == eng.backend.kk.nbytes \
         + eng.backend.vv.nbytes
     assert pool.seconds > 0
@@ -322,9 +323,13 @@ def test_a_recompile_in_service_is_named_with_its_bucket(toy_engine):
     before = eng.span_summary()[profiling.COMPILE_BACKEND][
         "after_first_token"]
     # the engine's own warm-up: the decode program compiled after the first
-    # prefill had completed, and nothing of bucket 64 yet
+    # prefill had completed, and nothing of bucket 64 yet (this engine's:
+    # the ring is the process's, and a file run before this one in the same
+    # worker may have compiled a bucket of 64 after ITS first prefill)
+    own = profiling.compiles_after(profiling.spans(), began)["programs"]
     assert {p["fun_name"] for p in before["programs"]} >= {"jit(_decode_fn)"}
-    assert not [p for p in before["programs"] if p.get("bucket") == 64]
+    assert {p["fun_name"] for p in own} >= {"jit(_decode_fn)"}
+    assert not [p for p in own if p.get("bucket") == 64]
     t = time.perf_counter()
     eng.submit(list(range(1, 50)), 3)       # past the warmed bucket
     eng.run_until_idle()

@@ -4,6 +4,7 @@ helpers): a prefill bucket of several row blocks, the decode program beside
 its pool, a bucket's products where every expert is held.  Nothing
 executes."""
 
+import dataclasses
 import math
 import re
 
@@ -140,6 +141,71 @@ def test_decode_program_keeps_no_copy_of_the_kv_pool(
             (i["name"], i["shape"]) for i in whole]
     assert len(large) - len(whole) <= views, [
         i["name"] for i in large if i not in whole]
+
+@pytest.mark.parametrize("form,copies", [("rows", 0), ("heads", 4)])
+def test_a_block_models_pass_reads_its_pool_of_rows_as_it_lies(
+        one_chip_mesh, monkeypatch, form, copies):
+    """``sdar30b-chat4k-open``'s pass at the cell's widths, slots and
+    positions, two layers of it, as the chip's compiler leaves it (PR 62).
+    Over the pool ``init_kv_cache`` gives a block model, rows ``[L, slots,
+    S, KV D]``, the only entry ops as large as a layer's view are the
+    in-place writes of the blocks: the two products take the view where it
+    lies.  Handed ``[L, slots, S, KV, D]`` arrays the same model goes through
+    the grouped products and XLA copies K's and V's view of every layer out
+    head-major first (``slice_bitcast_fusion``, 264 MB each: a third of the
+    parent's pass)."""
+    import os
+
+    from benchmarks import run as harness
+    from horovod_tpu.models import transformer as T
+    from horovod_tpu.serving.engine import TransformerBackend
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one_chip = NamedSharding(one_chip_mesh, P())
+    on_chip = lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    manifest = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "BENCHMARK.json")
+    _, _, config, traffic = harness.load_cell(manifest,
+                                              "sdar30b-chat4k-open")
+    family = harness.load_module("families", config["family"])
+    cfg = dataclasses.replace(family.model_config(config, traffic),
+                              num_layers=2)
+    model = T.Transformer(cfg)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    slots, max_len = int(traffic["num_slots"]), int(traffic["max_seq_len"])
+    rows = (cfg.num_layers, slots, max_len, cfg.kv_heads * cfg.head_dim)
+    assert tuple(p.shape for p in jax.eval_shape(
+        lambda: T.init_kv_cache(cfg, slots, max_len))) == (rows, rows)
+    shape = rows if form == "rows" else rows[:3] + (cfg.kv_heads,
+                                                    cfg.head_dim)
+    pool = on_chip(jax.ShapeDtypeStruct(shape, cfg.dtype))
+    with monkeypatch.context() as m:    # no pool is made: nothing runs
+        m.setattr(T, "init_kv_cache", lambda *a, **kw: (None, None))
+        backend = TransformerBackend(model, None, cfg, slots, max_len)
+    compiled = backend._decode.lower(
+        params, pool, pool,
+        on_chip(jax.ShapeDtypeStruct((slots, cfg.attention_block),
+                                     jnp.int32)),
+        on_chip(jax.ShapeDtypeStruct((slots,), jnp.int32)),
+        on_chip(jax.ShapeDtypeStruct((slots,), jnp.bool_))).compile()
+    view = math.prod(shape[1:])
+    large = [i for i in _entry_instructions(compiled.as_text())
+             if i["opcode"] not in ("parameter", "tuple", "get-tuple-element",
+                                    "bitcast")
+             and any(n >= view for _, n in _arrays(i["shape"]))]
+    written = [i for i in large if "dynamic-update-slice" in i["opcode"]
+               or "dynamic-update-slice" in i["name"]]
+    assert len(written) == 2 * cfg.num_layers * slots
+    copied = [i for i in large if i not in written]
+    assert len(copied) == copies, [(i["name"], i["shape"]) for i in copied]
+    assert all("slice_bitcast_fusion" in i["name"] for i in copied)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 2 * math.prod(shape)
+    # a copied view of K and one of V are live at a time
+    assert mem.temp_size_in_bytes < 0.2e9 + (2 * 2 * view if copies else 0)
+
 
 @pytest.mark.parametrize("b,s,ours", [(1, 1024, 2), (1, 8192, 2), (24, 1, 0)],
                          ids=["shortest_bucket", "longest_bucket",
